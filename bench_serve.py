@@ -73,6 +73,7 @@ import argparse
 import json
 import os
 import random
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -395,8 +396,8 @@ def bench_paged_kernel(on_tpu: bool, seed: int = 0) -> Dict:
     rng = np.random.default_rng(seed)
     N = 1 + B * T
     dt = np.float32
-    kc = rng.normal(size=(N, bs, KVH, D)).astype(dt)
-    vc = rng.normal(size=(N, bs, KVH, D)).astype(dt)
+    kc = rng.normal(size=(N, KVH, bs, D)).astype(dt)
+    vc = rng.normal(size=(N, KVH, bs, D)).astype(dt)
     q = rng.normal(size=(B, 1, H, D)).astype(dt)
     bt = rng.permutation(np.arange(1, N)).astype(np.int32).reshape(B, T)
     # mixed lengths: even slots hold a handful of tokens, odd slots a
@@ -406,8 +407,9 @@ def bench_paged_kernel(on_tpu: bool, seed: int = 0) -> Dict:
     pos = (lens - 1)[:, None].astype(np.int32)
 
     ref_fn = jax.jit(lambda *a: paged_attention(*a, impl="reference"))
+    impl = "kernel" if on_tpu else "interpret"
     ker_fn = jax.jit(lambda q_, k_, v_, bt_, p_, l_: paged_attention(
-        q_, k_, v_, bt_, p_, lens=l_, impl="kernel"))
+        q_, k_, v_, bt_, p_, lens=l_, impl=impl))
     ref = np.asarray(ref_fn(q, kc, vc, bt, pos))
     ker = np.asarray(ker_fn(q, kc, vc, bt, pos, lens))
     parity = float(np.max(np.abs(ref - ker)))
@@ -463,6 +465,7 @@ def _disagg_fleet_run(name: str, model: Dict, engine: Dict,
     router = deploy_disaggregated(
         model, engine, name=name, num_prefill=1, num_decode=1,
         decode_slots=decode_slots,
+        ray_actor_options=_REPLICA_ACTOR_OPTIONS,
         max_ongoing_requests=4 * clients + 8)
     # one throwaway request compiles both fleets' programs (and the
     # hand-off path) outside the measured window
@@ -700,7 +703,9 @@ def _scale_up_run(name: str, model: Dict, engine: Dict,
         }
     else:
         kw["num_replicas"] = 1
-    dep = serve.deployment(name=name, **kw)(serve.LLMServer)
+    dep = serve.deployment(
+        name=name, ray_actor_options=_REPLICA_ACTOR_OPTIONS,
+        **kw)(serve.LLMServer)
     serve.run(dep.bind(model=model, engine=engine), name=name)
     handle = serve.get_app_handle(name)
     list(handle.options(stream=True).generate.remote([2, 3, 5], 2))
@@ -785,6 +790,7 @@ def _fleet_leg(name: str, model: Dict, engine: Dict, workload: List[dict],
 
     dep = serve.deployment(
         name=name, num_replicas=replicas,
+        ray_actor_options=_REPLICA_ACTOR_OPTIONS,
         max_ongoing_requests=4 * clients + 8)(serve.LLMServer)
     serve.run(dep.bind(model=model, engine=engine), name=name)
     handle = serve.get_app_handle(name)
@@ -860,17 +866,63 @@ def bench_fleet(model: Dict, engine: Dict, replicas: int, clients: int,
     return fleet
 
 
+#: actor options of every LLMServer replica the legs deploy: one chip
+#: each once bench() has found a TPU (replicas are worker processes and
+#: each must own its chip; this driver never opens one)
+_REPLICA_ACTOR_OPTIONS: Dict = {}
+
+_CLUSTERLESS_TAG = "CLUSTERLESS_LEGS "
+
+
+def clusterless_legs(spec: Dict) -> Dict:
+    """The five legs that need a device but no cluster — paged-kernel
+    op comparison, mixed-length engine run, trace overhead, disagg
+    parity, warm-prefix migration — in the process that calls this.
+    bench() runs it in a child (``--clusterless-legs``) that owns the
+    chip and exits before the cluster's replicas need it."""
+    model, engine, seed = spec["model"], spec["engine"], spec["seed"]
+    return {
+        "paged": bench_paged_kernel(spec["on_tpu"], seed=seed),
+        "mixed": bench_mixed_lengths(model, engine, seed=seed,
+                                     **spec["mixed_kw"]),
+        "trace": bench_trace_overhead(model, engine, seed=seed,
+                                      **spec["trace_kw"]),
+        "parity": bench_disagg_parity(model, engine, seed=seed),
+        "migration": bench_migration(model, engine, seed=seed),
+    }
+
+
+def _clusterless_legs_in_child(spec: Dict) -> Dict:
+    import subprocess
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__),
+         "--clusterless-legs", json.dumps(spec)],
+        stdout=subprocess.PIPE, text=True, timeout=7200)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"clusterless legs child failed (rc={proc.returncode})")
+    for line in reversed(proc.stdout.splitlines()):
+        if line.startswith(_CLUSTERLESS_TAG):
+            return json.loads(line[len(_CLUSTERLESS_TAG):])
+    raise RuntimeError("clusterless legs child printed no result")
+
+
 def bench(smoke: bool = False, clients: int = 8, requests: int = 24,
           seed: int = 0, fleet_replicas: int = 0,
           fleet_clients: int = 0, fleet_requests: int = 0,
           scale_up: bool = True) -> dict:
-    import jax
-
     import ray_tpu
     from ray_tpu import serve
+    from ray_tpu.core.accelerators import probe_devices
 
-    backend = jax.default_backend()
+    # a child asks jax what is here: this process starts the workers
+    # that will own the chips and must not hold one itself
+    probe = probe_devices()
+    backend = probe["platform"]
     on_tpu = backend == "tpu"
+    _REPLICA_ACTOR_OPTIONS.clear()
+    if on_tpu:
+        _REPLICA_ACTOR_OPTIONS["num_tpus"] = 1
     if smoke:
         clients, requests = min(clients, 4), min(requests, 6)
         model = {"vocab_size": 128, "d_model": 32, "n_layers": 2,
@@ -954,16 +1006,18 @@ def bench(smoke: bool = False, clients: int = 8, requests: int = 24,
                          mean_interarrival_s=0.02,
                          prompt_rng=(48, 96), out_rng=(16, 32))
 
-    # clusterless legs first: the paged-kernel op comparison and the
-    # mixed-length engine run need a device, not the cluster
-    paged = bench_paged_kernel(on_tpu, seed=seed)
-    mixed = bench_mixed_lengths(model, engine, seed=seed, **mixed_kw)
-    trace = bench_trace_overhead(model, engine, seed=seed, **trace_kw)
-    parity = bench_disagg_parity(model, engine, seed=seed)
-    migration = bench_migration(model, engine, seed=seed)
+    # clusterless legs first, in one child: the paged-kernel op
+    # comparison and the engine runs need a device, not the cluster
+    legs = _clusterless_legs_in_child({
+        "on_tpu": on_tpu, "model": model, "engine": engine,
+        "seed": seed, "mixed_kw": mixed_kw, "trace_kw": trace_kw})
+    paged, mixed, trace, parity, migration = (
+        legs[k] for k in ("paged", "mixed", "trace", "parity",
+                          "migration"))
 
     ray_tpu.init(num_cpus=max(8, clients + 4,
                               fleet_kw["clients"] // 2 + 6),
+                 num_tpus=probe["count"] if on_tpu else None,
                  _num_initial_workers=3, ignore_reinit_error=True)
     modes = {}
     stats = {}
@@ -973,7 +1027,8 @@ def bench(smoke: bool = False, clients: int = 8, requests: int = 24,
             ecfg = dict(engine, decode_slots=slots)
             name = f"llm_{mode}"
             dep = serve.deployment(
-                name=name, max_ongoing_requests=4 * clients + 8)(
+                name=name, ray_actor_options=_REPLICA_ACTOR_OPTIONS,
+                max_ongoing_requests=4 * clients + 8)(
                     serve.LLMServer)
             serve.run(dep.bind(model=model, engine=ecfg), name=name)
             handle = serve.get_app_handle(name)
@@ -1065,12 +1120,17 @@ def main() -> int:
                     help="fleet-leg Poisson clients (0 = default)")
     ap.add_argument("--fleet-requests", type=int, default=0,
                     help="fleet-leg request count (0 = default)")
+    ap.add_argument("--clusterless-legs", help=argparse.SUPPRESS)
     ap.add_argument("--scale-up-mid-load",
                     action=argparse.BooleanOptionalAction, default=True,
                     help="run the autoscaling-fleet-under-load leg "
                          "(one backlogged replica must scale up "
                          "mid-run; --no-scale-up-mid-load skips it)")
     args = ap.parse_args()
+    if args.clusterless_legs:
+        out = clusterless_legs(json.loads(args.clusterless_legs))
+        print(_CLUSTERLESS_TAG + json.dumps(out), flush=True)
+        return 0
     rec = bench(smoke=args.smoke, clients=args.clients,
                 requests=args.requests, seed=args.seed,
                 fleet_replicas=args.fleet_replicas,

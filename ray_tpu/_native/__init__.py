@@ -1,8 +1,11 @@
 """ctypes loader for the native store (builds on first use).
 
-The C++ extension is optional: if g++ (or a prebuilt
-``libnativestore.so``) is unavailable the Python mmap store is used.
-Set ``RAY_TPU_NATIVE_STORE=0`` to force the fallback.
+The C++ extension is optional in exactly two stated cases, in which the
+Python mmap store is used: ``RAY_TPU_NATIVE_STORE=0``, or no ``g++`` on
+this machine (and no library already built for this ``store.cpp``).
+With a compiler present, a build or load that fails is an error — the
+store never switches implementation quietly. :func:`store_kind` says
+which one a process got.
 """
 
 from __future__ import annotations
@@ -10,6 +13,7 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -34,7 +38,8 @@ _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _build() -> None:
+    """Compile store.cpp into ``_LIB_PATH``; raises if g++ fails."""
     # Sanitizer-instrumented builds live in tests/core/test_store_sanitize.py
     # (a standalone stress binary over the same TU) — the loader builds
     # the production library only.
@@ -43,16 +48,16 @@ def _build() -> bool:
     tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
            "-o", tmp, _SRC_PATH, "-lpthread"]
-    try:
-        out = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+    out = subprocess.run(cmd, capture_output=True, timeout=300)
     if out.returncode != 0:
         try:
             os.unlink(tmp)
         except FileNotFoundError:
             pass
-        return False
+        raise RuntimeError(
+            "native store build failed (set RAY_TPU_NATIVE_STORE=0 to "
+            "run on the Python store):\n"
+            + out.stderr.decode(errors="replace")[-2000:])
     os.replace(tmp, _LIB_PATH)
     # reap binaries for older source revisions (processes that still have
     # one mapped keep it alive via the inode; the name can go)
@@ -64,25 +69,33 @@ def _build() -> bool:
                 os.unlink(os.path.join(_HERE, name))
             except OSError:
                 pass
-    return True
+
+
+def store_kind() -> str:
+    """"native", or why this process runs on the Python store."""
+    if load() is not None:
+        return "native"
+    if os.environ.get("RAY_TPU_NATIVE_STORE", "1") == "0":
+        return "python (RAY_TPU_NATIVE_STORE=0)"
+    return "python (no g++ to build the native store)"
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The native library, building it if needed; None if unavailable."""
+    """The native library, building it if needed. None only when it is
+    switched off or cannot be built for want of a compiler (module
+    docstring); a failed build or load raises."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
-        _tried = True
-        if os.environ.get("RAY_TPU_NATIVE_STORE", "1") == "0":
+        if os.environ.get("RAY_TPU_NATIVE_STORE", "1") == "0" or (
+                not os.path.exists(_LIB_PATH)
+                and shutil.which("g++") is None):
+            _tried = True
             return None
         if not os.path.exists(_LIB_PATH):
-            if not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            return None
+            _build()   # raises on failure; the next call tries again
+        lib = ctypes.CDLL(_LIB_PATH)
         lib.ns_create.restype = ctypes.c_void_p
         lib.ns_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                   ctypes.c_uint32]
@@ -132,4 +145,5 @@ def load() -> Optional[ctypes.CDLL]:
         lib.ns_total_size.argtypes = [ctypes.c_void_p]
         lib.ns_close.argtypes = [ctypes.c_void_p]
         _lib = lib
+        _tried = True
         return _lib

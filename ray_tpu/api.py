@@ -118,6 +118,10 @@ def init(address: Optional[str] = None,
         config.object_store_memory = int(object_store_memory)
     config.apply_system_config(_system_config or {})
     set_config(config)
+    # before the node manager starts workers: they inherit the
+    # environment, and with it where compiled programs persist
+    from ray_tpu.util import compile_cache
+    compile_cache.enable()
 
     from ray_tpu.core.node import detect_resources
     from ray_tpu.core.runtime import Runtime

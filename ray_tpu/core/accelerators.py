@@ -12,23 +12,42 @@ environments; the same env vars the metadata would populate are honored):
 - ``TPU_CHIPS_PER_HOST_BOUNDS`` / ``TPU_CHIPS`` — chips on this host
 - ``TPU_NAME`` — pod/slice name
 
-If jax is already imported (or ``RAY_TPU_DETECT_WITH_JAX=1``), chip count
-falls back to ``jax.local_device_count()``.
+The chip count comes first from the device files the TPU driver creates,
+then from those variables. Nothing here initialises a JAX backend in the asking
+process: a TPU chip belongs to the one process that opened it, and the
+processes that ask (the driver, the node manager) are not the ones that
+compute. :func:`probe_devices` asks a short-lived child instead.
 """
 
 from __future__ import annotations
 
+import glob
+import json
 import os
+import subprocess
 import sys
-from typing import List, Optional
+from typing import Any, Dict, List, Optional
 
 NUM_TPUS_PER_HOST_DEFAULT = 4
 
+#: device nodes of one TPU chip each: ``/dev/accel*`` (PCI driver, v4 and
+#: up on TPU VMs) or ``/dev/vfio/<n>`` (VFIO-bound chips)
+_CHIP_DEVICE_GLOBS = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
+
 
 def tpu_chip_count() -> int:
+    """Chips on this host — never asked of JAX (module docstring).
+    ``TPU_CHIPS`` overrides; then the device files, which are the chips
+    this machine really has (a VM handed one chip of a 2x2 host still
+    carries the host's ``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1``); the
+    ``TPU_*`` topology env only where no device file says otherwise."""
     raw = os.environ.get("TPU_CHIPS")
     if raw:
         return int(raw)
+    for pattern in _CHIP_DEVICE_GLOBS:
+        found = glob.glob(pattern)
+        if found:
+            return len(found)
     bounds = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS")  # e.g. "2,2,1"
     if bounds:
         n = 1
@@ -37,14 +56,41 @@ def tpu_chip_count() -> int:
         return n
     if os.environ.get("TPU_ACCELERATOR_TYPE") or os.environ.get("ACCELERATOR_TYPE"):
         return NUM_TPUS_PER_HOST_DEFAULT
-    jax = sys.modules.get("jax")
-    if jax is not None or os.environ.get("RAY_TPU_DETECT_WITH_JAX") == "1":
-        try:
-            import jax
-            return sum(1 for d in jax.local_devices() if d.platform == "tpu")
-        except Exception:
-            return 0
     return 0
+
+
+def jax_backend_initialized() -> bool:
+    """True once this process has created a JAX backend — from then on
+    chip visibility, ``XLA_FLAGS`` and the distributed runtime are
+    fixed for its lifetime. Importing jax alone does not count."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
+_PROBE_SRC = (
+    "import json, jax\n"
+    "d = jax.devices()\n"
+    "print('RAY_TPU_PROBE ' + json.dumps({'platform': d[0].platform, "
+    "'kind': d[0].device_kind, 'count': len(d)}))\n")
+
+
+def probe_devices(timeout_s: float = 180.0) -> Dict[str, Any]:
+    """What JAX finds on this host — ``{"platform", "kind", "count"}``
+    as ``jax.devices()`` reports them — learnt from a child process
+    that exits (and so lets go of the chips) before this returns. For
+    launchers that go on to start the workers which will own the chips.
+    Raises if the child fails."""
+    proc = subprocess.run([sys.executable, "-c", _PROBE_SRC],
+                          capture_output=True, text=True,
+                          timeout=timeout_s)
+    for line in proc.stdout.splitlines():
+        if line.startswith("RAY_TPU_PROBE "):
+            return json.loads(line[len("RAY_TPU_PROBE "):])
+    raise RuntimeError(
+        f"device probe child failed (rc={proc.returncode}):\n"
+        f"{proc.stderr[-2000:]}")
 
 
 def tpu_accelerator_type() -> Optional[str]:
@@ -88,15 +134,29 @@ def tpu_pod_worker_count() -> int:
     return max(1, total_chips // per_host)
 
 
+#: libtpu's per-process topology for a subset of a host's chips
+#: (reference: tpu.py:158-192): n chips -> TPU_CHIPS_PER_PROCESS_BOUNDS
+_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
 def set_visible_chips(chip_ids: List[int]) -> None:
-    """Per-worker chip isolation (reference: tpu.py:158-192). Must run
-    before jax initializes in the worker process."""
-    os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in chip_ids)
-    # Bounds for a single-chip or sub-host topology.
+    """Per-worker chip isolation (reference: tpu.py:158-192): this
+    process will open exactly ``chip_ids``. Must run before jax
+    initializes a backend here — afterwards libtpu has already opened
+    whatever it could see, so that is an error, not a no-op."""
+    if jax_backend_initialized():
+        raise RuntimeError(
+            f"cannot pin this process to TPU chips {chip_ids}: its jax "
+            f"backend is already initialized. Chip work needs a worker "
+            f"that has not touched jax.")
     n = len(chip_ids)
-    if n == 1:
-        os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
-        os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    if n not in _PROCESS_BOUNDS:
+        raise ValueError(
+            f"a process can hold {sorted(_PROCESS_BOUNDS)} chips of a "
+            f"host, not {n}")
+    os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in chip_ids)
+    os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = _PROCESS_BOUNDS[n]
+    os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
 
 
 def gang_resource_name() -> Optional[str]:

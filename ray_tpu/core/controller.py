@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import math
 import os
 import threading
 import time
@@ -101,6 +102,19 @@ class NodeInfo:
     starting_workers: int = 0
     stats: dict = field(default_factory=dict)
     alive: bool = True
+    #: TPU chip indices on this host no live worker has opened. A chip
+    #: belongs to the process that opened it until that process exits,
+    #: so ids come back in _h_worker_exit — not when a task finishes.
+    free_chips: List[int] = field(default_factory=list)
+    #: chip tasks waiting for free chips or a worker that has run
+    #: nothing yet (see Controller._bind_chips)
+    wait_chips: Deque[bytes] = field(default_factory=collections.deque)
+
+
+def _chips_needed(spec: TaskSpec) -> int:
+    """Whole TPU chips a task or actor must be pinned to: its ``TPU``
+    demand rounded up (two processes cannot share a chip)."""
+    return int(math.ceil(spec.resources.get("TPU", 0.0)))
 
 
 class Controller:
@@ -182,6 +196,13 @@ class Controller:
         self._last_actor_prestart = 0.0
         # worker -> last runtime-env key (env-affinity dispatch)
         self._worker_env: Dict[bytes, str] = {}
+        #: worker -> TPU chips it was pinned to (TPU_VISIBLE_CHIPS); such
+        #: a worker never returns to the idle pool, it is retired
+        self._worker_chips: Dict[bytes, List[int]] = {}
+        #: workers that have been handed any work: they may have
+        #: initialised jax (seeing every chip or none), so chip work
+        #: only goes to workers outside this set
+        self._used_workers: Set[bytes] = set()
         # worker identity -> owning driver identity: workers leased to a
         # driver for DIRECT task submission (reference: worker leases,
         # direct_task_transport.h — tasks bypass the controller wholly;
@@ -545,7 +566,9 @@ class Controller:
                                     m.get("labels") or {})
                 info = NodeInfo(node_id=node_id, identity=identity,
                                 resources=res,
-                                last_heartbeat=time.monotonic())
+                                last_heartbeat=time.monotonic(),
+                                free_chips=list(range(int(
+                                    m["resources"].get("TPU", 0)))))
                 self.nodes[node_id.binary()] = info
                 self.scheduler.add_node(res)
                 self._publish("node", {"event": "added",
@@ -586,11 +609,19 @@ class Controller:
                 node.all_workers[identity] = {"pid": m.get("pid"),
                                               "worker_id": m.get("id")}
                 node.starting_workers = max(0, node.starting_workers - 1)
+                if m.get("tpu_chips"):
+                    # re-announce after a controller restart: the
+                    # process still owns the chips it opened
+                    self._worker_chips[identity] = list(m["tpu_chips"])
+                    self._used_workers.add(identity)
+                    node.free_chips = [c for c in node.free_chips
+                                       if c not in m["tpu_chips"]]
                 if m.get("actor_id") is None and not m.get("busy"):
                     # mid-task workers return to the idle pool at their
                     # TASK_DONE (transient resource over-admission until
                     # then self-corrects)
                     node.idle_workers.append(identity)
+                    self._serve_chip_waiters(node)
                     self._grant_parked_leases()
                     self._drain_waiting_tasks(node)
             if m.get("actor_id") is not None:
@@ -882,6 +913,7 @@ class Controller:
                         node.node_id, {"CPU": 1.0}):
                     break
                 w = node.idle_workers.popleft()
+                self._used_workers.add(w)
                 self.driver_leases[w] = identity
                 self._lease_node[w] = node.node_id.binary()
                 granted.append(w)
@@ -1414,7 +1446,64 @@ class Controller:
                     node, self.tasks[tid].spec)
                 self._dispatch_to_worker(tid, node, worker)
 
+    def _bind_chips(self, tid: bytes, node: NodeInfo,
+                    worker: bytes) -> bool:
+        """Pin ``worker`` to the chips task ``tid`` holds, before the
+        dispatch that will make it import jax. Needs free chips and a
+        worker that has run nothing (one that has may already own a jax
+        backend, which cannot be re-pointed). Otherwise the task parks
+        in ``node.wait_chips``, the worker goes back to the pool, and
+        _serve_chip_waiters finds or starts a fresh one. Returns whether
+        the dispatch may proceed."""
+        n = _chips_needed(self.tasks[tid].spec)
+        if n == 0 or worker in self._worker_chips:
+            return True   # no chips, or a lease's next task: same chips
+        if worker not in self._used_workers and len(node.free_chips) >= n:
+            self._worker_chips[worker] = [node.free_chips.pop(0)
+                                          for _ in range(n)]
+            return True
+        node.wait_chips.append(tid)
+        self.tasks[tid].state = "QUEUED_WORKER"
+        node.idle_workers.append(worker)
+        self._drain_waiting_tasks(node)
+        self._serve_chip_waiters(node)
+        return False
+
+    def _serve_chip_waiters(self, node: NodeInfo) -> None:
+        """Dispatch parked chip tasks in order while chips are free and
+        an unused idle worker exists; with chips free but no such
+        worker, ask the node for one. Called when a worker registers
+        and when an exiting worker gives its chips back."""
+        while node.wait_chips:
+            tid = node.wait_chips[0]
+            t = self.tasks.get(tid)
+            if t is None:
+                node.wait_chips.popleft()
+                continue
+            if len(node.free_chips) < _chips_needed(t.spec):
+                return    # held by a worker that is still exiting
+            fresh = next((w for w in node.idle_workers
+                          if w not in self._used_workers), None)
+            if fresh is None:
+                if node.starting_workers == 0:
+                    self._request_worker(node)
+                return
+            node.wait_chips.popleft()
+            node.idle_workers.remove(fresh)
+            self._dispatch_to_worker(tid, node, fresh)
+            waiting = node.stats.get("wait_worker")
+            if waiting and not node.idle_workers \
+                    and node.starting_workers < len(waiting):
+                # the worker just taken may have been started for them
+                self._request_worker(node)
+
+    def _request_worker(self, node: NodeInfo) -> None:
+        node.starting_workers += 1
+        self._send(node.identity, P.TASK_ASSIGN, {"start_worker": True})
+
     def _dispatch_to_worker(self, tid: bytes, node: NodeInfo, worker: bytes) -> None:
+        if not self._bind_chips(tid, node, worker):
+            return
         t = self.tasks[tid]
         if t.spec.is_actor_creation:
             t.worker = worker
@@ -1475,8 +1564,10 @@ class Controller:
                 errors[oid.binary()] = e.error
             elif e.inline is not None:
                 inline_args[oid.binary()] = e.inline
+        self._used_workers.add(worker)
         self._send(worker, P.TASK_DISPATCH, {
-            "spec": t.spec, "inline_args": inline_args, "arg_errors": errors})
+            "spec": t.spec, "inline_args": inline_args, "arg_errors": errors,
+            "tpu_chips": self._worker_chips.get(worker)})
 
     def _lease_housekeeping(self, worker: bytes, lease: Lease) -> None:
         """After a completion on a leased worker: refill its pipeline from
@@ -1753,6 +1844,12 @@ class Controller:
             return
         node = self.nodes.get(info.get("node_id") or b"")
         if node is None or identity not in node.all_workers:
+            return
+        if identity in self._worker_chips:
+            # it opened TPU chips and holds them while it lives: retire
+            # it; _h_worker_exit returns the chips
+            self._send(node.identity, P.KILL_ACTOR, {
+                "pid": node.all_workers[identity].get("pid")})
             return
         waiting = node.stats.get("wait_worker")
         if waiting:
@@ -2300,6 +2397,10 @@ class Controller:
             # counted, so those must not decrement)
             node.starting_workers = max(0, node.starting_workers - 1)
         self.peers.pop(worker_identity, None)
+        self._used_workers.discard(worker_identity)
+        chips = self._worker_chips.pop(worker_identity, None)
+        if chips and node is not None:
+            node.free_chips.extend(chips)
         aid = self.worker_actors.pop(worker_identity, None)
         # close any lease first: its single resource allocation is released
         # here, so per-task failure handling must not release again
@@ -2347,6 +2448,7 @@ class Controller:
                 node.starting_workers += 1
                 self._send(node.identity, P.TASK_ASSIGN,
                            {"start_worker": True})
+            self._serve_chip_waiters(node)
         self._maybe_schedule()
 
     def _on_actor_worker_died(self, worker_identity: bytes, tid: bytes) -> None:

@@ -209,6 +209,8 @@ class Runtime:
         # attribute puts/events to each other's task ids
         self._task_ctx = threading.local()
         self._current_actor_id: Optional[ActorID] = None
+        #: TPU chips this worker process is pinned to (worker._execute)
+        self.tpu_chips: Optional[List[int]] = None
 
         self.dispatch_handler: Optional[Callable[[dict], None]] = None
         #: WorkerExecutor hook: True while a task is queued/running (a
@@ -681,6 +683,9 @@ class Runtime:
              "node_id": self.node_id.binary(), "pid": os.getpid()}
         if self._current_actor_id is not None:
             m["actor_id"] = self._current_actor_id.binary()
+        if self.tpu_chips:
+            # a restarted controller must not hand these chips out again
+            m["tpu_chips"] = self.tpu_chips
         if self.busy_probe is not None:
             try:
                 m["busy"] = bool(self.busy_probe())

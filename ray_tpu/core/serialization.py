@@ -203,6 +203,16 @@ class _OOBBytes:
         return self.ctor, (pickle.PickleBuffer(self.value),)
 
 
+def _jax_array_type():
+    """``jax.Array`` if this process has imported jax, else None —
+    without importing it. A worker imports jax lazily, inside whatever
+    task first needs it, while other threads serialize: a module found
+    half-imported in ``sys.modules`` has no ``Array`` yet (and no array
+    of it can exist yet either)."""
+    import sys
+    return getattr(sys.modules.get("jax"), "Array", None)
+
+
 def _pre_serialize(value):
     """Convert device-resident jax arrays to host numpy so the object store
     stays host-side (TPU HBM is not host-mappable; SURVEY.md §7 hard part 4).
@@ -212,9 +222,8 @@ def _pre_serialize(value):
         return _OOBBytes(bytes, value)
     if type(value) is bytearray and len(value) > _OOB_BYTES_THRESHOLD:
         return _OOBBytes(bytearray, value)
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is not None and isinstance(value, jax.Array):
+    jax_array = _jax_array_type()
+    if jax_array is not None and isinstance(value, jax_array):
         import numpy as np
         return np.asarray(value)
     return value
@@ -238,15 +247,13 @@ def _device_array_dispatch() -> Optional[dict]:
     global _jax_dispatch
     if _jax_dispatch is not None:
         return _jax_dispatch or None
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return None  # keep probing until jax shows up in the process
-    try:
-        from jax._src.array import ArrayImpl as _concrete
-    except Exception:  # pragma: no cover - layout drift across versions
-        _concrete = type(jax.numpy.zeros((), jax.numpy.float32))
-    _jax_dispatch = {_concrete: _reduce_device_array}
+    if _jax_array_type() is None:
+        return None  # keep probing until jax is (fully) imported here
+    # the class by import, never by allocating an array to take its
+    # type: that would create a backend (and grab a TPU) in whatever
+    # process happens to pickle
+    from jax._src.array import ArrayImpl
+    _jax_dispatch = {ArrayImpl: _reduce_device_array}
     return _jax_dispatch
 
 
@@ -279,9 +286,8 @@ def to_host(value):
     """Eagerly move a top-level device array to host numpy (no-op for
     anything else). The streaming worker calls this at yield time so
     the device fetch happens outside the store/report critical path."""
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is not None and isinstance(value, jax.Array):
+    jax_array = _jax_array_type()
+    if jax_array is not None and isinstance(value, jax_array):
         import numpy as np
         return np.asarray(value)
     return value

@@ -26,6 +26,7 @@ from ray_tpu.parallel.quantization import DEFAULT_BLOCK_SIZE, fake_quant
 from ray_tpu.parallel.sharding import (
     ShardingRules, FSDP_RULES, shard_params, batch_sharding, replicated,
     flatten_tree, unflatten_like)
+from ray_tpu.util import compile_cache
 
 GRAD_TRANSPORTS = ("fp32", "int8")
 
@@ -181,6 +182,7 @@ def make_train_step(config: TransformerConfig, mesh,
     if grad_transport not in GRAD_TRANSPORTS:
         raise ValueError(f"grad_transport must be one of "
                          f"{GRAD_TRANSPORTS}, got {grad_transport!r}")
+    compile_cache.enable()
     rules = rules if rules is not None else FSDP_RULES
     if remat_policy is not None:
         config = dataclasses.replace(config, remat=None,

@@ -82,9 +82,11 @@ class TransformerConfig:
     attn_block_q: int = 0                    # 0 = chip-aware default
     attn_block_k: int = 0
     # Paged decode path (serving): "auto" dispatches the Pallas paged
-    # kernel on TPU (interpret mode off-TPU when forced to "kernel");
-    # "reference" pins the pure-XLA gather. paged_block_r = 0 picks the
-    # chip-aware query-row block (ops.paged_flash.default_paged_block_r).
+    # kernel on TPU when shapes tile; "kernel" demands it (an error off
+    # TPU); "interpret" runs it in Pallas interpret mode (CPU parity);
+    # "reference" pins the pure-XLA gather — ops.attention has the
+    # rules. paged_block_r = 0 picks the chip-aware query-row block
+    # (ops.paged_flash.default_paged_block_r).
     paged_impl: str = "auto"
     paged_block_r: int = 0
     # Chunked prefill runs the same paged kernel at chunk*(heads/kv)
@@ -306,22 +308,31 @@ def remat_policy_fn(name: str):
 # --------------------------------------------------------------- forward
 def _attention(c: TransformerConfig, q, k, v, mesh, rules):
     """Dispatch attention: ring over the sp axis when it's nontrivial,
-    otherwise the flash/reference dispatcher (ops layer)."""
+    otherwise the flash/reference dispatcher (ops layer).
+
+    Under a mesh the dispatcher runs inside ``shard_map`` over the batch
+    and heads axes: attention is independent per (sequence, head), and
+    XLA cannot partition a Pallas custom call — left to GSPMD it would
+    all-gather the batch and run the kernel replicated on every chip."""
+    from jax.sharding import PartitionSpec as P
     sp_axis = rules.get("sequence") if rules else None
     if mesh is not None and sp_axis is not None and sp_axis in mesh.shape \
             and mesh.shape[sp_axis] > 1:
-        from jax.sharding import PartitionSpec as P
-        from ray_tpu.util.jax_compat import shard_map
         batch_axes = rules.get("batch")
         spec = P(batch_axes, sp_axis, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(ring_attention, axis_name=sp_axis,
                               causal=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return fn(q, k, v)
-    return multihead_attention(
-        q, k, v, causal=True, impl=c.attn_impl,
+    fn = functools.partial(
+        multihead_attention, causal=True, impl=c.attn_impl,
         block_q=c.attn_block_q, block_k=c.attn_block_k)
+    if mesh is not None and rules is not None and mesh.size > 1:
+        spec = P(rules.get("batch"), None, rules.get("heads"), None)
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
+    return fn(q, k, v)
 
 
 def _attn_sublayer(c, h, lp, sin, cos, layout, mesh, rules):
@@ -606,8 +617,8 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 
 
 # ------------------------------------------------------- inference (KV)
-# The serving decode path: a paged KV cache ([num_blocks, block_size,
-# kv_heads, head_dim] per layer, block table per sequence) written by
+# The serving decode path: a paged KV cache ([num_blocks, kv_heads,
+# block_size, head_dim] per layer, block table per sequence) written by
 # chunked prefill and batched single-token decode steps. Both entry
 # points are shape-stable — jit them once at the engine's fixed
 # (batch, chunk, table) shapes and admission never recompiles.
@@ -615,11 +626,13 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 def init_kv_cache(config: TransformerConfig, num_blocks: int,
                   block_size: int) -> Dict[str, jnp.ndarray]:
     """Allocate the paged KV cache: ``{"k", "v"}`` of shape
-    ``[n_layers, num_blocks, block_size, kv_heads, head_dim]`` in the
-    compute dtype. Zero-filled; a zero key scores 0 pre-softmax, so
-    reserved/trash blocks are numerically harmless."""
+    ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` in the
+    compute dtype — ``kv_heads`` ahead of ``block_size`` so one head's
+    page is a contiguous ``(block_size, head_dim)`` tile, which is what
+    the Pallas kernel DMAs. Zero-filled; a zero key scores 0
+    pre-softmax, so reserved/trash blocks are numerically harmless."""
     c = config
-    shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.head_dim)
+    shape = (c.n_layers, num_blocks, c.kv_heads, block_size, c.head_dim)
     return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
 
 
@@ -643,13 +656,15 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
     q = apply_rotary(q, sin, cos, positions=positions, layout=layout)
     k = apply_rotary(k, sin, cos, positions=positions, layout=layout)
 
-    n_blocks, bs = kc.shape[0], kc.shape[1]
+    n_blocks, bs = kc.shape[0], kc.shape[2]
     bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
     slot = positions % bs
     # invalid (padded) chunk positions scatter out of bounds -> dropped
     bid = jnp.where(write_mask, bid, n_blocks)
-    kc = kc.at[bid, slot].set(k.astype(kc.dtype), mode="drop")
-    vc = vc.at[bid, slot].set(v.astype(vc.dtype), mode="drop")
+    # [N, KVH, bs, D] indexed (bid, :, slot): the two index arrays
+    # broadcast to (B, C) and lead the result, matching k's (B, C, KVH, D)
+    kc = kc.at[bid, :, slot].set(k.astype(kc.dtype), mode="drop")
+    vc = vc.at[bid, :, slot].set(v.astype(vc.dtype), mode="drop")
 
     # h.shape[1] is static under jit: > 1 means a prefill chunk, whose
     # much larger query-row count can carry a bigger row block than the
@@ -679,7 +694,7 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     if c.n_experts:
         raise NotImplementedError(
             "paged decode does not support MoE configs yet")
-    bs = cache["k"].shape[2]
+    bs = cache["k"].shape[3]
     window = block_tables.shape[1] * bs
     sin, cos = rotary_table(
         window, c.rotary_dim if c.block_style == "gptj" else c.head_dim,

@@ -1,15 +1,34 @@
 """Model-facing attention API with automatic kernel dispatch.
 
 Layout here is (batch, seq, num_heads, head_dim) — the layout models carry
-activations in. Dispatch: the Pallas flash kernel on TPU when shapes tile
-cleanly onto the MXU (head_dim % 128 == 0, seq divisible by the block);
-otherwise the pure-XLA reference path (which is what CPU tests exercise).
+activations in. Both entry points take ``impl``:
+
+- ``"auto"`` — on TPU, the compiled Pallas kernel when the shape rules
+  below hold, else the pure-XLA reference; off TPU, the reference (what
+  CPU tests exercise). The choice is made from shapes alone, never by
+  catching a compile error.
+- ``"flash"`` / ``"kernel"`` — the compiled Pallas kernel, or an error:
+  off TPU there is nothing to compile it for.
+- ``"interpret"`` — the same kernel in Pallas interpret mode (CPU parity
+  tests and rehearsals; never a measurement).
+- ``"reference"`` — the pure-XLA path, by request.
+
+Shape rules. Flash: no arbitrary mask, ``head_dim % 128 == 0`` and both
+sequence lengths divisible by their (clamped) blocks. Paged:
+``head_dim % 128 == 0`` and ``block_size`` a multiple of the cache
+dtype's sublane tile (8 rows of f32, 16 of bf16).
+
+Every call records what it resolved to and why (:func:`dispatch_log`):
+the record is made while JAX traces, so it lists each distinct attention
+call site of each compiled program once.
 """
 
 from __future__ import annotations
 
+import collections
 import math
-from typing import Optional
+import threading
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +37,50 @@ from ray_tpu.ops.flash_attention import (
     default_flash_blocks, flash_attention)
 
 _NEG_INF = -1e30
+
+# Process-wide on purpose: jit caches are process-wide too, and the
+# record answers "what did the programs this process compiled run".
+_dispatch_lock = threading.Lock()
+_dispatch: Dict[tuple, int] = collections.Counter()
+
+
+def dispatch_log() -> List[Dict[str, object]]:
+    """Every attention dispatch decision this process has traced:
+    ``{"op": "flash"|"paged", "impl": "kernel"|"interpret"|"reference",
+    "why": ..., "count": n}``. ``why`` is ``"auto"`` or ``"requested"``
+    for a kernel, and for a reference either ``"requested"`` or the
+    shape rule (or platform) that ruled the kernel out."""
+    with _dispatch_lock:
+        return [{"op": op, "impl": impl, "why": why, "count": n}
+                for (op, impl, why), n in sorted(_dispatch.items())]
+
+
+def _resolve(op: str, impl: str, kernel_name: str,
+             unfit: Optional[str]) -> str:
+    """Shared dispatch rule -> "kernel" | "interpret" | "reference".
+    ``unfit`` names the shape rule the call breaks (None = it tiles)."""
+    backend = jax.default_backend()
+    if impl == "auto":
+        if backend != "tpu":
+            choice, why = "reference", "platform is not tpu"
+        elif unfit:
+            choice, why = "reference", unfit
+        else:
+            choice, why = "kernel", "auto"
+    elif impl == kernel_name:
+        if backend != "tpu":
+            raise ValueError(
+                f"{op} attention impl={impl!r} needs a TPU (platform is "
+                f"{backend!r}); pass impl='interpret' to run the kernel "
+                f"in Pallas interpret mode")
+        choice, why = "kernel", "requested"
+    elif impl in ("interpret", "reference"):
+        choice, why = impl, "requested"
+    else:
+        raise ValueError(f"unknown {op} attention impl: {impl!r}")
+    with _dispatch_lock:
+        _dispatch[(op, choice, why)] += 1
+    return choice
 
 
 def attention_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -41,13 +104,17 @@ def attention_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       ).astype(q.dtype)
 
 
-def _can_use_paged_kernel(q: jnp.ndarray, k_cache: jnp.ndarray) -> bool:
-    """TPU dispatch guard for the Pallas paged kernel: head_dim must
-    tile the lanes; tiny KV blocks fall back (per-page matmuls would be
-    bookkeeping-bound)."""
-    d = q.shape[-1]
-    bs = k_cache.shape[1]
-    return d % 128 == 0 and bs % 8 == 0
+def _paged_unfit(q: jnp.ndarray, k_cache: jnp.ndarray) -> Optional[str]:
+    """The shape rule a paged call breaks for the compiled kernel, or
+    None: head_dim must tile the lanes and a page the sublanes."""
+    from ray_tpu.ops.paged_flash import sublane_tile
+    d, bs = q.shape[-1], k_cache.shape[2]
+    tile = sublane_tile(k_cache.dtype)
+    if d % 128:
+        return f"head_dim {d} % 128 != 0"
+    if bs % tile:
+        return f"block_size {bs} % {tile} != 0 ({k_cache.dtype} pages)"
+    return None
 
 
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -56,13 +123,12 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     lens: Optional[jnp.ndarray] = None,
                     sm_scale: Optional[float] = None,
                     impl: str = "auto",
-                    block_r: Optional[int] = None,
-                    interpret: bool = False) -> jnp.ndarray:
+                    block_r: Optional[int] = None) -> jnp.ndarray:
     """Attention of new-token queries against a paged KV cache.
 
     The serving decode/prefill primitive: keys and values live in a pool
     of fixed-size blocks (``k_cache``/``v_cache`` of shape
-    ``[num_blocks, block_size, kv_heads, head_dim]``); each sequence owns
+    ``[num_blocks, kv_heads, block_size, head_dim]``); each sequence owns
     an ordered list of block ids (``block_tables[b, t]`` holds the block
     storing absolute positions ``t*block_size .. t*block_size+bs-1`` of
     sequence ``b``). Queries ``q[b, i]`` sit at absolute position
@@ -74,10 +140,10 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     are grouped onto their kv head at read time — the cache is never
     repeated.
 
-    ``impl``: "auto" | "kernel" | "reference". "kernel" is the Pallas
-    paged kernel (:mod:`ray_tpu.ops.paged_flash`) — auto-selected on
-    TPU when shapes tile; off-TPU it runs in interpret mode (parity
-    tests). ``lens [B]`` is the per-sequence LIVE token count; the
+    ``impl``: "auto" | "kernel" | "interpret" | "reference" (module
+    docstring). "kernel" is the Pallas paged kernel
+    (:mod:`ray_tpu.ops.paged_flash`) — auto-selected on TPU when shapes
+    tile. ``lens [B]`` is the per-sequence LIVE token count; the
     kernel skips whole blocks past it, making decode work proportional
     to live tokens instead of the table window. ``lens = None``
     derives a conservative bound from ``q_positions`` (every key the
@@ -88,30 +154,25 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     O(B * C * T * block_size) regardless of true lengths; keep
     ``block_tables`` sized to the serving window, not the model max.
     """
-    n_blocks, bs, kvh, d = k_cache.shape
+    n_blocks, kvh, bs, d = k_cache.shape
     b, c, h, _ = q.shape
     t = block_tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        impl = "kernel" if ((on_tpu or interpret)
-                            and _can_use_paged_kernel(q, k_cache)) \
-            else "reference"
-    if impl == "kernel":
+    choice = _resolve("paged", impl, "kernel", _paged_unfit(q, k_cache))
+    if choice != "reference":
         from ray_tpu.ops.paged_flash import paged_flash_attention
         if lens is None:
             lens = jnp.max(q_positions, axis=1).astype(jnp.int32) + 1
-        if jax.default_backend() != "tpu":
-            interpret = True
         return paged_flash_attention(
             q, k_cache, v_cache, block_tables, q_positions, lens,
-            sm_scale=sm_scale, block_r=block_r, interpret=interpret)
-    if impl != "reference":
-        raise ValueError(f"unknown paged attention impl: {impl!r}")
-    # Gather each sequence's blocks: [B, T, bs, KVH, D] -> [B, K, KVH, D]
-    k = jnp.take(k_cache, block_tables, axis=0).reshape(b, t * bs, kvh, d)
-    v = jnp.take(v_cache, block_tables, axis=0).reshape(b, t * bs, kvh, d)
+            sm_scale=sm_scale, block_r=block_r,
+            interpret=choice == "interpret")
+    # Gather each sequence's blocks: [B, T, KVH, bs, D] -> [B, K, KVH, D]
+    def gather(cache):
+        return jnp.take(cache, block_tables, axis=0) \
+            .transpose(0, 1, 3, 2, 4).reshape(b, t * bs, kvh, d)
+    k, v = gather(k_cache), gather(v_cache)
     # key slot j of the gathered view holds absolute position j
     key_pos = jnp.arange(t * bs, dtype=jnp.int32)
     mask = key_pos[None, None, :] <= q_positions[:, :, None]   # [B, C, K]
@@ -136,13 +197,16 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _can_use_flash(q, k, block_q: int, block_k: int) -> bool:
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    if d % 128 != 0:
-        return False
+def _flash_cannot(q, k, mask, block_q: int, block_k: int) -> Optional[str]:
+    """Why the flash kernel cannot compute this call in any mode, or
+    None: it knows only the causal structure and whole blocks."""
+    sq, sk = q.shape[1], k.shape[1]
+    if mask is not None:
+        return "arbitrary mask"
     bq, bk = min(block_q, sq), min(block_k, sk)
-    return sq % bq == 0 and sk % bk == 0
+    if sq % bq or sk % bk:
+        return f"seq ({sq}, {sk}) not divisible by blocks ({bq}, {bk})"
+    return None
 
 
 def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -151,35 +215,36 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         mask: Optional[jnp.ndarray] = None,
                         impl: str = "auto",
                         block_q: Optional[int] = None,
-                        block_k: Optional[int] = None,
-                        interpret: bool = False) -> jnp.ndarray:
+                        block_k: Optional[int] = None) -> jnp.ndarray:
     """Attention over (batch, seq, heads, head_dim).
 
-    ``impl``: "auto" | "flash" | "reference". Arbitrary ``mask`` forces the
-    reference path (the flash kernel handles only the causal structure).
-    ``block_q``/``block_k`` of ``None`` (or 0) resolve to chip-aware
-    defaults (``flash_attention.default_flash_blocks``).
+    ``impl``: "auto" | "flash" | "interpret" | "reference" (module
+    docstring). An arbitrary ``mask`` needs the reference path (the
+    flash kernel handles only the causal structure): "auto" takes it,
+    a forced kernel raises. ``block_q``/``block_k`` of ``None`` (or 0)
+    resolve to chip-aware defaults
+    (``flash_attention.default_flash_blocks``).
     """
     if not block_q or not block_k:
         dq_, dk_ = default_flash_blocks(
             q.shape[1], k.shape[1], q.shape[-1],
-            chip="cpu" if interpret else None)
+            chip="cpu" if impl == "interpret" else None)
         block_q = block_q or dq_
         block_k = block_k or dk_
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        use_flash = (mask is None and (on_tpu or interpret)
-                     and _can_use_flash(q, k, block_q, block_k))
-        impl = "flash" if use_flash else "reference"
-    if impl == "reference" or mask is not None:
+    d = q.shape[-1]
+    cannot = _flash_cannot(q, k, mask, block_q, block_k)
+    unfit = cannot or (f"head_dim {d} % 128 != 0" if d % 128 else None)
+    choice = _resolve("flash", impl, "flash", unfit)
+    if choice == "reference":
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                                    mask=mask)
-    if impl != "flash":
-        raise ValueError(f"unknown attention impl: {impl!r}")
+    if cannot:
+        raise ValueError(f"flash attention kernel forced on a call it "
+                         f"cannot compute: {cannot}")
     qt = jnp.swapaxes(q, 1, 2)    # (B, H, S, D)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     o = flash_attention(qt, kt, vt, causal=causal, sm_scale=sm_scale,
                         block_q=block_q, block_k=block_k,
-                        interpret=interpret)
+                        interpret=choice == "interpret")
     return jnp.swapaxes(o, 1, 2)

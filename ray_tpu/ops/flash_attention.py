@@ -30,18 +30,19 @@ from __future__ import annotations
 import dataclasses
 import functools
 import json
+import logging
 import math
 import os
-from typing import Optional, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend is importable on CPU too (for interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from ray_tpu.util import compile_cache
+
+logger = logging.getLogger(__name__)
 
 _NEG_INF = -1e30  # large-finite instead of -inf: avoids NaN from inf-inf
 
@@ -113,6 +114,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = (m_s[:, 0] + jnp.log(l[:, 0])).reshape(1, bq)
 
 
+def _compiler_params(cfg: _Cfg, grid_rank: int):
+    """Mosaic grid semantics: every axis parallel except the innermost
+    reduction axis of the rank-4 grids. Interpret mode takes none."""
+    if cfg.interpret:
+        return None
+    sem = ("parallel",) * 3 + ("arbitrary",) * (grid_rank - 3)
+    return pltpu.CompilerParams(dimension_semantics=sem)
+
+
 def _fwd_pallas(cfg: _Cfg, q, k, v) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -123,11 +133,6 @@ def _fwd_pallas(cfg: _Cfg, q, k, v) -> Tuple[jnp.ndarray, jnp.ndarray]:
     grid = (b, h, nq, nk)
 
     kernel = functools.partial(_fwd_kernel, cfg=cfg, offset=sk - sq)
-    compiler_params = None
-    if pltpu is not None and not cfg.interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -149,8 +154,9 @@ def _fwd_pallas(cfg: _Cfg, q, k, v) -> Tuple[jnp.ndarray, jnp.ndarray]:
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom l
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=compiler_params,
+        compiler_params=_compiler_params(cfg, 4),
         interpret=cfg.interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -179,10 +185,6 @@ def _delta_pallas(cfg: _Cfg, o, do):
     b, h, sq, d = o.shape
     bq = min(cfg.block_q, sq)
     nq = sq // bq
-    compiler_params = None
-    if pltpu is not None and not cfg.interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"))
     return pl.pallas_call(
         functools.partial(_delta_kernel, bq=bq),
         grid=(b, h, nq),
@@ -193,8 +195,9 @@ def _delta_pallas(cfg: _Cfg, o, do):
         out_specs=pl.BlockSpec(
             (1, 1, 1, bq), lambda b_, h_, i: (b_, h_, 0, i)),
         out_shape=jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
-        compiler_params=compiler_params,
+        compiler_params=_compiler_params(cfg, 3),
         interpret=cfg.interpret,
+        name="flash_bwd_delta",
     )(o, do)
 
 
@@ -309,11 +312,7 @@ def _bwd_pallas(cfg: _Cfg, q, k, v, o, lse, do):
     delta = _delta_pallas(cfg, o, do)                     # (b,h,1,sq)
     lse4 = lse[:, :, None, :]                             # (b,h,1,sq)
 
-    compiler_params = None
-    if pltpu is not None and not cfg.interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
+    compiler_params = _compiler_params(cfg, 4)
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, cfg=cfg, offset=offset),
         grid=(b, h, nk, nq),
@@ -339,6 +338,7 @@ def _bwd_pallas(cfg: _Cfg, q, k, v, o, lse, do):
         ],
         compiler_params=compiler_params,
         interpret=cfg.interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, do, lse4, delta)
 
     dq = pl.pallas_call(
@@ -358,6 +358,7 @@ def _bwd_pallas(cfg: _Cfg, q, k, v, o, lse, do):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=compiler_params,
         interpret=cfg.interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse4, delta)
     return dq, dk, dv
 
@@ -409,6 +410,41 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # --------------------------------------------------- block-size selection
+def resolve_chip(chip: Optional[str]) -> str:
+    """The chip name block pickers key on: ``chip`` when given, else the
+    running platform's (``chip_spec`` raises on a TPU kind it does not
+    know — block sizes are never guessed for an unknown device)."""
+    if chip is not None:
+        return chip
+    from ray_tpu.parallel.mesh import chip_spec
+    return chip_spec().name
+
+
+def time_candidates(what: str, candidates: Sequence,
+                    timer: Callable[..., float]):
+    """Time each autotune candidate and return the fastest. A candidate
+    the compiler rejects (a block too large for VMEM) is skipped with a
+    warning; if none runs this raises — an autotuner never reports a
+    winner it did not time."""
+    best, best_t, last_err = None, float("inf"), None
+    for cand in candidates:
+        args = cand if isinstance(cand, tuple) else (cand,)
+        try:
+            t = timer(*args)
+        except (ValueError, RuntimeError) as e:  # lowering / Mosaic
+            logger.warning("%s autotune: candidate %s rejected: %s",
+                           what, cand, str(e)[:200])
+            last_err = e
+            continue
+        if t < best_t:
+            best, best_t = cand, t
+    if best is None:
+        raise RuntimeError(
+            f"{what} autotune: none of {list(candidates)} compiled"
+        ) from last_err
+    return best
+
+
 def default_flash_blocks(seq_q: int, seq_k: int, head_dim: int,
                          chip: Optional[str] = None) -> Tuple[int, int]:
     """Chip-aware default (block_q, block_k).
@@ -418,12 +454,7 @@ def default_flash_blocks(seq_q: int, seq_k: int, head_dim: int,
     large head dims shrink both blocks to keep the f32 S/P tiles plus the
     (block, head_dim) operands inside VMEM.
     """
-    if chip is None:
-        try:
-            from ray_tpu.parallel.mesh import chip_spec
-            chip = chip_spec().name
-        except Exception:  # jax backend not initializable — be safe
-            chip = "cpu"
+    chip = resolve_chip(chip)
     if chip == "cpu":
         bq, bk = 256, 256
     elif head_dim >= 256:
@@ -447,16 +478,14 @@ _AUTOTUNE_CACHE: dict = {}
 # ---- disk persistence: serving replicas must not re-time the candidate
 # grid on every process start. Winners are stored as JSON keyed by
 # "chip|jax_version|seq|head_dim|causal" (the jax version is part of the
-# key because a compiler upgrade can move the optimum) under
-# $RAY_TPU_FLASH_CACHE_DIR (default ~/.cache/ray_tpu). Only TIMED
-# winners persist — chip-default fallbacks cost nothing to recompute.
+# key because a compiler upgrade can move the optimum) under the
+# compile-cache root (util/compile_cache.py). Only TIMED winners
+# persist — chip defaults cost nothing to recompute.
 _DISK_CACHE_LOADED = False
 
 
 def _autotune_cache_path() -> str:
-    d = os.environ.get("RAY_TPU_FLASH_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "ray_tpu")
-    return os.path.join(d, "flash_autotune.json")
+    return os.path.join(compile_cache.cache_root(), "flash_autotune.json")
 
 
 def _disk_cache_enabled() -> bool:
@@ -587,14 +616,10 @@ def autotune_flash_blocks(seq: int, head_dim: int, *,
 
     Off-TPU (and without an injected ``timer``) this returns the
     chip-aware default without running anything. ``timer`` is injectable
-    for tests: a callable ``(block_q, block_k) -> seconds``.
+    for tests: a callable ``(block_q, block_k) -> seconds``. Raises when
+    no candidate compiles (:func:`time_candidates`).
     """
-    if chip is None:
-        try:
-            from ray_tpu.parallel.mesh import chip_spec
-            chip = chip_spec().name
-        except Exception:
-            chip = "cpu"
+    chip = resolve_chip(chip)
     key = (chip, int(seq), int(head_dim), bool(causal))
     if key in _AUTOTUNE_CACHE:
         return _AUTOTUNE_CACHE[key]
@@ -609,18 +634,12 @@ def autotune_flash_blocks(seq: int, head_dim: int, *,
         cands.insert(0, default)
     if timer is None:
         if jax.default_backend() != "tpu" or len(cands) <= 1:
-            _AUTOTUNE_CACHE[key] = default
             return default
         timer = _flash_block_timer(batch, heads, seq, head_dim, causal,
                                    dtype, iters, include_backward)
-    best, best_t = default, float("inf")
-    for bq, bk in cands:
-        try:
-            t = timer(min(bq, seq), min(bk, seq))
-        except Exception:  # a candidate may not fit VMEM — skip it
-            continue
-        if t < best_t:
-            best, best_t = (min(bq, seq), min(bk, seq)), t
+    clamped = list(dict.fromkeys(
+        (min(bq, seq), min(bk, seq)) for bq, bk in cands))
+    best = time_candidates("flash", clamped, timer)
     _AUTOTUNE_CACHE[key] = best
     _persist_winner(key, best)   # timed winner: survive process restarts
     return best

@@ -2,14 +2,14 @@
 fast path.
 
 The serving hot loop attends a handful of new-token queries per
-sequence against a paged KV cache (``[num_blocks, block_size, kv_heads,
+sequence against a paged KV cache (``[num_blocks, kv_heads, block_size,
 head_dim]`` pool + per-sequence block tables). The pure-XLA reference
 (:func:`ray_tpu.ops.attention.paged_attention`) gathers the WHOLE
 table window every step — work is O(B · T · block_size) regardless of
 how many tokens a sequence actually holds. This kernel makes decode
 work proportional to **live tokens**:
 
-- grid ``(batch, kv_head_group, q_row_blocks, table_slots)`` with the
+- grid ``(batch, kv_head_groups, q_row_blocks, table_slots)`` with the
   table-slot axis innermost so the online-softmax accumulators
   (m, l, acc in f32 VMEM scratch) persist across a sequence's pages;
 - the block table and per-sequence ``lens`` ride **scalar prefetch**
@@ -23,13 +23,21 @@ work proportional to **live tokens**:
   work instead of all of it;
 - GQA is handled by **indexing kv heads in-kernel**: queries are
   regrouped host-side to ``[B, kv_heads, C·group, D]`` rows (a
-  transpose of the tiny q tensor, not of the cache) and each grid step
-  loads ONE kv head's page — the cache is never repeated or copied.
+  transpose of the tiny q tensor, not of the cache); a grid step loads
+  one page of ``heads_per_step`` kv heads and walks them with a static
+  loop — the cache is never repeated or copied.
+
+Every operand tiles the way Mosaic requires. A page is a
+``(block_size, head_dim)`` tile per kv head because ``kv_heads`` sits
+AHEAD of ``block_size`` in the cache, so a head is picked on an untiled
+leading dim. Row positions travel as a ``[B, rows, 1]`` column (no
+lane-to-sublane relayout in the kernel), and rows are padded to the
+sublane tile of the query dtype (8 rows for 32-bit, 16 for bf16).
 
 Rows are padded to ``block_r`` (chip-aware default via
 :func:`default_paged_block_r`; :func:`autotune_paged_block_r` times a
 candidate grid once and persists the winner through the SAME on-disk
-cache as ``autotune_flash_blocks``). Padded rows carry position −1 —
+table as ``autotune_flash_blocks``). Padded rows carry position −1 —
 fully masked, dropped on unpack.
 
 ``interpret=True`` runs the kernel on CPU (tier-1 parity tests); on
@@ -41,21 +49,29 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is importable on CPU too (for interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.flash_attention import (
-    load_cached_blocks, persist_cached_blocks)
+    load_cached_blocks, persist_cached_blocks, resolve_chip,
+    time_candidates)
 
 _NEG_INF = -1e30
+
+#: query rows (heads_per_step × block_r) one grid step may carry: bounds
+#: the f32 (m, l, acc) scratch to ~1 MiB at head_dim 256
+_MAX_ROWS_PER_STEP = 512
+
+
+def sublane_tile(dtype) -> int:
+    """Rows of one (sublane, 128-lane) tile for ``dtype``: 8 for 32-bit,
+    16 for bf16 — the granularity query rows and KV pages must meet for
+    the compiled kernel."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 def paged_work_pages(lens, block_size: int):
@@ -69,11 +85,12 @@ def paged_work_pages(lens, block_size: int):
 
 
 def _paged_kernel(bt_ref, lens_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
-                  m_s, l_s, acc_s, *, bs: int, sm_scale: float):
-    """One (batch b, kv head g, row block r, table slot t) step: fold
-    page t of sequence b into the row block's online softmax. Scalar
-    refs (bt, lens) land in SMEM ahead of the body — the same values
-    the index maps used to pick this step's page."""
+                  m_s, l_s, acc_s, *, bs: int, hb: int, sm_scale: float):
+    """One (batch b, kv head group, row block r, table slot t) step:
+    fold page t of sequence b into the row block's online softmax, one
+    kv head of the group at a time. Scalar refs (bt, lens) land in SMEM
+    ahead of the body — the same values the index maps used to pick
+    this step's page."""
     b = pl.program_id(0)
     t = pl.program_id(3)
     nt = pl.num_programs(3)
@@ -90,36 +107,48 @@ def _paged_kernel(bt_ref, lens_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
 
     @pl.when(t < pages)
     def _compute():
-        q = q_ref[0, 0]                        # (block_r, d)
-        k = k_ref[0, :, 0, :]                  # (bs, d) — one page, one
-        v = v_ref[0, :, 0, :]                  # kv head, indexed in-kernel
-        rows_pos = pos_ref[0]                  # (block_r,) int32
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        key_pos = t * bs + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(key_pos <= rows_pos[:, None], s, _NEG_INF)
+        rows_pos = pos_ref[0]                  # (block_r, 1) int32
+        for i in range(hb):                    # static: kv heads here
+            q = q_ref[0, i]                    # (block_r, d)
+            k = k_ref[0, i]                    # (bs, d) — one page of
+            v = v_ref[0, i]                    # one kv head
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            key_pos = t * bs + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(key_pos <= rows_pos, s, _NEG_INF)
 
-        m_prev = m_s[...]                      # (block_r, 128) lanes equal
-        l_prev = l_s[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next[:, 0:1])
-        l_s[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_s[...] = m_next
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_s[...] = acc_s[...] * alpha[:, 0:1] + pv
+            m_prev = m_s[i]                    # (block_r, 128) lanes equal
+            l_prev = l_s[i]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_next = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next[:, 0:1])
+            l_s[i] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            m_s[i] = m_next
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_s[i] = acc_s[i] * alpha[:, 0:1] + pv
 
     @pl.when(t == nt - 1)
     def _final():
-        l = l_s[:, 0:1]
-        # padded (position −1) rows never scored a key: emit zeros
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
+        for i in range(hb):
+            l = l_s[i][:, 0:1]
+            # padded (position −1) rows never scored a key: emit zeros
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, i] = (acc_s[i] / l).astype(o_ref.dtype)
+
+
+def _heads_per_step(kv_heads: int, block_r: int) -> int:
+    """Largest divisor of ``kv_heads`` whose rows fit one grid step:
+    decode (a few rows per head) takes every head of a page in one
+    step, a prefill chunk (hundreds of rows per head) one or two."""
+    hb = max(1, min(kv_heads, _MAX_ROWS_PER_STEP // block_r))
+    while kv_heads % hb:
+        hb -= 1
+    return hb
 
 
 def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -135,7 +164,7 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     Same contract as the XLA reference
     (:func:`ray_tpu.ops.attention.paged_attention`): ``q`` is
     ``[B, C, H, D]`` at absolute ``q_positions [B, C]``, caches are
-    ``[N, bs, KVH, D]``, ``block_tables [B, T]``. ``lens [B]`` is the
+    ``[N, KVH, bs, D]``, ``block_tables [B, T]``. ``lens [B]`` is the
     number of LIVE cached positions per sequence (after this step's
     writes); table slots past ``ceil(lens/bs)`` are skipped entirely.
     Rows whose position ≥ ``lens[b]`` (padded prefill tail) attend only
@@ -143,7 +172,7 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     with the reference path.
     """
     b, c, h, d = q.shape
-    n_blocks, bs, g, _ = k_cache.shape
+    n_blocks, g, bs, _ = k_cache.shape
     t = block_tables.shape[1]
     if h % g:
         raise ValueError(f"n_heads {h} not divisible by kv_heads {g}")
@@ -151,12 +180,14 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     rows = c * rep
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    tile = sublane_tile(q.dtype)
     if not block_r:
         block_r = default_paged_block_r(
             rows, d, chip="cpu" if interpret else None)
-    block_r = max(8, min(block_r, _round8(rows)))
-    rows_pad = -(-rows // block_r) * block_r
+    block_r = _round_up(min(block_r, _round_up(rows, tile)), tile)
+    rows_pad = _round_up(rows, block_r)
     nr = rows_pad // block_r
+    hb = _heads_per_step(g, block_r)
 
     # Group-major query rows: row r of kv head g is (c = r // rep,
     # head = g*rep + r % rep). Only q (tiny) is reshaped — never the
@@ -168,6 +199,7 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
         pos_rows = jnp.pad(pos_rows, ((0, 0), (0, rows_pad - rows)),
                            constant_values=-1)
+    pos_rows = pos_rows[:, :, None]            # [B, rows_pad, 1] column
 
     def _pages(ln):
         return jnp.maximum(pl.cdiv(ln, bs), 1)
@@ -176,42 +208,40 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         return (b_, g_, r_, 0)
 
     def pos_map(b_, g_, r_, t_, bt, ln):
-        return (b_, r_)
+        return (b_, r_, 0)
 
     def kv_map(b_, g_, r_, t_, bt, ln):
         # slots past the live pages revisit the last live page: the
         # unchanged block index issues no fresh DMA
         tt = jnp.minimum(t_, _pages(ln[b_]) - 1)
-        return (bt[b_, tt], 0, g_, 0)
+        return (bt[b_, tt], g_, 0, 0)
 
-    grid = (b, g, nr, t)
-    compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(b, g // hb, nr, t),
         in_specs=[
-            pl.BlockSpec((1, 1, block_r, d), q_map),
-            pl.BlockSpec((1, block_r), pos_map),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
+            pl.BlockSpec((1, hb, block_r, d), q_map),
+            pl.BlockSpec((1, block_r, 1), pos_map),
+            pl.BlockSpec((1, hb, bs, d), kv_map),
+            pl.BlockSpec((1, hb, bs, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_r, d), q_map),
+        out_specs=pl.BlockSpec((1, hb, block_r, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((block_r, 128), jnp.float32),   # running max m
-            pltpu.VMEM((block_r, 128), jnp.float32),   # running denom l
-            pltpu.VMEM((block_r, d), jnp.float32),     # output accumulator
+            pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running max m
+            pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running denom l
+            pltpu.VMEM((hb, block_r, d), jnp.float32),    # out accumulator
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, sm_scale=float(sm_scale)),
+        functools.partial(_paged_kernel, bs=bs, hb=hb,
+                          sm_scale=float(sm_scale)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, d), q.dtype),
-        compiler_params=compiler_params,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables.astype(jnp.int32), lens.astype(jnp.int32),
       qg, pos_rows, k_cache, v_cache)
     out = out[:, :, :rows, :].reshape(b, g, c, rep, d) \
@@ -220,8 +250,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 
 # --------------------------------------------------- block-size selection
-def _round8(n: int) -> int:
-    return max(8, -(-n // 8) * 8)
+def _round_up(n: int, m: int) -> int:
+    return max(m, -(-n // m) * m)
 
 
 def default_paged_block_r(rows: int, head_dim: int,
@@ -234,14 +264,9 @@ def default_paged_block_r(rows: int, head_dim: int,
     row-block matmuls fill MXU tiles; large head dims halve the block
     to keep the f32 (rows, bs) score tile + accumulators in VMEM.
     """
-    if chip is None:
-        try:
-            from ray_tpu.parallel.mesh import chip_spec
-            chip = chip_spec().name
-        except Exception:  # jax backend not initializable — be safe
-            chip = "cpu"
+    chip = resolve_chip(chip)
     cap = 128 if chip == "cpu" else (128 if head_dim >= 256 else 256)
-    return min(_round8(rows), cap)
+    return min(_round_up(rows, 8), cap)
 
 
 # Winner cache: (chip, block_size, table_len, rows, head_dim) -> block_r.
@@ -267,19 +292,17 @@ def autotune_paged_block_r(block_size: int, table_len: int, rows: int,
     candidate grid once and cache the winner per
     ``(chip, block_size, table_len, rows, head_dim)``; timed winners
     persist through the SAME on-disk JSON as the flash autotuner
-    (``$RAY_TPU_FLASH_CACHE_DIR/flash_autotune.json``, keys prefixed
-    ``paged|``), so serving replicas never re-time on process start.
+    (``flash_autotune.json`` under the compile-cache root, keys
+    prefixed ``paged|``), so serving replicas never re-time on process
+    start.
 
     Off-TPU (without an injected ``timer``) returns the chip-aware
     default without running anything. ``timer`` is injectable for
-    tests: a callable ``(block_r) -> seconds``.
+    tests: a callable ``(block_r) -> seconds``. A candidate that does
+    not compile (VMEM) is skipped with a warning; when none compiles
+    this raises — an untimed default is never returned as a winner.
     """
-    if chip is None:
-        try:
-            from ray_tpu.parallel.mesh import chip_spec
-            chip = chip_spec().name
-        except Exception:
-            chip = "cpu"
+    chip = resolve_chip(chip)
     key = (chip, int(block_size), int(table_len), int(rows),
            int(head_dim))
     if key in _PAGED_AUTOTUNE_CACHE:
@@ -290,24 +313,16 @@ def autotune_paged_block_r(block_size: int, table_len: int, rows: int,
         return _PAGED_AUTOTUNE_CACHE[key]
 
     default = default_paged_block_r(rows, head_dim, chip=chip)
-    cands = sorted({min(c, _round8(rows))
+    cands = sorted({min(c, _round_up(rows, 8))
                     for c in (candidates or _PAGED_CANDIDATES)})
     if default not in cands:
         cands.insert(0, default)
     if timer is None:
         if jax.default_backend() != "tpu" or len(cands) <= 1:
-            _PAGED_AUTOTUNE_CACHE[key] = default
             return default
         timer = _paged_block_timer(batch, block_size, table_len, rows,
                                    head_dim, dtype, iters)
-    best, best_t = default, float("inf")
-    for br in cands:
-        try:
-            tt = timer(br)
-        except Exception:  # a candidate may not fit VMEM — skip it
-            continue
-        if tt < best_t:
-            best, best_t = br, tt
+    best = time_candidates("paged", cands, timer)
     _PAGED_AUTOTUNE_CACHE[key] = best
     persist_cached_blocks(_paged_disk_key(key), (best, best))
     return best
@@ -321,9 +336,9 @@ def _paged_block_timer(batch, block_size, table_len, rows, head_dim,
 
     n_blocks = 1 + batch * table_len
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    kc = jax.random.normal(ks[0], (n_blocks, block_size, 1, head_dim),
+    kc = jax.random.normal(ks[0], (n_blocks, 1, block_size, head_dim),
                            dtype)
-    vc = jax.random.normal(ks[1], (n_blocks, block_size, 1, head_dim),
+    vc = jax.random.normal(ks[1], (n_blocks, 1, block_size, head_dim),
                            dtype)
     q = jax.random.normal(ks[2], (batch, rows, 1, head_dim), dtype)
     bt = jnp.arange(1, n_blocks, dtype=jnp.int32).reshape(

@@ -81,8 +81,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
 
     pspec_params = jax.tree.map(lambda _: P(axis), stage_params)
     rep = P()
-    from ray_tpu.util.jax_compat import shard_map
-    out = shard_map(
+    out = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(pspec_params, rep),
         out_specs=rep,

@@ -167,8 +167,6 @@ def _build_stub(mesh, op: str, **kw):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.util.jax_compat import shard_map
-
     axes = mesh.axis_names
     reduce_op = kw.get("reduce_op", "sum")
 
@@ -180,14 +178,14 @@ def _build_stub(mesh, op: str, **kw):
         # (world, *shape) sharded on dim 0 -> reduced (*shape), replicated
         def f(x):
             return _red(x[0], axes)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P(axes), out_specs=P(),
             check_vma=False))
     if op == "allgather":
         # (world, *shape) sharded -> (world, *shape) replicated everywhere
         def f(x):
             return jax.lax.all_gather(x[0], axes, axis=0, tiled=False)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P(axes), out_specs=P(),
             check_vma=False))
     if op == "reducescatter":
@@ -199,7 +197,7 @@ def _build_stub(mesh, op: str, **kw):
         def f(x):
             summed = _red(x[0], axes)
             return jnp.stack(jnp.split(summed, world, axis=0))
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P(axes), out_specs=P(),
             check_vma=False))
     if op in ("quantized_allreduce", "quantized_reducescatter"):
@@ -231,7 +229,7 @@ def _build_stub(mesh, op: str, **kw):
             if op == "quantized_reducescatter":
                 return jnp.stack(jnp.split(out, world, axis=0))
             return out
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P(axes), P()), out_specs=P(),
             check_vma=False))
     raise ValueError(f"unknown collective {op}")

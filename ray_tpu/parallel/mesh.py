@@ -49,18 +49,36 @@ CHIP_SPECS: Dict[str, ChipSpec] = {
 
 
 def chip_spec(kind: Optional[str] = None) -> ChipSpec:
-    """Resolve the chip spec for the current platform (or a named one)."""
-    if kind is None:
-        import jax
-        d = jax.devices()[0]
-        if d.platform != "tpu":
-            return CHIP_SPECS["cpu"]
-        k = getattr(d, "device_kind", "").lower()
-        for name in ("v6e", "v5p", "v5e", "v4"):
-            if name in k.replace(" ", "").replace("lite", "e"):
-                return CHIP_SPECS[name]
-        return CHIP_SPECS["v5e"]
-    return CHIP_SPECS[kind]
+    """Resolve the chip spec for the current platform (or a named one).
+    A TPU whose ``device_kind`` is not in the table is an error: peaks
+    and block sizes are never borrowed from another chip."""
+    if kind is not None:
+        return CHIP_SPECS[kind]
+    import jax
+    d = jax.devices()[0]
+    if d.platform != "tpu":
+        return CHIP_SPECS["cpu"]
+    return chip_spec_for_kind(d.device_kind)
+
+
+#: ``jax.Device.device_kind`` strings -> CHIP_SPECS row.
+_DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e", "TPU v5e": "v5e",
+    "TPU v5": "v5p", "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e", "TPU v6e": "v6e",
+}
+
+
+def chip_spec_for_kind(device_kind: str) -> ChipSpec:
+    """Table row for a TPU ``device_kind`` string as JAX reports it."""
+    name = _DEVICE_KINDS.get(device_kind)
+    if name is None:
+        raise ValueError(
+            f"unknown TPU device_kind {device_kind!r}: add it and its "
+            f"published peaks to parallel.mesh (have "
+            f"{sorted(_DEVICE_KINDS)})")
+    return CHIP_SPECS[name]
 
 
 @dataclasses.dataclass

@@ -607,8 +607,6 @@ class PipelineStage:
 
         from ray_tpu.models.transformer import stage_forward, stage_loss
         from ray_tpu.parallel.collective import psum_tree
-        from ray_tpu.util.jax_compat import shard_map
-
         c, K = self.config, self.n_chunks
         mesh, world = self.mesh, self.n_model
         axes = ("dp", "fsdp")
@@ -616,7 +614,7 @@ class PipelineStage:
         rep = P()
 
         def smap(f, in_specs, out_specs):
-            return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
+            return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                                      out_specs=out_specs,
                                      check_vma=False))
 

@@ -16,9 +16,13 @@ import time
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.exceptions import GetTimeoutError
 from ray_tpu.serve._private.replica import Replica
 
 CONTROLLER_NAME = "SERVE_CONTROLLER_ACTOR"
+
+#: how long a replica may take to construct before it counts as dead
+REPLICA_STARTUP_TIMEOUT_S = 900.0
 
 
 def autoscale_decision(cfg, target_num: int, avg_ongoing: float,
@@ -57,6 +61,9 @@ class _DeploymentInfo:
         self.init_kwargs = init_kwargs
         self.target_num = deployment.num_replicas
         self.replicas: List[Any] = []
+        #: replica -> (birth time, check_health ref still unanswered) for
+        #: replicas that have not passed a health check yet
+        self.starting: Dict[Any, tuple] = {}
         self.version = 0
         self.replica_counter = 0
         # delay-gate from DEPLOY time: an epoch-zero stamp would let
@@ -277,6 +284,7 @@ class ServeController:
     def _scale_to(self, name: str, info: _DeploymentInfo, n: int) -> None:
         while len(info.replicas) > n:
             replica = info.replicas.pop()
+            info.starting.pop(replica, None)
             if info.replicas and getattr(
                     info.deployment, "migrate_prefixes", False):
                 # warm-prefix migration: drain the victim's warm
@@ -297,7 +305,10 @@ class ServeController:
                 pass
             info.version += 1
         while len(info.replicas) < n:
-            info.replicas.append(self._make_replica(name, info))
+            replica = self._make_replica(name, info)
+            info.starting[replica] = (time.time(),
+                                      replica.check_health.remote())
+            info.replicas.append(replica)
             info.version += 1
 
     def _reconcile_one(self, name: str, info: _DeploymentInfo) -> None:
@@ -320,15 +331,31 @@ class ServeController:
                 pass  # the loop must survive transient errors
 
     def _health_check(self, name: str, info: _DeploymentInfo) -> None:
+        """A replica that has answered once must keep answering within
+        30 s. One that is still constructing (weights, compilation —
+        minutes on a TPU) is not unhealthy for being slow: its first
+        check_health stays outstanding, polled briefly each pass, until
+        it answers, the constructor fails (the call raises at once), or
+        REPLICA_STARTUP_TIMEOUT_S passes."""
         dead = []
-        for replica in info.replicas:
+        for replica in list(info.replicas):
+            born, ref = info.starting.get(replica, (None, None))
             try:
-                ray_tpu.get(replica.check_health.remote(), timeout=30)
+                if born is None:
+                    ray_tpu.get(replica.check_health.remote(), timeout=30)
+                else:
+                    ray_tpu.get(ref, timeout=0.2)
+                    info.starting.pop(replica, None)
+            except GetTimeoutError:
+                if born is None or \
+                        time.time() - born > REPLICA_STARTUP_TIMEOUT_S:
+                    dead.append(replica)
             except Exception:
                 dead.append(replica)
         if dead:
             with self._lock:
                 for replica in dead:
+                    info.starting.pop(replica, None)
                     if replica in info.replicas:
                         info.replicas.remove(replica)
                         info.version += 1

@@ -58,8 +58,8 @@ class DisaggHandoffError(RayTpuError):
 # ------------------------------------------------------------ KV codec
 def pack_kv_blocks(k: np.ndarray, v: np.ndarray,
                    wire: str = "bf16") -> Dict[str, Any]:
-    """Pack gathered KV block slabs ``[n_layers, n_blocks, block_size,
-    kv_heads, head_dim]`` for the wire. ``"bf16"`` ships the arrays in
+    """Pack gathered KV block slabs ``[n_layers, n_blocks, kv_heads,
+    block_size, head_dim]`` for the wire. ``"bf16"`` ships the arrays in
     their native dtype (bit-exact roundtrip); ``"int8"`` quantizes each
     slab blockwise (``quantize_int8_np``). ``wire_bytes`` is the actual
     transport footprint as the zero-copy serializer would ship it."""
@@ -98,7 +98,7 @@ def _np_dtype(name: str) -> np.dtype:
 def unpack_kv_blocks(kv: Dict[str, Any], dtype=None
                      ) -> Tuple[np.ndarray, np.ndarray]:
     """Invert :func:`pack_kv_blocks`: ``(k, v)`` numpy slabs
-    ``[n_layers, n_blocks, block_size, kv_heads, head_dim]``, cast to
+    ``[n_layers, n_blocks, kv_heads, block_size, head_dim]``, cast to
     ``dtype`` (default: the dtype they were packed from)."""
     shape = tuple(kv["shape"])
     tgt = np.dtype(dtype) if dtype is not None else _np_dtype(kv["dtype"])
@@ -444,7 +444,8 @@ def deploy_disaggregated(model: Dict[str, Any], engine: Dict[str, Any],
                          kv_wire: Optional[str] = None,
                          migrate_prefixes: bool = False,
                          max_ongoing_requests: int = 100,
-                         route_prefix: Optional[str] = None
+                         route_prefix: Optional[str] = None,
+                         ray_actor_options: Optional[Dict[str, Any]] = None
                          ) -> DisaggRouter:
     """Deploy ``{name}-prefill`` + ``{name}-decode`` LLMServer fleets
     sharing one model/engine config (same seed => identical params =>
@@ -453,7 +454,8 @@ def deploy_disaggregated(model: Dict[str, Any], engine: Dict[str, Any],
     more ``decode_slots`` than a colocated replica since it never
     interleaves prefill chunks; ``kv_wire`` picks the hand-off format;
     ``migrate_prefixes`` arms the controller's drain-time warm-prefix
-    migration on the decode fleet."""
+    migration on the decode fleet; ``ray_actor_options`` go to every
+    replica of both fleets (``{"num_tpus": 1}`` pins each to a chip)."""
     from ray_tpu import serve
     from ray_tpu.serve import api as serve_api
 
@@ -472,6 +474,7 @@ def deploy_disaggregated(model: Dict[str, Any], engine: Dict[str, Any],
         dep = serve.deployment(
             name=f"{name}-{suffix}", num_replicas=n,
             max_ongoing_requests=max_ongoing_requests,
+            ray_actor_options=ray_actor_options,
             migrate_prefixes=migrate)(serve.LLMServer)
         serve.run(dep.bind(model=model, engine=ecfg),
                   name=f"{name}-{suffix}", route_prefix=None)

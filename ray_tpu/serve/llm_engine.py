@@ -244,24 +244,22 @@ class LLMEngine:
         # (winner persists in the flash autotune cache under paged|
         # keys; off-TPU without an injected timer this is the chip
         # default and the config is left alone).
+        from ray_tpu.util import compile_cache
+        compile_cache.enable()
+        compile_cache.stats()     # count loads vs compiles from here on
         window = ec.blocks_per_seq * ec.kv_block_size
-        if (getattr(model_config, "paged_block_r_prefill", 0) == 0
+        if (model_config.paged_block_r_prefill == 0
                 and window >= 4096 and ec.prefill_chunk > 1):
-            try:
-                from ray_tpu.ops.paged_flash import (
-                    autotune_paged_block_r)
-                rows = ec.prefill_chunk * (model_config.n_heads
-                                           // model_config.kv_heads)
-                br = autotune_paged_block_r(
-                    ec.kv_block_size, ec.blocks_per_seq, rows,
-                    model_config.head_dim,
-                    candidates=(32, 64, 128, 256, 512))
-                if br:
-                    model_config = dataclasses.replace(
-                        model_config, paged_block_r_prefill=int(br))
-                    self.model_config = model_config
-            except Exception:
-                pass
+            from ray_tpu.ops.paged_flash import autotune_paged_block_r
+            rows = ec.prefill_chunk * (model_config.n_heads
+                                       // model_config.kv_heads)
+            br = autotune_paged_block_r(
+                ec.kv_block_size, ec.blocks_per_seq, rows,
+                model_config.head_dim,
+                candidates=(32, 64, 128, 256, 512))
+            model_config = dataclasses.replace(
+                model_config, paged_block_r_prefill=int(br))
+            self.model_config = model_config
 
         self._params = params if params is not None \
             else init_params(model_config, jax.random.PRNGKey(seed))
@@ -840,11 +838,42 @@ class LLMEngine:
 
         return self._run_on_step_thread(_do)
 
+    def _programs(self) -> Dict[str, Any]:
+        """The jitted programs this engine can run, by name. With
+        speculation on, decode steps go through ``verify`` and the
+        plain ``decode`` program is never called."""
+        progs = {"prefill": self._jit_prefill, "copy": self._jit_copy,
+                 "gather": self._jit_gather,
+                 "scatter": self._jit_scatter}
+        if self._jit_verify is not None:
+            progs["verify"] = self._jit_verify
+        else:
+            progs["decode"] = self._jit_decode
+        return progs
+
+    def _warm_block_io(self) -> None:
+        """Compile the CoW copy and the hand-off gather/scatter (step
+        thread). Every index is the reserved trash block and the slab
+        written back is zeros, so no live block changes."""
+        np, jnp = self._np, self._jnp
+        zero = np.int32(0)
+        self._cache = self._jit_copy(self._cache, zero, zero)
+        ids = jnp.zeros((self.config.blocks_per_seq,), jnp.int32)
+        k, v = self._jit_gather(self._cache, ids)
+        self._cache = self._jit_scatter(
+            self._cache, ids, jnp.zeros_like(k), jnp.zeros_like(v))
+        self._jax.block_until_ready(self._cache)
+
     def warmup(self, timeout_s: float = 600.0) -> None:
-        """Compile every jitted program (one tiny end-to-end generate)
-        and reset the session counters it skewed: the TTFT EWMA would
-        otherwise carry the compile wall into the gauge router's
-        scoring and starve a freshly-scaled-up replica of traffic."""
+        """Compile every jitted program the engine can run — prefill
+        and decode (or verify) through one tiny end-to-end generate,
+        then the CoW copy and the KV gather/scatter — so nothing
+        compiles under traffic, and reset the session counters the
+        generate skewed: the TTFT EWMA would otherwise carry the compile
+        wall into the gauge router's scoring and starve a
+        freshly-scaled-up replica of traffic. Raises if any program
+        fails to compile or run."""
+        self._run_on_step_thread(self._warm_block_io, timeout_s)
         req = self.submit([2, 3], 2, _warmup=True)
         deadline = time.monotonic() + timeout_s
         try:
@@ -879,6 +908,7 @@ class LLMEngine:
         """Scheduler counters (the autoscaling signal surface): queue
         depth, batch occupancy histogram, tokens/s, leak-check views of
         the slot/block free lists."""
+        from ray_tpu.ops.attention import dispatch_log
         with self._lock:
             elapsed = max(time.monotonic() - self._t_start, 1e-9)
             ps = self._pool.stats()
@@ -918,8 +948,17 @@ class LLMEngine:
                           / self._decode_pages_window, 4)
                     if self._decode_pages_window else None),
                 "kv_block_size": self.config.kv_block_size,
-                "paged_impl": getattr(self.model_config, "paged_impl",
-                                      "auto"),
+                "paged_impl": self.model_config.paged_impl,
+                # what each traced attention call resolved to and why
+                # (ops.attention.dispatch_log) — a reference entry that
+                # was not requested is a kernel the shapes ruled out
+                "attention_dispatch": dispatch_log(),
+                # compiled variants per jitted program: 1 each after
+                # warmup, and still 1 under any traffic (shapes are
+                # fixed) — more means a compile happened mid-serving
+                "compiled_programs": {
+                    name: fn._cache_size()
+                    for name, fn in self._programs().items()},
                 # trie-root fingerprints: the router's prefix-aware
                 # COLD-session placement signal (first-turn requests
                 # land where their system prompt's KV already lives)
@@ -1916,14 +1955,13 @@ class LLMServer:
                                 seed=seed,
                                 replica_tag=f"pid:{os.getpid()}")
         if warmup:
-            # compile prefill + decode BEFORE the replica enters
+            # compile every program BEFORE the replica enters
             # rotation: actor calls queue behind __init__, so a
             # replica the autoscaler adds mid-load serves its first
-            # request hot instead of charging users the jit wall
-            try:
-                self.engine.warmup()
-            except Exception:
-                pass
+            # request hot instead of charging users the jit wall. A
+            # program that does not compile fails the constructor, and
+            # with it the replica — it must never enter rotation.
+            self.engine.warmup()
 
     @staticmethod
     def _trace_ctx() -> Optional[Dict[str, Any]]:
@@ -2054,6 +2092,61 @@ class LLMServer:
 
     def pool_audit(self) -> List[str]:
         return self.engine.pool_audit()
+
+    def device_info(self) -> Dict[str, Any]:
+        """Which process this replica is and which devices it holds, as
+        JAX reports them here — so a fleet can show one chip per
+        replica and nothing on a chip it does not own."""
+        import jax
+
+        from ray_tpu.util import compile_cache
+        devs = jax.devices()
+        mem = devs[0].memory_stats() or {}
+        return {
+            "pid": os.getpid(),
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            # a pinned process sees its chip as device 0 at (0, 0, 0):
+            # which chip it is shows only in what it was given
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            # programs this process loaded from / compiled into the
+            # persistent cache since its engine was built
+            "compile_cache": {"dir": compile_cache.cache_root(),
+                              **compile_cache.stats()},
+            "bytes_in_use": mem.get("bytes_in_use"),
+            "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
+            "bytes_limit": mem.get("bytes_limit"),
+        }
+
+    async def kernel_truth(self) -> List[Dict[str, Any]]:
+        """Check, inside this replica and on its own device, that the
+        paged kernel (at the decode and the prefill-chunk shape this
+        engine runs) matches the XLA reference and that logits through
+        the cache match ``apply`` on the engine's params
+        (:mod:`ray_tpu.models.kernel_truth`). Compiles its own small
+        programs: a start-up or smoke check, not for use under load."""
+        loop = asyncio.get_running_loop()
+        return await loop.run_in_executor(self.engine._poll_pool,
+                                          self._kernel_truth)
+
+    def _kernel_truth(self) -> List[Dict[str, Any]]:
+        from ray_tpu.models import kernel_truth as KT
+        mc, ec = self.model_config, self.engine_config
+        shape = dict(heads=mc.n_heads, kv_heads=mc.kv_heads,
+                     head_dim=mc.head_dim, block_size=ec.kv_block_size,
+                     table_len=ec.blocks_per_seq, dtype=mc.dtype,
+                     impl=mc.paged_impl)
+        return [
+            KT.paged_truth(batch=ec.decode_slots, chunk=1, **shape),
+            KT.paged_truth(batch=1, chunk=ec.prefill_chunk, **shape),
+            KT.cached_logits_truth(
+                mc, self.engine._params, block_size=ec.kv_block_size,
+                chunk=ec.prefill_chunk, table_len=ec.blocks_per_seq,
+                prompt_len=min(ec.max_seq_len - 5,
+                               ec.prefill_chunk * 3 // 2 + 3),
+                n_decode=4),
+        ]
 
     def kv_block_bytes(self) -> int:
         ec, mc = self.engine_config, self.model_config

@@ -65,18 +65,14 @@ def _setup_jax_distributed(rendezvous_key: bytes, port: int,
         flags.append(
             f"--xla_force_host_platform_device_count={local_device_count}")
         os.environ["XLA_FLAGS"] = " ".join(flags)
-    # platform pinning already happened at worker startup
-    # (ray_tpu.core.worker.main honors RAY_TPU_JAX_PLATFORM)
     import jax
-    try:
-        from jax._src import xla_bridge
-        if getattr(xla_bridge, "_backends", None):
-            raise RuntimeError(
-                "jax backend already initialized in this worker process; "
-                "distributed setup (XLA_FLAGS / coordination service) "
-                "cannot apply. Use fresh training workers.")
-    except ImportError:
-        pass
+
+    from ray_tpu.core.accelerators import jax_backend_initialized
+    if jax_backend_initialized():
+        raise RuntimeError(
+            "jax backend already initialized in this worker process; "
+            "distributed setup (XLA_FLAGS / coordination service) "
+            "cannot apply. Use fresh training workers.")
     w = global_worker()
     if process_id == 0:
         import socket

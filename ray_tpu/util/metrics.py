@@ -418,7 +418,10 @@ class MetricsReporter:
         self._pending_drop = pending_drop
         self._seq = 0
         self._lock = threading.Lock()
-        self._last = 0.0
+        # -inf, not 0.0: time.monotonic() counts from boot, so on a host
+        # up for less than the interval a 0.0 start would gate the
+        # FIRST report
+        self._last = float("-inf")
         self.dropped = 0
         self._dropped_metric = None
         #: False when another (higher-precedence or newer) reporter in

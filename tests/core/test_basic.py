@@ -161,6 +161,6 @@ def test_main_module_class_round_trip():
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=240,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"})
+        env=dict(os.environ))
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     assert "MAIN-OK" in proc.stdout

@@ -244,3 +244,31 @@ def test_crash_recovery_rebuilds_allocator(session):
     seg.view[off:off + 3 * 1024] = b"z" * (3 * 1024)
     assert seg.seal(oid) == 3 * 1024
     seg.close()
+
+
+def test_failed_build_is_an_error_not_a_quiet_switch(monkeypatch,
+                                                     tmp_path):
+    """With a compiler present and no library built, a build that fails
+    raises (and a later call tries again): the store never falls to
+    the Python implementation unannounced. Without any g++ — or
+    switched off — it is the Python store, and says so."""
+    import subprocess
+    import types
+
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_tried", False)
+    monkeypatch.setattr(_native, "_LIB_PATH",
+                        str(tmp_path / "libnativestore-test.so"))
+    monkeypatch.setattr(
+        subprocess, "run",
+        lambda *a, **k: types.SimpleNamespace(
+            returncode=1, stderr=b"store.cpp:1: error: boom"))
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="error: boom"):
+            _native.load()
+    monkeypatch.setattr(_native.shutil, "which", lambda name: None)
+    assert _native.load() is None
+    assert _native.store_kind().startswith("python (no g++")
+    monkeypatch.setattr(_native, "_tried", False)
+    monkeypatch.setenv("RAY_TPU_NATIVE_STORE", "0")
+    assert _native.store_kind() == "python (RAY_TPU_NATIVE_STORE=0)"

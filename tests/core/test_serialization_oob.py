@@ -91,3 +91,18 @@ def test_plain_pickle_semantics_untouched():
     a = jnp.arange(16, dtype=jnp.float32)
     out = pickle.loads(pickle.dumps(a))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(out))
+
+
+def test_half_imported_jax_is_not_an_error(monkeypatch):
+    """Workers import jax lazily inside a task while other threads
+    serialize results: a `jax` found in sys.modules mid-import has no
+    `Array` yet, and must read as "no jax here", not AttributeError."""
+    import sys
+    import types
+
+    from ray_tpu.core import serialization as S
+    monkeypatch.setitem(sys.modules, "jax", types.ModuleType("jax"))
+    monkeypatch.setattr(S, "_jax_dispatch", None)
+    assert S._pre_serialize({"w": 1.5}) == {"w": 1.5}
+    assert S.to_host(7) == 7
+    assert S._device_array_dispatch() is None

@@ -127,7 +127,7 @@ print("PUT-SPILL-OK")
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=600,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"})
+        env=dict(os.environ))
     assert proc.returncode == 0, (proc.stdout[-2000:],
                                   proc.stderr[-2000:])
     assert "PUT-SPILL-OK" in proc.stdout
@@ -173,7 +173,7 @@ print("SPILL-SHUFFLE-OK")
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=600,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"})
+        env=dict(os.environ))
     assert proc.returncode == 0, (proc.stdout[-2000:],
                                   proc.stderr[-2000:])
     assert "SPILL-SHUFFLE-OK" in proc.stdout

@@ -230,6 +230,22 @@ def test_autotune_survives_failing_candidate():
     _AUTOTUNE_CACHE.clear()
 
 
+def test_autotune_raises_when_no_candidate_compiles(tmp_path,
+                                                    monkeypatch):
+    """Every candidate rejected: the tuner raises instead of handing
+    back (or persisting) a default nobody timed."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    _AUTOTUNE_CACHE.clear()
+
+    def timer(bq, bk):
+        raise RuntimeError("vmem oom")
+
+    with pytest.raises(RuntimeError, match="none of"):
+        autotune_flash_blocks(256, 128, timer=timer, chip="v5e")
+    assert not _AUTOTUNE_CACHE
+    assert not (tmp_path / "flash_autotune.json").exists()
+
+
 def test_autotune_winner_persists_across_processes(tmp_path,
                                                    monkeypatch):
     """A TIMED winner is written to disk keyed by (chip, jax version,
@@ -245,7 +261,7 @@ def test_autotune_winner_persists_across_processes(tmp_path,
     # re-exports over it
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
-    monkeypatch.setenv("RAY_TPU_FLASH_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     _AUTOTUNE_CACHE.clear()
     monkeypatch.setattr(fa, "_DISK_CACHE_LOADED", False)
     calls = []
@@ -290,7 +306,7 @@ def test_autotune_default_path_not_persisted(tmp_path, monkeypatch):
     import importlib
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
-    monkeypatch.setenv("RAY_TPU_FLASH_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     _AUTOTUNE_CACHE.clear()
     monkeypatch.setattr(fa, "_DISK_CACHE_LOADED", False)
     autotune_flash_blocks(1024, 128, chip="cpu")

@@ -115,14 +115,14 @@ def test_paged_attention_matches_reference():
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)).astype(np.float32))
     # scatter the sequences into a shuffled block pool
     n_blocks = 1 + B * T
-    kc = np.zeros((n_blocks, bs, H, D), np.float32)
-    vc = np.zeros((n_blocks, bs, H, D), np.float32)
+    kc = np.zeros((n_blocks, H, bs, D), np.float32)    # [N, KVH, bs, D]
+    vc = np.zeros((n_blocks, H, bs, D), np.float32)
     order = rng.permutation(np.arange(1, n_blocks))
     bt = order.reshape(B, T)
     for b in range(B):
         for t in range(T):
-            kc[bt[b, t]] = k_seq[b, t * bs:(t + 1) * bs]
-            vc[bt[b, t]] = v_seq[b, t * bs:(t + 1) * bs]
+            kc[bt[b, t]] = k_seq[b, t * bs:(t + 1) * bs].swapaxes(0, 1)
+            vc[bt[b, t]] = v_seq[b, t * bs:(t + 1) * bs].swapaxes(0, 1)
     # query sits at position 9 -> attends positions 0..9 of 12 cached
     qpos = jnp.full((B, 1), 9, jnp.int32)
     out = paged_attention(q, jnp.asarray(kc), jnp.asarray(vc),
@@ -139,11 +139,11 @@ def test_paged_attention_matches_reference():
 ])
 def test_prefill_decode_parity_kernel_impl(style, kv_heads):
     """The full vertical with the Pallas kernel forced (interpret mode
-    on CPU): chunked prefill + decode through ``paged_impl="kernel"``
+    on CPU): chunked prefill + decode through ``paged_impl="interpret"``
     must reproduce apply() exactly like the reference path — uneven
     last block and GQA included."""
     cfg = _cfg(block_style=style, n_kv_heads=kv_heads,
-               paged_impl="kernel")
+               paged_impl="interpret")
     params = init_params(cfg, jax.random.PRNGKey(0))
     _run_paged.params = params
     B, prompt, n_dec = 2, 7, 5
@@ -163,17 +163,19 @@ def test_gqa_reference_read_parity_with_repeat_formulation():
     import math
     rng = np.random.default_rng(2)
     B, H, KVH, D, bs, T = 2, 8, 2, 8, 4, 3
-    kc = rng.normal(size=(1 + B * T, bs, KVH, D)).astype(np.float32)
-    vc = rng.normal(size=(1 + B * T, bs, KVH, D)).astype(np.float32)
+    kc = rng.normal(size=(1 + B * T, KVH, bs, D)).astype(np.float32)
+    vc = rng.normal(size=(1 + B * T, KVH, bs, D)).astype(np.float32)
     q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
     bt = np.arange(1, 1 + B * T, dtype=np.int32).reshape(B, T)
     pos = np.array([[7], [10]], np.int32)
     new = paged_attention(q, kc, vc, bt, jnp.asarray(pos),
                           impl="reference")
-    k = jnp.repeat(jnp.take(jnp.asarray(kc), jnp.asarray(bt), axis=0)
-                   .reshape(B, T * bs, KVH, D), H // KVH, axis=2)
-    v = jnp.repeat(jnp.take(jnp.asarray(vc), jnp.asarray(bt), axis=0)
-                   .reshape(B, T * bs, KVH, D), H // KVH, axis=2)
+
+    def repeated(cache):     # [N, KVH, bs, D] pool -> [B, K, H, D]
+        g = jnp.take(jnp.asarray(cache), jnp.asarray(bt), axis=0)
+        return jnp.repeat(g.transpose(0, 1, 3, 2, 4)
+                          .reshape(B, T * bs, KVH, D), H // KVH, axis=2)
+    k, v = repeated(kc), repeated(vc)
     mask = np.arange(T * bs)[None, None, :] <= pos[:, :, None]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (1.0 / math.sqrt(D))
     s = jnp.where(jnp.asarray(mask)[:, None], s, -1e30)
@@ -205,10 +207,10 @@ def test_engine_greedy_decode_bitwise_stable_kernel_vs_reference():
     ref = _engine_tokens(dict(block_style="llama", n_kv_heads=2),
                          ekw, prompts)
     ker = _engine_tokens(dict(block_style="llama", n_kv_heads=2,
-                              paged_impl="kernel"), ekw, prompts)
+                              paged_impl="interpret"), ekw, prompts)
     assert ref == ker
     spec = _engine_tokens(dict(block_style="llama", n_kv_heads=2,
-                               paged_impl="kernel"),
+                               paged_impl="interpret"),
                           dict(ekw, spec_tokens=3), prompts)
     assert ref == spec
 
@@ -216,7 +218,7 @@ def test_engine_greedy_decode_bitwise_stable_kernel_vs_reference():
 def test_gqa_cache_stores_kv_heads_only():
     cfg = _cfg(block_style="llama", n_kv_heads=2)
     cache = init_kv_cache(cfg, num_blocks=5, block_size=4)
-    assert cache["k"].shape == (cfg.n_layers, 5, 4, 2, cfg.head_dim)
+    assert cache["k"].shape == (cfg.n_layers, 5, 2, 4, cfg.head_dim)
     assert cache["v"].shape == cache["k"].shape
 
 

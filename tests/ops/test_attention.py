@@ -72,14 +72,12 @@ def test_dispatcher_reference_on_cpu():
 def test_ring_attention_matches_reference(cpu_mesh_devices):
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ray_tpu.util.jax_compat import shard_map
-
     mesh = Mesh(np.asarray(cpu_mesh_devices).reshape(8), ("sp",))
     b, s, h, d = 2, 64, 2, 8
     key = jax.random.PRNGKey(3)
     q, k, v = _rand_qkv(key, b=b, s=s, h=h, d=d)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", causal=True),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
@@ -94,13 +92,11 @@ def test_ring_attention_matches_reference(cpu_mesh_devices):
 def test_ring_attention_grads(cpu_mesh_devices):
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ray_tpu.util.jax_compat import shard_map
-
     mesh = Mesh(np.asarray(cpu_mesh_devices).reshape(8), ("sp",))
     b, s, h, d = 1, 32, 2, 8
     q, k, v = _rand_qkv(jax.random.PRNGKey(4), b=b, s=s, h=h, d=d)
 
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(ring_attention, axis_name="sp", causal=True),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
@@ -233,3 +229,59 @@ def test_pallas_backward_matches_reference_and_xla():
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=5e-3, rtol=5e-3,
                 err_msg=f"{name} vs xla causal={causal}")
+
+
+# ------------------------------------------------------- dispatch rules
+def _last(op):
+    from ray_tpu.ops.attention import dispatch_log
+    return [e for e in dispatch_log() if e["op"] == op]
+
+
+def test_dispatch_is_by_stated_rules_and_recorded():
+    """"auto" off a TPU is the reference, with the reason on record;
+    the compiled kernel off a TPU is an error, never a quiet
+    interpreter; "interpret" is the explicit way to run the kernel."""
+    from ray_tpu.ops import paged_attention
+    q, k, v = _rand_qkv(jax.random.PRNGKey(7), s=128, d=128)
+    multihead_attention(q, k, v, causal=True)
+    assert any(e["impl"] == "reference"
+               and e["why"] == "platform is not tpu"
+               for e in _last("flash"))
+    with pytest.raises(ValueError, match="needs a TPU"):
+        multihead_attention(q, k, v, causal=True, impl="flash")
+    out = multihead_attention(q, k, v, causal=True, impl="interpret")
+    assert any(e["impl"] == "interpret" for e in _last("flash"))
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(attention_reference(q, k, v, causal=True)),
+        atol=2e-5, rtol=2e-5)
+    # a forced kernel on a call it cannot compute is an error too
+    mask = jnp.ones((1, 1, 128, 128), bool)
+    with pytest.raises(ValueError, match="arbitrary mask"):
+        multihead_attention(q, k, v, mask=mask, impl="interpret")
+    kc = jnp.zeros((3, 4, 8, 128), jnp.float32)
+    pq = jnp.zeros((1, 1, 4, 128), jnp.float32)
+    bt = jnp.ones((1, 2), jnp.int32)
+    pos = jnp.zeros((1, 1), jnp.int32)
+    with pytest.raises(ValueError, match="needs a TPU"):
+        paged_attention(pq, kc, kc, bt, pos, impl="kernel")
+    with pytest.raises(ValueError, match="unknown paged attention impl"):
+        paged_attention(pq, kc, kc, bt, pos, impl="pallas")
+
+
+def test_tpu_shape_rules_name_what_does_not_tile(monkeypatch):
+    """On a TPU "auto" must decide from shapes alone and say which rule
+    sent a call to the reference (nothing is compiled here: the
+    reference path is what the rules pick)."""
+    import ray_tpu.ops.attention as A
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), s=64, d=64)
+    multihead_attention(q, k, v, causal=True)
+    assert any(e["why"] == "head_dim 64 % 128 != 0"
+               for e in _last("flash"))
+    pq = jnp.zeros((1, 1, 4, 128), jnp.bfloat16)
+    kc = jnp.zeros((3, 4, 8, 128), jnp.bfloat16)    # 8-row bf16 pages
+    A.paged_attention(pq, kc, kc, jnp.ones((1, 2), jnp.int32),
+                      jnp.zeros((1, 1), jnp.int32))
+    assert any(e["why"] == "block_size 8 % 16 != 0 (bfloat16 pages)"
+               for e in _last("paged"))

@@ -32,15 +32,16 @@ def _paged_case(seed, B, S, H, KVH, D, bs, T, shuffle=True):
     k_seq = rng.normal(size=(B, T * bs, KVH, D)).astype(np.float32)
     v_seq = rng.normal(size=(B, T * bs, KVH, D)).astype(np.float32)
     n_blocks = 1 + B * T
-    kc = rng.normal(size=(n_blocks, bs, KVH, D)).astype(np.float32)
-    vc = rng.normal(size=(n_blocks, bs, KVH, D)).astype(np.float32)
+    # pool layout [N, KVH, bs, D]: kv_heads ahead of block_size
+    kc = rng.normal(size=(n_blocks, KVH, bs, D)).astype(np.float32)
+    vc = rng.normal(size=(n_blocks, KVH, bs, D)).astype(np.float32)
     order = rng.permutation(np.arange(1, n_blocks)) if shuffle \
         else np.arange(1, n_blocks)
     bt = order.astype(np.int32).reshape(B, T)
     for b in range(B):
         for t in range(T):
-            kc[bt[b, t]] = k_seq[b, t * bs:(t + 1) * bs]
-            vc[bt[b, t]] = v_seq[b, t * bs:(t + 1) * bs]
+            kc[bt[b, t]] = k_seq[b, t * bs:(t + 1) * bs].swapaxes(0, 1)
+            vc[bt[b, t]] = v_seq[b, t * bs:(t + 1) * bs].swapaxes(0, 1)
     return k_seq, v_seq, kc, vc, bt
 
 
@@ -48,7 +49,7 @@ def _both(q, kc, vc, bt, pos, lens):
     ref = paged_attention(q, kc, vc, bt, pos, impl="reference")
     ker = paged_attention(q, kc, vc, bt, pos,
                           lens=jnp.asarray(np.asarray(lens, np.int32)),
-                          impl="kernel")
+                          impl="interpret")
     return np.asarray(ref), np.asarray(ker)
 
 
@@ -114,8 +115,8 @@ def test_lens_zero_idle_slot_is_finite_and_matches_reference():
     never a NaN that could poison a donated buffer."""
     B, H, KVH, D, bs, T = 2, 4, 2, 8, 4, 3
     rng = np.random.default_rng(6)
-    kc = rng.normal(size=(1 + B * T, bs, KVH, D)).astype(np.float32)
-    vc = rng.normal(size=(1 + B * T, bs, KVH, D)).astype(np.float32)
+    kc = rng.normal(size=(1 + B * T, KVH, bs, D)).astype(np.float32)
+    vc = rng.normal(size=(1 + B * T, KVH, bs, D)).astype(np.float32)
     q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
     bt = np.zeros((B, T), np.int32)            # all slots -> trash block
     pos = np.zeros((B, 1), np.int32)
@@ -163,7 +164,7 @@ def test_jit_stable_across_lens_values():
     B, H, KVH, D, bs, T = 2, 4, 2, 8, 4, 4
     _, _, kc, vc, bt = _paged_case(11, B, 16, H, KVH, D, bs, T)
     q = np.zeros((B, 1, H, D), np.float32)
-    f = jax.jit(functools.partial(paged_attention, impl="kernel"))
+    f = jax.jit(functools.partial(paged_attention, impl="interpret"))
     for ln in ([4, 9], [16, 1], [2, 2]):
         lens = np.asarray(ln, np.int32)
         f(q, kc, vc, bt, jnp.asarray((lens - 1).clip(0)[:, None]),
@@ -180,7 +181,7 @@ def test_lens_none_derives_bound_from_positions():
     ref = paged_attention(q, kc, vc, bt, jnp.asarray(pos),
                           impl="reference")
     ker = paged_attention(q, kc, vc, bt, jnp.asarray(pos),
-                          impl="kernel")       # lens derived: pos + 1
+                          impl="interpret")    # lens derived: pos + 1
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), **TOL)
 
 
@@ -228,9 +229,9 @@ def test_gqa_reference_has_no_materialized_repeat():
     ref = paged_attention(q, kc, vc, bt, jnp.asarray(pos),
                           impl="reference")
     k = jnp.take(jnp.asarray(kc), jnp.asarray(bt), axis=0) \
-        .reshape(B, T * bs, KVH, D)
+        .transpose(0, 1, 3, 2, 4).reshape(B, T * bs, KVH, D)
     v = jnp.take(jnp.asarray(vc), jnp.asarray(bt), axis=0) \
-        .reshape(B, T * bs, KVH, D)
+        .transpose(0, 1, 3, 2, 4).reshape(B, T * bs, KVH, D)
     kr = jnp.repeat(k, H // KVH, axis=2)
     vr = jnp.repeat(v, H // KVH, axis=2)
     key_pos = np.arange(T * bs)
@@ -286,7 +287,7 @@ def test_autotune_paged_block_r_times_and_persists(tmp_path,
     import json
     import ray_tpu.ops.paged_flash as pf
 
-    monkeypatch.setenv("RAY_TPU_FLASH_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(pf, "_PAGED_AUTOTUNE_CACHE", {})
     calls = []
 
@@ -318,7 +319,7 @@ def test_autotune_large_prefill_window_picks_past_128(tmp_path,
     import json
     import ray_tpu.ops.paged_flash as pf
 
-    monkeypatch.setenv("RAY_TPU_FLASH_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(pf, "_PAGED_AUTOTUNE_CACHE", {})
     timed = []
 
@@ -355,7 +356,7 @@ def test_flash_disk_cache_ignores_foreign_paged_keys(tmp_path,
     import json
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
-    monkeypatch.setenv("RAY_TPU_FLASH_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     path = tmp_path / "flash_autotune.json"
     path.write_text(json.dumps({
         f"paged|cpu|{jax.__version__}|16|8|256|64": [32, 32],

@@ -38,6 +38,27 @@ def test_chip_spec_cpu():
     assert chip_spec("v5e").bf16_flops == 197e12
 
 
+def test_chip_spec_unknown_tpu_kind_raises(monkeypatch):
+    """A TPU the table does not know is an error, never v5e's peak."""
+    import types
+
+    from ray_tpu.parallel.mesh import chip_spec_for_kind
+    assert chip_spec_for_kind("TPU v5 lite").name == "v5e"
+    assert chip_spec_for_kind("TPU v5").name == "v5p"
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        chip_spec_for_kind("TPU v9x")
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v9x")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [fake])
+    with pytest.raises(ValueError, match="TPU v9x"):
+        chip_spec()
+    # and the block pickers no longer paper over it
+    from ray_tpu.ops import default_flash_blocks, default_paged_block_r
+    with pytest.raises(ValueError, match="TPU v9x"):
+        default_flash_blocks(1024, 1024, 128)
+    with pytest.raises(ValueError, match="TPU v9x"):
+        default_paged_block_r(8, 128)
+
+
 def test_sharding_rules_spec():
     rules = ShardingRules(batch=("dp", "fsdp"), embed="fsdp", mlp="tp")
     p = rules.spec_for(("batch", None, "embed"))

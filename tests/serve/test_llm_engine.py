@@ -12,8 +12,9 @@ import pytest
 import jax.numpy as jnp
 
 from ray_tpu.models import TransformerConfig
-from ray_tpu.serve.llm_engine import (EngineConfig, EngineDeadError,
-                                      LLMEngine, RequestTooLargeError)
+from ray_tpu.serve.llm_engine import (_DONE, EngineConfig,
+                                      EngineDeadError, LLMEngine,
+                                      RequestTooLargeError)
 
 pytestmark = pytest.mark.serve_llm
 
@@ -384,15 +385,50 @@ def test_warmup_compiles_then_resets_session_stats():
         assert s["ttft_ewma_s"] is None
         assert s["tokens_total"] == 0
         assert s["decode_wall_s"] == 0.0
-        assert eng._jit_prefill._cache_size() == 1
-        assert eng._jit_decode._cache_size() == 1
-        # warm: the next request compiles nothing
+        # EVERY program the engine can run is compiled, not only the
+        # two a plain generate touches
+        every = {"prefill": 1, "decode": 1, "copy": 1, "gather": 1,
+                 "scatter": 1}
+        assert s["compiled_programs"] == every
+        # warm: traffic compiles nothing — a plain request, then a
+        # block-aligned repeat (CoW copy) and a KV export + adoption
+        # (gather / scatter)
         list(eng.generate_sync([7, 7, 7], max_new_tokens=3))
-        assert eng._jit_prefill._cache_size() == 1
-        assert eng._jit_decode._cache_size() == 1
-        assert eng.stats()["ttft_ewma_s"] is not None
+        prompt = list(range(1, 9))          # two whole blocks
+        list(eng.generate_sync(prompt, max_new_tokens=2))
+        list(eng.generate_sync(prompt, max_new_tokens=2))
+        payload = eng.prefill_export([9, 8, 7, 6, 5, 4])
+        req = eng.submit_adopt(payload, max_new_tokens=2)
+        while req.out.get(timeout=30) is not _DONE:
+            pass
+        s = eng.stats()
+        assert s["cow_copies_total"] == 1 and s["kv_adopts"] == 1
+        assert s["compiled_programs"] == every
+        assert s["ttft_ewma_s"] is not None
     finally:
         eng.shutdown()
+
+
+def test_warmup_failure_is_fatal_to_the_replica():
+    """A program that cannot compile must fail LLMServer's constructor
+    (and so the replica actor): forcing the compiled kernel on a host
+    with no TPU is such a program. Swallowed, the replica would enter
+    rotation and die on its first request."""
+    from ray_tpu.serve.llm_engine import LLMServer
+    with pytest.raises(EngineDeadError, match="needs a TPU"):
+        LLMServer(model=dict(MODEL_DICT, paged_impl="kernel"),
+                  engine=dict(decode_slots=2, kv_block_size=4,
+                              max_seq_len=32, prefill_chunk=8))
+
+
+def test_stats_record_what_attention_resolved_to(engine4):
+    """On a CPU the engine's "auto" takes the XLA reference, and says
+    why — the record chip_smoke.py fails on when it names anything but
+    the compiled kernel."""
+    list(engine4.generate_sync([3, 4, 5], max_new_tokens=2))
+    assert any(e["op"] == "paged" and e["impl"] == "reference"
+               and e["why"] == "platform is not tpu"
+               for e in engine4.stats()["attention_dispatch"])
 
 
 def test_kv_block_math():

@@ -247,3 +247,62 @@ def test_multiplexed_model_id(serve_session):
     import pytest as _pytest
     with _pytest.raises(TypeError):
         h.options(bogus_option=1)
+
+
+def test_health_check_does_not_kill_a_replica_that_is_still_starting(
+        monkeypatch):
+    """A replica whose constructor is still running (weights and
+    compilation take minutes on a TPU) answers no health check yet:
+    that is a timeout, and for a starting replica a timeout is not
+    death. A constructor that FAILS raises at once, and a replica that
+    has answered before gets the plain 30 s rule."""
+    from ray_tpu.exceptions import ActorDiedError, GetTimeoutError
+    from ray_tpu.serve._private import controller as C
+
+    class FakeReplica:
+        def __init__(self, name):
+            self.name = name
+            self.check_health = self
+            self.killed = False
+
+        def remote(self):
+            return ("health-ref", self.name)
+
+    outcomes = {}
+
+    def fake_get(ref, timeout=None):
+        exc = outcomes.get(ref[1])
+        if exc is not None:
+            raise exc
+        return None
+
+    monkeypatch.setattr(C.ray_tpu, "get", fake_get)
+    monkeypatch.setattr(C.ray_tpu, "kill",
+                        lambda r: setattr(r, "killed", True))
+    ctl = object.__new__(C.ServeController)
+    import threading
+    ctl._lock = threading.RLock()
+    info = object.__new__(C._DeploymentInfo)
+    slow, broken, old, ready = (FakeReplica(n) for n in
+                                ("slow", "broken", "old", "ready"))
+    info.replicas = [slow, broken, old, ready]
+    info.version = 0
+    now = __import__("time").time()
+    info.starting = {
+        slow: (now, ("health-ref", "slow")),
+        broken: (now, ("health-ref", "broken")),
+        old: (now - C.REPLICA_STARTUP_TIMEOUT_S - 1,
+              ("health-ref", "old")),
+        ready: (now, ("health-ref", "ready")),
+    }
+    outcomes.update(slow=GetTimeoutError("still constructing"),
+                    broken=ActorDiedError(None, "constructor raised"),
+                    old=GetTimeoutError("still constructing"))
+    ctl._health_check("d", info)
+    assert info.replicas == [slow, ready]
+    assert not slow.killed and broken.killed and old.killed
+    assert slow in info.starting and ready not in info.starting
+    # once it has answered, a timeout does mean unhealthy
+    outcomes["ready"] = GetTimeoutError("wedged")
+    ctl._health_check("d", info)
+    assert info.replicas == [slow] and ready.killed

@@ -18,8 +18,7 @@ pytestmark = [pytest.mark.serve_llm]
 
 @pytest.mark.slow
 def test_bench_serve_smoke_subprocess():
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               RAY_TPU_JAX_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench_serve.py"),
          "--smoke"],

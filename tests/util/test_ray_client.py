@@ -106,7 +106,7 @@ def test_ray_client_end_to_end(client_server):
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=240,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"})
+        env=dict(os.environ))
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     assert "CLIENT-OK" in proc.stdout
 
@@ -148,7 +148,7 @@ def test_client_get_outlives_connection_timeout(client_server,
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=240,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu",
+        env={**os.environ,
              "RAY_TPU_CLIENT_TIMEOUT": "4"})
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     assert "SLOW-OK" in proc.stdout
@@ -168,7 +168,7 @@ def test_client_disconnect_releases_leases(client_server, ray_start_shared):
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=240,
-        env={**os.environ, "RAY_TPU_JAX_PLATFORM": "cpu"})
+        env=dict(os.environ))
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     # the host cluster is still healthy after the client went away
     assert ray_tpu.get(ray_tpu.put(1)) == 1
