@@ -1,0 +1,97 @@
+"""The metric arithmetic of benchmarks/stats.py on hand-made records."""
+import pytest
+
+from benchmarks import spec, stats
+
+
+def test_percentile_interpolates_like_numpy():
+    xs = [10.0, 20.0, 30.0, 40.0]
+    assert stats.percentile(xs, 0) == 10.0
+    assert stats.percentile(xs, 100) == 40.0
+    assert stats.percentile(xs, 50) == 25.0
+    assert stats.percentile(xs, 90) == pytest.approx(37.0)
+    assert stats.percentile([], 50) is None
+
+
+def test_slowest_tenth_is_a_mean_over_the_tail_not_one_sample():
+    xs = list(range(1, 101))                 # 1..100
+    assert stats.slowest_tenth_mean(xs) == pytest.approx(95.5)
+    assert stats.slowest_tenth_mean([7.0]) == 7.0        # at least one
+    assert stats.slowest_tenth_mean(list(range(11))) == pytest.approx(9.5)
+
+
+def test_window_rate_takes_all_tokens_over_all_time():
+    ends = [0.0, 1.0, 2.0, 4.0, 5.0]          # one slow step
+    assert stats.window_tokens_per_s(ends, 100) == pytest.approx(80.0)
+    assert stats.step_times(ends) == [1.0, 1.0, 2.0, 1.0]
+
+
+def test_block_median_spoils_only_the_block_with_the_hiccup():
+    ends = [float(i) for i in range(9)]       # 8 steps of 1 s
+    ends = ends[:5] + [e + 3.0 for e in ends[5:]]   # a 3 s stall in step 5
+    blocks = stats.block_tokens_per_s(ends, 10, 2)
+    assert blocks == pytest.approx([10.0, 10.0, 4.0, 10.0])
+    assert stats.median(blocks) == 10.0
+    assert stats.window_tokens_per_s(ends, 10) == pytest.approx(80 / 11)
+    # a trailing partial block is not counted
+    assert len(stats.block_tokens_per_s(ends[:8], 10, 2)) == 3
+
+
+def _req(due, tokens, asked=3, error=None, sent=None):
+    return {"due": due, "sent": due if sent is None else sent,
+            "tokens": tokens, "asked": asked, "error": error}
+
+
+def test_tokens_are_counted_one_by_one_at_the_windows_edges():
+    reqs = [_req(-1.0, [-0.5, 0.0, 0.5]),     # started before the window
+            _req(9.0, [9.5, 9.999, 10.0]),    # 10.0 is outside [0, 10)
+            _req(5.0, [5.1], asked=3)]        # never completed: still counts
+    assert stats.tokens_in_window(reqs, 10.0) == 2 + 2 + 1
+    # completed-request counting (what PR 22 judged) sees far less
+    assert stats.completed_tokens(reqs, 10.0) == 3
+
+
+def test_ttft_is_from_the_due_instant_and_a_missing_one_costs_the_window():
+    reqs = [_req(1.0, [1.5, 1.6], sent=1.2),          # 0.5 s from due
+            _req(9.9, [10.3]),                        # answered while listening
+            _req(9.95, []),                           # never answered
+            _req(3.0, [3.1], error="boom"),           # failed
+            _req(12.0, [12.1])]                       # due after the window
+    got = stats.ttfts_ms(reqs, 10.0, 11.0)
+    assert got == pytest.approx([500.0, 400.0, 10000.0, 10000.0])
+    window = stats.due_in_window(reqs, 10.0)
+    assert sum(stats.is_failed(r, 11.0) for r in window) == 2
+    # a first token later than the run listened is no first token
+    assert stats.is_failed(_req(9.9, [11.5]), 11.0)
+
+
+def test_tpot_needs_two_tokens_inside_the_window():
+    reqs = [_req(0.0, [1.0, 1.1, 1.3]), _req(0.0, [2.0]),
+            _req(9.0, [9.5, 9.9, 10.4])]
+    assert stats.tpots_ms(reqs, 10.0) == pytest.approx([150.0, 400.0])
+
+
+def test_in_flight_counts_unfinished_requests_due_so_far():
+    reqs = [_req(0.0, [1.0, 2.0, 3.0]), _req(1.0, [2.0]), _req(8.0, [])]
+    assert stats.in_flight_at(reqs, 2.5) == 2
+    assert stats.in_flight_at(reqs, 9.0) == 2
+    assert stats.in_flight_at(reqs, 0.5) == 1
+
+
+
+def test_the_longest_silence_is_found_with_the_windows_edges():
+    reqs = [{"tokens": [0.5, 1.0, 4.0]}, {"tokens": [1.5, 9.5, 12.0]}]
+    assert stats.longest_silence(reqs, 10.0) == [5.5, 4.0]
+    assert stats.longest_silence([{"tokens": [7.0]}], 10.0) == [7.0, 0.0]
+    assert stats.longest_silence([], 10.0) == [10.0, 0.0]
+
+
+def test_the_heartbeat_remembers_its_worst_oversleep_since_reset():
+    import time
+    heart = spec.Heartbeat()
+    time.sleep(0.15)
+    late, at = heart.worst
+    assert 0 < late < 0.1 and 0 <= at < 0.2
+    heart.reset()
+    assert heart.worst == [0.0, 0.0]
+
