@@ -282,21 +282,27 @@ def make_train_step(config: TransformerConfig, mesh,
         (loss, aux), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"])
         if grad_transport == "int8":
-            grads = _quantize_grads(grads, state["step"])
+            with jax.named_scope("grad_transport"):
+                grads = _quantize_grads(grads, state["step"])
         if shard_weight_update:
             # Reduce-scatter grads to flat 1/N shards, update only the
             # local optimizer shard, all-gather fresh params (the
             # constraint back to the param sharding via out_shardings).
-            gflat = _flatten_tree(grads, constrain_to=flat_sh)
-            pflat = _flatten_tree(state["params"], constrain_to=flat_sh)
-            updates, new_opt = optimizer.update(
-                gflat, state["opt_state"], pflat)
-            new_pflat = optax.apply_updates(pflat, updates)
-            new_params = unflatten_like(state["params"], new_pflat)
+            with jax.named_scope("grad_transport"):
+                gflat = _flatten_tree(grads, constrain_to=flat_sh)
+                pflat = _flatten_tree(state["params"],
+                                      constrain_to=flat_sh)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(
+                    gflat, state["opt_state"], pflat)
+                new_pflat = optax.apply_updates(pflat, updates)
+                new_params = unflatten_like(state["params"], new_pflat)
         else:
-            updates, new_opt = optimizer.update(
-                grads, state["opt_state"], state["params"])
-            new_params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(
+                    grads, state["opt_state"], state["params"])
+                new_params = optax.apply_updates(state["params"],
+                                                 updates)
         new_state = {"params": new_params, "opt_state": new_opt,
                      "step": state["step"] + 1}
         metrics = {"loss": loss, "n_tokens": aux["n_tokens"],

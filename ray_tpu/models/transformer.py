@@ -335,6 +335,7 @@ def _attention(c: TransformerConfig, q, k, v, mesh, rules):
     return fn(q, k, v)
 
 
+@jax.named_scope("attn")
 def _attn_sublayer(c, h, lp, sin, cos, layout, mesh, rules):
     """qkv projection → rotary → GQA repeat → attention → output proj.
     Shared by both block styles (only the rotary layout differs)."""
@@ -359,6 +360,7 @@ def _attn_sublayer(c, h, lp, sin, cos, layout, mesh, rules):
                       lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
 
 
+@jax.named_scope("mlp")
 def _mlp_sublayer(c, h, lp):
     """Dense or MoE MLP on normed input h; returns (out, moe_aux)."""
     dt = c.dtype
@@ -416,7 +418,8 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
         body = jax.checkpoint(body, policy=remat_policy_fn(policy))
 
     def scan_fn(carry, lp):
-        out, aux = body(carry, lp)
+        with jax.named_scope("layer"):
+            out, aux = body(carry, lp)
         if mesh is not None and rules is not None:
             from ray_tpu.parallel.sharding import constrain
             out = constrain(out, mesh, rules, ("batch", "sequence", None))
@@ -426,6 +429,7 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
     return x, (jnp.sum(layer_aux) if c.n_experts else 0.0)
 
 
+@jax.named_scope("final_norm")
 def _final_norm(config: TransformerConfig, params: Dict, x: jnp.ndarray):
     fn = params["final_norm"]
     if config.block_style == "llama":
@@ -442,9 +446,19 @@ def hidden_states(config: TransformerConfig, params: Dict,
     chunked loss so full logits never materialize).
     """
     c = config
-    x = jnp.take(params["embed"], input_ids, axis=0).astype(c.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0).astype(c.dtype)
     x, moe_aux = run_layers(c, params["layers"], x, mesh=mesh, rules=rules)
     return _final_norm(c, params, x), moe_aux
+
+
+@jax.named_scope("lm_head")
+def _lm_head(c: TransformerConfig, params: Dict, x: jnp.ndarray):
+    logits = jnp.dot(x.astype(c.dtype),
+                     params["lm_head"]["w"].astype(c.dtype))
+    if c.block_style != "llama":
+        logits = logits + params["lm_head"]["b"].astype(c.dtype)
+    return logits
 
 
 def apply(config: TransformerConfig, params: Dict, input_ids: jnp.ndarray,
@@ -458,10 +472,7 @@ def apply(config: TransformerConfig, params: Dict, input_ids: jnp.ndarray,
     """
     c = config
     x, moe_aux = hidden_states(c, params, input_ids, mesh=mesh, rules=rules)
-    logits = jnp.dot(x.astype(c.dtype),
-                     params["lm_head"]["w"].astype(c.dtype))
-    if c.block_style != "llama":
-        logits = logits + params["lm_head"]["b"].astype(c.dtype)
+    logits = _lm_head(c, params, x)
     if return_moe_aux:
         return logits, moe_aux
     return logits
@@ -492,14 +503,17 @@ def lm_loss(config: TransformerConfig, params: Dict, batch: Dict,
     if c.ce_chunk_size and not seq_sharded:
         x, moe_aux = hidden_states(c, params, ids, mesh=mesh, rules=rules)
         head = params["lm_head"]
-        loss, n = fused_lm_head_loss(
-            x.astype(c.dtype)[:, :-1], head["w"], labels,
-            head_bias=head.get("b"), mask=mask,
-            chunk_size=c.ce_chunk_size)
+        with jax.named_scope("lm_head_loss"):
+            loss, n = fused_lm_head_loss(
+                x.astype(c.dtype)[:, :-1], head["w"], labels,
+                head_bias=head.get("b"), mask=mask,
+                chunk_size=c.ce_chunk_size)
     else:
         logits, moe_aux = apply(c, params, ids, mesh=mesh, rules=rules,
                                 return_moe_aux=True)
-        loss, n = cross_entropy_loss(logits[:, :-1], labels, mask=mask)
+        with jax.named_scope("lm_head_loss"):
+            loss, n = cross_entropy_loss(logits[:, :-1], labels,
+                                         mask=mask)
     aux = {"n_tokens": n}
     if c.n_experts:
         loss = loss + c.moe_aux_weight * moe_aux
@@ -636,6 +650,7 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
 
 
+@jax.named_scope("attn")
 def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
                          block_tables, positions, write_mask, lens):
     """Decode-path attention sublayer: project qkv for the new tokens,
@@ -657,14 +672,17 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
     k = apply_rotary(k, sin, cos, positions=positions, layout=layout)
 
     n_blocks, bs = kc.shape[0], kc.shape[2]
-    bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-    slot = positions % bs
-    # invalid (padded) chunk positions scatter out of bounds -> dropped
-    bid = jnp.where(write_mask, bid, n_blocks)
-    # [N, KVH, bs, D] indexed (bid, :, slot): the two index arrays
-    # broadcast to (B, C) and lead the result, matching k's (B, C, KVH, D)
-    kc = kc.at[bid, :, slot].set(k.astype(kc.dtype), mode="drop")
-    vc = vc.at[bid, :, slot].set(v.astype(vc.dtype), mode="drop")
+    with jax.named_scope("kv_write"):
+        bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        slot = positions % bs
+        # invalid (padded) chunk positions scatter out of bounds ->
+        # dropped
+        bid = jnp.where(write_mask, bid, n_blocks)
+        # [N, KVH, bs, D] indexed (bid, :, slot): the two index arrays
+        # broadcast to (B, C) and lead the result, matching k's
+        # (B, C, KVH, D)
+        kc = kc.at[bid, :, slot].set(k.astype(kc.dtype), mode="drop")
+        vc = vc.at[bid, :, slot].set(v.astype(vc.dtype), mode="drop")
 
     # h.shape[1] is static under jit: > 1 means a prefill chunk, whose
     # much larger query-row count can carry a bigger row block than the
@@ -672,9 +690,10 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
     br = c.paged_block_r_prefill \
         if (h.shape[1] > 1 and c.paged_block_r_prefill) \
         else c.paged_block_r
-    att = paged_attention(q, kc, vc, block_tables, positions,
-                          lens=lens, impl=c.paged_impl,
-                          block_r=br or None)
+    with jax.named_scope("paged_attn"):
+        att = paged_attention(q, kc, vc, block_tables, positions,
+                              lens=lens, impl=c.paged_impl,
+                              block_r=br or None)
     out = jnp.einsum("bshd,hde->bse", att,
                      lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
     return out, kc, vc
@@ -700,7 +719,8 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
         window, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
         c.rope_base)
     layout = "gptj" if c.block_style == "gptj" else "neox"
-    x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
 
     def gptj_step(x, lp, kc, vc):
         h = layer_norm(x, lp["ln_scale"], lp["ln_bias"])
@@ -724,22 +744,15 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
 
     def scan_fn(carry, per_layer):
         lp, kc, vc = per_layer
-        out, kc, vc = step(carry, lp, kc, vc)
+        with jax.named_scope("layer"):
+            out, kc, vc = step(carry, lp, kc, vc)
         return out, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(
         scan_fn, x, (params["layers"], cache["k"], cache["v"]))
 
-    fn = params["final_norm"]
-    if c.block_style == "llama":
-        x = rms_norm(x, fn["scale"])
-    else:
-        x = layer_norm(x, fn["scale"], fn["bias"])
-    logits = jnp.dot(x.astype(c.dtype),
-                     params["lm_head"]["w"].astype(c.dtype))
-    if c.block_style != "llama":
-        logits = logits + params["lm_head"]["b"].astype(c.dtype)
-    return logits, {"k": new_k, "v": new_v}
+    x = _final_norm(c, params, x)
+    return _lm_head(c, params, x), {"k": new_k, "v": new_v}
 
 
 def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,

@@ -82,6 +82,7 @@ def _chunk_logits(xi, w, bias):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+@jax.named_scope("lm_head_loss")
 def _fused_ce(cfg, x, w, bias, labels, mask):
     loss, n, _ = _fused_ce_fwd_impl(cfg, x, w, bias, labels, mask)
     return loss, n
@@ -110,11 +111,13 @@ def _fused_ce_fwd_impl(cfg, x, w, bias, labels, mask):
     return loss_sum / n, n, lses
 
 
+@jax.named_scope("lm_head_loss")
 def _fused_ce_fwd(cfg, x, w, bias, labels, mask):
     loss, n, lses = _fused_ce_fwd_impl(cfg, x, w, bias, labels, mask)
     return (loss, n), (x, w, bias, labels, mask, lses, loss, n)
 
 
+@jax.named_scope("lm_head_loss")
 def _fused_ce_bwd(cfg, res, cts):
     chunk, z = cfg
     x, w, bias, labels, mask, lses, loss, n = res

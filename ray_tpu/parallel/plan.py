@@ -278,19 +278,31 @@ class _SPMDProgram(TrainProgram):
             quant_stochastic=plan.quant_stochastic,
             telemetry_interval_s=telemetry_interval_s)
         self.state = self.bundle.init(seed=seed)
+        # the step's own clock: train.step and, inside it, the host's
+        # part before the device has the step (dispatch), the blocking
+        # fetch of the loss (wait) and what follows it (tail)
+        from ray_tpu.util.tracing import PhaseClock
+        self.clock = PhaseClock("train", steps=True)
 
     def step(self, batch: Dict[str, Any]) -> PlanStepResult:
         import numpy as np
-        self.plan.validate_batch(
-            int(np.asarray(batch["input_ids"]).shape[0]))
-        t0 = time.perf_counter()
-        self.state, metrics = self.bundle.step(self.state, batch)
-        loss = float(metrics["loss"])
-        wall = time.perf_counter() - t0
-        return PlanStepResult(
-            loss=loss, grad_norm=float(metrics["grad_norm"]),
-            step=int(self.state["step"]), wall_s=wall,
-            n_tokens=float(metrics["n_tokens"]), detail=metrics)
+        clock = self.clock
+        clock.tick()
+        with clock.phase("train.step"):
+            with clock.phase("train.dispatch"):
+                self.plan.validate_batch(
+                    int(np.asarray(batch["input_ids"]).shape[0]))
+                t0 = time.perf_counter()
+                self.state, metrics = self.bundle.step(self.state, batch)
+            with clock.phase("train.wait"):
+                loss = float(metrics["loss"])
+            with clock.phase("train.tail"):
+                wall = time.perf_counter() - t0
+                res = PlanStepResult(
+                    loss=loss, grad_norm=float(metrics["grad_norm"]),
+                    step=int(self.state["step"]), wall_s=wall,
+                    n_tokens=float(metrics["n_tokens"]), detail=metrics)
+        return res
 
     # ------------------------------------------------------ checkpoint
     def save_checkpoint(self) -> Dict[str, Any]:

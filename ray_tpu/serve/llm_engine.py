@@ -162,7 +162,8 @@ class _Request:
                  "trie_cursor", "spec_ewma", "spec_disabled", "warmup",
                  "detailed", "trace", "t_enqueue_wall", "queue_wait_s",
                  "last_tok_wall", "tick_t0", "tick_toks", "export",
-                 "adopt")
+                 "adopt", "t_slot", "t_first_chunk", "tick_first_chunk",
+                 "n_chunks")
 
     def __init__(self, rid: int, prompt: List[int], max_new_tokens: int,
                  eos_token_id: Optional[int]):
@@ -185,9 +186,14 @@ class _Request:
         self.adopt: Optional[dict] = None   # shipped payload to adopt
         self.t_submit = time.monotonic()
         self.t_first_token: Optional[float] = None
+        # -- the TTFT split (always stamped; time.monotonic)
+        self.t_slot: Optional[float] = None         # a decode slot won
+        self.t_first_chunk: Optional[float] = None  # first chunk staged
+        self.tick_first_chunk = 0     # engine tick of that chunk
+        self.n_chunks = 0             # prefill chunks it has run
+        self.t_enqueue_wall = 0.0     # router (or submit) wall clock
         # -- per-request tracing (serve/request_trace.py)
         self.trace = None             # RequestTrace or None
-        self.t_enqueue_wall = 0.0     # router (or submit) wall clock
         self.queue_wait_s = 0.0       # enqueue -> engine admission
         self.last_tok_wall: Optional[float] = None
         self.tick_t0: Optional[float] = None   # open DECODE tick start
@@ -295,25 +301,27 @@ class LLMEngine:
         def _prefill_fn(params, tokens, cache, bt, start, lens):
             logits, cache = prefill(model_config, params, tokens, cache,
                                     bt, start, lens)
-            last = jnp.take_along_axis(
-                logits, (lens - 1)[:, None, None], axis=1)[:, 0]
-            tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            if capture:
-                lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(last, axis=-1), tok[:, None],
-                    axis=-1)[:, 0]
-                return tok, lp, cache
+            with jax.named_scope("sample"):
+                last = jnp.take_along_axis(
+                    logits, (lens - 1)[:, None, None], axis=1)[:, 0]
+                tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                if capture:
+                    lp = jnp.take_along_axis(
+                        jax.nn.log_softmax(last, axis=-1), tok[:, None],
+                        axis=-1)[:, 0]
+                    return tok, lp, cache
             return tok, cache
 
         def _decode_fn(params, toks, cache, bt, seq_lens):
             logits, cache = decode_step(model_config, params, toks,
                                         cache, bt, seq_lens)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            if capture:
-                lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits, axis=-1), tok[:, None],
-                    axis=-1)[:, 0]
-                return tok, lp, cache
+            with jax.named_scope("sample"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                if capture:
+                    lp = jnp.take_along_axis(
+                        jax.nn.log_softmax(logits, axis=-1),
+                        tok[:, None], axis=-1)[:, 0]
+                    return tok, lp, cache
             return tok, cache
 
         self._jit_prefill = jax.jit(_prefill_fn, donate_argnums=(2,))
@@ -327,7 +335,8 @@ class LLMEngine:
         def _verify_fn(params, toks, cache, bt, start, lens):
             logits, cache = prefill(model_config, params, toks, cache,
                                     bt, start, lens)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+            with jax.named_scope("sample"):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
         self._jit_verify = jax.jit(_verify_fn, donate_argnums=(2,)) \
             if ec.spec_tokens > 0 else None
@@ -335,6 +344,7 @@ class LLMEngine:
         # copy-on-write block copy (fully-matched prompt tail): one
         # block's k/v copied src -> dst across all layers; indices are
         # traced scalars, so every CoW reuses the same compiled program
+        @jax.named_scope("kv_copy")
         def _copy_fn(cache, src, dst):
             k = cache["k"]
             v = cache["v"]
@@ -355,10 +365,12 @@ class LLMEngine:
         # never recompiles — pad ids point at the reserved trash block
         # and pad data is zeros, so the duplicate block-0 writes all
         # write zeros and scatter order cannot matter.
+        @jax.named_scope("kv_gather")
         def _gather_fn(cache, ids):
             return (jnp.take(cache["k"], ids, axis=1),
                     jnp.take(cache["v"], ids, axis=1))
 
+        @jax.named_scope("kv_scatter")
         def _scatter_fn(cache, ids, k_slab, v_slab):
             return {"k": cache["k"].at[:, ids].set(k_slab),
                     "v": cache["v"].at[:, ids].set(v_slab)}
@@ -413,7 +425,6 @@ class LLMEngine:
         # bytes/wall come from here; exports count on the prefill
         # fleet, adopts on the decode fleet)
         self._kv_exports = 0
-        self._kv_export_bytes = 0
         self._kv_adopts = 0
         self._kv_adopt_bytes = 0
         self._kv_adopt_blocks = 0
@@ -428,6 +439,19 @@ class LLMEngine:
         # histogram is right for dashboards, wrong for a scale-up
         # decision that wants "what are users seeing RIGHT NOW")
         self._ttft_ewma: Optional[float] = None
+        # where first-token time went, summed over ttft_requests (the
+        # three parts add up to ttft_s): submit -> slot, slot -> first
+        # chunk (behind other requests' chunks), first chunk -> first
+        # token, with the ticks and chunks that took; stats() keys
+        self._ttft = {
+            "ttft_requests": 0, "ttft_s": 0.0, "ttft_queue_s": 0.0,
+            "ttft_prefill_wait_s": 0.0, "ttft_prefill_s": 0.0,
+            "ttft_prefill_ticks": 0, "ttft_prefill_chunks": 0}
+        # the tick's own clock (step thread only): phase totals, the
+        # newest spans, profiler annotations (util/tracing.PhaseClock)
+        from ray_tpu.util.tracing import PhaseClock
+        self._clock = PhaseClock(
+            f"engine:{replica_tag}" if replica_tag else "engine")
         self._metrics = self._recorder = None
         try:
             from ray_tpu.core.metric_defs import runtime_metrics
@@ -528,6 +552,11 @@ class LLMEngine:
             req.detailed = detailed
             req.export = _export
             req.adopt = _adopt
+            # clamp a skewed cross-process enqueue stamp: the queue
+            # wait must never start in this process's future
+            now = time.time()
+            req.t_enqueue_wall = min(
+                float((trace_ctx or {}).get("enqueue_ts") or now), now)
             if not _warmup:
                 self._attach_trace(req, trace_ctx)
             self._pending.append(req)
@@ -544,7 +573,6 @@ class LLMEngine:
         tr = self._tracer
         if tr is None or not tr.enabled:
             return
-        now = time.time()
         ctx = trace_ctx or {}
         rid = ctx.get("request_id")
         # a caller-pinned id with no explicit sampling verdict (RLHF
@@ -560,10 +588,6 @@ class LLMEngine:
         if trace is None:
             return
         req.trace = trace
-        # clamp a skewed cross-process enqueue stamp: the QUEUED span
-        # must never start in this process's future
-        req.t_enqueue_wall = min(float(ctx.get("enqueue_ts") or now),
-                                 now)
 
     def cancel(self, req: _Request) -> None:
         """Mark a request cancelled; the step thread frees its slot and
@@ -903,6 +927,7 @@ class LLMEngine:
             self._decode_pages_live = self._decode_pages_window = 0
             self._prompt_blocks_total = 0
             self._occupancy.clear()
+            self._clock.reset()
 
     def stats(self) -> Dict[str, Any]:
         """Scheduler counters (the autoscaling signal surface): queue
@@ -975,6 +1000,15 @@ class LLMEngine:
                 "queue_wait_ewma_s": (
                     round(self._queue_wait_ewma, 6)
                     if self._queue_wait_ewma is not None else None),
+                # the tick's phases, name -> [count, seconds], and two
+                # sums of them: all tick time, and the part of it in
+                # which the device had nothing queued (from the end of
+                # a *.wait to the start of the next *.dispatch)
+                "phases": self._clock.totals(),
+                "tick_wall_s": self._clock.seconds("engine.tick"),
+                "host_gap_s": self._clock.gap_s,
+                # first-token time by where it went (ttft_* keys)
+                **self._ttft,
                 # in-flight weight refresh accounting (RLHF rollout
                 # backend): swaps are pointer flips between decode
                 # steps, so sync_stall_s — decode time lost waiting on
@@ -983,7 +1017,6 @@ class LLMEngine:
                 # prefill fleet, adopts (+ ship wall measured
                 # ship_ts -> adoption-complete) on the decode fleet
                 "kv_exports": self._kv_exports,
-                "kv_export_bytes": self._kv_export_bytes,
                 "kv_adopts": self._kv_adopts,
                 "kv_adopt_bytes": self._kv_adopt_bytes,
                 "kv_adopt_blocks": self._kv_adopt_blocks,
@@ -1091,13 +1124,19 @@ class LLMEngine:
     # one engine step: drain posted ops -> swap staged weights -> reap
     # -> admit -> one prefill chunk -> one decode
     def _step(self) -> None:
-        self._drain_ops()
-        self._maybe_swap_weights()
-        self._reap_cancelled()
-        self._admit()
-        self._prefill_one_chunk()
-        self._decode_once()
-        self._emit_stats()
+        clock = self._clock
+        clock.tick()
+        with clock.phase("engine.tick"):
+            with clock.phase("engine.ops"):
+                self._drain_ops()
+                self._maybe_swap_weights()
+                self._reap_cancelled()
+            with clock.phase("engine.admit"):
+                self._admit()
+            self._prefill_one_chunk()
+            self._decode_once()
+            with clock.phase("engine.report"):
+                self._emit_stats()
 
     def _maybe_swap_weights(self) -> None:
         """Apply a staged weight refresh between decode steps: a pure
@@ -1228,10 +1267,8 @@ class LLMEngine:
                             req.hit_blocks)
                     except Exception:
                         pass
+                now = self._stamp_slot(req)
                 if req.trace is not None:
-                    now = time.time()
-                    req.queue_wait_s = max(
-                        0.0, now - req.t_enqueue_wall)
                     req.trace.span(RT.QUEUED, req.t_enqueue_wall, now)
                     req.trace.span(RT.ADMITTED, now, None,
                                    slot=req.slot,
@@ -1248,6 +1285,14 @@ class LLMEngine:
                     self._np.int32(cow_dst))
                 with self._lock:
                     self._pool.release([cow_src])
+
+    def _stamp_slot(self, req: _Request) -> float:
+        """A decode slot is won: the end of the queue wait, traced or
+        not. Returns the wall clock the trace spans use."""
+        req.t_slot = time.monotonic()
+        now = time.time()
+        req.queue_wait_s = max(0.0, now - req.t_enqueue_wall)
+        return now
 
     # --------------------------------------------- disagg adopt / export
     def _admit_adopt(self, req: _Request) -> bool:
@@ -1302,9 +1347,11 @@ class LLMEngine:
                     self._metrics.serve_prefix_hits.inc(req.hit_blocks)
                 except Exception:
                     pass
+            now = self._stamp_slot(req)
+            # no chunk of its own: the shipped slab is its prefill
+            req.t_first_chunk = req.t_slot
+            req.tick_first_chunk = self._clock.tick_no
             if req.trace is not None:
-                now = time.time()
-                req.queue_wait_s = max(0.0, now - req.t_enqueue_wall)
                 req.trace.span(RT.QUEUED, req.t_enqueue_wall, now)
                 req.trace.span(RT.ADMITTED, now, None, slot=req.slot,
                                hit_blocks=req.hit_blocks,
@@ -1362,7 +1409,7 @@ class LLMEngine:
                     dur_s=round(t1w - t0w, 6))
             except Exception:
                 pass
-        self._record_ttft(req)
+        self._first_token(req)
         with self._lock:
             # trie-index every full prompt chunk: the shipped prefix
             # is warm on THIS replica for later requests
@@ -1433,7 +1480,6 @@ class LLMEngine:
         }
         t1w = time.time()
         self._kv_exports += 1
-        self._kv_export_bytes += int(kv["wire_bytes"])
         if req.trace is not None:
             req.trace.span(RT.KV_SHIP, t0w, t1w,
                            bytes=kv["wire_bytes"], wire=ec.kv_wire,
@@ -1463,30 +1509,54 @@ class LLMEngine:
             return
         np, jnp = self._np, self._jnp
         ec = self.config
+        clock = self._clock
         C = ec.prefill_chunk
         start = req.prefill_pos
         n = min(C, len(req.prompt) - start)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :n] = req.prompt[start:start + n]
-        t0w = time.time()
-        t0 = time.monotonic()
-        out = self._jit_prefill(
-            self._params, jnp.asarray(chunk), self._cache,
-            jnp.asarray(self._block_tables[req.slot:req.slot + 1]),
-            jnp.full((1,), start, jnp.int32),
-            jnp.full((1,), n, jnp.int32))
+        with clock.phase("engine.prefill.stage"):
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :n] = req.prompt[start:start + n]
+            t0w = time.time()
+            t0 = time.monotonic()
+            if req.t_first_chunk is None:
+                req.t_first_chunk = t0
+                req.tick_first_chunk = clock.tick_no
+                if req.trace is not None:
+                    # slot won, waiting behind other requests' chunks
+                    req.trace.span(RT.PREFILL_WAIT,
+                                   t0w - (t0 - req.t_slot), t0w)
+            toks = jnp.asarray(chunk)
+            bt = jnp.asarray(self._block_tables[req.slot:req.slot + 1])
+            at = jnp.full((1,), start, jnp.int32)
+            lens = jnp.full((1,), n, jnp.int32)
+        with clock.phase("engine.prefill.dispatch"):
+            out = self._jit_prefill(self._params, toks, self._cache, bt,
+                                    at, lens)
+            # let go of the uploads while the device is busy: freed
+            # later, they are freed on the next program's path
+            del toks, bt, at, lens
         if self.config.capture_logprobs:
             tok, lp, self._cache = out
         else:
             tok, self._cache = out
             lp = None
-        self._jax.block_until_ready(tok)
+        with clock.phase("engine.prefill.wait"):
+            self._jax.block_until_ready(tok)
         self._prefill_wall_s += time.monotonic() - t0
+        with clock.phase("engine.prefill.book"):
+            self._book_prefill(req, start, n, t0w, tok, lp)
+
+    def _book_prefill(self, req: _Request, start: int, n: int,
+                      t0w: float, tok, lp) -> None:
+        """After a chunk: the trie's new blocks and, at the prompt's
+        end, the first token (or the hand-off)."""
+        ec = self.config
         req.prefill_pos += n
+        req.n_chunks += 1
         self._prefill_chunks += 1
         if req.trace is not None:
             req.trace.span(RT.PREFILL, t0w, time.time(),
-                           pos=start, tokens=n)
+                           pos=start, tokens=n, tick=self._clock.tick_no)
         # index newly-completed FULL prompt blocks in the radix trie so
         # concurrent/later requests with the same prefix share them; a
         # lost insert race (same chunk path already indexed) keeps our
@@ -1515,7 +1585,7 @@ class LLMEngine:
             return
         req.seq_len = len(req.prompt)
         req.t_first_token = time.monotonic()
-        self._record_ttft(req)
+        self._first_token(req)
         with self._lock:
             self._prefilling.popleft()
             if req.cancelled:
@@ -1555,36 +1625,48 @@ class LLMEngine:
         if self.config.spec_tokens > 0:
             self._decode_speculative()
             return
-        with self._lock:
-            active = [r for r in self._slots
-                      if r is not None and r.state == _DECODE]
-            if not active:
-                return
-            self._decode_steps += 1
-            self._occupancy[len(active)] += 1
-            if self._metrics is not None:
-                try:
-                    self._metrics.serve_batch_occupancy.observe(
-                        len(active))
-                except Exception:
-                    pass
-            toks = self._last_tok.copy()
-            lens = self._seq_lens.copy()
-            bt = self._block_tables.copy()
-        self._account_decode_pages(lens + 1)
+        # only this thread moves a request in or out of a slot
+        active = [r for r in self._slots
+                  if r is not None and r.state == _DECODE]
+        if not active:
+            return
+        clock = self._clock
         jnp = self._jnp
-        t0 = time.monotonic()
-        res = self._jit_decode(
-            self._params, jnp.asarray(toks), self._cache,
-            jnp.asarray(bt), jnp.asarray(lens))
-        if self.config.capture_logprobs:
-            out, lps, self._cache = res
-            lps = self._np.asarray(lps)
-        else:
-            out, self._cache = res
-            lps = None
-        out = self._np.asarray(out)
+        with clock.phase("engine.decode.stage"):
+            with self._lock:
+                self._decode_steps += 1
+                self._occupancy[len(active)] += 1
+                if self._metrics is not None:
+                    try:
+                        self._metrics.serve_batch_occupancy.observe(
+                            len(active))
+                    except Exception:
+                        pass
+                toks = self._last_tok.copy()
+                lens = self._seq_lens.copy()
+                bt = self._block_tables.copy()
+            self._account_decode_pages(lens + 1)
+            t0 = time.monotonic()
+            toks, bt, lens = (jnp.asarray(toks), jnp.asarray(bt),
+                              jnp.asarray(lens))
+        with clock.phase("engine.decode.dispatch"):
+            res = self._jit_decode(self._params, toks, self._cache, bt,
+                                   lens)
+            del toks, bt, lens      # as in _prefill_one_chunk
+        with clock.phase("engine.decode.wait"):
+            if self.config.capture_logprobs:
+                out, lps, self._cache = res
+                lps = self._np.asarray(lps)
+            else:
+                out, self._cache = res
+                lps = None
+            out = self._np.asarray(out)
         self._decode_wall_s += time.monotonic() - t0
+        with clock.phase("engine.decode.emit"):
+            self._emit_decoded(active, out, lps)
+
+    def _emit_decoded(self, active: List[_Request], out, lps) -> None:
+        """One decoded token to each active request's stream."""
         produced = 0
         now_w = time.time()
         with self._lock:
@@ -1651,49 +1733,66 @@ class LLMEngine:
         L = ec.spec_tokens + 1
         S = ec.decode_slots
         bs = ec.kv_block_size
-        with self._lock:
-            active = [r for r in self._slots
-                      if r is not None and r.state == _DECODE]
-            if not active:
-                return
-            self._decode_steps += 1
-            self._occupancy[len(active)] += 1
-            if self._metrics is not None:
-                try:
-                    self._metrics.serve_batch_occupancy.observe(
-                        len(active))
-                except Exception:
-                    pass
-            toks = np.zeros((S, L), np.int32)
-            lens = np.zeros((S,), np.int32)
-            starts = np.zeros((S,), np.int32)
-            drafts: Dict[int, List[int]] = {}
-            for req in active:
-                s = req.slot
-                # cap drafts to the sequence's allocated block span so
-                # speculative writes NEVER spill into the shared trash
-                # block (concurrent slots' junk could corrupt verify)
-                span = len(req.blocks) * bs
-                budget = min(L, span - req.seq_len,
-                             req.max_new_tokens - req.generated + 1)
-                d = [] if req.spec_disabled else \
-                    self._draft(req, max(0, budget - 1))
-                toks[s, 0] = self._last_tok[s]
-                if d:
-                    toks[s, 1:1 + len(d)] = d
-                lens[s] = 1 + len(d)
-                starts[s] = req.seq_len
-                drafts[s] = d
-            bt = self._block_tables.copy()
-        self._account_decode_pages(starts + lens)
+        active = [r for r in self._slots
+                  if r is not None and r.state == _DECODE]
+        if not active:
+            return
+        clock = self._clock
         jnp = self._jnp
-        t0w = time.time()
-        t0 = time.monotonic()
-        preds, self._cache = self._jit_verify(
-            self._params, jnp.asarray(toks), self._cache,
-            jnp.asarray(bt), jnp.asarray(starts), jnp.asarray(lens))
-        preds = np.asarray(preds)
+        with clock.phase("engine.decode.stage"):
+            with self._lock:
+                self._decode_steps += 1
+                self._occupancy[len(active)] += 1
+                if self._metrics is not None:
+                    try:
+                        self._metrics.serve_batch_occupancy.observe(
+                            len(active))
+                    except Exception:
+                        pass
+                toks = np.zeros((S, L), np.int32)
+                lens = np.zeros((S,), np.int32)
+                starts = np.zeros((S,), np.int32)
+                drafts: Dict[int, List[int]] = {}
+                for req in active:
+                    s = req.slot
+                    # cap drafts to the sequence's allocated block span
+                    # so speculative writes NEVER spill into the shared
+                    # trash block (concurrent slots' junk could corrupt
+                    # verify)
+                    span = len(req.blocks) * bs
+                    budget = min(L, span - req.seq_len,
+                                 req.max_new_tokens - req.generated + 1)
+                    d = [] if req.spec_disabled else \
+                        self._draft(req, max(0, budget - 1))
+                    toks[s, 0] = self._last_tok[s]
+                    if d:
+                        toks[s, 1:1 + len(d)] = d
+                    lens[s] = 1 + len(d)
+                    starts[s] = req.seq_len
+                    drafts[s] = d
+                bt = self._block_tables.copy()
+            self._account_decode_pages(starts + lens)
+            t0w = time.time()
+            t0 = time.monotonic()
+            toks_d, bt, starts_d, lens_d = (
+                jnp.asarray(toks), jnp.asarray(bt), jnp.asarray(starts),
+                jnp.asarray(lens))
+        with clock.phase("engine.decode.dispatch"):
+            preds, self._cache = self._jit_verify(
+                self._params, toks_d, self._cache, bt, starts_d, lens_d)
+            del toks_d, bt, starts_d, lens_d    # as in _prefill_one_chunk
+        with clock.phase("engine.decode.wait"):
+            preds = np.asarray(preds)
         self._decode_wall_s += time.monotonic() - t0
+        with clock.phase("engine.decode.emit"):
+            self._emit_verified(active, preds, drafts, t0w)
+
+    def _emit_verified(self, active: List[_Request], preds,
+                       drafts: Dict[int, List[int]],
+                       t0w: float) -> None:
+        """Each active request's accepted drafts and its bonus token
+        to its stream; the acceptance books."""
+        ec = self.config
         produced = 0
         now_w = time.time()
         with self._lock:
@@ -1734,7 +1833,8 @@ class LLMEngine:
                     if req.trace is not None:
                         req.trace.span(RT.SPEC_VERIFY, t0w, now_w,
                                        drafted=len(d),
-                                       accepted=accepted)
+                                       accepted=accepted,
+                                       tick=self._clock.tick_no)
                     ratio = accepted / len(d)
                     req.spec_ewma = ratio if req.spec_ewma is None \
                         else 0.8 * req.spec_ewma + 0.2 * ratio
@@ -1773,7 +1873,7 @@ class LLMEngine:
         req.tick_toks += 1
         if req.tick_toks >= self.config.trace_decode_tick:
             tr.span(RT.DECODE, req.tick_t0, now_w,
-                    tokens=req.tick_toks)
+                    tokens=req.tick_toks, tick=self._clock.tick_no)
             req.tick_t0, req.tick_toks = None, 0
 
     def _item(self, req: _Request, tok: int, logprob):
@@ -1820,7 +1920,8 @@ class LLMEngine:
         req.trace = None
         now = time.time()
         if req.tick_toks and req.tick_t0 is not None:
-            tr.span(RT.DECODE, req.tick_t0, now, tokens=req.tick_toks)
+            tr.span(RT.DECODE, req.tick_t0, now, tokens=req.tick_toks,
+                    tick=self._clock.tick_no)
             req.tick_t0, req.tick_toks = None, 0
         if err is not None:
             tr.span(RT.FAILED, now, None,
@@ -1832,6 +1933,23 @@ class LLMEngine:
             self._tracer.finish(tr)
 
     # ------------------------------------------------ metrics / events
+    def _first_token(self, req: _Request) -> None:
+        """The first token is out (``t_first_token`` stamped): book
+        where its time went (the ttft_* counters), then tell the gauges
+        and the trace."""
+        if not req.warmup:
+            split = self._ttft
+            split["ttft_requests"] += 1
+            split["ttft_s"] += req.t_first_token - req.t_submit
+            split["ttft_queue_s"] += req.t_slot - req.t_submit
+            split["ttft_prefill_wait_s"] += req.t_first_chunk - req.t_slot
+            split["ttft_prefill_s"] += \
+                req.t_first_token - req.t_first_chunk
+            split["ttft_prefill_ticks"] += \
+                self._clock.tick_no - req.tick_first_chunk + 1
+            split["ttft_prefill_chunks"] += req.n_chunks
+        self._record_ttft(req)
+
     def _record_ttft(self, req: _Request) -> None:
         if getattr(req, "warmup", False):
             # compile-only traffic: its TTFT is the jit wall, noise for

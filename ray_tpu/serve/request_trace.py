@@ -12,6 +12,9 @@ phase          meaning
 =============  =====================================================
 QUEUED         router enqueue -> engine admission (a decode slot won)
 ADMITTED       slot assignment incl. prefix-cache match / CoW forks
+PREFILL_WAIT   slot won -> its first chunk staged: behind the chunks of
+               requests admitted earlier (one chunk of one request a
+               tick)
 PREFILL        one chunked-prefill step (per chunk)
 KV_SHIP        disagg hand-off: finished prefill KV blocks in flight
                from the prefill replica to the chosen decode replica
@@ -53,6 +56,7 @@ from typing import Any, Dict, List, Optional
 # Canonical phase names. Terminal phases close the waterfall.
 QUEUED = "QUEUED"
 ADMITTED = "ADMITTED"
+PREFILL_WAIT = "PREFILL_WAIT"
 PREFILL = "PREFILL"
 KV_SHIP = "KV_SHIP"
 KV_ADOPT = "KV_ADOPT"
@@ -67,9 +71,9 @@ SHED = "SHED"
 TERMINAL_PHASES = frozenset({DONE, FAILED, SHED})
 
 #: Render/aggregation order for waterfalls and per-phase breakdowns.
-PHASE_ORDER = (QUEUED, ADMITTED, PREFILL, KV_SHIP, KV_ADOPT,
-               SPEC_VERIFY, DECODE, WEIGHT_SWAP, FIRST_TOKEN, DONE,
-               FAILED, SHED)
+PHASE_ORDER = (QUEUED, ADMITTED, PREFILL_WAIT, PREFILL, KV_SHIP,
+               KV_ADOPT, SPEC_VERIFY, DECODE, WEIGHT_SWAP, FIRST_TOKEN,
+               DONE, FAILED, SHED)
 
 #: Cap on spans buffered per request: a pathological 100k-token decode
 #: must not make its own trace unbounded. Oldest non-terminal spans are

@@ -15,14 +15,21 @@ runtime threads that context through task/actor-call submission
 built-in timeline show parent→child links across processes. On the
 executing side, :func:`task_execution_span` re-parents the task's span
 under the propagated remote context.
+
+:class:`PhaseClock` is the hot loops' own clock (the engine's tick, the
+train step): always-on counts and seconds per phase, a bounded ring of
+the newest spans, and a ``jax.profiler`` annotation around the same
+interval so that a running profiler session holds the span on the
+device trace's clock. :func:`clocks` is how readers find them.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 _enabled = False
 _lock = threading.Lock()
@@ -169,3 +176,152 @@ def task_execution_span(name: str, trace: Optional[tuple]
         return
     with tracer.start_as_current_span(name, context=parent_ctx):
         yield
+
+
+# ------------------------------------------------------------ phase clock
+#: newest spans a clock keeps (a few hundred ticks of a dozen phases)
+PHASE_RING = 4096
+
+_clocks: Dict[str, "PhaseClock"] = {}
+
+
+def clocks() -> Dict[str, "PhaseClock"]:
+    """owner -> the newest :class:`PhaseClock` registered under that
+    name in this process (``stats()``, the dashboard's stats and an
+    in-process reader find the loops' clocks here)."""
+    with _lock:
+        return dict(_clocks)
+
+
+class _Phase:
+    """One named phase of one clock: its totals, and the context
+    manager ``clock.phase(name)`` hands out (one object per name, so
+    entering a phase allocates nothing but its ring entry and, under a
+    profiler session, its annotation). A phase never nests inside
+    itself."""
+
+    __slots__ = ("clock", "name", "count", "seconds", "t0", "parent",
+                 "ann", "dispatch", "wait")
+
+    def __init__(self, clock: "PhaseClock", name: str):
+        self.clock, self.name = clock, name
+        self.count, self.seconds = 0, 0.0
+        self.t0, self.parent, self.ann = 0.0, None, None
+        self.dispatch = name.endswith(".dispatch")
+        self.wait = name.endswith(".wait")
+
+    def __enter__(self) -> "_Phase":
+        c = self.clock
+        stack = c._stack
+        self.parent = stack[-1] if stack else None
+        # with no profiler session running this is a flag test
+        if c._annotation.is_enabled():
+            if self.parent is None and c._step_annotation is not None:
+                self.ann = c._step_annotation(self.name,
+                                              step_num=c.tick_no)
+            else:
+                self.ann = c._annotation(self.name, tick=c.tick_no)
+            self.ann.__enter__()
+        stack.append(self.name)
+        self.t0 = t = time.perf_counter()
+        if self.parent is None:
+            c._root_t0 = c._idle_from = t
+        elif self.dispatch and c._idle_from is not None:
+            c.gap_s += t - c._idle_from
+            c._idle_from = None
+        return self
+
+    def __exit__(self, *exc) -> None:
+        c = self.clock
+        t1 = time.perf_counter()
+        c._stack.pop()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+            self.ann = None
+        if self.t0 < c._since:
+            return              # began before a reset: not in its books
+        self.count += 1
+        self.seconds += t1 - self.t0
+        c.ring.append((self.name, c.tick_no, self.t0, t1, self.parent))
+        if self.wait:
+            if c._root_t0 >= c._since:
+                c._idle_from = t1
+        elif self.parent is None and c._idle_from is not None:
+            c.gap_s += t1 - c._idle_from
+            c._idle_from = None
+
+
+class PhaseClock:
+    """The clock a hot loop owns. One thread drives it::
+
+        clock.tick()
+        with clock.phase("engine.tick"):
+            with clock.phase("engine.admit"):
+                ...
+
+    Always: per phase a count and a sum of seconds
+    (``time.perf_counter``), and a ring of the newest ``(name, tick, t0,
+    t1, parent)`` spans, ``parent`` being the name of the phase it ran
+    inside. Around the same interval a ``jax.profiler.TraceAnnotation``
+    carrying ``tick`` (``StepTraceAnnotation`` with ``step_num`` for the
+    outermost phase of a ``steps=True`` clock), so a profiler trace
+    holds the span in its host plane and joins the ring's entry by tick.
+
+    ``gap_s`` is the clock's account of the device having nothing
+    queued: inside an outermost phase, the time not between the start
+    of a ``*.dispatch`` phase and the end of the ``*.wait`` phase that
+    follows it.
+
+    Other threads may read ``totals()`` and ``spans()`` at any time.
+    """
+
+    def __init__(self, owner: str, steps: bool = False):
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        self.owner = owner
+        self.tick_no = 0
+        self.gap_s = 0.0
+        self.ring: "collections.deque[tuple]" = collections.deque(
+            maxlen=PHASE_RING)
+        self._phases: Dict[str, _Phase] = {}
+        self._stack: List[str] = []
+        self._idle_from: Optional[float] = None
+        self._root_t0 = self._since = 0.0
+        self._annotation = TraceAnnotation
+        self._step_annotation = StepTraceAnnotation if steps else None
+        with _lock:
+            _clocks[owner] = self
+
+    def tick(self) -> int:
+        """Start the next tick; every span until the next call carries
+        its number."""
+        self.tick_no += 1
+        return self.tick_no
+
+    def phase(self, name: str) -> _Phase:
+        p = self._phases.get(name)
+        if p is None:
+            p = self._phases[name] = _Phase(self, name)
+        return p
+
+    def totals(self) -> Dict[str, List[float]]:
+        """name -> [count, seconds] over every finished phase."""
+        return {name: [p.count, p.seconds]
+                for name, p in list(self._phases.items())}
+
+    def seconds(self, name: str) -> float:
+        p = self._phases.get(name)
+        return p.seconds if p is not None else 0.0
+
+    def spans(self) -> List[tuple]:
+        """The ring, oldest first: (name, tick, t0, t1, parent)."""
+        return list(self.ring)
+
+    def reset(self) -> None:
+        """Zero the totals and empty the ring (tick numbers go on). A
+        phase another thread is inside of stays out of the new books."""
+        self._since = time.perf_counter()
+        self._idle_from = None
+        for p in list(self._phases.values()):
+            p.count, p.seconds = 0, 0.0
+        self.gap_s = 0.0
+        self.ring.clear()
