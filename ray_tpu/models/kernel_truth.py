@@ -102,12 +102,15 @@ def paged_truth(*, batch: int, chunk: int, heads: int, kv_heads: int,
                 seed: int = 0) -> Dict[str, Any]:
     """Paged kernel against the XLA gather reference for ``batch``
     sequences of ``chunk`` new tokens each (``chunk=1`` is a decode
-    step) over a shuffled block pool. Lengths are ragged and mid-block
-    so length skipping and the in-page mask are both exercised."""
+    step) over a shuffled block pool, handed over as the step programs
+    hand it: the whole (here one-layer) pool with a layer index; that
+    the index picks the layer is :func:`cached_logits_truth`'s to show.
+    Lengths are ragged and mid-block so length skipping and the in-page
+    mask are both exercised."""
     rng = np.random.default_rng(seed)
     n_blocks = 1 + batch * table_len
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    pool = (n_blocks, kv_heads, block_size, head_dim)
+    pool = (1, n_blocks, kv_heads, block_size, head_dim)
     kc = jax.random.normal(ks[0], pool, dtype)
     vc = jax.random.normal(ks[1], pool, dtype)
     q = jax.random.normal(ks[2], (batch, chunk, heads, head_dim), dtype)
@@ -121,11 +124,11 @@ def paged_truth(*, batch: int, chunk: int, heads: int, kv_heads: int,
     pos = (lens - chunk)[:, None] + np.arange(chunk, dtype=np.int32)
     args = (q, kc, vc, jnp.asarray(bt), jnp.asarray(pos))
 
-    kernel = functools.partial(paged_attention, impl=impl)
+    kernel = functools.partial(paged_attention, layer=0, impl=impl)
     out = jax.jit(kernel)(*args, lens=jnp.asarray(lens))
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(functools.partial(
-            paged_attention, impl="reference"))(*args)
+            paged_attention, layer=0, impl="reference"))(*args)
     return _report(
         "paged_decode" if chunk == 1 else "paged_prefill_chunk", impl,
         _has_mosaic_call(kernel, *args), {"out": _rel_err(out, ref)},

@@ -631,11 +631,15 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 
 
 # ------------------------------------------------------- inference (KV)
-# The serving decode path: a paged KV cache ([num_blocks, kv_heads,
-# block_size, head_dim] per layer, block table per sequence) written by
-# chunked prefill and batched single-token decode steps. Both entry
-# points are shape-stable — jit them once at the engine's fixed
-# (batch, chunk, table) shapes and admission never recompiles.
+# The serving decode path: a paged KV cache (one pool
+# [n_layers, num_blocks, kv_heads, block_size, head_dim] for k and one
+# for v, block table per sequence) written by chunked prefill and
+# batched single-token decode steps. Both entry points are shape-stable
+# — jit them once at the engine's fixed (batch, chunk, table) shapes
+# and admission never recompiles — and neither slices, stacks or copies
+# the pool: the layer scan carries it whole, each layer scatters its new
+# rows into it and attends it by (layer, block). Jitted with the cache
+# donated, a step updates the caller's buffer in place.
 
 def init_kv_cache(config: TransformerConfig, num_blocks: int,
                   block_size: int) -> Dict[str, jnp.ndarray]:
@@ -643,22 +647,29 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` in the
     compute dtype — ``kv_heads`` ahead of ``block_size`` so one head's
     page is a contiguous ``(block_size, head_dim)`` tile, which is what
-    the Pallas kernel DMAs. Zero-filled; a zero key scores 0
-    pre-softmax, so reserved/trash blocks are numerically harmless."""
+    the Pallas kernel DMAs. :func:`prefill` and :func:`decode_step`
+    pass each pool WHOLE, with a layer index, to the write and to the
+    attention kernel; nothing takes a layer's slice of it. Zero-filled;
+    a zero key scores 0 pre-softmax, so reserved/trash blocks are
+    numerically harmless."""
     c = config
     shape = (c.n_layers, num_blocks, c.kv_heads, block_size, c.head_dim)
     return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
 
 
 @jax.named_scope("attn")
-def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
-                         block_tables, positions, write_mask, lens):
-    """Decode-path attention sublayer: project qkv for the new tokens,
-    rotate at their absolute positions, write k/v into the cache blocks,
-    then attend against the (now-updated) paged cache. ``lens`` is the
-    per-sequence live token count after this call's writes — the Pallas
-    kernel skips whole cache blocks past it. Returns
-    (attn_out, kc, vc)."""
+def _paged_attn_sublayer(c, h, lp, sin, cos, layout, layer, k_pool,
+                         v_pool, block_tables, positions, write_mask,
+                         lens):
+    """Decode-path attention sublayer of layer ``layer`` (an int32
+    scalar, traced by the layer scan): project qkv for the new tokens,
+    rotate at their absolute positions, scatter k/v into that layer's
+    pages of the WHOLE 5-D pools, then attend against the (now-updated)
+    pages by ``(layer, block)``. The pools come in and go out whole —
+    the scan's carry — so the write is one in-place scatter of the new
+    rows, not a copy of the layer. ``lens`` is the per-sequence live
+    token count after this call's writes — the Pallas kernel skips
+    whole cache blocks past it. Returns (attn_out, k_pool, v_pool)."""
     e = h.shape[-1]
     dt = c.dtype
 
@@ -671,18 +682,27 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
     q = apply_rotary(q, sin, cos, positions=positions, layout=layout)
     k = apply_rotary(k, sin, cos, positions=positions, layout=layout)
 
-    n_blocks, bs = kc.shape[0], kc.shape[2]
+    n_blocks, bs = k_pool.shape[1], k_pool.shape[3]
     with jax.named_scope("kv_write"):
         bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
         slot = positions % bs
         # invalid (padded) chunk positions scatter out of bounds ->
         # dropped
         bid = jnp.where(write_mask, bid, n_blocks)
-        # [N, KVH, bs, D] indexed (bid, :, slot): the two index arrays
-        # broadcast to (B, C) and lead the result, matching k's
-        # (B, C, KVH, D)
-        kc = kc.at[bid, :, slot].set(k.astype(kc.dtype), mode="drop")
-        vc = vc.at[bid, :, slot].set(v.astype(vc.dtype), mode="drop")
+        # [L, N, KVH, bs, D] indexed (layer, bid, head, slot): the
+        # index arrays broadcast to k's own (B, C, KVH) and each names
+        # one D-long row, the pool's minor-most dim. A window over
+        # (KVH, D) — at[layer, bid, :, slot] — writes the same rows but
+        # is not contiguous in this layout: the TPU compiler then keeps
+        # the carried pool with block_size ahead of kv_heads for the
+        # scatter and copies ALL of it into the kernel's layout in every
+        # layer (tests/ops/test_tpu_lowering.py compiles and looks)
+        bid, slot = bid[..., None], slot[..., None]
+        head = jnp.arange(c.kv_heads, dtype=jnp.int32)
+        k_pool = k_pool.at[layer, bid, head, slot].set(
+            k.astype(k_pool.dtype), mode="drop")
+        v_pool = v_pool.at[layer, bid, head, slot].set(
+            v.astype(v_pool.dtype), mode="drop")
 
     # h.shape[1] is static under jit: > 1 means a prefill chunk, whose
     # much larger query-row count can carry a bigger row block than the
@@ -691,12 +711,12 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, kc, vc,
         if (h.shape[1] > 1 and c.paged_block_r_prefill) \
         else c.paged_block_r
     with jax.named_scope("paged_attn"):
-        att = paged_attention(q, kc, vc, block_tables, positions,
-                              lens=lens, impl=c.paged_impl,
+        att = paged_attention(q, k_pool, v_pool, block_tables, positions,
+                              layer=layer, lens=lens, impl=c.paged_impl,
                               block_r=br or None)
     out = jnp.einsum("bshd,hde->bse", att,
                      lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
-    return out, kc, vc
+    return out, k_pool, v_pool
 
 
 def _forward_with_cache(c: TransformerConfig, params: Dict,
@@ -709,7 +729,12 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     (B, C) token ids at absolute ``positions`` -> (B, C, vocab) logits,
     writing each layer's k/v into the paged cache as it goes. ``lens``
     (B,) is each sequence's live token count including this call's
-    writes — the attention kernel's length-skipping bound."""
+    writes — the attention kernel's length-skipping bound.
+
+    The scan runs over ``(layers, layer index)`` and carries
+    ``(x, k_pool, v_pool)``: the pool is never among the scanned inputs
+    or outputs (those are sliced per layer and stacked into a new
+    buffer — a copy of the whole pool every step)."""
     if c.n_experts:
         raise NotImplementedError(
             "paged decode does not support MoE configs yet")
@@ -722,37 +747,39 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
 
-    def gptj_step(x, lp, kc, vc):
+    def gptj_step(x, lp, layer, kp, vp):
         h = layer_norm(x, lp["ln_scale"], lp["ln_bias"])
-        att, kc, vc = _paged_attn_sublayer(
-            c, h, lp, sin, cos, layout, kc, vc,
+        att, kp, vp = _paged_attn_sublayer(
+            c, h, lp, sin, cos, layout, layer, kp, vp,
             block_tables, positions, write_mask, lens)
         mlp, _ = _mlp_sublayer(c, h, lp)
-        return x + (att + mlp).astype(x.dtype), kc, vc
+        return x + (att + mlp).astype(x.dtype), kp, vp
 
-    def llama_step(x, lp, kc, vc):
+    def llama_step(x, lp, layer, kp, vp):
         h = rms_norm(x, lp["attn_norm"])
-        att, kc, vc = _paged_attn_sublayer(
-            c, h, lp, sin, cos, layout, kc, vc,
+        att, kp, vp = _paged_attn_sublayer(
+            c, h, lp, sin, cos, layout, layer, kp, vp,
             block_tables, positions, write_mask, lens)
         x = x + att.astype(x.dtype)
         h2 = rms_norm(x, lp["mlp_norm"]).astype(c.dtype)
         mlp, _ = _mlp_sublayer(c, h2, lp)
-        return x + mlp.astype(x.dtype), kc, vc
+        return x + mlp.astype(x.dtype), kp, vp
 
     step = gptj_step if c.block_style == "gptj" else llama_step
 
     def scan_fn(carry, per_layer):
-        lp, kc, vc = per_layer
+        x, kp, vp = carry
+        lp, layer = per_layer
         with jax.named_scope("layer"):
-            out, kc, vc = step(carry, lp, kc, vc)
-        return out, (kc, vc)
+            return step(x, lp, layer, kp, vp), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        scan_fn, x, (params["layers"], cache["k"], cache["v"]))
+    n_layers = cache["k"].shape[0]
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        scan_fn, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
 
     x = _final_norm(c, params, x)
-    return _lm_head(c, params, x), {"k": new_k, "v": new_v}
+    return _lm_head(c, params, x), {"k": k_pool, "v": v_pool}
 
 
 def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,

@@ -108,7 +108,7 @@ def _paged_unfit(q: jnp.ndarray, k_cache: jnp.ndarray) -> Optional[str]:
     """The shape rule a paged call breaks for the compiled kernel, or
     None: head_dim must tile the lanes and a page the sublanes."""
     from ray_tpu.ops.paged_flash import sublane_tile
-    d, bs = q.shape[-1], k_cache.shape[2]
+    d, bs = q.shape[-1], k_cache.shape[-2]
     tile = sublane_tile(k_cache.dtype)
     if d % 128:
         return f"head_dim {d} % 128 != 0"
@@ -120,6 +120,7 @@ def _paged_unfit(q: jnp.ndarray, k_cache: jnp.ndarray) -> Optional[str]:
 def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     v_cache: jnp.ndarray, block_tables: jnp.ndarray,
                     q_positions: jnp.ndarray, *,
+                    layer=None,
                     lens: Optional[jnp.ndarray] = None,
                     sm_scale: Optional[float] = None,
                     impl: str = "auto",
@@ -127,13 +128,20 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     """Attention of new-token queries against a paged KV cache.
 
     The serving decode/prefill primitive: keys and values live in a pool
-    of fixed-size blocks (``k_cache``/``v_cache`` of shape
-    ``[num_blocks, kv_heads, block_size, head_dim]``); each sequence owns
-    an ordered list of block ids (``block_tables[b, t]`` holds the block
-    storing absolute positions ``t*block_size .. t*block_size+bs-1`` of
-    sequence ``b``). Queries ``q[b, i]`` sit at absolute position
-    ``q_positions[b, i]`` and attend every cached position ``<= q_positions
-    [b, i]`` — causal by construction, so the SAME call serves batched
+    of fixed-size blocks. ``k_cache``/``v_cache`` are the WHOLE pool
+    ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` and
+    ``layer`` (an int32 scalar, traced inside a layer scan) names the
+    layer attended: every ``impl`` reads pages by ``(layer, block)``
+    out of the pool's own buffer, so a step program that carries the
+    pool through its layer scan never slices a layer out of it (a copy
+    of that layer, in and out, every step). ``layer=None`` takes one
+    layer's ``[num_blocks, kv_heads, block_size, head_dim]`` pool. Each
+    sequence owns an ordered list of block ids (``block_tables[b, t]``
+    holds the block storing absolute positions
+    ``t*block_size .. t*block_size+bs-1`` of sequence ``b``). Queries
+    ``q[b, i]`` sit at absolute position ``q_positions[b, i]`` and attend
+    every cached position ``<= q_positions[b, i]`` — causal by
+    construction, so the SAME call serves batched
     single-token decode (``q`` of shape ``[B, 1, H, D]``) and chunked
     prefill (``[B, C, H, D]``, the chunk's own keys having been written to
     the cache first). GQA caches store ``kv_heads < num_heads``; queries
@@ -154,23 +162,26 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     O(B * C * T * block_size) regardless of true lengths; keep
     ``block_tables`` sized to the serving window, not the model max.
     """
-    n_blocks, kvh, bs, d = k_cache.shape
+    from ray_tpu.ops.paged_flash import (
+        layered_pool, paged_flash_attention)
+    k_cache, v_cache, layer = layered_pool(k_cache, v_cache, layer)
+    kvh, bs, d = k_cache.shape[2:]
     b, c, h, _ = q.shape
     t = block_tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     choice = _resolve("paged", impl, "kernel", _paged_unfit(q, k_cache))
     if choice != "reference":
-        from ray_tpu.ops.paged_flash import paged_flash_attention
         if lens is None:
             lens = jnp.max(q_positions, axis=1).astype(jnp.int32) + 1
         return paged_flash_attention(
             q, k_cache, v_cache, block_tables, q_positions, lens,
-            sm_scale=sm_scale, block_r=block_r,
+            layer=layer, sm_scale=sm_scale, block_r=block_r,
             interpret=choice == "interpret")
-    # Gather each sequence's blocks: [B, T, KVH, bs, D] -> [B, K, KVH, D]
+    # Gather each sequence's blocks out of the layer, one gather on the
+    # 5-D pool: [B, T, KVH, bs, D] -> [B, K, KVH, D]
     def gather(cache):
-        return jnp.take(cache, block_tables, axis=0) \
+        return cache[layer[0], block_tables] \
             .transpose(0, 1, 3, 2, 4).reshape(b, t * bs, kvh, d)
     k, v = gather(k_cache), gather(v_cache)
     # key slot j of the gathered view holds absolute position j

@@ -2,8 +2,11 @@
 fast path.
 
 The serving hot loop attends a handful of new-token queries per
-sequence against a paged KV cache (``[num_blocks, kv_heads, block_size,
-head_dim]`` pool + per-sequence block tables). The pure-XLA reference
+sequence against a paged KV cache (``[n_layers, num_blocks, kv_heads,
+block_size, head_dim]`` pool + per-sequence block tables). The pool is
+handed over WHOLE with a layer index, never sliced: a step program's
+layer scan carries it and writes it in place, and a slice per layer
+would copy that layer in and out of every step. The pure-XLA reference
 (:func:`ray_tpu.ops.attention.paged_attention`) gathers the WHOLE
 table window every step — work is O(B · T · block_size) regardless of
 how many tokens a sequence actually holds. This kernel makes decode
@@ -12,10 +15,10 @@ work proportional to **live tokens**:
 - grid ``(batch, kv_head_groups, q_row_blocks, table_slots)`` with the
   table-slot axis innermost so the online-softmax accumulators
   (m, l, acc in f32 VMEM scratch) persist across a sequence's pages;
-- the block table and per-sequence ``lens`` ride **scalar prefetch**
-  (:class:`pltpu.PrefetchScalarGridSpec`): the k/v BlockSpec index
-  maps read the table to DMA exactly the physical page a grid step
-  needs;
+- the block table, per-sequence ``lens`` and the layer index ride
+  **scalar prefetch** (:class:`pltpu.PrefetchScalarGridSpec`): the k/v
+  BlockSpec index maps read them to DMA exactly the physical page
+  ``(layer, block)`` a grid step needs, out of the pool's own buffer;
 - table slots past ``ceil(lens[b] / block_size)`` are **skipped** —
   their index map clamps to the last live page (an unchanged block
   index issues no new copy) and ``pl.when`` skips the matmuls, so a
@@ -84,13 +87,14 @@ def paged_work_pages(lens, block_size: int):
         if hasattr(lens, "clip") else max(-(-lens // block_size), 1)
 
 
-def _paged_kernel(bt_ref, lens_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
-                  m_s, l_s, acc_s, *, bs: int, hb: int, sm_scale: float):
+def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_ref,
+                  v_ref, o_ref, m_s, l_s, acc_s, *, bs: int, hb: int,
+                  sm_scale: float):
     """One (batch b, kv head group, row block r, table slot t) step:
     fold page t of sequence b into the row block's online softmax, one
-    kv head of the group at a time. Scalar refs (bt, lens) land in SMEM
-    ahead of the body — the same values the index maps used to pick
-    this step's page."""
+    kv head of the group at a time. Scalar refs (bt, lens, layer) land
+    in SMEM ahead of the body — the same values the index maps used to
+    pick this step's page (the body itself never needs the layer)."""
     b = pl.program_id(0)
     t = pl.program_id(3)
     nt = pl.num_programs(3)
@@ -151,11 +155,27 @@ def _heads_per_step(kv_heads: int, block_r: int) -> int:
     return hb
 
 
+def layered_pool(k_cache: jnp.ndarray, v_cache: jnp.ndarray, layer):
+    """``(k_pool, v_pool, layer)`` with the pools 5-D
+    ``[L, N, KVH, bs, D]`` and ``layer`` a ``(1,)`` int32 array: a
+    4-D one-layer pool (``layer=None``) becomes layer 0 of a one-layer
+    pool — a reshape, not a copy."""
+    if (layer is None) != (k_cache.ndim == 4):
+        raise ValueError(
+            f"a {k_cache.ndim}-D kv pool with layer={layer!r}: pass the "
+            f"whole [L, N, KVH, bs, D] pool with its layer index, or one "
+            f"layer's [N, KVH, bs, D] pool without")
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+    return k_cache, v_cache, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                           v_cache: jnp.ndarray,
                           block_tables: jnp.ndarray,
                           q_positions: jnp.ndarray,
                           lens: jnp.ndarray, *,
+                          layer=None,
                           sm_scale: Optional[float] = None,
                           block_r: Optional[int] = None,
                           interpret: bool = False) -> jnp.ndarray:
@@ -163,16 +183,22 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     Same contract as the XLA reference
     (:func:`ray_tpu.ops.attention.paged_attention`): ``q`` is
-    ``[B, C, H, D]`` at absolute ``q_positions [B, C]``, caches are
-    ``[N, KVH, bs, D]``, ``block_tables [B, T]``. ``lens [B]`` is the
-    number of LIVE cached positions per sequence (after this step's
-    writes); table slots past ``ceil(lens/bs)`` are skipped entirely.
-    Rows whose position ≥ ``lens[b]`` (padded prefill tail) attend only
-    live keys — their outputs are the caller's to discard, exactly as
-    with the reference path.
+    ``[B, C, H, D]`` at absolute ``q_positions [B, C]``,
+    ``block_tables [B, T]``. The caches are the WHOLE pool
+    ``[L, N, KVH, bs, D]`` with ``layer`` (an int32 scalar, traced in a
+    layer scan) naming the layer to attend — the kernel DMAs pages
+    ``(layer, block)`` straight out of that buffer, so the caller never
+    slices (= copies) a layer out of a pool it carries and updates in
+    place. ``layer=None`` takes one layer's ``[N, KVH, bs, D]`` pool.
+    ``lens [B]`` is the number of LIVE cached positions per sequence
+    (after this step's writes); table slots past ``ceil(lens/bs)`` are
+    skipped entirely. Rows whose position ≥ ``lens[b]`` (padded prefill
+    tail) attend only live keys — their outputs are the caller's to
+    discard, exactly as with the reference path.
     """
+    k_cache, v_cache, layer = layered_pool(k_cache, v_cache, layer)
     b, c, h, d = q.shape
-    n_blocks, g, bs, _ = k_cache.shape
+    g, bs = k_cache.shape[2:4]
     t = block_tables.shape[1]
     if h % g:
         raise ValueError(f"n_heads {h} not divisible by kv_heads {g}")
@@ -204,26 +230,29 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     def _pages(ln):
         return jnp.maximum(pl.cdiv(ln, bs), 1)
 
-    def q_map(b_, g_, r_, t_, bt, ln):
+    def q_map(b_, g_, r_, t_, bt, ln, ly):
         return (b_, g_, r_, 0)
 
-    def pos_map(b_, g_, r_, t_, bt, ln):
+    def pos_map(b_, g_, r_, t_, bt, ln, ly):
         return (b_, r_, 0)
 
-    def kv_map(b_, g_, r_, t_, bt, ln):
+    def kv_map(b_, g_, r_, t_, bt, ln, ly):
         # slots past the live pages revisit the last live page: the
         # unchanged block index issues no fresh DMA
         tt = jnp.minimum(t_, _pages(ln[b_]) - 1)
-        return (bt[b_, tt], g_, 0, 0)
+        return (ly[0], bt[b_, tt], g_, 0, 0)
 
+    # the layer dim is squeezed: the body sees the same (1, hb, bs, d)
+    # page of one layer it always did
+    page = pl.BlockSpec((None, 1, hb, bs, d), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, g // hb, nr, t),
         in_specs=[
             pl.BlockSpec((1, hb, block_r, d), q_map),
             pl.BlockSpec((1, block_r, 1), pos_map),
-            pl.BlockSpec((1, hb, bs, d), kv_map),
-            pl.BlockSpec((1, hb, bs, d), kv_map),
+            page,
+            page,
         ],
         out_specs=pl.BlockSpec((1, hb, block_r, d), q_map),
         scratch_shapes=[
@@ -242,7 +271,7 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                                  "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), layer,
       qg, pos_rows, k_cache, v_cache)
     out = out[:, :, :rows, :].reshape(b, g, c, rep, d) \
         .transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)

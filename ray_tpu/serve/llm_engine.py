@@ -292,7 +292,12 @@ class LLMEngine:
                                      ec.kv_block_size, reserved=(0,))
 
         # jit once at the fixed shapes; caches are donated so XLA
-        # updates them in place step over step. With capture_logprobs
+        # updates them in place step over step: the trunk carries the
+        # pool whole through its layer scan, scatters the new rows into
+        # it and hands it whole to the paged kernel, so the donated
+        # buffer is the output buffer and nothing copies a layer of it
+        # (tests/ops/test_tpu_lowering.py reads that off the programs
+        # the TPU compiler builds). With capture_logprobs
         # the same programs also return the selected token's logprob
         # (greedy argmax is unchanged — the extra output is the RLHF
         # rollout payload, not a sampling change).

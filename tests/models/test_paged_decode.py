@@ -5,6 +5,8 @@ block styles, with GQA, and across uneven last blocks. This is the
 correctness contract the serving engine is built on: if it holds, the
 engine can admit/evict/interleave freely without touching model code."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -13,7 +15,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import (TransformerConfig, decode_step,
                             init_kv_cache, init_params, prefill)
-from ray_tpu.models.transformer import apply
+from ray_tpu.models.transformer import _forward_with_cache, apply
 from ray_tpu.ops import attention_reference, paged_attention
 
 pytestmark = pytest.mark.serve_llm
@@ -231,3 +233,185 @@ def test_moe_decode_unsupported():
     with pytest.raises(NotImplementedError):
         decode_step(cfg, params, jnp.zeros((1,), jnp.int32), cache, bt,
                     jnp.zeros((1,), jnp.int32))
+
+
+# ------------------------------------------------ the pool is carried whole
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_scans(sub))
+    return found
+
+
+@pytest.mark.parametrize("entry", ["decode_step", "prefill"])
+def test_layer_scan_carries_the_pool_whole(entry):
+    """The pool rides the layer scan's CARRY: nothing with a page's
+    dimensions is among the scanned inputs or the stacked outputs (which
+    XLA slices per layer and stacks into a new buffer: a copy of the
+    whole pool every step), nor among the closed-over constants. With
+    the cache donated that is what lets XLA update it in place."""
+    cfg = _cfg(n_layers=3)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    cache = init_kv_cache(cfg, num_blocks=9, block_size=4)
+    bt, _ = _block_tables(2, 4)
+    if entry == "decode_step":
+        jaxpr = jax.make_jaxpr(
+            lambda p, c: decode_step(cfg, p, jnp.zeros((2,), jnp.int32),
+                                     c, bt, jnp.full((2,), 5, jnp.int32))
+        )(params, cache)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda p, c: prefill(cfg, p, jnp.zeros((2, 3), jnp.int32), c,
+                                 bt, jnp.zeros((2,), jnp.int32),
+                                 jnp.full((2,), 3, jnp.int32))
+        )(params, cache)
+    scans = _scans(jaxpr.jaxpr)
+    assert len(scans) == 1
+    scan, = scans
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    pool, page = cache["k"].shape, cache["k"].shape[1:]
+    consts = [v.aval.shape for v in scan.invars[:n_consts]]
+    carry = [v.aval.shape for v in scan.invars[n_consts:n_consts + n_carry]]
+    xs = [v.aval.shape for v in scan.invars[n_consts + n_carry:]]
+    ys = [v.aval.shape for v in scan.outvars[n_carry:]]
+    assert carry.count(pool) == 2           # k and v
+    assert [v.aval.shape for v in scan.outvars[:n_carry]].count(pool) == 2
+    for shape in consts + xs + ys:
+        assert shape[-4:] != page, shape
+
+
+def _old_trunk(c):
+    """What the trunk computed before the pool was carried, as a plain
+    loop: slice each layer's 4-D pool out, scatter the new rows into
+    the slice, attend the slice, restack the slices into a new pool.
+    Head, layer body and tail are each one compiled program, as the
+    scan's body is, so the arithmetic fuses alike and bits can be
+    compared."""
+    from ray_tpu.models import transformer as T
+    layout = "gptj" if c.block_style == "gptj" else "neox"
+    e, dt = c.d_model, c.dtype
+
+    @jax.jit
+    def one_layer(x, lp, kc, vc, bt, positions, write_mask, lens):
+        bs = kc.shape[2]
+        sin, cos = T.rotary_table(
+            bt.shape[1] * bs,
+            c.rotary_dim if c.block_style == "gptj" else c.head_dim,
+            c.rope_base)
+        h = T.layer_norm(x, lp["ln_scale"], lp["ln_bias"]) \
+            if c.block_style == "gptj" else T.rms_norm(x, lp["attn_norm"])
+
+        def proj(w, n):
+            return jnp.einsum("bse,ehd->bshd", h.astype(dt),
+                              w.reshape(e, n, -1).astype(dt))
+        q = T.apply_rotary(proj(lp["wq"], c.n_heads), sin, cos,
+                           positions=positions, layout=layout)
+        k = T.apply_rotary(proj(lp["wk"], c.kv_heads), sin, cos,
+                           positions=positions, layout=layout)
+        v = proj(lp["wv"], c.kv_heads)
+        bid = jnp.take_along_axis(bt, positions // bs, axis=1)
+        bid = jnp.where(write_mask, bid, kc.shape[0])
+        slot = positions % bs
+        kc = kc.at[bid, :, slot].set(k.astype(kc.dtype), mode="drop")
+        vc = vc.at[bid, :, slot].set(v.astype(vc.dtype), mode="drop")
+        att = paged_attention(q, kc, vc, bt, positions, lens=lens,
+                              impl=c.paged_impl)
+        att = jnp.einsum(
+            "bshd,hde->bse", att,
+            lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
+        if c.block_style == "gptj":
+            mlp, _ = T._mlp_sublayer(c, h, lp)
+            return x + (att + mlp).astype(x.dtype), kc, vc
+        x = x + att.astype(x.dtype)
+        h2 = T.rms_norm(x, lp["mlp_norm"]).astype(dt)
+        mlp, _ = T._mlp_sublayer(c, h2, lp)
+        return x + mlp.astype(x.dtype), kc, vc
+
+    embed = jax.jit(lambda emb, ids: jnp.take(emb, ids, axis=0).astype(dt))
+    tail = jax.jit(lambda p, x: T._lm_head(c, p, T._final_norm(c, p, x)))
+
+    def forward(params, ids, cache, bt, positions, write_mask, lens):
+        x = embed(params["embed"], ids)
+        new_k, new_v = [], []
+        for layer in range(c.n_layers):
+            x, kc, vc = one_layer(
+                x, jax.tree.map(lambda a: a[layer], params["layers"]),
+                cache["k"][layer], cache["v"][layer], bt, positions,
+                write_mask, lens)
+            new_k.append(kc)
+            new_v.append(vc)
+        return tail(params, x), \
+            {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    return forward
+
+
+@pytest.mark.parametrize("impl", ["reference", "interpret"])
+@pytest.mark.parametrize("style,kv_heads", [
+    ("gptj", None), ("gptj", 2), ("llama", None), ("llama", 2)])
+def test_carried_pool_equals_sliced_and_restacked_pool(style, kv_heads,
+                                                       impl):
+    """Two prefill chunks (the second with a padded tail, one slot idle
+    throughout) then three decode steps: logits and the returned pool
+    equal, bit for bit, the per-layer slice / write / restack loop the
+    trunk used to be. The pool starts as noise, so a write that strays
+    shows: masked positions and the idle slot change no page but the
+    trash block 0."""
+    cfg = _cfg(block_style=style, n_kv_heads=kv_heads, n_layers=3,
+               paged_impl=impl)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    bs, T, chunk = 4, 4, 4
+    # slots 0 and 1 own blocks 1..8; slot 2 is idle: its table is the
+    # engine's all-trash row; blocks 9 and 10 belong to nobody
+    bt = np.zeros((3, T), np.int32)
+    bt[:2] = np.arange(1, 1 + 2 * T).reshape(2, T)
+    bt = jnp.asarray(bt)
+    shape = init_kv_cache(cfg, num_blocks=11, block_size=bs)["k"].shape
+    k0, v0 = jax.random.normal(jax.random.PRNGKey(5), (2,) + shape)
+    first = {"k": k0, "v": v0}
+    prompt = np.array([7, 5, 0])                  # tokens per slot
+    ids = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (3, 12), 0, cfg.vocab_size))
+
+    def run(forward):
+        cache, logits = dict(first), []
+        for start in (0, chunk):
+            lens = np.clip(prompt - start, 0, chunk).astype(np.int32)
+            start_pos = jnp.full((3,), start, jnp.int32)
+            positions = start_pos[:, None] + jnp.arange(chunk,
+                                                        dtype=jnp.int32)
+            mask = jnp.arange(chunk)[None, :] < jnp.asarray(lens)[:, None]
+            out, cache = forward(
+                params, jnp.asarray(ids[:, start:start + chunk]), cache,
+                bt, positions, mask, start_pos + jnp.asarray(lens))
+            logits.append(out)
+        seq = prompt.astype(np.int32)
+        for i in range(3):
+            toks = jnp.asarray(ids[np.arange(3), seq])[:, None]
+            out, cache = forward(
+                params, toks, cache, bt, jnp.asarray(seq)[:, None],
+                jnp.ones((3, 1), bool), jnp.asarray(seq) + 1)
+            logits.append(out)
+            seq = seq + np.array([1, 1, 0], np.int32)   # idle stays at 0
+        return logits, cache
+
+    new_logits, new_cache = run(jax.jit(
+        functools.partial(_forward_with_cache, cfg)))
+    old_logits, old_cache = run(_old_trunk(cfg))
+    for new, old in zip(new_logits, old_logits):
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+    for name in ("k", "v"):
+        got = np.asarray(new_cache[name])
+        np.testing.assert_array_equal(got, np.asarray(old_cache[name]))
+        before = np.asarray(first[name])
+        # nobody's pages keep their noise; so do the rows of owned
+        # pages past each sequence's length (7+3 and 5+3 tokens)
+        np.testing.assert_array_equal(got[:, 9:], before[:, 9:])
+        np.testing.assert_array_equal(got[:, 3, :, 2:], before[:, 3, :, 2:])
+        np.testing.assert_array_equal(got[:, 4], before[:, 4])
+        np.testing.assert_array_equal(got[:, 7:9], before[:, 7:9])
+        # the live rows were written, in every layer
+        assert (got[:, 1:3] != before[:, 1:3]).all(axis=(1, 2, 3, 4)).all()

@@ -366,3 +366,53 @@ def test_flash_disk_cache_ignores_foreign_paged_keys(tmp_path,
     monkeypatch.setattr(fa, "_AUTOTUNE_CACHE", {})
     fa._load_disk_cache()
     assert fa._AUTOTUNE_CACHE == {("cpu", 128, 64, True): (256, 512)}
+
+
+@pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_whole_pool_with_layer_index_equals_that_layers_slice(layer, H,
+                                                              KVH):
+    """The wrapper takes the whole ``[L, N, KVH, bs, D]`` pool and a
+    layer index (traced, as a layer scan hands it over) and reads the
+    pages ``(layer, block)``: bit for bit the call on that layer's own
+    4-D slice, for the first and the last layer, kernel and reference."""
+    L, B, D, bs, T, C = 3, 2, 8, 4, 3, 2
+    rng = np.random.default_rng(11)
+    kp = jnp.asarray(rng.normal(size=(L, 1 + B * T, KVH, bs, D))
+                     .astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=kp.shape).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(B, C, H, D)).astype(np.float32))
+    bt = jnp.asarray(rng.permutation(np.arange(1, 1 + B * T))
+                     .astype(np.int32).reshape(B, T))
+    pos = jnp.asarray(np.array([[5, 6], [9, 10]], np.int32))
+    lens = jnp.asarray(np.array([7, 11], np.int32))
+
+    whole = jax.jit(lambda ly: paged_flash_attention(
+        q, kp, vp, bt, pos, lens, layer=ly, interpret=True))(
+            jnp.int32(layer))
+    sliced = paged_flash_attention(q, kp[layer], vp[layer], bt, pos, lens,
+                                   interpret=True)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(sliced))
+    for impl in ("interpret", "reference"):
+        whole = jax.jit(lambda ly: paged_attention(
+            q, kp, vp, bt, pos, layer=ly, lens=lens, impl=impl))(
+                jnp.int32(layer))
+        sliced = paged_attention(q, kp[layer], vp[layer], bt, pos,
+                                 lens=lens, impl=impl)
+        np.testing.assert_array_equal(np.asarray(whole),
+                                      np.asarray(sliced))
+    # the other layers are different data: the index is not ignored
+    other = paged_attention(q, kp[1], vp[1], bt, pos, lens=lens,
+                            impl="reference")
+    assert not np.array_equal(np.asarray(whole), np.asarray(other))
+
+
+def test_pool_rank_and_layer_must_agree():
+    kp = jnp.zeros((2, 3, 1, 4, 8))
+    q, bt = jnp.zeros((1, 1, 1, 8)), jnp.ones((1, 2), jnp.int32)
+    pos = jnp.zeros((1, 1), jnp.int32)
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp, kp, bt, pos, impl="reference")
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp[0], kp[0], bt, pos, layer=0,
+                        impl="reference")

@@ -3,9 +3,14 @@ lowering at the shapes ``chip_smoke.py`` runs (GPT-J: 16 heads x 256,
 bf16). The lowering — block shapes against the (8, 128) tiling rule,
 supported ops — needs no chip:
 ``jit(f).trace(*shapes).lower(lowering_platforms=("tpu",))``. The Mosaic
-compile proper and the numerics are the chip run's to prove."""
+compile proper and the numerics are the chip run's to prove — except
+the serving step programs' handling of the KV pool, which the TPU
+compiler itself is asked about (a described v5e, nothing attached):
+whether a step copies the pool is decided by XLA's layout assignment
+and buffer aliasing, and only the compiled program shows it."""
 
 import functools
+import re
 
 import pytest
 
@@ -45,6 +50,20 @@ def test_paged_kernel_lowers_for_tpu(batch, chunk, kv_heads):
     assert "tpu_custom_call" in text
 
 
+def test_paged_kernel_lowers_for_tpu_on_the_whole_pool():
+    """The serving form: the 5-D pool whole, the layer index traced and
+    riding the scalar prefetch."""
+    t = WINDOW // BLOCK
+    pool = _s((6, 1 + SLOTS * t, HEADS, BLOCK, HEAD_DIM))
+    text = _lower_for_tpu(
+        lambda q, k, v, bt, pos, lens, layer: paged_flash_attention(
+            q, k, v, bt, pos, lens, layer=layer),
+        _s((SLOTS, 1, HEADS, HEAD_DIM)), pool, pool,
+        _s((SLOTS, t), jnp.int32), _s((SLOTS, 1), jnp.int32),
+        _s((SLOTS,), jnp.int32), _s((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_flash_forward_and_backward_lower_for_tpu_at_head_dim_256():
     q = _s((1, HEADS, SEQ, HEAD_DIM))
     attn = functools.partial(flash_attention, causal=True,
@@ -78,3 +97,78 @@ def test_old_page_layout_would_not_lower():
 
     with pytest.raises(ValueError, match="divisible by 8 and 128"):
         _lower_for_tpu(old_layout, _s((4, BLOCK, HEADS, HEAD_DIM)))
+
+
+# ------------------------------------------ the compiled step programs
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("entry", ["decode_step", "prefill"])
+def test_compiled_step_updates_the_donated_pool_in_place(
+        entry, one_chip, monkeypatch):
+    """What "caches are donated so XLA updates them in place" rests on
+    (serve/llm_engine.py): in the step program the TPU compiler builds,
+    the pool is an aliased input/output, it is handed to the paged
+    kernel, and NO other op produces an array with a page's dimensions —
+    no copy into another layout for the scatter, no slice or
+    update-slice of a layer. GQA at head_dim 128, two layers."""
+    from ray_tpu.models import (TransformerConfig, decode_step,
+                                init_kv_cache, init_params, prefill)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = TransformerConfig(
+            vocab_size=512, d_model=512, n_layers=2, n_heads=4,
+            n_kv_heads=2, head_dim=128, d_ff=1024, max_seq_len=256,
+            block_style="llama", dtype=jnp.bfloat16, remat_policy="none",
+            paged_impl="kernel")
+        # 2049 pages: a pool too large for the compiler to park in fast
+        # memory, as a tiny one is (slices and copies of another kind)
+        slots, table, blocks, chunk = 8, 16, 2049, 64
+
+        def shaped(tree):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip), tree)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                        sharding=one_chip)
+        params = shaped(jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0))))
+        cache = shaped(jax.eval_shape(
+            lambda: init_kv_cache(cfg, blocks, BLOCK)))
+        if entry == "decode_step":
+            args = (params, i32(slots), cache, i32(slots, table),
+                    i32(slots))
+            fn = functools.partial(decode_step, cfg)
+        else:
+            args = (params, i32(1, chunk), cache, i32(1, table), i32(1),
+                    i32(1))
+            fn = functools.partial(prefill, cfg)
+        text = jax.jit(fn, donate_argnums=(2,)).lower(*args) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in text
+    assert text.count("may-alias") + text.count("must-alias") >= 2
+    page = f"{blocks},{cfg.kv_heads},{BLOCK},{cfg.head_dim}]"
+    made = re.findall(
+        r"= bf16\[(?:\d+,)?" + re.escape(page) + r"\S* ([\w\-]+)\(", text)
+    moved = [op for op in made if op not in (
+        "parameter", "get-tuple-element", "bitcast", "scatter", "fusion")]
+    assert not moved, moved
+    # the two scatters (k, v), alone or as the root of a fusion
+    assert made.count("scatter") == 2
