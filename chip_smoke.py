@@ -62,8 +62,9 @@ DEPTH_WHY = {
              "two-layer step is 15.6 GiB of a 15.75 GiB chip",
     "train4": "eight layers (2.02B params, 30.2 GiB of training state) "
               "need the fsdp=4 sharding and fit at 11.4 GiB a chip",
-    "serve": "the engine holds f32 weights and XLA keeps a bf16 copy "
-             "while a step runs: 6.5 GiB at four layers with the KV pool",
+    "serve": "sized when the engine held f32 weights beside XLA's bf16 "
+             "copy; it now holds bf16 alone (1.22B params, 2.3 GiB at "
+             "four layers): more would fit, four keep the phase short",
 }
 DEPTH_WHY["serve4"] = DEPTH_WHY["serve"]
 TRAIN_BATCH = {"train": 2, "train4": 4}

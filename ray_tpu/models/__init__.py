@@ -21,6 +21,7 @@ from ray_tpu.models.transformer import (
     lm_loss,
     hidden_states,
     init_params,
+    inference_params,
     init_kv_cache,
     prefill,
     decode_step,
@@ -41,6 +42,7 @@ __all__ = [
     "lm_loss",
     "hidden_states",
     "init_params",
+    "inference_params",
     "init_kv_cache",
     "prefill",
     "decode_step",

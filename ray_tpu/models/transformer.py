@@ -18,9 +18,13 @@ Design (TPU-first, not a port):
   with tuples of logical names ("embed", "mlp", "heads", "vocab", …);
   ``parallel.sharding.ShardingRules`` maps those to mesh axes, so DP /
   FSDP / TP / SP are rule-table changes, not model changes.
-- master params live in f32; ``config.dtype`` (bf16 on TPU) is the
-  compute dtype, cast at use sites so the MXU sees bf16 while layernorm
-  statistics and the softmax stay f32 (ops layer contract).
+- TRAINING's master params live in f32; ``config.dtype`` (bf16 on TPU)
+  is the compute dtype, cast at use sites so the MXU sees bf16 while
+  layernorm statistics and the softmax stay f32 (ops layer contract).
+  The serving engine holds no f32 masters: it takes its tree through
+  :func:`inference_params` (every matmul/lookup leaf already in
+  ``config.dtype``, norm leaves f32), on which the same use-site casts
+  are no-ops, so no step program casts a weight.
 - rematerialization is a named policy (``remat_policy``), not a bool:
   ``"dots"`` (default) saves projection/MLP matmul outputs and the
   attention output (``checkpoint_name``) while recomputing elementwise
@@ -149,19 +153,34 @@ class TransformerConfig:
 
 
 # ------------------------------------------------------------------ init
-def _dense_init(key, shape, scale=0.02):
-    return scale * jax.random.normal(key, shape, jnp.float32)
+# scaled where it lies: beside the draw, a second f32 buffer of a stacked
+# leaf's size would set the peak of an engine's whole life
+_scale_in_place = jax.jit(lambda w, scale: scale * w, donate_argnums=(0,))
 
 
-def init_params(config: TransformerConfig, key) -> Dict:
+def _dense_init(key, shape, scale=0.02, dtype=jnp.float32):
+    w = _scale_in_place(jax.random.normal(key, shape, jnp.float32), scale)
+    return w.astype(dtype)          # f32: the array itself
+
+
+def init_params(config: TransformerConfig, key,
+                dtype=jnp.float32) -> Dict:
+    """Seeded initial parameters: float32 masters by default (training).
+    ``dtype`` is what the randomly initialised leaves — the embedding
+    and every matmul weight, all but a sliver of the tree — are STORED
+    in: each is drawn in f32 and rounded as it is made, so a serving
+    engine asking for ``config.dtype`` never holds an f32 tree
+    (:func:`inference_params` then casts the few bias leaves left). The
+    bits are those of casting the f32 tree afterwards."""
     c = config
     keys = jax.random.split(key, 9)
     h = c.n_heads * c.head_dim
     kvh = c.kv_heads * c.head_dim
     L = c.n_layers
+    dense = functools.partial(_dense_init, dtype=dtype)
 
     def stack(k, shape, scale=0.02):
-        return _dense_init(k, (L,) + shape, scale)
+        return dense(k, (L,) + shape, scale)
 
     out_scale = 0.02 / (2 * L) ** 0.5    # scaled residual-out init
     layers: Dict[str, jnp.ndarray] = {
@@ -183,14 +202,14 @@ def init_params(config: TransformerConfig, key) -> Dict:
                 "attn_norm": jnp.ones((L, c.d_model), jnp.float32),
                 "mlp_norm": jnp.ones((L, c.d_model), jnp.float32)})
             final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
-            head = {"w": _dense_init(keys[8], (c.d_model, c.vocab_size))}
+            head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
         else:
             layers.update({
                 "ln_scale": jnp.ones((L, c.d_model), jnp.float32),
                 "ln_bias": jnp.zeros((L, c.d_model), jnp.float32)})
             final = {"scale": jnp.ones((c.d_model,), jnp.float32),
                      "bias": jnp.zeros((c.d_model,), jnp.float32)}
-            head = {"w": _dense_init(keys[8], (c.d_model, c.vocab_size)),
+            head = {"w": dense(keys[8], (c.d_model, c.vocab_size)),
                     "b": jnp.zeros((c.vocab_size,), jnp.float32)}
     elif c.block_style == "llama":
         layers.update({
@@ -201,7 +220,7 @@ def init_params(config: TransformerConfig, key) -> Dict:
             "mlp_norm": jnp.ones((L, c.d_model), jnp.float32),
         })
         final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
-        head = {"w": _dense_init(keys[8], (c.d_model, c.vocab_size))}
+        head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
     else:
         layers.update({
             "fc_in": stack(keys[4], (c.d_model, c.d_ff)),
@@ -213,11 +232,11 @@ def init_params(config: TransformerConfig, key) -> Dict:
         })
         final = {"scale": jnp.ones((c.d_model,), jnp.float32),
                  "bias": jnp.zeros((c.d_model,), jnp.float32)}
-        head = {"w": _dense_init(keys[8], (c.d_model, c.vocab_size)),
+        head = {"w": dense(keys[8], (c.d_model, c.vocab_size)),
                 "b": jnp.zeros((c.vocab_size,), jnp.float32)}
 
     return {
-        "embed": _dense_init(keys[7], (c.vocab_size, c.d_model)),
+        "embed": dense(keys[7], (c.vocab_size, c.d_model)),
         "layers": layers,
         "final_norm": final,
         "lm_head": head,
@@ -271,6 +290,43 @@ def logical_axes(config: TransformerConfig) -> Dict:
         "final_norm": final,
         "lm_head": head,
     }
+
+
+#: Leaves a use site reads in float32 (``ops/norms.py`` takes a norm's
+#: scale and bias to f32) — these and everything under ``final_norm``.
+#: Every other leaf is read through ``.astype(config.dtype)``.
+_F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias"))
+
+
+def inference_params(config: TransformerConfig, params: Dict) -> Dict:
+    """``params`` as a serving engine holds them: each leaf that every
+    use site reads through ``.astype(config.dtype)`` (embedding,
+    projections, MLP / MoE weights and biases, LM head) cast to
+    ``config.dtype`` once, here, so that no step program casts it again
+    on every call; norm leaves stay f32. The rounding is the one the
+    use sites would do, so the programs compute the same bits.
+
+    Where nothing needs casting (``config.dtype`` is f32, or the tree
+    has been through here already) the tree handed in is returned, the
+    same object. Otherwise leaf by leaf into a new tree; the one handed
+    in is the caller's to drop."""
+    dt = jnp.dtype(config.dtype)
+
+    def wants_cast(path, leaf) -> bool:
+        names = {getattr(k, "key", None) for k in path}
+        return "final_norm" not in names and not names & _F32_LEAVES \
+            and jnp.issubdtype(leaf.dtype, jnp.floating) \
+            and leaf.dtype != dt
+
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    todo = [wants_cast(path, leaf) for path, leaf in flat]
+    if not any(todo):
+        return params
+    # every leaf ends up a device array (a refresh off the wire is
+    # numpy): the programs' arguments then look alike call to call
+    return jax.tree_util.tree_unflatten(treedef, [
+        jnp.asarray(leaf).astype(dt) if cast else jnp.asarray(leaf)
+        for (_, leaf), cast in zip(flat, todo)])
 
 
 # ---------------------------------------------------------------- remat

@@ -170,7 +170,8 @@ class RolloutEngine:
                  recorder=None):
         import jax
         import jax.numpy as jnp
-        from ray_tpu.models import TransformerConfig, init_params
+        from ray_tpu.models import (TransformerConfig, inference_params,
+                                    init_params)
         from ray_tpu.serve.llm_engine import (EngineConfig, LLMEngine,
                                               _resolve_dtype)
         self.config = config
@@ -181,7 +182,10 @@ class RolloutEngine:
         if params is None:
             params = init_params(self.model_config,
                                  jax.random.PRNGKey(config.seed))
-        params = jax.tree.map(jnp.asarray, params)
+        # cast once, here: the engines then share one tree in the
+        # compute dtype instead of each casting a copy of its own
+        params = inference_params(self.model_config,
+                                  jax.tree.map(jnp.asarray, params))
         self.engines = [
             LLMEngine(self.model_config, ec, params=params,
                       replica_tag=f"rlhf-engine-{i}")

@@ -8,8 +8,10 @@ reconfiguration. Function deployments get a synthesized callable class.
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import inspect
+import threading
 from typing import Any, Dict
 
 #: Request-scoped metadata (reference: serve.context._serve_request_context);
@@ -41,6 +43,8 @@ class Replica:
         self.replica_id = replica_id
         self._num_ongoing = 0
         self._num_total = 0
+        #: sync handlers run one at a time, as they did on the event loop
+        self._sync_handlers = threading.Lock()
         if isinstance(func_or_class, type):
             self._instance = func_or_class(*init_args, **init_kwargs)
         elif callable(func_or_class):
@@ -59,12 +63,27 @@ class Replica:
         self._num_total += 1
         try:
             method = getattr(self._instance, method_name)
-            out = method(*args, **kwargs)
+            if inspect.iscoroutinefunction(method):
+                return await method(*args, **kwargs)
+            out = await self._off_the_loop(method, *args, **kwargs)
             if inspect.iscoroutine(out):
                 out = await out
             return out
         finally:
             self._num_ongoing -= 1
+
+    async def _off_the_loop(self, method, *args, **kwargs):
+        """Run a sync handler in one of the actor's pool threads, under
+        the caller's request context. On the event loop it would hold up
+        every other call of this actor for as long as it runs — token
+        streams, and the controller's health check, whose 30 s rule then
+        kills a healthy replica for being asked something slow. Sync
+        handlers still never overlap each other."""
+        def run():
+            with self._sync_handlers:
+                return method(*args, **kwargs)
+        return await asyncio.get_running_loop().run_in_executor(
+            None, contextvars.copy_context().run, run)
 
     async def handle_request_ctx(self, ctx: dict, method_name: str,
                                  *args, **kwargs):

@@ -223,8 +223,8 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from ray_tpu.models import (decode_step, init_kv_cache,
-                                    init_params, prefill)
+        from ray_tpu.models import (decode_step, inference_params,
+                                    init_kv_cache, init_params, prefill)
 
         self.model_config = model_config
         self.config = engine_config or EngineConfig()
@@ -267,8 +267,18 @@ class LLMEngine:
                 model_config, paged_block_r_prefill=int(br))
             self.model_config = model_config
 
-        self._params = params if params is not None \
-            else init_params(model_config, jax.random.PRNGKey(seed))
+        # The programs take their weights in the compute dtype: cast
+        # once here (and in stage_weights), never inside a step program,
+        # where XLA would redo the cast of every stacked weight in every
+        # call. A tree of the engine's own making is rounded leaf by
+        # leaf as it is drawn and is never resident in f32; a caller's
+        # f32 tree is cast before the pool exists, while there is room.
+        self._inference_params = functools.partial(inference_params,
+                                                   model_config)
+        self._params = self._inference_params(
+            params if params is not None else init_params(
+                model_config, jax.random.PRNGKey(seed),
+                dtype=model_config.dtype))
         self._cache = init_kv_cache(model_config, ec.resolved_num_blocks,
                                     ec.kv_block_size)
 
@@ -407,6 +417,7 @@ class LLMEngine:
         self._staged_weights: Optional[tuple] = None
         self._weight_version = 0
         self._weight_swaps = 0
+        self._weight_casts = 0
         self._weight_swap_wall_s = 0.0
         self._sync_stall_s = 0.0
 
@@ -513,12 +524,17 @@ class LLMEngine:
         emitted token stamped by the version that actually produced it.
         Staging twice before a swap keeps only the newest tree (the
         double buffer holds one pending refresh). Safe from any thread;
-        dequantize on the caller's thread, not here."""
+        dequantize on the caller's thread, not here. A tree that is not
+        yet in the compute dtype (a learner's f32 masters) is cast here,
+        on the caller's thread, so the programs always see the avals
+        they were compiled for and a refresh compiles nothing."""
+        cast = self._inference_params(params)
         with self._work:
             if self._dead is not None:
                 raise EngineDeadError(
                     f"engine step loop died: {self._dead!r}")
-            self._staged_weights = (params, int(version))
+            self._weight_casts += cast is not params
+            self._staged_weights = (cast, int(version))
             self._work.notify_all()
 
     @property
@@ -1028,6 +1044,13 @@ class LLMEngine:
                 "kv_ship_wall_s": round(self._kv_ship_wall_s, 4),
                 "weight_version": self._weight_version,
                 "weight_swaps": self._weight_swaps,
+                # bytes of the tree the programs take, and how many
+                # refreshes arrived outside the compute dtype and were
+                # cast on the stager's thread
+                "weight_bytes": sum(
+                    x.nbytes for x in
+                    self._jax.tree.leaves(self._params)),
+                "weight_casts_total": self._weight_casts,
                 "weight_swap_wall_s": round(self._weight_swap_wall_s,
                                             6),
                 "sync_stall_s": round(self._sync_stall_s, 6),

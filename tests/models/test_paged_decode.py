@@ -415,3 +415,93 @@ def test_carried_pool_equals_sliced_and_restacked_pool(style, kv_heads,
         np.testing.assert_array_equal(got[:, 7:9], before[:, 7:9])
         # the live rows were written, in every layer
         assert (got[:, 1:3] != before[:, 1:3]).all(axis=(1, 2, 3, 4)).all()
+
+
+@pytest.mark.parametrize("style,kv_heads", [
+    ("gptj", None), ("gptj", 2), ("llama", None), ("llama", 2)])
+def test_inference_params_cast_once_gives_the_same_bits(style, kv_heads):
+    """The tree a serving engine holds (``inference_params``: matmul and
+    lookup leaves in the compute dtype, norm leaves f32) against the f32
+    masters, through the same jitted trunk: two prefill chunks and three
+    decode steps give logits and pools equal bit for bit, because the
+    use sites round the f32 leaves exactly as the one cast did. Where
+    nothing needs casting the function hands back the object it got."""
+    from ray_tpu.models import inference_params
+    cfg = _cfg(block_style=style, n_kv_heads=kv_heads, n_layers=3,
+               dtype=jnp.bfloat16)
+    masters = init_params(cfg, jax.random.PRNGKey(0))
+    # biases and norms are zeros and ones at init: give them values
+    # that rounding to bf16 changes
+    leaves, treedef = jax.tree.flatten(masters)
+    noise = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    masters = jax.tree.unflatten(treedef, [
+        a + 0.02 * jax.random.normal(k, a.shape) if a.ndim <= 2 else a
+        for a, k in zip(leaves, noise)])
+    served = inference_params(cfg, masters)
+
+    norms = {"attn_norm", "mlp_norm", "ln_scale", "ln_bias"}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(served)[0]:
+        names = {k.key for k in path}
+        is_norm = "final_norm" in names or bool(names & norms)
+        assert leaf.dtype == (jnp.float32 if is_norm else jnp.bfloat16), \
+            (path, leaf.dtype)
+    assert any(jnp.any(a.astype(jnp.bfloat16).astype(jnp.float32) != a)
+               for a in jax.tree.leaves(masters["final_norm"]))
+    # nothing to cast: the same object comes back
+    assert inference_params(cfg, served) is served
+    f32 = _cfg(block_style=style, n_kv_heads=kv_heads, n_layers=3)
+    assert inference_params(f32, masters) is masters
+
+    bs, T, chunk = 4, 4, 4
+    bt = np.zeros((3, T), np.int32)
+    bt[:2] = np.arange(1, 1 + 2 * T).reshape(2, T)
+    bt = jnp.asarray(bt)
+    shape = init_kv_cache(cfg, num_blocks=11, block_size=bs)["k"].shape
+    k0, v0 = jax.random.normal(jax.random.PRNGKey(5), (2,) + shape,
+                               jnp.bfloat16)
+    prompt = np.array([7, 5, 0], np.int32)
+    ids = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(1), (3, 12), 0, cfg.vocab_size))
+    jit_prefill = jax.jit(functools.partial(prefill, cfg))
+    jit_decode = jax.jit(functools.partial(decode_step, cfg))
+
+    def run(params):
+        cache, logits = {"k": k0, "v": v0}, []
+        for start in (0, chunk):
+            lens = np.clip(prompt - start, 0, chunk).astype(np.int32)
+            out, cache = jit_prefill(
+                params, jnp.asarray(ids[:, start:start + chunk]), cache,
+                bt, jnp.full((3,), start, jnp.int32), jnp.asarray(lens))
+            logits.append(out)
+        seq = prompt.copy()
+        for _ in range(3):
+            out, cache = jit_decode(
+                params, jnp.asarray(ids[np.arange(3), seq]), cache, bt,
+                jnp.asarray(seq))
+            logits.append(out)
+            seq = seq + np.array([1, 1, 0], np.int32)
+        return logits, cache
+
+    want_logits, want_cache = run(masters)
+    got_logits, got_cache = run(served)
+    for got, want in zip(got_logits, want_logits):
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32))
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(got_cache[name], np.float32),
+            np.asarray(want_cache[name], np.float32))
+        assert (np.asarray(got_cache[name][:, 1:3], np.float32)
+                != np.asarray(k0 if name == "k" else v0,
+                              np.float32)[:, 1:3]).any()
+
+    # an engine's own tree is drawn and rounded leaf by leaf: the same
+    # bits, and never a whole f32 tree
+    own = inference_params(cfg, init_params(cfg, jax.random.PRNGKey(0),
+                                            dtype=cfg.dtype))
+    plain = inference_params(cfg, init_params(cfg, jax.random.PRNGKey(0)))
+    for a, b in zip(jax.tree.leaves(own), jax.tree.leaves(plain)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

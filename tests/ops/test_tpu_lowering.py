@@ -172,3 +172,82 @@ def test_compiled_step_updates_the_donated_pool_in_place(
     assert not moved, moved
     # the two scatters (k, v), alone or as the root of a fusion
     assert made.count("scatter") == 2
+
+
+@pytest.mark.parametrize("entry", ["decode_step", "prefill"])
+def test_compiled_step_casts_no_weight_of_the_engines_tree(
+        entry, one_chip, monkeypatch):
+    """The engine hands its programs ``inference_params`` of the model's
+    tree, so the program the TPU compiler builds from those avals holds
+    no ``convert`` whose result has a weight's shape (stacked, or one
+    layer of it), and its temporaries are a few activations: under
+    4 MiB here. From the f32 masters' avals the same source compiles to
+    a program that casts every stacked weight ahead of its layer loop on
+    every call and keeps the bf16 copy among its temporaries (over
+    64 MiB here) — which is what the detector must see, and what this
+    test would see if the engine's tree stopped being cast. Wide enough
+    (2048 x 8192) that the compiler hoists the casts as it does at the
+    served widths; narrower, it fuses them into the loop's matmuls."""
+    from ray_tpu.models import (TransformerConfig, decode_step,
+                                inference_params, init_kv_cache,
+                                init_params, prefill)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=2048, n_layers=2, n_heads=16,
+        n_kv_heads=2, head_dim=128, d_ff=8192, max_seq_len=256,
+        block_style="llama", dtype=jnp.bfloat16, remat_policy="none",
+        paged_impl="kernel")
+    slots, table, blocks, chunk = 8, 16, 2049, 64
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def compiled(params):
+        cache = shaped(jax.eval_shape(
+            lambda: init_kv_cache(cfg, blocks, BLOCK)))
+        if entry == "decode_step":
+            args = (shaped(params), i32(slots), cache, i32(slots, table),
+                    i32(slots))
+            fn = functools.partial(decode_step, cfg)
+        else:
+            args = (shaped(params), i32(1, chunk), cache, i32(1, table),
+                    i32(1), i32(1))
+            fn = functools.partial(prefill, cfg)
+        return jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+
+    def masters():
+        return init_params(cfg, jax.random.PRNGKey(0))
+    try:
+        served = jax.eval_shape(lambda: inference_params(cfg, masters()))
+        on_served = compiled(served)
+        on_masters = compiled(jax.eval_shape(masters))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+    shapes = set()
+    for leaf in jax.tree.leaves(served):
+        if leaf.dtype == jnp.bfloat16 and leaf.ndim >= 2:
+            shapes.add(leaf.shape)
+            shapes.add(leaf.shape[1:])       # one layer of a stacked leaf
+    shapes = {",".join(map(str, s)) for s in shapes if len(s) >= 2}
+
+    def weight_casts(program):
+        return [s for s in re.findall(
+            r"= bf16\[([\d,]+)\]\S* convert\(", program.as_text())
+            if s in shapes]
+
+    assert "tpu_custom_call" in on_served.as_text()
+    assert weight_casts(on_served) == []
+    assert on_served.memory_analysis().temp_size_in_bytes < 4 << 20
+    stacked = weight_casts(on_masters)
+    assert f"{cfg.n_layers},{cfg.d_model},{cfg.d_ff}" in stacked, stacked
+    assert on_masters.memory_analysis().temp_size_in_bytes > 64 << 20

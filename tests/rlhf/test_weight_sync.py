@@ -98,3 +98,65 @@ def test_publisher_fans_out_with_monotone_versions():
     assert s["publishes"] == 2 and s["version"] == 2
     assert s["compression"] is not None and s["compression"] > 2.0
     assert s["wire_bytes_total"] > 0
+
+
+def test_packed_refresh_into_a_bf16_engine_is_cast_before_the_swap():
+    """The wire's float32 tree (``unpack_weights``, numpy leaves) into an
+    engine that computes in bf16, by both routes — the publisher's
+    in-process ``stage_weights`` and a replica's ``sync_weights``: each
+    casts on the caller's thread, the version lands, no step program
+    compiles again, and both serve the tokens of an engine built on the
+    same dequantised tree."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import TransformerConfig, init_params
+    from ray_tpu.serve.llm_engine import (EngineConfig, LLMEngine,
+                                          LLMServer)
+    model = dict(vocab_size=64, d_model=16, n_layers=2, n_heads=2,
+                 head_dim=8, d_ff=32, max_seq_len=64, rotary_dim=8,
+                 block_style="llama", n_kv_heads=1, remat_policy="none")
+    ekw = dict(decode_slots=2, kv_block_size=4, max_seq_len=48,
+               prefill_chunk=8, max_new_tokens=16)
+    cfg = TransformerConfig(**model, dtype=jnp.bfloat16)
+    learner = init_params(cfg, jax.random.PRNGKey(3))      # f32 masters
+    wire, _ = unpack_weights(pack_weights(learner, 1, block_size=8))
+    assert wire["embed"].dtype == np.float32
+
+    def wait(engine, version):
+        deadline = time.monotonic() + 30
+        while engine.stats()["weight_version"] != version:
+            assert time.monotonic() < deadline, "the swap never landed"
+            time.sleep(0.005)
+
+    eng = LLMEngine(cfg, EngineConfig(**ekw))
+    srv = LLMServer(model=dict(model, dtype="bfloat16"), engine=ekw)
+    built = LLMEngine(cfg, EngineConfig(**ekw), params=wire)
+    try:
+        eng.warmup()
+        prompt = [5, 3, 5, 8, 9, 7, 9]
+        want = list(built.generate_sync(prompt, max_new_tokens=8))
+        for engine, refresh in (
+                (eng, lambda: WeightPublisher([eng], block_size=8)
+                    .publish(learner)),
+                (srv.engine, lambda: srv.sync_weights(
+                    pack_weights(learner, 1, block_size=8)))):
+            every = engine.stats()["compiled_programs"]
+            # (another prompt: a refresh keeps the prefix cache's pages)
+            assert list(engine.generate_sync(prompt[::-1], 8))
+            assert refresh() == 1
+            wait(engine, 1)
+            p = engine._params
+            assert p["embed"].dtype == p["layers"]["w_down"].dtype \
+                == jnp.bfloat16
+            assert p["layers"]["attn_norm"].dtype == jnp.float32
+            assert list(engine.generate_sync(prompt, 8)) == want
+            s = engine.stats()
+            assert s["compiled_programs"] == every
+            assert s["weight_casts_total"] == s["weight_swaps"] == 1
+    finally:
+        eng.shutdown()
+        srv.engine.shutdown()
+        built.shutdown()

@@ -409,6 +409,80 @@ def test_warmup_compiles_then_resets_session_stats():
         eng.shutdown()
 
 
+def _wait_version(eng, version, timeout_s=30.0):
+    deadline = time.monotonic() + timeout_s
+    while eng.stats()["weight_version"] != version:
+        assert time.monotonic() < deadline, "the swap never landed"
+        time.sleep(0.005)
+
+
+def test_refresh_in_f32_is_cast_on_the_stagers_thread_and_compiles_nothing():
+    """An engine whose compute dtype is bf16 holds its tree in bf16
+    (norm leaves f32) and no f32 masters. A learner's f32 tree staged
+    into it is cast in ``stage_weights``, on the caller's thread: what
+    the step thread swaps in has the avals the programs were compiled
+    for, so the refresh compiles nothing, and the next tokens are those
+    of an engine built on that tree."""
+    import jax
+    from ray_tpu.models import init_params
+    cfg = TransformerConfig(**dict(MODEL_KW, dtype=jnp.bfloat16))
+    ekw = dict(decode_slots=2, kv_block_size=4, max_seq_len=48,
+               prefill_chunk=8, max_new_tokens=16)
+    eng = LLMEngine(cfg, EngineConfig(**ekw))
+    fresh = init_params(cfg, jax.random.PRNGKey(1))       # f32 masters
+    other = LLMEngine(cfg, EngineConfig(**ekw), params=fresh)
+    try:
+        def dtypes(e):
+            p = e._params
+            return (p["embed"].dtype, p["layers"]["wq"].dtype,
+                    p["layers"]["fc_in_b"].dtype, p["lm_head"]["w"].dtype,
+                    p["layers"]["ln_scale"].dtype,
+                    p["final_norm"]["bias"].dtype)
+        held = (jnp.bfloat16,) * 4 + (jnp.float32,) * 2
+        assert dtypes(eng) == held and dtypes(other) == held
+        # a caller's tree is cast, not spent
+        assert not fresh["embed"].is_deleted()
+        eng.warmup()
+        s = eng.stats()
+        every = dict(s["compiled_programs"])
+        assert set(every.values()) == {1}
+        n_cast = sum(x.size for x in jax.tree.leaves(fresh)) \
+            - sum(x.size for x in jax.tree.leaves(
+                (fresh["final_norm"], fresh["layers"]["ln_scale"],
+                 fresh["layers"]["ln_bias"])))
+        n_all = sum(x.size for x in jax.tree.leaves(fresh))
+        assert s["weight_bytes"] == 2 * n_cast + 4 * (n_all - n_cast)
+        assert s["weight_casts_total"] == 0 and s["weight_swaps"] == 0
+
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5]
+        # (not this prompt: a refresh keeps the prefix cache's pages)
+        before = list(eng.generate_sync(prompt[::-1], max_new_tokens=8))
+        eng.stage_weights(fresh, version=5)
+        staged = eng._staged_weights
+        # cast before it was handed over, whoever swaps it in
+        assert staged is None or staged[0]["embed"].dtype == jnp.bfloat16
+        _wait_version(eng, 5)
+        assert dtypes(eng) == held
+        after = list(eng.generate_sync(prompt, max_new_tokens=8))
+        s = eng.stats()
+        assert s["compiled_programs"] == every, "the refresh recompiled"
+        assert (s["weight_swaps"], s["weight_casts_total"]) == (1, 1)
+        assert after == list(other.generate_sync(prompt, max_new_tokens=8))
+        assert before != list(other.generate_sync(prompt[::-1],
+                                                  max_new_tokens=8))
+
+        # a tree that is already the engine's kind is staged as it is
+        eng.stage_weights(other._params, version=6)
+        _wait_version(eng, 6)
+        assert eng._params is other._params
+        s = eng.stats()
+        assert (s["weight_swaps"], s["weight_casts_total"]) == (2, 1)
+        assert s["compiled_programs"] == every
+    finally:
+        eng.shutdown()
+        other.shutdown()
+
+
 def test_warmup_failure_is_fatal_to_the_replica():
     """A program that cannot compile must fail LLMServer's constructor
     (and so the replica actor): forcing the compiled kernel on a host

@@ -306,3 +306,55 @@ def test_health_check_does_not_kill_a_replica_that_is_still_starting(
     outcomes["ready"] = GetTimeoutError("wedged")
     ctl._health_check("d", info)
     assert info.replicas == [slow] and ready.killed
+
+
+def test_a_long_sync_handler_does_not_starve_the_replicas_other_calls(
+        serve_session):
+    """A sync handler runs off the replica's event loop: while one is
+    busy for seconds (a profiler trace being reduced, weights being
+    dequantised), the replica still answers its controller's health
+    check and serves its async methods — the 30 s rule would otherwise
+    kill a healthy replica for being asked something slow. Sync handlers
+    still never overlap each other, and still see their request's
+    context."""
+    import threading
+
+    @serve.deployment(max_ongoing_requests=8)
+    class Slow:
+        def __init__(self):
+            self.inside = 0
+            self.overlapped = False
+
+        def grind(self, seconds):
+            self.inside += 1
+            self.overlapped |= self.inside > 1
+            time.sleep(seconds)
+            self.inside -= 1
+            return (serve.get_multiplexed_model_id(),
+                    threading.current_thread().name)
+
+        async def quick(self):
+            return "quick"
+
+        def report(self):
+            return self.overlapped
+
+    serve.run(Slow.bind(), name="slow")
+    from ray_tpu.serve.api import CONTROLLER_NAME
+    controller = ray_tpu.get_actor(CONTROLLER_NAME)
+    replica = ray_tpu.get(controller.get_replicas.remote("Slow"))[0]
+    ray_tpu.get(replica.check_health.remote(), timeout=30)
+
+    busy = [replica.handle_request_ctx.remote(
+        {"multiplexed_model_id": f"m{i}"}, "grind", 1.5) for i in range(2)]
+    time.sleep(0.3)                      # the first grind is under way
+    t0 = time.monotonic()
+    assert ray_tpu.get(replica.check_health.remote(), timeout=30) is True
+    assert ray_tpu.get(replica.handle_request.remote("quick"),
+                       timeout=30) == "quick"
+    assert time.monotonic() - t0 < 1.0, "waited for the sync handler"
+    got = ray_tpu.get(busy, timeout=60)
+    assert [g[0] for g in got] == ["m0", "m1"]
+    assert all(g[1] != "actor-asyncio" for g in got)
+    assert ray_tpu.get(replica.handle_request.remote("report"),
+                       timeout=30) is False
