@@ -641,17 +641,7 @@ class ChaosMonkey:
         head = self._get_head()
         old = head.controller
         self.log.append(("restart_controller",))
-        old._shutdown.set()
-        rel = getattr(old, "_reliable", None)
-        if rel is not None:
-            # a kill -9 takes the retransmit thread with it too
-            rel.stop()
-        try:
-            old._wake_send.send(b"")
-        except Exception:
-            pass
-        if old._thread is not None:
-            old._thread.join(timeout=10)
+        old.halt()  # takes the retransmit thread with it, as a kill -9 does
         head.controller = Controller(head.session_dir, old.config)
         head.controller.start()
         return head.controller

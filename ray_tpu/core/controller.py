@@ -37,6 +37,7 @@ from ray_tpu.core.config import Config
 from ray_tpu.core.ids import ActorID, JobID, NodeID, ObjectID, PlacementGroupID, TaskID, WorkerID
 from ray_tpu.core.reference_counter import GlobalRefTable
 from ray_tpu.core.scheduler import ClusterResourceScheduler, NodeResources
+from ray_tpu.core.sockloop import SocketLoop, open_socket
 from ray_tpu.core.task_spec import ActorInfo, PlacementGroupSpec, TaskSpec
 
 logger = logging.getLogger(__name__)
@@ -155,7 +156,7 @@ class Controller:
             config)
         # reliable-delivery sublayer: TASK_DISPATCH/TASK_ASSIGN/
         # TASK_RESULT to workers, nodes and owners get ack/retransmit;
-        # resends re-enter _send (thread-safe cross-thread marshal)
+        # resends re-enter _send (off the loop thread: posted to its outbox)
         self._reliable = RD.maybe_transport(
             config, lambda t, mt, pl: self._send(t, mt, pl),
             lambda route, pl: self._send(route, P.MSG_ACK, pl),
@@ -163,23 +164,11 @@ class Controller:
             if self._chaos is not None else None, name="controller",
             recorder=self.recorder)
         self.ctx = zmq.Context.instance()
-        self.sock = self.ctx.socket(zmq.ROUTER)
-        self.sock.setsockopt(zmq.ROUTER_MANDATORY, 0)
-        self.sock.setsockopt(zmq.LINGER, 0)
-        # unbounded per-peer queues: result bursts (thousands of TASK_RESULT
-        # pushes to one owner) must not be silently dropped at the HWM
-        self.sock.setsockopt(zmq.SNDHWM, 0)
-        self.sock.setsockopt(zmq.RCVHWM, 0)
         self.addr = P.socket_path(session_dir)
-        self.sock.bind(self.addr)
-        # wakeup channel for cross-thread sends
-        self._wake_recv = self.ctx.socket(zmq.PULL)
-        self._wake_recv.bind(f"inproc://ctl-wake-{id(self)}")
-        self._wake_send = self.ctx.socket(zmq.PUSH)
-        self._wake_send.connect(f"inproc://ctl-wake-{id(self)}")
-        self._send_q: Deque[Tuple[bytes, bytes, bytes]] = collections.deque()
-        self._call_q: Deque = collections.deque()  # marshaled loop calls
-        self._send_lock = threading.Lock()
+        # the loop thread owns the ROUTER (core/sockloop.py): other threads
+        # post framed bytes to its outbox, or a closure for it to run
+        self._loop = SocketLoop("controller", self._open_sockets,
+                                each_cycle=self._each_cycle)
         self._sched_dirty = True
         # local_waiters parked on UNKNOWN objects: first-park timestamp
         # + audit strike counts (directory-hole detection)
@@ -263,7 +252,6 @@ class Controller:
         self._job_counter = 0
 
         self._shutdown = threading.Event()
-        self._thread: Optional[threading.Thread] = None
         self._health_thread: Optional[threading.Thread] = None
         self._transfers: Dict[Tuple[bytes, bytes], int] = {}  # (object, dest_node) -> attempt
 
@@ -340,65 +328,37 @@ class Controller:
 
     # ------------------------------------------------------------------ run
     def start(self) -> None:
-        self._thread = threading.Thread(target=self._run, name="controller", daemon=True)
-        self._thread.start()
+        self._loop.start()
         self._health_thread = threading.Thread(
             target=self._health_loop, name="controller-health", daemon=True)
         self._health_thread.start()
 
-    def stop(self) -> None:
+    def halt(self) -> None:
+        """Stop the loops with no state flush: what a kill -9 leaves is
+        what the WAL already holds."""
         self._shutdown.set()
         if self._reliable is not None:
             self._reliable.stop()
-        with self._send_lock:
-            pass
-        try:
-            self._wake_send.send(b"")
-        except Exception:
-            pass
-        if self._thread:
-            self._thread.join(timeout=5)
+        self._loop.stop(wait_s=10.0)
+
+    def stop(self) -> None:
+        self.halt()
         self.store.close()
 
-    def _run(self) -> None:
-        poller = zmq.Poller()
-        poller.register(self.sock, zmq.POLLIN)
-        poller.register(self._wake_recv, zmq.POLLIN)
-        while not self._shutdown.is_set():
-            try:
-                events = dict(poller.poll(timeout=1000))
-            except zmq.ZMQError:
-                break
-            if self._wake_recv in events:
-                while True:
-                    try:
-                        self._wake_recv.recv(zmq.NOBLOCK)
-                    except zmq.ZMQError:
-                        break
-            self._drain_sends()
-            self._drain_calls()
-            if self.sock in events:
-                for _ in range(1000):
-                    try:
-                        frames = self.sock.recv_multipart(zmq.NOBLOCK)
-                    except zmq.ZMQError:
-                        break
-                    try:
-                        self._handle(frames)
-                    except Exception:
-                        logger.exception("controller: error handling %s",
-                                         frames[1] if len(frames) > 1 else frames)
-            self._flush_outbox()
-            self._drain_sends()
-            # latency bound on the controller's OWN flight-recorder
-            # events reaching the aggregation buffer
-            self.recorder.maybe_flush()
-        try:
-            self.sock.close(0)
-            self._wake_recv.close(0)
-            self._wake_send.close(0)
-        except Exception:
-            pass
+    def _open_sockets(self):
+        """Loop thread."""
+        # unbounded per-peer queues: result bursts (thousands of TASK_RESULT
+        # pushes to one owner) must not be silently dropped at the HWM
+        self.sock = open_socket(self.ctx, zmq.ROUTER)
+        self.sock.setsockopt(zmq.ROUTER_MANDATORY, 0)
+        self.sock.bind(self.addr)
+        return [(self.sock, self._handle)]
+
+    def _each_cycle(self) -> None:
+        self._flush_outbox()
+        # latency bound on the controller's OWN flight-recorder
+        # events reaching the aggregation buffer
+        self.recorder.maybe_flush()
 
     def call_on_loop(self, fn, timeout: float = 10.0):
         """Run ``fn()`` on the controller loop thread and return its
@@ -406,7 +366,7 @@ class Controller:
         (mirroring the GCS's one io_context) — cross-thread readers like
         the dashboard must marshal through here rather than iterate live
         dicts."""
-        if threading.current_thread() is self._thread:
+        if self._loop.on_thread():
             return fn()
         done = threading.Event()
         box: list = [None, None]
@@ -418,33 +378,18 @@ class Controller:
                 box[1] = e
             done.set()
 
-        with self._send_lock:
-            self._call_q.append(run)
-        try:
-            self._wake_send.send(b"", zmq.NOBLOCK)
-        except zmq.ZMQError:
-            pass
+        self._loop.call(run)
         if not done.wait(timeout):
             raise TimeoutError("controller loop busy")
         if box[1] is not None:
             raise box[1]
         return box[0]
 
-    def _drain_calls(self) -> None:
-        while self._call_q:
-            try:
-                run = self._call_q.popleft()
-            except IndexError:
-                break
-            try:
-                run()
-            except Exception:
-                logger.exception("controller: error in marshaled call")
-
     def _send(self, identity: bytes, mtype: bytes, payload: Any) -> None:
-        """Thread-safe send. Loop-thread sends are buffered per peer and
-        flushed at the end of the handling cycle (order-preserving);
-        cross-thread sends are marshaled through the wake channel."""
+        """Any thread may call this; only the loop thread touches the
+        socket. Loop-thread sends are buffered per peer and flushed at the
+        end of the handling cycle (order-preserving); other threads' sends
+        are pickled here and posted to the loop's outbox."""
         if self._reliable is not None:
             # stamp + ring-record critical one-way messages before the
             # chaos filter (a dropped message must already be tracked);
@@ -454,8 +399,7 @@ class Controller:
             for delay_s, pl in self._chaos.plan_send(
                     identity, mtype, payload):
                 if delay_s > 0.0:
-                    # the timer thread re-enters via the cross-thread
-                    # marshal path, which is safe from any thread
+                    # the timer thread posts to the loop's outbox
                     t = threading.Timer(delay_s, self._send_now,
                                         args=(identity, mtype, pl))
                     t.daemon = True
@@ -466,19 +410,13 @@ class Controller:
         self._send_now(identity, mtype, payload)
 
     def _send_now(self, identity: bytes, mtype: bytes, payload: Any) -> None:
-        if threading.current_thread() is self._thread:
+        if self._loop.on_thread():
             box = self._outbox.get(identity)
             if box is None:
                 box = self._outbox[identity] = []
             box.append((mtype, payload))
         else:
-            blob = P.dumps(payload)
-            with self._send_lock:
-                self._send_q.append((identity, mtype, blob))
-            try:
-                self._wake_send.send(b"", zmq.NOBLOCK)
-            except zmq.ZMQError:
-                pass
+            self._loop.post([identity, mtype, P.dumps(payload)])
 
     def _flush_outbox(self) -> None:
         if not self._outbox:
@@ -497,17 +435,6 @@ class Controller:
             except zmq.ZMQError:
                 logger.warning("controller: drop %d msgs to %s", len(msgs),
                                identity.hex()[:8])
-
-    def _drain_sends(self) -> None:
-        while True:
-            with self._send_lock:
-                if not self._send_q:
-                    return
-                identity, mtype, blob = self._send_q.popleft()
-            try:
-                self.sock.send_multipart([identity, mtype, blob], zmq.NOBLOCK)
-            except zmq.ZMQError:
-                pass
 
     def _reply(self, identity: bytes, rid: bytes, data: Any, ok: bool = True) -> None:
         self._send(identity, P.GENERIC_REPLY if ok else P.ERROR_REPLY,
@@ -2904,6 +2831,7 @@ class Controller:
         for node in self.nodes.values():
             self._send(node.identity, P.SHUTDOWN, {})
         self._shutdown.set()
+        self._loop.stop()  # this cycle still flushes the lines above
 
     _HANDLERS = {
         P.REGISTER: _h_register,

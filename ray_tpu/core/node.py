@@ -21,7 +21,6 @@ import subprocess
 import sys
 import threading
 import time
-from collections import deque
 from typing import Any, Dict, List, Optional
 
 import zmq
@@ -34,6 +33,7 @@ from ray_tpu.core import reliable as RD
 from ray_tpu.core.config import Config, get_config
 from ray_tpu.core.ids import NodeID, ObjectID, WorkerID
 from ray_tpu.core.shm_store import make_client, make_store
+from ray_tpu.core.sockloop import PeerDealers, SocketLoop, open_socket
 
 logger = logging.getLogger(__name__)
 
@@ -140,25 +140,17 @@ class NodeManager:
         self._stopped = threading.Event()
 
         self.ctx = zmq.Context.instance()
-        self.sock = self.ctx.socket(zmq.DEALER)
         # node identity: its NodeID binary (distinct size from WorkerID use
         # is fine — identities are opaque to zmq)
         self.identity = b"N" + self.node_id.binary()[:27]
-        self.sock.setsockopt(zmq.IDENTITY, self.identity)
-        self.sock.setsockopt(zmq.LINGER, 0)
-        self.sock.connect(P.socket_path(session_dir))
-        self._send_lock = threading.Lock()
-        # direct peer channel: object chunks move node-to-node here and
-        # NEVER transit the controller (reference: object_manager.h:206
-        # pushes between object managers; GCS sees only locations)
         D.ensure_dir(session_dir)
-        self.direct_sock = self.ctx.socket(zmq.ROUTER)
-        self.direct_sock.setsockopt(zmq.LINGER, 0)
-        self.direct_sock.setsockopt(zmq.SNDHWM, 0)
-        self.direct_sock.setsockopt(zmq.RCVHWM, 0)
-        self.direct_sock.bind(D.direct_addr(session_dir, self.identity))
-        self._peer_socks: Dict[bytes, zmq.Socket] = {}  # loop-thread-only
-        self._threads: List[threading.Thread] = []
+        # the loop thread owns the controller DEALER, the direct ROUTER and
+        # the peer DEALERs (core/sockloop.py); every other thread's _send
+        # posts framed bytes to its outbox
+        self._peers = PeerDealers(self.ctx, self.identity, session_dir)
+        self._loop = SocketLoop("node-loop", self._open_sockets,
+                                each_cycle=self._check_pull_timeouts,
+                                on_close=self._peers.close)
         self.num_initial_workers = num_initial_workers
         self._incoming: Dict[bytes, dict] = {}
         # pull manager (reference: pull_manager.h:52): bytes-budgeted
@@ -169,10 +161,6 @@ class NodeManager:
         # source-side outbound streams, windowed by receiver acks so a
         # huge object never sits fully buffered in zmq send queues
         self._outgoing: Dict[tuple, dict] = {}  # (requester, oid) -> state
-        self._peer_last_used: Dict[bytes, float] = {}
-        #: pull retries parked by restore-capacity backoff timers;
-        #: drained by the message loop (appends are GIL-atomic)
-        self._pull_retries: "deque" = deque()
         from queue import SimpleQueue
         self._store_rpc_q: "SimpleQueue" = SimpleQueue()
         self._store_rpc_thread: Optional[threading.Thread] = None
@@ -194,10 +182,6 @@ class NodeManager:
         self._chaos = CH.maybe_injector("node", self_id=self.identity)
         self._chaos_dedup = CH.SeqDeduper() if self._chaos is not None \
             else None
-        #: chaos-delayed direct sends (timer threads) and reliable-layer
-        #: direct acks parked here; drained by the message loop (peer
-        #: sockets are loop-thread-only)
-        self._chaos_delayed: "deque" = deque()
         # flight recorder (core/events.py): the node's contribution is
         # transport-health events (retransmits of its PUT announcements,
         # dedup drops); flushed with the heartbeat
@@ -208,7 +192,8 @@ class NodeManager:
         # traffic is controller-bound (PUT_OBJECT announcements); it
         # also acks the controller's TASK_ASSIGNs
         self._reliable = RD.maybe_transport(
-            self.config, self._reliable_resend, self._reliable_ack,
+            self.config, self._reliable_resend,
+            lambda route, pl: self._reliable_resend(route, P.MSG_ACK, pl),
             rng=self._chaos.rng_for("retransmit")
             if self._chaos is not None else None, name="node",
             recorder=self.recorder)
@@ -313,13 +298,11 @@ class NodeManager:
 
     def start(self) -> None:
         self._register_with_controller()
-        for t in (threading.Thread(target=self._loop, name="node-loop", daemon=True),
-                  threading.Thread(target=self._heartbeat_loop, name="node-hb", daemon=True),
-                  threading.Thread(target=self._reaper_loop, name="node-reaper", daemon=True),
-                  threading.Thread(target=self._memory_monitor_loop,
-                                   name="node-memmon", daemon=True)):
-            t.start()
-            self._threads.append(t)
+        self._loop.start()
+        for loop, name in ((self._heartbeat_loop, "node-hb"),
+                           (self._reaper_loop, "node-reaper"),
+                           (self._memory_monitor_loop, "node-memmon")):
+            threading.Thread(target=loop, name=name, daemon=True).start()
         for _ in range(self.num_initial_workers):
             self._start_worker(requested=False)
 
@@ -356,35 +339,21 @@ class NodeManager:
                 os.unlink(self._zygote_sock)
             except OSError:
                 pass
-        try:
-            self.sock.close(0)
-            self.direct_sock.close(0)
-            for s in self._peer_socks.values():
-                s.close(0)
-            self._peer_socks.clear()
-        except Exception:
-            pass
+        self._loop.stop(wait_s=2.0)
         self.shm.close()
         self.store.destroy()
 
     def _reliable_resend(self, target, mtype: bytes, payload) -> None:
-        """Retransmit hook (reliable-layer thread): controller-bound
-        messages re-enter _send (chaos filter re-applied; the stamp is
-        idempotent); direct-channel resends park for the loop thread."""
+        """Retransmit and batched-ack hook (reliable-layer thread):
+        controller-bound messages re-enter _send (chaos filter re-applied;
+        the stamp is idempotent); direct-channel ones go to the loop
+        thread, which owns the peer sockets."""
         if self._stopped.is_set():
             return
         if target is None:
             self._send(mtype, payload)
         else:
-            self._chaos_delayed.append((target, mtype, payload))
-
-    def _reliable_ack(self, route, payload) -> None:
-        if self._stopped.is_set():
-            return
-        if route is None:
-            self._send(P.MSG_ACK, payload)
-        else:
-            self._chaos_delayed.append((route, P.MSG_ACK, payload))
+            self._loop.call(lambda: self._ship_direct(target, mtype, payload))
 
     def _send(self, mtype: bytes, payload) -> None:
         if self._reliable is not None:
@@ -402,102 +371,47 @@ class NodeManager:
         self._send_now(mtype, payload)
 
     def _send_now(self, mtype: bytes, payload) -> None:
-        with self._send_lock:
-            self.sock.send_multipart([mtype, P.dumps(payload)])
+        self._loop.post([mtype, P.dumps(payload)])
 
     # ------------------------------------------------------------ messages
-    def _loop(self) -> None:
-        poller = zmq.Poller()
-        poller.register(self.sock, zmq.POLLIN)
-        poller.register(self.direct_sock, zmq.POLLIN)
-        while not self._stopped.is_set():
-            try:
-                events = dict(poller.poll(timeout=1000))
-            except zmq.ZMQError:
-                break
-            while self._pull_retries:
-                requester, m = self._pull_retries.popleft()
-                try:
-                    self._start_stream(requester, m)
-                except Exception:
-                    logger.exception("pull retry failed")
-            while self._chaos_delayed:
-                # chaos-delayed direct sends: already stamped/planned —
-                # ship as-is from the loop thread that owns peer sockets
-                target, mtype, pl = self._chaos_delayed.popleft()
-                try:
-                    self._peer_sock(target).send_multipart(
-                        [mtype, P.dumps(pl)])
-                except Exception:
-                    pass
-            if self.sock in events:
-                while True:
-                    try:
-                        frames = self.sock.recv_multipart(zmq.NOBLOCK)
-                    except zmq.ZMQError:
-                        break
-                    try:
-                        self._handle(frames[0], P.loads(frames[1]))
-                    except Exception:
-                        logger.exception("node: error handling %s", frames[0])
-            if self.direct_sock in events:
-                while True:
-                    try:
-                        frames = self.direct_sock.recv_multipart(zmq.NOBLOCK)
-                    except zmq.ZMQError:
-                        break
-                    try:
-                        # [sender identity, mtype, payload]
-                        self._handle_direct(frames[0], frames[1],
-                                            P.loads(frames[2]))
-                    except Exception:
-                        logger.exception("node: error in direct %s",
-                                         frames[1])
-            self._check_pull_timeouts()
+    def _open_sockets(self):
+        """Loop thread: the DEALER to the controller, and the direct peer
+        channel's ROUTER — object chunks move node-to-node there and NEVER
+        transit the controller (reference: object_manager.h:206 pushes
+        between object managers; GCS sees only locations)."""
+        self.sock = open_socket(self.ctx, zmq.DEALER, self.identity,
+                                unbounded=False)
+        self.sock.connect(P.socket_path(self.session_dir))
+        self.direct_sock = open_socket(self.ctx, zmq.ROUTER)
+        self.direct_sock.bind(D.direct_addr(self.session_dir, self.identity))
+        return [
+            (self.sock, lambda f: self._handle(f[0], P.loads(f[1]))),
+            # [sender identity, mtype, payload]
+            (self.direct_sock,
+             lambda f: self._handle_direct(f[0], f[1], P.loads(f[2]))),
+        ]
 
-    def _peer_sock(self, target: bytes) -> "zmq.Socket":
-        """Loop-thread-only: lazily connected DEALER to a peer node's
-        direct ROUTER."""
-        s = self._peer_socks.get(target)
-        if s is None:
-            s = self.ctx.socket(zmq.DEALER)
-            s.setsockopt(zmq.IDENTITY, self.identity)
-            s.setsockopt(zmq.LINGER, 0)
-            s.setsockopt(zmq.SNDHWM, 0)
-            s.connect(D.direct_addr(self.session_dir, target))
-            self._peer_socks[target] = s
-        self._peer_last_used[target] = time.monotonic()
-        return s
+    def _ship_direct(self, target: bytes, mtype: bytes, payload) -> None:
+        """Loop-thread-only: already stamped and planned, onto the wire."""
+        self._peers.get(target).send_multipart([mtype, P.dumps(payload)])
 
     def _send_direct(self, target: bytes, mtype: bytes, payload) -> None:
         if self._chaos is not None:
             for delay_s, pl in self._chaos.plan_send(target, mtype,
                                                      payload):
                 if delay_s > 0.0:
-                    # peer sockets are loop-thread-only: the timer parks
-                    # the send; the loop drains it on its next wakeup
+                    # peer sockets are loop-thread-only: the timer hands
+                    # the send back to the loop
                     t = threading.Timer(
-                        delay_s, self._chaos_delayed.append,
-                        args=((target, mtype, pl),))
+                        delay_s, self._loop.call,
+                        args=(lambda pl=pl: self._ship_direct(
+                            target, mtype, pl),))
                     t.daemon = True
                     t.start()
                 else:
-                    self._peer_sock(target).send_multipart(
-                        [mtype, P.dumps(pl)])
+                    self._ship_direct(target, mtype, pl)
             return
-        self._peer_sock(target).send_multipart([mtype, P.dumps(payload)])
-
-    def _prune_peer_socks(self, idle_s: float = 120.0) -> None:
-        now = time.monotonic()
-        for target in [t for t, used in self._peer_last_used.items()
-                       if now - used > idle_s]:
-            self._peer_last_used.pop(target, None)
-            s = self._peer_socks.pop(target, None)
-            if s is not None:
-                try:
-                    s.close(0)
-                except Exception:
-                    pass
+        self._ship_direct(target, mtype, payload)
 
     def _handle(self, mtype: bytes, m: dict) -> None:
         if self._chaos_dedup is not None and CH.check_dedup(
@@ -864,11 +778,6 @@ class NodeManager:
             self._pull_failed(m["object_id"], m.get("src_node"),
                               stale_src=True)
 
-    def _requeue_pull_request(self, requester: bytes, m: dict) -> None:
-        # timer thread: park the retry; the message loop drains it on
-        # its next wakeup (loop thread owns all stream/peer state)
-        self._pull_retries.append((requester, m))
-
     def _store_rpc_loop(self) -> None:
         #: reply sockets cached per sender (this thread only)
         reply_socks: Dict[bytes, zmq.Socket] = {}
@@ -922,7 +831,7 @@ class NodeManager:
                 out["error"] = f"unknown store op {op!r}"
         except Exception as e:  # noqa: BLE001
             out["error"] = str(e)
-        # maintenance thread (not the message loop): _peer_socks is
+        # maintenance thread (not the message loop): _peers is
         # loop-thread-only, so reply over this thread's own cached
         # DEALER per sender. Unique identity: reusing the node's fixed
         # identity would collide with its persistent DEALER to the same
@@ -1024,7 +933,7 @@ class NodeManager:
             for key, st in list(self._outgoing.items()):
                 if now - st["last_activity"] > self.config.pull_timeout_s:
                     self._close_stream(key)
-        self._prune_peer_socks()
+        self._peers.prune()
 
     # Source side (reference: ObjectManager::Push): windowed streaming —
     # at most stream_window_chunks unacked chunks per stream, so a huge
@@ -1042,10 +951,11 @@ class NodeManager:
             # extents): the on-disk copy EXISTS — reporting PULL_FAILED
             # would make the controller drop the only holder. Re-try
             # shortly instead (off-loop timer; the message loop must
-            # not sleep).
+            # not sleep, and owns all stream/peer state).
             m = dict(m, _restore_tries=m.get("_restore_tries", 0) + 1)
-            t = threading.Timer(0.5, self._requeue_pull_request,
-                                args=(requester, m))
+            t = threading.Timer(
+                0.5, self._loop.call,
+                args=(lambda: self._start_stream(requester, m),))
             t.daemon = True
             t.start()
             return

@@ -37,6 +37,7 @@ from ray_tpu.core.reference_counter import ReferenceCounter
 from ray_tpu.core.serialization import SerializationContext, SerializedObject
 from ray_tpu.exceptions import ObjectStoreFullError
 from ray_tpu.core.shm_store import make_client
+from ray_tpu.core.sockloop import PeerDealers, SocketLoop, open_socket
 from ray_tpu.core.task_spec import TaskSpec
 from ray_tpu.exceptions import GetTimeoutError
 
@@ -100,7 +101,8 @@ class Runtime:
         # receiver-side. Resends re-enter the flusher queue (thread-safe)
         # and pass the chaos filter again like any first transmission.
         self._reliable = RD.maybe_transport(
-            self.config, self._reliable_resend, self._reliable_ack,
+            self.config, self._reliable_resend,
+            lambda route, pl: self._reliable_resend(route, P.MSG_ACK, pl),
             rng=self._chaos.rng_for("retransmit")
             if self._chaos is not None else None, name=kind,
             recorder=self.recorder)
@@ -238,27 +240,9 @@ class Runtime:
         self._cb_thread.start()
 
         self.ctx = zmq.Context.instance()
-        self.sock = self.ctx.socket(zmq.DEALER)
-        self.sock.setsockopt(zmq.IDENTITY, self.worker_id.binary())
-        self.sock.setsockopt(zmq.LINGER, 0)
-        # unbounded queues: a burst of task results must never be dropped
-        # at the HWM (the control plane has no retransmit)
-        self.sock.setsockopt(zmq.SNDHWM, 0)
-        self.sock.setsockopt(zmq.RCVHWM, 0)
-        self.sock.connect(P.socket_path(session_dir))
-        self._send_lock = threading.Lock()
-        # direct peer channel (reference: direct_actor_transport.h — actor
-        # calls and task results move worker<->worker without the broker).
-        # The ROUTER is recv-only (pump thread); outgoing peer DEALERs are
-        # owned by the flusher thread.
         D.ensure_dir(session_dir)
-        self.direct_sock = self.ctx.socket(zmq.ROUTER)
-        self.direct_sock.setsockopt(zmq.LINGER, 0)
-        self.direct_sock.setsockopt(zmq.SNDHWM, 0)
-        self.direct_sock.setsockopt(zmq.RCVHWM, 0)
-        self.direct_sock.bind(D.direct_addr(session_dir, self.worker_id.binary()))
-        self._peer_socks: Dict[bytes, list] = {}  # flusher-owned: [sock, last_used]
-        self._last_peer_prune = time.time()
+        self._peers = PeerDealers(  # flusher-owned
+            self.ctx, self.worker_id.binary(), session_dir)
         # client-side actor submitter state machine (reference:
         # CoreWorkerDirectActorTaskSubmitter: per-actor connection state +
         # pending queue, direct_actor_task_submitter.h)
@@ -268,23 +252,20 @@ class Runtime:
         # a restarted controller on RECONNECT)
         self._inflight_specs: Dict[bytes, TaskSpec] = {}
         self._inflight_lock = threading.Lock()
-        # all sends go through one flusher thread: preserves FIFO order,
-        # moves pickling off the caller's critical path, and coalesces
-        # consecutive task submissions into SUBMIT_BATCH messages
-        # (reference: pipelined submission, direct_task_transport.h:157)
+        # every send goes through the flusher thread: it preserves FIFO
+        # order, moves pickling off the caller's critical path and
+        # coalesces consecutive task submissions into SUBMIT_BATCH
+        # messages (reference: pipelined submission,
+        # direct_task_transport.h:157). It owns the outgoing peer DEALERs;
+        # controller-bound frames it hands to the pump's outbox.
         self._out_q: "SimpleQueue[Optional[Tuple[bytes, Any]]]" = SimpleQueue()
         self._flusher = threading.Thread(target=self._flush_loop,
                                          name=f"{kind}-flush", daemon=True)
         self._flusher.start()
-        # wake channel so shutdown can interrupt the pump's long poll and
-        # join it before closing the DEALER (zmq sockets are not
-        # thread-safe; close must not race poll/recv)
-        self._pump_wake_recv = self.ctx.socket(zmq.PULL)
-        self._pump_wake_recv.bind(f"inproc://pump-wake-{id(self)}")
-        self._pump_wake_send = self.ctx.socket(zmq.PUSH)
-        self._pump_wake_send.connect(f"inproc://pump-wake-{id(self)}")
-        self._pump = threading.Thread(target=self._pump_loop,
-                                      name=f"{kind}-pump", daemon=True)
+        # the pump thread owns the controller DEALER and the direct ROUTER
+        # (core/sockloop.py): it alone opens, polls, reads, writes and
+        # closes them
+        self._pump = SocketLoop(f"{kind}-pump", self._open_sockets)
         self._pump.start()
         if kind == "driver":
             # liveness poke: an idle driver otherwise never speaks, so a
@@ -325,17 +306,13 @@ class Runtime:
 
     # ------------------------------------------------------------ transport
     def _reliable_resend(self, target, mtype: bytes, payload) -> None:
-        """Retransmit hook (reliable-layer thread): re-enqueue through
-        the flusher so the resend takes the same path — stamped payloads
-        pass through ``stamp()`` untouched."""
+        """Retransmit and batched-ack hook (reliable-layer thread):
+        re-enqueue through the flusher so the resend takes the same path
+        (stamped payloads pass through ``stamp()`` untouched) and an ack
+        ships back over the link the stamped messages arrived on (None =
+        the controller DEALER)."""
         if not self._stopped.is_set():
             self._out_q.put((target, mtype, payload))
-
-    def _reliable_ack(self, route, payload) -> None:
-        """Batched-ack hook: ship back over the link the stamped
-        messages arrived on (None = the controller DEALER)."""
-        if not self._stopped.is_set():
-            self._out_q.put((route, P.MSG_ACK, payload))
 
     def _send(self, mtype: bytes, payload: Any) -> None:
         self._out_q.put((None, mtype, payload))
@@ -357,13 +334,7 @@ class Runtime:
     def _send_direct(self, target: bytes, mtype: bytes, payload: Any) -> None:
         """Queue a message for a peer's direct channel (``target`` is the
         peer's identity bytes). Same-process sends short-circuit."""
-        if target == self.worker_id.binary():
-            try:
-                self._on_message(mtype, payload)
-            except Exception:
-                logger.exception("%s: error in local direct %s", self.kind, mtype)
-            return
-        self._out_q.put((target, mtype, payload))
+        self._send_many([(target, mtype, payload)])
 
     def _send_many(self, msgs: List[Tuple[Optional[bytes], bytes, Any]]
                    ) -> None:
@@ -384,36 +355,24 @@ class Runtime:
         if rest:
             self._out_q.put(rest)
 
-    def _sock_send(self, mtype: bytes, blob: bytes) -> None:
-        with self._send_lock:
-            self.sock.send_multipart([mtype, blob])
-
-    def _peer_sock(self, target: bytes) -> "zmq.Socket":
-        """Flusher-thread-only: lazily connected DEALER to a peer ROUTER."""
-        ent = self._peer_socks.get(target)
-        if ent is None:
-            s = self.ctx.socket(zmq.DEALER)
-            s.setsockopt(zmq.IDENTITY, self.worker_id.binary())
-            s.setsockopt(zmq.LINGER, 0)
-            s.setsockopt(zmq.SNDHWM, 0)
-            s.connect(D.direct_addr(self.session_dir, target))
-            ent = self._peer_socks[target] = [s, time.time()]
-        else:
-            ent[1] = time.time()
-        return ent[0]
-
-    def _prune_peer_socks(self, idle_s: float = 120.0) -> None:
-        """Flusher-thread-only. ipc connects never fail, so a DEALER to a
-        dead peer would otherwise queue messages forever (SNDHWM=0) and the
-        socket itself leak; idle-pruning bounds both."""
-        now = time.time()
-        for target in [t for t, (_, used) in self._peer_socks.items()
-                       if now - used > idle_s]:
-            sock, _ = self._peer_socks.pop(target)
-            try:
-                sock.close(0)
-            except Exception:
-                pass
+    def _open_sockets(self):
+        """Pump thread: the DEALER to the controller and the direct peer
+        channel's ROUTER (reference: direct_actor_transport.h — actor
+        calls and task results move worker<->worker without the broker).
+        The ROUTER is recv-only."""
+        self.sock = open_socket(self.ctx, zmq.DEALER,
+                                self.worker_id.binary())
+        self.sock.connect(P.socket_path(self.session_dir))
+        self.direct_sock = open_socket(self.ctx, zmq.ROUTER)
+        self.direct_sock.bind(
+            D.direct_addr(self.session_dir, self.worker_id.binary()))
+        return [
+            (self.sock,
+             lambda f: self._on_message(f[0], P.loads(f[1]))),
+            # [sender identity, mtype, payload]
+            (self.direct_sock,
+             lambda f: self._on_message(f[1], P.loads(f[2]), source=f[0])),
+        ]
 
     def _send_deferred(self, mtype: bytes, payload: Any) -> None:
         """Queue a controller-bound message that tolerates a few ms of
@@ -442,7 +401,7 @@ class Runtime:
                 else:
                     item = self._out_q.get()
             except Exception:
-                return
+                break
             batch = [item]
             while len(batch) < 512:
                 try:
@@ -489,11 +448,10 @@ class Runtime:
                 deferred = []
             for target, msgs in boxes.items():
                 self._flush_box(target, msgs)
-            if time.time() - self._last_peer_prune > 30.0:
-                self._last_peer_prune = time.time()
-                self._prune_peer_socks()
+            self._peers.prune()
             if stop:
-                return
+                break
+        self._peers.close()  # on their owner, as the pump closes its own
 
     def _flush_box(self, target: Optional[bytes],
                    msgs: List[Tuple[bytes, Any]]) -> None:
@@ -509,19 +467,20 @@ class Runtime:
             msgs = self._chaos_filter(target, msgs)
             if not msgs:
                 return
-        send = self._sock_send if target is None else \
-            (lambda mt, blob: self._peer_sock(target).send_multipart([mt, blob]))
+        # controller-bound frames go to the pump, which owns that socket
+        send = self._pump.post if target is None \
+            else self._peers.get(target).send_multipart
         try:
             if len(msgs) == 1:
-                send(msgs[0][0], P.dumps(msgs[0][1]))
+                send([msgs[0][0], P.dumps(msgs[0][1])])
             else:
-                send(P.MSG_BATCH, P.dumps({"msgs": msgs}))
+                send([P.MSG_BATCH, P.dumps({"msgs": msgs})])
         except Exception:
             # one bad payload must not discard the whole batch: retry
             # each message individually, dropping only the culprit
             for mtype, payload in msgs:
                 try:
-                    send(mtype, P.dumps(payload))
+                    send([mtype, P.dumps(payload)])
                 except Exception:
                     if not self._stopped.is_set():
                         logger.exception(
@@ -530,10 +489,10 @@ class Runtime:
     def _chaos_filter(self, target: Optional[bytes],
                       msgs: List[Tuple[bytes, Any]]
                       ) -> List[Tuple[bytes, Any]]:
-        """Fault-injection choke point for every outgoing message (the
-        flusher thread owns all sends, so one hook covers the controller
-        DEALER and every peer channel). Dropped messages vanish here;
-        delayed ones re-enter the flusher queue on a timer; duplicates
+        """Fault-injection choke point for every outgoing message (all
+        of them pass through the flusher thread, so one hook covers the
+        controller DEALER and every peer channel). Dropped messages vanish
+        here; delayed ones re-enter the flusher queue on a timer; duplicates
         ship twice with one wire seq (receivers dedup)."""
         out: List[Tuple[bytes, Any]] = []
         for mtype, payload in msgs:
@@ -557,49 +516,6 @@ class Runtime:
         if isinstance(reply, dict) and reply.get("__error__"):
             raise RuntimeError(reply["data"])
         return reply
-
-    def _pump_loop(self) -> None:
-        poller = zmq.Poller()
-        poller.register(self.sock, zmq.POLLIN)
-        poller.register(self.direct_sock, zmq.POLLIN)
-        poller.register(self._pump_wake_recv, zmq.POLLIN)
-        # long idle timeout: poll wakes instantly on traffic; frequent
-        # timer wakeups across many processes starve small hosts
-        while not self._stopped.is_set():
-            try:
-                events = dict(poller.poll(timeout=1000))
-            except zmq.ZMQError:
-                break
-            if self._pump_wake_recv in events:
-                try:
-                    while True:
-                        self._pump_wake_recv.recv(zmq.NOBLOCK)
-                except zmq.ZMQError:
-                    pass
-            if self.sock in events:
-                while True:
-                    try:
-                        frames = self.sock.recv_multipart(zmq.NOBLOCK)
-                    except zmq.ZMQError:
-                        break
-                    try:
-                        self._on_message(frames[0], P.loads(frames[1]))
-                    except Exception:
-                        logger.exception("%s: error handling %s", self.kind,
-                                         frames[0])
-            if self.direct_sock in events:
-                while True:
-                    try:
-                        frames = self.direct_sock.recv_multipart(zmq.NOBLOCK)
-                    except zmq.ZMQError:
-                        break
-                    try:
-                        # [sender identity, mtype, payload]
-                        self._on_message(frames[1], P.loads(frames[2]),
-                                         source=frames[0])
-                    except Exception:
-                        logger.exception("%s: error handling direct %s",
-                                         self.kind, frames[1])
 
     def _on_message(self, mtype: bytes, m: dict, source=None) -> None:
         if self._chaos_dedup is not None and CH.check_dedup(
@@ -769,24 +685,12 @@ class Runtime:
         if self._reliable is not None:
             self._reliable.stop()
         self._cb_queue.put(None)
-        # sentinel after the final enqueues: FIFO guarantees they flush
+        # sentinel after the final enqueues: FIFO guarantees they flush.
+        # The flusher drains and closes its peer sockets, then the pump
+        # sends what it was handed and closes its own.
         self._out_q.put(None)
         self._flusher.join(timeout=2.0)
-        try:
-            self._pump_wake_send.send(b"", zmq.NOBLOCK)
-        except Exception:
-            pass
-        self._pump.join(timeout=2.0)
-        try:
-            self.sock.close(0)
-            self.direct_sock.close(0)
-            for s, _ in self._peer_socks.values():
-                s.close(0)
-            self._peer_socks.clear()
-            self._pump_wake_recv.close(0)
-            self._pump_wake_send.close(0)
-        except Exception:
-            pass
+        self._pump.stop(wait_s=2.0)
         if self.shm:
             self.shm.close()
 

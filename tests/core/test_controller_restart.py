@@ -27,12 +27,7 @@ def _crash_and_restart_controller():
     from ray_tpu.core.controller import Controller
     head = api._head
     old = head.controller
-    old._shutdown.set()          # stop loops without any state flush
-    try:
-        old._wake_send.send(b"")
-    except Exception:
-        pass
-    old._thread.join(timeout=5)
+    old.halt()                   # stop loops without any state flush
     head.controller = Controller(head.session_dir, old.config)
     head.controller.start()
     return head.controller
@@ -56,17 +51,29 @@ def test_state_survives_controller_restart(cluster):
     c = Counter.options(name="survivor", lifetime="detached").remote()
     assert ray_tpu.get(c.inc.remote(), timeout=60) == 1
 
-    _crash_and_restart_controller()
+    new = _crash_and_restart_controller()
 
-    # KV recovered from the WAL
-    deadline = time.time() + 30
+    # wait on the reconnect itself, not on a wall-clock guess: the
+    # driver's next ping draws a RECONNECT from the new controller, and
+    # handling it stamps the new generation
+    deadline = time.time() + 120
+    while w._reconnect_gen != new.generation and time.time() < deadline:
+        time.sleep(0.05)
+    assert w._reconnect_gen == new.generation
+
+    # KV recovered from the WAL. A request written to the dead ROUTER's
+    # connection is lost for good (RPCs have no retransmit): short
+    # per-request timeouts, so one loss costs 5 s and not rpc_timeout_s
+    from ray_tpu.core import protocol as P
     val = None
     while time.time() < deadline:
         try:
-            val = w.kv_get(b"persist-key", ns="testns")
+            val = w.request(P.KV_OP, {"op": "get", "ns": "testns",
+                                      "key": b"persist-key"},
+                            timeout=5.0)["value"]
             break
         except Exception:
-            time.sleep(0.5)
+            time.sleep(0.1)
     assert val == b"persist-value"
 
     # the existing handle still works: calls ride the direct channel to

@@ -30,13 +30,13 @@ def test_flush_batch_bad_payload_does_not_drop_batch():
 
     sent = []
 
+    class FakePump:
+        post = staticmethod(lambda frames: sent.append(tuple(frames)))
+
     class FakeRuntime:
         kind = "test"
         _stopped = threading.Event()
-        _sock_send = staticmethod(lambda mt, blob: sent.append((mt, blob)))
-
-        def _peer_sock(self, target):  # pragma: no cover
-            raise AssertionError("no peers in this test")
+        _pump = FakePump()  # no peers in this test: no _peers
 
     class Unpicklable:
         def __reduce__(self):
