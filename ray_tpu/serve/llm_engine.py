@@ -148,6 +148,71 @@ class EngineConfig:
         return 2 * c.n_layers * c.kv_heads * c.head_dim * itemsize
 
 
+def _unpack(rows, width: int, scalars: int):
+    """A step program's integer inputs out of the one int32 array it is
+    fed by, by static slices: every row is ``[width tokens | scalars |
+    block-table row]``. Returns ``(tokens, *scalar columns, table)``."""
+    cols = [rows[:, width + i] for i in range(scalars)]
+    return (rows[:, :width], *cols, rows[:, width + scalars:])
+
+
+def _step_fns(model_config, ec: EngineConfig):
+    """The engine's step programs, unjitted: ``fn(params, rows, cache)``
+    with ``rows`` the staged int32 array — prefill ``[1, prefill_chunk +
+    2 + blocks_per_seq]`` (chunk tokens, start, n, the slot's table
+    row), decode ``[decode_slots, 2 + blocks_per_seq]`` (token, length,
+    table row of every slot), verify ``[decode_slots, spec_tokens + 1 +
+    2 + blocks_per_seq]`` (prefill's layout over all slots). With
+    ``capture_logprobs`` prefill and decode also return the selected
+    token's logprob (greedy argmax is unchanged — the extra output is
+    the RLHF rollout payload, not a sampling change)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import decode_step, prefill
+    capture = ec.capture_logprobs
+
+    def _prefill_fn(params, rows, cache):
+        tokens, start, lens, bt = _unpack(rows, ec.prefill_chunk, 2)
+        logits, cache = prefill(model_config, params, tokens, cache,
+                                bt, start, lens)
+        with jax.named_scope("sample"):
+            last = jnp.take_along_axis(
+                logits, (lens - 1)[:, None, None], axis=1)[:, 0]
+            tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            if capture:
+                lp = jnp.take_along_axis(
+                    jax.nn.log_softmax(last, axis=-1), tok[:, None],
+                    axis=-1)[:, 0]
+                return tok, lp, cache
+        return tok, cache
+
+    def _decode_fn(params, rows, cache):
+        toks, seq_lens, bt = _unpack(rows, 1, 1)
+        logits, cache = decode_step(model_config, params, toks[:, 0],
+                                    cache, bt, seq_lens)
+        with jax.named_scope("sample"):
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if capture:
+                lp = jnp.take_along_axis(
+                    jax.nn.log_softmax(logits, axis=-1),
+                    tok[:, None], axis=-1)[:, 0]
+                return tok, lp, cache
+        return tok, cache
+
+    # speculative verify: the whole slot array steps k+1 tokens per
+    # call through the chunked-prefill trunk (positions/write-masks
+    # already handle ragged per-slot lengths); per-position argmax
+    # comes back for host-side longest-prefix acceptance
+    def _verify_fn(params, rows, cache):
+        toks, start, lens, bt = _unpack(rows, ec.spec_tokens + 1, 2)
+        logits, cache = prefill(model_config, params, toks, cache,
+                                bt, start, lens)
+        with jax.named_scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+
+    return _prefill_fn, _decode_fn, _verify_fn
+
+
 _DONE = object()          # stream-end sentinel on the request queue
 
 # request lifecycle states
@@ -223,8 +288,8 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from ray_tpu.models import (decode_step, inference_params,
-                                    init_kv_cache, init_params, prefill)
+        from ray_tpu.models import (inference_params, init_kv_cache,
+                                    init_params)
 
         self.model_config = model_config
         self.config = engine_config or EngineConfig()
@@ -285,12 +350,15 @@ class LLMEngine:
         S, T = ec.decode_slots, ec.blocks_per_seq
         self._np = np
         self._jnp = jnp
-        # Host-side slot arrays. Block-table row 0s point idle slots at
-        # the reserved trash block, so their (masked-garbage) decode
-        # writes never touch a live sequence's blocks.
-        self._block_tables = np.zeros((S, T), np.int32)
-        self._seq_lens = np.zeros((S,), np.int32)
-        self._last_tok = np.zeros((S,), np.int32)
+        # Host-side slot arrays: views of the one array a decode step is
+        # fed by, [token, length, block-table row] a slot. Block-table
+        # row 0s point idle slots at the reserved trash block, so their
+        # (masked-garbage) decode writes never touch a live sequence's
+        # blocks.
+        self._slot_rows = np.zeros((S, 2 + T), np.int32)
+        self._last_tok = self._slot_rows[:, 0]
+        self._seq_lens = self._slot_rows[:, 1]
+        self._block_tables = self._slot_rows[:, 2:]
         self._slots: List[Optional[_Request]] = [None] * S
         self._free_slots = list(range(S))
         # refcounted block pool + radix prefix index (block 0 = trash,
@@ -307,53 +375,13 @@ class LLMEngine:
         # it and hands it whole to the paged kernel, so the donated
         # buffer is the output buffer and nothing copies a layer of it
         # (tests/ops/test_tpu_lowering.py reads that off the programs
-        # the TPU compiler builds). With capture_logprobs
-        # the same programs also return the selected token's logprob
-        # (greedy argmax is unchanged — the extra output is the RLHF
-        # rollout payload, not a sampling change).
-        capture = ec.capture_logprobs
-
-        def _prefill_fn(params, tokens, cache, bt, start, lens):
-            logits, cache = prefill(model_config, params, tokens, cache,
-                                    bt, start, lens)
-            with jax.named_scope("sample"):
-                last = jnp.take_along_axis(
-                    logits, (lens - 1)[:, None, None], axis=1)[:, 0]
-                tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                if capture:
-                    lp = jnp.take_along_axis(
-                        jax.nn.log_softmax(last, axis=-1), tok[:, None],
-                        axis=-1)[:, 0]
-                    return tok, lp, cache
-            return tok, cache
-
-        def _decode_fn(params, toks, cache, bt, seq_lens):
-            logits, cache = decode_step(model_config, params, toks,
-                                        cache, bt, seq_lens)
-            with jax.named_scope("sample"):
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                if capture:
-                    lp = jnp.take_along_axis(
-                        jax.nn.log_softmax(logits, axis=-1),
-                        tok[:, None], axis=-1)[:, 0]
-                    return tok, lp, cache
-            return tok, cache
-
-        self._jit_prefill = jax.jit(_prefill_fn, donate_argnums=(2,))
-        self._jit_decode = jax.jit(_decode_fn, donate_argnums=(2,))
-
-        # speculative verify: the whole slot array steps k+1 tokens per
-        # call through the chunked-prefill trunk (positions/write-masks
-        # already handle ragged per-slot lengths); per-position argmax
-        # comes back for host-side longest-prefix acceptance. Jitted
-        # once at (S, k+1) — drafting never recompiles.
-        def _verify_fn(params, toks, cache, bt, start, lens):
-            logits, cache = prefill(model_config, params, toks, cache,
-                                    bt, start, lens)
-            with jax.named_scope("sample"):
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-        self._jit_verify = jax.jit(_verify_fn, donate_argnums=(2,)) \
+        # the TPU compiler builds).
+        prefill_fn, decode_fn, verify_fn = _step_fns(model_config, ec)
+        self._jit_prefill = jax.jit(prefill_fn, donate_argnums=(2,))
+        self._jit_decode = jax.jit(decode_fn, donate_argnums=(2,))
+        # speculative verify is jitted once at (S, k+1) — drafting never
+        # recompiles
+        self._jit_verify = jax.jit(verify_fn, donate_argnums=(2,)) \
             if ec.spec_tokens > 0 else None
 
         # copy-on-write block copy (fully-matched prompt tail): one
@@ -425,6 +453,17 @@ class LLMEngine:
         self._tokens_total = 0
         self._decode_steps = 0
         self._prefill_chunks = 0
+        # The newest step program's results. A result's device buffer is
+        # let go where the next program's results take this place, inside
+        # that program's *.dispatch and so while the device runs it; let
+        # go when its fetch returns, it is freed with the device idle
+        self._spent = None
+        # host-to-device transfers the step thread staged: one a step
+        # program (its integer inputs as one numpy array, uploaded by
+        # the jitted call itself: on the chip that was 0.2 ms a program
+        # cheaper than an explicit device_put ahead of the call), so it
+        # equals prefill_chunks + decode_steps
+        self._h2d_transfers = 0
         # device-wall split (the kernel-vs-reference bench reads these):
         # decode wall includes the result sync the step loop does anyway
         self._decode_wall_s = 0.0
@@ -944,6 +983,7 @@ class LLMEngine:
             self._tokens_total = 0
             self._decode_steps = 0
             self._prefill_chunks = 0
+            self._h2d_transfers = 0
             self._decode_wall_s = self._prefill_wall_s = 0.0
             self._decode_pages_live = self._decode_pages_window = 0
             self._prompt_blocks_total = 0
@@ -983,6 +1023,7 @@ class LLMEngine:
                 "tokens_per_s": round(self._tokens_total / elapsed, 2),
                 "decode_steps": self._decode_steps,
                 "prefill_chunks": self._prefill_chunks,
+                "h2d_transfers_total": self._h2d_transfers,
                 # device-wall split + length-aware work fraction (the
                 # paged-kernel bench legs and perf gate read these)
                 "decode_wall_s": round(self._decode_wall_s, 4),
@@ -1256,7 +1297,7 @@ class LLMEngine:
                         mtok -= bs
                 n_priv = need - len(matched) - (1 if cow_src is not None
                                                 else 0)
-                priv = self._pool.allocate(n_priv)
+                priv = self._allocate_locked(n_priv)
                 if priv is None:
                     # full occupancy: release the match and WAIT for
                     # blocks (shapes are fixed; admission pressure
@@ -1314,6 +1355,15 @@ class LLMEngine:
                 with self._lock:
                     self._pool.release([cow_src])
 
+    def _allocate_locked(self, n: int) -> Optional[List[int]]:
+        """``n`` private blocks for an admission, or None under pool
+        pressure (call with self._lock held). What the free list cannot
+        cover is evicted from the trie, under a phase of its own."""
+        if n <= len(self._pool._free):
+            return self._pool.allocate(n)
+        with self._clock.phase("engine.admit.evict"):
+            return self._pool.allocate(n)
+
     def _stamp_slot(self, req: _Request) -> float:
         """A decode slot is won: the end of the queue wait, traced or
         not. Returns the wall clock the trace spans use."""
@@ -1352,7 +1402,7 @@ class LLMEngine:
             if ec.enable_prefix_sharing:
                 matched, mtok, req.trie_node = \
                     self._pool.match_prefix(req.prompt)
-            priv = self._pool.allocate(need - len(matched))
+            priv = self._allocate_locked(need - len(matched))
             if priv is None:
                 self._pool.release(matched)
                 req.trie_node = None
@@ -1535,15 +1585,17 @@ class LLMEngine:
             req = self._prefilling[0] if self._prefilling else None
         if req is None:
             return
-        np, jnp = self._np, self._jnp
+        np = self._np
         ec = self.config
         clock = self._clock
         C = ec.prefill_chunk
         start = req.prefill_pos
         n = min(C, len(req.prompt) - start)
         with clock.phase("engine.prefill.stage"):
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :n] = req.prompt[start:start + n]
+            row = np.zeros((1, C + 2 + ec.blocks_per_seq), np.int32)
+            row[0, :n] = req.prompt[start:start + n]
+            row[0, C:C + 2] = start, n
+            row[0, C + 2:] = self._block_tables[req.slot]
             t0w = time.time()
             t0 = time.monotonic()
             if req.t_first_chunk is None:
@@ -1553,23 +1605,23 @@ class LLMEngine:
                     # slot won, waiting behind other requests' chunks
                     req.trace.span(RT.PREFILL_WAIT,
                                    t0w - (t0 - req.t_slot), t0w)
-            toks = jnp.asarray(chunk)
-            bt = jnp.asarray(self._block_tables[req.slot:req.slot + 1])
-            at = jnp.full((1,), start, jnp.int32)
-            lens = jnp.full((1,), n, jnp.int32)
+            self._h2d_transfers += 1
         with clock.phase("engine.prefill.dispatch"):
-            out = self._jit_prefill(self._params, toks, self._cache, bt,
-                                    at, lens)
-            # let go of the uploads while the device is busy: freed
-            # later, they are freed on the next program's path
-            del toks, bt, at, lens
+            # the call uploads the row, its one transfer, and holds the
+            # only reference to the device's copy: nothing of it is left
+            # to free on the next program's path; the last program's
+            # results go here, under this one (_spent)
+            out = self._spent = self._jit_prefill(self._params, row,
+                                                  self._cache)
         if self.config.capture_logprobs:
             tok, lp, self._cache = out
         else:
             tok, self._cache = out
             lp = None
         with clock.phase("engine.prefill.wait"):
-            self._jax.block_until_ready(tok)
+            tok = np.asarray(tok)
+            if lp is not None:
+                lp = np.asarray(lp)
         self._prefill_wall_s += time.monotonic() - t0
         with clock.phase("engine.prefill.book"):
             self._book_prefill(req, start, n, t0w, tok, lp)
@@ -1659,7 +1711,6 @@ class LLMEngine:
         if not active:
             return
         clock = self._clock
-        jnp = self._jnp
         with clock.phase("engine.decode.stage"):
             with self._lock:
                 self._decode_steps += 1
@@ -1670,17 +1721,13 @@ class LLMEngine:
                             len(active))
                     except Exception:
                         pass
-                toks = self._last_tok.copy()
-                lens = self._seq_lens.copy()
-                bt = self._block_tables.copy()
-            self._account_decode_pages(lens + 1)
+                rows = self._slot_rows.copy()
+            self._account_decode_pages(rows[:, 1] + 1)
             t0 = time.monotonic()
-            toks, bt, lens = (jnp.asarray(toks), jnp.asarray(bt),
-                              jnp.asarray(lens))
+            self._h2d_transfers += 1
         with clock.phase("engine.decode.dispatch"):
-            res = self._jit_decode(self._params, toks, self._cache, bt,
-                                   lens)
-            del toks, bt, lens      # as in _prefill_one_chunk
+            res = self._spent = self._jit_decode(self._params, rows,
+                                                 self._cache)
         with clock.phase("engine.decode.wait"):
             if self.config.capture_logprobs:
                 out, lps, self._cache = res
@@ -1766,7 +1813,6 @@ class LLMEngine:
         if not active:
             return
         clock = self._clock
-        jnp = self._jnp
         with clock.phase("engine.decode.stage"):
             with self._lock:
                 self._decode_steps += 1
@@ -1777,9 +1823,8 @@ class LLMEngine:
                             len(active))
                     except Exception:
                         pass
-                toks = np.zeros((S, L), np.int32)
-                lens = np.zeros((S,), np.int32)
-                starts = np.zeros((S,), np.int32)
+                # a row: [last token, drafts | start | n | table row]
+                rows = np.zeros((S, L + 2 + ec.blocks_per_seq), np.int32)
                 drafts: Dict[int, List[int]] = {}
                 for req in active:
                     s = req.slot
@@ -1792,23 +1837,19 @@ class LLMEngine:
                                  req.max_new_tokens - req.generated + 1)
                     d = [] if req.spec_disabled else \
                         self._draft(req, max(0, budget - 1))
-                    toks[s, 0] = self._last_tok[s]
+                    rows[s, 0] = self._last_tok[s]
                     if d:
-                        toks[s, 1:1 + len(d)] = d
-                    lens[s] = 1 + len(d)
-                    starts[s] = req.seq_len
+                        rows[s, 1:1 + len(d)] = d
+                    rows[s, L:L + 2] = req.seq_len, 1 + len(d)
                     drafts[s] = d
-                bt = self._block_tables.copy()
-            self._account_decode_pages(starts + lens)
+                rows[:, L + 2:] = self._block_tables
+            self._account_decode_pages(rows[:, L] + rows[:, L + 1])
             t0w = time.time()
             t0 = time.monotonic()
-            toks_d, bt, starts_d, lens_d = (
-                jnp.asarray(toks), jnp.asarray(bt), jnp.asarray(starts),
-                jnp.asarray(lens))
+            self._h2d_transfers += 1
         with clock.phase("engine.decode.dispatch"):
-            preds, self._cache = self._jit_verify(
-                self._params, toks_d, self._cache, bt, starts_d, lens_d)
-            del toks_d, bt, starts_d, lens_d    # as in _prefill_one_chunk
+            preds, self._cache = self._spent = self._jit_verify(
+                self._params, rows, self._cache)
         with clock.phase("engine.decode.wait"):
             preds = np.asarray(preds)
         self._decode_wall_s += time.monotonic() - t0

@@ -25,6 +25,14 @@ chunks, generated tokens, speculative drafts) always land at positions
 partial tail of a fully-matched prompt is handled by the engine with a
 copy-on-write block copy (see ``LLMEngine._admit``).
 
+Cost: the engine calls the pool on its step thread, with the device
+idle, so nothing here walks the pool. The evictable set (ref-0 leaves)
+is a heap ordered by ``touch`` with lazy deletion — an entry is dropped
+when it is popped and its node is no longer an untouched ref-0 leaf —
+so an eviction is O(log n); ``stats()`` reads counts kept where
+refcounts change; the root's children are kept in touch order with
+their fingerprints, so ``root_fingerprints`` is O(limit).
+
 Thread model: the pool is NOT internally locked — the engine calls it
 with its scheduler lock held (all mutations happen on the step
 thread).
@@ -33,6 +41,8 @@ thread).
 from __future__ import annotations
 
 import collections
+import heapq
+import itertools
 import zlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -90,6 +100,16 @@ class PrefixBlockPool:
         self._node_of: Dict[int, _TrieNode] = {}  # trie-resident blocks
         self._root = _TrieNode(None, None, None)
         self._clock = 0
+        # ref-0 leaves as (touch, push number, node), least recently
+        # touched first; the push number keeps two entries of one node
+        # and one touch from comparing nodes
+        self._evictable: List[Tuple[int, int, _TrieNode]] = []
+        self._pushes = 0
+        # the root's children -> first-block fingerprint, in touch order
+        self._root_fps: "collections.OrderedDict[_TrieNode, Optional[int]]" \
+            = collections.OrderedDict()
+        self._cached = 0           # ref-0, trie-resident
+        self._shared = 0           # refcount > 1
         # -- counters (engine surfaces these in stats())
         self.hits_total = 0        # blocks handed out via prefix match
         self.inserts_total = 0
@@ -97,21 +117,30 @@ class PrefixBlockPool:
 
     # ------------------------------------------------------- refcounts
     def incref(self, block: int) -> None:
-        if block in self._ref:
-            self._ref[block] += 1
-        else:
-            # resurrecting a cached (ref-0, trie-resident) block
-            self._ref[block] = 1
+        n = self._ref.get(block, 0)
+        self._ref[block] = n + 1
+        if n == 1:
+            self._shared += 1
+        elif n == 0:
+            # resurrecting a cached (ref-0, trie-resident) block; its
+            # heap entries are dropped when they are popped
+            self._cached -= 1
 
     def decref(self, block: int) -> None:
         n = self._ref[block] - 1
         if n > 0:
             self._ref[block] = n
+            if n == 1:
+                self._shared -= 1
             return
         del self._ref[block]
-        if block not in self._node_of:
+        node = self._node_of.get(block)
+        if node is None:
             self._free.append(block)
-        # else: stays resident in the trie as reusable cache
+            return
+        # stays resident in the trie as reusable cache
+        self._cached += 1
+        self._offer(node)
 
     def release(self, blocks: Sequence[int]) -> None:
         for b in blocks:
@@ -121,6 +150,29 @@ class PrefixBlockPool:
     def _touch(self, node: _TrieNode) -> None:
         self._clock += 1
         node.touch = self._clock
+        if node.parent is self._root:
+            self._root_fps.move_to_end(node)
+        self._offer(node)
+
+    def _offer(self, node: _TrieNode) -> None:
+        """Queue ``node`` for eviction if it is a ref-0 leaf. Called
+        wherever a node may have become one or been touched as one: a
+        release to zero, a touch, the eviction of its last child."""
+        if node.children or node.block in self._ref:
+            return
+        self._pushes += 1
+        heapq.heappush(self._evictable, (node.touch, self._pushes, node))
+        # entries outlive what they name (a re-touched or re-referenced
+        # node, a leaf that grew a child): bound them by the trie's size
+        if len(self._evictable) > 2 * len(self._node_of) + 64:
+            self._evictable = [e for e in self._evictable
+                               if self._is_victim(e[0], e[2])]
+            heapq.heapify(self._evictable)
+
+    def _is_victim(self, stamp: int, node: _TrieNode) -> bool:
+        """Whether a heap entry still names an untouched ref-0 leaf."""
+        return not (node.detached or node.children
+                    or node.block in self._ref or node.touch != stamp)
 
     def match_prefix(self, tokens: Sequence[int]
                      ) -> Tuple[List[int], int, _TrieNode]:
@@ -174,20 +226,22 @@ class PrefixBlockPool:
         referenced or cached children is load-bearing for deeper
         matches and never evicted; freeing a leaf may expose its
         parent as the next candidate)."""
-        best: Optional[Tuple[int, _TrieNode]] = None
-        for block, node in self._node_of.items():
-            if block in self._ref or node.children:
-                continue
-            if best is None or node.touch < best[1].touch:
-                best = (block, node)
-        if best is None:
+        while self._evictable:
+            stamp, _, node = heapq.heappop(self._evictable)
+            if self._is_victim(stamp, node):
+                break
+        else:
             return False
-        block, node = best
         node.detached = True
-        if node.parent is not None:
-            node.parent.children.pop(node.key, None)
-        del self._node_of[block]
-        self._free.append(block)
+        parent = node.parent
+        parent.children.pop(node.key, None)
+        if parent is self._root:
+            del self._root_fps[node]
+        else:
+            self._offer(parent)
+        del self._node_of[node.block]
+        self._free.append(node.block)
+        self._cached -= 1
         self.evictions_total += 1
         return True
 
@@ -215,6 +269,10 @@ class PrefixBlockPool:
         node = _TrieNode(parent, key, block)
         parent.children[key] = node
         self._node_of[block] = node
+        if block not in self._ref:
+            self._cached += 1
+        if parent is self._root:
+            self._root_fps[node] = prefix_fingerprint(key, self.block_size)
         self._touch(node)
         self.inserts_total += 1
         return node, True
@@ -265,28 +323,20 @@ class PrefixBlockPool:
     # -------------------------------------------------------- introspection
     def root_fingerprints(self, limit: int = 64) -> List[int]:
         """Fingerprints of the trie ROOT's children — the first-block
-        chunks this pool holds warm. O(root fan-out), capped at
-        ``limit`` (most-recently-touched first): cheap enough for every
-        ``Replica.stats()`` probe, rich enough for a router to place a
-        cold session where its system prompt already lives."""
-        kids = sorted(self._root.children.values(),
-                      key=lambda n: -n.touch)[:limit]
-        out = []
-        for node in kids:
-            fp = prefix_fingerprint(node.key, self.block_size)
-            if fp is not None:
-                out.append(fp)
-        return out
+        chunks this pool holds warm. O(``limit``), most-recently-touched
+        first (each was computed when its node was made): cheap enough
+        for every ``Replica.stats()`` probe, rich enough for a router to
+        place a cold session where its system prompt already lives."""
+        newest = itertools.islice(reversed(self._root_fps.values()), limit)
+        return [fp for fp in newest if fp is not None]
 
     def stats(self) -> Dict[str, int]:
-        cached = sum(1 for b in self._node_of if b not in self._ref)
-        shared = sum(1 for b, r in self._ref.items() if r > 1)
         return {
             "free": len(self._free),
-            "cached": cached,               # ref-0, trie-resident
-            "reclaimable": len(self._free) + cached,
+            "cached": self._cached,         # ref-0, trie-resident
+            "reclaimable": len(self._free) + self._cached,
             "active": len(self._ref),
-            "shared": shared,               # refcount > 1 right now
+            "shared": self._shared,         # refcount > 1 right now
             "trie_blocks": len(self._node_of),
             "hits_total": self.hits_total,
             "inserts_total": self.inserts_total,
@@ -341,4 +391,21 @@ class PrefixBlockPool:
         dangling = trie - reachable
         if dangling:
             problems.append(f"unreachable trie blocks: {sorted(dangling)}")
+        # the books kept beside the maps
+        cached = len(trie - ref)
+        shared = sum(1 for r in self._ref.values() if r > 1)
+        if (cached, shared) != (self._cached, self._shared):
+            problems.append(
+                f"cached/shared counts {self._cached}/{self._shared}, "
+                f"the maps say {cached}/{shared}")
+        queued = {node.block for stamp, _, node in self._evictable
+                  if node.touch == stamp and not node.detached}
+        unqueued = {b for b in trie - ref
+                    if not self._node_of[b].children} - queued
+        if unqueued:
+            problems.append(
+                f"ref-0 leaves no eviction can find: {sorted(unqueued)}")
+        if list(self._root_fps) != sorted(self._root.children.values(),
+                                          key=lambda n: n.touch):
+            problems.append("root children out of touch order")
         return problems

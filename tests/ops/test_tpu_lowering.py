@@ -112,6 +112,30 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _engine_program(cfg, entry, slots, table, chunk):
+    """The engine's own wrapper of ``entry`` (serve/llm_engine.py
+    ``_step_fns``: ``fn(params, rows, cache)``) and the shape of the one
+    int32 array it is fed by."""
+    from ray_tpu.serve.llm_engine import EngineConfig, _step_fns
+    prefill_fn, decode_fn, _ = _step_fns(cfg, EngineConfig(
+        decode_slots=slots, kv_block_size=BLOCK,
+        max_seq_len=table * BLOCK, prefill_chunk=chunk))
+    if entry == "decode_step":
+        return decode_fn, (slots, 2 + table)
+    return prefill_fn, (1, chunk + 2 + table)
+
+
+def _readers_of_the_staged_rows(text):
+    """(op, elements of its largest result) for every instruction of a
+    compiled program that takes the ``rows`` parameter."""
+    uses = re.findall(
+        r"= (.*?) ([\w\-]+)\((?:[^()]*, )?%rows[.\d]*[,)]", text)
+    return [(op, max(
+        functools.reduce(lambda a, b: a * int(b), dims.split(","), 1)
+        for dims in re.findall(r"\w+\[([\d,]+)\]", result)))
+        for result, op in uses]
+
+
 @pytest.mark.parametrize("entry", ["decode_step", "prefill"])
 def test_compiled_step_updates_the_donated_pool_in_place(
         entry, one_chip, monkeypatch):
@@ -120,9 +144,12 @@ def test_compiled_step_updates_the_donated_pool_in_place(
     the pool is an aliased input/output, it is handed to the paged
     kernel, and NO other op produces an array with a page's dimensions —
     no copy into another layout for the scatter, no slice or
-    update-slice of a layer. GQA at head_dim 128, two layers."""
-    from ray_tpu.models import (TransformerConfig, decode_step,
-                                init_kv_cache, init_params, prefill)
+    update-slice of a layer. GQA at head_dim 128, two layers. The
+    program is the engine's wrapper, whose integer inputs arrive as one
+    staged array: unpacking it is static slices of that array (a few
+    KiB), alone or fused into their readers, and nothing else."""
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params)
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cache_was = jax.config.jax_enable_compilation_cache
@@ -149,14 +176,9 @@ def test_compiled_step_updates_the_donated_pool_in_place(
             lambda: init_params(cfg, jax.random.PRNGKey(0))))
         cache = shaped(jax.eval_shape(
             lambda: init_kv_cache(cfg, blocks, BLOCK)))
-        if entry == "decode_step":
-            args = (params, i32(slots), cache, i32(slots, table),
-                    i32(slots))
-            fn = functools.partial(decode_step, cfg)
-        else:
-            args = (params, i32(1, chunk), cache, i32(1, table), i32(1),
-                    i32(1))
-            fn = functools.partial(prefill, cfg)
+        fn, rows = _engine_program(cfg, entry, slots, table, chunk)
+        args = (params, i32(*rows), cache)
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
         text = jax.jit(fn, donate_argnums=(2,)).lower(*args) \
             .compile().as_text()
     finally:
@@ -172,6 +194,18 @@ def test_compiled_step_updates_the_donated_pool_in_place(
     assert not moved, moved
     # the two scatters (k, v), alone or as the root of a fusion
     assert made.count("scatter") == 2
+    # the staged rows: traced as static slices, compiled to slices and
+    # fusions (or the rows moved whole into fast memory first) no result
+    # of which is larger than the rows themselves
+    staged, = [v for v in jaxpr.invars
+               if v.aval.shape == rows and v.aval.dtype == jnp.int32]
+    assert {e.primitive.name for e in jaxpr.eqns
+            if staged in e.invars} == {"slice"}
+    readers = _readers_of_the_staged_rows(text)
+    assert len(readers) >= 2 and rows[0] * rows[1] * 4 < 4096
+    assert {op for op, _ in readers} \
+        <= {"slice", "fusion", "copy-start"}, readers
+    assert max(n for _, n in readers) <= rows[0] * rows[1], readers
 
 
 @pytest.mark.parametrize("entry", ["decode_step", "prefill"])
@@ -188,9 +222,8 @@ def test_compiled_step_casts_no_weight_of_the_engines_tree(
     test would see if the engine's tree stopped being cast. Wide enough
     (2048 x 8192) that the compiler hoists the casts as it does at the
     served widths; narrower, it fuses them into the loop's matmuls."""
-    from ray_tpu.models import (TransformerConfig, decode_step,
-                                inference_params, init_kv_cache,
-                                init_params, prefill)
+    from ray_tpu.models import (TransformerConfig, inference_params,
+                                init_kv_cache, init_params)
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cache_was = jax.config.jax_enable_compilation_cache
@@ -213,15 +246,9 @@ def test_compiled_step_casts_no_weight_of_the_engines_tree(
     def compiled(params):
         cache = shaped(jax.eval_shape(
             lambda: init_kv_cache(cfg, blocks, BLOCK)))
-        if entry == "decode_step":
-            args = (shaped(params), i32(slots), cache, i32(slots, table),
-                    i32(slots))
-            fn = functools.partial(decode_step, cfg)
-        else:
-            args = (shaped(params), i32(1, chunk), cache, i32(1, table),
-                    i32(1), i32(1))
-            fn = functools.partial(prefill, cfg)
-        return jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+        fn, rows = _engine_program(cfg, entry, slots, table, chunk)
+        return jax.jit(fn, donate_argnums=(2,)).lower(
+            shaped(params), i32(*rows), cache).compile()
 
     def masters():
         return init_params(cfg, jax.random.PRNGKey(0))

@@ -26,6 +26,9 @@ TICK_CHILDREN = {
     "engine.prefill.book", "engine.decode.stage",
     "engine.decode.dispatch", "engine.decode.wait", "engine.decode.emit",
     "engine.report"}
+# a phase inside a phase: eviction, where an admission's blocks are not
+# all on the free list (six prompts of five blocks through 24: it runs)
+NESTED = {"engine.admit.evict": "engine.admit"}
 TTFT_KEYS = ("ttft_queue_s", "ttft_prefill_wait_s", "ttft_prefill_s")
 
 
@@ -89,8 +92,13 @@ def test_every_ticks_phases_lie_inside_it_and_cover_it(served):
         (_, lo, hi, parent), = [s for s in spans if s[0] == "engine.tick"]
         assert parent is None
         last = lo
+        for name, t0, t1, up in spans:
+            if name in NESTED:
+                assert up == NESTED[name] and any(
+                    s[0] == up and s[1] <= t0 <= t1 <= s[2] for s in spans)
         for name, t0, t1, up in sorted(
-                (s for s in spans if s[0] != "engine.tick"),
+                (s for s in spans
+                 if s[0] != "engine.tick" and s[0] not in NESTED),
                 key=lambda s: s[1]):
             assert name in TICK_CHILDREN and up == "engine.tick"
             assert last <= t0 <= t1 <= hi       # in order, no overlap
@@ -104,7 +112,11 @@ def test_every_ticks_phases_lie_inside_it_and_cover_it(served):
 def test_stats_carry_the_phase_table_and_the_gap(served):
     eng, _, _ = served
     st = eng.stats()
-    assert TICK_CHILDREN | {"engine.tick"} == set(st["phases"])
+    assert TICK_CHILDREN | {"engine.tick"} | set(NESTED) \
+        == set(st["phases"])
+    evictions, evict_s = st["phases"]["engine.admit.evict"]
+    assert 0 < evictions <= st["prefix_evictions_total"]
+    assert 0 < evict_s <= st["phases"]["engine.admit"][1]
     for name in ("prefill", "decode"):
         counts = {st["phases"][f"engine.{name}.{p}"][0]
                   for p in ("stage", "dispatch", "wait")}
@@ -177,7 +189,8 @@ def test_speculative_ticks_use_the_same_phase_names():
         eng.warmup()
         _serve(eng, n=3)
         st = eng.stats()
-        assert set(st["phases"]) == TICK_CHILDREN | {"engine.tick"}
+        assert set(st["phases"]) - set(NESTED) \
+            == TICK_CHILDREN | {"engine.tick"}
         assert st["phases"]["engine.decode.wait"][0] == st["decode_steps"]
         assert st["host_gap_s"] <= st["tick_wall_s"]
         spec = [s for tr in eng._tracer.recent for s in tr.spans
@@ -227,13 +240,14 @@ def test_compiled_programs_ops_carry_the_scopes():
     try:
         S, T = eng.config.decode_slots, eng.config.blocks_per_seq
         i32 = jnp.int32
+        # each step program's integers are one array: [tokens | start |
+        # n | table row] a prefill row, [token | length | table row] a
+        # decode slot
         prefill = eng._jit_prefill.lower(
-            eng._params, jnp.zeros((1, 8), i32), eng._cache,
-            jnp.zeros((1, T), i32), jnp.zeros((1,), i32),
-            jnp.ones((1,), i32)).as_text(debug_info=True)
+            eng._params, jnp.zeros((1, 8 + 2 + T), i32), eng._cache
+        ).as_text(debug_info=True)
         decode = eng._jit_decode.lower(
-            eng._params, jnp.zeros((S,), i32), eng._cache,
-            jnp.zeros((S, T), i32), jnp.zeros((S,), i32)
+            eng._params, jnp.zeros((S, 2 + T), i32), eng._cache
         ).as_text(debug_info=True)
         copy = eng._jit_copy.lower(
             eng._cache, jnp.int32(0), jnp.int32(0)

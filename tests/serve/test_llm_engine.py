@@ -755,3 +755,111 @@ def test_mid_decode_replica_sigkill_fails_typed(serve_session):
     else:
         # kill raced the stream's natural end: it must have completed
         assert len(got) == 40, got
+
+
+# ------------------------------------------------- the tick's host gap
+# Greedy tokens of the build before the step programs took one staged
+# array (commit 7d0386c, this engine, seed 4, the prompts in this
+# order): a shorter-than-a-block prompt, one of three chunks, two that
+# share three blocks, and the first two blocks of the long one again —
+# fully matched and block-aligned, so its last block is copied on write.
+_LONG = [(7 * i + 3) % 61 + 1 for i in range(20)]
+_SHARED = [(5 * i + 2) % 59 + 1 for i in range(12)]
+_PROMPTS = [[7, 9, 11], _LONG, _SHARED + [4, 5, 6], _SHARED + [8, 9],
+            _LONG[:8]]
+_PARENT_TOKENS = [
+    [2, 1, 60, 21, 5, 48, 3, 17, 23, 29, 60, 21],
+    [32, 58, 6, 61, 52, 12, 5, 48, 3, 17, 23, 29],
+    [61, 52, 12, 5, 48, 3, 17, 23, 29, 60, 21, 5],
+    [61, 52, 12, 5, 48, 3, 17, 23, 29, 60, 21, 5],
+    [30, 28, 4, 41, 57, 21, 5, 48, 3, 17, 23, 29]]
+
+
+def _seeded_engine(spec_tokens):
+    eng = LLMEngine(
+        TransformerConfig(**MODEL_KW),
+        EngineConfig(decode_slots=4, kv_block_size=4, max_seq_len=48,
+                     prefill_chunk=8, max_new_tokens=16,
+                     spec_tokens=spec_tokens), seed=4)
+    eng.warmup()
+    return eng
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 4])
+def test_tokens_are_the_parents_and_each_program_has_one_transfer(
+        spec_tokens):
+    eng = _seeded_engine(spec_tokens)
+    try:
+        served = [list(eng.generate_sync(p, max_new_tokens=12))
+                  for p in _PROMPTS]
+        _assert_clean(eng, 4)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert served == _PARENT_TOKENS
+    assert st["prefill_chunks"] == 8 and st["cow_copies_total"] == 1
+    assert st["prefix_hit_blocks_total"] == 5
+    # a verify call is this engine's decode step
+    assert st["h2d_transfers_total"] \
+        == st["prefill_chunks"] + st["decode_steps"] > 40
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 4])
+def test_warm_ticks_run_no_eager_device_op_on_the_step_thread(
+        spec_tokens):
+    """Fifty warm ticks under a transfer guard that refuses every
+    implicit host-to-device transfer (a ``jnp.full`` or ``jnp.zeros``
+    scalar, an index into a device array, a numpy argument to a jitted
+    call) and with ``jnp`` itself refused to the step thread. The one
+    transfer a program is allowed, the numpy array it is called with,
+    is made explicit here, at the call: nothing else may reach the
+    device."""
+    import jax
+    eng = _seeded_engine(spec_tokens)
+
+    class NoEagerOps:
+        def __getattr__(self, name):
+            if threading.current_thread() is eng._thread:
+                raise AssertionError(f"jnp.{name} on the step thread")
+            return getattr(jnp, name)
+
+    def one_explicit_transfer(program):
+        def call(params, rows, cache):
+            assert type(rows) is np.ndarray and rows.dtype == np.int32
+            return program(params, jax.device_put(rows), cache)
+        call._cache_size = program._cache_size      # stats() reads it
+        return call
+
+    was = jax.config.jax_transfer_guard_host_to_device
+    try:
+        jax.config.update("jax_transfer_guard_host_to_device", "disallow")
+        eng._jnp = NoEagerOps()
+        eng._jit_prefill = one_explicit_transfer(eng._jit_prefill)
+        eng._jit_decode = one_explicit_transfer(eng._jit_decode)
+        if spec_tokens:
+            eng._jit_verify = one_explicit_transfer(eng._jit_verify)
+        tick0, before = eng._clock.tick_no, eng.stats()
+        # prompts of one to three chunks, all but the first served out of
+        # the trie from the second round on, none block-aligned: a CoW
+        # copy takes two host scalars, which the guard would refuse too
+        prompts = [[7, 9, 11], _LONG + [5], _SHARED + [4, 5, 6],
+                   _SHARED + [8, 9]]
+        reqs = [eng.submit(p, 12) for p in prompts * 5]
+        for req in reqs:
+            items = []
+            while True:
+                item = req.out.get(timeout=60)
+                if item is _DONE:
+                    break
+                assert not isinstance(item, BaseException), item
+                items.append(item)
+            assert len(items) == 12
+        st = eng.stats()
+    finally:
+        jax.config.update("jax_transfer_guard_host_to_device", was)
+        eng.shutdown()
+    assert eng._clock.tick_no - tick0 >= 50 and st["dead"] is None
+    assert st["cow_copies_total"] == 0
+    assert st["h2d_transfers_total"] - before["h2d_transfers_total"] \
+        == st["prefill_chunks"] + st["decode_steps"] \
+        - before["prefill_chunks"] - before["decode_steps"]

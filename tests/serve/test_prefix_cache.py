@@ -163,3 +163,171 @@ def test_audit_catches_inconsistencies():
     problems = p.audit()
     assert problems and any("free and trie-resident" in m
                             for m in problems)
+
+
+# ------------------------------------------- eviction against its oracle
+class _Recording(PrefixBlockPool):
+    """The pool under test, noting the block each eviction frees."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.victims = []
+
+    def _evict_one(self):
+        evicted = super()._evict_one()
+        if evicted:
+            self.victims.append(self._free[-1])
+        return evicted
+
+
+class _ScanPool(_Recording):
+    """The oracle: eviction, ``stats()`` and ``root_fingerprints`` as
+    they were before the pool kept an evictable heap and running counts
+    — a scan of every cached node for every block evicted, sums over the
+    whole pool, a sort of all the root's children. It reads only the
+    maps (``_node_of``, ``_ref``, ``children``, ``touch``)."""
+
+    def _evict_one(self):
+        best = None
+        for block, node in self._node_of.items():
+            if block in self._ref or node.children:
+                continue
+            if best is None or node.touch < best[1].touch:
+                best = (block, node)
+        if best is None:
+            return False
+        block, node = best
+        node.detached = True
+        if node.parent is not None:
+            node.parent.children.pop(node.key, None)
+        del self._node_of[block]
+        self._free.append(block)
+        self.evictions_total += 1
+        self.victims.append(block)
+        return True
+
+    def root_fingerprints(self, limit=64):
+        from ray_tpu.serve.prefix_cache import prefix_fingerprint
+        kids = sorted(self._root.children.values(),
+                      key=lambda n: -n.touch)[:limit]
+        fps = [prefix_fingerprint(n.key, self.block_size) for n in kids]
+        return [fp for fp in fps if fp is not None]
+
+    def stats(self):
+        cached = sum(1 for b in self._node_of if b not in self._ref)
+        shared = sum(1 for b, r in self._ref.items() if r > 1)
+        return dict(super().stats(), cached=cached, shared=shared,
+                    reclaimable=len(self._free) + cached)
+
+
+class _Client:
+    """One request's hold on one pool: what the engine's admission,
+    prefill loop and release do with it."""
+
+    def __init__(self, pool, prompt, need):
+        self.pool, self.prompt, self.cursor = pool, prompt, 0
+        matched, mtok, self.node = pool.match_prefix(prompt)
+        priv = pool.allocate(need - len(matched))
+        self.admitted = priv is not None
+        if not self.admitted:           # admission waits: match undone
+            pool.release(matched)
+            self.blocks = []
+        else:
+            self.blocks = matched + priv
+            self.cursor = len(matched)
+        self.result = (matched, mtok, priv)
+
+    def index(self, n):
+        """Insert the next ``n`` full chunks the prefill has covered."""
+        bs, out = self.pool.block_size, []
+        while n and self.node is not None \
+                and (self.cursor + 1) * bs <= len(self.prompt):
+            i = self.cursor
+            self.node, inserted = self.pool.insert_child(
+                self.node, self.prompt[i * bs:(i + 1) * bs],
+                self.blocks[i])
+            out.append(inserted)
+            self.cursor += 1
+            n -= 1
+        return out
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_eviction_matches_the_scan_oracle(seed):
+    """A seeded random run of admissions, chunk inserts and releases on
+    a pool that stays under pressure, chains deep enough that a freed
+    leaf exposes its parent: the heap evicts the scan's victim every
+    time, and every result and ``stats()`` along the way is the same."""
+    import random
+    rng = random.Random(seed)
+    bs, blocks = 2, 49
+    new, ref = _Recording(blocks, bs), _ScanPool(blocks, bs)
+    docs = [[rng.randrange(50) for _ in range(bs * rng.randint(2, 12))]
+            for _ in range(14)]
+    docs += [d[:bs * 2] + [rng.randrange(50) for _ in range(bs * 5)]
+             for d in docs[:6]]         # forks off a shared first blocks
+    live = []                           # (client on new, client on ref)
+    for step in range(4000):
+        op = rng.random()
+        if op < 0.4 and len(live) < 5:
+            doc = rng.choice(docs)
+            prompt = doc[:rng.randint(1, len(doc))] \
+                + [rng.randrange(50) for _ in range(rng.randint(0, 3))]
+            need = -(-(len(prompt) + rng.randint(1, 4)) // bs)
+            pair = _Client(new, prompt, need), _Client(ref, prompt, need)
+            assert pair[0].result == pair[1].result, step
+            if pair[0].admitted:
+                live.append(pair)
+        elif op < 0.75 and live:
+            pair, n = rng.choice(live), rng.randint(1, 4)
+            assert pair[0].index(n) == pair[1].index(n), step
+        elif live:
+            pair = live.pop(rng.randrange(len(live)))
+            for client in pair:
+                client.pool.release(client.blocks)
+        assert new.victims == ref.victims, step
+        assert new.stats() == ref.stats(), step
+        assert new.root_fingerprints(4) == ref.root_fingerprints(4), step
+        if step % 97 == 0:
+            assert new.audit() == [], step
+    assert new.audit() == []
+    assert len(new.victims) > 300       # it was under pressure
+    assert new.root_fingerprints() == ref.root_fingerprints()
+    # a leaf's eviction did expose its parent: some chain went whole
+    assert new.stats()["trie_blocks"] < new.inserts_total - 300
+
+
+def test_allocate_costs_what_it_evicts_not_the_pool(monkeypatch):
+    """4,096 cached nodes (256 chains of 16), none free: ``allocate(256)``
+    is a few heap operations a block on a heap no larger than the trie,
+    O(256 log n), where the scan walked the pool for every block."""
+    import heapq
+    from ray_tpu.serve import prefix_cache
+    p = PrefixBlockPool(4097, 1)
+    held = []
+    for chain in range(256):
+        held += _index_prompt(p, [chain] + list(range(1000, 1015)))
+    p.release(held)
+    assert p.stats()["cached"] == 4096 and p.stats()["free"] == 0
+
+    ops = []
+
+    class Counting:
+        @staticmethod
+        def heappush(heap, item):
+            ops.append(len(heap))
+            heapq.heappush(heap, item)
+
+        @staticmethod
+        def heappop(heap):
+            ops.append(len(heap))
+            return heapq.heappop(heap)
+
+        heapify = staticmethod(heapq.heapify)
+
+    monkeypatch.setattr(prefix_cache, "heapq", Counting)
+    got = p.allocate(256)
+    assert got is not None and len(set(got)) == 256
+    assert p.evictions_total == 256
+    assert len(ops) <= 3 * 256 and max(ops) <= 4096
+    assert p.audit() == []
