@@ -78,3 +78,93 @@ def moe_logical_axes():
         "moe_wi": ("layers", "expert", "embed", "mlp"),
         "moe_wo": ("layers", "expert", "mlp", "embed"),
     }
+
+
+# ------------------------------------------------------ dropless top-k
+# The served form (``experts_per_token > 0``): every token goes to its k
+# best experts, no capacity, no drop; gated (SwiGLU) experts of their own
+# width; weights the renormalised softmax. One code path for a prefill
+# chunk (thousands of rows) and a decode step (a handful): sort the
+# (token, expert) assignments by expert, run one grouped product over
+# the sorted rows (``jax.lax.ragged_dot``: rows of group i times ``w[i]``,
+# exact, and the work is the rows', not one dense product an expert),
+# unsort, combine.
+
+def route_topk(c, lp, x):
+    """Router of the dropless layer. ``x [N, D]`` -> (weights ``[N, k]``
+    float32 summing to one, experts ``[N, k]`` int32): softmax over all
+    experts in float32, the k largest, renormalised."""
+    logits = jnp.dot(x, lp["w_router"].astype(c.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, c.experts_per_token)
+    return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
+
+
+#: the dropless layer's stacked expert leaves
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+@jax.named_scope("moe")
+def topk_moe_mlp(c, lp, h, layer=None):
+    """Dropless top-k gated-expert MLP. ``h [B, S, D]`` (compute dtype)
+    -> ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the experts
+    ``we_gate / we_up [E, D, F]``, ``we_down [E, F, D]``.
+
+    Inside a scan over layers pass ``layer`` (the scan's int32 index)
+    and the expert leaves WHOLE, ``[L, E, ...]``: the grouped product
+    then runs on ``[L * E, ...]`` with the rows in layer ``layer``'s
+    groups and every other group empty. Scanned like the other leaves,
+    each layer's experts would be sliced out of the stack — a copy of
+    all of them, in every layer of every step — because a grouped
+    product, unlike a plain dot, cannot read its operand through the
+    slice."""
+    dt = c.dtype
+    B, S, D = h.shape
+    E, k = c.n_experts, c.experts_per_token
+    N = B * S
+    x = h.reshape(N, D).astype(dt)
+    weights, experts = route_topk(c, lp, x)
+    flat = experts.reshape(N * k)
+    order = jnp.argsort(flat)                  # stable: assignment order
+    xs = jnp.take(x, order // k, axis=0)       # rows sorted by expert
+    w_gate, w_up, w_down = (lp[name].astype(dt) for name in EXPERT_LEAVES)
+    groups = E
+    if layer is not None:
+        groups = w_gate.shape[0] * E
+        flat = flat + layer * E
+        w_gate, w_up, w_down = (w.reshape((groups,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+    sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1)
+    gate = jax.lax.ragged_dot(xs, w_gate, sizes,
+                              preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(xs, w_up, sizes,
+                            preferred_element_type=jnp.float32)
+    mid = (jax.nn.silu(gate) * up).astype(dt)
+    ys = jax.lax.ragged_dot(mid, w_down, sizes,
+                            preferred_element_type=jnp.float32)
+    # unsort: row i of ys is assignment order[i]
+    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    ys = jnp.take(ys, inverse, axis=0).reshape(N, k, D)
+    y = jnp.sum(ys * weights[..., None], axis=1)
+    return y.reshape(B, S, D).astype(dt)
+
+
+def topk_moe_param_shapes(c):
+    f = c.expert_width
+    return {
+        "w_router": (c.d_model, c.n_experts),
+        "we_gate": (c.n_experts, c.d_model, f),
+        "we_up": (c.n_experts, c.d_model, f),
+        "we_down": (c.n_experts, f, c.d_model),
+    }
+
+
+def topk_moe_logical_axes():
+    return {
+        "w_router": ("layers", "embed", None),
+        "we_gate": ("layers", "expert", "embed", "mlp"),
+        "we_up": ("layers", "expert", "embed", "mlp"),
+        "we_down": ("layers", "expert", "mlp", "embed"),
+    }

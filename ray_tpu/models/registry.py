@@ -4,8 +4,10 @@ The flagship ``gptj-6b`` mirrors the architecture the reference's GPT-J
 fine-tune recipe trains (EleutherAI GPT-J-6B: 28 layers, d_model 4096,
 16 heads x 256, rotary_dim 64, vocab 50400 — see
 ``release/air_examples/gptj_deepspeed_finetuning/`` in the reference);
-``llama2-7b`` covers the reference's Llama-2 release tests. ``*-tiny``
-variants keep the same block structure at test scale.
+``llama2-7b`` covers the reference's Llama-2 release tests;
+``keye-vl-2.0-30b-a3b`` is the benchmark's routed, key-selecting
+configuration at its published depth. ``*-tiny`` variants keep the same
+block structure at test scale.
 """
 
 from __future__ import annotations
@@ -34,6 +36,16 @@ MODEL_CONFIGS: Dict[str, TransformerConfig] = {
         vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
         head_dim=128, d_ff=11008, max_seq_len=4096, rotary_dim=128,
         block_style="llama"),
+    # Kwai-Keye/Keye-VL-2.0-30B-A3B, the language model (served only:
+    # dropless top-8 of 128 experts, q/k norm, a top-2048 key indexer)
+    # at the published depth: 61 GB in bf16, a pipeline over eight chips.
+    # The benchmark serves six layers, one stage (benchmarks/configs/)
+    "keye-vl-2.0-30b-a3b": TransformerConfig(
+        vocab_size=151936, d_model=2048, n_layers=48, n_heads=32,
+        head_dim=128, n_kv_heads=4, d_ff=6144, max_seq_len=262144,
+        rotary_dim=128, rope_base=1e7, block_style="llama",
+        n_experts=128, experts_per_token=8, expert_width=768,
+        qk_norm=True, index_topk=2048, index_heads=16, index_dim=64),
     "llama2-tiny": TransformerConfig(
         vocab_size=512, d_model=64, n_layers=2, n_heads=4, head_dim=16,
         n_kv_heads=2, d_ff=128, max_seq_len=128, rotary_dim=16,

@@ -103,10 +103,36 @@ class TransformerConfig:
     n_experts: int = 0
     capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # Served forms ("llama" blocks, the cache path only; training keeps
+    # Switch top-1). experts_per_token > 0: every token to its k best of
+    # n_experts gated (SwiGLU) experts of width expert_width (0 = d_ff),
+    # renormalised softmax weights, none dropped (models/moe.py).
+    experts_per_token: int = 0
+    expert_width: int = 0
+    # RMSNorm over head_dim, a learned weight a head, on q and k before
+    # rotary
+    qk_norm: bool = False
+    # Learned key selection (ops/sparse_attention.py): index_heads x
+    # index_dim indexer queries over one shared key head cached beside K
+    # and V; attention reads the index_topk keys it ranks highest.
+    # 0 = attend every key.
+    index_topk: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def d_expert(self) -> int:
+        return self.expert_width or self.d_ff
+
+    @property
+    def served_only(self) -> bool:
+        """A form only ``prefill`` / ``decode_step`` implement."""
+        return bool(self.experts_per_token or self.qk_norm
+                    or self.index_topk)
 
     @property
     def resolved_remat_policy(self) -> str:
@@ -121,7 +147,15 @@ class TransformerConfig:
         e, v, h = self.d_model, self.vocab_size, self.n_heads * self.head_dim
         kvh = self.kv_heads * self.head_dim
         per_layer = e * h + 2 * e * kvh + h * e          # q, k, v, o
-        if self.n_experts:
+        if self.qk_norm:
+            per_layer += 2 * self.head_dim
+        if self.index_topk:                  # wq, wk, ww, k layernorm
+            per_layer += e * self.index_dim * (self.index_heads + 1) \
+                + e * self.index_heads + 2 * self.index_dim
+        if self.experts_per_token:
+            per_layer += e * self.n_experts \
+                + self.n_experts * 3 * e * self.d_expert + 2 * e
+        elif self.n_experts:
             per_layer += e * self.n_experts \
                 + self.n_experts * 2 * e * self.d_ff     # router + experts
             per_layer += 2 * e                           # norms
@@ -138,9 +172,13 @@ class TransformerConfig:
     @property
     def num_active_params(self) -> int:
         """Params touched per token: with Switch top-1 routing only ONE
-        expert's MLP runs per token — FLOPs must not count the rest."""
+        expert's MLP runs per token — FLOPs must not count the rest; the
+        dropless form runs ``experts_per_token`` gated experts."""
         if not self.n_experts:
             return self.num_params
+        if self.experts_per_token:
+            return self.num_params - self.n_layers * 3 * self.d_model \
+                * (self.n_experts - self.experts_per_token) * self.d_expert
         inactive = self.n_layers * (self.n_experts - 1) \
             * 2 * self.d_model * self.d_ff
         return self.num_params - inactive
@@ -161,6 +199,17 @@ _scale_in_place = jax.jit(lambda w, scale: scale * w, donate_argnums=(0,))
 def _dense_init(key, shape, scale=0.02, dtype=jnp.float32):
     w = _scale_in_place(jax.random.normal(key, shape, jnp.float32), scale)
     return w.astype(dtype)          # f32: the array itself
+
+
+@functools.partial(jax.jit, static_argnames=("n", "shape", "dtype"))
+def _layered_init(key, scale, n, shape, dtype):
+    """``[n, *shape]`` drawn a layer at a time into the stored dtype: a
+    stack of experts in f32 would be twice what it is to be held in."""
+    def one(i, out):
+        w = scale * jax.random.normal(jax.random.fold_in(key, i), shape,
+                                      jnp.float32)
+        return out.at[i].set(w.astype(dtype))
+    return jax.lax.fori_loop(0, n, one, jnp.zeros((n,) + shape, dtype))
 
 
 def init_params(config: TransformerConfig, key,
@@ -189,7 +238,38 @@ def init_params(config: TransformerConfig, key,
         "wv": stack(keys[2], (c.d_model, kvh)),
         "wo": stack(keys[3], (h, c.d_model), out_scale),
     }
-    if c.n_experts:
+    if c.served_only and c.block_style != "llama":
+        raise ValueError("experts_per_token, qk_norm and index_topk are "
+                         "forms of the 'llama' block")
+    # new leaves draw from keys folded out of ``key``: the nine above
+    # stay what they were
+    if c.qk_norm:
+        layers.update({"q_norm": jnp.ones((L, c.head_dim), jnp.float32),
+                       "k_norm": jnp.ones((L, c.head_dim), jnp.float32)})
+    if c.index_topk:
+        ik = jax.random.split(jax.random.fold_in(key, 101), 3)
+        layers.update({
+            "wq_idx": stack(ik[0], (c.d_model,
+                                    c.index_heads * c.index_dim)),
+            "wk_idx": stack(ik[1], (c.d_model, c.index_dim)),
+            "ww_idx": stack(ik[2], (c.d_model, c.index_heads)),
+            "k_idx_scale": jnp.ones((L, c.index_dim), jnp.float32),
+            "k_idx_bias": jnp.zeros((L, c.index_dim), jnp.float32)})
+    if c.experts_per_token:
+        from ray_tpu.models.moe import topk_moe_param_shapes
+        ek = jax.random.fold_in(key, 102)
+        for i, (name, shape) in enumerate(
+                sorted(topk_moe_param_shapes(c).items())):
+            layers[name] = _layered_init(
+                jax.random.fold_in(ek, i),
+                out_scale if name == "we_down" else 0.02, L, shape,
+                jnp.dtype(dtype))
+        layers.update({
+            "attn_norm": jnp.ones((L, c.d_model), jnp.float32),
+            "mlp_norm": jnp.ones((L, c.d_model), jnp.float32)})
+        final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
+        head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
+    elif c.n_experts:
         from ray_tpu.models.moe import moe_param_shapes
         mk = jax.random.split(keys[6], 3)
         layers.update({
@@ -252,7 +332,23 @@ def logical_axes(config: TransformerConfig) -> Dict:
         "wv": ("layers", "embed", "kv"),
         "wo": ("layers", "heads", "embed"),
     }
-    if c.n_experts:
+    if c.qk_norm:
+        common.update({"q_norm": ("layers", None),
+                       "k_norm": ("layers", None)})
+    if c.index_topk:
+        common.update({"wq_idx": ("layers", "embed", None),
+                       "wk_idx": ("layers", "embed", None),
+                       "ww_idx": ("layers", "embed", None),
+                       "k_idx_scale": ("layers", None),
+                       "k_idx_bias": ("layers", None)})
+    if c.experts_per_token:
+        from ray_tpu.models.moe import topk_moe_logical_axes
+        layers = {**common, **topk_moe_logical_axes(),
+                  "attn_norm": ("layers", "embed"),
+                  "mlp_norm": ("layers", "embed")}
+        final = {"scale": ("embed",)}
+        head = {"w": ("embed", "vocab")}
+    elif c.n_experts:
         from ray_tpu.models.moe import moe_logical_axes
         layers = {**common, **moe_logical_axes()}
         if c.block_style == "llama":
@@ -295,7 +391,8 @@ def logical_axes(config: TransformerConfig) -> Dict:
 #: Leaves a use site reads in float32 (``ops/norms.py`` takes a norm's
 #: scale and bias to f32) — these and everything under ``final_norm``.
 #: Every other leaf is read through ``.astype(config.dtype)``.
-_F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias"))
+_F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias",
+                         "q_norm", "k_norm", "k_idx_scale", "k_idx_bias"))
 
 
 def inference_params(config: TransformerConfig, params: Dict) -> Dict:
@@ -417,9 +514,14 @@ def _attn_sublayer(c, h, lp, sin, cos, layout, mesh, rules):
 
 
 @jax.named_scope("mlp")
-def _mlp_sublayer(c, h, lp):
-    """Dense or MoE MLP on normed input h; returns (out, moe_aux)."""
+def _mlp_sublayer(c, h, lp, layer=None):
+    """Dense or MoE MLP on normed input h; returns (out, moe_aux).
+    ``layer``: ``lp``'s expert leaves are whole stacks and this is the
+    layer's index in them (``moe.topk_moe_mlp``)."""
     dt = c.dtype
+    if c.experts_per_token:
+        from ray_tpu.models.moe import topk_moe_mlp
+        return topk_moe_mlp(c, lp, h, layer), 0.0
     if c.n_experts:
         from ray_tpu.models.moe import moe_mlp
         return moe_mlp(c, lp, h.astype(dt))
@@ -461,6 +563,11 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
     pipeline-stage forward (a stage's trunk is a contiguous slice of
     the stacked layer leaves — same scan, fewer layers)."""
     c = config
+    if c.served_only:
+        raise NotImplementedError(
+            "experts_per_token, qk_norm and index_topk are served through "
+            "prefill / decode_step only; training keeps Switch top-1 "
+            "experts and dense attention")
     seq = x.shape[1]
     sin, cos = rotary_table(
         seq, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
@@ -689,8 +796,9 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 # ------------------------------------------------------- inference (KV)
 # The serving decode path: a paged KV cache (one pool
 # [n_layers, num_blocks, kv_heads, block_size, head_dim] for k and one
-# for v, block table per sequence) written by chunked prefill and
-# batched single-token decode steps. Both entry points are shape-stable
+# for v, with an indexer a third for its keys, block table per
+# sequence) written by chunked prefill and batched single-token decode
+# steps. Both entry points are shape-stable
 # — jit them once at the engine's fixed (batch, chunk, table) shapes
 # and admission never recompiles — and neither slices, stacks or copies
 # the pool: the layer scan carries it whole, each layer scatters its new
@@ -703,31 +811,65 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` in the
     compute dtype — ``kv_heads`` ahead of ``block_size`` so one head's
     page is a contiguous ``(block_size, head_dim)`` tile, which is what
-    the Pallas kernel DMAs. :func:`prefill` and :func:`decode_step`
-    pass each pool WHOLE, with a layer index, to the write and to the
-    attention kernel; nothing takes a layer's slice of it. Zero-filled;
-    a zero key scores 0 pre-softmax, so reserved/trash blocks are
-    numerically harmless."""
+    the Pallas kernel DMAs. With an indexer (``index_topk``) a page
+    carries a third kind of state under the same block id: ``"ki"``,
+    ``[n_layers, num_blocks, 1, block_size, index_dim]``, the one shared
+    head of indexer keys. Every pool has blocks on axis 1: what copies,
+    ships or adopts a page moves that index of every pool.
+    :func:`prefill` and :func:`decode_step` pass each pool WHOLE, with a
+    layer index, to the write and to the attention; nothing takes a
+    layer's slice of it. Zero-filled; a zero key scores 0 pre-softmax,
+    so reserved/trash blocks are numerically harmless."""
     c = config
     shape = (c.n_layers, num_blocks, c.kv_heads, block_size, c.head_dim)
-    return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
+    cache = {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
+    if c.index_topk:
+        cache["ki"] = jnp.zeros(
+            (c.n_layers, num_blocks, 1, block_size, c.index_dim), c.dtype)
+    return cache
+
+
+@jax.named_scope("indexer")
+def _indexer(c, h, lp, isin, icos, positions):
+    """The indexer's side of a layer for the new tokens: queries ``[B,
+    C, Hi, Di]`` and the one key head ``[B, C, 1, Di]`` (LayerNorm, then
+    rotary over all of ``index_dim``, both), and the head weights ``[B,
+    C, Hi]`` in float32, scaled by ``Hi^-1/2 * Di^-1/2``."""
+    e = h.shape[-1]
+    dt = c.dtype
+    hd = h.astype(dt)
+    qi = jnp.einsum("bse,ehd->bshd", hd, lp["wq_idx"].reshape(
+        e, c.index_heads, c.index_dim).astype(dt))
+    ki = layer_norm(jnp.dot(hd, lp["wk_idx"].astype(dt)),
+                    lp["k_idx_scale"], lp["k_idx_bias"], eps=1e-6)
+    wi = jnp.dot(hd, lp["ww_idx"].astype(dt),
+                 preferred_element_type=jnp.float32) \
+        * (c.index_heads ** -0.5 * c.index_dim ** -0.5)
+    qi = apply_rotary(qi, isin, icos, positions=positions, layout="neox")
+    ki = apply_rotary(ki[:, :, None], isin, icos, positions=positions,
+                      layout="neox")
+    return qi, ki, wi
 
 
 @jax.named_scope("attn")
-def _paged_attn_sublayer(c, h, lp, sin, cos, layout, layer, k_pool,
-                         v_pool, block_tables, positions, write_mask,
-                         lens):
+def _paged_attn_sublayer(c, h, lp, rot, layout, layer, cache,
+                         block_tables, positions, write_mask, lens):
     """Decode-path attention sublayer of layer ``layer`` (an int32
     scalar, traced by the layer scan): project qkv for the new tokens,
     rotate at their absolute positions, scatter k/v into that layer's
     pages of the WHOLE 5-D pools, then attend against the (now-updated)
-    pages by ``(layer, block)``. The pools come in and go out whole —
-    the scan's carry — so the write is one in-place scatter of the new
-    rows, not a copy of the layer. ``lens`` is the per-sequence live
-    token count after this call's writes — the Pallas kernel skips
-    whole cache blocks past it. Returns (attn_out, k_pool, v_pool)."""
+    pages by ``(layer, block)``. The pools (``cache``, as
+    :func:`init_kv_cache` made it) come in and go out whole — the scan's
+    carry — so the write is one in-place scatter of the new rows, not a
+    copy of the layer. ``lens`` is the per-sequence live token count
+    after this call's writes — the Pallas kernel skips whole cache
+    blocks past it. With an indexer the new tokens' ``kI`` goes into the
+    same pages, and attention reads the keys the indexer selects.
+    ``rot``: the (sin, cos) tables, the head's and the indexer's.
+    Returns (attn_out, cache)."""
     e = h.shape[-1]
     dt = c.dtype
+    sin, cos, isin, icos = rot
 
     def proj(w, n):
         return jnp.einsum("bse,ehd->bshd", h.astype(dt),
@@ -735,10 +877,16 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, layer, k_pool,
     q = proj(lp["wq"], c.n_heads)
     k = proj(lp["wk"], c.kv_heads)
     v = proj(lp["wv"], c.kv_heads)
+    if c.qk_norm:
+        q = rms_norm(q, lp["q_norm"])
+        k = rms_norm(k, lp["k_norm"])
     q = apply_rotary(q, sin, cos, positions=positions, layout=layout)
     k = apply_rotary(k, sin, cos, positions=positions, layout=layout)
+    new = {"k": k, "v": v}
+    if c.index_topk:
+        qi, new["ki"], wi = _indexer(c, h, lp, isin, icos, positions)
 
-    n_blocks, bs = k_pool.shape[1], k_pool.shape[3]
+    n_blocks, bs = cache["k"].shape[1], cache["k"].shape[3]
     with jax.named_scope("kv_write"):
         bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
         slot = positions % bs
@@ -754,11 +902,11 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, layer, k_pool,
         # scatter and copies ALL of it into the kernel's layout in every
         # layer (tests/ops/test_tpu_lowering.py compiles and looks)
         bid, slot = bid[..., None], slot[..., None]
-        head = jnp.arange(c.kv_heads, dtype=jnp.int32)
-        k_pool = k_pool.at[layer, bid, head, slot].set(
-            k.astype(k_pool.dtype), mode="drop")
-        v_pool = v_pool.at[layer, bid, head, slot].set(
-            v.astype(v_pool.dtype), mode="drop")
+        cache = {
+            name: pool.at[
+                layer, bid, jnp.arange(pool.shape[2], dtype=jnp.int32),
+                slot].set(new[name].astype(pool.dtype), mode="drop")
+            for name, pool in cache.items()}
 
     # h.shape[1] is static under jit: > 1 means a prefill chunk, whose
     # much larger query-row count can carry a bigger row block than the
@@ -766,13 +914,23 @@ def _paged_attn_sublayer(c, h, lp, sin, cos, layout, layer, k_pool,
     br = c.paged_block_r_prefill \
         if (h.shape[1] > 1 and c.paged_block_r_prefill) \
         else c.paged_block_r
-    with jax.named_scope("paged_attn"):
-        att = paged_attention(q, k_pool, v_pool, block_tables, positions,
-                              layer=layer, lens=lens, impl=c.paged_impl,
-                              block_r=br or None)
+    if 0 < c.index_topk < block_tables.shape[1] * bs:
+        from ray_tpu.ops.sparse_attention import sparse_paged_attention
+        att = sparse_paged_attention(
+            q, qi, wi, cache["k"], cache["v"], cache["ki"], block_tables,
+            positions, lens, layer=layer, topk=c.index_topk)
+    else:
+        # no indexer: every dense model's path. An indexer whose window
+        # holds no more than index_topk tokens lands here too (the
+        # selection is the identity): no cell runs that, a test holds
+        # it to the dense path bit for bit
+        with jax.named_scope("paged_attn"):
+            att = paged_attention(q, cache["k"], cache["v"], block_tables,
+                                  positions, layer=layer, lens=lens,
+                                  impl=c.paged_impl, block_r=br or None)
     out = jnp.einsum("bshd,hde->bse", att,
                      lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
-    return out, k_pool, v_pool
+    return out, cache
 
 
 def _forward_with_cache(c: TransformerConfig, params: Dict,
@@ -787,55 +945,67 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     (B,) is each sequence's live token count including this call's
     writes — the attention kernel's length-skipping bound.
 
-    The scan runs over ``(layers, layer index)`` and carries
-    ``(x, k_pool, v_pool)``: the pool is never among the scanned inputs
-    or outputs (those are sliced per layer and stacked into a new
-    buffer — a copy of the whole pool every step)."""
-    if c.n_experts:
+    The scan runs over ``(layers, layer index)`` and carries ``(x,
+    cache)``: the pools are never among the scanned inputs or outputs
+    (those are sliced per layer and stacked into a new buffer — a copy
+    of the whole pool every step)."""
+    if c.n_experts and not c.experts_per_token:
         raise NotImplementedError(
-            "paged decode does not support MoE configs yet")
+            "paged decode serves dropless top-k experts "
+            "(experts_per_token > 0); Switch top-1 with capacity drops "
+            "tokens by the batch they arrive in")
     bs = cache["k"].shape[3]
     window = block_tables.shape[1] * bs
-    sin, cos = rotary_table(
+    rot = rotary_table(
         window, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
         c.rope_base)
+    rot += rotary_table(window, c.index_dim, c.rope_base) \
+        if c.index_topk else (None, None)
     layout = "gptj" if c.block_style == "gptj" else "neox"
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
+    # the dropless experts stay out of the scanned leaves: the grouped
+    # product reads layer ``layer`` of the whole stack in place
+    scanned, whole = params["layers"], {}
+    if c.experts_per_token:
+        from ray_tpu.models.moe import EXPERT_LEAVES
+        whole = {k: scanned[k] for k in EXPERT_LEAVES}
+        scanned = {k: v for k, v in scanned.items() if k not in whole}
 
-    def gptj_step(x, lp, layer, kp, vp):
+    def gptj_step(x, lp, layer, cache):
         h = layer_norm(x, lp["ln_scale"], lp["ln_bias"])
-        att, kp, vp = _paged_attn_sublayer(
-            c, h, lp, sin, cos, layout, layer, kp, vp,
+        att, cache = _paged_attn_sublayer(
+            c, h, lp, rot, layout, layer, cache,
             block_tables, positions, write_mask, lens)
         mlp, _ = _mlp_sublayer(c, h, lp)
-        return x + (att + mlp).astype(x.dtype), kp, vp
+        return x + (att + mlp).astype(x.dtype), cache
 
-    def llama_step(x, lp, layer, kp, vp):
+    def llama_step(x, lp, layer, cache):
         h = rms_norm(x, lp["attn_norm"])
-        att, kp, vp = _paged_attn_sublayer(
-            c, h, lp, sin, cos, layout, layer, kp, vp,
+        att, cache = _paged_attn_sublayer(
+            c, h, lp, rot, layout, layer, cache,
             block_tables, positions, write_mask, lens)
         x = x + att.astype(x.dtype)
         h2 = rms_norm(x, lp["mlp_norm"]).astype(c.dtype)
-        mlp, _ = _mlp_sublayer(c, h2, lp)
-        return x + mlp.astype(x.dtype), kp, vp
+        mlp, _ = _mlp_sublayer(c, h2, {**lp, **whole},
+                               layer if whole else None)
+        return x + mlp.astype(x.dtype), cache
 
     step = gptj_step if c.block_style == "gptj" else llama_step
 
     def scan_fn(carry, per_layer):
-        x, kp, vp = carry
+        x, cache = carry
         lp, layer = per_layer
         with jax.named_scope("layer"):
-            return step(x, lp, layer, kp, vp), None
+            return step(x, lp, layer, cache), None
 
     n_layers = cache["k"].shape[0]
-    (x, k_pool, v_pool), _ = jax.lax.scan(
-        scan_fn, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    (x, cache), _ = jax.lax.scan(
+        scan_fn, (x, dict(cache)),
+        (scanned, jnp.arange(n_layers, dtype=jnp.int32)))
 
     x = _final_norm(c, params, x)
-    return _lm_head(c, params, x), {"k": k_pool, "v": v_pool}
+    return _lm_head(c, params, x), cache
 
 
 def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,

@@ -1,0 +1,280 @@
+"""Learned sparse attention over the paged cache: an indexer ranks every
+visible key of a query, the ``topk`` best are selected exactly, and
+attention runs over those keys alone (DeepSeek-Sparse-Attention's
+"lightning indexer"; ``TransformerConfig.index_topk``).
+
+A page holds three kinds of state under one block table: K and V
+(``[L, N, kv_heads, bs, D]``) and the indexer's keys ``kI`` (``[L, N, 1,
+bs, Di]``, one shared key head). The pools come in whole with a layer
+index, as in :mod:`ray_tpu.ops.paged_flash`, so a step program's layer
+scan carries and writes them in place.
+
+Everything here is plain XLA, one form on every platform (CPU tests run
+what the chip runs). Work follows the live context, not the window: the
+loops over key tiles stop at the longest live sequence.
+
+- :func:`index_scores` — ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] .
+  kI[s])`` against the cached ``kI``, tiled over keys.
+- :func:`topk_mask` — the exact ``k`` largest of each row as a mask
+  (ties to the lower index, as a stable descending sort would): the
+  k-th largest value is found two bits a pass on an order-preserving
+  integer image of the scores, 16 counting passes and no sort.
+  ``jax.lax.approx_max_k`` would be a different model.
+- a decode step (one query a sequence) takes ``jax.lax.top_k`` of its
+  row and reads K and V of the selected tokens only, ``topk`` rows a
+  sequence and layer whatever the context; a prefill chunk (thousands
+  of queries, each with a selection of its own) masks a tiled
+  online-softmax pass over the live pages.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+_NEG_INF = -1e30
+
+#: query rows of a chunk handled at a time
+_ROW_BLOCK = 256
+
+#: float32 bytes one tile of scores (query rows x keys) may take: sets
+#: how many pages a loop iteration covers
+_TILE_BYTES = 256 << 20
+
+
+def _pages_per_tile(rows: int, table_len: int, block_size: int) -> int:
+    """Pages per key tile so that ``rows x keys`` float32 fits
+    ``_TILE_BYTES``: a power of two, at most the table."""
+    keys = max(block_size, _TILE_BYTES // (4 * max(rows, 1)))
+    pages = 1 << int(math.log2(max(1, keys // block_size)))
+    return min(pages, 1 << max(0, (table_len - 1).bit_length()))
+
+
+def _pad_table(block_tables, pages: int):
+    """The block table padded to a whole number of tiles of ``pages``;
+    the padding names block 0, whose keys lie past every query's
+    position."""
+    pad = -block_tables.shape[1] % pages
+    return jnp.pad(block_tables, ((0, 0), (0, pad))) if pad \
+        else block_tables
+
+
+def _live_tiles(lens, keys_per_tile: int):
+    return (jnp.max(lens).astype(jnp.int32) + keys_per_tile - 1) \
+        // keys_per_tile
+
+
+@jax.named_scope("indexer_scores")
+def index_scores(qi, wi, ki_pool, block_tables, positions, lens, layer):
+    """Indexer scores of new-token queries against the cached ``kI``.
+
+    ``qi [B, C, Hi, Di]`` (rotated), ``wi [B, C, Hi]`` float32 (scaled),
+    ``ki_pool [L, N, 1, bs, Di]``, ``positions [B, C]`` absolute,
+    ``lens [B]`` live tokens. Returns ``[B, C, W]`` float32, ``W`` the
+    (tile-padded) window: ``-inf`` where key ``s > positions[b, c]``."""
+    b, c, hi, di = qi.shape
+    bs = ki_pool.shape[3]
+    pages = _pages_per_tile(b * c * hi, block_tables.shape[1], bs)
+    bt = _pad_table(block_tables, pages)
+    keys = pages * bs
+    window = bt.shape[1] * bs
+
+    def tile(j, out):
+        ids = jax.lax.dynamic_slice_in_dim(bt, j * pages, pages, axis=1)
+        kb = ki_pool[layer, ids, 0].reshape(b, keys, di)
+        s = jnp.einsum("bchd,bkd->bchk", qi, kb,
+                       preferred_element_type=jnp.float32)
+        # on the VPU in float32: a dot would round both sides to bf16
+        s = jnp.sum(jax.nn.relu(s) * wi[..., None], axis=2)
+        key_pos = j * keys + jnp.arange(keys, dtype=jnp.int32)
+        s = jnp.where(key_pos[None, None] <= positions[:, :, None], s,
+                      -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(out, s, j * keys,
+                                                   axis=2)
+
+    return jax.lax.fori_loop(
+        0, _live_tiles(lens, keys), tile,
+        jnp.full((b, c, window), -jnp.inf, jnp.float32))
+
+
+@jax.named_scope("select")
+def topk_mask(scores, k: int):
+    """Mask of the ``k`` largest entries of each row of ``scores [...,
+    W]`` (float32, ``-inf`` = not a candidate): every candidate while a
+    row has at most ``k``; equal scores go to the lower index. Exact."""
+    visible = scores > -jnp.inf
+    scores = jnp.where(scores == 0.0, 0.0, scores)        # -0.0 == +0.0
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    # unsigned image with the floats' own order; 0 = not a candidate
+    u = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    u = jnp.where(visible, u, jnp.uint32(0))
+
+    def count(cand):
+        return jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+
+    def two_bits(i, t):
+        # the largest of t, t|lo, t|hi, t|hi|lo with k entries at or
+        # above it: three counts off one read of u
+        hi = jnp.uint32(1 << 31) >> (2 * i).astype(jnp.uint32)
+        lo = hi >> 1
+        return jnp.where(
+            count(t | hi | lo) >= k, t | hi | lo, jnp.where(
+                count(t | hi) >= k, t | hi, jnp.where(
+                    count(t | lo) >= k, t | lo, t)))
+
+    # the largest t with at least k entries >= t: the k-th largest value
+    # (0 while the row has fewer than k candidates)
+    t = jax.lax.fori_loop(0, 16, two_bits,
+                          jnp.zeros(u.shape[:-1], jnp.uint32))[..., None]
+    equal = u == t
+    need = k - jnp.sum(u > t, axis=-1, dtype=jnp.int32, keepdims=True)
+    tied = (t > 0) & (jnp.sum(equal, axis=-1, dtype=jnp.int32,
+                              keepdims=True) > need)
+    width = u.shape[-1]
+
+    def last_equal_taken():
+        # where values tie at the threshold, the first ``need`` of them
+        # by index: the index of the need-th
+        nth = jnp.cumsum(equal, axis=-1, dtype=jnp.int32) == need
+        cut = jnp.argmax(nth & equal, axis=-1, keepdims=True)
+        return jnp.where(tied, cut.astype(jnp.int32), width)
+
+    cut = jax.lax.cond(jnp.any(tied), last_equal_taken,
+                       lambda: jnp.full(tied.shape, width, jnp.int32))
+    index = jax.lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
+    return ((u > t) | (equal & (index <= cut))) & visible
+
+
+def _grouped(q, kv_heads: int):
+    b, c, h, d = q.shape
+    return q.reshape(b, c, kv_heads, h // kv_heads, d)
+
+
+@jax.named_scope("sparse_attn")
+def _decode_selected(q, k_pool, v_pool, block_tables, scores, layer,
+                     topk: int, sm_scale: float):
+    """One query a sequence: ``top_k`` of its score row, then K and V of
+    the selected tokens alone, gathered row by row out of the pool."""
+    b, _, h, d = q.shape
+    kvh, bs = k_pool.shape[2:4]
+    with jax.named_scope("select"):
+        val, idx = jax.lax.top_k(scores[:, 0],
+                                 min(topk, scores.shape[-1]))
+    chosen = val > -jnp.inf
+    bid = jnp.take_along_axis(block_tables, idx // bs, axis=1)[..., None]
+    slot = (idx % bs)[..., None]
+    head = jnp.arange(kvh, dtype=jnp.int32)
+    ks = k_pool[layer, bid, head, slot]                  # [B, k, KVH, D]
+    vs = v_pool[layer, bid, head, slot]
+    qg = _grouped(q, kvh)[:, 0]                          # [B, KVH, r, D]
+    s = jnp.einsum("bgrd,bkgd->bgrk", qg, ks,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(chosen[:, None, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bgrk,bkgd->bgrd", p.astype(vs.dtype), vs,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, 1, h, d).astype(q.dtype)
+
+
+@jax.named_scope("sparse_attn")
+def _chunk_masked(q, k_pool, v_pool, block_tables, chosen, lens, layer,
+                  sm_scale: float):
+    """A chunk of queries, each with a selection of its own (``chosen
+    [B, C, W]``): online softmax over tiles of the live pages, a key
+    counted only where its query selected it."""
+    b, c, h, d = q.shape
+    kvh, bs = k_pool.shape[2:4]
+    rep = h // kvh
+    pages = _pages_per_tile(b * c * h, block_tables.shape[1], bs)
+    bt = _pad_table(block_tables, pages)
+    keys = pages * bs
+    if chosen.shape[-1] < bt.shape[1] * bs:
+        chosen = jnp.pad(chosen, ((0, 0), (0, 0),
+                                  (0, bt.shape[1] * bs - chosen.shape[-1])))
+    qg = _grouped(q, kvh)
+
+    def tile(j, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(bt, j * pages, pages, axis=1)
+
+        def page_rows(pool):                  # [B, KVH, keys, D]
+            return pool[layer, ids].transpose(0, 2, 1, 3, 4) \
+                .reshape(b, kvh, keys, d)
+        kb, vb = page_rows(k_pool), page_rows(v_pool)
+        sel = jax.lax.dynamic_slice_in_dim(chosen, j * keys, keys,
+                                           axis=2)[:, None, None]
+        s = jnp.einsum("bcgrd,bgkd->bgrck", qg, kb,
+                       preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(sel, s, _NEG_INF)
+        m_next = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_next)
+        # a row none of whose keys is selected so far has m = -1e30 and
+        # would count exp(0) a key
+        p = jnp.where(sel, jnp.exp(s - m_next[..., None]), 0.0)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgrck,bgkd->bgrcd", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return m_next, l, acc
+
+    shape = (b, kvh, rep, c)
+    m, l, acc = jax.lax.fori_loop(
+        0, _live_tiles(lens, keys), tile,
+        (jnp.full(shape, _NEG_INF, jnp.float32),
+         jnp.zeros(shape, jnp.float32),
+         jnp.zeros(shape + (d,), jnp.float32)))
+    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, c, h, d).astype(q.dtype)
+
+
+def sparse_paged_attention(q, qi, wi, k_pool, v_pool, ki_pool,
+                           block_tables, positions, lens, *, layer,
+                           topk: int, sm_scale=None):
+    """Attention of new-token queries over the ``topk`` cached keys the
+    indexer ranks highest for each (all of them while a query sees at
+    most ``topk``), the new tokens' own K, V and ``kI`` having been
+    written first. ``q [B, C, H, D]``, ``qi [B, C, Hi, Di]``, ``wi [B,
+    C, Hi]``; pools whole, ``layer`` an int32 scalar; ``positions [B,
+    C]``, ``lens [B]`` as for :func:`ray_tpu.ops.paged_attention`. Rows
+    at positions past ``lens`` (a chunk's padding) come back zero or
+    attend live keys: the caller's to discard."""
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    c = q.shape[1]
+    if c == 1:
+        scores = index_scores(qi, wi, ki_pool, block_tables, positions,
+                              lens, layer)
+        # the scores' window is the table padded to whole tiles
+        bt = _pad_table(block_tables,
+                        scores.shape[-1] // k_pool.shape[3])
+        return _decode_selected(q, k_pool, v_pool, bt, scores, layer,
+                                topk, sm_scale)
+
+    def chunk(q, qi, wi, positions, lens):
+        scores = index_scores(qi, wi, ki_pool, block_tables, positions,
+                              lens, layer)
+        return _chunk_masked(q, k_pool, v_pool, block_tables,
+                             topk_mask(scores, topk), lens, layer,
+                             sm_scale)
+
+    rows = _ROW_BLOCK
+    if c <= rows or c % rows:
+        return chunk(q, qi, wi, positions, lens)
+
+    # a chunk in blocks of rows, as many as hold a live row: a question
+    # of 64 tokens behind a cached document fills one block of a
+    # 2048-row chunk, and a block's keys end at its own last row
+    def block(i, out):
+        def rows_of(x):
+            return jax.lax.dynamic_slice_in_dim(x, i * rows, rows, axis=1)
+        pos = rows_of(positions)
+        o = chunk(rows_of(q), rows_of(qi), rows_of(wi), pos,
+                  jnp.minimum(lens, pos[:, -1] + 1))
+        return jax.lax.dynamic_update_slice_in_dim(out, o, i * rows,
+                                                   axis=1)
+
+    live = jnp.max(lens - positions[:, 0]).astype(jnp.int32)
+    return jax.lax.fori_loop(0, (live + rows - 1) // rows, block,
+                             jnp.zeros_like(q))

@@ -56,13 +56,18 @@ class DisaggHandoffError(RayTpuError):
 
 
 # ------------------------------------------------------------ KV codec
-def pack_kv_blocks(k: np.ndarray, v: np.ndarray,
-                   wire: str = "bf16") -> Dict[str, Any]:
+def pack_kv_blocks(k: np.ndarray, v: np.ndarray, wire: str = "bf16",
+                   extra: Optional[Dict[str, np.ndarray]] = None
+                   ) -> Dict[str, Any]:
     """Pack gathered KV block slabs ``[n_layers, n_blocks, kv_heads,
     block_size, head_dim]`` for the wire. ``"bf16"`` ships the arrays in
     their native dtype (bit-exact roundtrip); ``"int8"`` quantizes each
-    slab blockwise (``quantize_int8_np``). ``wire_bytes`` is the actual
-    transport footprint as the zero-copy serializer would ship it."""
+    slab blockwise (``quantize_int8_np``). ``extra`` holds the same
+    blocks of a cache's further pools (an indexer's keys), by name:
+    shipped as they are under either wire — a selection made from
+    quantized keys is another selection, and they are a sliver of a
+    page. ``wire_bytes`` is the actual transport footprint as the
+    zero-copy serializer would ship it."""
     if wire not in ("bf16", "int8"):
         raise ValueError(f"unknown kv wire format {wire!r}")
     k = np.ascontiguousarray(k)
@@ -79,12 +84,22 @@ def pack_kv_blocks(k: np.ndarray, v: np.ndarray,
         out["k"], out["k_scales"] = quantize_int8_np(k)
         out["v"], out["v_scales"] = quantize_int8_np(v)
         payload = [out["k"], out["k_scales"], out["v"], out["v_scales"]]
+    if extra:
+        out["extra"] = {name: np.ascontiguousarray(a)
+                        for name, a in extra.items()}
+        payload += list(out["extra"].values())
     try:
         from ray_tpu.core.protocol import wire_sizeof
         out["wire_bytes"] = int(wire_sizeof(payload))
     except Exception:
         out["wire_bytes"] = int(sum(a.nbytes for a in payload))
     return out
+
+
+def unpack_kv_extra(kv: Dict[str, Any]) -> Dict[str, np.ndarray]:
+    """The further pools' slabs a payload carries, by name (none for a
+    cache of k and v alone)."""
+    return {name: np.asarray(a) for name, a in kv.get("extra", {}).items()}
 
 
 def _np_dtype(name: str) -> np.dtype:

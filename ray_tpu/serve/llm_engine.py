@@ -145,7 +145,10 @@ class EngineConfig:
         import jax.numpy as jnp
         c = model_config
         itemsize = jnp.dtype(c.dtype).itemsize
-        return 2 * c.n_layers * c.kv_heads * c.head_dim * itemsize
+        # k and v, and the indexer's one key head where there is one
+        row = 2 * c.kv_heads * c.head_dim \
+            + (c.index_dim if c.index_topk else 0)
+        return c.n_layers * row * itemsize
 
 
 def _unpack(rows, width: int, scalars: int):
@@ -384,39 +387,36 @@ class LLMEngine:
         self._jit_verify = jax.jit(verify_fn, donate_argnums=(2,)) \
             if ec.spec_tokens > 0 else None
 
+        # every pool of the cache (k, v, and the indexer's keys where
+        # the model has them) has blocks on axis 1: a page is that index
+        # of each, and whatever moves a page moves all of them.
         # copy-on-write block copy (fully-matched prompt tail): one
-        # block's k/v copied src -> dst across all layers; indices are
+        # block copied src -> dst across all layers; indices are
         # traced scalars, so every CoW reuses the same compiled program
         @jax.named_scope("kv_copy")
         def _copy_fn(cache, src, dst):
-            k = cache["k"]
-            v = cache["v"]
-            k = jax.lax.dynamic_update_slice_in_dim(
-                k, jax.lax.dynamic_slice_in_dim(k, src, 1, axis=1),
-                dst, axis=1)
-            v = jax.lax.dynamic_update_slice_in_dim(
-                v, jax.lax.dynamic_slice_in_dim(v, src, 1, axis=1),
-                dst, axis=1)
-            return {"k": k, "v": v}
+            return {name: jax.lax.dynamic_update_slice_in_dim(
+                pool, jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=1),
+                dst, axis=1) for name, pool in cache.items()}
 
         self._jit_copy = jax.jit(_copy_fn, donate_argnums=(0,))
 
         # disaggregated hand-off block I/O (serve/disagg.py): gather
-        # pulls a request's blocks into one contiguous slab for the
-        # wire; scatter adopts a shipped slab into this pool. Both run
-        # at the FIXED padded shape (blocks_per_seq ids) so adoption
+        # pulls a request's blocks into one contiguous slab a pool for
+        # the wire; scatter adopts shipped slabs into this pool. Both
+        # run at the FIXED padded shape (blocks_per_seq ids) so adoption
         # never recompiles — pad ids point at the reserved trash block
         # and pad data is zeros, so the duplicate block-0 writes all
         # write zeros and scatter order cannot matter.
         @jax.named_scope("kv_gather")
         def _gather_fn(cache, ids):
-            return (jnp.take(cache["k"], ids, axis=1),
-                    jnp.take(cache["v"], ids, axis=1))
+            return {name: jnp.take(pool, ids, axis=1)
+                    for name, pool in cache.items()}
 
         @jax.named_scope("kv_scatter")
-        def _scatter_fn(cache, ids, k_slab, v_slab):
-            return {"k": cache["k"].at[:, ids].set(k_slab),
-                    "v": cache["v"].at[:, ids].set(v_slab)}
+        def _scatter_fn(cache, ids, slabs):
+            return {name: pool.at[:, ids].set(slabs[name])
+                    for name, pool in cache.items()}
 
         self._jit_gather = jax.jit(_gather_fn)
         self._jit_scatter = jax.jit(_scatter_fn, donate_argnums=(0,))
@@ -474,6 +474,11 @@ class LLMEngine:
         # work fraction of the paged fast path (any backend)
         self._decode_pages_live = 0
         self._decode_pages_window = 0
+        # what a selecting, routing model did, from positions alone (no
+        # device work): keys a query could see and keys it attended
+        # (min(visible, index_topk)), summed over queries; keys the
+        # indexer scored and (token, expert) assignments, over layers
+        self._sparse = collections.Counter()
         self._prompt_blocks_total = 0   # full prompt blocks seen
         self._cow_copies = 0
         # disagg hand-off accounting (the bench's per-request ship
@@ -794,18 +799,7 @@ class LLMEngine:
                             entries.append((key, blk))
             if not entries:
                 return None
-            T = ec.blocks_per_seq
-            ks, vs = [], []
-            for i0 in range(0, len(entries), T):
-                grp = entries[i0:i0 + T]
-                ids = np.zeros((T,), np.int32)
-                ids[:len(grp)] = [b for _, b in grp]
-                k, v = self._jit_gather(self._cache, jnp.asarray(ids))
-                ks.append(np.asarray(k)[:, :len(grp)])
-                vs.append(np.asarray(v)[:, :len(grp)])
-            from ray_tpu.serve.disagg import pack_kv_blocks
-            kv = pack_kv_blocks(np.concatenate(ks, axis=1),
-                                np.concatenate(vs, axis=1), ec.kv_wire)
+            kv = self._ship_blocks([b for _, b in entries])
             payload = {
                 "chains": [[(list(key), slab_idx[blk])
                             for key, blk in chain] for chain in chains],
@@ -852,9 +846,6 @@ class LLMEngine:
         np, jnp = self._np, self._jnp
 
         def _do():
-            from ray_tpu.serve.disagg import unpack_kv_blocks
-            k_slab, v_slab = unpack_kv_blocks(
-                payload["kv"], dtype=self._cache["k"].dtype)
             plan: List[tuple] = []     # (slab index, local block id)
             with self._lock:
                 if not ec.enable_prefix_sharing:
@@ -889,21 +880,7 @@ class LLMEngine:
                     # chain truncated: deeper chunks need their parent
             if not plan:
                 return 0
-            T = ec.blocks_per_seq
-            shp = self._cache["k"].shape
-            for i0 in range(0, len(plan), T):
-                grp = plan[i0:i0 + T]
-                ids = np.zeros((T,), np.int32)
-                ids[:len(grp)] = [b for _, b in grp]
-                k_pad = np.zeros((shp[0], T) + shp[2:], k_slab.dtype)
-                v_pad = np.zeros_like(k_pad)
-                for j, (idx, _) in enumerate(grp):
-                    k_pad[:, j] = k_slab[:, idx]
-                    v_pad[:, j] = v_slab[:, idx]
-                self._cache = self._jit_scatter(
-                    self._cache, jnp.asarray(ids), jnp.asarray(k_pad),
-                    jnp.asarray(v_pad))
-            self._jax.block_until_ready(self._cache["k"])
+            self._adopt_blocks(payload["kv"], plan)
             if self._metrics is not None:
                 try:
                     self._metrics.serve_prefix_migrated.inc(
@@ -921,6 +898,51 @@ class LLMEngine:
             return len(plan)
 
         return self._run_on_step_thread(_do)
+
+    def _ship_blocks(self, blocks: List[int]) -> Dict[str, Any]:
+        """These pages of every pool, packed for the wire (step thread):
+        gathered ``blocks_per_seq`` ids at a time, the compiled shape."""
+        from ray_tpu.serve.disagg import pack_kv_blocks
+        np, T = self._np, self.config.blocks_per_seq
+        parts: List[Dict[str, Any]] = []
+        for i0 in range(0, len(blocks), T):
+            grp = blocks[i0:i0 + T]
+            ids = np.zeros((T,), np.int32)
+            ids[:len(grp)] = grp
+            slabs = self._jit_gather(self._cache, self._jnp.asarray(ids))
+            parts.append({name: np.asarray(a)[:, :len(grp)]
+                          for name, a in slabs.items()})
+        slabs = {name: np.concatenate([p[name] for p in parts], axis=1)
+                 for name in parts[0]}
+        return pack_kv_blocks(
+            slabs.pop("k"), slabs.pop("v"), self.config.kv_wire,
+            extra=slabs)
+
+    def _adopt_blocks(self, kv: Dict[str, Any], plan: List[tuple]) -> None:
+        """Write shipped pages into this pool (step thread): ``plan``
+        pairs a slab index of the packed ``kv`` with a local block id."""
+        from ray_tpu.serve.disagg import unpack_kv_blocks, unpack_kv_extra
+        np, T = self._np, self.config.blocks_per_seq
+        slabs = dict(unpack_kv_extra(kv))
+        slabs["k"], slabs["v"] = unpack_kv_blocks(
+            kv, dtype=self._cache["k"].dtype)
+        if set(slabs) != set(self._cache):
+            raise ValueError(
+                f"shipped pools {sorted(slabs)} are not this engine's "
+                f"{sorted(self._cache)}")
+        for i0 in range(0, len(plan), T):
+            grp = plan[i0:i0 + T]
+            ids = np.zeros((T,), np.int32)
+            ids[:len(grp)] = [b for _, b in grp]
+            pads = {}
+            for name, slab in slabs.items():
+                shp = self._cache[name].shape
+                pads[name] = np.zeros((shp[0], T) + shp[2:], slab.dtype)
+                pads[name][:, :len(grp)] = slab[:, [i for i, _ in grp]]
+            self._cache = self._jit_scatter(
+                self._cache, self._jnp.asarray(ids),
+                {name: self._jnp.asarray(a) for name, a in pads.items()})
+        self._jax.block_until_ready(self._cache)
 
     def _programs(self) -> Dict[str, Any]:
         """The jitted programs this engine can run, by name. With
@@ -943,9 +965,9 @@ class LLMEngine:
         zero = np.int32(0)
         self._cache = self._jit_copy(self._cache, zero, zero)
         ids = jnp.zeros((self.config.blocks_per_seq,), jnp.int32)
-        k, v = self._jit_gather(self._cache, ids)
+        slabs = self._jit_gather(self._cache, ids)
         self._cache = self._jit_scatter(
-            self._cache, ids, jnp.zeros_like(k), jnp.zeros_like(v))
+            self._cache, ids, self._jax.tree.map(jnp.zeros_like, slabs))
         self._jax.block_until_ready(self._cache)
 
     def warmup(self, timeout_s: float = 600.0) -> None:
@@ -986,6 +1008,7 @@ class LLMEngine:
             self._h2d_transfers = 0
             self._decode_wall_s = self._prefill_wall_s = 0.0
             self._decode_pages_live = self._decode_pages_window = 0
+            self._sparse.clear()
             self._prompt_blocks_total = 0
             self._occupancy.clear()
             self._clock.reset()
@@ -1034,6 +1057,10 @@ class LLMEngine:
                     round(self._decode_pages_live
                           / self._decode_pages_window, 4)
                     if self._decode_pages_window else None),
+                "keys_visible_total": self._sparse["visible"],
+                "keys_attended_total": self._sparse["attended"],
+                "indexer_keys_scored_total": self._sparse["scored"],
+                "moe_assignments_total": self._sparse["assigned"],
                 "kv_block_size": self.config.kv_block_size,
                 "paged_impl": self.model_config.paged_impl,
                 # what each traced attention call resolved to and why
@@ -1440,22 +1467,8 @@ class LLMEngine:
             dst = req.blocks[req.hit_blocks:n_ship]
         # scatter OUTSIDE the lock (step thread owns the device)
         if dst:
-            from ray_tpu.serve.disagg import unpack_kv_blocks
-            k_slab, v_slab = unpack_kv_blocks(
-                payload["kv"], dtype=self._cache["k"].dtype)
-            T = ec.blocks_per_seq
-            ids = np.zeros((T,), np.int32)
-            ids[:len(dst)] = dst
-            shp = self._cache["k"].shape
-            k_pad = np.zeros((shp[0], T) + shp[2:], k_slab.dtype)
-            v_pad = np.zeros_like(k_pad)
-            k_pad[:, :len(dst)] = k_slab[:, req.hit_blocks:n_ship]
-            v_pad[:, :len(dst)] = v_slab[:, req.hit_blocks:n_ship]
-            jnp = self._jnp
-            self._cache = self._jit_scatter(
-                self._cache, jnp.asarray(ids), jnp.asarray(k_pad),
-                jnp.asarray(v_pad))
-            self._jax.block_until_ready(self._cache["k"])
+            self._adopt_blocks(payload["kv"], list(zip(
+                range(req.hit_blocks, n_ship), dst)))
         t1w = time.time()
         first = int(payload["first"])
         req.seq_len = plen
@@ -1536,14 +1549,8 @@ class LLMEngine:
             if req.cancelled:
                 self._release_locked(req)
                 return
-            ids = np.zeros((ec.blocks_per_seq,), np.int32)
-            ids[:n_ship] = req.blocks[:n_ship]
-        k_slab, v_slab = self._jit_gather(self._cache,
-                                          self._jnp.asarray(ids))
-        k_np = np.asarray(k_slab)[:, :n_ship]
-        v_np = np.asarray(v_slab)[:, :n_ship]
-        from ray_tpu.serve.disagg import pack_kv_blocks
-        kv = pack_kv_blocks(k_np, v_np, ec.kv_wire)
+            shipped = list(req.blocks[:n_ship])
+        kv = self._ship_blocks(shipped)
         payload = {
             "prompt": list(req.prompt),
             "first": int(first),
@@ -1634,6 +1641,7 @@ class LLMEngine:
         req.prefill_pos += n
         req.n_chunks += 1
         self._prefill_chunks += 1
+        self._account_queries([start], [n])
         if req.trace is not None:
             req.trace.span(RT.PREFILL, t0w, time.time(),
                            pos=start, tokens=n, tick=self._clock.tick_no)
@@ -1701,6 +1709,30 @@ class LLMEngine:
         self._decode_pages_live += int(pages.sum())
         self._decode_pages_window += ec.decode_slots * ec.blocks_per_seq
 
+    def _account_queries(self, first, n) -> None:
+        """Book the queries at positions ``first[i] .. first[i] + n[i] -
+        1`` (numpy arrays, a sequence each): what each could see, what
+        it attended, what the indexer scored and the experts it was
+        sent to. Host arithmetic on positions; a model that neither
+        selects nor routes books nothing (its counters stay 0)."""
+        mc, np = self.model_config, self._np
+        if not (mc.index_topk or mc.experts_per_token):
+            return
+        first, n = np.asarray(first, np.int64), np.asarray(n, np.int64)
+        last = first + n                      # query p sees p + 1 keys
+        visible = int((last * (last + 1) - first * (first + 1)).sum()) // 2
+        attended = visible
+        if mc.index_topk:
+            k = mc.index_topk                 # positions past k see k
+            lo, hi = np.minimum(first, k), np.minimum(last, k)
+            attended = int((hi * (hi + 1) - lo * (lo + 1)).sum()) // 2 \
+                + int(((last - hi) - (first - lo)).sum()) * k
+            self._sparse["scored"] += visible * mc.n_layers
+        self._sparse["visible"] += visible
+        self._sparse["attended"] += attended
+        self._sparse["assigned"] += int(n.sum()) * mc.n_layers \
+            * mc.experts_per_token
+
     def _decode_once(self) -> None:
         if self.config.spec_tokens > 0:
             self._decode_speculative()
@@ -1723,6 +1755,8 @@ class LLMEngine:
                         pass
                 rows = self._slot_rows.copy()
             self._account_decode_pages(rows[:, 1] + 1)
+            self._account_queries([r.seq_len for r in active],
+                                  [1] * len(active))
             t0 = time.monotonic()
             self._h2d_transfers += 1
         with clock.phase("engine.decode.dispatch"):

@@ -278,3 +278,60 @@ def test_compiled_step_casts_no_weight_of_the_engines_tree(
     stacked = weight_casts(on_masters)
     assert f"{cfg.n_layers},{cfg.d_model},{cfg.d_ff}" in stacked, stacked
     assert on_masters.memory_analysis().temp_size_in_bytes > 64 << 20
+
+
+@pytest.mark.parametrize("entry", ["decode_step", "prefill"])
+def test_compiled_selecting_routing_step_reads_the_expert_stack_in_place(
+        entry, one_chip, monkeypatch):
+    """The served forms of PR 34 (dropless top-k experts, an indexer and
+    a third pool) at two layers of narrowed Keye widths: the TPU
+    compiler accepts the whole step; the grouped products are its own
+    ragged-dot kernels fed the WHOLE ``[layers, experts, ...]`` stack
+    (scanned like the other leaves, each layer's experts were sliced
+    out first: a copy of all of them in every layer of every call, 22
+    of a decode step's 46 ms on the chip); K and V are written in place
+    as before; and the temporaries stay a few tiles."""
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = TransformerConfig(
+            vocab_size=512, d_model=1024, n_layers=2, n_heads=8,
+            n_kv_heads=2, head_dim=128, d_ff=1024, max_seq_len=4096,
+            rotary_dim=128, block_style="llama", dtype=jnp.bfloat16,
+            remat_policy="none", paged_impl="kernel", n_experts=16,
+            experts_per_token=4, expert_width=512, qk_norm=True,
+            index_topk=512, index_heads=4, index_dim=64)
+        slots, table, blocks, chunk = 8, 256, 2049, 512
+
+        def shaped(tree):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip), tree)
+        params = shaped(jax.eval_shape(lambda: init_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+        cache = shaped(jax.eval_shape(
+            lambda: init_kv_cache(cfg, blocks, BLOCK)))
+        fn, rows = _engine_program(cfg, entry, slots, table, chunk)
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+            params, jax.ShapeDtypeStruct(rows, jnp.int32,
+                                         sharding=one_chip),
+            cache).compile()
+        text = compiled.as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert set(cache) == {"k", "v", "ki"}
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3
+    # no op makes one layer's experts: [16, 1024, 512] or [16, 512, 1024]
+    assert not re.findall(r"= bf16\[16,(?:1024,512|512,1024)\]", text)
+    assert text.count("may-alias") + text.count("must-alias") >= 3
+    page = f"{blocks},{cfg.kv_heads},{BLOCK},{cfg.head_dim}]"
+    made = re.findall(
+        r"= bf16\[(?:\d+,)?" + re.escape(page) + r"\S* ([\w\-]+)\(", text)
+    assert not [op for op in made if op not in (
+        "parameter", "get-tuple-element", "bitcast", "scatter", "fusion")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20

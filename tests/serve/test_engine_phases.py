@@ -254,9 +254,9 @@ def test_compiled_programs_ops_carry_the_scopes():
         ).as_text(debug_info=True)
         ids = jnp.zeros((T,), i32)
         gather = eng._jit_gather.lower(eng._cache, ids)
-        slab = jax.eval_shape(eng._jit_gather, eng._cache, ids)[0]
+        slabs = jax.eval_shape(eng._jit_gather, eng._cache, ids)
         scatter = eng._jit_scatter.lower(
-            eng._cache, ids, slab, slab).as_text(debug_info=True)
+            eng._cache, ids, slabs).as_text(debug_info=True)
         gather = gather.as_text(debug_info=True)
     finally:
         eng.shutdown()
