@@ -62,8 +62,13 @@ class CompileCounter:
 
 
 class Tracer:
-    """A profiler trace between two marks, reduced when it stops. Only
-    the process that holds the chip can trace it."""
+    """A profiler trace between two marks. ``close`` writes the second
+    mark and costs nothing; ``finish`` stops the profiler (tens of
+    seconds after a few seconds of serving: it converts every host
+    event) and turns what it wrote into the summary, of the stretch
+    between the marks. Only the process that holds the chip can trace
+    it; a process that serves finishes when nothing is being served any
+    more."""
 
     def __init__(self, out_dir: str, rehearse: bool = False):
         self.dir, self.rehearse = out_dir, rehearse
@@ -75,9 +80,11 @@ class Tracer:
         with jax.profiler.TraceAnnotation(T.OPEN_MARK):
             pass
 
-    def stop(self) -> Dict[str, Any]:
+    def close(self) -> None:
         with jax.profiler.TraceAnnotation(T.CLOSE_MARK):
             pass
+
+    def finish(self) -> Dict[str, Any]:
         jax.profiler.stop_trace()
         path = T.find_xplane(self.dir)
         if path is None:

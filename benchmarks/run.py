@@ -15,12 +15,15 @@ the cell left behind. ``--rehearse`` (tests only) walks the same code
 on the CPU at a narrow width and reports ``"platform": "cpu"``.
 """
 import argparse
+import collections
 import json
 import os
+import shutil
 import signal
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 
 T_START = time.time()
@@ -28,6 +31,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 #: a run must end inside the driver's 360 s (1200 s when it compiles)
 CHILD_LIMIT_S = 1150.0
+#: of a failed run: this much of the end of what the cell's process
+#: printed is kept, and of each worker's log shown
+TAIL_BYTES, LOG_TAIL_BYTES = 64 * 1024, 1500
 
 
 def _kill_marked(marker: str) -> None:
@@ -46,6 +52,37 @@ def _kill_marked(marker: str) -> None:
             continue
 
 
+def keep_failed(args, rc: int, pid: int, tail: bytes) -> str:
+    """What a failed run leaves, kept inside the checkout: the end of
+    what the cell's process printed and the session's worker logs. The
+    end of each log goes to stderr too, where whoever ran this keeps
+    the last lines. Returns the directory."""
+    dest = os.path.join(ROOT, ".bench_tmp", "failed",
+                        f"{args.workload}.seed{args.seed}.trace{args.trace}")
+    shutil.rmtree(dest, ignore_errors=True)
+    os.makedirs(dest)
+    with open(os.path.join(dest, "stderr_tail.txt"), "wb") as f:
+        f.write(tail)
+    with open(os.path.join(dest, "exit.json"), "w") as f:
+        json.dump({"code": rc, "argv": sys.argv[1:], "pid": pid,
+                   "seconds_in": time.time() - T_START}, f)
+    from benchmarks.spec import session_dir      # no JAX in there
+    logs = os.path.join(session_dir(pid), "logs")
+    if os.path.isdir(logs):
+        shutil.copytree(logs, os.path.join(dest, "logs"))
+        for name in sorted(os.listdir(logs)):
+            path = os.path.join(logs, name)
+            if not os.path.isfile(path) or not os.path.getsize(path):
+                continue
+            with open(path, "rb") as f:
+                f.seek(max(0, os.path.getsize(path) - LOG_TAIL_BYTES))
+                end = f.read().decode(errors="replace")
+            print(f"benchmarks: the end of {name}:\n{end.rstrip()}",
+                  file=sys.stderr)
+    shutil.rmtree(session_dir(pid), ignore_errors=True)
+    return dest
+
+
 def parent(args) -> int:
     marker = f"{os.getpid()}.{int(T_START)}"
     fd, result_path = tempfile.mkstemp(prefix="bench_result_", suffix=".json")
@@ -60,9 +97,23 @@ def parent(args) -> int:
            "--trace", str(args.trace)]
     if args.rehearse:
         cmd.append("--rehearse")
-    # the child's own chatter goes to stderr: stdout carries the result
-    proc = subprocess.Popen(cmd, cwd=ROOT, env=env, stdout=sys.stderr,
-                            start_new_session=True)
+    # the child's own chatter goes to stderr (stdout carries the result)
+    # through this process, which keeps its end for a run that fails
+    proc = subprocess.Popen(cmd, cwd=ROOT, env=env, stdout=subprocess.PIPE,
+                            stderr=subprocess.STDOUT, start_new_session=True)
+    tail: collections.deque = collections.deque()
+
+    def relay():
+        kept = 0
+        for chunk in iter(lambda: proc.stdout.read1(65536), b""):
+            sys.stderr.buffer.write(chunk)
+            sys.stderr.buffer.flush()
+            tail.append(chunk)
+            kept += len(chunk)
+            while kept - len(tail[0]) >= TAIL_BYTES:
+                kept -= len(tail.popleft())
+    relayer = threading.Thread(target=relay, daemon=True)
+    relayer.start()
     try:
         rc = proc.wait(timeout=CHILD_LIMIT_S)
     except subprocess.TimeoutExpired:
@@ -72,14 +123,17 @@ def parent(args) -> int:
             os.killpg(proc.pid, signal.SIGKILL)
             proc.wait()
         _kill_marked(marker)
+    relayer.join(timeout=10)     # the workers held the pipe's other end
     try:
         with open(result_path) as f:
             line = f.read().strip()
     finally:
         os.unlink(result_path)
     if rc != 0 or not line:
+        kept = keep_failed(args, rc, proc.pid, b"".join(tail)[-TAIL_BYTES:])
         print(f"benchmarks: the cell's process ended with code {rc} and "
-              f"{'no' if not line else 'a'} result", file=sys.stderr)
+              f"{'no' if not line else 'a'} result; its last output and "
+              f"its workers' logs are kept under {kept}", file=sys.stderr)
         return rc or 1
     print(line, flush=True)
     return 0
@@ -115,9 +169,13 @@ def child(args) -> None:
     print("[bench] all metrics: " + json.dumps(
         {k: v["value"] for k, v in both.items()}), file=sys.stderr)
     print("[bench] notes: " + json.dumps(out["notes"], default=str),
-          file=sys.stderr, flush=True)
+          file=sys.stderr)
+    print(f"[bench] heartbeats late, [s, at]: {json.dumps(out['beats'])}\n"
+          f"[bench] correct {out['correct']}; compared, [number, limit]: "
+          + json.dumps(out["compared"]), file=sys.stderr, flush=True)
     line = spec.result_line(out["correct"], out["attempted"], out["failed"],
-                            metrics, device, breakdown)
+                            metrics, device, breakdown, out["beats"],
+                            out["compared"])
     with open(args.child, "w") as f:
         f.write(line)
 

@@ -5,12 +5,12 @@ deploy (weights, warm-up), the correctness check, warming the handles
 and the traffic that runs before the window opens (an open loop's warm
 blocks, a closed loop's cache fill); the window is ``--seconds`` of the
 schedule; with ``--trace 1`` the traffic goes on after the window for
-the traced stretch. After that nothing new is sent, and the run listens
-on for the first tokens of the requests that were due in the window."""
+the traced stretch. After that nothing new is sent, the run listens on
+for the first tokens of the requests that were due in the window, and
+only when the clients have stopped is the trace reduced."""
 import os
 import queue
 import shutil
-import tempfile
 import threading
 import time
 from typing import Any, Dict, List
@@ -128,38 +128,34 @@ def _open_loop_feeder(p, clients: Clients, seed, total_s, vocab):
 
 
 def _closed_loop_feeders(cell, p, clients: Clients, seed, vocab,
-                         ran_out: list):
-    """The clients' threads, after the cache fill: every client's first
-    document is prefilled once (set-up), and client c starts at question
-    c mod ``questions_per_doc`` of it, so that the window opens on
-    clients spread over their documents as in a long-running service,
-    not on sixteen cold documents at once."""
-    per_client = traffic.closed_loop(p, seed, vocab)
-    per_doc, qlen = p["questions_per_doc"], p["question_len"]
-    fills = []
-    for c, reqs in enumerate(per_client):
-        for r in reqs:     # tokens the prefix cache can serve: the
-            # document, once a question (or the fill) has prefilled it
-            cached = r["question"] > 0 or r["doc"] == 0
-            r["shared"] = len(r["prompt"]) - qlen if cached else 0
-        fills.append({"id": -1 - c, "prompt": reqs[0]["prompt"][:-qlen],
-                      "asked": 1})
-        per_client[c] = reqs[c % per_doc:]
+                         asked: list, ran_out: list):
+    """The clients' threads, after the cache fill (set-up; see
+    ``traffic.closed_loop_start``). ``asked[c]`` counts what client c
+    has sent. A replay has no end unless its file gives it one; a client
+    that does reach it leaves the loop a client short from then on, and
+    says when in ``ran_out``."""
+    fills, replays = traffic.closed_loop_start(p, seed, vocab)
     clients.submit_and_wait(fills)
+    asked.extend(0 for _ in replays)
 
-    def client(reqs):
+    def client(c, reqs):
         for req in reqs:
             if clients.closing.is_set():
                 return
             done = threading.Event()
+            asked[c] += 1
             clients.todo.put((req, done))
             while not done.wait(0.5):
                 if clients.stop.is_set():
                     return
-        ran_out.append(reqs[0]["id"])
-        log(f"{cell.name}: a client ran out of requests before the end")
-    return [threading.Thread(target=client, args=(reqs,), daemon=True)
-            for reqs in per_client]
+        if clients.closing.is_set():
+            return
+        when = clients.now()
+        ran_out.append([c, when])
+        log(f"{cell.name}: client {c} ran out of requests at {when:.1f} s "
+            f"of the window, after {asked[c]}")
+    return [threading.Thread(target=client, args=(c, reqs), daemon=True)
+            for c, reqs in enumerate(replays)]
 
 
 def _served_check(p, clients: Clients, ask, seed, vocab) -> Dict[str, Any]:
@@ -189,12 +185,13 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if not cell.rehearse and tpu_chip_count() < cell.chips:
         raise SystemExit(f"benchmarks: the cell needs {cell.chips} chip(s) "
                          f"and this machine has {tpu_chip_count()}")
-    session = os.path.join(tempfile.gettempdir(), f"rtb{os.getpid()}")
+    session = spec.session_dir(os.getpid())
     ray_tpu.init(num_cpus=16, num_tpus=max(1, cell.chips),
                  _num_initial_workers=2, _session_dir=session)
     try:
         out = _drive(cell, seed, seconds, trace, t_start)
     except BaseException:
+        # run.py's parent keeps them, and the end of this output
         log(f"{cell.name}: failed; the workers' logs are under {session}/logs")
         raise
     finally:
@@ -246,7 +243,8 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
     log(f"{cell.name}: served check {served} at "
         f"{time.time() - t_start:.1f} s")
     trace_s = float(p["trace_seconds"]) if trace else 0.0
-    ran_out: list = []     # closed loop: clients whose replay was too short
+    asked: list = []       # closed loop: requests each client has sent,
+    ran_out: list = []     # and [client, when] if its replay ended
     if cell.kind == "open_loop":
         # a few short requests first: the token path back to this
         # process has run once
@@ -259,7 +257,7 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
     else:
         warm_s = float(p["warm_seconds"])
         feeders = _closed_loop_feeders(cell, p, clients, seed, vocab,
-                                       ran_out)
+                                       asked, ran_out)
     clients.records.clear()
     log(f"{cell.name}: clients ready at {time.time() - t_start:.1f} s; "
         f"{warm_s:.1f} s of traffic before the window opens")
@@ -273,30 +271,41 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
     setup_s = time.time() - t_start
     time.sleep(max(0.0, seconds - clients.now()))
     after = ask("bench_facts")
-    window_end = clients.now()
-    beats = {"driver": heart.worst, "replica": after["heartbeat"]}
-    summary = None
+    at = {"window_end": clients.now()}      # seconds of the window
+    beats = {"driver": list(heart.worst), "replica": after["heartbeat"]}
+    traced = None
     if trace:
         s0 = ask("bench_trace_start")
         time.sleep(trace_s)
-        out = ask("bench_trace_stop")
-        summary = out["summary"]
-        summary["engine"] = counters_delta(out["stats"], s0)
-    # nothing new; listen on for the first tokens of the requests due in
-    # the window, so that one sent at its very end is not a failure
+        traced = counters_delta(ask("bench_trace_stop"), s0)
+    # what is measured ends here. Nothing new is sent; listen on for the
+    # first tokens of the requests due in the window, so that one sent
+    # at its very end is not a failure
     clients.closing.set()
+    at["closing"] = clients.now()
     deadline = clients.now() + float(p["drain_seconds"])
     while clients.now() < deadline and any(
             not r["tokens"] and not r["error"]
             for r in stats.due_in_window(list(clients.records), seconds)):
         time.sleep(0.02)
-    listen_s = clients.now()
+    listen_s = at["listened"] = clients.now()
     clients.shutdown()
     records = sorted(clients.records, key=lambda r: r["due"])
+    summary = None
+    if trace:
+        # with no client asking any more: stopping the profiler and
+        # reducing its trace is tens of seconds of the replica's process
+        at["reduce_from"] = clients.now()
+        summary = ask("bench_trace_reduce")
+        summary["engine"] = traced
+        at["reduce_to"] = clients.now()
     end = ask("bench_facts")
     audit = ask("pool_audit")
     if jax_backend_initialized():
         raise RuntimeError("the serve driver initialised a JAX backend")
+    # closed loop: what the busiest client sent, and [client, second of
+    # the window] of each that reached a replay's end while measured
+    replay = {"asked_max": max(asked), "ran_out": ran_out} if asked else None
 
     window = stats.due_in_window(records, seconds)
     failed = sum(stats.is_failed(r, listen_s) for r in window)
@@ -310,8 +319,8 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
         f"tokens arrived; in flight at the middle and the end {in_flight}; "
         f"longest silence [s, at] {stats.longest_silence(records, seconds)}; "
         f"idle threads woke at worst [s late, at] {beats}; "
-        f"window closed at {window_end:.3f} s, listened to {listen_s:.3f} s"
-        f"; programs {programs}")
+        f"seconds of the window at which {at}; programs {programs}; "
+        f"closed-loop replay {replay}")
     obs = {
         "setup_s": setup_s, "window_s": float(seconds),
         "listen_s": listen_s, "requests": records,
@@ -339,6 +348,17 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
     }
     return {"correct": bool(verdict["ok"] and served["ok"] and engine_ok),
             "attempted": len(window), "failed": int(failed), "obs": obs,
+            "beats": beats,
+            # each number held to a limit, [number, limit]
+            "compared": {
+                "logits": [verdict["errors"]["logits"],
+                           verdict["tol"]["logits"]],
+                "served_token_gap": [served["errors"]["served_token_gap"],
+                                     served["tol"]],
+                "served_tokens_short": [served["tokens_short"], 0],
+                "pool_audit_findings": [len(audit), 0],
+                "clients_ran_out": [len(ran_out), 0]},
             "notes": {"check": verdict, "served_check": served,
-                      "pool_audit": audit,
-                      "programs": programs, "in_flight_mid_end": in_flight}}
+                      "pool_audit": audit, "programs": programs,
+                      "in_flight_mid_end": in_flight, "at": at,
+                      "replay": replay}}

@@ -86,7 +86,15 @@ class BenchLLMServer(LLMServer):
         return self.engine.stats()
 
     def bench_trace_stop(self) -> Dict[str, Any]:
+        """Ends the traced stretch and returns the engine's counters at
+        its end. The profiler runs on: stopping it and reducing what it
+        wrote is tens of seconds of this process's time, which the cell
+        asks for once its clients have stopped (``bench_trace_reduce``)."""
         stats = self.engine.stats()
-        summary = self._tracer.stop()
+        self._tracer.close()
+        return stats
+
+    def bench_trace_reduce(self) -> Dict[str, Any]:
+        summary = self._tracer.finish()
         self._tracer = None
-        return {"summary": summary, "stats": stats}
+        return summary

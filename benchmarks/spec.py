@@ -7,6 +7,7 @@ import importlib
 import json
 import os
 import sys
+import tempfile
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -46,6 +47,12 @@ class Heartbeat:
             late = time.perf_counter() - before - 0.02
             if late > self.worst[0]:
                 self.worst = [late, before - self._t0]
+
+
+def session_dir(pid: int) -> str:
+    """The runtime session of a serving cell whose process is ``pid``;
+    its workers log under ``logs/``."""
+    return os.path.join(tempfile.gettempdir(), f"rtb{pid}")
 
 
 def _load(*parts: str) -> Dict[str, Any]:
@@ -157,9 +164,17 @@ def weight_seed(seed: int) -> int:
 
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Any], device: Dict[str, Any],
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]],
+                beats: Dict[str, Any], compared: Dict[str, Any]) -> str:
+    """The run's last line. After the keys the driver reads:
+    ``heartbeat_late_s``, by process the worst [seconds late, at] an
+    idle thread woke inside the window (a machine that stood still shows
+    in every process at the same instant; a fault does not), and last
+    ``compared``, every number ``correct`` rests on as [number, limit]."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         line["breakdown"] = breakdown
+    line["heartbeat_late_s"] = beats
+    line["compared"] = compared
     return json.dumps(line)

@@ -6,9 +6,10 @@ each block of arrivals and the instant inside each arrival interval);
 ``--seed`` draws the token ids, nothing else: a tail over some tens of
 requests does not repeat when the seed also moves who queues behind
 whom (PERF.md, PR 24). No JAX."""
+import itertools
 import math
 from statistics import NormalDist
-from typing import Any, Dict, List
+from typing import Any, Dict, Iterator, List, Tuple
 
 import numpy as np
 
@@ -40,7 +41,7 @@ def length_pool(params: Dict[str, Any]) -> List[List[tuple]]:
 
 
 def _tokens(rng, vocab: int, n: int) -> List[int]:
-    return [int(t) for t in rng.integers(2, vocab, size=n)]
+    return rng.integers(2, vocab, size=n).tolist()
 
 
 def warm_seconds(params: Dict[str, Any]) -> float:
@@ -107,28 +108,58 @@ def check_requests(engine: Dict[str, Any], seed: int, vocab: int
             for i in range(2)]
 
 
-def closed_loop(params: Dict[str, Any], seed: int, vocab: int
-                ) -> List[List[Dict[str, Any]]]:
-    """For each client its requests in order: document k of client c
-    has the length the file gives it, and is asked
-    ``questions_per_doc`` times in a row with a fresh question; the
-    seed draws the tokens only."""
-    rng = np.random.default_rng(seed)
+#: a client's request ids start here times its number
+CLIENT_ID_STRIDE = 10**6
+
+
+def closed_loop(params: Dict[str, Any], seed: int, vocab: int,
+                client: int) -> Iterator[Dict[str, Any]]:
+    """Client c's requests in order, without an end: document k = 0, 1,
+    2, ... has the length the file gives it (its ``doc_lengths``,
+    ``answer_lengths`` and ``doc_stride`` continued modulo their
+    lengths) and is asked ``questions_per_doc`` times in a row with a
+    fresh question. A document is built when the client comes to it, so
+    a run pays for the documents it asks and for no other, and a faster
+    engine is asked more of them, never fewer clients. The seed draws
+    the tokens only, document by document (``[seed, c, k]``): client c's
+    k-th document is the same whatever the other clients have read and
+    however many there are. ``docs_per_client``, which no cell's file
+    states, ends the replay after that many documents (the tests' way to
+    the guard that a client ran out). ``shared`` is how many of the
+    prompt's tokens the prefix cache can serve: the document, once a
+    question (or set-up's fill, for the first) has prefilled it."""
     docs, answers = params["doc_lengths"], params["answer_lengths"]
     per_doc, qlen = params["questions_per_doc"], params["question_len"]
-    clients = []
+    last = params.get("docs_per_client")
+    for k in itertools.count() if last is None else range(last):
+        rng = np.random.default_rng([int(seed), client, k])
+        n = docs[(client * params["doc_stride"] + k) % len(docs)]
+        doc = _tokens(rng, vocab, n)
+        for q in range(per_doc):
+            a = answers[(client + k * per_doc + q) % len(answers)]
+            yield {"id": k * per_doc + q + CLIENT_ID_STRIDE * client,
+                   "prompt": doc + _tokens(rng, vocab, qlen), "asked": a,
+                   "doc": k, "question": q,
+                   "shared": n if q > 0 or k == 0 else 0}
+
+
+def closed_loop_start(params: Dict[str, Any], seed: int, vocab: int
+                      ) -> Tuple[List[Dict[str, Any]], List[Iterator]]:
+    """(fills, replays). Every client's first document is prefilled
+    once in set-up (``fills``: the document alone, one token asked), and
+    client c's replay starts at question c mod ``questions_per_doc`` of
+    it, so that the window opens on clients spread over their documents
+    as in a long-running service, not on all of them cold at once."""
+    per_doc, qlen = params["questions_per_doc"], params["question_len"]
+    fills, replays = [], []
     for c in range(params["clients"]):
-        reqs = []
-        for k in range(params["docs_per_client"]):
-            n = docs[(c * params["doc_stride"] + k) % len(docs)]
-            doc = _tokens(rng, vocab, n)
-            for q in range(per_doc):
-                a = answers[(c + k * per_doc + q) % len(answers)]
-                reqs.append({"id": len(reqs) + 10000 * c,
-                             "prompt": doc + _tokens(rng, vocab, qlen),
-                             "asked": a, "doc": k, "question": q})
-        clients.append(reqs)
-    return clients
+        reqs = closed_loop(params, seed, vocab, c)
+        first = next(reqs)
+        fills.append({"id": -1 - c, "prompt": first["prompt"][:-qlen],
+                      "asked": 1})
+        replays.append(itertools.islice(
+            itertools.chain([first], reqs), c % per_doc, None))
+    return fills, replays
 
 
 def train_batches(params: Dict[str, Any], seed: int, vocab: int

@@ -13,7 +13,10 @@ import pytest
 
 from benchmarks import spec
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device",
+        # beside what the driver reads: every process's worst oversleep
+        # in the window, and last each number compared with its limit
+        "heartbeat_late_s", "compared"}
 
 
 def _run(*flags, cwd=spec.ROOT, pythonpath=None, timeout=420):
@@ -37,9 +40,12 @@ def test_train_rehearsal_prints_the_contract_line():
     line = _last(_run("--workload", "gptj-6b.train_2k", "--seed",
                       str(2**31 + 7), "--seconds", "1", "--trace", "0",
                       "--rehearse"))
-    assert set(line) == KEYS
+    assert set(line) == KEYS and list(line)[-1] == "compared"
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
+    assert set(line["compared"]) == {"loss", "grad_norm", "loss_rise"}
+    assert all(number <= limit for number, limit in line["compared"].values())
+    assert set(line["heartbeat_late_s"]) == {"driver"}
     assert set(line["metrics"]) == {"train_tok_s", "setup_s"}
     for m in line["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
@@ -55,9 +61,13 @@ def test_traced_serve_rehearsal_prints_per_layer_metrics_and_a_breakdown():
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     names = set(line["metrics"])
-    assert {"prefill_chunk_ms.ttft", "decode_step_ms.tpot", "ttft_p50_ms",
+    assert {"prefill_chunk_ms.ttft", "decode_step_ms.tpot",
             "gen_late_p99_ms", "compiles_in_window"} <= names
-    assert not names & {"ttft_slow10_ms", "tpot_p90_ms", "setup_s"}
+    # since PR 35 the judged TTFT is the median over every request of
+    # the window, and the slowest tenth's mean a per-layer view of it (no
+    # bound the contract allows holds nine requests; PERF.md section 2)
+    assert "ttft_slow10_ms" in names
+    assert not names & {"ttft_p50_ms", "tpot_p90_ms", "setup_s"}
     # device numbers are not taken from a CPU
     assert not names & {"device_idle_share.tpot", "pool_copy_share.tpot",
                         "paged_decode_roofline.tpot", "mfu"}

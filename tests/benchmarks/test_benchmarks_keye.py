@@ -213,7 +213,15 @@ def test_the_manifest_holds_the_cell_and_the_new_entries_read():
         "prefill_chunk_ms.tok", "decode_step_ms.tok", "decode_occupancy.tok",
         "kv_pool_live_share.tok", "prefix_hit_rate.tok",
         "closed_ttft_p50_ms", "device_idle_share.tok", "ready_s",
-        "hbm_in_use_share", "compiles_in_window"}
+        "hbm_in_use_share", "compiles_in_window"} | {
+        m["name"] for m in ENTRIES}
+    # entered by PR 35 behind `step_host_share`, until then the list's
+    # last, as they were written here; no other cell's line carries them
+    assert spec.benchmark()["per_layer"][37:41] == ENTRIES
+    for w in spec.benchmark()["workloads"]:
+        if w["name"] != CELL:
+            assert not {m["name"] for m in ENTRIES} & {
+                m["name"] for m in spec.load_cell(w["name"]).per_layer}
     for m in ENTRIES:
         read, args = spec.metric_reader(m["name"])
         assert read is sparse.read and args["what"]

@@ -127,6 +127,21 @@ def test_every_cell_reports_what_its_metrics_move():
             assert m["moves"] in mine, (w["name"], m["name"], m["moves"])
 
 
+def test_chat_is_judged_on_a_ttft_and_the_ttft_side_says_so():
+    """A PR that starves prefill to speed decode must not pass: the chat
+    cell keeps an end-to-end TTFT (PERF.md section 2: the median over
+    every request, the slowest tenth's mean beside it per layer), and a
+    metric of the TTFT side names a TTFT under ``moves``."""
+    chat = spec.load_cell("gptj-6b.serve_chat")
+    assert {m["name"] for m in chat.end_to_end} == {
+        "ttft_p50_ms", "tpot_p90_ms", "setup_s"}
+    per_layer = {m["name"]: m for m in chat.per_layer}
+    assert "ttft_slow10_ms" in per_layer and "ttft_p90_ms.chat" in per_layer
+    for name, m in per_layer.items():
+        if "ttft" in name:
+            assert m["moves"] == "ttft_p50_ms", name
+
+
 def test_layers_are_spelled_one_way():
     layers = {m["layer"] for m in BENCH["per_layer"]}
     assert len({l.lower() for l in layers}) == len(layers)

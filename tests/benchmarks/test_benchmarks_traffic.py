@@ -1,6 +1,7 @@
 """The traffic generator: what the file fixes stays fixed, what the seed
 draws is only order, instants and token ids."""
 import collections
+import itertools
 import json
 import os
 
@@ -11,6 +12,10 @@ from benchmarks import spec, traffic
 CHAT = json.load(open(os.path.join(spec.HERE, "traffic", "serve_chat.json")))
 DOCQA = json.load(open(os.path.join(spec.HERE, "traffic",
                                     "serve_docqa.json")))
+LONGDOC = json.load(open(os.path.join(spec.HERE, "traffic",
+                                      "serve_longdoc.json")))
+CLOSED = pytest.mark.parametrize("mix", [DOCQA, LONGDOC],
+                                 ids=["docqa", "longdoc"])
 BIG_SEED = 2**31 + 12345          # more than 32 signed bits hold
 
 
@@ -77,15 +82,20 @@ def test_lengths_follow_the_file_and_stay_inside_its_clips():
     assert max(prompts) + max(answers) < CHAT["engine"]["max_seq_len"]
 
 
+def _first(replay, n):
+    return list(itertools.islice(replay, n))
+
+
 def test_closed_loop_gives_each_client_its_documents_in_turn():
-    a = traffic.closed_loop(DOCQA, 1, 32768)
-    b = traffic.closed_loop(DOCQA, BIG_SEED, 32768)
-    assert len(a) == DOCQA["clients"]
     per_doc, qlen = DOCQA["questions_per_doc"], DOCQA["question_len"]
-    for ca, cb in zip(a, b):
+    for c in range(DOCQA["clients"]):
+        ca = _first(traffic.closed_loop(DOCQA, 1, 32768, c), 8 * per_doc)
+        cb = _first(traffic.closed_loop(DOCQA, BIG_SEED, 32768, c),
+                    8 * per_doc)
         assert [len(r["prompt"]) for r in ca] == [len(r["prompt"]) for r in cb]
         assert [r["asked"] for r in ca] == [r["asked"] for r in cb]
-        assert len(ca) == DOCQA["docs_per_client"] * per_doc
+        assert [(r["doc"], r["question"]) for r in ca] == [
+            (k, q) for k in range(8) for q in range(per_doc)]
         first, second = ca[0], ca[1]
         n = len(first["prompt"]) - qlen
         assert n in DOCQA["doc_lengths"]
@@ -94,7 +104,107 @@ def test_closed_loop_gives_each_client_its_documents_in_turn():
         assert ca[per_doc]["prompt"][:64] != first["prompt"][:64]
         longest = max(len(r["prompt"]) + r["asked"] for r in ca)
         assert longest < DOCQA["engine"]["max_seq_len"]
-    assert a[0][0]["prompt"] != b[0][0]["prompt"]
+        # what the prefix cache can serve: the document, but for the
+        # first question of one that set-up's fill has not prefilled
+        assert [r["shared"] for r in ca[:2 * per_doc]] == \
+            [n] * per_doc + [0] + [len(ca[per_doc]["prompt"]) - qlen] \
+            * (per_doc - 1)
+        assert ca[0]["prompt"] != cb[0]["prompt"]
+    ids = [r["id"] for c in range(DOCQA["clients"])
+           for r in _first(traffic.closed_loop(DOCQA, 1, 32768, c), 200)]
+    assert len(set(ids)) == len(ids) and min(ids) >= 0
+
+
+def _the_replay_before_pr_35(params, docs_per_client):
+    """``closed_loop`` and the stagger of ``_closed_loop_feeders`` as
+    they stood while a client walked a finite list (PR 34), lengths
+    only: per client [(prompt length, tokens asked, document, question,
+    cacheable tokens)]. The oracle for what a client sees, in order."""
+    docs, answers = params["doc_lengths"], params["answer_lengths"]
+    per_doc, qlen = params["questions_per_doc"], params["question_len"]
+    clients = []
+    for c in range(params["clients"]):
+        reqs = []
+        for k in range(docs_per_client):
+            n = docs[(c * params["doc_stride"] + k) % len(docs)]
+            for q in range(per_doc):
+                a = answers[(c + k * per_doc + q) % len(answers)]
+                cached = q > 0 or k == 0
+                reqs.append((n + qlen, a, k, q, n if cached else 0))
+        clients.append(reqs[c % per_doc:])
+    return clients
+
+
+@CLOSED
+def test_every_client_sees_the_lengths_answers_and_stagger_it_always_did(mix):
+    """The first 32 requests of every client, and the cache fill, are
+    the finite list's: the file's pattern, only continued."""
+    old = _the_replay_before_pr_35(mix, docs_per_client=9)
+    fills, replays = traffic.closed_loop_start(mix, BIG_SEED, 32768)
+    assert len(fills) == len(replays) == len(old) == mix["clients"]
+    for c, (fill, replay, was) in enumerate(zip(fills, replays, old)):
+        got = _first(replay, 32)
+        assert [(len(r["prompt"]), r["asked"], r["doc"], r["question"],
+                 r["shared"]) for r in got] == was[:32]
+        # the stagger: client c starts at question c mod questions_per_doc
+        assert (got[0]["doc"], got[0]["question"]) == \
+            (0, c % mix["questions_per_doc"])
+        # the fill is the first document alone, asked for one token
+        n = len(got[0]["prompt"]) - mix["question_len"]
+        assert fill == {"id": -1 - c, "prompt": got[0]["prompt"][:n],
+                        "asked": 1}
+
+
+@CLOSED
+@pytest.mark.parametrize("client", [0, 3, 7])
+def test_a_clients_kth_document_is_its_own_whatever_the_others_did(
+        mix, client):
+    """Tokens come from [seed, client, document]: not from how many
+    clients there are, nor from how far the others (or this one, in
+    another run) have read."""
+    alone = _first(traffic.closed_loop(mix, BIG_SEED, 32768, client), 12)
+    fewer = dict(mix, clients=client + 1)
+    assert _first(traffic.closed_loop(fewer, BIG_SEED, 32768, client),
+                  12) == alone
+    _, replays = traffic.closed_loop_start(mix, BIG_SEED, 32768)
+    for c, other in enumerate(replays):         # the others read ahead
+        if c != client:
+            _first(other, 5 + 3 * c)
+    skip = client % mix["questions_per_doc"]
+    assert _first(replays[client], 12 - skip) == alone[skip:]
+    # document 2 without documents 0 and 1 having been asked in full
+    again = traffic.closed_loop(mix, BIG_SEED, 32768, client)
+    per_doc = mix["questions_per_doc"]
+    third = [r for r in _first(again, 3 * per_doc) if r["doc"] == 2]
+    assert third == alone[2 * per_doc:3 * per_doc]
+    other_seed = _first(traffic.closed_loop(mix, 5, 32768, client), 1)
+    assert other_seed[0]["prompt"] != alone[0]["prompt"]
+
+
+@CLOSED
+def test_a_client_asked_four_times_todays_rate_is_still_served(mix):
+    """A traced docqa run listened 88 s at 5.3 requests a second over
+    sixteen clients (PERF.md, PR 34): 30 requests a client, with 29-32
+    in its list. At four times that a client asks ~120; the replay has
+    no end, every request fits the engine's window, and set-up builds
+    nothing it does not ask."""
+    _, replays = traffic.closed_loop_start(mix, 7, 32768)
+    got = _first(replays[-1], 120)
+    assert len(got) == 120 and len({r["id"] for r in got}) == 120
+    assert max(len(r["prompt"]) + r["asked"] for r in got) \
+        < mix["engine"]["max_seq_len"]
+    assert got[-1]["doc"] >= 29
+    assert "docs_per_client" not in mix
+    assert "docs_per_client" not in mix["rehearse"]
+
+
+def test_a_replay_cut_short_ends_after_its_documents_less_the_stagger():
+    cut = dict(DOCQA, docs_per_client=2)
+    per_doc = DOCQA["questions_per_doc"]
+    _, replays = traffic.closed_loop_start(cut, 7, 32768)
+    for c, replay in enumerate(replays):
+        n = len(list(replay))
+        assert n == 2 * per_doc - c % per_doc
 
 
 def test_train_batches_are_the_same_work_every_step():
