@@ -1,5 +1,4 @@
-"""Pallas paged-attention decode kernel — the length-aware serving
-fast path.
+"""Pallas paged-attention kernel — the length-aware serving fast path.
 
 The serving hot loop attends a handful of new-token queries per
 sequence against a paged KV cache (``[n_layers, num_blocks, kv_heads,
@@ -10,24 +9,51 @@ would copy that layer in and out of every step. The pure-XLA reference
 (:func:`ray_tpu.ops.attention.paged_attention`) gathers the WHOLE
 table window every step — work is O(B · T · block_size) regardless of
 how many tokens a sequence actually holds. This kernel makes decode
-work proportional to **live tokens**:
+work proportional to **live tokens**, a **group of P pages a grid
+step**:
 
-- grid ``(batch, kv_head_groups, q_row_blocks, table_slots)`` with the
-  table-slot axis innermost so the online-softmax accumulators
+- grid ``(batch, kv_head_groups, q_row_blocks, ceil(T / P))`` with the
+  page-group axis innermost so the online-softmax accumulators
   (m, l, acc in f32 VMEM scratch) persist across a sequence's pages;
-- the block table, per-sequence ``lens`` and the layer index ride
-  **scalar prefetch** (:class:`pltpu.PrefetchScalarGridSpec`): the k/v
-  BlockSpec index maps read them to DMA exactly the physical page
-  ``(layer, block)`` a grid step needs, out of the pool's own buffer;
-- table slots past ``ceil(lens[b] / block_size)`` are **skipped** —
-  their index map clamps to the last live page (an unchanged block
-  index issues no new copy) and ``pl.when`` skips the matmuls, so a
-  16-token sequence in a 1024-token window does 1/64th of the window's
-  work instead of all of it;
+- the pools stay in HBM (``memory_space=pl.ANY``) and the kernel
+  fetches pages itself. The block table, per-sequence ``lens`` and the
+  layer index ride **scalar prefetch**
+  (:class:`pltpu.PrefetchScalarGridSpec`); a step reads them to start
+  one async copy a live page, HBM page ``(layer, bt[b, t·P + j], this
+  step's kv heads)`` to slot ``j`` of a ``[2, P, heads, block_size,
+  head_dim]`` VMEM buffer, K and V each, all 2·P at once. The two
+  halves alternate: step ``t`` starts group ``t + 1``'s copies, waits
+  for its own (started by step ``t − 1``; group 0's by step 0 itself,
+  the one exposed fetch a sequence) and folds them. Only q, the row
+  positions and the output are BlockSpec operands: the BlockSpec
+  pipeline spends ~0.08 µs an operand a step on its index map and its
+  changed-or-not test whether or not a copy follows, so with a
+  BlockSpec a page a dead step costs as much as the table slots it
+  covers, grouped or not (measured on a v5e; PERF.md, PR 36);
+- the body reads the P pages of one kv head as ONE ``(P·block_size,
+  head_dim)`` K tile and one V tile (a page is a whole number of
+  sublane tiles, so the join moves nothing) and runs one ``q·Kᵀ``, one
+  online-softmax update and one ``p·V`` a head a step: the step's
+  fixed cost is paid once for P pages, and the products are P·block_size
+  key columns wide instead of one page's;
+- groups past ``last = (pages − 1) // P`` (``pages =
+  ceil(lens[b] / block_size)``, at least the one page an idle slot
+  runs) are **skipped**: no copy is started for them and ``pl.when``
+  skips the body, so a dead step costs what the three BlockSpec
+  operands' bookkeeping costs (~0.05 µs). In a partly live last group
+  the pages past the last live one are not fetched either: their key
+  columns are masked out (``key position < pages · block_size``)
+  beside the causal mask, and their V rows are zeroed (a zero weight
+  times whatever the buffer held would otherwise be free to be NaN);
+- P is the largest of ``_PAGE_GROUPS`` whose buffers fit
+  ``_VMEM_BUDGET`` and that the table has a use for
+  (:func:`paged_pages_per_step`): it follows the page's bytes (kv heads
+  a step × block_size × head_dim × itemsize), the row block and the
+  table's length, nothing else. T need not divide by P;
 - GQA is handled by **indexing kv heads in-kernel**: queries are
   regrouped host-side to ``[B, kv_heads, C·group, D]`` rows (a
   transpose of the tiny q tensor, not of the cache); a grid step loads
-  one page of ``heads_per_step`` kv heads and walks them with a static
+  the pages of ``heads_per_step`` kv heads and walks them with a static
   loop — the cache is never repeated or copied.
 
 Every operand tiles the way Mosaic requires. A page is a
@@ -43,9 +69,11 @@ candidate grid once and persists the winner through the SAME on-disk
 table as ``autotune_flash_blocks``). Padded rows carry position −1 —
 fully masked, dropped on unpack.
 
-``interpret=True`` runs the kernel on CPU (tier-1 parity tests); on
-TPU it compiles with parallel/arbitrary dimension semantics like the
-flash kernels.
+``interpret=True`` runs the same kernel, copies and semaphores
+included, on CPU (tier-1 parity tests); on TPU it compiles with
+parallel/arbitrary dimension semantics like the flash kernels (what a
+step carries to the next — accumulators, the buffer's other half —
+stays inside one sweep of the innermost axis).
 """
 
 from __future__ import annotations
@@ -69,6 +97,15 @@ _NEG_INF = -1e30
 #: the f32 (m, l, acc) scratch to ~1 MiB at head_dim 256
 _MAX_ROWS_PER_STEP = 512
 
+#: pages a grid step may fold, largest first (paged_pages_per_step)
+_PAGE_GROUPS = (32, 16, 8, 4, 2, 1)
+
+#: VMEM a grid step may plan for (_step_vmem_bytes): three quarters of
+#: the 16 MiB of scoped VMEM a Mosaic kernel has by default on a v5e,
+#: the rest left to the compiler's own temporaries. The kernel asks for
+#: no ``vmem_limit_bytes``.
+_VMEM_BUDGET = 12 << 20
+
 
 def sublane_tile(dtype) -> int:
     """Rows of one (sublane, 128-lane) tile for ``dtype``: 8 for 32-bit,
@@ -87,41 +124,97 @@ def paged_work_pages(lens, block_size: int):
         if hasattr(lens, "clip") else max(-(-lens // block_size), 1)
 
 
-def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_ref,
-                  v_ref, o_ref, m_s, l_s, acc_s, *, bs: int, hb: int,
+def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm,
+                  v_hbm, o_ref, m_s, l_s, acc_s, k_buf, v_buf, sem, *,
+                  bs: int, hb: int, pp: int, slots: int,
                   sm_scale: float):
-    """One (batch b, kv head group, row block r, table slot t) step:
-    fold page t of sequence b into the row block's online softmax, one
-    kv head of the group at a time. Scalar refs (bt, lens, layer) land
-    in SMEM ahead of the body — the same values the index maps used to
-    pick this step's page (the body itself never needs the layer)."""
-    b = pl.program_id(0)
-    t = pl.program_id(3)
+    """One (batch b, kv head group g, row block r, page group t) step:
+    fold pages ``t·pp .. t·pp + pp − 1`` of sequence b into the row
+    block's online softmax, one kv head of the group at a time.
+    ``k_hbm`` / ``v_hbm`` are the whole pools, left where they are;
+    ``k_buf`` / ``v_buf`` ``[2, pp, hb, bs, d]`` hold two groups, the
+    one this step folds and the one the next step will; ``sem[slot,
+    0|1]`` counts a buffer's K and V copies. Scalar refs (bt, lens,
+    layer) are in SMEM ahead of the body."""
+    b, g, t = pl.program_id(0), pl.program_id(1), pl.program_id(3)
     nt = pl.num_programs(3)
+    # Length-aware skipping: groups past the last live one fetch
+    # nothing and fold nothing. A sequence holds at least the one page
+    # an idle slot runs and at most the table.
+    pages = jnp.clip(pl.cdiv(lens_ref[b], bs), 1, slots)
+    last = (pages - 1) // pp
+
+    def copies(group, slot, j):
+        """The K and the V copy of page ``j`` of ``group``, HBM page
+        ``(layer, block, this step's kv heads)`` to ``buf[slot, j]``."""
+        src = (layer_ref[0], bt_ref[b, group * pp + j], pl.ds(g * hb, hb))
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, j],
+                                      sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, j],
+                                      sem.at[slot, 1]))
+
+    def live_in(group):
+        """Pages of ``group`` the sequence holds."""
+        return jnp.clip(pages - group * pp, 0, pp)
+
+    def fetch(group, slot):
+        """Start the copies of ``group``'s live pages, all at once. A
+        page past the last live one is not fetched; its key columns are
+        masked out below, and its V rows are zeroed so that what the
+        buffer held before (anything, at a call's start) cannot turn a
+        zero weight into a NaN. Loops, not ``pp`` unrolled branches: the
+        kernel is traced and lowered in every process that loads a step
+        program, and that time is set-up."""
+        def start(j, carry):
+            for c in copies(group, slot, j):
+                c.start()
+            return carry
+
+        def zero(j, carry):
+            v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+            return carry
+
+        n = live_in(group)
+        jax.lax.fori_loop(0, n, start, 0)
+        jax.lax.fori_loop(n, pp, zero, 0)
 
     @pl.when(t == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
+        fetch(0, 0)
 
-    # Length-aware skipping: slots past the live pages do nothing (and
-    # their k/v index maps re-point at the last live page, so no DMA).
-    pages = jnp.maximum(pl.cdiv(lens_ref[b], bs), 1)
-
-    @pl.when(t < pages)
+    @pl.when(t <= last)
     def _compute():
-        rows_pos = pos_ref[0]                  # (block_r, 1) int32
+        slot = t % 2
+
+        @pl.when(t < last)
+        def _prefetch():                       # under this step's matmuls
+            fetch(t + 1, 1 - slot)
+
+        def wait(j, carry):
+            for c in copies(t, slot, j):
+                c.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(t), wait, 0)
+
+        # a row sees keys up to its own position (causal) and none of
+        # the pages past the last live one
+        key_max = jnp.minimum(pos_ref[0], pages * bs - 1)  # (block_r, 1)
         for i in range(hb):                    # static: kv heads here
             q = q_ref[0, i]                    # (block_r, d)
-            k = k_ref[0, i]                    # (bs, d) — one page of
-            v = v_ref[0, i]                    # one kv head
+            # the group's pages of one kv head, one under the other: a
+            # page is whole sublane tiles, the join moves nothing
+            k = k_buf[slot, :, i].reshape(pp * bs, -1)
+            v = v_buf[slot, :, i].reshape(pp * bs, -1)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
-            key_pos = t * bs + jax.lax.broadcasted_iota(
+            key_pos = t * (pp * bs) + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(key_pos <= rows_pos, s, _NEG_INF)
+            s = jnp.where(key_pos <= key_max, s, _NEG_INF)
 
             m_prev = m_s[i]                    # (block_r, 128) lanes equal
             l_prev = l_s[i]
@@ -153,6 +246,70 @@ def _heads_per_step(kv_heads: int, block_r: int) -> int:
     while kv_heads % hb:
         hb -= 1
     return hb
+
+
+def _row_block(rows: int, head_dim: int, dtype, block_r: Optional[int],
+               chip: Optional[str]) -> int:
+    """The row block a call runs with: the caller's (or the chip-aware
+    default), no more than the rows there are, whole sublane tiles."""
+    tile = sublane_tile(dtype)
+    if not block_r:
+        block_r = default_paged_block_r(rows, head_dim, chip=chip)
+    return _round_up(min(block_r, _round_up(rows, tile)), tile)
+
+
+def _step_vmem_bytes(pp: int, hb: int, bs: int, d: int, itemsize: int,
+                     block_r: int) -> int:
+    """VMEM one grid step holds with ``pp`` pages a group: the K and V
+    pages (two groups each: this step's and the next's), one head's
+    joined K and V tile, the f32 score tile and its exponentials, and
+    what does not grow with ``pp``: q and out blocks (double-buffered)
+    and the (m, l, acc) scratch."""
+    pages = 2 * 2 * pp * hb * bs * d * itemsize
+    joined = 2 * pp * bs * d * itemsize
+    scores = 2 * block_r * _round_up(pp * bs, 128) * 4
+    fixed = 2 * 2 * hb * block_r * d * itemsize \
+        + hb * block_r * (2 * 128 + d) * 4
+    return pages + joined + scores + fixed
+
+
+def paged_pages_per_step(rows: int, kv_heads: int, block_size: int,
+                         head_dim: int, dtype, table_len: int, *,
+                         block_r: Optional[int] = None,
+                         chip: Optional[str] = None) -> int:
+    """P, the pages of a sequence one grid step of
+    :func:`paged_flash_attention` folds for a call of ``rows`` query
+    rows a kv head (C · heads per kv head) over tables of ``table_len``
+    slots: the largest of ``_PAGE_GROUPS`` whose step fits
+    ``_VMEM_BUDGET``, and no larger than the table has a use for. It
+    follows what the call can observe — the page's bytes, the row
+    block, the table — so MHA at head_dim 256 (a 128 KB page of sixteen
+    heads) gets a smaller group than GQA at 128 (32 KB). The engine's
+    grid-step counters ask the same function the kernel does."""
+    block_r = _row_block(rows, head_dim, dtype, block_r, chip)
+    return _pages_per_step(_heads_per_step(kv_heads, block_r), block_size,
+                           head_dim, dtype, block_r, table_len)
+
+
+def _pages_per_step(hb: int, bs: int, d: int, dtype, block_r: int,
+                    table_len: int) -> int:
+    itemsize = jnp.dtype(dtype).itemsize
+    for pp in _PAGE_GROUPS:
+        if pp // 2 < table_len and _step_vmem_bytes(
+                pp, hb, bs, d, itemsize, block_r) <= _VMEM_BUDGET:
+            return pp
+    return 1
+
+
+def paged_grid_steps(pages, table_len: int, pages_per_step: int):
+    """``(steps, live)`` of one call's innermost grid axis over a batch
+    whose sequences hold ``pages`` pages (:func:`paged_work_pages`, a
+    numpy array): ``len(pages) · ceil(T / P)`` steps taken, ``Σ
+    ceil(pages / P)`` of them with a live page to fold (the rest are
+    skipped bodies that still cost a step)."""
+    pages = pages.clip(max=table_len)
+    groups = -(-table_len // pages_per_step)
+    return len(pages) * groups, int((-(-pages // pages_per_step)).sum())
 
 
 def layered_pool(k_cache: jnp.ndarray, v_cache: jnp.ndarray, layer):
@@ -206,14 +363,12 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     rows = c * rep
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    tile = sublane_tile(q.dtype)
-    if not block_r:
-        block_r = default_paged_block_r(
-            rows, d, chip="cpu" if interpret else None)
-    block_r = _round_up(min(block_r, _round_up(rows, tile)), tile)
+    block_r = _row_block(rows, d, q.dtype, block_r,
+                         "cpu" if interpret else None)
     rows_pad = _round_up(rows, block_r)
     nr = rows_pad // block_r
     hb = _heads_per_step(g, block_r)
+    pp = _pages_per_step(hb, bs, d, k_cache.dtype, block_r, t)
 
     # Group-major query rows: row r of kv head g is (c = r // rep,
     # head = g*rep + r % rep). Only q (tiny) is reshaped — never the
@@ -227,42 +382,33 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            constant_values=-1)
     pos_rows = pos_rows[:, :, None]            # [B, rows_pad, 1] column
 
-    def _pages(ln):
-        return jnp.maximum(pl.cdiv(ln, bs), 1)
-
     def q_map(b_, g_, r_, t_, bt, ln, ly):
         return (b_, g_, r_, 0)
 
     def pos_map(b_, g_, r_, t_, bt, ln, ly):
         return (b_, r_, 0)
 
-    def kv_map(b_, g_, r_, t_, bt, ln, ly):
-        # slots past the live pages revisit the last live page: the
-        # unchanged block index issues no fresh DMA
-        tt = jnp.minimum(t_, _pages(ln[b_]) - 1)
-        return (ly[0], bt[b_, tt], g_, 0, 0)
-
-    # the layer dim is squeezed: the body sees the same (1, hb, bs, d)
-    # page of one layer it always did
-    page = pl.BlockSpec((None, 1, hb, bs, d), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, g // hb, nr, t),
+        grid=(b, g // hb, nr, pl.cdiv(t, pp)),
         in_specs=[
             pl.BlockSpec((1, hb, block_r, d), q_map),
             pl.BlockSpec((1, block_r, 1), pos_map),
-            page,
-            page,
+            pl.BlockSpec(memory_space=pl.ANY),    # the K pool, in HBM
+            pl.BlockSpec(memory_space=pl.ANY),    # the V pool
         ],
         out_specs=pl.BlockSpec((1, hb, block_r, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running max m
             pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running denom l
             pltpu.VMEM((hb, block_r, d), jnp.float32),    # out accumulator
+            pltpu.VMEM((2, pp, hb, bs, d), k_cache.dtype),  # two groups'
+            pltpu.VMEM((2, pp, hb, bs, d), v_cache.dtype),  # K and V pages
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, hb=hb,
+        functools.partial(_paged_kernel, bs=bs, hb=hb, pp=pp, slots=t,
                           sm_scale=float(sm_scale)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, d), q.dtype),

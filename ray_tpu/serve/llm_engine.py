@@ -474,6 +474,21 @@ class LLMEngine:
         # work fraction of the paged fast path (any backend)
         self._decode_pages_live = 0
         self._decode_pages_window = 0
+        # the same for the paged kernel's innermost grid axis: it folds
+        # P pages of a sequence a grid step, so a decode call takes
+        # slots x ceil(T/P) steps of which sum(ceil(pages/P)) have a
+        # live page; P is the kernel's own choice for the decode (or
+        # verify) call's shape
+        from ray_tpu.ops.paged_flash import paged_pages_per_step
+        self._decode_pages_per_step = paged_pages_per_step(
+            (ec.spec_tokens + 1)
+            * (model_config.n_heads // model_config.kv_heads),
+            model_config.kv_heads, ec.kv_block_size, model_config.head_dim,
+            model_config.dtype, ec.blocks_per_seq,
+            block_r=model_config.paged_block_r,
+            chip="cpu" if model_config.paged_impl == "interpret" else None)
+        self._decode_grid_steps = 0
+        self._decode_grid_steps_live = 0
         # what a selecting, routing model did, from positions alone (no
         # device work): keys a query could see and keys it attended
         # (min(visible, index_topk)), summed over queries; keys the
@@ -1008,6 +1023,7 @@ class LLMEngine:
             self._h2d_transfers = 0
             self._decode_wall_s = self._prefill_wall_s = 0.0
             self._decode_pages_live = self._decode_pages_window = 0
+            self._decode_grid_steps = self._decode_grid_steps_live = 0
             self._sparse.clear()
             self._prompt_blocks_total = 0
             self._occupancy.clear()
@@ -1057,6 +1073,16 @@ class LLMEngine:
                     round(self._decode_pages_live
                           / self._decode_pages_window, 4)
                     if self._decode_pages_window else None),
+                # how often the kernel's page groups engage: grid steps
+                # a decode call took on its innermost axis, those with
+                # a live page to fold, and their ratio
+                "decode_pages_per_step": self._decode_pages_per_step,
+                "decode_grid_steps": self._decode_grid_steps,
+                "decode_grid_steps_live": self._decode_grid_steps_live,
+                "decode_grid_live_frac": (
+                    round(self._decode_grid_steps_live
+                          / self._decode_grid_steps, 4)
+                    if self._decode_grid_steps else None),
                 "keys_visible_total": self._sparse["visible"],
                 "keys_attended_total": self._sparse["attended"],
                 "indexer_keys_scored_total": self._sparse["scored"],
@@ -1699,15 +1725,21 @@ class LLMEngine:
         """Book one decode step's length-aware work: pages the paged
         kernel touches (``max(ceil(live/bs), 1)`` per slot — idle slots
         run their one trash page) vs the full table window the XLA
-        reference gathers. Host-side numpy over the slot arrays the
-        step already copied — no device work."""
-        from ray_tpu.ops.paged_flash import paged_work_pages
+        reference gathers, and the kernel's grid steps with a live page
+        vs all it takes. Host-side numpy over the slot arrays the step
+        already copied — no device work."""
+        from ray_tpu.ops.paged_flash import (paged_grid_steps,
+                                             paged_work_pages)
         ec = self.config
         pages = paged_work_pages(
             self._np.asarray(live_lens, self._np.int64),
             ec.kv_block_size)
         self._decode_pages_live += int(pages.sum())
         self._decode_pages_window += ec.decode_slots * ec.blocks_per_seq
+        steps, live = paged_grid_steps(pages, ec.blocks_per_seq,
+                                       self._decode_pages_per_step)
+        self._decode_grid_steps += steps
+        self._decode_grid_steps_live += live
 
     def _account_queries(self, first, n) -> None:
         """Book the queries at positions ``first[i] .. first[i] + n[i] -

@@ -17,11 +17,27 @@ import jax.numpy as jnp
 from ray_tpu.ops import (attention_reference, autotune_paged_block_r,
                          default_paged_block_r, paged_attention,
                          paged_work_pages)
-from ray_tpu.ops.paged_flash import paged_flash_attention
+import ray_tpu.ops.paged_flash as pf
+from ray_tpu.ops.paged_flash import (paged_flash_attention,
+                                     paged_grid_steps,
+                                     paged_pages_per_step)
 
 pytestmark = pytest.mark.serve_llm
 
 TOL = dict(rtol=2e-5, atol=2e-5)
+
+
+@pytest.fixture
+def group_of(monkeypatch):
+    """``group_of(P)`` makes the kernel fold P pages a grid step at
+    whatever shape it is given (its own choice follows a VMEM budget
+    that these tiny shapes never reach, and the table: the smallest
+    group that holds all of it); ``group_of(None)`` leaves the choice
+    alone."""
+    def set_(pp):
+        if pp:
+            monkeypatch.setattr(pf, "_PAGE_GROUPS", (pp,))
+    return set_
 
 
 def _paged_case(seed, B, S, H, KVH, D, bs, T, shuffle=True):
@@ -53,11 +69,15 @@ def _both(q, kc, vc, bt, pos, lens):
     return np.asarray(ref), np.asarray(ker)
 
 
+@pytest.mark.parametrize("pp", [None, 1, 2, 4])
 @pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
-def test_decode_parity_mixed_uneven_lens(H, KVH):
+def test_decode_parity_mixed_uneven_lens(H, KVH, pp, group_of):
     """Batched single-token decode over mixed lengths, none of them
     block-aligned — the kernel must match the reference on every live
-    row while touching only live pages."""
+    row while touching only live pages. 2, 6 and 3 live pages of a
+    6-slot table: whole groups and a group and a page at P = 2, a
+    partly live first group and a table that P does not divide at 4."""
+    group_of(pp)
     B, D, bs, T = 3, 16, 4, 6
     _, _, kc, vc, bt = _paged_case(0, B, 24, H, KVH, D, bs, T)
     rng = np.random.default_rng(1)
@@ -88,10 +108,14 @@ def test_chunked_prefill_parity_and_shape_duality():
         np.testing.assert_allclose(one[:, 0], ker[:, c], **TOL)
 
 
-def test_length_skipping_ignores_dead_blocks():
+@pytest.mark.parametrize("pp", [None, 2, 4])
+def test_length_skipping_ignores_dead_blocks(pp, group_of):
     """The headline semantics: junk written into table slots past
     ``ceil(lens/bs)`` must be bit-invisible — work is proportional to
-    live tokens, not the serving window."""
+    live tokens, not the serving window. 3 and 4 live pages of 8: at
+    P = 2 and 4 the dead slots are the tail of a partly live group
+    (masked page by page) and whole dead groups (skipped)."""
+    group_of(pp)
     B, H, KVH, D, bs, T = 2, 4, 4, 8, 4, 8
     _, _, kc, vc, bt = _paged_case(4, B, 32, H, KVH, D, bs, T)
     rng = np.random.default_rng(5)
@@ -269,6 +293,116 @@ def test_wide_row_blocks_parity_chunked_prefill(block_r):
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), **TOL)
 
 
+# ------------------------------------------------- a group of P pages
+@pytest.mark.parametrize("pp", [2, 4, 8])
+@pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
+def test_group_edges_with_idle_slots_between_live_ones(H, KVH, pp,
+                                                       group_of):
+    """Live pages equal to k·P, k·P + 1 and fewer than P, in a table
+    that P does not divide (2·P + 3 slots), with ``lens = 0`` idle slots
+    (every table row the trash block) between the live ones, as the
+    engine's decode batch has them: each against the reference, and
+    junk in every dead slot unreachable."""
+    group_of(pp)
+    bs, D = 4, 8
+    T = 2 * pp + 3
+    lens = np.array([2 * pp * bs, 0, 2 * pp * bs + 1, 0,
+                     max(1, (pp - 1) * bs - 1), bs], np.int32)
+    B = len(lens)
+    _, _, kc, vc, bt = _paged_case(20, B, T * bs, H, KVH, D, bs, T)
+    bt[lens == 0] = 0
+    rng = np.random.default_rng(21)
+    q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
+    pos = (lens - 1).clip(0)[:, None]
+    ref, ker = _both(q, kc, vc, bt, jnp.asarray(pos), lens)
+    assert np.all(np.isfinite(ker))
+    np.testing.assert_allclose(ker, ref, **TOL)
+    for b in range(B):
+        dead = max(1, -(-int(lens[b]) // bs))
+        if lens[b]:
+            kc[bt[b, dead:]] = 1e3
+            vc[bt[b, dead:]] = -1e3
+    _, ker2 = _both(q, kc, vc, bt, jnp.asarray(pos), lens)
+    np.testing.assert_array_equal(ker, ker2)
+
+
+@pytest.mark.parametrize("pp", [2, 4])
+@pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
+def test_padded_chunk_tail_in_a_partly_live_group(H, KVH, pp, group_of):
+    """A prefill chunk whose last rows are padding: the engine gives
+    them the positions after the prompt's end, ≥ ``lens``, inside the
+    table. The last group's pages past the last live one are not
+    fetched, so without the per-page mask those rows would score
+    whatever the buffer held there. Valid rows match the reference;
+    EVERY row, padded ones too, matches the one-page-a-step kernel,
+    where a live group has no such page."""
+    bs, D, T, C = 4, 8, 7, 8
+    start, n = 6, 5                     # lens 11: 3 live pages of 7
+    _, _, kc, vc, bt = _paged_case(22, 1, T * bs, H, KVH, D, bs, T)
+    rng = np.random.default_rng(23)
+    q = rng.normal(size=(1, C, H, D)).astype(np.float32)
+    pos = jnp.asarray(start + np.arange(C, dtype=np.int32)[None, :])
+    lens = np.array([start + n], np.int32)
+    group_of(pp)
+    ref, ker = _both(q, kc, vc, bt, pos, lens)
+    np.testing.assert_allclose(ker[:, :n], ref[:, :n], **TOL)
+    group_of(1)
+    _, one = _both(q, kc, vc, bt, pos, lens)
+    np.testing.assert_allclose(ker, one, **TOL)
+
+
+#: the cells' four calls (benchmarks/configs/*.json, traffic/*.json):
+#: rows a kv head, kv heads, head_dim, block_r, table slots, the P
+#: each gets
+CELL_SHAPES = {
+    "chat_decode": (1, 16, 256, 8, 128, 16),
+    "chat_prefill": (256, 16, 256, 128, 128, 32),
+    "docqa_decode": (4, 8, 128, 16, 256, 32),
+    "docqa_prefill": (1024, 8, 128, 512, 256, 32),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_pages_per_step_follows_the_vmem_budget(cell):
+    """P comes from the call's shapes and the budget alone: at the
+    cells' shapes (bf16 pages of 16 tokens) the chosen group's buffers
+    fit ``_VMEM_BUDGET`` and the next larger group, where there is one,
+    does not; an MHA page four times as large gets a smaller group,
+    and a table shorter than the group a smaller one too."""
+    rows, kvh, d, block_r, table, want = CELL_SHAPES[cell]
+    pp = paged_pages_per_step(rows, kvh, 16, d, jnp.bfloat16, table,
+                              block_r=block_r, chip="v5e")
+    assert pp == want
+    br = pf._row_block(rows, d, jnp.bfloat16, block_r, "v5e")
+    hb = pf._heads_per_step(kvh, br)
+    assert pf._step_vmem_bytes(pp, hb, 16, d, 2, br) <= pf._VMEM_BUDGET
+    assert pf._VMEM_BUDGET <= 16 << 20      # the default scoped limit
+    for bigger in [g for g in pf._PAGE_GROUPS if g > pp]:
+        assert pf._step_vmem_bytes(bigger, hb, 16, d, 2, br) \
+            > pf._VMEM_BUDGET
+    # a page of 64 MHA heads x 256: 512 KB, four of them at a time
+    assert paged_pages_per_step(1, 64, 16, 256, jnp.bfloat16, table,
+                                block_r=8, chip="v5e") < pp
+    # a short table gets the smallest group that holds all of it
+    assert [paged_pages_per_step(rows, kvh, 16, d, jnp.bfloat16, t,
+                                 block_r=block_r, chip="v5e")
+            for t in (1, 3, 4, 5, 16, 17)] == [1, 4, 4, 8, 16, min(32, pp)]
+
+
+def test_paged_grid_steps_accounting():
+    """What the engine books a decode step: ``batch · ceil(T / P)``
+    steps, ``Σ ceil(pages / P)`` of them live (an idle slot's one)."""
+    pages = paged_work_pages(
+        np.array([0, 1, 64, 65, 128, 2048, 5000], np.int64), 16)
+    np.testing.assert_array_equal(pages, [1, 1, 4, 5, 8, 128, 313])
+    for pp in (1, 4, 8, 16):
+        steps, live = paged_grid_steps(pages, 128, pp)
+        assert steps == len(pages) * -(-128 // pp)
+        assert live == sum(-(-min(int(p), 128) // pp) for p in pages)
+    assert paged_grid_steps(pages, 100, 8) == (7 * 13, 1 + 1 + 1 + 1 + 1
+                                               + 13 + 13)
+
+
 # ------------------------------------------------ autotune / disk cache
 def test_default_paged_block_r_shapes():
     assert default_paged_block_r(2, 32, chip="cpu") == 8
@@ -368,14 +502,18 @@ def test_flash_disk_cache_ignores_foreign_paged_keys(tmp_path,
     assert fa._AUTOTUNE_CACHE == {("cpu", 128, 64, True): (256, 512)}
 
 
+@pytest.mark.parametrize("pp", [None, 2])
 @pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
 @pytest.mark.parametrize("layer", [0, 2])
-def test_whole_pool_with_layer_index_equals_that_layers_slice(layer, H,
-                                                              KVH):
+def test_whole_pool_with_layer_index_equals_that_layers_slice(
+        layer, H, KVH, pp, group_of):
     """The wrapper takes the whole ``[L, N, KVH, bs, D]`` pool and a
     layer index (traced, as a layer scan hands it over) and reads the
     pages ``(layer, block)``: bit for bit the call on that layer's own
-    4-D slice, for the first and the last layer, kernel and reference."""
+    4-D slice, for the first and the last layer, kernel and reference —
+    with every page of the 3-slot table in one group, and with two
+    groups of two pages."""
+    group_of(pp)
     L, B, D, bs, T, C = 3, 2, 8, 4, 3, 2
     rng = np.random.default_rng(11)
     kp = jnp.asarray(rng.normal(size=(L, 1 + B * T, KVH, bs, D))

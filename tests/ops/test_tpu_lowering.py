@@ -33,18 +33,26 @@ def _s(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-@pytest.mark.parametrize("batch,chunk,kv_heads", [
-    (SLOTS, 1, HEADS),      # decode: one row per kv head (MHA)
-    (SLOTS, 1, 1),          # decode, every head on one kv head
-    (1, CHUNK, HEADS),      # one prefill chunk
-    (SLOTS, 5, HEADS),      # speculative verify: k+1 tokens per slot
-])
-def test_paged_kernel_lowers_for_tpu(batch, chunk, kv_heads):
-    t = WINDOW // BLOCK
-    pool = _s((1 + SLOTS * t, kv_heads, BLOCK, HEAD_DIM))
+@pytest.mark.parametrize(
+    "batch,chunk,heads,kv_heads,head_dim,window,block_r", [
+        (SLOTS, 1, HEADS, HEADS, HEAD_DIM, WINDOW, None),  # decode (MHA)
+        (SLOTS, 1, HEADS, 1, HEAD_DIM, WINDOW, None),  # all on 1 kv head
+        (1, CHUNK, HEADS, HEADS, HEAD_DIM, WINDOW, None),  # prefill chunk
+        (SLOTS, 5, HEADS, HEADS, HEAD_DIM, WINDOW, None),  # verify: k+1
+        # the cells' own calls (benchmarks/configs, traffic): chat's
+        # chunk at block_r 128 over a 2048 window; docqa's decode and
+        # 256-token chunk, 32 heads on 8 kv heads x 128, window 4096
+        (1, CHUNK, HEADS, HEADS, HEAD_DIM, 2048, 128),
+        (16, 1, 32, 8, 128, 4096, 16),
+        (1, CHUNK, 32, 8, 128, 4096, 512),
+    ])
+def test_paged_kernel_lowers_for_tpu(batch, chunk, heads, kv_heads,
+                                     head_dim, window, block_r):
+    t = window // BLOCK
+    pool = _s((1 + batch * t, kv_heads, BLOCK, head_dim))
     text = _lower_for_tpu(
-        paged_flash_attention,
-        _s((batch, chunk, HEADS, HEAD_DIM)), pool, pool,
+        functools.partial(paged_flash_attention, block_r=block_r),
+        _s((batch, chunk, heads, head_dim)), pool, pool,
         _s((batch, t), jnp.int32), _s((batch, chunk), jnp.int32),
         _s((batch,), jnp.int32))
     assert "tpu_custom_call" in text
@@ -110,6 +118,46 @@ def one_chip():
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("batch,chunk,heads,kv_heads,head_dim,window,"
+                         "layers,blocks,block_r", [
+    (64, 1, 16, 16, 256, 2048, 6, 1921, 8),        # chat decode
+    (1, 256, 16, 16, 256, 2048, 6, 1921, 128),     # chat chunk
+    (16, 1, 32, 8, 128, 4096, 8, 3585, 16),        # docqa decode
+    (1, 256, 32, 8, 128, 4096, 8, 3585, 512),      # docqa chunk
+])
+def test_paged_kernel_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, batch, chunk, heads, kv_heads, head_dim, window, layers,
+        blocks, block_r):
+    """The Mosaic compile proper, which the lowering above stops short
+    of: the group's 2·P page copies out of the pool left in HBM, the
+    read of P pages as one tile and the VMEM the step plans for
+    (``_VMEM_BUDGET``, under the default scoped limit: the kernel asks
+    for no other) are accepted for a described v5e at each of the
+    cells' four calls, on the whole pool with a traced layer, and the
+    call has no temporaries."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = s((layers, blocks, kv_heads, BLOCK, head_dim))
+    t = window // BLOCK
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda q, k, v, bt, pos, lens, layer: paged_flash_attention(
+                q, k, v, bt, pos, lens, layer=layer, block_r=block_r)
+        ).lower(s((batch, chunk, heads, head_dim)), pool, pool,
+                s((batch, t), jnp.int32), s((batch, chunk), jnp.int32),
+                s((batch,), jnp.int32), s((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def _engine_program(cfg, entry, slots, table, chunk):

@@ -805,6 +805,37 @@ def test_tokens_are_the_parents_and_each_program_has_one_transfer(
 
 
 @pytest.mark.parametrize("spec_tokens", [0, 4])
+def test_stats_book_the_paged_kernels_grid_steps(spec_tokens,
+                                                 monkeypatch):
+    """``decode_grid_steps`` / ``decode_grid_steps_live``: what the
+    paged kernel's innermost grid axis takes and what of it has a live
+    page, at the P the kernel itself picks for the decode (or verify)
+    call — here held to 2 so that a 12-slot table is six groups. Host
+    arithmetic on the slot lengths: one transfer a program, as before."""
+    import ray_tpu.ops.paged_flash as pf
+    monkeypatch.setattr(pf, "_PAGE_GROUPS", (2,))
+    eng = _seeded_engine(spec_tokens)
+    try:
+        for p in _PROMPTS:
+            list(eng.generate_sync(p, max_new_tokens=12))
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    slots, groups = 4, 6
+    assert st["decode_pages_per_step"] == 2
+    assert st["decode_grid_steps"] == st["decode_steps"] * slots * groups
+    # every slot, idle ones too, has its first group live; no sequence
+    # here grows past 48 tokens, and most are far shorter
+    assert st["decode_steps"] * slots <= st["decode_grid_steps_live"] \
+        < st["decode_grid_steps"] // 2
+    assert st["decode_grid_steps_live"] * 2 >= st["decode_pages_live"]
+    assert st["decode_grid_live_frac"] == pytest.approx(
+        st["decode_grid_steps_live"] / st["decode_grid_steps"], abs=1e-3)
+    assert st["h2d_transfers_total"] \
+        == st["prefill_chunks"] + st["decode_steps"]
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 4])
 def test_warm_ticks_run_no_eager_device_op_on_the_step_thread(
         spec_tokens):
     """Fifty warm ticks under a transfer guard that refuses every
