@@ -1,6 +1,9 @@
-"""Mixture-of-experts MLP with capacity-based top-1 (Switch) routing.
+"""Mixture-of-experts MLPs: capacity-based top-1 (Switch) routing, the
+trained form, and below it the dropless top-k gated experts, the served
+form (softmax or sigmoid router, a shared expert, a held share of the
+experts).
 
-TPU-first dispatch: token->expert movement is expressed as einsums over a
+Switch, TPU-first dispatch: token->expert movement is expressed as einsums over a
 dispatch one-hot ``[tokens, experts, capacity]`` (the flaxformer/Switch
 formulation). With expert weights sharded on the ``ep`` mesh axis and
 tokens on ``dp``/``fsdp``, XLA lowers the two boundary einsums to
@@ -83,49 +86,86 @@ def moe_logical_axes():
 # ------------------------------------------------------ dropless top-k
 # The served form (``experts_per_token > 0``): every token goes to its k
 # best experts, no capacity, no drop; gated (SwiGLU) experts of their own
-# width; weights the renormalised softmax. One code path for a prefill
+# width; weights the renormalised scores. One code path for a prefill
 # chunk (thousands of rows) and a decode step (a handful): sort the
 # (token, expert) assignments by expert, run one grouped product over
 # the sorted rows (``jax.lax.ragged_dot``: rows of group i times ``w[i]``,
 # exact, and the work is the rows', not one dense product an expert),
 # unsort, combine.
+#
+# A program may HOLD a share of the experts (``experts_held`` of
+# ``n_experts`` from ``expert_first``: one chip of a deployment that
+# splits them). It still routes every token over all of them, and
+# computes the part of the sum its own experts give: the assignments
+# that land elsewhere are sorted behind the held ones' rows, belong to no
+# group of the grouped product and are left out of the combine. Nothing
+# stands in for the absent chips' part.
 
 def route_topk(c, lp, x):
     """Router of the dropless layer. ``x [N, D]`` -> (weights ``[N, k]``
-    float32 summing to one, experts ``[N, k]`` int32): softmax over all
-    experts in float32, the k largest, renormalised."""
+    float32, experts ``[N, k]`` int32): each expert's score in float32
+    (``router_score``: softmax over all experts, or a sigmoid of each),
+    the k largest, renormalised to sum to one and scaled by
+    ``routed_scale``."""
     logits = jnp.dot(x, lp["w_router"].astype(c.dtype),
                      preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, c.experts_per_token)
-    return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
+    if c.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif c.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router_score {c.router_score!r}")
+    weights, experts = jax.lax.top_k(scores, c.experts_per_token)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if c.routed_scale != 1.0:
+        weights = weights * c.routed_scale
+    return weights, experts
 
 
 #: the dropless layer's stacked expert leaves
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 
 
+@jax.named_scope("moe_shared")
+def _shared_expert(c, lp, x):
+    """The SwiGLU expert every token passes. ``x [N, D]`` -> ``[N, D]``."""
+    dt = c.dtype
+    gate = jax.nn.silu(jnp.dot(x, lp["ws_gate"].astype(dt)))
+    return jnp.dot(gate * jnp.dot(x, lp["ws_up"].astype(dt)),
+                   lp["ws_down"].astype(dt))
+
+
 @jax.named_scope("moe")
 def topk_moe_mlp(c, lp, h, layer=None):
     """Dropless top-k gated-expert MLP. ``h [B, S, D]`` (compute dtype)
-    -> ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the experts
-    ``we_gate / we_up [E, D, F]``, ``we_down [E, F, D]``.
+    -> ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the held
+    experts ``we_gate / we_up [E_held, D, F]``, ``we_down [E_held, F,
+    D]`` (``E_held`` = ``E`` unless ``experts_held`` is set), and with
+    ``shared_expert_width`` the shared expert's ``ws_gate / ws_up /
+    ws_down``.
 
     Inside a scan over layers pass ``layer`` (the scan's int32 index)
-    and the expert leaves WHOLE, ``[L, E, ...]``: the grouped product
-    then runs on ``[L * E, ...]`` with the rows in layer ``layer``'s
-    groups and every other group empty. Scanned like the other leaves,
-    each layer's experts would be sliced out of the stack — a copy of
-    all of them, in every layer of every step — because a grouped
-    product, unlike a plain dot, cannot read its operand through the
-    slice."""
+    and the expert leaves WHOLE, ``[L, E_held, ...]``: the grouped
+    product then runs on ``[L * E_held, ...]`` with the rows in layer
+    ``layer``'s groups and every other group empty. Scanned like the
+    other leaves, each layer's experts would be sliced out of the stack
+    — a copy of all of them, in every layer of every step — because a
+    grouped product, unlike a plain dot, cannot read its operand through
+    the slice."""
     dt = c.dtype
     B, S, D = h.shape
-    E, k = c.n_experts, c.experts_per_token
+    E, k = c.n_experts_held, c.experts_per_token
     N = B * S
     x = h.reshape(N, D).astype(dt)
     weights, experts = route_topk(c, lp, x)
     flat = experts.reshape(N * k)
+    here = None
+    if E != c.n_experts:
+        # an assignment to an expert held elsewhere gets group E: sorted
+        # behind every held expert's rows, counted in no group
+        flat = flat - c.expert_first
+        here = (flat >= 0) & (flat < E)
+        flat = jnp.where(here, flat, E)
     order = jnp.argsort(flat)                  # stable: assignment order
     xs = jnp.take(x, order // k, axis=0)       # rows sorted by expert
     w_gate, w_up, w_down = (lp[name].astype(dt) for name in EXPERT_LEAVES)
@@ -135,7 +175,9 @@ def topk_moe_mlp(c, lp, h, layer=None):
         flat = flat + layer * E
         w_gate, w_up, w_down = (w.reshape((groups,) + w.shape[2:])
                                 for w in (w_gate, w_up, w_down))
-    sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1)
+    if here is not None:
+        flat = jnp.where(here, flat, groups)   # out of bounds: dropped
+    sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
     gate = jax.lax.ragged_dot(xs, w_gate, sizes,
                               preferred_element_type=jnp.float32)
     up = jax.lax.ragged_dot(xs, w_up, sizes,
@@ -147,24 +189,40 @@ def topk_moe_mlp(c, lp, h, layer=None):
     inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
         jnp.arange(N * k, dtype=jnp.int32))
     ys = jnp.take(ys, inverse, axis=0).reshape(N, k, D)
+    if here is not None:
+        # a row of no group is whatever the product left there: chosen
+        # away, not multiplied by a zero weight
+        ys = jnp.where(here.reshape(N, k, 1), ys, 0.0)
     y = jnp.sum(ys * weights[..., None], axis=1)
+    if c.shared_expert_width:
+        y = y + _shared_expert(c, lp, x).astype(jnp.float32)
     return y.reshape(B, S, D).astype(dt)
 
 
 def topk_moe_param_shapes(c):
-    f = c.expert_width
-    return {
+    f, held = c.expert_width, c.n_experts_held
+    shapes = {
         "w_router": (c.d_model, c.n_experts),
-        "we_gate": (c.n_experts, c.d_model, f),
-        "we_up": (c.n_experts, c.d_model, f),
-        "we_down": (c.n_experts, f, c.d_model),
+        "we_gate": (held, c.d_model, f),
+        "we_up": (held, c.d_model, f),
+        "we_down": (held, f, c.d_model),
     }
+    if c.shared_expert_width:
+        fs = c.shared_expert_width
+        shapes.update({"ws_gate": (c.d_model, fs), "ws_up": (c.d_model, fs),
+                       "ws_down": (fs, c.d_model)})
+    return shapes
 
 
-def topk_moe_logical_axes():
-    return {
+def topk_moe_logical_axes(c):
+    axes = {
         "w_router": ("layers", "embed", None),
         "we_gate": ("layers", "expert", "embed", "mlp"),
         "we_up": ("layers", "expert", "embed", "mlp"),
         "we_down": ("layers", "expert", "mlp", "embed"),
     }
+    if c.shared_expert_width:
+        axes.update({"ws_gate": ("layers", "embed", "mlp"),
+                     "ws_up": ("layers", "embed", "mlp"),
+                     "ws_down": ("layers", "mlp", "embed")})
+    return axes

@@ -119,10 +119,44 @@ class TransformerConfig:
     index_topk: int = 0
     index_heads: int = 0
     index_dim: int = 0
+    # Latent (MLA) attention (ops/latent_attention.py), kv_lora_rank > 0:
+    # queries through a q_lora_rank bottleneck with its own RMSNorm, to
+    # n_heads of qk_nope_dim + qk_rope_dim (head_dim is their sum); keys
+    # and values from one normed latent of kv_lora_rank a token, beside
+    # one rotated key of qk_rope_dim that every head shares; heads of
+    # v_head_dim out. The cache is ONE pool of latent rows, no head axis.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # a second RMSNorm on each sublayer's output, ahead of the residual
+    sandwich_norm: bool = False
+    norm_eps: float = 1e-6
+    # the first n_dense_layers of n_layers have a dense SwiGLU MLP of
+    # d_ff ahead of the expert layers (params["dense_layers"])
+    n_dense_layers: int = 0
+    # the dropless experts (models/moe.py): a SwiGLU expert of this
+    # width every token passes, beside the routed ones; the router's
+    # score ("softmax" over all experts | "sigmoid" of each) and the
+    # scale on the renormalised weights; and the share of the experts
+    # this program HOLDS: experts_held of n_experts from expert_first
+    # (0 = all). Every token is routed over all n_experts; the held
+    # ones' part is computed and the rest left to the chips that hold
+    # them, no exchange.
+    shared_expert_width: int = 0
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    experts_held: int = 0
+    expert_first: int = 0
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def d_expert(self) -> int:
@@ -132,7 +166,8 @@ class TransformerConfig:
     def served_only(self) -> bool:
         """A form only ``prefill`` / ``decode_step`` implement."""
         return bool(self.experts_per_token or self.qk_norm
-                    or self.index_topk)
+                    or self.index_topk or self.kv_lora_rank
+                    or self.sandwich_norm or self.n_dense_layers)
 
     @property
     def resolved_remat_policy(self) -> str:
@@ -147,14 +182,23 @@ class TransformerConfig:
         e, v, h = self.d_model, self.vocab_size, self.n_heads * self.head_dim
         kvh = self.kv_heads * self.head_dim
         per_layer = e * h + 2 * e * kvh + h * e          # q, k, v, o
+        if self.kv_lora_rank:    # wq_a, wq_b, wkv_a, wkv_b, wo, 2 norms
+            qr, r = self.q_lora_rank, self.kv_lora_rank
+            per_layer = e * qr + qr * h + e * (r + self.qk_rope_dim) \
+                + r * self.n_heads * (self.qk_nope_dim + self.v_head_dim) \
+                + self.n_heads * self.v_head_dim * e + qr + r
+        if self.sandwich_norm:
+            per_layer += 2 * e
+        dense_layer = per_layer + 3 * e * self.d_ff + 2 * e
         if self.qk_norm:
             per_layer += 2 * self.head_dim
         if self.index_topk:                  # wq, wk, ww, k layernorm
             per_layer += e * self.index_dim * (self.index_heads + 1) \
                 + e * self.index_heads + 2 * self.index_dim
         if self.experts_per_token:
-            per_layer += e * self.n_experts \
-                + self.n_experts * 3 * e * self.d_expert + 2 * e
+            per_layer += e * self.n_experts + 2 * e + 3 * e * (
+                self.n_experts_held * self.d_expert
+                + self.shared_expert_width)
         elif self.n_experts:
             per_layer += e * self.n_experts \
                 + self.n_experts * 2 * e * self.d_ff     # router + experts
@@ -164,7 +208,8 @@ class TransformerConfig:
         else:
             per_layer += 2 * e * self.d_ff + self.d_ff + e  # fc biases
             per_layer += 2 * e                           # ln scale+bias
-        total = v * e + self.n_layers * per_layer
+        total = v * e + (self.n_layers - self.n_dense_layers) * per_layer \
+            + self.n_dense_layers * dense_layer
         total += e if self.block_style == "llama" else 2 * e  # final norm
         total += e * v + (v if self.block_style == "gptj" else 0)  # lm head
         return total
@@ -177,8 +222,13 @@ class TransformerConfig:
         if not self.n_experts:
             return self.num_params
         if self.experts_per_token:
-            return self.num_params - self.n_layers * 3 * self.d_model \
-                * (self.n_experts - self.experts_per_token) * self.d_expert
+            # of the held experts a token meets its share of the k
+            met = self.experts_per_token * self.n_experts_held \
+                / self.n_experts
+            return int(self.num_params
+                       - (self.n_layers - self.n_dense_layers) * 3
+                       * self.d_model * self.d_expert
+                       * (self.n_experts_held - met))
         inactive = self.n_layers * (self.n_experts - 1) \
             * 2 * self.d_model * self.d_ff
         return self.num_params - inactive
@@ -232,15 +282,15 @@ def init_params(config: TransformerConfig, key,
         return dense(k, (L,) + shape, scale)
 
     out_scale = 0.02 / (2 * L) ** 0.5    # scaled residual-out init
+    _check_served_forms(c)
+    if c.kv_lora_rank:
+        return _init_latent_params(c, key, jnp.dtype(dtype), out_scale)
     layers: Dict[str, jnp.ndarray] = {
         "wq": stack(keys[0], (c.d_model, h)),
         "wk": stack(keys[1], (c.d_model, kvh)),
         "wv": stack(keys[2], (c.d_model, kvh)),
         "wo": stack(keys[3], (h, c.d_model), out_scale),
     }
-    if c.served_only and c.block_style != "llama":
-        raise ValueError("experts_per_token, qk_norm and index_topk are "
-                         "forms of the 'llama' block")
     # new leaves draw from keys folded out of ``key``: the nine above
     # stay what they were
     if c.qk_norm:
@@ -323,9 +373,126 @@ def init_params(config: TransformerConfig, key,
     }
 
 
+def _check_served_forms(c: TransformerConfig) -> None:
+    if c.served_only and c.block_style != "llama":
+        raise ValueError("the served forms (experts_per_token, qk_norm, "
+                         "index_topk, kv_lora_rank, sandwich_norm, "
+                         "n_dense_layers) are forms of the 'llama' block")
+    if c.kv_lora_rank:
+        if c.qk_norm or c.index_topk:
+            raise ValueError("qk_norm and index_topk are forms of "
+                             "per-head K/V, not of a latent cache")
+        if c.head_dim != c.qk_nope_dim + c.qk_rope_dim or not (
+                c.q_lora_rank and c.v_head_dim):
+            raise ValueError(
+                "latent attention needs q_lora_rank, v_head_dim and "
+                f"head_dim == qk_nope_dim + qk_rope_dim, got {c}")
+    if (c.sandwich_norm or c.n_dense_layers) and not c.kv_lora_rank:
+        raise ValueError("sandwich_norm and n_dense_layers are served "
+                         "with latent attention (kv_lora_rank > 0)")
+    if (c.shared_expert_width or c.experts_held
+            or c.router_score != "softmax") and not c.experts_per_token:
+        raise ValueError("shared_expert_width, experts_held and "
+                         "router_score belong to the dropless experts "
+                         "(experts_per_token > 0)")
+    if c.kv_lora_rank and not (
+            c.experts_per_token and 0 <= c.n_dense_layers < c.n_layers):
+        raise ValueError("latent attention is served ahead of dropless "
+                         "experts, n_dense_layers < n_layers of them dense")
+    if c.experts_held and not (
+            0 <= c.expert_first <= c.n_experts - c.experts_held):
+        raise ValueError(
+            f"experts {c.expert_first}..{c.expert_first + c.experts_held} "
+            f"held of {c.n_experts}")
+
+
+def _latent_norm_shapes(c: TransformerConfig) -> Dict[str, tuple]:
+    e = c.d_model
+    norms = {"attn_norm": e, "mlp_norm": e, "q_a_norm": c.q_lora_rank,
+             "kv_a_norm": c.kv_lora_rank}
+    if c.sandwich_norm:
+        norms.update({"post_attn_norm": e, "post_mlp_norm": e})
+    return norms
+
+
+def _latent_layer_shapes(c: TransformerConfig, dense: bool
+                         ) -> Dict[str, tuple]:
+    """One layer's matmul leaves of the latent model, ``dense`` (a
+    leading SwiGLU layer of d_ff) or an expert layer: name -> (shape,
+    logical axes without the layers axis)."""
+    from ray_tpu.models.moe import (topk_moe_logical_axes,
+                                    topk_moe_param_shapes)
+    e, H = c.d_model, c.n_heads
+    out = {
+        "wq_a": ((e, c.q_lora_rank), ("embed", None)),
+        "wq_b": ((c.q_lora_rank, H * c.head_dim), (None, "heads")),
+        "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_dim), ("embed", None)),
+        "wkv_b": ((c.kv_lora_rank, H * (c.qk_nope_dim + c.v_head_dim)),
+                  (None, "heads")),
+        "wo": ((H * c.v_head_dim, e), ("heads", "embed")),
+    }
+    if dense:
+        out.update({"w_gate": ((e, c.d_ff), ("embed", "mlp")),
+                    "w_up": ((e, c.d_ff), ("embed", "mlp")),
+                    "w_down": ((c.d_ff, e), ("mlp", "embed"))})
+    else:
+        axes = topk_moe_logical_axes(c)
+        out.update({name: (shape, axes[name][1:]) for name, shape
+                    in topk_moe_param_shapes(c).items()})
+    return out
+
+
+def _init_latent_params(c, key, dtype, out_scale) -> Dict:
+    """The latent model's tree: ``dense_layers`` (the leading
+    ``n_dense_layers``, stacked) and ``layers`` (the expert layers,
+    stacked), every matmul leaf drawn a layer at a time into ``dtype``."""
+    def stack(k, n, dense):
+        out: Dict[str, jnp.ndarray] = {}
+        for i, (name, (shape, _)) in enumerate(
+                sorted(_latent_layer_shapes(c, dense).items())):
+            out[name] = _layered_init(
+                jax.random.fold_in(k, i),
+                out_scale if name in ("wo", "w_down", "we_down", "ws_down")
+                else 0.02, n, shape, dtype)
+        out.update({name: jnp.ones((n, width), jnp.float32)
+                    for name, width in _latent_norm_shapes(c).items()})
+        return out
+    keys = jax.random.split(jax.random.fold_in(key, 103), 4)
+    n_moe = c.n_layers - c.n_dense_layers
+    params = {
+        "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
+                             dtype=dtype),
+        "layers": stack(keys[1], n_moe, False),
+        "final_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
+        "lm_head": {"w": _dense_init(keys[2], (c.d_model, c.vocab_size),
+                                     dtype=dtype)},
+    }
+    if c.n_dense_layers:
+        params["dense_layers"] = stack(keys[3], c.n_dense_layers, True)
+    return params
+
+
+def _latent_logical_axes(c) -> Dict:
+    def stack(dense):
+        out = {name: ("layers",) + axes for name, (_, axes)
+               in _latent_layer_shapes(c, dense).items()}
+        out.update({name: ("layers", "embed" if width == c.d_model
+                           else None)
+                    for name, width in _latent_norm_shapes(c).items()})
+        return out
+    axes = {"embed": ("vocab", "embed"), "layers": stack(False),
+            "final_norm": {"scale": ("embed",)},
+            "lm_head": {"w": ("embed", "vocab")}}
+    if c.n_dense_layers:
+        axes["dense_layers"] = stack(True)
+    return axes
+
+
 def logical_axes(config: TransformerConfig) -> Dict:
     """Pytree (same treedef as params) of logical-axis tuples."""
     c = config
+    if c.kv_lora_rank:
+        return _latent_logical_axes(c)
     common = {
         "wq": ("layers", "embed", "heads"),
         "wk": ("layers", "embed", "kv"),
@@ -343,7 +510,7 @@ def logical_axes(config: TransformerConfig) -> Dict:
                        "k_idx_bias": ("layers", None)})
     if c.experts_per_token:
         from ray_tpu.models.moe import topk_moe_logical_axes
-        layers = {**common, **topk_moe_logical_axes(),
+        layers = {**common, **topk_moe_logical_axes(c),
                   "attn_norm": ("layers", "embed"),
                   "mlp_norm": ("layers", "embed")}
         final = {"scale": ("embed",)}
@@ -392,7 +559,9 @@ def logical_axes(config: TransformerConfig) -> Dict:
 #: scale and bias to f32) — these and everything under ``final_norm``.
 #: Every other leaf is read through ``.astype(config.dtype)``.
 _F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias",
-                         "q_norm", "k_norm", "k_idx_scale", "k_idx_bias"))
+                         "q_norm", "k_norm", "k_idx_scale", "k_idx_bias",
+                         "q_a_norm", "kv_a_norm", "post_attn_norm",
+                         "post_mlp_norm"))
 
 
 def inference_params(config: TransformerConfig, params: Dict) -> Dict:
@@ -513,6 +682,14 @@ def _attn_sublayer(c, h, lp, sin, cos, layout, mesh, rules):
                       lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
 
 
+def _swiglu(c, h, lp):
+    """The dense gated MLP of the 'llama' block on normed input h."""
+    dt = c.dtype
+    gate = jax.nn.silu(jnp.dot(h, lp["w_gate"].astype(dt)))
+    up = jnp.dot(h, lp["w_up"].astype(dt))
+    return jnp.dot(gate * up, lp["w_down"].astype(dt))
+
+
 @jax.named_scope("mlp")
 def _mlp_sublayer(c, h, lp, layer=None):
     """Dense or MoE MLP on normed input h; returns (out, moe_aux).
@@ -526,9 +703,7 @@ def _mlp_sublayer(c, h, lp, layer=None):
         from ray_tpu.models.moe import moe_mlp
         return moe_mlp(c, lp, h.astype(dt))
     if c.block_style == "llama":
-        gate = jax.nn.silu(jnp.dot(h, lp["w_gate"].astype(dt)))
-        up = jnp.dot(h, lp["w_up"].astype(dt))
-        return jnp.dot(gate * up, lp["w_down"].astype(dt)), 0.0
+        return _swiglu(c, h, lp), 0.0
     mlp = jnp.dot(h.astype(dt), lp["fc_in"].astype(dt)) \
         + lp["fc_in_b"].astype(dt)
     mlp = jax.nn.gelu(mlp)
@@ -565,9 +740,10 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
     c = config
     if c.served_only:
         raise NotImplementedError(
-            "experts_per_token, qk_norm and index_topk are served through "
-            "prefill / decode_step only; training keeps Switch top-1 "
-            "experts and dense attention")
+            "experts_per_token, qk_norm, index_topk and the latent forms "
+            "(kv_lora_rank, sandwich_norm, n_dense_layers) are served "
+            "through prefill / decode_step only; training keeps Switch "
+            "top-1 experts and dense attention")
     seq = x.shape[1]
     sin, cos = rotary_table(
         seq, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
@@ -596,7 +772,7 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
 def _final_norm(config: TransformerConfig, params: Dict, x: jnp.ndarray):
     fn = params["final_norm"]
     if config.block_style == "llama":
-        return rms_norm(x, fn["scale"])
+        return rms_norm(x, fn["scale"], eps=config.norm_eps)
     return layer_norm(x, fn["scale"], fn["bias"])
 
 
@@ -796,7 +972,8 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 # ------------------------------------------------------- inference (KV)
 # The serving decode path: a paged KV cache (one pool
 # [n_layers, num_blocks, kv_heads, block_size, head_dim] for k and one
-# for v, with an indexer a third for its keys, block table per
+# for v, with an indexer a third for its keys; with latent attention ONE
+# pool of rows that are every head's key and value; block table per
 # sequence) written by chunked prefill and batched single-token decode
 # steps. Both entry points are shape-stable
 # — jit them once at the engine's fixed (batch, chunk, table) shapes
@@ -819,8 +996,22 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     :func:`prefill` and :func:`decode_step` pass each pool WHOLE, with a
     layer index, to the write and to the attention; nothing takes a
     layer's slice of it. Zero-filled; a zero key scores 0 pre-softmax,
-    so reserved/trash blocks are numerically harmless."""
+    so reserved/trash blocks are numerically harmless.
+
+    With latent attention (``kv_lora_rank``) the cache is
+    ``{"latent"}`` alone, ``[n_layers, num_blocks, 1, block_size,
+    row]``: what the engine copies, ships, counts and sizes
+    (``kv_bytes_per_token``) it takes from the pools returned here."""
     c = config
+    if c.kv_lora_rank:
+        # a latent cache: ONE pool, a row a token and layer for every
+        # head (the normed latent | the rotated shared key | zeros up
+        # to whole lane tiles), under the one "head" the page layout
+        # keeps: key and, in its first kv_lora_rank columns, value
+        from ray_tpu.ops.latent_attention import latent_row_width
+        return {"latent": jnp.zeros(
+            (c.n_layers, num_blocks, 1, block_size,
+             latent_row_width(c.kv_lora_rank, c.qk_rope_dim)), c.dtype)}
     shape = (c.n_layers, num_blocks, c.kv_heads, block_size, c.head_dim)
     cache = {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
     if c.index_topk:
@@ -849,6 +1040,32 @@ def _indexer(c, h, lp, isin, icos, positions):
     ki = apply_rotary(ki[:, :, None], isin, icos, positions=positions,
                       layout="neox")
     return qi, ki, wi
+
+
+def _write_rows(cache, new, layer, block_tables, positions, write_mask):
+    """Scatter the new tokens' rows ``new[name] [B, C, heads, D]`` into
+    layer ``layer``'s pages of the whole pools, in place."""
+    pool = next(iter(cache.values()))
+    n_blocks, bs = pool.shape[1], pool.shape[3]
+    with jax.named_scope("kv_write"):
+        bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
+        # invalid (padded) chunk positions scatter out of bounds ->
+        # dropped
+        bid = jnp.where(write_mask, bid, n_blocks)[..., None]
+        slot = (positions % bs)[..., None]
+        # [L, N, KVH, bs, D] indexed (layer, bid, head, slot): the
+        # index arrays broadcast to the rows' own (B, C, KVH) and each
+        # names one D-long row, the pool's minor-most dim. A window over
+        # (KVH, D) — at[layer, bid, :, slot] — writes the same rows but
+        # is not contiguous in this layout: the TPU compiler then keeps
+        # the carried pool with block_size ahead of kv_heads for the
+        # scatter and copies ALL of it into the kernel's layout in every
+        # layer (tests/ops/test_tpu_lowering.py compiles and looks)
+        return {
+            name: pool.at[
+                layer, bid, jnp.arange(pool.shape[2], dtype=jnp.int32),
+                slot].set(new[name].astype(pool.dtype), mode="drop")
+            for name, pool in cache.items()}
 
 
 @jax.named_scope("attn")
@@ -886,27 +1103,9 @@ def _paged_attn_sublayer(c, h, lp, rot, layout, layer, cache,
     if c.index_topk:
         qi, new["ki"], wi = _indexer(c, h, lp, isin, icos, positions)
 
-    n_blocks, bs = cache["k"].shape[1], cache["k"].shape[3]
-    with jax.named_scope("kv_write"):
-        bid = jnp.take_along_axis(block_tables, positions // bs, axis=1)
-        slot = positions % bs
-        # invalid (padded) chunk positions scatter out of bounds ->
-        # dropped
-        bid = jnp.where(write_mask, bid, n_blocks)
-        # [L, N, KVH, bs, D] indexed (layer, bid, head, slot): the
-        # index arrays broadcast to k's own (B, C, KVH) and each names
-        # one D-long row, the pool's minor-most dim. A window over
-        # (KVH, D) — at[layer, bid, :, slot] — writes the same rows but
-        # is not contiguous in this layout: the TPU compiler then keeps
-        # the carried pool with block_size ahead of kv_heads for the
-        # scatter and copies ALL of it into the kernel's layout in every
-        # layer (tests/ops/test_tpu_lowering.py compiles and looks)
-        bid, slot = bid[..., None], slot[..., None]
-        cache = {
-            name: pool.at[
-                layer, bid, jnp.arange(pool.shape[2], dtype=jnp.int32),
-                slot].set(new[name].astype(pool.dtype), mode="drop")
-            for name, pool in cache.items()}
+    bs = cache["k"].shape[3]
+    cache = _write_rows(cache, new, layer, block_tables, positions,
+                        write_mask)
 
     # h.shape[1] is static under jit: > 1 means a prefill chunk, whose
     # much larger query-row count can carry a bigger row block than the
@@ -933,6 +1132,54 @@ def _paged_attn_sublayer(c, h, lp, rot, layout, layer, cache,
     return out, cache
 
 
+@jax.named_scope("attn")
+def _latent_attn_sublayer(c, h, lp, rot, layer, cache, block_tables,
+                          positions, write_mask, lens):
+    """The latent (MLA) attention sublayer of cache layer ``layer``:
+    queries through the ``q_lora_rank`` bottleneck, one latent row a
+    new token written to the pool (normed latent | rotated shared key),
+    then every head attends the pool's rows themselves
+    (``ops/latent_attention.py``): ``wkv_b``'s key half goes into the
+    query and its value half comes after the softmax, so no per-head K
+    or V of the context exists. Returns (attn_out, cache)."""
+    from ray_tpu.ops.latent_attention import latent_attention
+    dt = c.dtype
+    b, n, e = h.shape
+    H, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
+    r = c.kv_lora_rank
+    sin, cos = rot
+    hd = h.astype(dt)
+    with jax.named_scope("mla_q"):
+        cq = rms_norm(jnp.dot(hd, lp["wq_a"].astype(dt)), lp["q_a_norm"],
+                      eps=c.norm_eps)
+        q = jnp.dot(cq, lp["wq_b"].astype(dt)).reshape(b, n, H, dn + dr)
+        q_nope = q[..., :dn]
+        q_rope = apply_rotary(q[..., dn:], sin, cos, positions=positions,
+                              layout="neox")
+    with jax.named_scope("mla_latent"):
+        ckv = jnp.dot(hd, lp["wkv_a"].astype(dt))
+        lat = rms_norm(ckv[..., :r], lp["kv_a_norm"], eps=c.norm_eps)
+        k_rope = apply_rotary(ckv[..., None, r:], sin, cos,
+                              positions=positions, layout="neox")
+        width = cache["latent"].shape[-1]
+        row = jnp.concatenate(
+            [lat[:, :, None], k_rope,
+             jnp.zeros((b, n, 1, width - r - dr), dt)], axis=-1)
+    cache = _write_rows(cache, {"latent": row}, layer, block_tables,
+                        positions, write_mask)
+    wkv_b = lp["wkv_b"].reshape(r, H, dn + dv)
+    br = c.paged_block_r_prefill if (n > 1 and c.paged_block_r_prefill) \
+        else c.paged_block_r
+    att = latent_attention(
+        q_nope, q_rope, wkv_b[..., :dn], wkv_b[..., dn:], cache["latent"],
+        block_tables, positions, layer=layer, lens=lens,
+        sm_scale=(dn + dr) ** -0.5, impl=c.paged_impl, block_r=br or None)
+    with jax.named_scope("mla_out"):
+        out = jnp.einsum("bshd,hde->bse", att,
+                         lp["wo"].reshape(H, dv, e).astype(dt))
+    return out, cache
+
+
 def _forward_with_cache(c: TransformerConfig, params: Dict,
                         ids: jnp.ndarray, cache: Dict[str, jnp.ndarray],
                         block_tables: jnp.ndarray,
@@ -954,13 +1201,18 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
             "paged decode serves dropless top-k experts "
             "(experts_per_token > 0); Switch top-1 with capacity drops "
             "tokens by the batch they arrive in")
-    bs = cache["k"].shape[3]
+    _check_served_forms(c)
+    bs = next(iter(cache.values())).shape[3]
     window = block_tables.shape[1] * bs
-    rot = rotary_table(
-        window, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
-        c.rope_base)
-    rot += rotary_table(window, c.index_dim, c.rope_base) \
-        if c.index_topk else (None, None)
+    if c.kv_lora_rank:
+        rot = rotary_table(window, c.qk_rope_dim, c.rope_base)
+    else:
+        rot = rotary_table(
+            window,
+            c.rotary_dim if c.block_style == "gptj" else c.head_dim,
+            c.rope_base)
+        rot += rotary_table(window, c.index_dim, c.rope_base) \
+            if c.index_topk else (None, None)
     layout = "gptj" if c.block_style == "gptj" else "neox"
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
@@ -991,7 +1243,35 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                                layer if whole else None)
         return x + mlp.astype(x.dtype), cache
 
-    step = gptj_step if c.block_style == "gptj" else llama_step
+    def latent_step(x, lp, layer, cache):
+        """The latent model's layer (cache layer ``layer``): a leading
+        dense one (``w_gate`` among its leaves) or an expert layer,
+        whose place in the expert stack is ``layer - n_dense_layers``.
+        With ``sandwich_norm`` each sublayer's output passes a second
+        RMSNorm ahead of the residual add."""
+        eps = c.norm_eps
+
+        def post(y, name):
+            if not c.sandwich_norm:
+                return y
+            with jax.named_scope("post_norm"):
+                return rms_norm(y, lp[name], eps=eps)
+        h = rms_norm(x, lp["attn_norm"], eps=eps)
+        att, cache = _latent_attn_sublayer(
+            c, h, lp, rot, layer, cache, block_tables, positions,
+            write_mask, lens)
+        x = x + post(att, "post_attn_norm").astype(x.dtype)
+        h2 = rms_norm(x, lp["mlp_norm"], eps=eps).astype(c.dtype)
+        if "w_gate" in lp:
+            with jax.named_scope("mlp"):
+                mlp = _swiglu(c, h2, lp)
+        else:
+            mlp, _ = _mlp_sublayer(c, h2, {**lp, **whole},
+                                   layer - c.n_dense_layers)
+        return x + post(mlp, "post_mlp_norm").astype(x.dtype), cache
+
+    step = gptj_step if c.block_style == "gptj" else \
+        latent_step if c.kv_lora_rank else llama_step
 
     def scan_fn(carry, per_layer):
         x, cache = carry
@@ -999,10 +1279,19 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
         with jax.named_scope("layer"):
             return step(x, lp, layer, cache), None
 
-    n_layers = cache["k"].shape[0]
+    n_layers = next(iter(cache.values())).shape[0]
+    carry = (x, dict(cache))
+    if c.n_dense_layers:
+        # the leading dense layers, a scan of their own ahead of the
+        # expert layers': each kind of layer is compiled once, and the
+        # pools are the carry of both
+        carry, _ = jax.lax.scan(
+            scan_fn, carry,
+            (params["dense_layers"],
+             jnp.arange(c.n_dense_layers, dtype=jnp.int32)))
     (x, cache), _ = jax.lax.scan(
-        scan_fn, (x, dict(cache)),
-        (scanned, jnp.arange(n_layers, dtype=jnp.int32)))
+        scan_fn, carry,
+        (scanned, jnp.arange(c.n_dense_layers, n_layers, dtype=jnp.int32)))
 
     x = _final_norm(c, params, x)
     return _lm_head(c, params, x), cache

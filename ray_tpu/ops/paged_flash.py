@@ -69,6 +69,14 @@ candidate grid once and persists the winner through the SAME on-disk
 table as ``autotune_flash_blocks``). Padded rows carry position −1 —
 fully masked, dropped on unpack.
 
+A **latent cache** (``v_width``; :mod:`ray_tpu.ops.latent_attention`)
+is the same kernel with one key head and no V pool: a page ``[1,
+block_size, row]`` is fetched once and read as the key tile and, in its
+first ``v_width`` columns, as the value tile; a fourth prefetched scalar
+gives each sequence's live query rows, and a row block past them (a
+chunk's padding: at 128 rows a token, most of a short question's chunk)
+fetches and folds nothing. The dense calls' programs are unchanged.
+
 ``interpret=True`` runs the same kernel, copies and semaphores
 included, on CPU (tier-1 parity tests); on TPU it compiles with
 parallel/arbitrary dimension semantics like the flash kernels (what a
@@ -124,10 +132,8 @@ def paged_work_pages(lens, block_size: int):
         if hasattr(lens, "clip") else max(-(-lens // block_size), 1)
 
 
-def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm,
-                  v_hbm, o_ref, m_s, l_s, acc_s, k_buf, v_buf, sem, *,
-                  bs: int, hb: int, pp: int, slots: int,
-                  sm_scale: float):
+def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
+                  sm_scale: float, v_width: Optional[int] = None):
     """One (batch b, kv head group g, row block r, page group t) step:
     fold pages ``t·pp .. t·pp + pp − 1`` of sequence b into the row
     block's online softmax, one kv head of the group at a time.
@@ -135,21 +141,43 @@ def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm,
     ``k_buf`` / ``v_buf`` ``[2, pp, hb, bs, d]`` hold two groups, the
     one this step folds and the one the next step will; ``sem[slot,
     0|1]`` counts a buffer's K and V copies. Scalar refs (bt, lens,
-    layer) are in SMEM ahead of the body."""
+    layer) are in SMEM ahead of the body.
+
+    ``v_width`` (a latent cache): there is no V pool and no V buffer;
+    a page's value is the first ``v_width`` columns of its key tile,
+    read from the one copy of it. One more scalar rides ahead of the
+    body, ``rows_ref [B]``, the sequence's live query rows: a row block
+    past them (the padding of a chunk that holds a 64-token question:
+    128 heads' rows a token make 2048 tokens 512 blocks) holds no page
+    and folds none."""
+    if v_width is None:
+        (bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref,
+         m_s, l_s, acc_s, k_buf, v_buf, sem) = refs
+    else:
+        (bt_ref, lens_ref, layer_ref, rows_ref, q_ref, pos_ref, k_hbm,
+         o_ref, m_s, l_s, acc_s, k_buf, sem) = refs
+        v_buf = k_buf           # what a dead page's rows are zeroed in
     b, g, t = pl.program_id(0), pl.program_id(1), pl.program_id(3)
     nt = pl.num_programs(3)
     # Length-aware skipping: groups past the last live one fetch
     # nothing and fold nothing. A sequence holds at least the one page
     # an idle slot runs and at most the table.
     pages = jnp.clip(pl.cdiv(lens_ref[b], bs), 1, slots)
+    if v_width is not None:
+        block_r = q_ref.shape[2]
+        pages = jnp.where(pl.program_id(2) * block_r < rows_ref[b],
+                          pages, 0)
     last = (pages - 1) // pp
 
     def copies(group, slot, j):
         """The K and the V copy of page ``j`` of ``group``, HBM page
         ``(layer, block, this step's kv heads)`` to ``buf[slot, j]``."""
         src = (layer_ref[0], bt_ref[b, group * pp + j], pl.ds(g * hb, hb))
-        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, j],
-                                      sem.at[slot, 0]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, j],
+                                       sem.at[slot, 0])
+        if v_width is not None:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, j],
                                       sem.at[slot, 1]))
 
@@ -183,7 +211,10 @@ def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm,
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
-        fetch(0, 0)
+        if v_width is None:
+            fetch(0, 0)
+        else:
+            pl.when(pages > 0)(lambda: fetch(0, 0))
 
     @pl.when(t <= last)
     def _compute():
@@ -208,7 +239,8 @@ def _paged_kernel(bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm,
             # the group's pages of one kv head, one under the other: a
             # page is whole sublane tiles, the join moves nothing
             k = k_buf[slot, :, i].reshape(pp * bs, -1)
-            v = v_buf[slot, :, i].reshape(pp * bs, -1)
+            v = v_buf[slot, :, i].reshape(pp * bs, -1) \
+                if v_width is None else k[:, :v_width]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
@@ -259,14 +291,15 @@ def _row_block(rows: int, head_dim: int, dtype, block_r: Optional[int],
 
 
 def _step_vmem_bytes(pp: int, hb: int, bs: int, d: int, itemsize: int,
-                     block_r: int) -> int:
+                     block_r: int, pools: int = 2) -> int:
     """VMEM one grid step holds with ``pp`` pages a group: the K and V
-    pages (two groups each: this step's and the next's), one head's
+    pages (two groups each: this step's and the next's; ``pools`` 1
+    where a latent page is both), one head's
     joined K and V tile, the f32 score tile and its exponentials, and
     what does not grow with ``pp``: q and out blocks (double-buffered)
     and the (m, l, acc) scratch."""
-    pages = 2 * 2 * pp * hb * bs * d * itemsize
-    joined = 2 * pp * bs * d * itemsize
+    pages = pools * 2 * pp * hb * bs * d * itemsize
+    joined = pools * pp * bs * d * itemsize
     scores = 2 * block_r * _round_up(pp * bs, 128) * 4
     fixed = 2 * 2 * hb * block_r * d * itemsize \
         + hb * block_r * (2 * 128 + d) * 4
@@ -276,7 +309,8 @@ def _step_vmem_bytes(pp: int, hb: int, bs: int, d: int, itemsize: int,
 def paged_pages_per_step(rows: int, kv_heads: int, block_size: int,
                          head_dim: int, dtype, table_len: int, *,
                          block_r: Optional[int] = None,
-                         chip: Optional[str] = None) -> int:
+                         chip: Optional[str] = None,
+                         pools: int = 2) -> int:
     """P, the pages of a sequence one grid step of
     :func:`paged_flash_attention` folds for a call of ``rows`` query
     rows a kv head (C · heads per kv head) over tables of ``table_len``
@@ -285,18 +319,20 @@ def paged_pages_per_step(rows: int, kv_heads: int, block_size: int,
     follows what the call can observe — the page's bytes, the row
     block, the table — so MHA at head_dim 256 (a 128 KB page of sixteen
     heads) gets a smaller group than GQA at 128 (32 KB). The engine's
-    grid-step counters ask the same function the kernel does."""
+    grid-step counters ask the same function the kernel does.
+    ``pools=1``: a latent cache, one pool whose page is key and value
+    (``head_dim`` is then the latent row's width)."""
     block_r = _row_block(rows, head_dim, dtype, block_r, chip)
     return _pages_per_step(_heads_per_step(kv_heads, block_r), block_size,
-                           head_dim, dtype, block_r, table_len)
+                           head_dim, dtype, block_r, table_len, pools)
 
 
 def _pages_per_step(hb: int, bs: int, d: int, dtype, block_r: int,
-                    table_len: int) -> int:
+                    table_len: int, pools: int = 2) -> int:
     itemsize = jnp.dtype(dtype).itemsize
     for pp in _PAGE_GROUPS:
         if pp // 2 < table_len and _step_vmem_bytes(
-                pp, hb, bs, d, itemsize, block_r) <= _VMEM_BUDGET:
+                pp, hb, bs, d, itemsize, block_r, pools) <= _VMEM_BUDGET:
             return pp
     return 1
 
@@ -323,7 +359,8 @@ def layered_pool(k_cache: jnp.ndarray, v_cache: jnp.ndarray, layer):
             f"whole [L, N, KVH, bs, D] pool with its layer index, or one "
             f"layer's [N, KVH, bs, D] pool without")
     if layer is None:
-        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+        k_cache, layer = k_cache[None], 0
+        v_cache = None if v_cache is None else v_cache[None]
     return k_cache, v_cache, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
@@ -335,7 +372,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                           layer=None,
                           sm_scale: Optional[float] = None,
                           block_r: Optional[int] = None,
-                          interpret: bool = False) -> jnp.ndarray:
+                          interpret: bool = False,
+                          v_width: Optional[int] = None) -> jnp.ndarray:
     """Paged attention of new-token queries against the block pool.
 
     Same contract as the XLA reference
@@ -352,9 +390,18 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     skipped entirely. Rows whose position ≥ ``lens[b]`` (padded prefill
     tail) attend only live keys — their outputs are the caller's to
     discard, exactly as with the reference path.
+
+    ``v_width`` with ``v_cache=None``: a latent cache, one pool
+    ``[L, N, 1, bs, D]`` whose row is a token's key for every head and,
+    in its first ``v_width`` columns, its value; the result is
+    ``[B, C, H, v_width]``. A page is fetched once and read as both.
     """
+    if (v_width is None) != (v_cache is not None):
+        raise ValueError("pass a V pool, or v_width for a latent pool "
+                         "whose page is key and value, not both")
     k_cache, v_cache, layer = layered_pool(k_cache, v_cache, layer)
     b, c, h, d = q.shape
+    dv = d if v_width is None else v_width
     g, bs = k_cache.shape[2:4]
     t = block_tables.shape[1]
     if h % g:
@@ -368,7 +415,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     rows_pad = _round_up(rows, block_r)
     nr = rows_pad // block_r
     hb = _heads_per_step(g, block_r)
-    pp = _pages_per_step(hb, bs, d, k_cache.dtype, block_r, t)
+    pp = _pages_per_step(hb, bs, d, k_cache.dtype, block_r, t,
+                         2 if v_width is None else 1)
 
     # Group-major query rows: row r of kv head g is (c = r // rep,
     # head = g*rep + r % rep). Only q (tiny) is reshaped — never the
@@ -382,45 +430,50 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                            constant_values=-1)
     pos_rows = pos_rows[:, :, None]            # [B, rows_pad, 1] column
 
-    def q_map(b_, g_, r_, t_, bt, ln, ly):
+    def q_map(b_, g_, r_, t_, *scalars):
         return (b_, g_, r_, 0)
 
-    def pos_map(b_, g_, r_, t_, bt, ln, ly):
+    def pos_map(b_, g_, r_, t_, *scalars):
         return (b_, r_, 0)
 
+    pools = (k_cache,) if v_cache is None else (k_cache, v_cache)
+    scalars = (block_tables.astype(jnp.int32), lens.astype(jnp.int32), layer)
+    if v_width is not None:
+        # live query rows a sequence: a chunk's padding (positions past
+        # lens, the caller's to discard) is no row of them
+        scalars += (jnp.sum(q_positions < lens[:, None], axis=1,
+                            dtype=jnp.int32) * rep,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(b, g // hb, nr, pl.cdiv(t, pp)),
         in_specs=[
             pl.BlockSpec((1, hb, block_r, d), q_map),
             pl.BlockSpec((1, block_r, 1), pos_map),
-            pl.BlockSpec(memory_space=pl.ANY),    # the K pool, in HBM
-            pl.BlockSpec(memory_space=pl.ANY),    # the V pool
-        ],
-        out_specs=pl.BlockSpec((1, hb, block_r, d), q_map),
+        ] + [pl.BlockSpec(memory_space=pl.ANY)  # the pools, left in HBM
+             for _ in pools],
+        out_specs=pl.BlockSpec((1, hb, block_r, dv), q_map),
         scratch_shapes=[
             pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running max m
             pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running denom l
-            pltpu.VMEM((hb, block_r, d), jnp.float32),    # out accumulator
-            pltpu.VMEM((2, pp, hb, bs, d), k_cache.dtype),  # two groups'
-            pltpu.VMEM((2, pp, hb, bs, d), v_cache.dtype),  # K and V pages
+            pltpu.VMEM((hb, block_r, dv), jnp.float32),   # out accumulator
+        ] + [pltpu.VMEM((2, pp, hb, bs, d), pool.dtype)   # two groups'
+             for pool in pools] + [                       # pages, a pool
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, bs=bs, hb=hb, pp=pp, slots=t,
-                          sm_scale=float(sm_scale)),
+                          sm_scale=float(sm_scale), v_width=v_width),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, dv), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name="paged_attention",
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), layer,
-      qg, pos_rows, k_cache, v_cache)
-    out = out[:, :, :rows, :].reshape(b, g, c, rep, d) \
-        .transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)
+        name="paged_attention" if v_width is None else "mla_attn",
+    )(*scalars, qg, pos_rows, *pools)
+    out = out[:, :, :rows, :].reshape(b, g, c, rep, dv) \
+        .transpose(0, 2, 1, 3, 4).reshape(b, c, h, dv)
     return out
 
 

@@ -56,7 +56,8 @@ class DisaggHandoffError(RayTpuError):
 
 
 # ------------------------------------------------------------ KV codec
-def pack_kv_blocks(k: np.ndarray, v: np.ndarray, wire: str = "bf16",
+def pack_kv_blocks(k: Optional[np.ndarray], v: Optional[np.ndarray],
+                   wire: str = "bf16",
                    extra: Optional[Dict[str, np.ndarray]] = None
                    ) -> Dict[str, Any]:
     """Pack gathered KV block slabs ``[n_layers, n_blocks, kv_heads,
@@ -66,10 +67,33 @@ def pack_kv_blocks(k: np.ndarray, v: np.ndarray, wire: str = "bf16",
     blocks of a cache's further pools (an indexer's keys), by name:
     shipped as they are under either wire — a selection made from
     quantized keys is another selection, and they are a sliver of a
-    page. ``wire_bytes`` is the actual transport footprint as the
+    page. A cache with no ``k`` / ``v`` pool (a latent cache: one pool
+    of rows that are key and value) passes ``None`` for both and ships
+    its pool under ``extra``, as it is: the latent is what every head's
+    key and value are expanded from, and a quantized one is another
+    model. ``wire_bytes`` is the actual transport footprint as the
     zero-copy serializer would ship it."""
     if wire not in ("bf16", "int8"):
         raise ValueError(f"unknown kv wire format {wire!r}")
+    if k is None and v is None:
+        out: Dict[str, Any] = {"wire": wire}
+        payload: List[np.ndarray] = []
+    else:
+        out, payload = _pack_kv(k, v, wire)
+    if extra:
+        out["extra"] = {name: np.ascontiguousarray(a)
+                        for name, a in extra.items()}
+        payload += list(out["extra"].values())
+    try:
+        from ray_tpu.core.protocol import wire_sizeof
+        out["wire_bytes"] = int(wire_sizeof(payload))
+    except Exception:
+        out["wire_bytes"] = int(sum(a.nbytes for a in payload))
+    return out
+
+
+def _pack_kv(k: np.ndarray, v: np.ndarray, wire: str):
+    """(packed k and v, the arrays that travel)."""
     k = np.ascontiguousarray(k)
     v = np.ascontiguousarray(v)
     if k.shape != v.shape:
@@ -84,16 +108,7 @@ def pack_kv_blocks(k: np.ndarray, v: np.ndarray, wire: str = "bf16",
         out["k"], out["k_scales"] = quantize_int8_np(k)
         out["v"], out["v_scales"] = quantize_int8_np(v)
         payload = [out["k"], out["k_scales"], out["v"], out["v_scales"]]
-    if extra:
-        out["extra"] = {name: np.ascontiguousarray(a)
-                        for name, a in extra.items()}
-        payload += list(out["extra"].values())
-    try:
-        from ray_tpu.core.protocol import wire_sizeof
-        out["wire_bytes"] = int(wire_sizeof(payload))
-    except Exception:
-        out["wire_bytes"] = int(sum(a.nbytes for a in payload))
-    return out
+    return out, payload
 
 
 def unpack_kv_extra(kv: Dict[str, Any]) -> Dict[str, np.ndarray]:

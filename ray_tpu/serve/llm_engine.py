@@ -142,13 +142,12 @@ class EngineConfig:
 
     def kv_bytes_per_token(self, model_config) -> int:
         """KV bytes/token — the HBM-budget side of the block math."""
-        import jax.numpy as jnp
-        c = model_config
-        itemsize = jnp.dtype(c.dtype).itemsize
-        # k and v, and the indexer's one key head where there is one
-        row = 2 * c.kv_heads * c.head_dim \
-            + (c.index_dim if c.index_topk else 0)
-        return c.n_layers * row * itemsize
+        import jax
+        from ray_tpu.models import init_kv_cache
+        # what a token takes of every pool init_kv_cache makes (k and v,
+        # an indexer's keys, or one latent row), by shape alone
+        pools = jax.eval_shape(lambda: init_kv_cache(model_config, 1, 1))
+        return sum(p.size * p.dtype.itemsize for p in pools.values())
 
 
 def _unpack(rows, width: int, scalars: int):
@@ -323,6 +322,7 @@ class LLMEngine:
         compile_cache.stats()     # count loads vs compiles from here on
         window = ec.blocks_per_seq * ec.kv_block_size
         if (model_config.paged_block_r_prefill == 0
+                and not model_config.kv_lora_rank
                 and window >= 4096 and ec.prefill_chunk > 1):
             from ray_tpu.ops.paged_flash import autotune_paged_block_r
             rows = ec.prefill_chunk * (model_config.n_heads
@@ -480,13 +480,17 @@ class LLMEngine:
         # live page; P is the kernel's own choice for the decode (or
         # verify) call's shape
         from ray_tpu.ops.paged_flash import paged_pages_per_step
+        # a page's heads and row width as the pools have them (a latent
+        # cache: one pool, one "head")
+        pool = next(iter(self._cache.values()))
+        page_heads, row = pool.shape[2], pool.shape[4]
         self._decode_pages_per_step = paged_pages_per_step(
-            (ec.spec_tokens + 1)
-            * (model_config.n_heads // model_config.kv_heads),
-            model_config.kv_heads, ec.kv_block_size, model_config.head_dim,
+            (ec.spec_tokens + 1) * (model_config.n_heads // page_heads),
+            page_heads, ec.kv_block_size, row,
             model_config.dtype, ec.blocks_per_seq,
             block_r=model_config.paged_block_r,
-            chip="cpu" if model_config.paged_impl == "interpret" else None)
+            chip="cpu" if model_config.paged_impl == "interpret" else None,
+            pools=2 if "v" in self._cache else 1)
         self._decode_grid_steps = 0
         self._decode_grid_steps_live = 0
         # what a selecting, routing model did, from positions alone (no
@@ -930,8 +934,8 @@ class LLMEngine:
         slabs = {name: np.concatenate([p[name] for p in parts], axis=1)
                  for name in parts[0]}
         return pack_kv_blocks(
-            slabs.pop("k"), slabs.pop("v"), self.config.kv_wire,
-            extra=slabs)
+            slabs.pop("k", None), slabs.pop("v", None),
+            self.config.kv_wire, extra=slabs)
 
     def _adopt_blocks(self, kv: Dict[str, Any], plan: List[tuple]) -> None:
         """Write shipped pages into this pool (step thread): ``plan``
@@ -939,8 +943,9 @@ class LLMEngine:
         from ray_tpu.serve.disagg import unpack_kv_blocks, unpack_kv_extra
         np, T = self._np, self.config.blocks_per_seq
         slabs = dict(unpack_kv_extra(kv))
-        slabs["k"], slabs["v"] = unpack_kv_blocks(
-            kv, dtype=self._cache["k"].dtype)
+        if "k" in kv:
+            slabs["k"], slabs["v"] = unpack_kv_blocks(
+                kv, dtype=self._cache["k"].dtype)
         if set(slabs) != set(self._cache):
             raise ValueError(
                 f"shipped pools {sorted(slabs)} are not this engine's "
@@ -1762,8 +1767,8 @@ class LLMEngine:
             self._sparse["scored"] += visible * mc.n_layers
         self._sparse["visible"] += visible
         self._sparse["attended"] += attended
-        self._sparse["assigned"] += int(n.sum()) * mc.n_layers \
-            * mc.experts_per_token
+        self._sparse["assigned"] += int(n.sum()) * mc.experts_per_token \
+            * (mc.n_layers - mc.n_dense_layers)
 
     def _decode_once(self) -> None:
         if self.config.spec_tokens > 0:

@@ -383,3 +383,102 @@ def test_compiled_selecting_routing_step_reads_the_expert_stack_in_place(
     assert not [op for op in made if op not in (
         "parameter", "get-tuple-element", "bitcast", "scatter", "fusion")]
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+# ------------------------------------------------ the latent (MLA) forms
+@pytest.mark.parametrize("batch,chunk,block_r", [
+    (16, 1, 128),           # the cell's decode step: a row a head
+    (1, 2048, 512),         # its chunk: 262,144 rows in blocks of 512
+])
+def test_latent_kernel_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, batch, chunk, block_r):
+    """The paged kernel over the one-pool latent cache
+    (``v_width``: no V pool, a page's first 512 columns its value, a
+    fourth scalar with the live rows) at openPangu's widths: 128 heads
+    on one 640-wide row (512 + 64 up to whole lane tiles; a 576-wide
+    page copy is refused by Mosaic, "must be aligned to tiling (128)"),
+    the whole 36,865-page pool with a traced layer, no temporaries."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    t = 32768 // BLOCK
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda q, pool, bt, pos, lens, layer: paged_flash_attention(
+                q, pool, None, bt, pos, lens, layer=layer, block_r=block_r,
+                v_width=512, sm_scale=192 ** -0.5)
+        ).lower(s((batch, chunk, 128, 640)), s((5, 36865, 1, BLOCK, 640)),
+                s((batch, t), jnp.int32), s((batch, chunk), jnp.int32),
+                s((batch,), jnp.int32), s((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the result leaves in the caller's layout: one transpose of it
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= batch * chunk * 128 * 512 * 2 + (1 << 20)
+
+
+@pytest.mark.parametrize("entry", ["decode_step", "prefill"])
+def test_compiled_latent_step_copies_no_pool_and_slices_no_expert_stack(
+        entry, one_chip, monkeypatch):
+    """The latent forms (a leading dense layer in a scan of its own, the
+    expert layers after it, sandwich norms, a shared expert, 8 of 32
+    experts held) at narrowed widths: the TPU compiler accepts the whole
+    step; the ONE latent pool is an aliased input/output that only the
+    row scatters of the two scans write; the latent kernel is called
+    once a scan; the grouped products are fed the WHOLE ``[layers,
+    held, ...]`` stack; the temporaries stay small."""
+    from ray_tpu.models import (TransformerConfig, init_kv_cache,
+                                init_params)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = TransformerConfig(
+            vocab_size=512, d_model=1024, n_layers=3, n_heads=16,
+            head_dim=192, d_ff=2048, max_seq_len=4096, rotary_dim=64,
+            block_style="llama", dtype=jnp.bfloat16, remat_policy="none",
+            paged_impl="kernel", norm_eps=1e-5, q_lora_rank=256,
+            kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+            v_head_dim=128, sandwich_norm=True, n_dense_layers=1,
+            n_experts=32, experts_per_token=4, expert_width=512,
+            shared_expert_width=512, router_score="sigmoid",
+            routed_scale=2.5, experts_held=8, expert_first=8)
+        slots, table, blocks, chunk = 8, 256, 2049, 512
+
+        def shaped(tree):
+            return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip), tree)
+        params = shaped(jax.eval_shape(lambda: init_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+        cache = shaped(jax.eval_shape(
+            lambda: init_kv_cache(cfg, blocks, BLOCK)))
+        fn, rows = _engine_program(cfg, entry, slots, table, chunk)
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+            params, jax.ShapeDtypeStruct(rows, jnp.int32,
+                                         sharding=one_chip),
+            cache).compile()
+        text = compiled.as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert {k: v.shape for k, v in cache.items()} \
+        == {"latent": (3, blocks, 1, BLOCK, 640)}
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3
+    # no op makes one layer's held experts: [8, 1024, 512] or its down
+    assert not re.findall(r"= bf16\[8,(?:1024,512|512,1024)\]", text)
+    assert text.count("may-alias") + text.count("must-alias") >= 1
+    page = f"{blocks},1,{BLOCK},640]"
+    made = re.findall(
+        r"= bf16\[(?:\d+,)?" + re.escape(page) + r"\S* ([\w\-]+)\(", text)
+    assert not [op for op in made if op not in (
+        "parameter", "get-tuple-element", "bitcast", "scatter", "fusion")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
