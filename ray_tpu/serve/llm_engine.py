@@ -357,7 +357,10 @@ class LLMEngine:
         # fed by, [token, length, block-table row] a slot. Block-table
         # row 0s point idle slots at the reserved trash block, so their
         # (masked-garbage) decode writes never touch a live sequence's
-        # blocks.
+        # blocks. A slot whose prompt is still prefilling is such a
+        # slot: its row stays 0s until the prompt ends (a decode step
+        # writes every slot's position `length`, and position 0 of its
+        # first block is its first token's, or a shared prefix's).
         self._slot_rows = np.zeros((S, 2 + T), np.int32)
         self._last_tok = self._slot_rows[:, 0]
         self._seq_lens = self._slot_rows[:, 1]
@@ -458,6 +461,14 @@ class LLMEngine:
         # that program's *.dispatch and so while the device runs it; let
         # go when its fetch returns, it is freed with the device idle
         self._spent = None
+        # One program ahead: the chunk launched under the last tick's
+        # decode step and not fetched yet (_launch_chunk's record), the
+        # count of programs launched while another was still out, and
+        # the launches that had to wait for a fetch, by reason
+        self._ahead: Optional[tuple] = None
+        self._programs_ahead = 0
+        self._ahead_blocked = dict.fromkeys(
+            ("last_chunk", "no_backlog", "op_or_swap", "speculative"), 0)
         # host-to-device transfers the step thread staged: one a step
         # program (its integer inputs as one numpy array, uploaded by
         # the jitted call itself: on the chip that was 0.2 ms a program
@@ -465,9 +476,13 @@ class LLMEngine:
         # equals prefill_chunks + decode_steps
         self._h2d_transfers = 0
         # device-wall split (the kernel-vs-reference bench reads these):
-        # decode wall includes the result sync the step loop does anyway
+        # a program's wall runs from its staging, or from the fetch of
+        # the program before it where that came later (it was launched
+        # ahead and queued behind that one), to its own fetch, so the
+        # two walls never overlap and add up to no more than the ticks
         self._decode_wall_s = 0.0
         self._prefill_wall_s = 0.0
+        self._fetched_at = 0.0        # time.monotonic of the last fetch
         # length-aware work accounting: pages a lens-skipping kernel
         # touches per decode step vs the full table window — FLOPs are
         # proportional to pages, so live/window IS the measured
@@ -1026,6 +1041,8 @@ class LLMEngine:
             self._decode_steps = 0
             self._prefill_chunks = 0
             self._h2d_transfers = 0
+            self._programs_ahead = 0
+            self._ahead_blocked = dict.fromkeys(self._ahead_blocked, 0)
             self._decode_wall_s = self._prefill_wall_s = 0.0
             self._decode_pages_live = self._decode_pages_window = 0
             self._decode_grid_steps = self._decode_grid_steps_live = 0
@@ -1068,6 +1085,11 @@ class LLMEngine:
                 "decode_steps": self._decode_steps,
                 "prefill_chunks": self._prefill_chunks,
                 "h2d_transfers_total": self._h2d_transfers,
+                # of those programs, the ones launched while the one
+                # before them was still out (its fetch came after), and
+                # the launches that waited for the fetch, by reason
+                "programs_ahead_total": self._programs_ahead,
+                "ahead_blocked_total": dict(self._ahead_blocked),
                 # device-wall split + length-aware work fraction (the
                 # paged-kernel bench legs and perf gate read these)
                 "decode_wall_s": round(self._decode_wall_s, 4),
@@ -1123,7 +1145,8 @@ class LLMEngine:
                 # the tick's phases, name -> [count, seconds], and two
                 # sums of them: all tick time, and the part of it in
                 # which the device had nothing queued (from the end of
-                # a *.wait to the start of the next *.dispatch)
+                # the *.wait that fetched the last program out to the
+                # start of the next *.dispatch)
                 "phases": self._clock.totals(),
                 "tick_wall_s": self._clock.seconds("engine.tick"),
                 "host_gap_s": self._clock.gap_s,
@@ -1195,8 +1218,7 @@ class LLMEngine:
 
     def _has_work_locked(self) -> bool:
         return bool(self._pending) or bool(self._prefilling) \
-            or bool(self._ops) \
-            or self._staged_weights is not None \
+            or self._op_or_swap_pending_locked() \
             or any(r is not None for r in self._slots)
 
     def _on_dead(self, e: BaseException) -> None:
@@ -1249,21 +1271,104 @@ class LLMEngine:
                 op["done"].set()
 
     # one engine step: drain posted ops -> swap staged weights -> reap
-    # -> admit -> one prefill chunk -> one decode
+    # -> admit -> one prefill chunk -> one decode. The two programs run
+    # on the device in that order, and the step thread stays ONE PROGRAM
+    # AHEAD of it where it holds the next program's inputs in full: the
+    # decode step is launched before the chunk is fetched (unless the
+    # chunk ends its prompt: its token is a decode input), and the
+    # backlog's next chunk before the decode step is fetched. Each
+    # launch and each fetch-book-emit then passes under the other
+    # program's device time; at most one program is queued behind the
+    # running one, and what runs, and in which order, is the serial
+    # tick's.
     def _step(self) -> None:
         clock = self._clock
         clock.tick()
         with clock.phase("engine.tick"):
+            # the chunk launched under the last tick's decode step is
+            # this tick's chunk
+            chunk, self._ahead = self._ahead, None
+            had_chunk = chunk is not None
+            if had_chunk:
+                with self._lock:
+                    pending = self._op_or_swap_pending_locked()
+                if pending:
+                    # a posted op or a staged swap finds nothing out
+                    self._finish_chunk(chunk)
+                    chunk = None
             with clock.phase("engine.ops"):
-                self._drain_ops()
-                self._maybe_swap_weights()
-                self._reap_cancelled()
+                if chunk is None:
+                    self._drain_ops()
+                    self._maybe_swap_weights()
+                self._reap_cancelled(chunk)
             with clock.phase("engine.admit"):
                 self._admit()
-            self._prefill_one_chunk()
-            self._decode_once()
+            if not had_chunk:
+                chunk = self._launch_chunk()
+            if self.config.spec_tokens > 0:
+                # drafting reads the host's history: serial throughout
+                if chunk is not None:
+                    self._finish_chunk(chunk)
+                self._decode_speculative()
+            else:
+                self._chunk_then_decode(chunk)
             with clock.phase("engine.report"):
                 self._emit_stats()
+
+    def _chunk_then_decode(self, chunk: Optional[tuple]) -> None:
+        """Fetch this tick's chunk (launched, or None) and run its
+        decode step, each launch ahead of the other's fetch where the
+        inputs allow."""
+        decode = None
+        if chunk is not None:
+            req, start, n = chunk[:3]
+            active = self._decoding()
+            # a decode step's rows are every active slot's last token:
+            # the chunk that ends its prompt brings one of them
+            if active and self._go_ahead(
+                    "last_chunk" if start + n == len(req.prompt)
+                    else None):
+                decode = self._launch_decode(active)
+            self._finish_chunk(chunk)
+        if decode is None:
+            active = self._decoding()
+            if not active:
+                return
+            decode = self._launch_decode(active)
+        # the next chunk's row is prompt tokens, start, n and a table
+        # row: all booked by now. The request at the backlog's head
+        # stays there until its prompt ends (admission appends; a
+        # cancelled head is reaped at the next tick's top, so its next
+        # chunk is nobody's)
+        with self._lock:
+            head = self._prefilling[0] if self._prefilling else None
+            reason = "no_backlog" if head is None or head.cancelled \
+                else "op_or_swap" if self._op_or_swap_pending_locked() \
+                else None
+        if self._go_ahead(reason):
+            self._ahead = self._launch_chunk()
+        self._finish_decode(decode)
+
+    def _go_ahead(self, blocked: Optional[str]) -> bool:
+        """Book one launch that could pass ahead of the running
+        program's fetch: it does, or waits for the fetch and why."""
+        if blocked is None:
+            self._programs_ahead += 1
+            return True
+        self._ahead_blocked[blocked] += 1
+        return False
+
+    def _op_or_swap_pending_locked(self) -> bool:
+        return bool(self._ops) or self._staged_weights is not None
+
+    def _program_wall(self, t0: float) -> float:
+        """A fetch has just returned: the fetched program's wall, from
+        its staging at ``t0`` or from the fetch before it, whichever
+        came later (``prefill_wall_s`` / ``decode_wall_s``)."""
+        now = time.monotonic()
+        wall = now - max(t0, self._fetched_at)
+        self._fetched_at = now
+        return wall
 
     def _maybe_swap_weights(self) -> None:
         """Apply a staged weight refresh between decode steps: a pure
@@ -1304,10 +1409,15 @@ class LLMEngine:
             except Exception:
                 pass
 
-    def _reap_cancelled(self) -> None:
+    def _reap_cancelled(self, chunk: Optional[tuple] = None) -> None:
+        """Release what was cancelled since the last tick. The request
+        whose ``chunk`` is in flight keeps its slot and blocks until the
+        chunk is booked: the next tick reaps it, or the booking does
+        where the chunk ends its prompt."""
+        flying = chunk[0] if chunk is not None else None
         with self._lock:
             for req in list(self._prefilling):
-                if req.cancelled:
+                if req.cancelled and req is not flying:
                     self._prefilling.remove(req)
                     self._release_locked(req)
             for req in list(self._pending):
@@ -1382,8 +1492,6 @@ class LLMEngine:
                 self._pending.popleft()
                 req.slot = self._free_slots.pop()
                 self._block_tables[req.slot, :] = 0
-                self._block_tables[req.slot, :len(req.blocks)] = \
-                    req.blocks
                 self._seq_lens[req.slot] = 0
                 req.state = _PREFILL
                 self._slots[req.slot] = req
@@ -1618,11 +1726,14 @@ class LLMEngine:
             req.out.put(payload)
             self._release_locked(req)
 
-    def _prefill_one_chunk(self) -> None:
+    def _launch_chunk(self) -> Optional[tuple]:
+        """Stage and dispatch the next chunk of the request at the
+        backlog's head. Returns what :meth:`_finish_chunk` needs, the
+        request, ``start`` and ``n`` first, or None with no backlog."""
         with self._lock:
             req = self._prefilling[0] if self._prefilling else None
         if req is None:
-            return
+            return None
         np = self._np
         ec = self.config
         clock = self._clock
@@ -1633,7 +1744,7 @@ class LLMEngine:
             row = np.zeros((1, C + 2 + ec.blocks_per_seq), np.int32)
             row[0, :n] = req.prompt[start:start + n]
             row[0, C:C + 2] = start, n
-            row[0, C + 2:] = self._block_tables[req.slot]
+            row[0, C + 2:C + 2 + len(req.blocks)] = req.blocks
             t0w = time.time()
             t0 = time.monotonic()
             if req.t_first_chunk is None:
@@ -1643,24 +1754,34 @@ class LLMEngine:
                     # slot won, waiting behind other requests' chunks
                     req.trace.span(RT.PREFILL_WAIT,
                                    t0w - (t0 - req.t_slot), t0w)
+            self._prefill_chunks += 1
             self._h2d_transfers += 1
         with clock.phase("engine.prefill.dispatch"):
             # the call uploads the row, its one transfer, and holds the
             # only reference to the device's copy: nothing of it is left
             # to free on the next program's path; the last program's
-            # results go here, under this one (_spent)
+            # results go here, under this one (_spent), unless they are
+            # still to be fetched (then their record holds them)
             out = self._spent = self._jit_prefill(self._params, row,
                                                   self._cache)
-        if self.config.capture_logprobs:
-            tok, lp, self._cache = out
-        else:
-            tok, self._cache = out
-            lp = None
+            # the next launch takes the pool this one returns
+            if self.config.capture_logprobs:
+                tok, lp, self._cache = out
+            else:
+                tok, self._cache = out
+                lp = None
+        return req, start, n, t0, t0w, tok, lp
+
+    def _finish_chunk(self, chunk: tuple) -> None:
+        """Fetch a launched chunk's result and book it."""
+        req, start, n, t0, t0w, tok, lp = chunk
+        np = self._np
+        clock = self._clock
         with clock.phase("engine.prefill.wait"):
             tok = np.asarray(tok)
             if lp is not None:
                 lp = np.asarray(lp)
-        self._prefill_wall_s += time.monotonic() - t0
+        self._prefill_wall_s += self._program_wall(t0)
         with clock.phase("engine.prefill.book"):
             self._book_prefill(req, start, n, t0w, tok, lp)
 
@@ -1671,7 +1792,6 @@ class LLMEngine:
         ec = self.config
         req.prefill_pos += n
         req.n_chunks += 1
-        self._prefill_chunks += 1
         self._account_queries([start], [n])
         if req.trace is not None:
             req.trace.span(RT.PREFILL, t0w, time.time(),
@@ -1725,6 +1845,7 @@ class LLMEngine:
             req.state = _DECODE
             self._last_tok[req.slot] = first
             self._seq_lens[req.slot] = req.seq_len
+            self._block_tables[req.slot, :len(req.blocks)] = req.blocks
 
     def _account_decode_pages(self, live_lens) -> None:
         """Book one decode step's length-aware work: pages the paged
@@ -1770,15 +1891,15 @@ class LLMEngine:
         self._sparse["assigned"] += int(n.sum()) * mc.experts_per_token \
             * (mc.n_layers - mc.n_dense_layers)
 
-    def _decode_once(self) -> None:
-        if self.config.spec_tokens > 0:
-            self._decode_speculative()
-            return
+    def _decoding(self) -> List[_Request]:
         # only this thread moves a request in or out of a slot
-        active = [r for r in self._slots
-                  if r is not None and r.state == _DECODE]
-        if not active:
-            return
+        return [r for r in self._slots
+                if r is not None and r.state == _DECODE]
+
+    def _launch_decode(self, active: List[_Request]) -> tuple:
+        """Stage and dispatch one decode step over the slot array, for
+        the ``active`` requests (``_decoding()``, not empty). Returns
+        what :meth:`_finish_decode` needs."""
         clock = self._clock
         with clock.phase("engine.decode.stage"):
             with self._lock:
@@ -1799,15 +1920,22 @@ class LLMEngine:
         with clock.phase("engine.decode.dispatch"):
             res = self._spent = self._jit_decode(self._params, rows,
                                                  self._cache)
-        with clock.phase("engine.decode.wait"):
             if self.config.capture_logprobs:
                 out, lps, self._cache = res
-                lps = self._np.asarray(lps)
             else:
                 out, self._cache = res
                 lps = None
+        return active, t0, out, lps
+
+    def _finish_decode(self, decode: tuple) -> None:
+        """Fetch a launched decode step's tokens and emit them."""
+        active, t0, out, lps = decode
+        clock = self._clock
+        with clock.phase("engine.decode.wait"):
+            if lps is not None:
+                lps = self._np.asarray(lps)
             out = self._np.asarray(out)
-        self._decode_wall_s += time.monotonic() - t0
+        self._decode_wall_s += self._program_wall(t0)
         with clock.phase("engine.decode.emit"):
             self._emit_decoded(active, out, lps)
 
@@ -1879,10 +2007,11 @@ class LLMEngine:
         L = ec.spec_tokens + 1
         S = ec.decode_slots
         bs = ec.kv_block_size
-        active = [r for r in self._slots
-                  if r is not None and r.state == _DECODE]
+        active = self._decoding()
         if not active:
             return
+        # neither ahead of its tick's chunk nor with the next under it
+        self._go_ahead("speculative")
         clock = self._clock
         with clock.phase("engine.decode.stage"):
             with self._lock:
@@ -1923,7 +2052,7 @@ class LLMEngine:
                 self._params, rows, self._cache)
         with clock.phase("engine.decode.wait"):
             preds = np.asarray(preds)
-        self._decode_wall_s += time.monotonic() - t0
+        self._decode_wall_s += self._program_wall(t0)
         with clock.phase("engine.decode.emit"):
             self._emit_verified(active, preds, drafts, t0w)
 

@@ -225,10 +225,14 @@ class _Phase:
         stack.append(self.name)
         self.t0 = t = time.perf_counter()
         if self.parent is None:
-            c._root_t0 = c._idle_from = t
-        elif self.dispatch and c._idle_from is not None:
-            c.gap_s += t - c._idle_from
-            c._idle_from = None
+            c._root_t0 = t
+            # a program launched in the last root phase may still be out
+            c._idle_from = None if c._in_flight else t
+        elif self.dispatch:
+            c._in_flight += 1
+            if c._idle_from is not None:
+                c.gap_s += t - c._idle_from
+                c._idle_from = None
         return self
 
     def __exit__(self, *exc) -> None:
@@ -238,13 +242,16 @@ class _Phase:
         if self.ann is not None:
             self.ann.__exit__(*exc)
             self.ann = None
+        if c._in_flight and (self.wait or
+                             (self.dispatch and exc[0] is not None)):
+            c._in_flight -= 1       # fetched, or the launch itself raised
         if self.t0 < c._since:
             return              # began before a reset: not in its books
         self.count += 1
         self.seconds += t1 - self.t0
         c.ring.append((self.name, c.tick_no, self.t0, t1, self.parent))
         if self.wait:
-            if c._root_t0 >= c._since:
+            if c._root_t0 >= c._since and not c._in_flight:
                 c._idle_from = t1
         elif self.parent is None and c._idle_from is not None:
             c.gap_s += t1 - c._idle_from
@@ -268,9 +275,15 @@ class PhaseClock:
     holds the span in its host plane and joins the ring's entry by tick.
 
     ``gap_s`` is the clock's account of the device having nothing
-    queued: inside an outermost phase, the time not between the start
-    of a ``*.dispatch`` phase and the end of the ``*.wait`` phase that
-    follows it.
+    queued. A ``*.dispatch`` phase launches a program (unless it
+    raises) and a ``*.wait`` phase fetches the oldest one out;
+    ``gap_s`` is the time inside an outermost phase with none out:
+    from the end of the ``*.wait`` that
+    brought the count to zero (or the outermost phase's start, if none
+    was out then) to the start of the next ``*.dispatch``. A loop with
+    one program out at a time reads the time outside [dispatch start,
+    wait end]; a program launched in one outermost phase and fetched in
+    the next keeps the count up between them.
 
     Other threads may read ``totals()`` and ``spans()`` at any time.
     """
@@ -285,6 +298,7 @@ class PhaseClock:
         self._phases: Dict[str, _Phase] = {}
         self._stack: List[str] = []
         self._idle_from: Optional[float] = None
+        self._in_flight = 0     # programs dispatched and not yet waited for
         self._root_t0 = self._since = 0.0
         self._annotation = TraceAnnotation
         self._step_annotation = StepTraceAnnotation if steps else None
@@ -321,6 +335,7 @@ class PhaseClock:
         phase another thread is inside of stays out of the new books."""
         self._since = time.perf_counter()
         self._idle_from = None
+        self._in_flight = 0
         for p in list(self._phases.values()):
             p.count, p.seconds = 0, 0.0
         self.gap_s = 0.0

@@ -20,6 +20,7 @@ import json
 import os
 import tempfile
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -130,9 +131,21 @@ def test_weight_sync_raced_against_decode_keeps_versions_consistent():
     ref_engine.shutdown()
 
     rollout = RolloutEngine(cfg, params=params)
+    eng = rollout.engines[0]
     pub = WeightPublisher(rollout.engines,
                           block_size=cfg.quant_block_size)
     stream = rollout.stream_round(suffixes, collect=True)
+
+    def swapped_in(version, timeout_s=60.0):
+        # the engine holds ONE staged refresh and swaps it in at the
+        # top of a tick with no program out: a second publish staged
+        # before that replaces the first and the two count as one swap.
+        # Blocks can land several at once, so let each publish land
+        # before the next (decode goes on meanwhile: no slot drains)
+        deadline = time.monotonic() + timeout_s
+        while eng.stats()["weight_version"] < version:
+            assert time.monotonic() < deadline, "the swap never landed"
+            time.sleep(0.002)
 
     # race: a publish fires the moment each of the first 3 blocks
     # lands, while the other trajectories are still mid-decode
@@ -143,6 +156,7 @@ def test_weight_sync_raced_against_decode_keeps_versions_consistent():
             t = threading.Thread(target=pub.publish, args=(params,))
             t.start()
             t.join()
+            swapped_in(pub.version)
     assert pub.stats()["publishes"] >= 3
 
     stamped = set()
@@ -162,7 +176,6 @@ def test_weight_sync_raced_against_decode_keeps_versions_consistent():
     assert max(stamped) >= 1, \
         "no token ever decoded under a synced version — race vacuous"
 
-    eng = rollout.engines[0]
     s = eng.stats()
     assert s["weight_swaps"] == pub.stats()["publishes"]
     assert s["weight_version"] == pub.version

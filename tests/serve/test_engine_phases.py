@@ -107,6 +107,52 @@ def test_every_ticks_phases_lie_inside_it_and_cover_it(served):
         total += hi - lo
     # what no phase covers is the glue between them
     assert covered >= 0.8 * total
+    # one program ahead: a launch (*.dispatch) with the program before
+    # it still out, never a third; a chunk launched under a decode step
+    # carries that tick on its dispatch and the next on its wait
+    out, kinds, crossed = [], set(), 0
+    for name, tick, t0, t1, _ in sorted(eng._clock.spans(),
+                                        key=lambda s: s[2]):
+        kind, _, what = name[len("engine."):].partition(".")
+        if what == "dispatch":
+            assert len(out) <= 1
+            if out:
+                kinds.add((out[0][0], kind))
+            out.append((kind, tick))
+        elif what == "wait":
+            was, launched = out.pop(0)          # fetched in launch order
+            assert was == kind and tick - launched in (0, 1)
+            assert tick == launched or kind == "prefill"
+            crossed += tick - launched
+    assert not out
+    assert kinds == {("prefill", "decode"), ("decode", "prefill")}
+    st = eng.stats()
+    assert 0 < crossed <= st["programs_ahead_total"] \
+        < st["prefill_chunks"] + st["decode_steps"]
+
+
+def _idle_inside_ticks(spans):
+    """Seconds inside ``engine.tick`` spans with no program out: what
+    ``host_gap_s`` means, recomputed from the ring."""
+    marks = []
+    for name, _, t0, t1, parent in spans:
+        if name.endswith(".dispatch"):
+            marks.append((t0, 1))
+        elif name.endswith(".wait"):
+            marks.append((t1, -1))
+    idle = 0.0
+    for lo, hi in sorted((t0, t1) for name, _, t0, t1, _ in spans
+                         if name == "engine.tick"):
+        flying = sum(d for t, d in marks if t < lo)
+        last = lo
+        for t, d in sorted(m for m in marks if lo <= m[0] <= hi):
+            if not flying:
+                idle += t - last
+            flying += d
+            last = t
+        if not flying:
+            idle += hi - last
+    return idle
 
 
 def test_stats_carry_the_phase_table_and_the_gap(served):
@@ -125,14 +171,30 @@ def test_stats_carry_the_phase_table_and_the_gap(served):
     assert st["phases"]["engine.prefill.wait"][0] == st["prefill_chunks"]
     assert st["tick_wall_s"] == st["phases"]["engine.tick"][1] > 0
     assert 0 < st["host_gap_s"] <= st["tick_wall_s"]
-    # the two outside clocks still read what they read: upload,
-    # dispatch and the blocking fetch
+    # the device idles only with no program out, two being out at times
+    spans = eng._clock.spans()
+    assert st["host_gap_s"] == pytest.approx(_idle_inside_ticks(spans),
+                                             abs=1e-4)
+    serial = st["tick_wall_s"] - sum(
+        st["phases"][f"engine.{name}.{p}"][1]
+        for name in ("prefill", "decode") for p in ("dispatch", "wait"))
+    assert st["host_gap_s"] < serial
+    # the two outside clocks: a program's wall ends with its fetch and
+    # starts with its staging or the fetch before it, whichever came
+    # later, so each holds its own wait, neither holds the other's and
+    # together they fit the ticks they ran in
+    ticks = sorted((t0, t1) for name, _, t0, t1, _ in spans
+                   if name == "engine.tick")
+    walls = st["prefill_wall_s"] + st["decode_wall_s"]
+    assert walls <= ticks[-1][1] - ticks[0][0] + 1e-3
     for name, wall in (("prefill", "prefill_wall_s"),
                        ("decode", "decode_wall_s")):
-        inner = sum(st["phases"][f"engine.{name}.{p}"][1]
-                    for p in ("dispatch", "wait"))
-        outer = inner + st["phases"][f"engine.{name}.stage"][1]
-        assert inner <= st[wall] + 1e-3 and st[wall] <= outer + 1e-3
+        assert st["phases"][f"engine.{name}.wait"][1] <= st[wall] + 1e-3
+    assert st["h2d_transfers_total"] \
+        == st["prefill_chunks"] + st["decode_steps"]
+    assert 0 < st["programs_ahead_total"] < st["h2d_transfers_total"]
+    assert set(st["ahead_blocked_total"]) == {
+        "last_chunk", "no_backlog", "op_or_swap", "speculative"}
     assert tracing.clocks()[eng._clock.owner] is eng._clock
 
 
@@ -193,6 +255,13 @@ def test_speculative_ticks_use_the_same_phase_names():
             == TICK_CHILDREN | {"engine.tick"}
         assert st["phases"]["engine.decode.wait"][0] == st["decode_steps"]
         assert st["host_gap_s"] <= st["tick_wall_s"]
+        # drafting reads the host's history: nothing goes ahead, and
+        # the gap is the tick outside [dispatch start, wait end]
+        assert st["programs_ahead_total"] == 0
+        assert st["ahead_blocked_total"]["speculative"] \
+            == st["decode_steps"]
+        assert st["host_gap_s"] == pytest.approx(
+            _idle_inside_ticks(eng._clock.spans()), abs=1e-4)
         spec = [s for tr in eng._tracer.recent for s in tr.spans
                 if s["phase"] == RT.SPEC_VERIFY]
         assert all("tick" in s["attrs"] for s in spec)

@@ -877,14 +877,7 @@ def test_warm_ticks_run_no_eager_device_op_on_the_step_thread(
                    _SHARED + [8, 9]]
         reqs = [eng.submit(p, 12) for p in prompts * 5]
         for req in reqs:
-            items = []
-            while True:
-                item = req.out.get(timeout=60)
-                if item is _DONE:
-                    break
-                assert not isinstance(item, BaseException), item
-                items.append(item)
-            assert len(items) == 12
+            assert len(_drain_stream(req)) == 12
         st = eng.stats()
     finally:
         jax.config.update("jax_transfer_guard_host_to_device", was)
@@ -894,3 +887,332 @@ def test_warm_ticks_run_no_eager_device_op_on_the_step_thread(
     assert st["h2d_transfers_total"] - before["h2d_transfers_total"] \
         == st["prefill_chunks"] + st["decode_steps"] \
         - before["prefill_chunks"] - before["decode_steps"]
+
+
+# ------------------------------------------------ one program ahead
+class _Flights:
+    """An engine's launches and fetches, logged from the step thread:
+    every program's kind and ``rows`` as the jitted call got them, how
+    many were out at once, a prompt's last chunk overtaken by a decode
+    step, and a fetch that found another weight version than its launch
+    had. ``on_chunk(req, last, ahead)`` / ``on_decode()`` run right
+    after that launch, with the program still out."""
+
+    def __init__(self, eng, on_chunk=None, on_decode=None):
+        self.eng = eng
+        self.programs, self.out = [], []
+        self.most_out = 0
+        self.overtaken, self.stale = [], []
+        self.first_tree = eng._params
+        self.on_chunk, self.on_decode = on_chunk, on_decode
+        for name in ("_jit_prefill", "_jit_decode", "_jit_verify"):
+            if getattr(eng, name) is not None:
+                setattr(eng, name, self._program(name[5:],
+                                                 getattr(eng, name)))
+        for name in ("_launch_chunk", "_launch_decode", "_finish_chunk",
+                     "_finish_decode"):
+            setattr(eng, name, getattr(self, name)(getattr(eng, name)))
+
+    def _program(self, kind, fn):
+        def call(params, rows, cache):
+            self.programs.append((kind, rows.tobytes()))
+            # the tree a program runs on is the version it is stamped by
+            if (params is self.first_tree) != \
+                    (self.eng._weight_version == 0):
+                self.stale.append((kind, "tree"))
+            return fn(params, rows, cache)
+        call._cache_size = fn._cache_size           # stats() reads it
+        return call
+
+    def _launched(self, kind, last):
+        self.out.append((kind, last, self.eng._weight_version))
+        self.most_out = max(self.most_out, len(self.out))
+
+    def _fetched(self, kind):
+        was, _, version = self.out.pop(0)           # in launch order
+        assert was == kind
+        if version != self.eng._weight_version:
+            self.stale.append((kind, version))
+
+    def _launch_chunk(self, launch):
+        def call():
+            ahead = bool(self.out)
+            rec = launch()
+            if rec is not None:
+                req, start, n = rec[:3]
+                last = start + n == len(req.prompt)
+                self._launched("prefill", last)
+                if self.on_chunk is not None:
+                    self.on_chunk(req, last, ahead)
+            return rec
+        return call
+
+    def _launch_decode(self, launch):
+        def call(active):
+            self.overtaken += [o for o in self.out if o[1]]
+            rec = launch(active)
+            self._launched("decode", False)
+            if self.on_decode is not None:
+                self.on_decode()
+            return rec
+        return call
+
+    def _finish_chunk(self, finish):
+        def call(rec):
+            self._fetched("prefill")
+            return finish(rec)
+        return call
+
+    def _finish_decode(self, finish):
+        def call(rec):
+            self._fetched("decode")
+            return finish(rec)
+        return call
+
+
+def _submit_together(eng, submits):
+    """Every request queued before the step thread sees the first: the
+    thread is held inside a posted op meanwhile, so what it admits, and
+    with it every program it launches, follows from the requests."""
+    started, gate = threading.Event(), threading.Event()
+
+    def hold():
+        started.set()
+        gate.wait(30)
+
+    holder = threading.Thread(target=eng._run_on_step_thread,
+                              args=(hold,))
+    holder.start()
+    assert started.wait(30)
+    reqs = [eng.submit(*a, **kw) for a, kw in submits]
+    gate.set()
+    holder.join(30)
+    return reqs
+
+
+def _drain_stream(req, timeout=60):
+    items = []
+    while True:
+        item = req.out.get(timeout=timeout)
+        if item is _DONE:
+            return items
+        assert not isinstance(item, BaseException), item
+        items.append(item)
+
+
+# two more prompts of three chunks
+_LONG2 = [(11 * i + 5) % 57 + 1 for i in range(19)]
+_LONG3 = [(13 * i + 7) % 53 + 1 for i in range(21)]
+
+
+def _together(prompts=_PROMPTS, **kw):
+    return [((p, 12), kw) for p in prompts]
+
+
+def test_launches_are_a_serial_replays_byte_for_byte():
+    """No cancels, swaps or ops: the programs the engine launches, kind
+    and ``rows``, are those of a strictly serial tick (the same engine
+    with every launch made to wait for the fetch before it), in the
+    same order; every stream is the parent's; and launches did pass
+    ahead, two programs out at most, no prompt's last chunk overtaken
+    by the decode step that needs its token."""
+    runs = {}
+    for serial in (False, True):
+        eng = _seeded_engine(0)
+        try:
+            if serial:
+                eng._go_ahead = lambda blocked: False
+            log = _Flights(eng)
+            served = [_drain_stream(r)
+                      for r in _submit_together(eng, _together())]
+            _assert_clean(eng, 4)
+            runs[serial] = (log, served, eng.stats(), eng.pool_audit())
+        finally:
+            eng.shutdown()
+    (log, served, st, audit), (slog, sserved, sst, saudit) = \
+        runs[False], runs[True]
+    assert served == sserved == _PARENT_TOKENS
+    assert audit == saudit == []
+    assert log.programs == slog.programs and len(log.programs) > 30
+    assert {k for k, _ in log.programs} == {"prefill", "decode"}
+    assert st["h2d_transfers_total"] == len(log.programs) \
+        == st["prefill_chunks"] + st["decode_steps"]
+    assert (log.most_out, slog.most_out) == (2, 1)
+    assert log.overtaken == slog.overtaken == []
+    assert 0 < st["programs_ahead_total"] < len(log.programs)
+    assert sst["programs_ahead_total"] == 0
+    blocked = st["ahead_blocked_total"]
+    assert blocked["last_chunk"] > 0 and blocked["no_backlog"] > 0
+    assert blocked["op_or_swap"] == blocked["speculative"] == 0
+    # every decode step is one chance for the next chunk, and every
+    # chunk that ran beside decoding sequences one for its decode step
+    assert st["programs_ahead_total"] + sum(blocked.values()) \
+        <= len(log.programs)
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 4])
+def test_nothing_goes_ahead_without_a_backlog_or_with_drafts(
+        spec_tokens):
+    """Single-chunk requests one at a time: the chunk ends its prompt
+    and no other is waiting, so each program is fetched before the next
+    is staged, as ever. With ``spec_tokens`` nothing goes ahead under
+    any traffic (drafting reads the host's history)."""
+    eng = _seeded_engine(spec_tokens)
+    try:
+        log = _Flights(eng)
+        for i in range(4):
+            assert len(list(eng.generate_sync(
+                [3 + i, 9, 11, 2 + i], max_new_tokens=6))) == 6
+        alone = eng.stats()
+        assert alone["programs_ahead_total"] == 0
+        reason = "speculative" if spec_tokens else "no_backlog"
+        assert alone["ahead_blocked_total"][reason] \
+            == alone["decode_steps"] > 0
+        assert sum(alone["ahead_blocked_total"].values()) \
+            == alone["decode_steps"]
+        served = [_drain_stream(r)
+                  for r in _submit_together(eng, _together())]
+        _assert_clean(eng, 4)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert served == _PARENT_TOKENS
+    assert (st["programs_ahead_total"] > 0) == (spec_tokens == 0)
+    assert log.most_out == (1 if spec_tokens else 2)
+    assert log.overtaken == []
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 4])
+def test_a_decode_step_leaves_a_prefilling_prompts_pages_alone(
+        spec_tokens):
+    """A decode step writes every slot's position ``length``; for a slot
+    whose prompt is still prefilling that is position 0, so its row
+    points at the trash block until the prompt ends. The three-chunk
+    prompt's first page reads the same served beside a decoding
+    sequence as served alone (it did not: position 0 held the K/V of
+    the slot's stale token)."""
+    pages = []
+    for beside in (False, True):
+        eng = _seeded_engine(spec_tokens)
+        try:
+            blocks = {}
+            book = eng._book_prefill
+
+            def booked(req, *a, _book=book, _blocks=blocks):
+                _blocks[tuple(req.prompt)] = list(req.blocks)
+                return _book(req, *a)
+
+            eng._book_prefill = booked
+            submits = _together([[7, 9, 11], _LONG] if beside
+                                else [_LONG])
+            served = [_drain_stream(r)
+                      for r in _submit_together(eng, submits)]
+            assert served[-1] == _PARENT_TOKENS[1]
+            first = blocks[tuple(_LONG)][0]
+            pages.append({name: np.asarray(pool)[:, first]
+                          for name, pool in eng._cache.items()})
+            beside_steps = eng.stats()["decode_steps"]
+        finally:
+            eng.shutdown()
+    assert beside_steps > 3         # the short prompt decoded meanwhile
+    for name in pages[0]:
+        assert np.array_equal(pages[0][name], pages[1][name]), name
+
+
+def test_eos_cancel_and_the_cap_with_a_program_out_leak_nothing():
+    """Streams that end while a program is out: a request cancelled
+    with its chunk launched ahead (one that ends its prompt, one that
+    does not), EOS as a prompt's first token and mid-stream, a cap of
+    one token. Slots, blocks and the trie come back whole, and the
+    pages still hold what the parent's did."""
+    eng = _seeded_engine(0)
+    cancel_on = {}            # rid -> cancel at its last chunk, or not
+
+    def on_chunk(req, last, ahead):
+        if ahead and cancel_on.get(req.rid) == last:
+            eng.cancel(req)
+            cancelled.append((req.rid, last))
+
+    cancelled = []
+    try:
+        log = _Flights(eng, on_chunk=on_chunk)
+        submits = _together() + [
+            ((_LONG2, 12), {}), ((_LONG3, 12), {}),
+            ((_LONG + [9], 12), {"eos_token_id": _PARENT_TOKENS[1][0]}),
+            ((_PROMPTS[2], 12), {"eos_token_id": _PARENT_TOKENS[2][0]}),
+            ((_PROMPTS[3], 12), {"eos_token_id": _PARENT_TOKENS[3][4]}),
+            ((_LONG2[::-1], 1), {}), ((_LONG3[::-1], 12), {})]
+        first = eng._rid + 1
+        cancel_on[first + 5], cancel_on[first + 6] = True, False
+        cancel_on[first + 11] = True
+        reqs = _submit_together(eng, submits)
+        streams = [_drain_stream(r) for r in reqs]
+        _assert_clean(eng, 4)
+        assert eng.pool_audit() == []
+        st = eng.stats()
+        again = [list(eng.generate_sync(p, max_new_tokens=12))
+                 for p in _PROMPTS]
+        assert eng.pool_audit() == []
+    finally:
+        eng.shutdown()
+    assert st["dead"] is None and log.overtaken == []
+    assert streams[:5] == again == _PARENT_TOKENS
+    assert sorted(cancelled) == [(first + 5, True), (first + 6, False),
+                                 (first + 11, True)]
+    assert streams[5] == streams[6] == streams[11] == []
+    assert streams[8] == [] and streams[9] == _PARENT_TOKENS[3][:4]
+    assert len(streams[10]) == 1 and st["programs_ahead_total"] > 0
+    assert st["h2d_transfers_total"] \
+        == st["prefill_chunks"] + st["decode_steps"]
+
+
+def test_version_stamps_across_a_swap_staged_with_a_chunk_out():
+    """A refresh staged while a chunk launched ahead is out, the chunk
+    ending its prompt: the chunk is fetched and its first token stamped
+    with the version it was launched on BEFORE the swap, and the swap
+    still lands before the next launch. A second refresh, staged under
+    a decode step with a backlog, keeps the next chunk from going
+    ahead. Every program ran on the tree of the version its tokens
+    carry."""
+    import jax
+    from ray_tpu.models import init_params
+    eng = _seeded_engine(0)
+    trees = [eng._inference_params(init_params(
+        eng.model_config, jax.random.PRNGKey(k))) for k in (1, 2)]
+    staged = {}
+
+    def on_chunk(req, last, ahead):
+        if ahead and last and 7 not in staged:
+            staged[7] = req.rid
+            eng.stage_weights(trees[0], version=7)
+
+    def on_decode():
+        if 7 in staged and 9 not in staged and eng._prefilling \
+                and eng._weight_version == 7:
+            staged[9] = True
+            eng.stage_weights(trees[1], version=9)
+
+    try:
+        log = _Flights(eng, on_chunk=on_chunk, on_decode=on_decode)
+        prompts = _PROMPTS + [_LONG2, _LONG3, _LONG2[::-1], _LONG3[::-1]]
+        reqs = _submit_together(eng, _together(prompts, detailed=True))
+        streams = {r.rid: _drain_stream(r) for r in reqs}
+        _assert_clean(eng, 4)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert log.stale == [] and log.overtaken == []
+    assert set(staged) == {7, 9}
+    assert (st["weight_swaps"], st["weight_version"]) == (2, 9)
+    assert st["ahead_blocked_total"]["op_or_swap"] >= 1
+    assert st["programs_ahead_total"] > 0
+    for items in streams.values():
+        assert len(items) == 12
+        versions = [v for _, v, _ in items]
+        assert versions == sorted(versions)
+        assert set(versions) <= {0, 7, 9}
+    # the chunk that was out when 7 was staged gave its token under 0
+    versions = [v for _, v, _ in streams[staged[7]]]
+    assert versions[0] == 0 and versions[1] >= 7
+    assert {v for items in streams.values() for _, v, _ in items} \
+        == {0, 7, 9}

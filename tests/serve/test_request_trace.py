@@ -369,10 +369,10 @@ def test_engine_death_ships_failed_span_naming_typed_error():
     try:
         list(eng.generate_sync([1, 2, 3], max_new_tokens=2))  # warm
 
-        def boom():
+        def boom(active):
             raise RuntimeError("injected decode fault")
 
-        eng._decode_once = boom
+        eng._launch_decode = boom
         rid = new_request_id()
         from ray_tpu.serve.llm_engine import EngineDeadError
         with pytest.raises(EngineDeadError):

@@ -3,6 +3,8 @@ ring's bound, the tick that joins a span to the profiler's annotation,
 the account of the device having nothing queued, and the registry."""
 import time
 
+import pytest
+
 from ray_tpu.util import tracing
 from ray_tpu.util.tracing import PhaseClock
 
@@ -86,6 +88,111 @@ def test_gap_is_the_tick_outside_dispatch_to_wait():
     with clock.phase("loop.tick"):
         time.sleep(0.002)
     assert clock.gap_s - before >= 0.002
+
+
+def _outside_dispatch_to_wait(clock, root):
+    """What ``gap_s`` read before it counted programs: every ``root``
+    span less its [``*.dispatch`` start, ``*.wait`` end]."""
+    by_tick = {}
+    for name, tick, t0, t1, _ in clock.spans():
+        by_tick.setdefault(tick, {})[name.rsplit(".", 1)[1]] = (t0, t1)
+    return sum(s[root][1] - s[root][0]
+               - (s["wait"][1] - s["dispatch"][0])
+               for s in by_tick.values())
+
+
+@pytest.mark.parametrize("loop,tail", [("loop", "emit"),
+                                       ("train", "tail")])
+def test_one_program_out_at_a_time_reads_what_it_read(loop, tail):
+    """The train step's pattern (dispatch, wait, tail) and the serial
+    tick's: the count of programs out is 0 or 1, and the gap is the
+    root span outside [dispatch start, wait end], to the clock's own
+    arithmetic."""
+    clock = PhaseClock(f"t-one-{loop}")
+    for _ in range(6):
+        clock.tick()
+        with clock.phase(f"{loop}.step"):
+            time.sleep(0.001)
+            with clock.phase(f"{loop}.dispatch"):
+                time.sleep(0.001)
+            with clock.phase(f"{loop}.wait"):
+                time.sleep(0.002)
+            with clock.phase(f"{loop}.{tail}"):
+                time.sleep(0.001)
+    assert clock.gap_s == pytest.approx(
+        _outside_dispatch_to_wait(clock, "step"), abs=1e-9)
+    assert clock.gap_s >= 6 * 0.002
+
+
+def test_with_two_programs_out_the_device_idles_only_at_none():
+    """One program ahead: B is dispatched while A is out, A is waited
+    for, C is dispatched in the next tick's place while B is out. The
+    gap is the time with nothing out, inside a root span; a program
+    out across two root spans keeps the second's start from counting
+    as idle."""
+    clock = PhaseClock("t-two")
+    idle = 0.0
+
+    def nap(s, counts):
+        nonlocal idle
+        t0 = time.perf_counter()
+        time.sleep(s)
+        if counts:
+            idle += time.perf_counter() - t0
+
+    clock.tick()
+    with clock.phase("loop.tick"):
+        nap(0.009, True)                    # nothing launched yet
+        with clock.phase("a.dispatch"):
+            nap(0.003, False)
+        with clock.phase("b.dispatch"):     # ahead: A still out
+            nap(0.003, False)
+        with clock.phase("a.wait"):
+            nap(0.006, False)
+        nap(0.009, False)                   # A fetched, B still out
+        with clock.phase("c.dispatch"):     # ahead again, under B
+            nap(0.003, False)
+        with clock.phase("b.wait"):
+            nap(0.006, False)
+        nap(0.006, False)                   # C is out
+    clock.tick()
+    with clock.phase("loop.tick"):
+        nap(0.009, False)                   # C still out at the start
+        with clock.phase("c.wait"):
+            nap(0.006, False)
+        nap(0.012, True)                    # none out: idle to the end
+    # (every stretch that must not count is 3 ms or more)
+    assert clock.gap_s == pytest.approx(idle, abs=2.5e-3)
+    assert clock.gap_s >= 0.021
+    # a reset with a program out: its wait is no launch's, and the next
+    # launch starts from none out
+    clock.tick()
+    with clock.phase("loop.tick"):
+        with clock.phase("a.dispatch"):
+            pass
+        clock.reset()
+        with clock.phase("a.wait"):
+            pass
+    idle = 0.0
+    clock.tick()
+    with clock.phase("loop.tick"):
+        nap(0.006, True)
+        with clock.phase("a.dispatch"):
+            pass
+        with clock.phase("a.wait"):
+            pass
+    assert clock.gap_s == pytest.approx(idle, abs=2.5e-3)
+    assert clock.gap_s >= 0.006
+    # a launch that raises put nothing out: the next tick idles again
+    clock.tick()
+    with pytest.raises(RuntimeError), clock.phase("loop.tick"):
+        with clock.phase("a.dispatch"):
+            raise RuntimeError("the launch failed")
+    clock.tick()
+    with clock.phase("loop.tick"):
+        nap(0.006, True)
+    assert clock.gap_s == pytest.approx(idle, abs=2.5e-3)
+    assert clock.gap_s >= 0.012
 
 
 def test_reset_zeroes_totals_and_keeps_counting_ticks():
