@@ -1,6 +1,8 @@
 """Numbers from the reduced profiler trace (``benchmarks/trace.py``):
-idle share, a kernel's share of device time and of its roofline, copies
-of the whole KV pool, exposed collectives. All None without a trace."""
+idle share and the part of it inside the program's own annotations, a
+kernel's share of device time and of its roofline, the share of the ops
+under named scopes and of those that write the KV pool, exposed
+collectives. All None without a trace."""
 import math
 
 from benchmarks import roofline, trace as T
@@ -50,13 +52,26 @@ def _paged_prefill_least(obs, tr):
         flops, nbytes, obs["device"]["kind"])
 
 
-def read(obs, what, module=None):
+def read(obs, what, module=None, scopes=()):
     tr = obs.get("trace")
     if not tr or obs["device"]["platform"] != "tpu":
         return None            # no trace, or a rehearsal's CPU trace
     window, busy = tr["window_s"], tr["busy_s"]
     if what == "idle_share":
         return 100.0 * (1.0 - busy / window)
+    if what == "idle_in_tick_share":
+        # of the idle seconds, those under any annotation of the thread
+        # that drives the device; None where it wrote none
+        by_phase = tr.get("idle_by_phase") or {}
+        idle = sum(by_phase.values())
+        if not idle or set(by_phase) <= {T.OUTSIDE}:
+            return None
+        return 100.0 * (1.0 - tr["idle_outside_tick_s"] / idle)
+    if what == "scope_share":
+        # busy time under these scope paths (none inside another)
+        found = [tr["by_scope"][s] for s in scopes
+                 if s in tr.get("by_scope", {})]
+        return 100.0 * sum(found) / busy if found else None
     if what == "exposed_collective_share":
         return 100.0 * tr["exposed_collective_s"] / window
     if what == "flash_share":
@@ -66,17 +81,26 @@ def read(obs, what, module=None):
         return 100.0 * least / spent if spent else None
     if what == "paged_share":
         return 100.0 * _kernel_seconds(tr, ("paged_attention",)) / busy
-    if what == "pool_copy_share":
+    if what == "kv_write_share":
+        # ops that write the KV pool: under these scopes (the rows'
+        # index arithmetic, a copy-on-write), or with a result of at
+        # least one layer of the pool. The in-place row scatters are
+        # found by that size alone: the compiler hands them the layer
+        # scan's own name (``jit(_decode_fn)/while``), not the scope
+        # they were written under (PERF.md section 6, PR 39)
         m = obs["model"]
         layer = m["num_kv_blocks"] * m["kv_heads"] * m["kv_block_size"] \
             * m["head_dim"]
-        sec = 0.0
+        sec = sum(tr.get("by_scope", {}).get(s, 0.0) for s in scopes)
         for op in tr["op_calls"].values():
             dims = T.result_shape(op["name"])[1]
+            scope = op.get("scope", "")
             if op["kind"] != "paged_attention" and dims \
-                    and math.prod(dims) >= layer:
-                sec += op["seconds"]
-        return 100.0 * sec / tr["chips"] / busy
+                    and math.prod(dims) >= layer and not any(
+                        scope == s or scope.startswith(s + "/")
+                        for s in scopes):
+                sec += op["seconds"] / tr["chips"]
+        return 100.0 * sec / busy if sec else None
     spent = _kernel_seconds(tr, ("paged_attention",), module)
     if not spent:
         return None

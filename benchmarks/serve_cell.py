@@ -24,19 +24,73 @@ from benchmarks.spec import log
 from benchmarks.serve_replica import BenchLLMServer
 
 APP = "bench_llm"
-COUNTERS = ("prefill_wall_s", "prefill_chunks", "decode_wall_s",
-            "decode_steps", "tokens_total", "prefix_hit_blocks_total",
-            "prompt_blocks_total", "decode_pages_live")
+#: what the configuration may add to ``obs["model"]``: a routed model's
+#: widths, for the readers that count its experts' work
+ROUTED_WIDTHS = ("d_model", "expert_width", "experts_per_token", "n_experts")
+
+
+def _is_number(v: Any) -> bool:
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
+def numerics(stats: Dict[str, Any]) -> Dict[str, Any]:
+    """The numeric part of one ``LLMEngine.stats()`` snapshot: every
+    numeric key, the numeric values of every dict-valued key one level
+    down (``ahead_blocked_total`` by reason, ``occupancy_hist`` by batch
+    size), and ``phases[name]`` as ``{"count", "seconds"}``. Strings,
+    lists and ``None`` are dropped."""
+    out: Dict[str, Any] = {}
+    for key, v in stats.items():
+        if key == "phases" and isinstance(v, dict):
+            out[key] = {name: {"count": c, "seconds": s}
+                        for name, (c, s) in v.items()}
+        elif key == "occupancy_hist" and isinstance(v, dict):
+            out[key] = {int(k): n for k, n in v.items()}
+        elif isinstance(v, dict):
+            out[key] = {k: n for k, n in v.items() if _is_number(n)}
+        elif _is_number(v):
+            out[key] = v
+    return out
 
 
 def counters_delta(after: Dict[str, Any], before: Dict[str, Any]
                    ) -> Dict[str, Any]:
-    """``LLMEngine.stats()`` counters, differenced over a stretch."""
-    out = {k: after[k] - before[k] for k in COUNTERS}
-    was = before["occupancy_hist"]
-    out["occupancy_hist"] = {int(k): v - was.get(k, 0)
-                             for k, v in after["occupancy_hist"].items()}
+    """``numerics`` of two ``LLMEngine.stats()`` snapshots, differenced
+    over the stretch between them (the window, or the traced stretch):
+    ``obs["engine"]`` and ``obs["trace"]["engine"]``. A key that either
+    snapshot lacks (an older program) is left out; one level down, a
+    counter or a phase that first shows inside the stretch counts from
+    nought. Counters (``*_total``, ``*_wall_s``, ``decode_steps``,
+    ``phases``, ...) difference to what the stretch did. Gauges
+    (``queue_depth``, ``free_blocks``, ``active_slots``, ``tokens_per_s``,
+    ``weight_version``, ...) difference to nothing useful: their values
+    when the window closed are ``obs["engine_end"]``, the same
+    ``numerics`` of that one snapshot."""
+    a, b = numerics(after), numerics(before)
+    nought = {"count": 0, "seconds": 0.0}
+    out: Dict[str, Any] = {}
+    for key, v in a.items():
+        if key not in b or isinstance(v, dict) != isinstance(b[key], dict):
+            continue
+        was = b[key]
+        if key == "phases":
+            out[key] = {name: {f: p[f] - was.get(name, nought)[f]
+                               for f in nought} for name, p in v.items()}
+        elif isinstance(v, dict):
+            out[key] = {k: n - was.get(k, 0) for k, n in v.items()}
+        else:
+            out[key] = v - was
     return out
+
+
+def phase_table(engine: Dict[str, Any]) -> str:
+    """``name ms each x count`` of a differenced stretch's phases, the
+    longest in total first: for the log, where a person reads it."""
+    rows = sorted((engine.get("phases") or {}).items(),
+                  key=lambda kv: -kv[1]["seconds"])
+    return ", ".join(
+        f"{name} {1e3 * p['seconds'] / p['count']:.3f} x {p['count']}"
+        for name, p in rows if p["count"])
 
 
 class Clients:
@@ -208,7 +262,7 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
     model = dict(cell.model_kwargs(), remat_policy="none",
                  max_seq_len=p["engine"]["max_seq_len"])
     # request tracing is on by default in the program and its cost is
-    # unresolved (ROADMAP A5): off in the runs that are judged
+    # unresolved (ROADMAP A0(c)): off in the runs that are judged
     engine = dict(p["engine"], enable_trace=bool(trace))
     dep = serve.deployment(
         BenchLLMServer, name=APP, num_replicas=1,
@@ -327,6 +381,7 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
         # the replica's own arrival-to-first-token times in the window
         "engine_ttft_s": [b - a for a, b in after["served"]],
         "engine": counters_delta(after["stats"], before["stats"]),
+        "engine_end": numerics(after["stats"]),
         "engine_config": dict(p["engine"]),
         "model": {"n_layers": model["n_layers"],
                   "n_heads": model["n_heads"],
@@ -335,7 +390,8 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
                   "kv_block_size": p["engine"]["kv_block_size"],
                   "num_kv_blocks": p["engine"]["num_kv_blocks"],
                   "prefill_chunk": p["engine"]["prefill_chunk"],
-                  "itemsize": 2 if model["dtype"] == "bfloat16" else 4},
+                  "itemsize": 2 if model["dtype"] == "bfloat16" else 4,
+                  **{k: model[k] for k in ROUTED_WIDTHS if k in model}},
         # pages some request holds when the window closes (the prefix
         # cache's unreferenced pages count as free: eviction takes them)
         "pool": {"live_pages": after["stats"]["total_blocks"]
@@ -346,6 +402,13 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
         "device": {**device, **end["memory"]},
         "trace": summary,
     }
+    log(f"{cell.name}: the window's phases: {phase_table(obs['engine'])}; "
+        f"programs ahead {obs['engine'].get('programs_ahead_total')}, "
+        f"blocked {obs['engine'].get('ahead_blocked_total')}")
+    if summary:
+        log(f"{cell.name}: the traced stretch's phases: "
+            f"{phase_table(summary['engine'])}; idle seconds by phase "
+            f"{ {k: round(v, 4) for k, v in summary['idle_by_phase'].items()} }")
     return {"correct": bool(verdict["ok"] and served["ok"] and engine_ok),
             "attempted": len(window), "failed": int(failed), "obs": obs,
             "beats": beats,

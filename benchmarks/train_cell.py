@@ -93,6 +93,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             n += 1
         tracer.close()
         summary = tracer.finish()
+        log(f"{cell.name}: traced idle seconds by phase "
+            f"{ {k: round(v, 5) for k, v in summary['idle_by_phase'].items()} }")
 
     # the loss must be finite at every step and must not have risen: on
     # random tokens it starts near ln(vocab) and can only creep down

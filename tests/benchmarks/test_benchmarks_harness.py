@@ -67,14 +67,26 @@ def test_traced_serve_rehearsal_prints_per_layer_metrics_and_a_breakdown():
     # the window, and the slowest tenth's mean a per-layer view of it (no
     # bound the contract allows holds nine requests; PERF.md section 2)
     assert "ttft_slow10_ms" in names
-    assert not names & {"ttft_p50_ms", "tpot_p90_ms", "setup_s"}
+    # and since PR 39 the judged time per token is the median request's,
+    # the 90th percentile a per-layer view of it
+    assert "tpot_p90_ms.chat" in names
+    assert not names & {"ttft_p50_ms", "tpot_p50_ms", "tpot_p90_ms",
+                        "setup_s"}
     # device numbers are not taken from a CPU
-    assert not names & {"device_idle_share.tpot", "pool_copy_share.tpot",
+    assert not names & {"device_idle_share.tpot", "kv_write_share.tpot",
+                        "idle_in_tick_share.tpot",
                         "paged_decode_roofline.tpot", "mfu"}
+    # what the engine's own books give is read on any platform
+    assert {"tick_ms.tpot", "host_ms_per_tick.tpot", "decode_launch_ms.tpot",
+            "programs_ahead_share.tpot", "ttft_queue_ms.ttft"} <= names
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     assert line["device"]["platform"] == "cpu"
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+    # an idle gap is named by the engine's phase it falls in, then the frame
+    gaps = [name for name, _ in line["breakdown"]["idle_gaps"]]
+    assert gaps and all(": " in name for name in gaps)
+    assert any(name.startswith("engine.") for name in gaps)
 
 
 def test_without_a_chip_it_fails_and_prints_no_result():
