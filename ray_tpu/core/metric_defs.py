@@ -107,10 +107,6 @@ class RuntimeMetrics:
         self.serve_queue_depth = Gauge(
             "serve_engine_queue_depth",
             "Requests waiting for a decode slot on this replica")
-        self.serve_batch_occupancy = Histogram(
-            "serve_engine_batch_occupancy",
-            "Active decode slots per batched decode step",
-            boundaries=[1, 2, 4, 8, 16, 32, 64])
         self.serve_ttft = Histogram(
             "serve_engine_ttft_seconds",
             "Submit-to-first-token latency (chunked prefill included)",

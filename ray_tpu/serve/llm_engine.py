@@ -483,6 +483,21 @@ class LLMEngine:
         self._decode_wall_s = 0.0
         self._prefill_wall_s = 0.0
         self._fetched_at = 0.0        # time.monotonic of the last fetch
+        # the same walls by what they hold (_program_wall): a fetch that
+        # blocked ends at its program's completion as the host can first
+        # know it, one that found its result ready (is_ready(), asked
+        # once before the fetch) says only that the host came late. Two
+        # programs that ran back to back finish one device time apart,
+        # so a program launched with another out, both fetches blocking,
+        # has its time on the DEVICE for a wall; one launched with none
+        # out is SERIAL, its wall launch + wait; (class, kind) ->
+        # [programs, seconds], a program with a ready fetch on either
+        # side in neither class
+        self._fetch_blocked = False   # the last fetch had to wait
+        self._found_ready = {"prefill": 0, "decode": 0}
+        self._class_walls = {(cls, kind): [0, 0.0]
+                             for cls in ("device", "serial")
+                             for kind in ("prefill", "decode")}
         # length-aware work accounting: pages a lens-skipping kernel
         # touches per decode step vs the full table window — FLOPs are
         # proportional to pages, so live/window IS the measured
@@ -1044,6 +1059,9 @@ class LLMEngine:
             self._programs_ahead = 0
             self._ahead_blocked = dict.fromkeys(self._ahead_blocked, 0)
             self._decode_wall_s = self._prefill_wall_s = 0.0
+            for booked in self._class_walls.values():
+                booked[:] = 0, 0.0
+            self._found_ready = dict.fromkeys(self._found_ready, 0)
             self._decode_pages_live = self._decode_pages_window = 0
             self._decode_grid_steps = self._decode_grid_steps_live = 0
             self._sparse.clear()
@@ -1094,6 +1112,14 @@ class LLMEngine:
                 # paged-kernel bench legs and perf gate read these)
                 "decode_wall_s": round(self._decode_wall_s, 4),
                 "prefill_wall_s": round(self._prefill_wall_s, 4),
+                # the walls by class: *_device_* of programs launched
+                # behind a running one (fetch to fetch, both blocking:
+                # their time on the device), *_serial_* of programs
+                # launched with none out (launch + wait); and the
+                # fetches that found their result ready, whose programs
+                # are in neither
+                **self._class_wall_stats(),
+                "fetch_found_ready_total": dict(self._found_ready),
                 "decode_pages_live": self._decode_pages_live,
                 "decode_pages_window": self._decode_pages_window,
                 "decode_block_work_frac": (
@@ -1361,14 +1387,33 @@ class LLMEngine:
     def _op_or_swap_pending_locked(self) -> bool:
         return bool(self._ops) or self._staged_weights is not None
 
-    def _program_wall(self, t0: float) -> float:
+    def _program_wall(self, kind: str, t0: float, ready: bool) -> float:
         """A fetch has just returned: the fetched program's wall, from
         its staging at ``t0`` or from the fetch before it, whichever
-        came later (``prefill_wall_s`` / ``decode_wall_s``)."""
+        came later (``prefill_wall_s`` / ``decode_wall_s``), booked by
+        class as well. ``ready``: the result was there before the fetch
+        asked."""
         now = time.monotonic()
+        ahead = t0 < self._fetched_at   # staged with the one before out
         wall = now - max(t0, self._fetched_at)
-        self._fetched_at = now
+        if ready:
+            self._found_ready[kind] += 1
+        elif not ahead or self._fetch_blocked:
+            booked = self._class_walls[
+                "device" if ahead else "serial", kind]
+            booked[0] += 1
+            booked[1] += wall
+        self._fetched_at, self._fetch_blocked = now, not ready
         return wall
+
+    def _class_wall_stats(self) -> Dict[str, Any]:
+        """``stats()``' eight keys of the walls by class."""
+        out = {}
+        for (cls, kind), (n, seconds) in self._class_walls.items():
+            unit = "chunks" if kind == "prefill" else "steps"
+            out[f"{kind}_{cls}_{unit}"] = n
+            out[f"{kind}_{cls}_s"] = round(seconds, 6)
+        return out
 
     def _maybe_swap_weights(self) -> None:
         """Apply a staged weight refresh between decode steps: a pure
@@ -1777,11 +1822,12 @@ class LLMEngine:
         req, start, n, t0, t0w, tok, lp = chunk
         np = self._np
         clock = self._clock
-        with clock.phase("engine.prefill.wait"):
+        ready = tok.is_ready()
+        with clock.phase("engine.prefill.wait", ready=int(ready)):
             tok = np.asarray(tok)
             if lp is not None:
                 lp = np.asarray(lp)
-        self._prefill_wall_s += self._program_wall(t0)
+        self._prefill_wall_s += self._program_wall("prefill", t0, ready)
         with clock.phase("engine.prefill.book"):
             self._book_prefill(req, start, n, t0w, tok, lp)
 
@@ -1905,12 +1951,6 @@ class LLMEngine:
             with self._lock:
                 self._decode_steps += 1
                 self._occupancy[len(active)] += 1
-                if self._metrics is not None:
-                    try:
-                        self._metrics.serve_batch_occupancy.observe(
-                            len(active))
-                    except Exception:
-                        pass
                 rows = self._slot_rows.copy()
             self._account_decode_pages(rows[:, 1] + 1)
             self._account_queries([r.seq_len for r in active],
@@ -1931,11 +1971,12 @@ class LLMEngine:
         """Fetch a launched decode step's tokens and emit them."""
         active, t0, out, lps = decode
         clock = self._clock
-        with clock.phase("engine.decode.wait"):
+        ready = (out if lps is None else lps).is_ready()
+        with clock.phase("engine.decode.wait", ready=int(ready)):
             if lps is not None:
                 lps = self._np.asarray(lps)
             out = self._np.asarray(out)
-        self._decode_wall_s += self._program_wall(t0)
+        self._decode_wall_s += self._program_wall("decode", t0, ready)
         with clock.phase("engine.decode.emit"):
             self._emit_decoded(active, out, lps)
 
@@ -2017,12 +2058,6 @@ class LLMEngine:
             with self._lock:
                 self._decode_steps += 1
                 self._occupancy[len(active)] += 1
-                if self._metrics is not None:
-                    try:
-                        self._metrics.serve_batch_occupancy.observe(
-                            len(active))
-                    except Exception:
-                        pass
                 # a row: [last token, drafts | start | n | table row]
                 rows = np.zeros((S, L + 2 + ec.blocks_per_seq), np.int32)
                 drafts: Dict[int, List[int]] = {}
@@ -2050,9 +2085,10 @@ class LLMEngine:
         with clock.phase("engine.decode.dispatch"):
             preds, self._cache = self._spent = self._jit_verify(
                 self._params, rows, self._cache)
-        with clock.phase("engine.decode.wait"):
+        ready = preds.is_ready()
+        with clock.phase("engine.decode.wait", ready=int(ready)):
             preds = np.asarray(preds)
-        self._decode_wall_s += self._program_wall(t0)
+        self._decode_wall_s += self._program_wall("decode", t0, ready)
         with clock.phase("engine.decode.emit"):
             self._emit_verified(active, preds, drafts, t0w)
 

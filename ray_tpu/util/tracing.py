@@ -197,16 +197,18 @@ class _Phase:
     """One named phase of one clock: its totals, and the context
     manager ``clock.phase(name)`` hands out (one object per name, so
     entering a phase allocates nothing but its ring entry and, under a
-    profiler session, its annotation). A phase never nests inside
+    profiler session, its annotation). ``stat`` is what this entry's
+    annotation carries beside ``tick``. A phase never nests inside
     itself."""
 
     __slots__ = ("clock", "name", "count", "seconds", "t0", "parent",
-                 "ann", "dispatch", "wait")
+                 "ann", "dispatch", "wait", "stat")
 
     def __init__(self, clock: "PhaseClock", name: str):
         self.clock, self.name = clock, name
         self.count, self.seconds = 0, 0.0
         self.t0, self.parent, self.ann = 0.0, None, None
+        self.stat: Dict[str, Any] = {}
         self.dispatch = name.endswith(".dispatch")
         self.wait = name.endswith(".wait")
 
@@ -217,10 +219,11 @@ class _Phase:
         # with no profiler session running this is a flag test
         if c._annotation.is_enabled():
             if self.parent is None and c._step_annotation is not None:
-                self.ann = c._step_annotation(self.name,
-                                              step_num=c.tick_no)
+                self.ann = c._step_annotation(
+                    self.name, step_num=c.tick_no, **self.stat)
             else:
-                self.ann = c._annotation(self.name, tick=c.tick_no)
+                self.ann = c._annotation(self.name, tick=c.tick_no,
+                                         **self.stat)
             self.ann.__enter__()
         stack.append(self.name)
         self.t0 = t = time.perf_counter()
@@ -273,6 +276,8 @@ class PhaseClock:
     carrying ``tick`` (``StepTraceAnnotation`` with ``step_num`` for the
     outermost phase of a ``steps=True`` clock), so a profiler trace
     holds the span in its host plane and joins the ring's entry by tick.
+    ``clock.phase(name, ready=1)`` puts a further stat on that one
+    entry's annotation; with no session it is kept and never read.
 
     ``gap_s`` is the clock's account of the device having nothing
     queued. A ``*.dispatch`` phase launches a program (unless it
@@ -311,10 +316,11 @@ class PhaseClock:
         self.tick_no += 1
         return self.tick_no
 
-    def phase(self, name: str) -> _Phase:
+    def phase(self, name: str, **stat: Any) -> _Phase:
         p = self._phases.get(name)
         if p is None:
             p = self._phases[name] = _Phase(self, name)
+        p.stat = stat
         return p
 
     def totals(self) -> Dict[str, List[float]]:

@@ -2,9 +2,14 @@
 it, ``host_gap_s`` within ``tick_wall_s``, the three parts of TTFT sum
 to it for every request with request tracing on and off, traced spans
 carry a tick the ring knows, the tick's annotations reach a profiler
-trace, and the compiled programs' ops carry the named scopes."""
+trace, the compiled programs' ops carry the named scopes, and each
+program's wall is booked by class from the engine's own fetches: its
+time on the device where it ran behind another, launch + wait where
+nothing was out, neither where a fetch found its result ready."""
 import glob
+import time
 
+import numpy as np
 import pytest
 
 import jax
@@ -269,8 +274,188 @@ def test_speculative_ticks_use_the_same_phase_names():
         eng.shutdown()
 
 
-def test_a_profiler_trace_holds_the_ticks_annotations(tmp_path):
+UNITS = {"prefill": "chunks", "decode": "steps"}
+ALL = {"prefill": "prefill_chunks", "decode": "decode_steps"}
+
+
+def _classes(st, kind):
+    """[programs, seconds] of ``kind``'s device and serial classes."""
+    return [[st[f"{kind}_{cls}_{UNITS[kind]}"], st[f"{kind}_{cls}_s"]]
+            for cls in ("device", "serial")]
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_the_walls_by_class_fit_inside_all_of_a_kind(served, kind):
+    eng, _, _ = served
+    st = eng.stats()
+    (device, device_s), (serial, serial_s) = _classes(st, kind)
+    found = st["fetch_found_ready_total"]
+    assert set(found) == {"prefill", "decode"}
+    assert 0 <= found[kind] <= st[ALL[kind]]
+    # a program is in neither class for a fetch that found its result
+    # ready: its own, or the one before a program launched behind it
+    left_out = st[ALL[kind]] - device - serial
+    assert found[kind] <= left_out <= sum(found.values())
+    if not sum(found.values()):
+        assert device + serial == st[ALL[kind]]
+        assert device_s + serial_s == pytest.approx(st[f"{kind}_wall_s"],
+                                                    abs=2e-4)
+    assert device_s + serial_s <= st[f"{kind}_wall_s"] + 2e-4
+    assert (device_s > 0) == (device > 0) and (serial_s > 0) == (serial > 0)
+
+
+class _Result:
+    """A step program's result as the fetch sees it: ``is_ready()`` says
+    what ``ready[kind]`` holds when it is asked, and a fetch that has to
+    wait takes 2 ms (a tick's other phases are tenths of that)."""
+
+    def __init__(self, array, kind, ready):
+        self.array, self.kind, self.ready = array, kind, ready
+
+    def is_ready(self):
+        return self.ready[self.kind]
+
+    def __array__(self, dtype=None, copy=None):
+        if not self.is_ready():
+            time.sleep(0.002)
+        return np.asarray(self.array, dtype)
+
+
+def _stub_results(eng, ready):
+    """Every result the step thread fetches answers ``is_ready()`` from
+    ``ready``: {"prefill": bool, "decode": bool}."""
+    def program(kind, fn):
+        def call(params, rows, cache):
+            first, *rest = fn(params, rows, cache)
+            return (_Result(first, kind, ready), *rest)
+        call._cache_size = fn._cache_size           # stats() reads it
+        return call
+    eng._jit_prefill = program("prefill", eng._jit_prefill)
+    if eng._jit_verify is not None:
+        eng._jit_verify = program("decode", eng._jit_verify)
+    else:
+        eng._jit_decode = program("decode", eng._jit_decode)
+
+
+def _ahead_walls(spans):
+    """kind -> [programs, seconds]: every program whose ``*.dispatch``
+    began with the program before it not yet fetched, from that
+    program's fetch (its ``*.wait``'s end) to its own."""
+    events = sorted((s for s in spans
+                     if s[0].endswith((".dispatch", ".wait"))),
+                    key=lambda s: s[2])
+    out = {"prefill": [0, 0.0], "decode": [0, 0.0]}
+    flying, ahead, last_fetch = 0, [], None
+    for name, _, t0, t1, _ in events:
+        kind, _, what = name[len("engine."):].partition(".")
+        if what == "dispatch":
+            ahead.append(flying > 0)
+            flying += 1
+        else:
+            flying -= 1
+            if ahead.pop(0):
+                out[kind][0] += 1
+                out[kind][1] += t1 - last_fetch
+            last_fetch = t1
+    return out
+
+
+@pytest.mark.parametrize("serial", [False, True], ids=["ahead", "serial"])
+def test_a_program_behind_another_books_its_device_time_the_rest_serial(
+        serial):
+    """Every fetch blocks (stubbed): a program launched ahead is in the
+    device class with the fetch-to-fetch wall, every other in the
+    serial class, none left out; with nothing launched ahead all of
+    them are serial."""
+    eng = _engine()
+    try:
+        eng.warmup()
+        _stub_results(eng, {"prefill": False, "decode": False})
+        if serial:
+            eng._go_ahead = lambda blocked: False
+        _serve(eng)
+        st = eng.stats()
+        ring = _ahead_walls(eng._clock.spans())
+    finally:
+        eng.shutdown()
+    assert st["fetch_found_ready_total"] == {"prefill": 0, "decode": 0}
+    ahead = 0
+    for kind in ("prefill", "decode"):
+        (device, device_s), (serial_n, serial_s) = _classes(st, kind)
+        assert device + serial_n == st[ALL[kind]] and serial_n > 0
+        assert device_s + serial_s == pytest.approx(
+            st[f"{kind}_wall_s"], abs=2e-4)
+        assert device == ring[kind][0]
+        # fetch to fetch (the ring's stamps are further calls of the
+        # same clock), not staging to fetch, which holds the wait of
+        # the program before as well: 2 ms more each
+        assert device_s == pytest.approx(ring[kind][1],
+                                         abs=5e-4 * max(device, 1))
+        assert (device > 0) == (not serial)
+        ahead += device
+    assert ahead == st["programs_ahead_total"]
+
+
+@pytest.mark.parametrize("kind,other", [("prefill", "decode"),
+                                        ("decode", "prefill")])
+def test_a_fetch_found_ready_keeps_both_its_sides_out(kind, other):
+    """Every fetch of ``kind`` finds its result ready: none of its
+    programs is a sample, and none of the ``other`` kind launched
+    behind one of them either (that wall does not start at a
+    completion); the others launched with nothing out stay serial."""
+    eng = _engine()
+    try:
+        eng.warmup()
+        _stub_results(eng, {kind: True, other: False})
+        _serve(eng)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert st["fetch_found_ready_total"] == {kind: st[ALL[kind]], other: 0}
+    assert _classes(st, kind) == [[0, 0.0], [0, 0.0]]
+    (device, device_s), (serial, serial_s) = _classes(st, other)
+    assert (device, device_s) == (0, 0.0)
+    assert 0 < serial < st[ALL[other]] and serial_s > 0
+    assert st["programs_ahead_total"] > 0
+    # the walls the older metrics read hold every program as before
+    assert st["prefill_wall_s"] > 0 and st["decode_wall_s"] > 0
+
+
+def test_speculation_books_no_device_sample():
+    eng = _engine(spec_tokens=2)
+    try:
+        eng.warmup()
+        _stub_results(eng, {"prefill": False, "decode": False})
+        _serve(eng, n=3)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    for kind in ("prefill", "decode"):
+        (device, device_s), (serial, _) = _classes(st, kind)
+        assert (device, device_s) == (0, 0.0)
+        assert serial == st[ALL[kind]] > 0
+
+
+def test_warmup_resets_the_classes_and_the_ready_count(served):
+    eng, _, _ = served
+    st = eng.stats()
+    assert sum(n for kind in ALL for n, _ in _classes(st, kind)) \
+        + sum(st["fetch_found_ready_total"].values()) > 0
+    eng.warmup()
+    st = eng.stats()
+    assert st["fetch_found_ready_total"] == {"prefill": 0, "decode": 0}
+    for kind in ALL:
+        assert _classes(st, kind) == [[0, 0.0], [0, 0.0]]
+        assert st[ALL[kind]] == 0
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One profiler session over two served requests: the ring's (name,
+    tick) pairs and, by name, the engine's annotations in the trace as
+    (stats, start, end)."""
     from jax.profiler import ProfileData
+    tmp_path = tmp_path_factory.mktemp("profile")
     eng = _engine(enable_trace=False)
     try:
         eng.warmup()
@@ -280,6 +465,7 @@ def test_a_profiler_trace_holds_the_ticks_annotations(tmp_path):
         finally:
             jax.profiler.stop_trace()
         ring = {(s[0], s[1]) for s in eng._clock.spans()}
+        found = sum(eng.stats()["fetch_found_ready_total"].values())
     finally:
         eng.shutdown()
     path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
@@ -290,9 +476,16 @@ def test_a_profiler_trace_holds_the_ticks_annotations(tmp_path):
         for line in plane.lines:
             for e in line.events:
                 if e.name.startswith("engine."):
-                    tick = dict(e.stats)["tick"]
                     seen.setdefault(e.name, []).append(
-                        (tick, e.start_ns, e.start_ns + e.duration_ns))
+                        (dict(e.stats), e.start_ns,
+                         e.start_ns + e.duration_ns))
+    return ring, seen, found
+
+
+def test_a_profiler_trace_holds_the_ticks_annotations(profiled):
+    ring, seen, _ = profiled
+    seen = {name: [(stats["tick"], a, b) for stats, a, b in spans]
+            for name, spans in seen.items()}
     assert {"engine.tick", "engine.decode.wait", "engine.prefill.dispatch",
             "engine.admit"} <= set(seen)
     # the annotation and the ring's entry are one span: joined by tick
@@ -302,6 +495,23 @@ def test_a_profiler_trace_holds_the_ticks_annotations(tmp_path):
     for t, a, b in seen["engine.decode.wait"]:
         if t in ticks:                # a tick the session saw whole
             assert ticks[t][0] <= a and b <= ticks[t][1]
+
+
+def test_a_wait_annotation_says_whether_its_fetch_found_the_result(
+        profiled):
+    """``ready`` beside ``tick`` on every ``*.wait`` and on no other
+    phase: a reader of the trace knows which waits end at a completion;
+    as many say 1 as the engine counted."""
+    _, seen, found = profiled
+    said = 0
+    for name, spans in seen.items():
+        for stats, _, _ in spans:
+            assert ("ready" in stats) == name.endswith(".wait"), name
+            if "ready" in stats:
+                assert stats["ready"] in (0, 1)
+                said += stats["ready"]
+    assert seen["engine.decode.wait"] and seen["engine.prefill.wait"]
+    assert said == found
 
 
 def test_compiled_programs_ops_carry_the_scopes():

@@ -229,3 +229,55 @@ def test_a_raising_phase_is_closed_and_counted():
     with clock.phase("loop.tick"):         # the stack is empty again
         pass
     assert clock.spans()[-1][4] is None
+
+
+@pytest.mark.parametrize("steps", [False, True], ids=["plain", "steps"])
+def test_a_phases_stat_reaches_its_annotation_and_nothing_else(steps):
+    """``clock.phase(name, ready=1)``: under a profiler session that
+    entry's annotation carries ``ready`` beside ``tick`` (``step_num``
+    on a ``steps`` clock's outermost phase), the same phase's next entry
+    does not; with no session no annotation is built and the totals are
+    what they are without a stat."""
+    class Fake:
+        """Stands where ``jax.profiler.TraceAnnotation`` does: keeps
+        what each one was built with, under a session switched by
+        ``on``."""
+        on, built = False, []
+
+        def __init__(self, name, **stats):
+            Fake.built.append((name, stats))
+
+        is_enabled = classmethod(lambda cls: cls.on)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    clock = PhaseClock(f"t-stat-{steps}", steps=steps)
+    clock._annotation = Fake
+    if steps:
+        clock._step_annotation = Fake
+    number = "step_num" if steps else "tick"
+
+    def run():
+        clock.tick()
+        with clock.phase("loop.tick", launched=2):
+            with clock.phase("loop.wait", ready=1):
+                pass
+            with clock.phase("loop.wait"):
+                pass
+
+    run()                                   # no session: a flag test
+    assert Fake.built == []
+    assert clock.totals()["loop.wait"][0] == 2
+    Fake.on = True
+    run()
+    assert Fake.built == [
+        ("loop.tick", {number: 2, "launched": 2}),
+        ("loop.wait", {"tick": 2, "ready": 1}),
+        ("loop.wait", {"tick": 2})]
+    assert clock.totals()["loop.wait"][0] == 4
+    assert [s[:2] for s in clock.spans()[-3:]] == [
+        ("loop.wait", 2), ("loop.wait", 2), ("loop.tick", 2)]
