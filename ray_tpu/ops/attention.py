@@ -155,7 +155,9 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     kernel skips whole blocks past it, making decode work proportional
     to live tokens instead of the table window. ``lens = None``
     derives a conservative bound from ``q_positions`` (every key the
-    queries may attend).
+    queries may attend). A sequence that holds nothing (``lens <= 0``,
+    its rows below position 0) comes back zero under every ``impl``,
+    and the kernel reads no page of its table.
 
     The reference path is the pure-XLA gather (one ``take`` per
     sequence over its block table, f32 softmax): work is
@@ -198,14 +200,19 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                        k.astype(jnp.float32)) * sm_scale
         s = jnp.where(mask[:, None, None], s, _NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v.astype(jnp.float32))
-        return o.reshape(b, c, h, d).astype(q.dtype)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * sm_scale
-    s = jnp.where(mask[:, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
+        o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v.astype(jnp.float32)) \
+            .reshape(b, c, h, d)
+    else:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                       k.astype(jnp.float32)) * sm_scale
+        s = jnp.where(mask[:, None], s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+    # a row below position 0 sees no key (every row of a sequence that
+    # holds nothing, which the kernel skips): zeros as the kernel's, not
+    # the mean of the window that a softmax of equal scores would be
+    return jnp.where((q_positions >= 0)[:, :, None, None], o,
+                     0.0).astype(q.dtype)
 
 
 def _flash_cannot(q, k, mask, block_q: int, block_k: int) -> Optional[str]:

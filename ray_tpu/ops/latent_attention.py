@@ -137,5 +137,10 @@ def _blocked(q, q_rope, pool, block_tables, q_positions, layer, lens,
                 jnp.zeros((b, h, c), f32),
                 jnp.zeros((b, h, c, rank), f32))
         _, l, acc = jax.lax.fori_loop(0, live, fold, init)
-        out = (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(dt)
+        # a row that met no key is zero whatever its window's pages
+        # hold (a zero weight times a NaN there would be a NaN)
+        seen = l > 0.0
+        out = jnp.where(seen[..., None],
+                        acc / jnp.where(seen, l, 1.0)[..., None],
+                        0.0).astype(dt)
         return out.transpose(0, 2, 1, 3)                 # [B, C, H, rank]

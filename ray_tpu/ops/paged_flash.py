@@ -37,10 +37,13 @@ step**:
   fixed cost is paid once for P pages, and the products are P·block_size
   key columns wide instead of one page's;
 - groups past ``last = (pages − 1) // P`` (``pages =
-  ceil(lens[b] / block_size)``, at least the one page an idle slot
-  runs) are **skipped**: no copy is started for them and ``pl.when``
-  skips the body, so a dead step costs what the three BlockSpec
-  operands' bookkeeping costs (~0.05 µs). In a partly live last group
+  ceil(lens[b] / block_size)``) are **skipped**: no copy is started
+  for them and ``pl.when`` skips the body, so a dead step costs what
+  the three BlockSpec operands' bookkeeping costs (~0.05 µs). A
+  sequence with ``lens[b] <= 0`` holds nothing (the engine's decode
+  slot with no sequence in it): ``pages = 0`` and ``last = −1``, every
+  step of it is a dead one, no page of its table is read and its rows
+  come back zero. In a partly live last group
   the pages past the last live one are not fetched either: their key
   columns are masked out (``key position < pages · block_size``)
   beside the causal mask, and their V rows are zeroed (a zero weight
@@ -123,13 +126,13 @@ def sublane_tile(dtype) -> int:
 
 
 def paged_work_pages(lens, block_size: int):
-    """Pages a length-aware kernel touches per sequence:
-    ``max(ceil(lens / block_size), 1)`` (an idle ``lens = 0`` slot still
-    runs its one trash page so the batch shape stays fixed). Works on
+    """Pages a length-aware kernel reads per sequence:
+    ``ceil(lens / block_size)``, and 0 for ``lens <= 0`` (a decode slot
+    that holds no sequence: the kernel reads no page for it). Works on
     numpy and jax arrays — the engine's FLOP accounting and the bench's
     work-reduction math share this definition with the kernel."""
-    return ((lens + block_size - 1) // block_size).clip(min=1) \
-        if hasattr(lens, "clip") else max(-(-lens // block_size), 1)
+    return ((lens + block_size - 1) // block_size).clip(min=0) \
+        if hasattr(lens, "clip") else max(-(-lens // block_size), 0)
 
 
 def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
@@ -160,9 +163,9 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
     b, g, t = pl.program_id(0), pl.program_id(1), pl.program_id(3)
     nt = pl.num_programs(3)
     # Length-aware skipping: groups past the last live one fetch
-    # nothing and fold nothing. A sequence holds at least the one page
-    # an idle slot runs and at most the table.
-    pages = jnp.clip(pl.cdiv(lens_ref[b], bs), 1, slots)
+    # nothing and fold nothing. A sequence holds at most the table, and
+    # none of it where lens <= 0: last = -1, every step a dead one.
+    pages = jnp.clip(pl.cdiv(lens_ref[b], bs), 0, slots)
     if v_width is not None:
         block_r = q_ref.shape[2]
         pages = jnp.where(pl.program_id(2) * block_r < rows_ref[b],
@@ -211,10 +214,7 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
-        if v_width is None:
-            fetch(0, 0)
-        else:
-            pl.when(pages > 0)(lambda: fetch(0, 0))
+        pl.when(pages > 0)(lambda: fetch(0, 0))
 
     @pl.when(t <= last)
     def _compute():
@@ -265,7 +265,8 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
     def _final():
         for i in range(hb):
             l = l_s[i][:, 0:1]
-            # padded (position −1) rows never scored a key: emit zeros
+            # rows that scored no key (padding at position −1, every
+            # row of a sequence with no live page): emit zeros
             l = jnp.where(l == 0.0, 1.0, l)
             o_ref[0, i] = (acc_s[i] / l).astype(o_ref.dtype)
 
@@ -389,7 +390,10 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     (after this step's writes); table slots past ``ceil(lens/bs)`` are
     skipped entirely. Rows whose position ≥ ``lens[b]`` (padded prefill
     tail) attend only live keys — their outputs are the caller's to
-    discard, exactly as with the reference path.
+    discard, exactly as with the reference path. A sequence with
+    ``lens[b] <= 0`` holds nothing: its rows come back zero and no page
+    of its table is read (what is in those pages, a NaN included,
+    reaches nothing).
 
     ``v_width`` with ``v_cache=None``: a latent cache, one pool
     ``[L, N, 1, bs, D]`` whose row is a token's key for every head and,

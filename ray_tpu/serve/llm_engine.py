@@ -164,7 +164,16 @@ def _step_fns(model_config, ec: EngineConfig):
     2 + blocks_per_seq]`` (chunk tokens, start, n, the slot's table
     row), decode ``[decode_slots, 2 + blocks_per_seq]`` (token, length,
     table row of every slot), verify ``[decode_slots, spec_tokens + 1 +
-    2 + blocks_per_seq]`` (prefill's layout over all slots). With
+    2 + blocks_per_seq]`` (prefill's layout over all slots). A slot
+    with no decoding sequence (free, or its prompt still prefilling) is
+    staged as ``[token 0, length -1 (``_NO_SEQUENCE``), a table of
+    trash blocks]``: the decode program makes of it position -1 and
+    ``lens = 0``, a sequence that holds nothing, for which the paged
+    kernel fetches no page and returns zeros, and whose one K/V row
+    lands in the trash block. Length 0 is a live sequence's first token
+    (``decode_step`` at ``seq_lens == 0``), so the program cannot guess:
+    the engine says. A verify row of such a slot is ``start 0, n 0``,
+    ``lens = 0`` as well, and writes nothing. With
     ``capture_logprobs`` prefill and decode also return the selected
     token's logprob (greedy argmax is unchanged — the extra output is
     the RLHF rollout payload, not a sampling change)."""
@@ -216,6 +225,10 @@ def _step_fns(model_config, ec: EngineConfig):
 
 
 _DONE = object()          # stream-end sentinel on the request queue
+
+#: the staged length of a decode slot with no decoding sequence
+#: (_step_fns): position -1, no live key
+_NO_SEQUENCE = -1
 
 # request lifecycle states
 _QUEUED, _PREFILL, _DECODE, _FINISHED = range(4)
@@ -357,13 +370,16 @@ class LLMEngine:
         # fed by, [token, length, block-table row] a slot. Block-table
         # row 0s point idle slots at the reserved trash block, so their
         # (masked-garbage) decode writes never touch a live sequence's
-        # blocks. A slot whose prompt is still prefilling is such a
-        # slot: its row stays 0s until the prompt ends (a decode step
-        # writes every slot's position `length`, and position 0 of its
-        # first block is its first token's, or a shared prefix's).
+        # blocks, and their length _NO_SEQUENCE tells the kernel to
+        # read nothing for them. A slot whose prompt is still
+        # prefilling is such a slot: its row stays so until the prompt
+        # ends (a decode step writes every slot's position `length`,
+        # and position 0 of its first block is its first token's, or a
+        # shared prefix's).
         self._slot_rows = np.zeros((S, 2 + T), np.int32)
         self._last_tok = self._slot_rows[:, 0]
         self._seq_lens = self._slot_rows[:, 1]
+        self._seq_lens[:] = _NO_SEQUENCE
         self._block_tables = self._slot_rows[:, 2:]
         self._slots: List[Optional[_Request]] = [None] * S
         self._free_slots = list(range(S))
@@ -504,6 +520,7 @@ class LLMEngine:
         # work fraction of the paged fast path (any backend)
         self._decode_pages_live = 0
         self._decode_pages_window = 0
+        self._decode_slots_skipped = 0
         # the same for the paged kernel's innermost grid axis: it folds
         # P pages of a sequence a grid step, so a decode call takes
         # slots x ceil(T/P) steps of which sum(ceil(pages/P)) have a
@@ -1063,6 +1080,7 @@ class LLMEngine:
                 booked[:] = 0, 0.0
             self._found_ready = dict.fromkeys(self._found_ready, 0)
             self._decode_pages_live = self._decode_pages_window = 0
+            self._decode_slots_skipped = 0
             self._decode_grid_steps = self._decode_grid_steps_live = 0
             self._sparse.clear()
             self._prompt_blocks_total = 0
@@ -1122,6 +1140,10 @@ class LLMEngine:
                 "fetch_found_ready_total": dict(self._found_ready),
                 "decode_pages_live": self._decode_pages_live,
                 "decode_pages_window": self._decode_pages_window,
+                # slots a decode step staged with no sequence (the
+                # kernel reads nothing for them), summed over steps:
+                # decode_steps x decode_slots less the occupancy
+                "decode_slots_skipped_total": self._decode_slots_skipped,
                 "decode_block_work_frac": (
                     round(self._decode_pages_live
                           / self._decode_pages_window, 4)
@@ -1537,7 +1559,7 @@ class LLMEngine:
                 self._pending.popleft()
                 req.slot = self._free_slots.pop()
                 self._block_tables[req.slot, :] = 0
-                self._seq_lens[req.slot] = 0
+                self._seq_lens[req.slot] = _NO_SEQUENCE
                 req.state = _PREFILL
                 self._slots[req.slot] = req
                 self._prefilling.append(req)
@@ -1628,7 +1650,7 @@ class LLMEngine:
             req.slot = self._free_slots.pop()
             self._block_tables[req.slot, :] = 0
             self._block_tables[req.slot, :len(req.blocks)] = req.blocks
-            self._seq_lens[req.slot] = 0
+            self._seq_lens[req.slot] = _NO_SEQUENCE
             req.state = _PREFILL
             self._slots[req.slot] = req
             if req.hit_blocks and self._metrics is not None:
@@ -1895,11 +1917,11 @@ class LLMEngine:
 
     def _account_decode_pages(self, live_lens) -> None:
         """Book one decode step's length-aware work: pages the paged
-        kernel touches (``max(ceil(live/bs), 1)`` per slot — idle slots
-        run their one trash page) vs the full table window the XLA
-        reference gathers, and the kernel's grid steps with a live page
-        vs all it takes. Host-side numpy over the slot arrays the step
-        already copied — no device work."""
+        kernel reads (``ceil(live/bs)`` per slot — none for a slot with
+        no sequence) vs the full table window the XLA reference
+        gathers, and the kernel's grid steps with a live page vs all it
+        takes. Host-side numpy over the slot arrays the step already
+        copied — no device work."""
         from ray_tpu.ops.paged_flash import (paged_grid_steps,
                                              paged_work_pages)
         ec = self.config
@@ -1907,6 +1929,8 @@ class LLMEngine:
             self._np.asarray(live_lens, self._np.int64),
             ec.kv_block_size)
         self._decode_pages_live += int(pages.sum())
+        self._decode_slots_skipped += \
+            len(pages) - int(self._np.count_nonzero(pages))
         self._decode_pages_window += ec.decode_slots * ec.blocks_per_seq
         steps, live = paged_grid_steps(pages, ec.blocks_per_seq,
                                        self._decode_pages_per_step)
@@ -2198,7 +2222,7 @@ class LLMEngine:
         if req.slot is not None and self._slots[req.slot] is req:
             self._slots[req.slot] = None
             self._block_tables[req.slot, :] = 0
-            self._seq_lens[req.slot] = 0
+            self._seq_lens[req.slot] = _NO_SEQUENCE
             self._last_tok[req.slot] = 0
             self._free_slots.append(req.slot)
             # decref, not free: trie-indexed blocks stay warm for the

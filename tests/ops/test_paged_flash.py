@@ -3,7 +3,8 @@
 The kernel is the serving decode fast path: every case here pins its
 contract against the XLA gather reference at fp32-softmax tolerance —
 GQA grouping, uneven last blocks, chunked-prefill row shapes, the
-engine's block-0 trash slot, ``lens = 0`` idle slots — plus the
+engine's block-0 trash slot, ``lens <= 0`` slots that hold no sequence
+(zeros, and no page read) — plus the
 length-skipping semantics themselves (content of dead blocks must be
 unreachable) and the autotune/persisted-cache machinery it shares with
 the flash kernel."""
@@ -132,22 +133,92 @@ def test_length_skipping_ignores_dead_blocks(pp, group_of):
     np.testing.assert_array_equal(ker, ker2)
 
 
-def test_lens_zero_idle_slot_is_finite_and_matches_reference():
-    """The engine's idle decode slots: block table all-zeros (the trash
-    block), ``lens = 0``, position 0. The kernel clamps to one page and
-    must produce the same (discarded) numerics as the reference — and
-    never a NaN that could poison a donated buffer."""
-    B, H, KVH, D, bs, T = 2, 4, 2, 8, 4, 3
-    rng = np.random.default_rng(6)
-    kc = rng.normal(size=(1 + B * T, KVH, bs, D)).astype(np.float32)
-    vc = rng.normal(size=(1 + B * T, KVH, bs, D)).astype(np.float32)
-    q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
-    bt = np.zeros((B, T), np.int32)            # all slots -> trash block
-    pos = np.zeros((B, 1), np.int32)
-    ref, ker = _both(q, kc, vc, bt, jnp.asarray(pos),
-                     np.zeros((B,), np.int32))
-    assert np.all(np.isfinite(ker))
+def _poisoned(pool):
+    """``pool`` (blocks on axis 0) with the trash block holding NaN and
+    inf: whatever reads it shows."""
+    pool = np.array(pool)
+    pool[0] = np.nan
+    pool[0, ..., ::2] = np.inf
+    return pool
+
+
+def _latent_both(lens, bt, seed=0):
+    """The latent form (one pool whose page is key and value) on a
+    decode batch with a poisoned trash block: (reference, kernel)."""
+    from ray_tpu.ops.latent_attention import (latent_attention,
+                                              latent_row_width)
+    H, dn, dr, dv, rank, bs = 4, 16, 8, 16, 128, 8
+    B = len(lens)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    pool = np.array(jax.random.normal(
+        ks[0], (1 + B * bt.shape[1], 1, bs, latent_row_width(rank, dr))))
+    pool[..., rank + dr:] = 0.0
+    lens = jnp.asarray(lens, jnp.int32)
+    return [np.asarray(latent_attention(
+        jax.random.normal(ks[1], (B, 1, H, dn)),
+        jax.random.normal(ks[2], (B, 1, H, dr)),
+        jax.random.normal(ks[3], (rank, H, dn)) * 0.1,
+        jax.random.normal(ks[4], (rank, H, dv)) * 0.1,
+        jnp.asarray(_poisoned(pool))[None], jnp.asarray(bt),
+        (lens - 1)[:, None], layer=0, lens=lens,
+        sm_scale=(dn + dr) ** -0.5, impl=impl))
+        for impl in ("reference", "interpret")]
+
+
+@pytest.mark.parametrize("form", ["dense", "latent"])
+def test_lens_zero_idle_slot_is_finite_and_matches_reference(form):
+    """The engine's decode slots that hold no sequence: ``lens <= 0``,
+    the row below position 0, block table all-zeros (the trash block).
+    Kernel and reference return zeros for them, and the kernel reads no
+    page of theirs: the trash block holds NaN and inf here, and a live
+    sequence between them is what it is without the poison."""
+    bs, T = (4, 3) if form == "dense" else (8, 3)
+    lens = np.array([0, bs + 1, -1], np.int32)
+    bt = np.zeros((3, T), np.int32)
+    bt[1] = 1 + np.arange(T)                   # the live one's own pages
+    if form == "latent":
+        ref, ker = _latent_both(lens, bt)
+    else:
+        H, KVH, D = 4, 2, 8
+        rng = np.random.default_rng(6)
+        kc = rng.normal(size=(1 + 3 * T, KVH, bs, D)).astype(np.float32)
+        vc = rng.normal(size=(1 + 3 * T, KVH, bs, D)).astype(np.float32)
+        q = rng.normal(size=(3, 1, H, D)).astype(np.float32)
+        pos = jnp.asarray(lens - 1)[:, None]
+        clean = _both(q, kc, vc, bt, pos, lens)
+        ref, ker = _both(q, _poisoned(kc), _poisoned(vc), bt, pos, lens)
+        for got, want in zip((ref, ker), clean):
+            np.testing.assert_array_equal(got[1], want[1])
+    for out in (ref, ker):
+        assert np.all(np.isfinite(out))
+        assert not out[[0, 2]].any() and out[1].any()
     np.testing.assert_allclose(ker, ref, **TOL)
+
+
+@pytest.mark.parametrize("pp", [None, 2])
+@pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
+def test_lens_minus_one_zero_and_one_side_by_side(H, KVH, pp, group_of):
+    """What a decode batch holds of short rows: the slot with no
+    sequence (the engine stages length -1: ``lens`` 0), a caller's
+    ``lens`` of -1, and a sequence's first token (``lens`` 1, position
+    0: a live row, one key). The first two are zeros and read nothing,
+    the third attends its one key, whichever slot each sits in."""
+    group_of(pp)
+    bs, D, T = 4, 8, 5
+    lens = np.array([1, -1, 0, 1, 0, -1], np.int32)
+    B = len(lens)
+    _, v_seq, kc, vc, bt = _paged_case(30, B, T * bs, H, KVH, D, bs, T)
+    bt[lens <= 0] = 0
+    rng = np.random.default_rng(31)
+    q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
+    ref, ker = _both(q, _poisoned(kc), _poisoned(vc), bt,
+                     jnp.asarray(lens - 1)[:, None], lens)
+    np.testing.assert_allclose(ker, ref, **TOL)
+    assert not ker[lens <= 0].any() and not ref[lens <= 0].any()
+    # one key: the softmax is 1 and the output that key's value
+    for b in np.flatnonzero(lens == 1):
+        np.testing.assert_allclose(
+            ker[b, 0], np.repeat(v_seq[b, 0], H // KVH, axis=0), **TOL)
 
 
 def test_block_size_not_dividing_sequence():
@@ -231,10 +302,15 @@ def test_explicit_block_r_and_row_padding():
 
 
 def test_paged_work_pages_accounting():
-    lens = np.array([0, 1, 4, 5, 16], np.int32)
+    """A sequence's pages, and none for one that holds nothing."""
+    lens = np.array([-1, 0, 1, 4, 5, 16], np.int32)
     pages = paged_work_pages(lens, 4)
-    np.testing.assert_array_equal(pages, [1, 1, 1, 2, 4])
-    assert paged_work_pages(0, 4) == 1
+    np.testing.assert_array_equal(pages, [0, 0, 1, 1, 2, 4])
+    np.testing.assert_array_equal(paged_work_pages(jnp.asarray(lens), 4),
+                                  pages)
+    assert paged_work_pages(0, 4) == 0
+    assert paged_work_pages(-1, 4) == 0
+    assert paged_work_pages(-9, 4) == 0
     assert paged_work_pages(9, 4) == 3
 
 
@@ -300,9 +376,10 @@ def test_group_edges_with_idle_slots_between_live_ones(H, KVH, pp,
                                                        group_of):
     """Live pages equal to k·P, k·P + 1 and fewer than P, in a table
     that P does not divide (2·P + 3 slots), with ``lens = 0`` idle slots
-    (every table row the trash block) between the live ones, as the
-    engine's decode batch has them: each against the reference, and
-    junk in every dead slot unreachable."""
+    (every table row the trash block, the row below position 0) between
+    the live ones, as the engine's decode batch has them: each against
+    the reference, the idle ones zero, and junk in every dead slot
+    unreachable."""
     group_of(pp)
     bs, D = 4, 8
     T = 2 * pp + 3
@@ -313,10 +390,12 @@ def test_group_edges_with_idle_slots_between_live_ones(H, KVH, pp,
     bt[lens == 0] = 0
     rng = np.random.default_rng(21)
     q = rng.normal(size=(B, 1, H, D)).astype(np.float32)
-    pos = (lens - 1).clip(0)[:, None]
+    pos = (lens - 1)[:, None]
     ref, ker = _both(q, kc, vc, bt, jnp.asarray(pos), lens)
     assert np.all(np.isfinite(ker))
     np.testing.assert_allclose(ker, ref, **TOL)
+    assert not ker[lens == 0].any() and not ref[lens == 0].any()
+    assert ker[lens > 0].any(axis=(1, 2, 3)).all()
     for b in range(B):
         dead = max(1, -(-int(lens[b]) // bs))
         if lens[b]:
@@ -391,16 +470,20 @@ def test_pages_per_step_follows_the_vmem_budget(cell):
 
 def test_paged_grid_steps_accounting():
     """What the engine books a decode step: ``batch · ceil(T / P)``
-    steps, ``Σ ceil(pages / P)`` of them live (an idle slot's one)."""
+    steps, ``Σ ceil(pages / P)`` of them live (none of a slot's that
+    holds no sequence)."""
     pages = paged_work_pages(
         np.array([0, 1, 64, 65, 128, 2048, 5000], np.int64), 16)
-    np.testing.assert_array_equal(pages, [1, 1, 4, 5, 8, 128, 313])
+    np.testing.assert_array_equal(pages, [0, 1, 4, 5, 8, 128, 313])
     for pp in (1, 4, 8, 16):
         steps, live = paged_grid_steps(pages, 128, pp)
         assert steps == len(pages) * -(-128 // pp)
         assert live == sum(-(-min(int(p), 128) // pp) for p in pages)
-    assert paged_grid_steps(pages, 100, 8) == (7 * 13, 1 + 1 + 1 + 1 + 1
+    assert paged_grid_steps(pages, 100, 8) == (7 * 13, 0 + 1 + 1 + 1 + 1
                                                + 13 + 13)
+    # a decode batch of slots that hold nobody: every step a dead one
+    assert paged_grid_steps(paged_work_pages(np.array([-1, 0, -1]), 16),
+                            128, 8) == (3 * 16, 0)
 
 
 # ------------------------------------------------ autotune / disk cache

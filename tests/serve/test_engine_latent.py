@@ -109,9 +109,11 @@ def test_counters_and_bytes_follow_the_pool():
         list(eng.generate_sync(DOC[:20], 6))
         s = eng.stats()
         assert s["moe_assignments_total"] == 25 * 2 * 2
-        # a decode step counts its latent pages as K/V pages are counted
+        # a decode step counts its latent pages as K/V pages are
+        # counted: the one sequence's, none for the slot beside it
         assert s["decode_pages_live"] == sum(
-            -(-(20 + i + 1) // 4) + 1 for i in range(5))
+            -(-(20 + i + 1) // 4) for i in range(5))
+        assert s["decode_slots_skipped_total"] == 5
         # one pool, a row of 128 (16 + 4 numbers up to a lane tile) a
         # token and layer
         assert set(eng._cache) == {"latent"}

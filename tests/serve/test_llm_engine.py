@@ -343,11 +343,17 @@ def test_stats_decode_wall_split_and_page_accounting(engine4):
     length-aware page accounting (live pages / window pages < 1 for
     short sequences in a wide window) that the bench's mixed-length leg
     and the paged kernel's FLOP claim read."""
+    was = engine4.stats()
     list(engine4.generate_sync([3, 1, 4, 1, 5], max_new_tokens=6))
     s = engine4.stats()
     assert s["decode_wall_s"] > 0 and s["prefill_wall_s"] > 0
-    assert s["decode_pages_window"] > 0
     assert 0 < s["decode_pages_live"] <= s["decode_pages_window"]
+    # five decode steps of the one sequence (5 prompt tokens): its
+    # pages alone, the three slots beside it read nothing
+    assert s["decode_pages_live"] - was["decode_pages_live"] == sum(
+        -(-(5 + i + 1) // 4) for i in range(5))
+    assert s["decode_slots_skipped_total"] \
+        - was["decode_slots_skipped_total"] == 5 * 3
     frac = s["decode_block_work_frac"]
     assert frac == pytest.approx(
         s["decode_pages_live"] / s["decode_pages_window"], abs=1e-3)
@@ -775,9 +781,9 @@ _PARENT_TOKENS = [
     [30, 28, 4, 41, 57, 21, 5, 48, 3, 17, 23, 29]]
 
 
-def _seeded_engine(spec_tokens):
+def _seeded_engine(spec_tokens, **model_kw):
     eng = LLMEngine(
-        TransformerConfig(**MODEL_KW),
+        TransformerConfig(**dict(MODEL_KW, **model_kw)),
         EngineConfig(decode_slots=4, kv_block_size=4, max_seq_len=48,
                      prefill_chunk=8, max_new_tokens=16,
                      spec_tokens=spec_tokens), seed=4)
@@ -824,9 +830,13 @@ def test_stats_book_the_paged_kernels_grid_steps(spec_tokens,
     slots, groups = 4, 6
     assert st["decode_pages_per_step"] == 2
     assert st["decode_grid_steps"] == st["decode_steps"] * slots * groups
-    # every slot, idle ones too, has its first group live; no sequence
-    # here grows past 48 tokens, and most are far shorter
-    assert st["decode_steps"] * slots <= st["decode_grid_steps_live"] \
+    # every sequence has its first group live and a slot that holds
+    # none has no live step; no sequence here grows past 48 tokens, and
+    # most are far shorter
+    decoding = sum(k * n for k, n in st["occupancy_hist"].items())
+    assert st["decode_slots_skipped_total"] + decoding \
+        == st["decode_steps"] * slots
+    assert decoding <= st["decode_grid_steps_live"] \
         < st["decode_grid_steps"] // 2
     assert st["decode_grid_steps_live"] * 2 >= st["decode_pages_live"]
     assert st["decode_grid_live_frac"] == pytest.approx(
@@ -1117,6 +1127,61 @@ def test_a_decode_step_leaves_a_prefilling_prompts_pages_alone(
     assert beside_steps > 3         # the short prompt decoded meanwhile
     for name in pages[0]:
         assert np.array_equal(pages[0][name], pages[1][name]), name
+
+
+@pytest.mark.parametrize("paged_impl", ["reference", "interpret"])
+def test_a_slot_with_no_decoding_sequence_is_staged_empty(paged_impl):
+    """Five requests on four slots: every decode row of a slot with no
+    decoding sequence, free or with its prompt still prefilling, is
+    ``[0, -1, trash blocks]`` (the kernel reads nothing for it) and
+    every other row a sequence's own; the streams are the parent's;
+    the books count the sequences' pages alone, and the skipped slots
+    are the occupancy's complement; and no pool ends with a NaN or an
+    inf, the trash block (where every empty row's K/V lands) included:
+    the reference gathers it into live rows under a zero weight."""
+    from ray_tpu.serve.llm_engine import _DECODE, _PREFILL
+    eng = _seeded_engine(0, paged_impl=paged_impl)
+    staged = []
+    try:
+        decode = eng._jit_decode
+
+        def logged(params, rows, cache):
+            state = [None if r is None else r.state for r in eng._slots]
+            staged.append((rows.copy(), state))
+            return decode(params, rows, cache)
+
+        logged._cache_size = decode._cache_size
+        eng._jit_decode = logged
+        served = [_drain_stream(r)
+                  for r in _submit_together(eng, _together())]
+        _assert_clean(eng, 4)
+        st = eng.stats()
+        pools = {name: np.asarray(pool)
+                 for name, pool in eng._cache.items()}
+        assert eng.pool_audit() == []
+    finally:
+        eng.shutdown()
+    assert served == _PARENT_TOKENS
+    assert len(staged) == st["decode_steps"] > 20
+    pages = empty = beside_a_prompt = 0
+    for rows, state in staged:
+        for row, was in zip(rows, state):
+            if was == _DECODE:
+                assert row[1] > 0 and row[2] > 0
+                pages += -(-(int(row[1]) + 1) // 4)
+            else:
+                assert row.tolist() == [0, -1] + [0] * 12
+                empty += 1
+                beside_a_prompt += was == _PREFILL
+    assert beside_a_prompt > 0 and empty > beside_a_prompt
+    assert st["decode_pages_live"] == pages
+    assert st["decode_slots_skipped_total"] == empty
+    assert empty + sum(k * n for k, n in st["occupancy_hist"].items()) \
+        == st["decode_steps"] * 4
+    assert eng._slot_rows[:, 1].tolist() == [-1] * 4
+    for name, pool in pools.items():
+        assert np.isfinite(pool).all(), name
+        assert np.any(pool[:, 0]), name         # the trash block was hit
 
 
 def test_eos_cancel_and_the_cap_with_a_program_out_leak_nothing():
