@@ -3,10 +3,7 @@ what a query could see it attended (from the request records, positions
 alone), and from the reduced trace the share and the roofline of the
 kernels the trace can name. None where there is nothing to read (no
 trace, a rehearsal's CPU trace, a program without these kernels)."""
-import json
-import os
-
-from benchmarks import roofline, roofline_sparse, spec, stats
+from benchmarks import roofline, roofline_sparse, stats
 
 
 def _select_share(obs, topk):
@@ -32,7 +29,7 @@ def _kernel_seconds(tr, kinds):
                if key.split("|", 1)[1] in kinds) / tr["chips"]
 
 
-def read(obs, what, topk=None, kinds=(), config=None):
+def read(obs, what, topk=None, kinds=()):
     if what == "select_share":
         return _select_share(obs, topk)
     tr = obs.get("trace")
@@ -44,18 +41,16 @@ def read(obs, what, topk=None, kinds=(), config=None):
     if what == "kernel_share":
         return 100.0 * spent / tr["busy_s"]
     if what == "moe_roofline":
+        # the experts' widths are the cell's own configuration's:
+        # obs["model"] carries its ``program`` group whole
         m, eng = obs["model"], tr["engine"]
         cfg = obs["engine_config"]
-        # the widths as the configuration's file has them: obs["model"]
-        # (serve_cell.py) carries the attention's alone
-        with open(os.path.join(spec.HERE, "configs", config + ".json")) as f:
-            prog = json.load(f)["program"]
         least = 0.0
         for calls, tokens in ((eng["prefill_chunks"], m["prefill_chunk"]),
                               (eng["decode_steps"], cfg["decode_slots"])):
             flops, nbytes = roofline_sparse.moe_grouped(
-                tokens, prog["d_model"], prog["expert_width"],
-                prog["experts_per_token"], m["itemsize"])
+                tokens, m["d_model"], m["expert_width"],
+                m["experts_per_token"], m["itemsize"])
             least += calls * m["n_layers"] * roofline.min_seconds(
                 flops, nbytes, obs["device"]["kind"])
         return 100.0 * least / spent

@@ -27,9 +27,10 @@ def key_cost(widths: Dict[str, Any], itemsize: int = 2
 def latent_decode(pages: int, block_size: int, widths: Dict[str, Any],
                   itemsize: int = 2) -> Tuple[float, float]:
     """(flops, bytes) of decode attention over ``pages`` live latent
-    pages summed over sequences, steps and layers (an idle slot's one
-    trash page counted as the engine counts it): a page is read once
-    and every head's one query row meets each of its keys."""
+    pages summed over sequences, steps and layers (a slot with no
+    sequence has no page: the engine counts none for it and the kernel
+    fetches none): a page is read once and every head's one query row
+    meets each of its keys."""
     flops, nbytes = key_cost(widths, itemsize)
     keys = pages * block_size
     return keys * flops, keys * nbytes

@@ -24,13 +24,38 @@ from benchmarks.spec import log
 from benchmarks.serve_replica import BenchLLMServer
 
 APP = "bench_llm"
-#: what the configuration may add to ``obs["model"]``: a routed model's
-#: widths, for the readers that count its experts' work
-ROUTED_WIDTHS = ("d_model", "expert_width", "experts_per_token", "n_experts")
 
 
 def _is_number(v: Any) -> bool:
     return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
+def _is_plain(v: Any) -> bool:
+    """A number, a string, a truth value, or a list or dict of them."""
+    if isinstance(v, dict):
+        return all(_is_plain(x) for x in v.values())
+    if isinstance(v, list):
+        return all(_is_plain(x) for x in v)
+    return isinstance(v, (int, float, str))
+
+
+def observed_model(program: Dict[str, Any], model: Dict[str, Any],
+                   engine: Dict[str, Any]) -> Dict[str, Any]:
+    """``obs["model"]``: the configuration's ``program`` group whole,
+    every plain key under its own name with the value the model was
+    built from (a rehearsal's is the narrowed one), so that a reader
+    which comes with a configuration reads whatever widths that
+    configuration has; beside them what the readers here have always
+    been given, which wins where a name is both."""
+    return {**{k: model[k] for k, v in program.items() if _is_plain(v)},
+            "n_layers": model["n_layers"],
+            "n_heads": model["n_heads"],
+            "kv_heads": model.get("n_kv_heads") or model["n_heads"],
+            "head_dim": model["head_dim"],
+            "kv_block_size": engine["kv_block_size"],
+            "num_kv_blocks": engine["num_kv_blocks"],
+            "prefill_chunk": engine["prefill_chunk"],
+            "itemsize": 2 if model["dtype"] == "bfloat16" else 4}
 
 
 def numerics(stats: Dict[str, Any]) -> Dict[str, Any]:
@@ -383,15 +408,7 @@ def _drive(cell, seed, seconds, trace, t_start) -> Dict[str, Any]:
         "engine": counters_delta(after["stats"], before["stats"]),
         "engine_end": numerics(after["stats"]),
         "engine_config": dict(p["engine"]),
-        "model": {"n_layers": model["n_layers"],
-                  "n_heads": model["n_heads"],
-                  "kv_heads": model.get("n_kv_heads") or model["n_heads"],
-                  "head_dim": model["head_dim"],
-                  "kv_block_size": p["engine"]["kv_block_size"],
-                  "num_kv_blocks": p["engine"]["num_kv_blocks"],
-                  "prefill_chunk": p["engine"]["prefill_chunk"],
-                  "itemsize": 2 if model["dtype"] == "bfloat16" else 4,
-                  **{k: model[k] for k in ROUTED_WIDTHS if k in model}},
+        "model": observed_model(cell.config["program"], model, p["engine"]),
         # pages some request holds when the window closes (the prefix
         # cache's unreferenced pages count as free: eviction takes them)
         "pool": {"live_pages": after["stats"]["total_blocks"]

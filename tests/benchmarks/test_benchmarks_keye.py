@@ -10,6 +10,7 @@ import sys
 
 import pytest
 
+import manifest_by_name
 from benchmarks import roofline_sparse, spec
 from benchmarks.readers import sparse
 
@@ -28,11 +29,10 @@ SA_CONFIG = {"indexer_head_dim": 64, "indexer_num_heads": 16,
              "q_chunk_size": 512, "topk": 2048}
 
 
-def test_the_file_holds_the_published_numbers_and_one_cut():
+def test_the_file_holds_the_published_numbers_and_the_manifest_one_cut():
     cell = spec.load_cell(CELL)
     cfg = cell.config
-    entry = [c for c in spec.benchmark()["configs"]
-             if c["name"] == cell.config_name][0]
+    entry = manifest_by_name.configuration(cell.config_name)
     assert entry["reduced"] == ["num_hidden_layers"]
     for key, value in PUBLISHED.items():
         assert cfg["published"][key] == value, key
@@ -112,8 +112,11 @@ def _obs():
     return {
         "window_s": 10.0, "requests": [req, dict(req, tokens=[], due=2.0),
                                        dict(req, due=11.0)],
+        # with the configuration's ``program`` widths, as
+        # ``serve_cell.observed_model`` carries them
         "model": {"kv_block_size": 16, "prefill_chunk": 64, "n_layers": 2,
-                  "itemsize": 2},
+                  "itemsize": 2, "d_model": 64, "expert_width": 16,
+                  "experts_per_token": 2},
         "engine_config": {"decode_slots": 4},
         "device": {"platform": "tpu", "kind": "TPU v5 lite"},
         "trace": {"chips": 1, "busy_s": 2.0,
@@ -124,7 +127,7 @@ def _obs():
                                      "jit__decode_fn|fusion": 1.0}}}
 
 
-def test_the_readers_on_made_up_observations(tmp_path, monkeypatch):
+def test_the_readers_on_made_up_observations():
     obs = _obs()
     # one request counts: 32 tokens cached (two whole pages), positions
     # 32..39 prefilled and 40, 41 decoded, topk 36
@@ -140,14 +143,8 @@ def test_the_readers_on_made_up_observations(tmp_path, monkeypatch):
     least = sum(calls * 2 * roofline.min_seconds(
         *roofline_sparse.moe_grouped(tokens, 64, 16, 2), "TPU v5 lite")
         for calls, tokens in ((3, 64), (10, 4)))
-    # the widths come from the named configuration's file
-    (tmp_path / "configs").mkdir()
-    (tmp_path / "configs" / "made-up.json").write_text(json.dumps(
-        {"program": {"d_model": 64, "expert_width": 16,
-                     "experts_per_token": 2}}))
-    monkeypatch.setattr(spec, "HERE", str(tmp_path))
-    assert sparse.read(obs, "moe_roofline", kinds=["ragged-dot-none"],
-                       config="made-up") \
+    # the widths are the observation's own (its configuration's)
+    assert sparse.read(obs, "moe_roofline", kinds=["ragged-dot-none"]) \
         == pytest.approx(100.0 * least / 0.5)
     # a program without these kernels (the parent), a rehearsal, no trace:
     # nothing to read, and no error
@@ -159,20 +156,19 @@ def test_the_readers_on_made_up_observations(tmp_path, monkeypatch):
     assert sparse.read({}, "select_share", topk=8) is None
 
 
-#: the per-layer entries the new readers are for. ``BENCHMARK.json`` does
-#: not hold them: the list's end is pinned to ``step_host_share`` by
-#: ``test_benchmarks_program_span.py`` and the driver refuses an entry
-#: anywhere else (PERF.md section 7), so they are read here as a
-#: ``benchmark`` PR would enter them
 #: what PR 39 lets every closed-loop cell read of the engine's own books
-#: (and, traced, of its annotations)
-BOOKS_PR39 = {f"{base}.tok" for base in (
+#: (and, traced, of its annotations), and PR 41's timing of its programs
+#: (listed for this cell by PR 44)
+BOOKS = {f"{base}.tok" for base in (
     "tick_ms", "host_ms_per_tick", "decode_launch_ms", "prefill_launch_ms",
     "host_gap_share", "programs_ahead_share", "ttft_queue_ms",
     "ttft_prefill_wait_ms", "ttft_prefill_ms", "idle_in_tick_share",
-    "profiler_launch_stretch")}
+    "profiler_launch_stretch", "decode_device_ms", "prefill_device_ms",
+    "fetch_found_ready_share")}
+#: the per-layer entries the new readers are for (entered by PR 35), as
+#: written but for their ``workloads``, which hold this cell
 ENTRIES = [{"name": name, "unit": "%", "better": better, "source": source,
-            "layer": layer, "moves": "serve_tok_s", "workloads": [CELL]}
+            "layer": layer, "moves": "serve_tok_s"}
            for name, better, source, layer in (
     ("sparse_select_share.tok", "lower", "host_clock",
      "kernels, sparse attention"),
@@ -215,23 +211,25 @@ def test_the_manifest_holds_the_cell_and_the_new_entries_read():
     here, each moving ``serve_tok_s``; the four new entries resolve to
     the new reader by their files' names and print beside them."""
     cell = spec.load_cell(CELL)
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tok_s", "setup_s"}
-    assert {m["name"] for m in cell.per_layer} == {
+    assert {m["name"] for m in cell.end_to_end} >= {"serve_tok_s", "setup_s"}
+    # the line holds at least these: a later PR may list the cell in more
+    assert manifest_by_name.line_of(CELL) >= {
         "prefill_chunk_ms.tok", "decode_step_ms.tok", "decode_occupancy.tok",
         "kv_pool_live_share.tok", "prefix_hit_rate.tok",
         "closed_ttft_p50_ms", "device_idle_share.tok", "ready_s",
         "hbm_in_use_share", "compiles_in_window"} | {
-        m["name"] for m in ENTRIES} | BOOKS_PR39 | {"sparse_attn_share.tok"}
-    # entered by PR 35, as they were written here and in this order
-    # among themselves (found by name: entries come and go around
-    # them); no other cell's line carries them
-    mine = {m["name"] for m in ENTRIES}
-    assert [m for m in spec.benchmark()["per_layer"]
-            if m["name"] in mine] == ENTRIES
-    for w in spec.benchmark()["workloads"]:
-        if w["name"] != CELL:
-            assert not {m["name"] for m in ENTRIES} & {
-                m["name"] for m in spec.load_cell(w["name"]).per_layer}
+        m["name"] for m in ENTRIES} | BOOKS | {"sparse_attn_share.tok"}
+    # entered by PR 35, each found by name with its fields as they were
+    # written here, and this cell among those it lists
+    for m in ENTRIES:
+        entry, cells = manifest_by_name.metric(m["name"])
+        assert entry == m and CELL in cells
+    # what reads the selection means nothing in a cell without one: no
+    # other cell's line carries it (the experts' two entries may be
+    # carried by any routed cell)
+    assert manifest_by_name.carried_only_by(
+        {"sparse_select_share.tok", "topk_sort_share.tok",
+         "sparse_attn_share.tok"}, CELL)
     for m in ENTRIES:
         read, args = spec.metric_reader(m["name"])
         assert read is sparse.read and args["what"]
@@ -244,3 +242,29 @@ def test_the_manifest_holds_the_cell_and_the_new_entries_read():
     # positions give is read, and nothing raises
     obs["trace"]["by_module_kind"] = {"jit__decode_fn|fusion": 1.0}
     assert set(spec.read_metrics(ENTRIES, obs)) == {"sparse_select_share.tok"}
+
+
+def test_the_experts_roofline_reads_what_the_files_widths_gave_to_the_digit():
+    """``moe_gmm_roofline.tok`` took its three widths from this
+    configuration's file by name until PR 44; it takes them from
+    ``obs["model"]``, which carries the file's ``program`` group whole:
+    on this cell's own sizes the same number to the last digit, and no
+    configuration named in the metric's file, so a second routed
+    configuration may list its cell."""
+    from benchmarks import roofline, serve_cell
+    cell = spec.load_cell(CELL)
+    engine, prog = cell.params["engine"], cell.config["program"]
+    model = dict(cell.model_kwargs(), max_seq_len=engine["max_seq_len"])
+    obs = dict(_obs(), engine_config=dict(engine),
+               model=serve_cell.observed_model(prog, model, engine))
+    least = 0.0
+    for calls, tokens in ((3, engine["prefill_chunk"]),
+                          (10, engine["decode_slots"])):
+        flops, nbytes = roofline_sparse.moe_grouped(
+            tokens, prog["d_model"], prog["expert_width"],
+            prog["experts_per_token"], 2)
+        least += calls * cell.depth * roofline.min_seconds(
+            flops, nbytes, "TPU v5 lite")
+    read, args = spec.metric_reader("moe_gmm_roofline.tok")
+    assert "config" not in args
+    assert read(obs, **args) == 100.0 * least / 0.5

@@ -2,8 +2,8 @@
 file holds the published numbers under their own keys and states its
 cuts, the traffic file the cell's stated parameters, the new counting
 rules against shapes counted by hand, the new readers on made-up
-observations, the manifest's new entries at the end of their lists, the
-cell rehearsed end to end on the CPU, and the dense configurations' step
+observations, the manifest's configuration, cell and entries found by
+name, the cell rehearsed end to end on the CPU, and the dense configurations' step
 programs compiled for a described v5e with the ops they had before this
 configuration came."""
 import json
@@ -14,6 +14,7 @@ import sys
 
 import pytest
 
+import manifest_by_name
 from benchmarks import roofline, roofline_latent, spec
 from benchmarks.readers import latent
 
@@ -38,12 +39,12 @@ WIDTHS = {"n_heads": 128, "kv_lora_rank": 512, "qk_rope_dim": 64,
           "experts_held": 16, "n_experts": 256, "n_dense_layers": 1}
 
 
-def test_the_file_holds_the_published_numbers_and_states_its_cuts():
+def test_the_file_and_the_manifest_hold_the_published_numbers_and_the_cuts():
     cell = spec.load_cell(CELL)
     cfg = cell.config
-    entry = [c for c in spec.benchmark()["configs"]
-             if c["name"] == cell.config_name][0]
-    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts"]
+    entry = manifest_by_name.configuration(cell.config_name)
+    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                                "vocab_size"]
     assert cfg["published"] == PUBLISHED
     for key, value in PUBLISHED.items():
         if key not in entry["reduced"]:
@@ -52,11 +53,12 @@ def test_the_file_holds_the_published_numbers_and_states_its_cuts():
     assert cfg["depth"]["published"] == 61 and cfg["depth"]["here"] == 5
     assert cfg["n_routed_experts"] == 16
     assert cfg["held"]["n_routed_experts"]["published"] == 256
-    # the vocabulary is sliced too, and the file says why that key is
-    # not among ``reduced``
+    # the vocabulary is sliced too: the key as run, listed in
+    # ``reduced`` (since PR 44), the published count beside it
     rows = cfg["held"]["vocab_rows"]
     assert (rows["published"], rows["here"]) == (153600, 19200)
-    assert "test_benchmarks_manifest.py" in rows["why"]
+    assert cfg["vocab_size"] == cfg["program"]["vocab_size"] == 19200
+    assert "reduced" in rows["why"]
     assert "16 chips share each layer" in cfg["deployment"]
     for item in ("router", "sandwich_norm", "rotary_layout", "rope",
                  "softmax_scale"):
@@ -225,9 +227,10 @@ def _obs():
                                      "jit__decode_fn|fusion": 1.0}}}
 
 
+#: as written but for their ``workloads``, which hold this cell
 ENTRIES = [{"name": name, "unit": "%", "better": better,
             "source": "device_trace", "layer": layer,
-            "moves": "serve_tok_s", "workloads": [CELL]}
+            "moves": "serve_tok_s"}
            for name, better, layer in (
     ("latent_attn_share.tok", "lower", "kernels, latent attention"),
     ("latent_decode_roofline.tok", "higher", "kernels, latent attention"),
@@ -279,49 +282,46 @@ def test_the_readers_on_made_up_observations(tmp_path, monkeypatch):
                     config="made-up")
 
 
-def test_the_manifest_gained_its_entries_at_the_end():
-    """One configuration, one cell and four per-layer metrics, each the
-    last of its list; the accepted ``.tok`` metrics that mean the same
-    thing here list the cell last; nothing else of the manifest names
-    it."""
-    bench = spec.benchmark()
-    assert bench["configs"][-1]["name"] == CONFIG
-    assert [c["name"] for c in bench["configs"][:-1]] \
-        == ["gptj-6b", "mistral-7b-v0.3", "keye-vl-2.0-30b-a3b"]
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["workloads"][-1]["chips"] == 1 and len(bench["workloads"]) == 6
-    # found by name (entries come and go around them): as written here,
-    # in this order among themselves, behind the Keye cell's four
-    order = [m["name"] for m in bench["per_layer"]]
-    mine = {m["name"] for m in ENTRIES}
-    assert [m for m in bench["per_layer"] if m["name"] in mine] == ENTRIES
-    assert order.index("moe_gmm_roofline.tok") \
-        < order.index(ENTRIES[0]["name"])
+def test_the_manifest_holds_the_configuration_the_cell_and_the_entries():
+    """One configuration, one cell and four per-layer metrics, each
+    found by its name and as it was written; the accepted ``.tok``
+    metrics that mean the same thing here list the cell; no other
+    cell's line carries the four, which read this configuration's
+    kernels and file."""
+    config = manifest_by_name.configuration(CONFIG)
+    assert config["file"] == f"benchmarks/configs/{CONFIG}.json"
+    assert config["source"] == spec.load_cell(CELL).config["source"]
+    entered = manifest_by_name.cell(CELL)
+    assert (entered["config"], entered["traffic"], entered["chips"]) \
+        == (CONFIG, "serve_longdoc16", 1)
+    for m in ENTRIES:
+        entry, cells = manifest_by_name.metric(m["name"])
+        assert entry == m and CELL in cells
     cell = spec.load_cell(CELL)
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tok_s", "setup_s"}
+    assert {m["name"] for m in cell.end_to_end} >= {"serve_tok_s", "setup_s"}
     accepted = {"prefill_chunk_ms.tok", "decode_step_ms.tok",
                 "decode_occupancy.tok", "kv_pool_live_share.tok",
                 "prefix_hit_rate.tok", "closed_ttft_p50_ms",
                 "device_idle_share.tok", "ready_s", "hbm_in_use_share",
                 "compiles_in_window"}
-    # and what PR 39 lets every closed-loop cell read of the engine's
-    # own books (traced, of its annotations)
+    # what PR 39 lets every closed-loop cell read of the engine's own
+    # books (traced, of its annotations), PR 41's timing of its programs
+    # and the grouped products' share (listed for this cell by PR 44)
     books = {f"{base}.tok" for base in (
         "tick_ms", "host_ms_per_tick", "decode_launch_ms",
         "prefill_launch_ms", "host_gap_share", "programs_ahead_share",
         "ttft_queue_ms", "ttft_prefill_wait_ms", "ttft_prefill_ms",
-        "idle_in_tick_share", "profiler_launch_stretch")}
-    assert {m["name"] for m in cell.per_layer} \
-        == accepted | books | {m["name"] for m in ENTRIES}
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL, m["name"]
+        "idle_in_tick_share", "profiler_launch_stretch",
+        "decode_device_ms", "prefill_device_ms", "fetch_found_ready_share",
+        "moe_share")}
+    # the line holds at least these: a later PR may list the cell in more
+    assert manifest_by_name.line_of(CELL) \
+        >= accepted | books | {m["name"] for m in ENTRIES}
+    assert manifest_by_name.carried_only_by(
+        {m["name"] for m in ENTRIES}, CELL)
     for m in ENTRIES:
         read, args = spec.metric_reader(m["name"])
         assert read is latent.read and args["kinds"]
-        for w in bench["workloads"][:-1]:
-            assert m["name"] not in {
-                x["name"] for x in spec.load_cell(w["name"]).per_layer}
     line = spec.read_metrics(ENTRIES, _obs() | {"requests": _obs()["requests"]})
     assert set(line) <= {m["name"] for m in ENTRIES}
     assert line["latent_attn_share.tok"]["value"] == pytest.approx(37.5)

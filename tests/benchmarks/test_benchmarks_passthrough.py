@@ -9,6 +9,7 @@ import os
 
 import pytest
 
+import manifest_by_name
 from benchmarks import serve_cell, spec
 from benchmarks.readers import device_trace, tick
 
@@ -195,25 +196,32 @@ def test_a_new_metric_reads_nothing_where_there_is_nothing(base, pair):
 
 
 def test_the_new_entries_are_in_the_manifest_with_their_cells():
-    per_layer = {m["name"]: m for m in spec.benchmark()["per_layer"]}
-    chat = ["gptj-6b.serve_chat"]
-    closed = ["mistral-7b-v0.3.serve_docqa",
-              "keye-vl-2.0-30b-a3b.serve_longdoc",
-              "openpangu-ultra-moe-718b.serve_longdoc16"]
+    """Each entry found by name; the cells that are in the manifest
+    today are among those it lists (a later cell may join them), and
+    it moves the end-to-end metric of its side."""
+    chat = "gptj-6b.serve_chat"
+    docqa = "mistral-7b-v0.3.serve_docqa"
+    keye = "keye-vl-2.0-30b-a3b.serve_longdoc"
+    closed = {docqa, keye, "openpangu-ultra-moe-718b.serve_longdoc16"}
+
+    def listed(name, moves=None):
+        entry, cells = manifest_by_name.metric(name)
+        assert moves is None or entry["moves"] == moves, name
+        return set(cells)
+
     for base in FROM_THE_BOOKS + ("idle_in_tick_share",):
         first = "ttft" if base.startswith(("ttft_", "prefill_")) else "tpot"
-        assert per_layer[f"{base}.{first}"]["workloads"] == chat
-        assert per_layer[f"{base}.{first}"]["moves"] \
-            == {"ttft": "ttft_p50_ms", "tpot": "tpot_p50_ms"}[first]
-        assert per_layer[f"{base}.tok"]["workloads"] == closed
-        assert per_layer[f"{base}.tok"]["moves"] == "serve_tok_s"
-    assert per_layer["head_loss_share"]["workloads"] == [
-        "gptj-6b.train_2k", "mistral-7b-v0.3.train_fsdp4_4k"]
-    assert per_layer["sparse_attn_share.tok"]["workloads"] == closed[1:2]
-    assert per_layer["kv_write_share.tpot"]["workloads"] == chat
-    assert per_layer["kv_write_share.tok"]["workloads"] == closed[:1]
+        assert chat in listed(f"{base}.{first}", {
+            "ttft": "ttft_p50_ms", "tpot": "tpot_p50_ms"}[first])
+        assert closed <= listed(f"{base}.tok", "serve_tok_s")
+    assert {"gptj-6b.train_2k", "mistral-7b-v0.3.train_fsdp4_4k"} \
+        <= listed("head_loss_share")
+    assert keye in listed("sparse_attn_share.tok")
+    assert chat in listed("kv_write_share.tpot")
+    assert docqa in listed("kv_write_share.tok")
     # retired in favour of kv_write_share.* (PERF.md, PR 39)
-    assert not [n for n in per_layer if n.startswith("pool_copy_share")]
+    assert not [m["name"] for m in spec.benchmark()["per_layer"]
+                if m["name"].startswith("pool_copy_share")]
     assert not os.path.exists(os.path.join(
         spec.HERE, "metrics", "pool_copy_share.json"))
     obs = {"trace": {"window_s": 1.0, "busy_s": 0.5, "chips": 1, "by_scope": {
@@ -242,3 +250,57 @@ def test_the_new_entries_are_in_the_manifest_with_their_cells():
         "layer/attn/kv_write", "kv_copy"]) == pytest.approx(12.0)
     read, args = spec.metric_reader("kv_write_share.tpot")
     assert read(obs, **args) == pytest.approx(100.0 * (0.06 + 0.04) / 0.5)
+
+
+SERVING = [w["name"] for w in spec.benchmark()["workloads"]
+           if spec.load_cell(w["name"]).kind != "train"]
+
+
+@pytest.mark.parametrize("name", SERVING)
+def test_every_serving_cell_of_the_manifest_observes_its_program_group_whole(
+        name):
+    """``obs["model"]`` holds what it held until PR 44 (the attention's
+    sizes, the engine's, and a routed model's four widths) with the same
+    values, and every key of the configuration's ``program`` group under
+    its own name as the model was built from it: a reader that comes
+    with a configuration reads whatever widths that configuration has."""
+    cell = spec.load_cell(name)
+    engine, program = cell.params["engine"], cell.config["program"]
+    model = dict(cell.model_kwargs(), remat_policy="none",
+                 max_seq_len=engine["max_seq_len"])
+    got = serve_cell.observed_model(program, model, engine)
+    held = {"n_layers": cell.depth, "n_heads": program["n_heads"],
+            "kv_heads": program.get("n_kv_heads") or program["n_heads"],
+            "head_dim": program["head_dim"],
+            "kv_block_size": engine["kv_block_size"],
+            "num_kv_blocks": engine["num_kv_blocks"],
+            "prefill_chunk": engine["prefill_chunk"], "itemsize": 2,
+            **{k: program[k] for k in ("d_model", "expert_width",
+                                       "experts_per_token", "n_experts")
+               if k in program}}
+    assert {k: got[k] for k in held} == held
+    assert set(program) <= set(got)
+    for key, value in program.items():
+        # as run: the window's length is the engine's
+        assert got[key] == (engine["max_seq_len"] if key == "max_seq_len"
+                            else value), key
+    json.dumps(got)                         # plain data
+
+
+def test_the_observed_model_drops_what_is_not_plain_and_keeps_its_own_keys():
+    program = {"d_model": 8, "n_heads": 2, "head_dim": 4, "dtype": "float32",
+               "router_score": "sigmoid", "qk_norm": True,
+               "layer_kinds": ["full", "window", "window", "window"],
+               "heads_by_kind": {"full": 48, "window": 64},
+               "n_layers": 40, "absent": None, "mixed": [1, None]}
+    model = dict(program, n_layers=5, d_model=16)       # as run
+    engine = {"kv_block_size": 16, "num_kv_blocks": 9, "prefill_chunk": 32}
+    got = serve_cell.observed_model(program, model, engine)
+    assert "absent" not in got and "mixed" not in got
+    assert got["layer_kinds"] == program["layer_kinds"]
+    assert got["heads_by_kind"] == {"full": 48, "window": 64}
+    assert (got["router_score"], got["qk_norm"]) == ("sigmoid", True)
+    # the value the model was built from, and the derived keys win
+    assert (got["d_model"], got["n_layers"]) == (16, 5)
+    assert (got["kv_heads"], got["itemsize"], got["num_kv_blocks"]) \
+        == (2, 4, 9)

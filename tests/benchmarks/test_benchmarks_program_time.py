@@ -3,31 +3,46 @@ walls by class and ``fetch_found_ready_total``, ``LLMEngine.stats()``
 through the pass-through): on a recorded pair of snapshots each reads
 to the digit; None where a key or a sample is missing (a program from
 before these books, speculation, a window without such a launch); and
-the seven entries stand in the manifest with their cells, and every
-serving cell's line still resolves."""
+the entries stand in the manifest, found by name, with their cells
+among those that list them."""
 import copy
 import json
 import os
 
 import pytest
 
+import manifest_by_name
 from benchmarks import serve_cell, spec
 from benchmarks.readers import program_time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CHAT = ["gptj-6b.serve_chat"]
-#: the closed-loop cell that may list them: test_benchmarks_keye.py and
-#: test_benchmarks_pangu.py hold the other two cells' lines to a fixed
-#: set of names (PERF.md section 7); their engines keep the same books
-#: and ``obs["engine"]`` carries them
-CLOSED = ["mistral-7b-v0.3.serve_docqa"]
-#: name -> (unit, source, layer, cells of .tpot, cells of .tok)
+DOCQA = ["mistral-7b-v0.3.serve_docqa"]
+#: every closed-loop cell's engine keeps these books and ``obs["engine"]``
+#: carries them (PR 44 listed Keye's and openPangu's cells)
+CLOSED = DOCQA + ["keye-vl-2.0-30b-a3b.serve_longdoc",
+                  "openpangu-ultra-moe-718b.serve_longdoc16"]
+#: name -> (unit, source, layer, the end-to-end metric it moves, cells
+#: that list it today). ``decode_exposed_ms.tok`` stays docqa's: in the
+#: long-document cells it is a difference of two unlike means and names
+#: no idle time (PERF.md section 6, PR 41, item 2b)
 ENTRIES = {
-    "decode_device_ms": ("ms", "program_span", "device", CHAT, CLOSED),
-    "prefill_device_ms": ("ms", "program_span", "device", None, CLOSED),
-    "decode_exposed_ms": ("ms", "program_span", "engine", CHAT, CLOSED),
-    "fetch_found_ready_share": ("%", "program_counter", "engine", CHAT,
-                                CLOSED),
+    "decode_device_ms.tpot": ("ms", "program_span", "device",
+                              "tpot_p50_ms", CHAT),
+    "decode_device_ms.tok": ("ms", "program_span", "device",
+                             "serve_tok_s", CLOSED),
+    "prefill_device_ms.ttft": ("ms", "program_span", "device",
+                               "ttft_p50_ms", CHAT),
+    "prefill_device_ms.tok": ("ms", "program_span", "device",
+                              "serve_tok_s", CLOSED),
+    "decode_exposed_ms.tpot": ("ms", "program_span", "engine",
+                               "tpot_p50_ms", CHAT),
+    "decode_exposed_ms.tok": ("ms", "program_span", "engine",
+                              "serve_tok_s", DOCQA),
+    "fetch_found_ready_share.tpot": ("%", "program_counter", "engine",
+                                     "tpot_p50_ms", CHAT),
+    "fetch_found_ready_share.tok": ("%", "program_counter", "engine",
+                                    "serve_tok_s", CLOSED),
 }
 CLASS_KEYS = [f"{kind}_{cls}_{what}"
               for kind, unit in (("prefill", "chunks"), ("decode", "steps"))
@@ -75,7 +90,7 @@ def test_the_pass_through_carries_the_classes_and_the_ready_count(obs):
     ("fetch_found_ready_share", 100.0 * (3 + 4) / 32),
 ])
 def test_each_metric_reads_the_recorded_pair_to_the_digit(obs, name, value):
-    for suffix in ("tpot", "tok"):
+    for suffix in ("tpot", "ttft", "tok"):
         assert _read(f"{name}.{suffix}", obs) == pytest.approx(value,
                                                                rel=1e-12)
 
@@ -107,20 +122,12 @@ def test_a_missing_key_or_sample_reads_none(obs, name, needs):
         program_time.read(obs, "no_such_quantity")
 
 
-def test_the_seven_entries_are_in_the_manifest_with_their_cells():
-    per_layer = spec.benchmark()["per_layer"]
-    by_name = {m["name"]: m for m in per_layer}
-    assert sum(n.split(".")[0] in ENTRIES for n in by_name) == 7
-    for base, (unit, source, layer, chat, closed) in ENTRIES.items():
-        for suffix, cells, moves in (("tpot", chat, "tpot_p50_ms"),
-                                     ("tok", closed, "serve_tok_s")):
-            if cells is None:
-                # no chunk is launched behind a program in chat
-                assert f"{base}.{suffix}" not in by_name
-                continue
-            assert by_name[f"{base}.{suffix}"] == {
-                "name": f"{base}.{suffix}", "unit": unit, "better": "lower",
-                "source": source, "layer": layer, "moves": moves,
-                "workloads": cells}
-        assert os.path.exists(os.path.join(spec.HERE, "metrics",
-                                           base + ".json"))
+def test_the_entries_are_in_the_manifest_with_their_cells():
+    for name, (unit, source, layer, moves, cells) in ENTRIES.items():
+        entry, listed = manifest_by_name.metric(name)
+        assert entry == {
+            "name": name, "unit": unit, "better": "lower",
+            "source": source, "layer": layer, "moves": moves}
+        assert set(cells) <= set(listed), name
+        assert os.path.exists(os.path.join(
+            spec.HERE, "metrics", name.split(".")[0] + ".json"))
