@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import optax
 
 from ray_tpu.models.transformer import (
-    TransformerConfig, init_params, logical_axes, lm_loss)
+    TransformerConfig, init_params, logical_axes, lm_loss,
+    refuse_training)
 from ray_tpu.parallel.quantization import DEFAULT_BLOCK_SIZE, fake_quant
 from ray_tpu.parallel.sharding import (
     ShardingRules, FSDP_RULES, shard_params, batch_sharding, replicated,
@@ -182,6 +183,7 @@ def make_train_step(config: TransformerConfig, mesh,
     if grad_transport not in GRAD_TRANSPORTS:
         raise ValueError(f"grad_transport must be one of "
                          f"{GRAD_TRANSPORTS}, got {grad_transport!r}")
+    refuse_training(config)
     compile_cache.enable()
     rules = rules if rules is not None else FSDP_RULES
     if remat_policy is not None:

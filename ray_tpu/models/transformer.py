@@ -45,6 +45,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import (
@@ -58,6 +59,7 @@ from ray_tpu.ops import (
     cross_entropy_loss,
     fused_lm_head_loss,
 )
+from ray_tpu.ops.rotary import rotary_at, yarn_inv_freq
 
 REMAT_POLICIES = ("full", "none", "dots", "dots_all", "offload")
 
@@ -149,6 +151,32 @@ class TransformerConfig:
     routed_scale: float = 1.0
     experts_held: int = 0
     expert_first: int = 0
+    # A stack described BY KIND OF LAYER (served; the 'llama' block over
+    # per-head K/V): layer l is of kind layer_pattern[l % len] ("full" |
+    # "window"; () = every layer "full"). A "full" layer has n_heads
+    # query heads and rotates the first rotary_dim of head_dim at
+    # rope_base, by a YaRN table where rope_yarn = (factor, original
+    # length, beta_fast, beta_slow, attention factor) is given; a
+    # "window" layer has window_heads (0 = n_heads), rotates
+    # window_rotary_dim (0 = head_dim) at window_rope_base, plain, and
+    # attends the sliding_window keys up to its own position; both over
+    # kv_heads. Each kind's leaves are stacked apart (params["layers"],
+    # ["window_layers"]), its K/V pages live in pools of their own
+    # (init_kv_cache) and the leading n_dense_layers, of one kind, in
+    # ["dense_layers"]. head_gate: sigmoid(h Wg), one number a head, on
+    # the head's output ahead of wo.
+    layer_pattern: Tuple[str, ...] = ()
+    window_heads: int = 0
+    sliding_window: int = 0
+    window_rotary_dim: int = 0
+    window_rope_base: float = 10000.0
+    rope_yarn: Tuple[float, ...] = ()
+    head_gate: bool = False
+
+    def __post_init__(self):
+        # plain JSON hands lists over: the config stays hashable
+        for name in ("layer_pattern", "rope_yarn"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
 
     @property
     def kv_heads(self) -> int:
@@ -162,12 +190,40 @@ class TransformerConfig:
     def d_expert(self) -> int:
         return self.expert_width or self.d_ff
 
+    #: the keys of the forms only ``prefill`` / ``decode_step`` implement
+    SERVED_KEYS = ("experts_per_token", "qk_norm", "index_topk",
+                   "kv_lora_rank", "sandwich_norm", "n_dense_layers",
+                   "layer_pattern", "window_heads", "sliding_window",
+                   "rope_yarn", "head_gate")
+
+    @property
+    def served_keys(self) -> Tuple[str, ...]:
+        """The served-only keys this configuration sets."""
+        return tuple(k for k in self.SERVED_KEYS if getattr(self, k))
+
     @property
     def served_only(self) -> bool:
         """A form only ``prefill`` / ``decode_step`` implement."""
-        return bool(self.experts_per_token or self.qk_norm
-                    or self.index_topk or self.kv_lora_rank
-                    or self.sandwich_norm or self.n_dense_layers)
+        return bool(self.served_keys)
+
+    @property
+    def by_kind(self) -> bool:
+        """The stack is built by kind of layer (``_forward_kinds``)."""
+        return not self.kv_lora_rank and bool(
+            self.layer_pattern or self.n_dense_layers or self.window_heads
+            or self.sliding_window or self.rope_yarn or self.head_gate)
+
+    def layer_kind(self, layer: int) -> str:
+        pattern = self.layer_pattern or ("full",)
+        return pattern[layer % len(pattern)]
+
+    def kind_heads(self, kind: str) -> int:
+        return (self.window_heads or self.n_heads) if kind == "window" \
+            else self.n_heads
+
+    def kind_layers(self, kind: str) -> int:
+        """Layers of ``kind`` in the stack, the dense ones among them."""
+        return sum(self.layer_kind(l) == kind for l in range(self.n_layers))
 
     @property
     def resolved_remat_policy(self) -> str:
@@ -181,6 +237,19 @@ class TransformerConfig:
         """Parameter count (for MFU accounting)."""
         e, v, h = self.d_model, self.vocab_size, self.n_heads * self.head_dim
         kvh = self.kv_heads * self.head_dim
+        if self.by_kind:
+            total = 2 * v * e + e                 # embed, head, final norm
+            for l in range(self.n_layers):
+                hk = self.kind_heads(self.layer_kind(l))
+                total += 2 * e * hk * self.head_dim + 2 * e * kvh + 2 * e \
+                    + (e * hk if self.head_gate else 0)
+                if l < self.n_dense_layers or not self.experts_per_token:
+                    total += 3 * e * self.d_ff
+                else:
+                    total += e * self.n_experts + 3 * e * (
+                        self.n_experts_held * self.d_expert
+                        + self.shared_expert_width)
+            return total
         per_layer = e * h + 2 * e * kvh + h * e          # q, k, v, o
         if self.kv_lora_rank:    # wq_a, wq_b, wkv_a, wkv_b, wo, 2 norms
             qr, r = self.q_lora_rank, self.kv_lora_rank
@@ -285,6 +354,8 @@ def init_params(config: TransformerConfig, key,
     _check_served_forms(c)
     if c.kv_lora_rank:
         return _init_latent_params(c, key, jnp.dtype(dtype), out_scale)
+    if c.by_kind:
+        return _init_kind_params(c, key, jnp.dtype(dtype), out_scale)
     layers: Dict[str, jnp.ndarray] = {
         "wq": stack(keys[0], (c.d_model, h)),
         "wk": stack(keys[1], (c.d_model, kvh)),
@@ -375,9 +446,35 @@ def init_params(config: TransformerConfig, key,
 
 def _check_served_forms(c: TransformerConfig) -> None:
     if c.served_only and c.block_style != "llama":
-        raise ValueError("the served forms (experts_per_token, qk_norm, "
-                         "index_topk, kv_lora_rank, sandwich_norm, "
-                         "n_dense_layers) are forms of the 'llama' block")
+        raise ValueError(
+            f"the served forms ({', '.join(c.SERVED_KEYS)}) are forms of "
+            f"the 'llama' block; got {', '.join(c.served_keys)} with "
+            f"block_style {c.block_style!r}")
+    if c.by_kind:
+        kinds = {c.layer_kind(l) for l in range(c.n_layers)}
+        if not kinds <= {"full", "window"}:
+            raise ValueError(f"layer_pattern {c.layer_pattern}: a layer "
+                             f"is 'full' or 'window'")
+        if ("window" in kinds) != bool(c.sliding_window):
+            raise ValueError("'window' layers in layer_pattern and "
+                             "sliding_window > 0 come together, got "
+                             f"{c.layer_pattern} and {c.sliding_window}")
+        if c.qk_norm or c.index_topk:
+            raise ValueError("qk_norm and index_topk are not forms of a "
+                             "stack by kind of layer (layer_pattern, "
+                             "n_dense_layers, head_gate, rope_yarn)")
+        if len({c.layer_kind(l) for l in range(c.n_dense_layers)}) > 1 \
+                or not 0 <= c.n_dense_layers <= c.n_layers:
+            raise ValueError("the leading n_dense_layers are of one kind")
+        if c.n_dense_layers and not (
+                c.experts_per_token and c.n_dense_layers < c.n_layers):
+            raise ValueError("n_dense_layers lead layers of dropless "
+                             "experts (experts_per_token > 0)")
+        if c.rope_yarn and len(c.rope_yarn) != 5:
+            raise ValueError("rope_yarn is (factor, original length, "
+                             "beta_fast, beta_slow, attention factor)")
+        if c.experts_held:
+            raise ValueError("a stack by kind of layer holds every expert")
     if c.kv_lora_rank:
         if c.qk_norm or c.index_topk:
             raise ValueError("qk_norm and index_topk are forms of "
@@ -387,9 +484,9 @@ def _check_served_forms(c: TransformerConfig) -> None:
             raise ValueError(
                 "latent attention needs q_lora_rank, v_head_dim and "
                 f"head_dim == qk_nope_dim + qk_rope_dim, got {c}")
-    if (c.sandwich_norm or c.n_dense_layers) and not c.kv_lora_rank:
-        raise ValueError("sandwich_norm and n_dense_layers are served "
-                         "with latent attention (kv_lora_rank > 0)")
+    if c.sandwich_norm and not c.kv_lora_rank:
+        raise ValueError("sandwich_norm is served with latent attention "
+                         "(kv_lora_rank > 0)")
     if (c.shared_expert_width or c.experts_held
             or c.router_score != "softmax") and not c.experts_per_token:
         raise ValueError("shared_expert_width, experts_held and "
@@ -404,6 +501,90 @@ def _check_served_forms(c: TransformerConfig) -> None:
         raise ValueError(
             f"experts {c.expert_first}..{c.expert_first + c.experts_held} "
             f"held of {c.n_experts}")
+
+
+#: where a stack by kind of layer keeps each kind's routed layers
+KIND_STACKS = {"full": "layers", "window": "window_layers"}
+
+
+def _kind_layer_shapes(c: TransformerConfig, kind: str, dense: bool
+                       ) -> Dict[str, tuple]:
+    """One layer's matmul leaves of a stack by kind: name -> (shape,
+    logical axes without the layers axis). ``kind`` sets the query
+    heads, ``dense`` a SwiGLU MLP of d_ff in place of the experts."""
+    from ray_tpu.models.moe import (topk_moe_logical_axes,
+                                    topk_moe_param_shapes)
+    e, h = c.d_model, c.kind_heads(kind) * c.head_dim
+    kvh = c.kv_heads * c.head_dim
+    out = {"wq": ((e, h), ("embed", "heads")),
+           "wk": ((e, kvh), ("embed", "kv")),
+           "wv": ((e, kvh), ("embed", "kv")),
+           "wo": ((h, e), ("heads", "embed"))}
+    if c.head_gate:
+        out["wg"] = ((e, c.kind_heads(kind)), ("embed", None))
+    if dense or not c.experts_per_token:
+        out.update({"w_gate": ((e, c.d_ff), ("embed", "mlp")),
+                    "w_up": ((e, c.d_ff), ("embed", "mlp")),
+                    "w_down": ((c.d_ff, e), ("mlp", "embed"))})
+    else:
+        axes = topk_moe_logical_axes(c)
+        out.update({name: (shape, axes[name][1:]) for name, shape
+                    in topk_moe_param_shapes(c).items()})
+    return out
+
+
+def _kind_stacks(c: TransformerConfig):
+    """(name in the tree, kind, dense, layers) of each stack a
+    configuration by kind of layer has, in the tree's order."""
+    out = []
+    if c.n_dense_layers:
+        out.append(("dense_layers", c.layer_kind(0), True,
+                    c.n_dense_layers))
+    for kind, name in KIND_STACKS.items():
+        n = sum(c.layer_kind(l) == kind
+                for l in range(c.n_dense_layers, c.n_layers))
+        if n:
+            out.append((name, kind, False, n))
+    return out
+
+
+def _init_kind_params(c, key, dtype, out_scale) -> Dict:
+    """The tree of a stack by kind of layer: ``dense_layers`` (the
+    leading ones), ``layers`` (the "full" layers behind them) and
+    ``window_layers``, each stacked, every matmul leaf drawn a layer at
+    a time into ``dtype``."""
+    keys = jax.random.split(jax.random.fold_in(key, 104), 5)
+    params = {
+        "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
+                             dtype=dtype),
+        "final_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
+        "lm_head": {"w": _dense_init(keys[1], (c.d_model, c.vocab_size),
+                                     dtype=dtype)},
+    }
+    for j, (name, kind, dense, n) in enumerate(_kind_stacks(c)):
+        stack: Dict[str, jnp.ndarray] = {}
+        for i, (leaf, (shape, _)) in enumerate(
+                sorted(_kind_layer_shapes(c, kind, dense).items())):
+            stack[leaf] = _layered_init(
+                jax.random.fold_in(keys[2 + j], i),
+                out_scale if leaf in ("wo", "w_down", "we_down", "ws_down")
+                else 0.02, n, shape, dtype)
+        stack.update({norm: jnp.ones((n, c.d_model), jnp.float32)
+                      for norm in ("attn_norm", "mlp_norm")})
+        params[name] = stack
+    return params
+
+
+def _kind_logical_axes(c) -> Dict:
+    axes = {"embed": ("vocab", "embed"),
+            "final_norm": {"scale": ("embed",)},
+            "lm_head": {"w": ("embed", "vocab")}}
+    for name, kind, dense, _ in _kind_stacks(c):
+        axes[name] = {leaf: ("layers",) + ax for leaf, (_, ax)
+                      in _kind_layer_shapes(c, kind, dense).items()}
+        axes[name].update({"attn_norm": ("layers", "embed"),
+                           "mlp_norm": ("layers", "embed")})
+    return axes
 
 
 def _latent_norm_shapes(c: TransformerConfig) -> Dict[str, tuple]:
@@ -493,6 +674,8 @@ def logical_axes(config: TransformerConfig) -> Dict:
     c = config
     if c.kv_lora_rank:
         return _latent_logical_axes(c)
+    if c.by_kind:
+        return _kind_logical_axes(c)
     common = {
         "wq": ("layers", "embed", "heads"),
         "wk": ("layers", "embed", "kv"),
@@ -730,6 +913,18 @@ def _llama_block(c, x, lp, sin, cos, mesh, rules):
     return x + mlp.astype(x.dtype), aux
 
 
+def refuse_training(c: TransformerConfig) -> None:
+    """Raise, naming the keys at fault, for a configuration whose forms
+    only the cache path implements (``run_layers``, ``make_train_step``
+    and ``ParallelPlan.build`` ask)."""
+    if c.served_only:
+        raise NotImplementedError(
+            f"{', '.join(c.served_keys)}: set here, and served through "
+            f"prefill / decode_step only (as are "
+            f"{', '.join(c.SERVED_KEYS)}); training keeps Switch top-1 "
+            f"experts and one kind of dense attention layer")
+
+
 def run_layers(config: TransformerConfig, layer_params: Dict,
                x: jnp.ndarray, mesh=None, rules=None):
     """Scan the transformer blocks in ``layer_params`` (leaves stacked
@@ -738,12 +933,7 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
     pipeline-stage forward (a stage's trunk is a contiguous slice of
     the stacked layer leaves — same scan, fewer layers)."""
     c = config
-    if c.served_only:
-        raise NotImplementedError(
-            "experts_per_token, qk_norm, index_topk and the latent forms "
-            "(kv_lora_rank, sandwich_norm, n_dense_layers) are served "
-            "through prefill / decode_step only; training keeps Switch "
-            "top-1 experts and dense attention")
+    refuse_training(c)
     seq = x.shape[1]
     sin, cos = rotary_table(
         seq, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
@@ -982,8 +1172,13 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 # rows into it and attends it by (layer, block). Jitted with the cache
 # donated, a step updates the caller's buffer in place.
 
+#: a window layer's pools, beside the full layers' "k" / "v"
+WINDOW_POOLS = ("k_window", "v_window")
+
+
 def init_kv_cache(config: TransformerConfig, num_blocks: int,
-                  block_size: int) -> Dict[str, jnp.ndarray]:
+                  block_size: int, window_blocks: Optional[int] = None
+                  ) -> Dict[str, jnp.ndarray]:
     """Allocate the paged KV cache: ``{"k", "v"}`` of shape
     ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` in the
     compute dtype — ``kv_heads`` ahead of ``block_size`` so one head's
@@ -1001,7 +1196,17 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     With latent attention (``kv_lora_rank``) the cache is
     ``{"latent"}`` alone, ``[n_layers, num_blocks, 1, block_size,
     row]``: what the engine copies, ships, counts and sizes
-    (``kv_bytes_per_token``) it takes from the pools returned here."""
+    (``kv_bytes_per_token``) it takes from the pools returned here.
+
+    A stack with "window" layers (``layer_pattern``) has TWO KINDS OF
+    PAGE: ``k`` / ``v`` hold the "full" layers alone, ``[full layers,
+    num_blocks, ...]``, and ``k_window`` / ``v_window`` the window
+    layers, ``[window layers, window_blocks, ...]``, with block ids, a
+    table and a lifetime of their own (a window layer never reads
+    behind ``sliding_window``, so its pages are given back as the
+    sequence passes them). ``window_blocks=None``: as many as
+    ``num_blocks``, for a caller that reads both kinds through one
+    table."""
     c = config
     if c.kv_lora_rank:
         # a latent cache: ONE pool, a row a token and layer for every
@@ -1012,6 +1217,14 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
         return {"latent": jnp.zeros(
             (c.n_layers, num_blocks, 1, block_size,
              latent_row_width(c.kv_lora_rank, c.qk_rope_dim)), c.dtype)}
+    if c.by_kind and c.sliding_window:
+        page = (c.kv_heads, block_size, c.head_dim)
+        sizes = {"k": ("full", num_blocks), "v": ("full", num_blocks)}
+        sizes.update(dict.fromkeys(
+            WINDOW_POOLS, ("window", num_blocks if window_blocks is None
+                           else window_blocks)))
+        return {name: jnp.zeros((c.kind_layers(kind), n) + page, c.dtype)
+                for name, (kind, n) in sizes.items()}
     shape = (c.n_layers, num_blocks, c.kv_heads, block_size, c.head_dim)
     cache = {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
     if c.index_topk:
@@ -1180,12 +1393,151 @@ def _latent_attn_sublayer(c, h, lp, rot, layer, cache, block_tables,
     return out, cache
 
 
+def _kind_inv_freq(c: TransformerConfig, kind: str):
+    """(inverse frequencies, scale of sin and cos) of a kind's rotary:
+    numpy, from the configuration alone."""
+    def plain(dim, base):
+        return (1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64)
+                               / dim)).astype(np.float32)
+    if kind == "window":
+        return plain(c.window_rotary_dim or c.head_dim,
+                     c.window_rope_base), 1.0
+    if c.rope_yarn:
+        factor, original, fast, slow, scale = c.rope_yarn
+        return yarn_inv_freq(c.rotary_dim, c.rope_base, factor,
+                             int(original), fast, slow), float(scale)
+    return plain(c.rotary_dim, c.rope_base), 1.0
+
+
+def _kind_attn_sublayer(c, kind, h, lp, rot, layer, cache, tables, first,
+                        positions, write_mask, lens):
+    """The attention sublayer of a layer of ``kind`` ("full" |
+    "window"), cache layer ``layer`` of that kind's pools: the kind's
+    head count and rotary, its K/V rows scattered into its own pools
+    through its own table, a window layer's keys masked behind
+    ``sliding_window``, a sigmoid gate a head (``head_gate``) on the
+    heads' outputs ahead of ``wo``. ``tables`` / ``first``: the kind's
+    block table and the absolute position of its entry 0 (a window
+    layer's short table starts behind the window, not at position 0);
+    ``rot``: (sin, cos) at ``positions``. Returns (attn_out, cache)."""
+    e = h.shape[-1]
+    dt = c.dtype
+    H = c.kind_heads(kind)
+    hd = h.astype(dt)
+
+    def proj(w, n):
+        return jnp.einsum("bse,ehd->bshd", hd,
+                          w.reshape(e, n, -1).astype(dt))
+    q = apply_rotary(proj(lp["wq"], H), *rot, layout="neox")
+    k = apply_rotary(proj(lp["wk"], c.kv_heads), *rot, layout="neox")
+    v = proj(lp["wv"], c.kv_heads)
+    names = WINDOW_POOLS if kind == "window" else ("k", "v")
+    # positions as the kind's table counts them
+    rel = positions - first[:, None]
+    pools = _write_rows({n: cache[n] for n in names},
+                        dict(zip(names, (k, v))), layer, tables, rel,
+                        write_mask)
+    cache = {**cache, **pools}
+    br = c.paged_block_r_prefill \
+        if (h.shape[1] > 1 and c.paged_block_r_prefill) \
+        else c.paged_block_r
+    with jax.named_scope("paged_attn"):
+        att = paged_attention(
+            q, pools[names[0]], pools[names[1]], tables, rel, layer=layer,
+            lens=lens - first, impl=c.paged_impl, block_r=br or None,
+            window=c.sliding_window if kind == "window" else 0)
+    if c.head_gate:
+        with jax.named_scope("gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                hd, lp["wg"].astype(dt),
+                preferred_element_type=jnp.float32))
+            att = att * gate[..., None].astype(att.dtype)
+    out = jnp.einsum("bshd,hde->bse", att,
+                     lp["wo"].reshape(H, c.head_dim, e).astype(dt))
+    return out, cache
+
+
+def _kind_runs(c: TransformerConfig):
+    """The stack as runs of consecutive layers that share a stack of the
+    tree: (stack name, kind, dense, first index in the stack, layers,
+    first cache layer of the kind)."""
+    runs, seen, ordinal = [], {}, {"full": 0, "window": 0}
+    for l in range(c.n_layers):
+        kind, dense = c.layer_kind(l), l < c.n_dense_layers
+        name = "dense_layers" if dense else KIND_STACKS[kind]
+        at = seen.get(name, 0)
+        if runs and runs[-1][0] == name:
+            runs[-1][4] += 1
+        else:
+            runs.append([name, kind, dense, at, 1, ordinal[kind]])
+        seen[name] = at + 1
+        ordinal[kind] += 1
+    return [tuple(r) for r in runs]
+
+
+def _forward_kinds(c, params, ids, cache, block_tables, positions,
+                   write_mask, lens, window_tables, window_first):
+    """The trunk of a stack by kind of layer: each run of layers of one
+    kind is a scan of its own over that kind's stacked leaves (a run
+    that is a whole stack, as at this repo's depths, reads it in place;
+    a shorter one a static slice of it), all of them carrying ``(x,
+    cache)`` with every pool whole. With no window table given the
+    window layers read ``block_tables`` through the window mask."""
+    from ray_tpu.models.moe import EXPERT_LEAVES
+    if window_tables is None:
+        window_tables = block_tables
+        window_first = jnp.zeros(block_tables.shape[:1], jnp.int32)
+    zero = jnp.zeros_like(window_first)
+    tables = {"full": (block_tables, zero),
+              "window": (window_tables, window_first)}
+    rot = {kind: rotary_at(positions, *_kind_inv_freq(c, kind))
+           for kind in tables}
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
+    carry = (x, dict(cache))
+    for name, kind, dense, at, n, cache_layer in _kind_runs(c):
+        stack = params[name]
+        whole = {} if dense or not c.experts_per_token else \
+            {k: stack[k] for k in EXPERT_LEAVES}
+        scanned = {k: v if (at, n) == (0, v.shape[0]) else v[at:at + n]
+                   for k, v in stack.items() if k not in whole}
+
+        def step(carry, per_layer, kind=kind, dense=dense, whole=whole):
+            x, cache = carry
+            lp, layer, place = per_layer
+            with jax.named_scope("layer"):
+                h = rms_norm(x, lp["attn_norm"], eps=c.norm_eps)
+                with jax.named_scope("attn"), jax.named_scope(kind):
+                    att, cache = _kind_attn_sublayer(
+                        c, kind, h, lp, rot[kind], layer, cache,
+                        *tables[kind], positions, write_mask, lens)
+                x = x + att.astype(x.dtype)
+                h2 = rms_norm(x, lp["mlp_norm"],
+                              eps=c.norm_eps).astype(c.dtype)
+                if dense or not c.experts_per_token:
+                    with jax.named_scope("mlp"):
+                        mlp = _swiglu(c, h2, lp)
+                else:
+                    mlp, _ = _mlp_sublayer(c, h2, {**lp, **whole}, place)
+                return (x + mlp.astype(x.dtype), cache), None
+
+        carry, _ = jax.lax.scan(step, carry, (
+            scanned,
+            jnp.arange(cache_layer, cache_layer + n, dtype=jnp.int32),
+            jnp.arange(at, at + n, dtype=jnp.int32)))
+    x, cache = carry
+    x = _final_norm(c, params, x)
+    return _lm_head(c, params, x), cache
+
+
 def _forward_with_cache(c: TransformerConfig, params: Dict,
                         ids: jnp.ndarray, cache: Dict[str, jnp.ndarray],
                         block_tables: jnp.ndarray,
                         positions: jnp.ndarray,
                         write_mask: jnp.ndarray,
-                        lens: jnp.ndarray):
+                        lens: jnp.ndarray,
+                        window_tables: Optional[jnp.ndarray] = None,
+                        window_first: Optional[jnp.ndarray] = None):
     """Shared trunk of :func:`prefill` and :func:`decode_step`:
     (B, C) token ids at absolute ``positions`` -> (B, C, vocab) logits,
     writing each layer's k/v into the paged cache as it goes. ``lens``
@@ -1202,6 +1554,10 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
             "(experts_per_token > 0); Switch top-1 with capacity drops "
             "tokens by the batch they arrive in")
     _check_served_forms(c)
+    if c.by_kind:
+        return _forward_kinds(c, params, ids, cache, block_tables,
+                              positions, write_mask, lens, window_tables,
+                              window_first)
     bs = next(iter(cache.values())).shape[3]
     window = block_tables.shape[1] * bs
     if c.kv_lora_rank:
@@ -1299,7 +1655,9 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
 
 def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
             cache: Dict[str, jnp.ndarray], block_tables: jnp.ndarray,
-            start_pos: jnp.ndarray, lens: jnp.ndarray):
+            start_pos: jnp.ndarray, lens: jnp.ndarray,
+            window_tables: Optional[jnp.ndarray] = None,
+            window_first: Optional[jnp.ndarray] = None):
     """Process one prompt chunk per sequence, writing cache blocks.
 
     ``tokens``: (B, C) int32 — chunk ``start_pos[b] .. start_pos[b]+
@@ -1310,6 +1668,13 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
     prefix. Returns ``(logits (B, C, vocab), cache)``; the first
     generated token comes from ``logits[b, lens[b]-1]`` of the FINAL
     chunk.
+
+    ``window_tables`` ``(B, Tw)`` / ``window_first`` ``(B,)``, for a
+    stack with "window" layers: the window pools' block table of each
+    sequence and the absolute position its entry 0 starts at (a multiple
+    of the page), so the table holds the pages from behind the window to
+    the chunk's end and none before. Left out, the window layers read
+    ``block_tables`` (the window pools then have its pages).
     """
     b, chunk = tokens.shape
     positions = start_pos[:, None] + jnp.arange(chunk, dtype=jnp.int32)
@@ -1319,12 +1684,14 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
     live = (start_pos + lens).astype(jnp.int32)
     return _forward_with_cache(config, params, tokens, cache,
                                block_tables, positions, write_mask,
-                               live)
+                               live, window_tables, window_first)
 
 
 def decode_step(config: TransformerConfig, params: Dict,
                 token_ids: jnp.ndarray, cache: Dict[str, jnp.ndarray],
-                block_tables: jnp.ndarray, seq_lens: jnp.ndarray):
+                block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
+                window_tables: Optional[jnp.ndarray] = None,
+                window_first: Optional[jnp.ndarray] = None):
     """One batched decode step: each sequence's newest token
     (``token_ids``: (B,) int32, sitting at absolute position
     ``seq_lens[b]``) is written to its cache block and attends every
@@ -1336,7 +1703,7 @@ def decode_step(config: TransformerConfig, params: Dict,
     logits, cache = _forward_with_cache(
         config, params, token_ids[:, None], cache,
         block_tables, positions, write_mask,
-        seq_lens.astype(jnp.int32) + 1)
+        seq_lens.astype(jnp.int32) + 1, window_tables, window_first)
     return logits[:, 0], cache
 
 

@@ -124,7 +124,8 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                     lens: Optional[jnp.ndarray] = None,
                     sm_scale: Optional[float] = None,
                     impl: str = "auto",
-                    block_r: Optional[int] = None) -> jnp.ndarray:
+                    block_r: Optional[int] = None,
+                    window: int = 0) -> jnp.ndarray:
     """Attention of new-token queries against a paged KV cache.
 
     The serving decode/prefill primitive: keys and values live in a pool
@@ -159,6 +160,13 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     its rows below position 0) comes back zero under every ``impl``,
     and the kernel reads no page of its table.
 
+    ``window > 0``: a sliding-window layer, a query attends the
+    ``window`` keys up to and including its own position (``key > query
+    - window``) under every ``impl``. Positions enter the masks as
+    ``query - key`` only, so the table may start at any page of the
+    sequence if ``q_positions`` and ``lens`` count from that page's
+    first position (a window layer's short table).
+
     The reference path is the pure-XLA gather (one ``take`` per
     sequence over its block table, f32 softmax): work is
     O(B * C * T * block_size) regardless of true lengths; keep
@@ -179,7 +187,7 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         return paged_flash_attention(
             q, k_cache, v_cache, block_tables, q_positions, lens,
             layer=layer, sm_scale=sm_scale, block_r=block_r,
-            interpret=choice == "interpret")
+            interpret=choice == "interpret", window=window)
     # Gather each sequence's blocks out of the layer, one gather on the
     # 5-D pool: [B, T, KVH, bs, D] -> [B, K, KVH, D]
     def gather(cache):
@@ -189,6 +197,8 @@ def paged_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     # key slot j of the gathered view holds absolute position j
     key_pos = jnp.arange(t * bs, dtype=jnp.int32)
     mask = key_pos[None, None, :] <= q_positions[:, :, None]   # [B, C, K]
+    if window:
+        mask &= key_pos[None, None, :] > q_positions[:, :, None] - window
     if kvh != h:
         # GQA read without materializing a repeated cache copy: group
         # the (tiny) queries onto their kv head and einsum over the

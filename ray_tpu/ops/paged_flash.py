@@ -136,7 +136,8 @@ def paged_work_pages(lens, block_size: int):
 
 
 def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
-                  sm_scale: float, v_width: Optional[int] = None):
+                  sm_scale: float, v_width: Optional[int] = None,
+                  window: int = 0):
     """One (batch b, kv head group g, row block r, page group t) step:
     fold pages ``t·pp .. t·pp + pp − 1`` of sequence b into the row
     block's online softmax, one kv head of the group at a time.
@@ -152,8 +153,19 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
     body, ``rows_ref [B]``, the sequence's live query rows: a row block
     past them (the padding of a chunk that holds a 64-token question:
     128 heads' rows a token make 2048 tokens 512 blocks) holds no page
-    and folds none."""
-    if v_width is None:
+    and folds none.
+
+    ``window`` (a sliding-window layer): a row sees the ``window`` keys
+    up to its own position and none behind them. One more scalar rides
+    ahead of the body, ``first_ref [B, row blocks]``, the lowest
+    position among a row block's rows: a page group all of whose keys
+    lie behind that row's window lies behind every row's, and is a dead
+    step like one past the length (no copy started, body skipped)."""
+    first_ref = None
+    if window:
+        (bt_ref, lens_ref, layer_ref, first_ref, q_ref, pos_ref, k_hbm,
+         v_hbm, o_ref, m_s, l_s, acc_s, k_buf, v_buf, sem) = refs
+    elif v_width is None:
         (bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref,
          m_s, l_s, acc_s, k_buf, v_buf, sem) = refs
     else:
@@ -171,6 +183,10 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
         pages = jnp.where(pl.program_id(2) * block_r < rows_ref[b],
                           pages, 0)
     last = (pages - 1) // pp
+    # the first group with a key inside the window of the row block's
+    # first row; a block of padding alone (position "never") has none
+    first = 0 if not window else jnp.maximum(
+        first_ref[b, pl.program_id(2)] - (window - 1), 0) // (pp * bs)
 
     def copies(group, slot, j):
         """The K and the V copy of page ``j`` of ``group``, HBM page
@@ -214,9 +230,12 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
-        pl.when(pages > 0)(lambda: fetch(0, 0))
+        if window:
+            pl.when(first <= last)(lambda: fetch(first, first % 2))
+        else:
+            pl.when(pages > 0)(lambda: fetch(0, 0))
 
-    @pl.when(t <= last)
+    @pl.when((t <= last) if not window else (t >= first) & (t <= last))
     def _compute():
         slot = t % 2
 
@@ -246,7 +265,10 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
                 preferred_element_type=jnp.float32) * sm_scale
             key_pos = t * (pp * bs) + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(key_pos <= key_max, s, _NEG_INF)
+            seen = key_pos <= key_max
+            if window:
+                seen &= key_pos > pos_ref[0] - window
+            s = jnp.where(seen, s, _NEG_INF)
 
             m_prev = m_s[i]                    # (block_r, 128) lanes equal
             l_prev = l_s[i]
@@ -374,7 +396,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                           sm_scale: Optional[float] = None,
                           block_r: Optional[int] = None,
                           interpret: bool = False,
-                          v_width: Optional[int] = None) -> jnp.ndarray:
+                          v_width: Optional[int] = None,
+                          window: int = 0) -> jnp.ndarray:
     """Paged attention of new-token queries against the block pool.
 
     Same contract as the XLA reference
@@ -399,7 +422,18 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     ``[L, N, 1, bs, D]`` whose row is a token's key for every head and,
     in its first ``v_width`` columns, its value; the result is
     ``[B, C, H, v_width]``. A page is fetched once and read as both.
+
+    ``window > 0`` (a sliding-window layer): a query attends the
+    ``window`` keys up to and including its own position, ``key >
+    query - window``; page groups wholly behind a row block's window are
+    dead steps. The masks depend on ``query - key`` alone, so a caller
+    may hand over a table that starts at any page of the sequence with
+    ``q_positions`` and ``lens`` counted from that page's first
+    position: a window layer's table need hold no page behind the
+    window.
     """
+    if window and v_width is not None:
+        raise ValueError("a latent cache has no sliding window")
     if (v_width is None) != (v_cache is not None):
         raise ValueError("pass a V pool, or v_width for a latent pool "
                          "whose page is key and value, not both")
@@ -432,6 +466,13 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
         pos_rows = jnp.pad(pos_rows, ((0, 0), (0, rows_pad - rows)),
                            constant_values=-1)
+    if window:
+        # the lowest position of each row block, its padding (-1) aside;
+        # a block of padding alone starts behind every page
+        never = jnp.iinfo(jnp.int32).max
+        scalars_window = (jnp.min(jnp.where(
+            pos_rows >= 0, pos_rows, never).reshape(b, nr, block_r),
+            axis=2),)
     pos_rows = pos_rows[:, :, None]            # [B, rows_pad, 1] column
 
     def q_map(b_, g_, r_, t_, *scalars):
@@ -442,6 +483,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     pools = (k_cache,) if v_cache is None else (k_cache, v_cache)
     scalars = (block_tables.astype(jnp.int32), lens.astype(jnp.int32), layer)
+    if window:
+        scalars += scalars_window
     if v_width is not None:
         # live query rows a sequence: a chunk's padding (positions past
         # lens, the caller's to discard) is no row of them
@@ -467,7 +510,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, bs=bs, hb=hb, pp=pp, slots=t,
-                          sm_scale=float(sm_scale), v_width=v_width),
+                          sm_scale=float(sm_scale), v_width=v_width,
+                          window=int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, dv), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(

@@ -190,6 +190,8 @@ class ParallelPlan:
         ``ray_tpu`` cluster), gang-scheduled onto a slice placement
         group when ``slice_strategy`` is set and capacity exists."""
         self.validate_config(config)
+        from ray_tpu.models.transformer import refuse_training
+        refuse_training(config)
         if self.pp == 1:
             return _SPMDProgram(
                 self, config, learning_rate=learning_rate,

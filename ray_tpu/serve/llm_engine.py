@@ -87,6 +87,18 @@ class EngineConfig:
       TTFT-vs-inter-token-latency tradeoff knob.
     - ``num_kv_blocks``: KV pool size; 0 = auto (full occupancy + the
       reserved trash block idle slots write into).
+    - ``num_window_blocks``: size of the SECOND pool a model with
+      sliding-window layers has (``TransformerConfig.layer_pattern``),
+      the window layers' pages; 0 = auto, ``decode_slots`` sequences of
+      ``ceil((sliding_window + prefill_chunk) / kv_block_size) + 1``
+      pages (the most one can pin: the window behind a chunk, the
+      chunk, a ragged edge) and a trash page. A window layer's page
+      lives while a row of it lies inside ``[next position -
+      sliding_window, next position)`` of its sequence and is given
+      back in the tick that passes it (to the prefix trie, as evictable
+      cache, where the trie indexes it); the full layers' pages live as
+      long as the sequence. More than the auto size is room for cached
+      tails, which is what a prefix hit needs of the window layers.
     - ``enable_prefix_sharing``: refcounted radix-trie sharing of full
       prompt KV blocks (prefill skips matched prefixes).
     - ``spec_tokens``: draft tokens per slot per decode step via
@@ -105,6 +117,7 @@ class EngineConfig:
     max_seq_len: int = 256
     prefill_chunk: int = 32
     num_kv_blocks: int = 0
+    num_window_blocks: int = 0
     max_new_tokens: int = 64          # default per-request cap
     eos_token_id: Optional[int] = None
     enable_prefix_sharing: bool = True
@@ -140,14 +153,33 @@ class EngineConfig:
             return self.num_kv_blocks
         return 1 + self.decode_slots * self.blocks_per_seq
 
-    def kv_bytes_per_token(self, model_config) -> int:
-        """KV bytes/token — the HBM-budget side of the block math."""
+    def window_blocks_per_seq(self, model_config) -> int:
+        """Window-pool pages one sequence can pin (0: no window layers):
+        the window behind a chunk, the chunk, and a ragged edge."""
+        if not getattr(model_config, "sliding_window", 0):
+            return 0
+        return -(-(model_config.sliding_window + self.prefill_chunk)
+                 // self.kv_block_size) + 1
+
+    def resolved_window_blocks(self, model_config) -> int:
+        per_seq = self.window_blocks_per_seq(model_config)
+        if not per_seq:
+            return 0
+        return self.num_window_blocks or 1 + self.decode_slots * per_seq
+
+    def kv_bytes_per_token(self, model_config, kind: str = "full") -> int:
+        """KV bytes/token — the HBM-budget side of the block math, BY
+        KIND of page: ``"full"`` what a token takes for as long as its
+        sequence lives (every pool of a model with one kind of layer: k
+        and v, an indexer's keys, or one latent row), ``"window"`` what
+        it takes of the window layers' pools, and only while it lies
+        inside the window (0 without such layers)."""
         import jax
         from ray_tpu.models import init_kv_cache
-        # what a token takes of every pool init_kv_cache makes (k and v,
-        # an indexer's keys, or one latent row), by shape alone
+        from ray_tpu.models.transformer import WINDOW_POOLS
         pools = jax.eval_shape(lambda: init_kv_cache(model_config, 1, 1))
-        return sum(p.size * p.dtype.itemsize for p in pools.values())
+        return sum(p.size * p.dtype.itemsize for name, p in pools.items()
+                   if (name in WINDOW_POOLS) == (kind == "window"))
 
 
 def _unpack(rows, width: int, scalars: int):
@@ -176,16 +208,30 @@ def _step_fns(model_config, ec: EngineConfig):
     ``lens = 0`` as well, and writes nothing. With
     ``capture_logprobs`` prefill and decode also return the selected
     token's logprob (greedy argmax is unchanged — the extra output is
-    the RLHF rollout payload, not a sampling change)."""
+    the RLHF rollout payload, not a sampling change). A model with
+    window layers has ``1 + window_blocks_per_seq`` columns more behind
+    each table row: the absolute position the window layers' short table
+    starts at, and that table (ids of the window pool)."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.models import decode_step, prefill
     capture = ec.capture_logprobs
+    T = ec.blocks_per_seq
+
+    def tables(bt):
+        """(table, the window layers' arguments) out of a row's table
+        part: with window layers it is ``[table | the window table's
+        first position | window table]``, two arguments more out of the
+        same one transfer."""
+        if not ec.window_blocks_per_seq(model_config):
+            return bt, ()
+        return bt[:, :T], (bt[:, T + 1:], bt[:, T])
 
     def _prefill_fn(params, rows, cache):
         tokens, start, lens, bt = _unpack(rows, ec.prefill_chunk, 2)
+        bt, window = tables(bt)
         logits, cache = prefill(model_config, params, tokens, cache,
-                                bt, start, lens)
+                                bt, start, lens, *window)
         with jax.named_scope("sample"):
             last = jnp.take_along_axis(
                 logits, (lens - 1)[:, None, None], axis=1)[:, 0]
@@ -199,8 +245,9 @@ def _step_fns(model_config, ec: EngineConfig):
 
     def _decode_fn(params, rows, cache):
         toks, seq_lens, bt = _unpack(rows, 1, 1)
+        bt, window = tables(bt)
         logits, cache = decode_step(model_config, params, toks[:, 0],
-                                    cache, bt, seq_lens)
+                                    cache, bt, seq_lens, *window)
         with jax.named_scope("sample"):
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if capture:
@@ -243,7 +290,7 @@ class _Request:
                  "detailed", "trace", "t_enqueue_wall", "queue_wait_s",
                  "last_tok_wall", "tick_t0", "tick_toks", "export",
                  "adopt", "t_slot", "t_first_chunk", "tick_first_chunk",
-                 "n_chunks")
+                 "n_chunks", "wpages", "wspan")
 
     def __init__(self, rid: int, prompt: List[int], max_new_tokens: int,
                  eos_token_id: Optional[int]):
@@ -255,6 +302,10 @@ class _Request:
         self.state = _QUEUED
         self.slot: Optional[int] = None
         self.blocks: List[int] = []
+        # window layers' pages it pins, page index -> window-pool id,
+        # and the (first, last) page its staged window table holds
+        self.wpages: Dict[int, int] = {}
+        self.wspan: Optional[tuple] = None
         self.prefill_pos = 0          # prompt tokens already in cache
         self.seq_len = 0              # cache positions written
         self.generated = 0            # tokens emitted
@@ -323,6 +374,22 @@ class LLMEngine:
         if ec.kv_wire not in ("bf16", "int8"):
             raise ValueError(
                 f"kv_wire must be 'bf16' or 'int8', got {ec.kv_wire!r}")
+        # window layers: a second pool, a second table a slot
+        self._window = int(getattr(model_config, "sliding_window", 0))
+        Tw = self._window_table = ec.window_blocks_per_seq(model_config)
+        if Tw:
+            if ec.spec_tokens > 0:
+                raise ValueError(
+                    "spec_tokens > 0 is not served with window layers "
+                    "(sliding_window): a rejected draft's rows would "
+                    "have to be unwound from pages the window has given "
+                    "back")
+            if ec.resolved_window_blocks(model_config) \
+                    < 1 + ec.decode_slots * Tw:
+                raise ValueError(
+                    f"num_window_blocks {ec.num_window_blocks} is under "
+                    f"what {ec.decode_slots} sequences can pin at once, "
+                    f"{ec.decode_slots} x {Tw} pages and the trash page")
 
         # Carried-over paged-kernel follow-on: at long table windows
         # (>= 4k tokens per sequence) the chunked-prefill side of the
@@ -360,8 +427,9 @@ class LLMEngine:
             params if params is not None else init_params(
                 model_config, jax.random.PRNGKey(seed),
                 dtype=model_config.dtype))
-        self._cache = init_kv_cache(model_config, ec.resolved_num_blocks,
-                                    ec.kv_block_size)
+        self._cache = init_kv_cache(
+            model_config, ec.resolved_num_blocks, ec.kv_block_size,
+            *([ec.resolved_window_blocks(model_config)] if Tw else []))
 
         S, T = ec.decode_slots, ec.blocks_per_seq
         self._np = np
@@ -376,20 +444,29 @@ class LLMEngine:
         # ends (a decode step writes every slot's position `length`,
         # and position 0 of its first block is its first token's, or a
         # shared prefix's).
-        self._slot_rows = np.zeros((S, 2 + T), np.int32)
+        # With window layers a row goes on: the absolute position the
+        # slot's window table starts at, and that table (the window
+        # pool's ids, trash where it holds nothing)
+        self._slot_rows = np.zeros((S, 2 + T + (1 + Tw if Tw else 0)),
+                                   np.int32)
         self._last_tok = self._slot_rows[:, 0]
         self._seq_lens = self._slot_rows[:, 1]
         self._seq_lens[:] = _NO_SEQUENCE
-        self._block_tables = self._slot_rows[:, 2:]
+        self._block_tables = self._slot_rows[:, 2:2 + T]
+        self._window_rows = self._slot_rows[:, 2 + T:]
         self._slots: List[Optional[_Request]] = [None] * S
         self._free_slots = list(range(S))
         # refcounted block pool + radix prefix index (block 0 = trash,
         # reserved); sharing off still routes through the pool — match/
         # insert are simply skipped, so the free-list path is one code
         # path either way
-        from ray_tpu.serve.prefix_cache import PrefixBlockPool
-        self._pool = PrefixBlockPool(ec.resolved_num_blocks,
-                                     ec.kv_block_size, reserved=(0,))
+        from ray_tpu.serve.prefix_cache import (PrefixBlockPool,
+                                                WindowPagePool)
+        self._wpool = WindowPagePool(
+            ec.resolved_window_blocks(model_config)) if Tw else None
+        self._pool = PrefixBlockPool(
+            ec.resolved_num_blocks, ec.kv_block_size, reserved=(0,),
+            window_pool=self._wpool, window=self._window)
 
         # jit once at the fixed shapes; caches are donated so XLA
         # updates them in place step over step: the trunk carries the
@@ -412,11 +489,18 @@ class LLMEngine:
         # copy-on-write block copy (fully-matched prompt tail): one
         # block copied src -> dst across all layers; indices are
         # traced scalars, so every CoW reuses the same compiled program
+        # With window layers there are two kinds of page and two pairs of
+        # ids: ``window`` = (src, dst) in the window pools
+        from ray_tpu.models.transformer import WINDOW_POOLS
+
         @jax.named_scope("kv_copy")
-        def _copy_fn(cache, src, dst):
-            return {name: jax.lax.dynamic_update_slice_in_dim(
-                pool, jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=1),
-                dst, axis=1) for name, pool in cache.items()}
+        def _copy_fn(cache, src, dst, *window):
+            def one(name, pool):
+                a, b = window if name in WINDOW_POOLS else (src, dst)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    pool, jax.lax.dynamic_slice_in_dim(pool, a, 1, axis=1),
+                    b, axis=1)
+            return {name: one(name, pool) for name, pool in cache.items()}
 
         self._jit_copy = jax.jit(_copy_fn, donate_argnums=(0,))
 
@@ -521,6 +605,17 @@ class LLMEngine:
         self._decode_pages_live = 0
         self._decode_pages_window = 0
         self._decode_slots_skipped = 0
+        # by kind of layer: pages ONE layer of the kind reads in the
+        # decode programs and in the chunks (full: the sequence's live
+        # pages; window: those with a key inside the window), the hits a
+        # missing window tail shortened or refused, and the most window
+        # pages one sequence has pinned
+        self._pages_live = dict.fromkeys(
+            ("decode_full", "decode_window", "prefill_full",
+             "prefill_window", "prefill_keys_full",
+             "prefill_keys_window"), 0)
+        self._prefix_hits = self._prefix_hits_cut = 0
+        self._window_pinned_max = 0
         # the same for the paged kernel's innermost grid axis: it folds
         # P pages of a sequence a grid step, so a decode call takes
         # slots x ceil(T/P) steps of which sum(ceil(pages/P)) have a
@@ -795,6 +890,7 @@ class LLMEngine:
         decoding — the prefill half of the disaggregated pipeline.
         Blocking; see :class:`LLMServer.prefill_export` for the actor
         wrapper."""
+        self._refuse_with_window("prefill_export")
         req = self.submit(prompt_ids, max_new_tokens=1,
                           trace_ctx=trace_ctx, _export=True)
         deadline = time.monotonic() + timeout_s
@@ -829,6 +925,7 @@ class LLMEngine:
         the decode half of the disaggregated pipeline. The returned
         request streams exactly what a colocated ``submit`` of the same
         prompt would have streamed (first token included)."""
+        self._refuse_with_window("submit_adopt")
         if int(payload.get("block_size", 0)) != self.config.kv_block_size:
             raise ValueError(
                 f"shipped block_size {payload.get('block_size')} != "
@@ -846,6 +943,7 @@ class LLMEngine:
         drain-path rescue of a trie that would otherwise die with this
         process. Runs on the step thread. Returns None when there is
         nothing worth shipping."""
+        self._refuse_with_window("export_warm_prefixes")
         ec = self.config
         bs = ec.kv_block_size
         np, jnp = self._np, self._jnp
@@ -904,6 +1002,7 @@ class LLMEngine:
         thread; returns the number of blocks adopted."""
         if payload is None:
             return 0
+        self._refuse_with_window("import_warm_prefixes")
         if int(payload.get("block_size", 0)) != self.config.kv_block_size:
             raise ValueError(
                 f"migrated block_size {payload.get('block_size')} != "
@@ -965,6 +1064,17 @@ class LLMEngine:
 
         return self._run_on_step_thread(_do)
 
+    def _refuse_with_window(self, what: str) -> None:
+        """The hand-off and the warm-prefix migration move ONE kind of
+        page under one list of ids: with window layers a shipped prefix
+        would also need its window tail, which they do not move."""
+        if self._window_table:
+            raise NotImplementedError(
+                f"{what} is not served with window layers "
+                f"(sliding_window={self._window}): it ships the full "
+                f"layers' pages alone, and a prefix resumed without the "
+                f"window layers' rows behind it would not be exact")
+
     def _ship_blocks(self, blocks: List[int]) -> Dict[str, Any]:
         """These pages of every pool, packed for the wire (step thread):
         gathered ``blocks_per_seq`` ids at a time, the compiled shape."""
@@ -1015,9 +1125,10 @@ class LLMEngine:
         """The jitted programs this engine can run, by name. With
         speculation on, decode steps go through ``verify`` and the
         plain ``decode`` program is never called."""
-        progs = {"prefill": self._jit_prefill, "copy": self._jit_copy,
-                 "gather": self._jit_gather,
-                 "scatter": self._jit_scatter}
+        progs = {"prefill": self._jit_prefill, "copy": self._jit_copy}
+        if not self._window_table:      # the hand-off's, refused there
+            progs.update(gather=self._jit_gather,
+                         scatter=self._jit_scatter)
         if self._jit_verify is not None:
             progs["verify"] = self._jit_verify
         else:
@@ -1030,6 +1141,10 @@ class LLMEngine:
         written back is zeros, so no live block changes."""
         np, jnp = self._np, self._jnp
         zero = np.int32(0)
+        if self._window_table:       # no hand-off: the copy alone
+            self._cache = self._jit_copy(self._cache, *[zero] * 4)
+            self._jax.block_until_ready(self._cache)
+            return
         self._cache = self._jit_copy(self._cache, zero, zero)
         ids = jnp.zeros((self.config.blocks_per_seq,), jnp.int32)
         slabs = self._jit_gather(self._cache, ids)
@@ -1082,6 +1197,8 @@ class LLMEngine:
             self._decode_pages_live = self._decode_pages_window = 0
             self._decode_slots_skipped = 0
             self._decode_grid_steps = self._decode_grid_steps_live = 0
+            self._pages_live = dict.fromkeys(self._pages_live, 0)
+            self._window_pinned_max = 0
             self._sparse.clear()
             self._prompt_blocks_total = 0
             self._occupancy.clear()
@@ -1144,6 +1261,7 @@ class LLMEngine:
                 # kernel reads nothing for them), summed over steps:
                 # decode_steps x decode_slots less the occupancy
                 "decode_slots_skipped_total": self._decode_slots_skipped,
+                **self._window_stats(),
                 "decode_block_work_frac": (
                     round(self._decode_pages_live
                           / self._decode_pages_window, 4)
@@ -1236,6 +1354,42 @@ class LLMEngine:
                     "disables": self._spec_disables,
                 }
             return out
+
+    def _window_stats(self) -> Dict[str, Any]:
+        """``stats()``' keys of the second kind of page (call with the
+        lock held); none for a model without window layers."""
+        if self._wpool is None:
+            return {}
+        ws = self._wpool.stats()
+        live = self._pages_live
+        return {
+            # gauges: the window pool's pages, those free or cached,
+            # those some sequence pins now, and the most one has pinned
+            # (the bound window_blocks_per_seq, which the tests hold)
+            "window_total_blocks": self._wpool.total_managed,
+            "window_free_blocks": ws["reclaimable"],
+            "window_pages_pinned": ws["active"],
+            "window_pages_pinned_max": self._window_pinned_max,
+            # pages given back behind the window, and cached ones evicted
+            # (tails lost to pressure: what num_window_blocks is sized by)
+            "window_pages_released": ws["released_total"],
+            "window_evictions_total": ws["evictions_total"],
+            # pages one layer of a kind reads, summed over the programs
+            # (decode_pages_live is the full layers' count)
+            "decode_pages_live_full": live["decode_full"],
+            "decode_pages_live_window": live["decode_window"],
+            "prefill_pages_live_full": live["prefill_full"],
+            "prefill_pages_live_window": live["prefill_window"],
+            # keys the chunks' query rows attend in one layer of a kind,
+            # summed (position p: p + 1, on a window layer the window's
+            # at most)
+            "prefill_keys_live_full": live["prefill_keys_full"],
+            "prefill_keys_live_window": live["prefill_keys_window"],
+            # requests admitted on a trie hit, and those of them whose
+            # hit a missing window tail shortened or refused
+            "prefix_hits": self._prefix_hits,
+            "prefix_hits_cut": self._prefix_hits_cut,
+        }
 
     def pool_audit(self) -> List[str]:
         """Block-accounting integrity check (leak regression tests):
@@ -1524,9 +1678,17 @@ class LLMEngine:
                 matched: List[int] = []
                 mtok = 0
                 cow_src = None
+                # the window layers' pages behind the boundary, taken
+                # with it, and whether a missing one cut the hit short
+                wtail: Dict[int, int] = {}
+                cut = False
                 if ec.enable_prefix_sharing:
-                    matched, mtok, req.trie_node = \
-                        self._pool.match_prefix(req.prompt)
+                    if self._wpool is None:
+                        matched, mtok, req.trie_node = \
+                            self._pool.match_prefix(req.prompt)
+                    else:
+                        matched, mtok, req.trie_node, wtail, cut = \
+                            self._pool.match_prefix_window(req.prompt)
                     if mtok == plen and matched:
                         cow_src = matched.pop()
                         mtok -= bs
@@ -1540,17 +1702,30 @@ class LLMEngine:
                     self._pool.release(matched)
                     if cow_src is not None:
                         self._pool.release([cow_src])
+                    for wblock in wtail.values():
+                        self._wpool.decref(wblock)
                     req.trie_node = None
                     return
                 cow_dst = None
+                wcow = None
                 if cow_src is not None:
                     cow_dst = priv[0]
                     priv = priv[1:]
                     self._cow_copies += 1
+                    if wtail:
+                        # the window layers' page of the same chunk: a
+                        # private copy as well (a sequence can always pin
+                        # its share of the window pool)
+                        page = plen // bs - 1
+                        wcow = wtail[page], self._wpool.allocate(1)[0]
+                        wtail[page] = wcow[1]
+                req.wpages, req.wspan = wtail, None
                 req.blocks = matched + \
                     ([cow_dst] if cow_dst is not None else []) + priv
                 req.hit_blocks = len(matched) + \
                     (1 if cow_src is not None else 0)
+                self._prefix_hits_cut += cut
+                self._prefix_hits += bool(cut or req.hit_blocks)
                 self._pool.count_hits(req.hit_blocks)
                 req.trie_cursor = req.hit_blocks
                 req.prefill_pos = (plen - 1) if cow_src is not None \
@@ -1582,11 +1757,13 @@ class LLMEngine:
             # device-side CoW copy OUTSIDE the lock (the step thread is
             # the only device user; submit/cancel stay responsive)
             if cow_src is not None:
+                ids = [cow_src, cow_dst] + list(wcow or ())
                 self._cache = self._jit_copy(
-                    self._cache, self._np.int32(cow_src),
-                    self._np.int32(cow_dst))
+                    self._cache, *(self._np.int32(i) for i in ids))
                 with self._lock:
                     self._pool.release([cow_src])
+                    if wcow is not None:
+                        self._wpool.decref(wcow[0])
 
     def _allocate_locked(self, n: int) -> Optional[List[int]]:
         """``n`` private blocks for an admission, or None under pool
@@ -1793,6 +1970,50 @@ class LLMEngine:
             req.out.put(payload)
             self._release_locked(req)
 
+    def _window_row(self, req: _Request, start: int, n: int):
+        """The window layers' side of a program over positions ``start
+        .. start + n - 1`` of ``req`` (call with the lock held): give
+        back the pages wholly behind the window of ``start`` (to the
+        trie's cache where it names them), take pages for the rows to be
+        written, and return ``[first position, table]`` for the row, or
+        None where the staged one still holds (no page came or went)."""
+        bs, pool = self.config.kv_block_size, self._wpool
+        first = max(0, start - self._window + 1) // bs
+        last = (start + n - 1) // bs
+        if req.wspan == (first, last):
+            return None
+        plen = len(req.prompt)
+        for page in [p for p in req.wpages if p < first]:
+            pool.decref(req.wpages.pop(page),
+                        near=self._near_prompt_end(page, plen))
+            pool.released_total += 1
+        new = [p for p in range(first, last + 1) if p not in req.wpages]
+        if new and new[0] < start // bs:
+            raise RuntimeError(
+                f"window page {new[0]} of a sequence at position {start} "
+                f"was given back while a query still sees it")
+        got = pool.allocate(len(new))
+        if got is None:
+            raise RuntimeError(
+                f"the window pool cannot cover {len(new)} pages: "
+                f"{pool.stats()}")
+        req.wpages.update(zip(new, got))
+        req.wspan = first, last
+        self._window_pinned_max = max(self._window_pinned_max,
+                                      len(req.wpages))
+        row = self._np.zeros((1 + self._window_table,), self._np.int32)
+        row[0] = first * bs
+        row[1:2 + last - first] = [req.wpages[p]
+                                   for p in range(first, last + 1)]
+        return row
+
+    def _near_prompt_end(self, page: int, prompt_len: int) -> bool:
+        """The eviction class of a cached window page: within two
+        windows of its request's prompt end (a re-ask of the document
+        resumes from there) or far behind it (evicted first)."""
+        return (page + 1) * self.config.kv_block_size \
+            > prompt_len - 2 * self._window
+
     def _launch_chunk(self) -> Optional[tuple]:
         """Stage and dispatch the next chunk of the request at the
         backlog's head. Returns what :meth:`_finish_chunk` needs, the
@@ -1804,14 +2025,28 @@ class LLMEngine:
         np = self._np
         ec = self.config
         clock = self._clock
-        C = ec.prefill_chunk
+        C, bs = ec.prefill_chunk, ec.kv_block_size
         start = req.prefill_pos
         n = min(C, len(req.prompt) - start)
         with clock.phase("engine.prefill.stage"):
-            row = np.zeros((1, C + 2 + ec.blocks_per_seq), np.int32)
+            row = np.zeros((1, C + self._slot_rows.shape[1]), np.int32)
             row[0, :n] = req.prompt[start:start + n]
             row[0, C:C + 2] = start, n
             row[0, C + 2:C + 2 + len(req.blocks)] = req.blocks
+            if self._wpool is not None:
+                with self._lock, clock.phase("engine.window.release"):
+                    row[0, C + 2 + ec.blocks_per_seq:] = \
+                        self._window_row(req, start, n)
+                    req.wspan = None      # a chunk's table is its own
+                live, w = self._pages_live, self._window
+                live["prefill_full"] += -(-(start + n) // bs)
+                live["prefill_window"] += (start + n - 1) // bs + 1 \
+                    - max(0, start - w + 1) // bs
+                # keys seen: position p sees p + 1, a window layer w at most
+                live["prefill_keys_full"] += n * start + n * (n + 1) // 2
+                ramp = max(0, min(start + n, w) - start)   # rows under w
+                live["prefill_keys_window"] += ramp * start \
+                    + ramp * (ramp + 1) // 2 + (n - ramp) * w
             t0w = time.time()
             t0 = time.monotonic()
             if req.t_first_chunk is None:
@@ -1877,7 +2112,8 @@ class LLMEngine:
                     chunk = req.prompt[i * ec.kv_block_size:
                                        (i + 1) * ec.kv_block_size]
                     node, _ = self._pool.insert_child(
-                        req.trie_node, chunk, req.blocks[i])
+                        req.trie_node, chunk, req.blocks[i],
+                        req.wpages.get(i))
                     req.trie_node = node   # None = parent evicted: stop
                     req.trie_cursor += 1
         if req.prefill_pos < len(req.prompt):
@@ -1975,6 +2211,8 @@ class LLMEngine:
             with self._lock:
                 self._decode_steps += 1
                 self._occupancy[len(active)] += 1
+                if self._wpool is not None:
+                    self._stage_window_rows(active)
                 rows = self._slot_rows.copy()
             self._account_decode_pages(rows[:, 1] + 1)
             self._account_queries([r.seq_len for r in active],
@@ -1990,6 +2228,20 @@ class LLMEngine:
                 out, self._cache = res
                 lps = None
         return active, t0, out, lps
+
+    def _stage_window_rows(self, active: List[_Request]) -> None:
+        """Before a decode step (lock held): each decoding sequence's
+        window table follows its next position, the pages it passed go
+        back, and the pages one window layer reads are booked."""
+        bs = self.config.kv_block_size
+        with self._clock.phase("engine.window.release"):
+            for req in active:
+                row = self._window_row(req, req.seq_len, 1)
+                if row is not None:
+                    self._window_rows[req.slot] = row
+                first, last = req.wspan
+                self._pages_live["decode_window"] += last - first + 1
+                self._pages_live["decode_full"] += req.seq_len // bs + 1
 
     def _finish_decode(self, decode: tuple) -> None:
         """Fetch a launched decode step's tokens and emit them."""
@@ -2222,9 +2474,14 @@ class LLMEngine:
         if req.slot is not None and self._slots[req.slot] is req:
             self._slots[req.slot] = None
             self._block_tables[req.slot, :] = 0
+            self._window_rows[req.slot, :] = 0
             self._seq_lens[req.slot] = _NO_SEQUENCE
             self._last_tok[req.slot] = 0
             self._free_slots.append(req.slot)
+            for page, wblock in req.wpages.items():
+                self._wpool.decref(wblock, near=self._near_prompt_end(
+                    page, len(req.prompt)))
+            req.wpages, req.wspan = {}, None
             # decref, not free: trie-indexed blocks stay warm for the
             # next request sharing this prefix (evicted LRU only under
             # pool pressure)

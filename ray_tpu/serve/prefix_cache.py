@@ -33,6 +33,19 @@ so an eviction is O(log n); ``stats()`` reads counts kept where
 refcounts change; the root's children are kept in touch order with
 their fingerprints, so ``root_fingerprints`` is O(limit).
 
+Two kinds of page (a model with sliding-window layers): the window
+layers' K/V live in pools of their own with ids, a free list and
+refcounts of their own (:class:`WindowPagePool`). A trie node then names
+up to two pages, the full layers' (``block``, as above) and the window
+layers' (``wblock``), and they live differently: a sequence holds its
+full pages to its end, and a window page only while a row of it lies
+inside the window of the sequence's next position. A window page that
+slides out while the trie indexes it stays as evictable cache, like a
+finished request's pages, and is evicted on its own, leaving the node
+and its full page in place. A prefix hit must be exact, so
+``match_prefix`` stops at the deepest page boundary ``P`` whose window
+pages covering ``[P - window, P)`` are all there, or at none.
+
 Thread model: the pool is NOT internally locked — the engine calls it
 with its scheduler lock held (all mutations happen on the step
 thread).
@@ -68,7 +81,7 @@ class _TrieNode:
     physical block holding that chunk's KV."""
 
     __slots__ = ("children", "parent", "key", "block", "touch",
-                 "detached", "hits")
+                 "detached", "hits", "wblock")
 
     def __init__(self, parent: Optional["_TrieNode"],
                  key: Optional[tuple], block: Optional[int]):
@@ -79,6 +92,151 @@ class _TrieNode:
         self.touch = 0          # LRU clock stamp
         self.detached = False   # evicted — inserts under it must abort
         self.hits = 0           # prefix-match count (migration floor)
+        self.wblock: Optional[int] = None   # the window layers' page
+
+
+class WindowPagePool:
+    """Refcounted allocator of the window layers' pages. A page is free,
+    referenced (a running sequence has a row of it inside its window),
+    or cached: unreferenced and named by a trie node (``node.wblock``),
+    from where a later prefix hit can take it. Cached pages are evicted
+    under pressure in TWO CLASSES, LRU within each: first the pages far
+    behind their request's prompt end (a long prefill sheds thousands of
+    them, and only a hit in mid-prompt could want one), then the pages
+    near it (the tail a re-ask of the same document resumes from).
+
+    It is a class beside :class:`PrefixBlockPool` and not a base under
+    both: what the two have in common is a deque of free ids and a dict
+    of refcounts, and every method over them differs. The full pool
+    evicts through the trie (a ref-0 LEAF, never a page a longer prefix
+    hangs under, and the node goes with it) and keeps its ``cached`` /
+    ``shared`` counts where refcounts change; a window page is evicted
+    on its own, by class, and its node and full page stay. A shared
+    core would be hooks at every step of the accepted pool's
+    ``incref`` / ``decref`` / ``allocate``, which run under the tick of
+    every serving cell, for some fifteen lines."""
+
+    def __init__(self, num_blocks: int, reserved: Sequence[int] = (0,)):
+        self._reserved = frozenset(reserved)
+        managed = [b for b in range(num_blocks)
+                   if b not in self._reserved]
+        self.total_managed = len(managed)
+        self._free: "collections.deque[int]" = collections.deque(managed)
+        self._ref: Dict[int, int] = {}
+        self._node_of: Dict[int, _TrieNode] = {}   # trie-resident pages
+        # cached page -> (class, touch): what its live heap entry says
+        self._stamp: Dict[int, Tuple[int, int]] = {}
+        self._evictable: List[Tuple[int, int, int]] = []
+        self._clock = 0
+        self.evictions_total = 0
+        self.released_total = 0    # refs dropped behind a window
+
+    def allocate(self, n: int) -> Optional[List[int]]:
+        """``n`` private pages (refcount 1), evicting cached ones under
+        pressure; None, with nothing taken, when that cannot cover it."""
+        got: List[int] = []
+        while len(got) < n:
+            if not self._free and not self._evict_one():
+                for b in got:
+                    del self._ref[b]
+                    self._free.append(b)
+                return None
+            b = self._free.popleft()
+            self._ref[b] = 1
+            got.append(b)
+        return got
+
+    def incref(self, block: int) -> None:
+        self._ref[block] = self._ref.get(block, 0) + 1
+        self._stamp.pop(block, None)       # cached no longer
+
+    def decref(self, block: int, near: bool = True) -> None:
+        """Drop one reference. The last one frees the page, or leaves it
+        cached where a trie node names it: ``near`` (the prompt's end)
+        is the class evicted last."""
+        n = self._ref[block] - 1
+        if n > 0:
+            self._ref[block] = n
+            return
+        del self._ref[block]
+        if block not in self._node_of:
+            self._free.append(block)
+            return
+        self._clock += 1
+        stamp = self._stamp[block] = (int(near), self._clock)
+        heapq.heappush(self._evictable, stamp + (block,))
+        if len(self._evictable) > 2 * len(self._node_of) + 64:
+            self._evictable = [e for e in self._evictable
+                               if self._stamp.get(e[2]) == e[:2]]
+            heapq.heapify(self._evictable)
+
+    def attach(self, block: int, node: _TrieNode) -> None:
+        """``node`` names this (referenced) page from now on."""
+        node.wblock = block
+        self._node_of[block] = node
+
+    def forget(self, node: _TrieNode) -> None:
+        """``node`` leaves the trie: its page, if it names one, is no
+        cache any more (freed if nobody holds it)."""
+        block, node.wblock = node.wblock, None
+        if block is None:
+            return
+        del self._node_of[block]
+        if self._stamp.pop(block, None) is not None:
+            self._free.append(block)
+
+    def _evict_one(self) -> bool:
+        while self._evictable:
+            cls, touch, block = heapq.heappop(self._evictable)
+            if self._stamp.get(block) == (cls, touch):
+                break
+        else:
+            return False
+        del self._stamp[block]
+        self._node_of.pop(block).wblock = None
+        self._free.append(block)
+        self.evictions_total += 1
+        return True
+
+    def stats(self) -> Dict[str, int]:
+        return {"free": len(self._free), "cached": len(self._stamp),
+                "reclaimable": len(self._free) + len(self._stamp),
+                "active": len(self._ref),
+                "evictions_total": self.evictions_total,
+                "released_total": self.released_total}
+
+    def audit(self) -> List[str]:
+        problems: List[str] = []
+        free, ref, cached = set(self._free), set(self._ref), \
+            set(self._stamp)
+        if len(free) != len(self._free):
+            problems.append("window: duplicate pages on the free list")
+        for a, b, what in ((free, ref, "free and referenced"),
+                           (free, cached, "free and cached"),
+                           (ref, cached, "referenced and cached")):
+            if a & b:
+                problems.append(f"window pages both {what}: "
+                                f"{sorted(a & b)}")
+        managed = {b for b in range(
+            self.total_managed + len(self._reserved))
+            if b not in self._reserved}
+        if free | ref | cached != managed:
+            problems.append(
+                f"window pages leaked {sorted(managed - free - ref - cached)}"
+                f" or unmanaged {sorted((free | ref | cached) - managed)}")
+        if set(self._node_of) != cached | (ref & set(self._node_of)) \
+                or cached - set(self._node_of):
+            problems.append("window: cached pages no trie node names")
+        for block, node in self._node_of.items():
+            if node.wblock != block or node.detached:
+                problems.append(f"window page {block}: its node names "
+                                f"{node.wblock}, detached {node.detached}")
+        queued = {b for c, t, b in self._evictable
+                  if self._stamp.get(b) == (c, t)}
+        if cached - queued:
+            problems.append(f"cached window pages no eviction can find: "
+                            f"{sorted(cached - queued)}")
+        return problems
 
 
 class PrefixBlockPool:
@@ -87,10 +245,15 @@ class PrefixBlockPool:
     trash block — are never handed out)."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 reserved: Sequence[int] = (0,)):
+                 reserved: Sequence[int] = (0,),
+                 window_pool: Optional[WindowPagePool] = None,
+                 window: int = 0):
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
         self.block_size = block_size
+        # the window layers' pages and the positions a query sees back
+        self.window_pool = window_pool
+        self.window = window
         self._reserved = frozenset(reserved)
         managed = [b for b in range(num_blocks)
                    if b not in self._reserved]
@@ -180,23 +343,74 @@ class PrefixBlockPool:
         ``(blocks, matched_tokens, node)`` — matched blocks are
         incref'd (caller owns one reference each; release on abort) and
         ``node`` is the deepest matched trie node (the parent for this
-        request's own inserts)."""
+        request's own inserts). A pool with two kinds of page is asked
+        through :meth:`match_prefix_window`."""
+        if self.window_pool is not None:
+            raise ValueError("two kinds of page: match_prefix_window")
+        return self._take(self._walk(tokens))
+
+    def match_prefix_window(self, tokens: Sequence[int]):
+        """:meth:`match_prefix` where a node names two kinds of page: a
+        hit is exact or it is not taken, so the match is cut back to the
+        deepest page boundary whose window pages (those covering
+        ``[boundary - window, boundary)``) are all in the trie, or to
+        nothing. Returns ``(blocks, matched_tokens, node, tail, cut)``:
+        ``tail`` is ``{page index: window page}`` of that boundary,
+        incref'd like ``blocks`` (the caller's to ``decref``), ``cut``
+        whether a missing tail made the match stop short."""
+        path = self._walk(tokens)
+        keep = self._cut_to_window_tail(path)
+        first = self.window_tail_from(keep * self.block_size)
+        tail = {j: path[j].wblock for j in range(first, keep)}
+        for wblock in tail.values():
+            self.window_pool.incref(wblock)
+        return self._take(path[:keep]) + (tail, keep < len(path))
+
+    def _walk(self, tokens: Sequence[int]) -> List[_TrieNode]:
         node = self._root
-        blocks: List[int] = []
+        path: List[_TrieNode] = []
         bs = self.block_size
         for i in range(len(tokens) // bs):
-            child = node.children.get(tuple(tokens[i * bs:(i + 1) * bs]))
-            if child is None:
+            node = node.children.get(tuple(tokens[i * bs:(i + 1) * bs]))
+            if node is None:
                 break
-            node = child
-            blocks.append(node.block)
+            path.append(node)
+        return path
+
+    def _take(self, path: List[_TrieNode]
+              ) -> Tuple[List[int], int, _TrieNode]:
+        for node in path:
             self.incref(node.block)
             self._touch(node)
             node.hits += 1
         # hits_total is NOT bumped here: a match may be released when
         # allocation fails (admission wait) and retried — the engine
         # counts hits once, on successful admission (count_hits)
-        return blocks, len(blocks) * bs, node
+        return [n.block for n in path], len(path) * self.block_size, \
+            path[-1] if path else self._root
+
+    def window_tail_from(self, boundary: int) -> int:
+        """The first page a sequence resumed at position ``boundary``
+        needs of the window layers: the one with key ``boundary -
+        window`` (what the query at ``boundary - 1``, a copy-on-write's,
+        still sees)."""
+        return max(0, boundary - self.window) // self.block_size
+
+    def _cut_to_window_tail(self, path: List[_TrieNode]) -> int:
+        """How many nodes of ``path`` an exact hit may take: up to the
+        deepest page boundary with every window page of its tail."""
+        missing = -1                    # the last page < i without one
+        last_missing = []
+        for i, node in enumerate(path):
+            last_missing.append(missing)
+            if node.wblock is None:
+                missing = i
+        last_missing.append(missing)
+        i = len(path)
+        while i > 0 and last_missing[i] >= self.window_tail_from(
+                i * self.block_size):
+            i -= 1
+        return i
 
     def count_hits(self, n: int) -> None:
         self.hits_total += n
@@ -243,14 +457,20 @@ class PrefixBlockPool:
         self._free.append(node.block)
         self._cached -= 1
         self.evictions_total += 1
+        if self.window_pool is not None:
+            self.window_pool.forget(node)
         return True
 
     # ------------------------------------------------------ insertion
     def insert_child(self, parent: Optional[_TrieNode],
-                     chunk: Sequence[int], block: int
+                     chunk: Sequence[int], block: int,
+                     wblock: Optional[int] = None
                      ) -> Tuple[Optional[_TrieNode], bool]:
         """Index ``block`` (full, holding exactly ``chunk``) under
-        ``parent``. Returns ``(node, inserted)``:
+        ``parent``, and ``wblock``, the window layers' page of the same
+        chunk (referenced by the caller), with it: also under a node
+        that was there already and has lost its own to eviction.
+        Returns ``(node, inserted)``:
 
         - fresh insert → the new node, True;
         - the path already exists (a concurrent request with the same
@@ -265,8 +485,12 @@ class PrefixBlockPool:
         existing = parent.children.get(key)
         if existing is not None:
             self._touch(existing)
+            if wblock is not None and existing.wblock is None:
+                self.window_pool.attach(wblock, existing)
             return existing, False
         node = _TrieNode(parent, key, block)
+        if wblock is not None:
+            self.window_pool.attach(wblock, node)
         parent.children[key] = node
         self._node_of[block] = node
         if block not in self._ref:
@@ -408,4 +632,11 @@ class PrefixBlockPool:
         if list(self._root_fps) != sorted(self._root.children.values(),
                                           key=lambda n: n.touch):
             problems.append("root children out of touch order")
+        if self.window_pool is not None:
+            problems += self.window_pool.audit()
+            held = {n.wblock for n in self._node_of.values()
+                    if n.wblock is not None}
+            if held != set(self.window_pool._node_of):
+                problems.append("window pages and the trie's nodes "
+                                "disagree on who names which")
         return problems

@@ -637,3 +637,153 @@ def test_pool_rank_and_layer_must_agree():
     with pytest.raises(ValueError, match="layer"):
         paged_attention(q, kp[0], kp[0], bt, pos, layer=0,
                         impl="reference")
+
+
+# ------------------------------------------------- a sliding window
+def _windowed_reference(q, k_seq, v_seq, pos, window):
+    """``attention_reference`` over the ordered sequence with the mask
+    of a window layer: key <= query, key > query - window. GQA by
+    repeating the (tiny) ordered K/V."""
+    rep = q.shape[2] // k_seq.shape[2]
+    keys = np.arange(k_seq.shape[1])[None, None, None, :]
+    at = np.asarray(pos)[:, None, :, None]
+    mask = (keys <= at) & (keys > at - window)
+    return np.asarray(attention_reference(
+        jnp.asarray(q), jnp.repeat(jnp.asarray(k_seq), rep, axis=2),
+        jnp.repeat(jnp.asarray(v_seq), rep, axis=2),
+        mask=jnp.asarray(mask)))
+
+
+@pytest.mark.parametrize("pp", [None, 2, 4])
+@pytest.mark.parametrize("H,KVH", [(6, 2), (8, 2)])
+@pytest.mark.parametrize("impl", ["interpret", "reference"])
+def test_window_decode_against_the_ordered_sequence(impl, H, KVH, pp,
+                                                    group_of):
+    """Decode with a window of 10 over sequences of 5, 23 and 38 keys
+    (inside the window, a window and a bit, several groups behind it):
+    3 and 4 query rows a kv head, one of them no whole sublane tile."""
+    group_of(pp)
+    B, D, bs, T, W = 3, 16, 4, 10, 10
+    k_seq, v_seq, kc, vc, bt = _paged_case(10, B, 40, H, KVH, D, bs, T)
+    q = np.random.default_rng(11).normal(size=(B, 1, H, D)) \
+        .astype(np.float32)
+    lens = np.array([5, 23, 38], np.int32)
+    pos = (lens - 1)[:, None]
+    got = paged_attention(q, kc, vc, bt, jnp.asarray(pos),
+                          lens=jnp.asarray(lens), impl=impl, window=W)
+    np.testing.assert_allclose(
+        np.asarray(got), _windowed_reference(q, k_seq, v_seq, pos, W),
+        **TOL)
+    # the window does something: without it the long ones differ
+    wide = paged_attention(q, kc, vc, bt, jnp.asarray(pos),
+                           lens=jnp.asarray(lens), impl=impl)
+    assert np.abs(np.asarray(wide) - np.asarray(got))[1:].max() > 1e-3
+
+
+@pytest.mark.parametrize("pp", [None, 2])
+@pytest.mark.parametrize("block_r", [None, 8])
+def test_a_chunk_that_straddles_the_window(block_r, pp, group_of):
+    """A chunk of 12 queries at positions 17..28 with a window of 10:
+    its first rows see keys the last ones do not and the other way
+    round, over row blocks whose first group differs (block_r 8: three
+    blocks of 24 rows a kv head), with a padded tail."""
+    group_of(pp)
+    B, C, H, KVH, D, bs, T, W = 2, 12, 4, 2, 8, 4, 8, 10
+    k_seq, v_seq, kc, vc, bt = _paged_case(12, B, 32, H, KVH, D, bs, T)
+    q = np.random.default_rng(13).normal(size=(B, C, H, D)) \
+        .astype(np.float32)
+    lens = np.array([29, 26], np.int32)        # the second: 3 rows padding
+    pos = np.stack([np.arange(C, dtype=np.int32) + 17] * B)
+    want = _windowed_reference(q, k_seq, v_seq, pos, W)
+    for impl in ("interpret", "reference"):
+        got = np.asarray(paged_attention(
+            q, kc, vc, bt, jnp.asarray(pos), lens=jnp.asarray(lens),
+            impl=impl, window=W, block_r=block_r))
+        np.testing.assert_allclose(got[0], want[0], **TOL)
+        np.testing.assert_allclose(got[1, :9], want[1, :9], **TOL)
+
+
+@pytest.mark.parametrize("impl", ["interpret", "reference"])
+def test_a_short_table_shifted_behind_the_window_equals_the_whole(impl):
+    """The masks see ``query - key`` alone: a table that starts at the
+    page of the first key the first query sees, with positions and
+    lengths counted from that page's first position, gives what the
+    whole table gives, decode and chunk alike, and reads no page
+    behind it (filled with NaN here)."""
+    B, H, KVH, D, bs, T, W = 2, 8, 2, 8, 4, 12, 10
+    k_seq, v_seq, kc, vc, bt = _paged_case(14, B, 48, H, KVH, D, bs, T)
+    rng = np.random.default_rng(15)
+    for C, start in ((1, 41), (6, 30)):
+        q = rng.normal(size=(B, C, H, D)).astype(np.float32)
+        pos = np.stack([np.arange(C, dtype=np.int32) + start] * B)
+        lens = np.full((B,), start + C, np.int32)
+        whole = np.asarray(paged_attention(
+            q, kc, vc, bt, jnp.asarray(pos), lens=jnp.asarray(lens),
+            impl=impl, window=W))
+        first = max(0, start - W + 1) // bs          # a page's index
+        width = (start + C - 1) // bs - first + 1
+        short = np.zeros((B, width + 1), np.int32)   # a trash slot behind
+        short[:, :width] = bt[:, first:first + width]
+        behind = np.unique(bt[:, :first])
+        kn, vn = kc.copy(), vc.copy()
+        kn[behind] = np.nan
+        vn[behind] = np.nan
+        got = np.asarray(paged_attention(
+            q, kn, vn, jnp.asarray(short), jnp.asarray(pos - first * bs),
+            lens=jnp.asarray(lens - first * bs), impl=impl, window=W))
+        np.testing.assert_allclose(got, whole, **TOL)
+        np.testing.assert_allclose(
+            whole, _windowed_reference(q, k_seq, v_seq, pos, W), **TOL)
+
+
+def test_window_refuses_a_latent_pool():
+    with pytest.raises(ValueError, match="no sliding window"):
+        paged_flash_attention(
+            jnp.zeros((1, 1, 2, 8)), jnp.zeros((1, 2, 1, 4, 8)), None,
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+            jnp.ones((1,), jnp.int32), layer=0, v_width=4, window=4,
+            interpret=True)
+
+
+@pytest.mark.parametrize("window", [0, 40])
+@pytest.mark.parametrize("seed", [21, 61])
+def test_the_scores_are_float32_and_bf16_scores_are_told(seed, window,
+                                                         monkeypatch):
+    """bf16 q, K and V with scores of order 16 (a sharp softmax), decode
+    over 37, 90 and 128 keys: the kernel's output lies within 0.4% (rms,
+    of the output's rms) of ``attention_reference`` in float32 on the
+    same bf16 numbers, which is the rounding of its bf16 output (0.12 -
+    0.15%), and the same kernel with its scores rounded to bf16 ahead
+    of the softmax does not (1.2 - 1.3%): a kernel that drops the
+    scores' precision is told here, which the benchmark's ``logits``
+    cannot (PERF.md section 6, PR 45: 1.9% against 1.7 sound)."""
+    B, H, KVH, D, bs, T, limit = 3, 8, 2, 64, 16, 8, 4e-3
+    k_seq, v_seq, kc, vc, bt = _paged_case(seed, B, T * bs, H, KVH, D,
+                                           bs, T)
+    q = 16 * np.random.default_rng(seed + 1).normal(size=(B, 1, H, D))
+    b16 = lambda a: jnp.asarray(a, jnp.float32).astype(jnp.bfloat16)
+    f32 = lambda a: np.asarray(b16(a).astype(jnp.float32))
+    lens = np.array([37, 90, 128], np.int32)
+    pos = (lens - 1)[:, None]
+    want = _windowed_reference(f32(q), f32(k_seq), f32(v_seq), pos,
+                               window or T * bs)
+
+    def off():
+        got = paged_attention(b16(q), b16(kc), b16(vc), bt,
+                              jnp.asarray(pos), lens=jnp.asarray(lens),
+                              impl="interpret", window=window)
+        assert got.dtype == jnp.bfloat16
+        err = np.asarray(got.astype(jnp.float32)) - want
+        return np.sqrt(np.mean(err ** 2) / np.mean(want ** 2))
+
+    assert off() < limit / 2.5
+    real = jax.lax.dot_general
+
+    def bf16_scores(a, b, dims, **kw):
+        out = real(a, b, dims, **kw)
+        if dims == (((1,), (1,)), ((), ())):       # q k^T, the scores
+            out = out.astype(jnp.bfloat16).astype(jnp.float32)
+        return out
+
+    monkeypatch.setattr(jax.lax, "dot_general", bf16_scores)
+    assert off() > limit * 2.5

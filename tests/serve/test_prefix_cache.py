@@ -331,3 +331,45 @@ def test_allocate_costs_what_it_evicts_not_the_pool(monkeypatch):
     assert p.evictions_total == 256
     assert len(ops) <= 3 * 256 and max(ops) <= 4096
     assert p.audit() == []
+
+
+def test_window_pages_two_classes_and_the_exact_hit():
+    """A trie node names two kinds of page. The window layers' pages are
+    cached in two classes, those far behind their prompt's end evicted
+    first, and a hit backs off to the deepest boundary whose window tail
+    is whole, or to none."""
+    from ray_tpu.serve.prefix_cache import PrefixBlockPool, WindowPagePool
+    wpool = WindowPagePool(7)
+    pool = PrefixBlockPool(16, 2, window_pool=wpool, window=4)
+    node, blocks, pages = pool._root, pool.allocate(5), wpool.allocate(5)
+    for i, (b, w) in enumerate(zip(blocks, pages)):
+        node, _ = pool.insert_child(node, (i, i), b, w)
+    # released in order, the two last as near their prompt's end
+    for i, w in enumerate(pages):
+        wpool.decref(w, near=i >= 3)
+    pool.release(blocks)
+    assert wpool.stats()["cached"] == 5 and pool.audit() == []
+    tokens = [t for i in range(5) for t in (i, i)]
+    with pytest.raises(ValueError, match="match_prefix_window"):
+        pool.match_prefix(tokens)
+    got, mtok, _, tail, cut = pool.match_prefix_window(tokens)
+    assert mtok == 10 and not cut
+    assert tail == {3: pages[3], 4: pages[4]}
+    pool.release(got)
+    for w in tail.values():
+        wpool.decref(w)
+    # four pages wanted, one free: the three far ones go, oldest first
+    taken = wpool.allocate(4)
+    assert set(taken) == {6, *pages[:3]} and wpool.evictions_total == 3
+    got, mtok, _, tail, cut = pool.match_prefix_window(tokens)
+    assert mtok == 10 and len(tail) == 2 and not cut
+    pool.release(got)
+    for w in tail.values():
+        wpool.decref(w)
+    # one more: the older of the near ones; the boundary at 10 has lost
+    # its tail and so has the one at 8; the hit backs off to 6... whose
+    # tail went first: nothing is taken
+    assert wpool.allocate(1) == [pages[3]]
+    got, mtok, node, tail, cut = pool.match_prefix_window(tokens)
+    assert (got, mtok, tail) == ([], 0, {}) and cut
+    assert node is pool._root and pool.audit() == []
