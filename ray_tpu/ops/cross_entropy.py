@@ -10,12 +10,21 @@ Two paths:
 - :func:`fused_lm_head_loss` — the memory-lean production path: takes the
   final *hidden states* and the LM-head weights and computes the loss in
   sequence chunks under a ``custom_vjp``. Per chunk it projects to logits
-  (float32 MXU accumulation), reduces to log-sum-exp + label logit, and
-  keeps only the per-token LSE as a residual; the backward recomputes each
-  chunk's logits and softmax to form dX/dW/db. The full
-  ``[batch, seq, vocab]`` float32 logits tensor is never resident — peak
-  loss memory drops from ``O(b·s·v)`` to ``O(b·chunk·v)``, which is what
-  frees HBM for larger batches at long sequence lengths.
+  (float32 MXU accumulation) and reduces to log-sum-exp + label logit. The
+  full ``[batch, seq, vocab]`` float32 logits tensor is never resident —
+  peak loss memory drops from ``O(b·s·v)`` to ``O(b·chunk·v)``, which is
+  what frees HBM for larger batches at long sequence lengths.
+
+  The two functions of the ``custom_vjp`` do different work. The primal
+  (a call nobody differentiates: evaluation, a pipeline stage's forward)
+  runs one matmul a chunk and no gradient work. The differentiated rule
+  forms the gradients in the same pass: the loss is a scalar, so its
+  gradient is known up to the incoming cotangent once a chunk's logits
+  and LSE are, and each chunk runs three matmuls (logits, dX, dW) where a
+  backward that recomputed the logits ran four. What it holds for the
+  backward is therefore not ``x``, ``W`` and the LSEs but the gradients at
+  unit cotangent — dX like ``x``, dW like ``W`` (``[e, v]``, the head's own
+  size), db — and the per-token nll; the backward only scales them.
 """
 
 from __future__ import annotations
@@ -71,7 +80,7 @@ def _chunk_layout(x, labels, mask, chunk: int):
     xc = jnp.moveaxis(x.reshape(b, nc, c, e), 1, 0)
     yc = jnp.moveaxis(labels.reshape(b, nc, c), 1, 0)
     mc = jnp.moveaxis(mask.reshape(b, nc, c), 1, 0)
-    return xc, yc, mc, pad
+    return xc, yc, mc
 
 
 def _chunk_logits(xi, w, bias):
@@ -81,84 +90,91 @@ def _chunk_logits(xi, w, bias):
     return logits + bias
 
 
+def _chunk_nll(logits, yi, z):
+    """One chunk's per-token (LSE, nll) from its float32 logits."""
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    ll = jnp.take_along_axis(logits, yi[..., None], axis=-1)[..., 0]
+    nll = lse - ll
+    if z:
+        nll = nll + z * jnp.square(lse)
+    return lse, nll
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 @jax.named_scope("lm_head_loss")
 def _fused_ce(cfg, x, w, bias, labels, mask):
-    loss, n, _ = _fused_ce_fwd_impl(cfg, x, w, bias, labels, mask)
-    return loss, n
-
-
-def _fused_ce_fwd_impl(cfg, x, w, bias, labels, mask):
+    """The primal (a call nobody differentiates): one matmul a chunk,
+    no gradient work, nothing kept."""
     chunk, z = cfg
     wd = w.astype(x.dtype)
-    xc, yc, mc, _ = _chunk_layout(x, labels, mask, chunk)
+    n = jnp.maximum(jnp.sum(mask), 1.0)
 
-    def body(carry, inp):
-        loss_sum, n = carry
+    def body(loss_sum, inp):
         xi, yi, mi = inp
-        logits = _chunk_logits(xi, wd, bias)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, yi[..., None], axis=-1)[..., 0]
-        nll = lse - ll
-        if z:
-            nll = nll + z * jnp.square(lse)
-        return (loss_sum + jnp.sum(nll * mi), n + jnp.sum(mi)), lse
+        _, nll = _chunk_nll(_chunk_logits(xi, wd, bias), yi, z)
+        return loss_sum + jnp.sum(nll * mi), None
 
-    (loss_sum, n), lses = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xc, yc, mc))
-    n = jnp.maximum(n, 1.0)
-    return loss_sum / n, n, lses
+    loss_sum, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                               _chunk_layout(x, labels, mask, chunk))
+    return loss_sum / n, n
 
 
 @jax.named_scope("lm_head_loss")
 def _fused_ce_fwd(cfg, x, w, bias, labels, mask):
-    loss, n, lses = _fused_ce_fwd_impl(cfg, x, w, bias, labels, mask)
-    return (loss, n), (x, w, bias, labels, mask, lses, loss, n)
-
-
-@jax.named_scope("lm_head_loss")
-def _fused_ce_bwd(cfg, res, cts):
+    """The differentiated rule: one scan forms the loss and, from each
+    chunk's one set of logits, dX, dW and db at unit cotangent (three
+    matmuls a chunk); the backward only scales them. Residuals: those
+    gradients, each in the dtype of the cotangent it becomes, and the
+    per-token nll (for the mask's gradient)."""
     chunk, z = cfg
-    x, w, bias, labels, mask, lses, loss, n = res
-    g_loss, _ = cts                      # n is a count — no useful cotangent
     wd = w.astype(x.dtype)
-    xc, yc, mc, pad = _chunk_layout(x, labels, mask, chunk)
     b, s, e = x.shape
     v = w.shape[-1]
+    n = jnp.maximum(jnp.sum(mask), 1.0)
 
     def body(carry, inp):
-        dw, db = carry
-        xi, yi, mi, lsei = inp
+        loss_sum, dw, db = carry
+        xi, yi, mi = inp
         logits = _chunk_logits(xi, wd, bias)
-        p = jnp.exp(logits - lsei[..., None])
-        coef = (g_loss / n) * mi                       # (b, C)
-        zf = (1.0 + 2.0 * z * lsei) if z else 1.0
+        lse, nll = _chunk_nll(logits, yi, z)
+        p = jnp.exp(logits - lse[..., None])
+        coef = (1.0 / n) * mi                          # (b, C)
+        zf = (1.0 + 2.0 * z * lse) if z else 1.0
         one_hot = jax.nn.one_hot(yi, v, dtype=jnp.float32)
         dl = p * (coef * zf)[..., None] - coef[..., None] * one_hot
         db = db + jnp.sum(dl, axis=(0, 1))
-        dlc = dl.astype(x.dtype)
+        # held once in the compute dtype for both products: left to fuse,
+        # XLA forms dl from the float32 logits again inside each of them
+        # (three exponentials an element; each product ~10% slower on a v5e)
+        dlc = jax.lax.optimization_barrier(dl.astype(x.dtype))
         dxi = jnp.einsum("bcv,ev->bce", dlc, wd,
                          preferred_element_type=jnp.float32).astype(x.dtype)
         dw = dw + jnp.einsum("bce,bcv->ev", xi, dlc,
                              preferred_element_type=jnp.float32)
-        # d loss / d mask_i = (nll_i - loss) / n  (mask enters sum and n)
-        ll = jnp.take_along_axis(logits, yi[..., None], axis=-1)[..., 0]
-        nll = lsei - ll
-        if z:
-            nll = nll + z * jnp.square(lsei)
-        dmi = g_loss * (nll - loss) / n
-        return (dw, db), (dxi, dmi)
+        return (loss_sum + jnp.sum(nll * mi), dw, db), (dxi, nll)
 
-    (dw, db), (dxc, dmc) = jax.lax.scan(
+    (loss_sum, dw, db), (dxc, nllc) = jax.lax.scan(
         body,
-        (jnp.zeros((e, v), jnp.float32), jnp.zeros((v,), jnp.float32)),
-        (xc, yc, mc, lses))
+        (jnp.zeros((), jnp.float32), jnp.zeros((e, v), jnp.float32),
+         jnp.zeros((v,), jnp.float32)),
+        _chunk_layout(x, labels, mask, chunk))
+    loss = loss_sum / n
     dx = jnp.moveaxis(dxc, 0, 1).reshape(b, -1, e)[:, :s]
-    dm = jnp.moveaxis(dmc, 0, 1).reshape(b, -1)[:, :s]
-    dlabels = np.zeros(labels.shape, jax.dtypes.float0)
-    return dx, dw.astype(w.dtype), db.astype(bias.dtype), dlabels, \
-        dm.astype(mask.dtype)
+    nll = jnp.moveaxis(nllc, 0, 1).reshape(b, -1)[:, :s]
+    return (loss, n), (dx, dw.astype(w.dtype), db.astype(bias.dtype), nll,
+                       loss, n)
+
+
+@jax.named_scope("lm_head_loss")
+def _fused_ce_bwd(cfg, res, cts):
+    dx, dw, db, nll, loss, n = res
+    g_loss, _ = cts                      # n is a count — no useful cotangent
+    # d loss / d mask_i = (nll_i - loss) / n  (mask enters sum and n);
+    # the mask is float32 (fused_lm_head_loss casts it), as nll is
+    dm = g_loss * (nll - loss) / n
+    dlabels = np.zeros(nll.shape, jax.dtypes.float0)
+    dx, dw, db = ((g_loss * g).astype(g.dtype) for g in (dx, dw, db))
+    return dx, dw, db, dlabels, dm
 
 
 _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
@@ -181,6 +197,15 @@ def fused_lm_head_loss(x: jnp.ndarray, head_w: jnp.ndarray,
     logits tensor or float32 upcast copy). ``z_loss_coeff`` must be a
     static Python float. Returns (mean_loss, n_valid_tokens) like
     :func:`cross_entropy_loss`.
+
+    Not differentiated, the call does no gradient work. Differentiated
+    (``jax.grad`` / ``jax.vjp``), the forward pass already forms dX, dW
+    and db at unit cotangent, and the residuals are those: one array like
+    ``x``, one like ``head_w``, one like the bias, and ``(b, s)`` float32
+    nll. Where the forward and the backward are one program they live for
+    an instant; a caller that carries ``jax.vjp``'s closure from one
+    program to another (the single-device MPMD pipeline's last stage)
+    carries the head's size per micro-batch in flight.
     """
     b, s, _ = x.shape
     if mask is None:

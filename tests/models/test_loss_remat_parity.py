@@ -47,7 +47,11 @@ def _ce_inputs(key, b=2, s=13, e=32, v=97):
 
 @pytest.mark.parametrize("z_loss", [0.0, 1e-3])
 @pytest.mark.parametrize("chunk", [5, 13, 64])   # uneven, exact, single
-def test_fused_ce_matches_reference(z_loss, chunk):
+@pytest.mark.parametrize("cotangent", [1.0, 0.25, 3.0])
+def test_fused_ce_matches_reference(cotangent, z_loss, chunk):
+    """Loss and the gradients for x, W, b and the (ragged) mask, pulled
+    back from a cotangent of 1 (``jax.grad``'s) and from others: the
+    fused rule forms its gradients at 1 and scales them afterwards."""
     x, w, bias, labels, mask = _ce_inputs(jax.random.PRNGKey(0))
 
     def ref(x, w, bias, mask):
@@ -60,14 +64,122 @@ def test_fused_ce_matches_reference(z_loss, chunk):
                                   z_loss_coeff=z_loss,
                                   chunk_size=chunk)[0]
 
-    np.testing.assert_allclose(np.asarray(jax.jit(fused)(x, w, bias, mask)),
-                               np.asarray(ref(x, w, bias, mask)),
+    def pulled_back(f):
+        loss, vjp = jax.vjp(f, x, w, bias, mask)
+        return loss, vjp(jnp.asarray(cotangent, loss.dtype))
+
+    l_ref, g_ref = pulled_back(ref)
+    l_fus, g_fus = jax.jit(lambda: pulled_back(fused))()
+    np.testing.assert_allclose(np.asarray(l_fus), np.asarray(l_ref),
                                rtol=1e-6, atol=1e-6)
-    g_ref = jax.grad(ref, argnums=(0, 1, 2, 3))(x, w, bias, mask)
-    g_fus = jax.jit(jax.grad(fused, argnums=(0, 1, 2, 3)))(x, w, bias, mask)
     for name, a, b in zip("xwbm", g_ref, g_fus):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6, err_msg=name)
+                                   rtol=1e-5, atol=1e-6 * max(cotangent, 1),
+                                   err_msg=name)
+
+
+def _dots_by_scan(jaxpr):
+    """(``dot_general`` count of each ``scan`` body, count outside any),
+    through every nested jaxpr (custom_vjp call, pjit, closed calls)."""
+    def subjaxprs(eqn):
+        for v in eqn.params.values():
+            for u in (v if isinstance(v, (tuple, list)) else (v,)):
+                u = getattr(u, "jaxpr", u)
+                if hasattr(u, "eqns"):
+                    yield u
+
+    def count(j):
+        scans, outside = [], 0
+        for eqn in j.eqns:
+            if eqn.primitive.name == "dot_general":
+                outside += 1
+            for sub in subjaxprs(eqn):
+                inner_scans, inner = count(sub)
+                if eqn.primitive.name == "scan":
+                    scans.append(inner + sum(inner_scans))
+                else:
+                    scans += inner_scans
+                    outside += inner
+        return scans, outside
+    return count(jaxpr)
+
+
+def test_fused_ce_forms_its_gradients_in_the_forward_scan():
+    """What the rule is for: differentiated, one scan over the chunks
+    runs three matmuls a chunk (logits, dX, dW) and the backward none;
+    not differentiated, the same call runs the one (no gradient work)."""
+    x, w, bias, labels, mask = _ce_inputs(jax.random.PRNGKey(0))
+
+    def fused(x, w, bias, mask):
+        return fused_lm_head_loss(x, w, labels, head_bias=bias, mask=mask,
+                                  z_loss_coeff=1e-3, chunk_size=5)[0]
+
+    grad = jax.make_jaxpr(jax.grad(fused, argnums=(0, 1, 2, 3)))(
+        x, w, bias, mask)
+    assert _dots_by_scan(grad.jaxpr) == ([3], 0)
+    primal = jax.make_jaxpr(fused)(x, w, bias, mask)
+    assert _dots_by_scan(primal.jaxpr) == ([1], 0)
+
+
+def _recomputing_rule_grads(x, w, bias, labels, mask, chunk, z, g_loss):
+    """The rule the library had until it formed its gradients in the
+    forward pass, kept here as a reference: per chunk the logits and
+    their softmax are computed (again, then), ``dl`` is scaled by
+    ``g_loss / n`` before its cast to the compute dtype, and dW / db
+    accumulate in float32. Returns (dx, dw, db)."""
+    e, v = w.shape
+    wd = w.astype(x.dtype)
+    n = jnp.maximum(jnp.sum(mask), 1.0)
+    dw, db, dxs = jnp.zeros((e, v)), jnp.zeros((v,)), []
+    for lo in range(0, x.shape[1], chunk):
+        xi, yi, mi = (a[:, lo:lo + chunk] for a in (x, labels, mask))
+        logits = jnp.einsum("bce,ev->bcv", xi, wd,
+                            preferred_element_type=jnp.float32) + bias
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        p = jnp.exp(logits - lse[..., None])
+        coef = (g_loss / n) * mi
+        zf = (1.0 + 2.0 * z * lse) if z else 1.0
+        dl = p * (coef * zf)[..., None] \
+            - coef[..., None] * jax.nn.one_hot(yi, v, dtype=jnp.float32)
+        db = db + jnp.sum(dl, axis=(0, 1))
+        dlc = dl.astype(x.dtype)
+        dxs.append(jnp.einsum(
+            "bcv,ev->bce", dlc, wd,
+            preferred_element_type=jnp.float32).astype(x.dtype))
+        dw = dw + jnp.einsum("bce,bcv->ev", xi, dlc,
+                             preferred_element_type=jnp.float32)
+    return (jnp.concatenate(dxs, axis=1), dw.astype(w.dtype),
+            db.astype(bias.dtype))
+
+
+@pytest.mark.parametrize("cotangent,rtol", [
+    (1.0, 1e-5),    # dl is formed by the operations the old rule used
+    (0.25, 1e-5),   # a power of two commutes with dl's rounding
+    (3.0, 2e-2),    # the scale lands after dl's bf16 rounding, not before
+])
+def test_fused_ce_bf16_matches_the_recomputing_rule(cotangent, rtol):
+    """bf16 activations over float32 master weights, as training runs
+    it: dW comes back in W's dtype, dX in x's, and both are what the
+    recomputing rule gave on the same inputs."""
+    x, w, bias, labels, mask = _ce_inputs(jax.random.PRNGKey(2))
+    x = x.astype(jnp.bfloat16)
+    chunk, z = 5, 1e-3
+
+    def fused(x, w, bias):
+        return fused_lm_head_loss(x, w, labels, head_bias=bias, mask=mask,
+                                  z_loss_coeff=z, chunk_size=chunk)[0]
+
+    _, vjp = jax.vjp(fused, x, w, bias)
+    got = vjp(jnp.float32(cotangent))
+    want = _recomputing_rule_grads(x, w, bias, labels, mask, chunk, z,
+                                   cotangent)
+    for name, g, r, like in zip("xwb", got, want, (x, w, bias)):
+        assert g.dtype == r.dtype == like.dtype, name
+        g, r = (np.asarray(a, np.float32) for a in (g, r))
+        # dX is rounded to bf16 on both sides: two ulps of 2**-8
+        np.testing.assert_allclose(
+            g, r, rtol=rtol if name != "x" else max(rtol, 8e-3),
+            atol=rtol * np.abs(r).max(), err_msg=name)
 
 
 def test_fused_ce_n_tokens_and_no_bias():
