@@ -101,11 +101,32 @@ def moe_logical_axes():
 # group of the grouped product and are left out of the combine. Nothing
 # stands in for the absent chips' part.
 
+@jax.named_scope("moe_router")
+def _group_limited(c, scores, bias):
+    """The experts ``[N, k]`` a token is sent to under a group limit
+    and a bias (DeepSeek-V3's ``noaux_tc``): ``scores [N, E]`` plus the
+    experts' ``bias`` select; the experts stand in ``n_group`` equal
+    groups, a group's score is the sum of its two largest, the
+    ``topk_group`` best groups are kept, and within them the k largest.
+    Ties go to the lower index at every step."""
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    if c.n_group:
+        n, e = pick.shape
+        groups = pick.reshape(n, c.n_group, e // c.n_group)
+        best_two = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(best_two, c.topk_group)
+        kept = jnp.any(kept[..., None] == jnp.arange(c.n_group), axis=1)
+        pick = jnp.where(kept[..., None], groups, -jnp.inf).reshape(n, e)
+    return jax.lax.top_k(pick, c.experts_per_token)[1]
+
+
 def route_topk(c, lp, x):
     """Router of the dropless layer. ``x [N, D]`` -> (weights ``[N, k]``
     float32, experts ``[N, k]`` int32): each expert's score in float32
     (``router_score``: softmax over all experts, or a sigmoid of each),
-    the k largest, renormalised to sum to one and scaled by
+    the k largest (with ``n_group`` / ``router_bias`` the k that
+    :func:`_group_limited` selects, whose weights are their SCORES, the
+    bias left out), renormalised to sum to one and scaled by
     ``routed_scale``."""
     logits = jnp.dot(x, lp["w_router"].astype(c.dtype),
                      preferred_element_type=jnp.float32)
@@ -115,7 +136,11 @@ def route_topk(c, lp, x):
         scores = jax.nn.softmax(logits, axis=-1)
     else:
         raise ValueError(f"unknown router_score {c.router_score!r}")
-    weights, experts = jax.lax.top_k(scores, c.experts_per_token)
+    if c.n_group or c.router_bias:
+        experts = _group_limited(c, scores, lp.get("router_bias"))
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        weights, experts = jax.lax.top_k(scores, c.experts_per_token)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if c.routed_scale != 1.0:
         weights = weights * c.routed_scale

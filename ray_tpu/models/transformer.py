@@ -59,6 +59,7 @@ from ray_tpu.ops import (
     cross_entropy_loss,
     fused_lm_head_loss,
 )
+from ray_tpu.ops.norms import gated_rms_norm
 from ray_tpu.ops.rotary import rotary_at, yarn_inv_freq
 
 REMAT_POLICIES = ("full", "none", "dots", "dots_all", "offload")
@@ -116,17 +117,29 @@ class TransformerConfig:
     qk_norm: bool = False
     # Learned key selection (ops/sparse_attention.py): index_heads x
     # index_dim indexer queries over one shared key head cached beside K
-    # and V; attention reads the index_topk keys it ranks highest.
-    # 0 = attend every key.
+    # and V (beside the latent rows, with kv_lora_rank); attention reads
+    # the index_topk keys it ranks highest. 0 = attend every key. The
+    # indexer's queries come from the normed hidden state, or with
+    # index_q_lora from the latent model's normed query bottleneck; it
+    # rotates all of index_dim at rope_base, or with kv_lora_rank its
+    # first qk_rope_dim numbers by the layer's own table.
     index_topk: int = 0
     index_heads: int = 0
     index_dim: int = 0
+    index_q_lora: bool = False
     # Latent (MLA) attention (ops/latent_attention.py), kv_lora_rank > 0:
     # queries through a q_lora_rank bottleneck with its own RMSNorm, to
     # n_heads of qk_nope_dim + qk_rope_dim (head_dim is their sum); keys
     # and values from one normed latent of kv_lora_rank a token, beside
     # one rotated key of qk_rope_dim that every head shares; heads of
-    # v_head_dim out. The cache is ONE pool of latent rows, no head axis.
+    # v_head_dim out. The cache is ONE pool of latent rows, no head axis
+    # (with index_topk a second, of index keys, under the same table).
+    # rope_yarn and head_gate (below) are forms of this sublayer too;
+    # rope_softmax_scale multiplies the softmax scale (YaRN's mscale
+    # squared, where a model puts it on the scores and not on sin and
+    # cos). gated_norm_rank > 0: the two norms ahead of a layer's
+    # sublayers pass a low-rank sigmoid gate of their own output
+    # (ops.norms.gated_rms_norm).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -135,6 +148,8 @@ class TransformerConfig:
     # a second RMSNorm on each sublayer's output, ahead of the residual
     sandwich_norm: bool = False
     norm_eps: float = 1e-6
+    rope_softmax_scale: float = 1.0
+    gated_norm_rank: int = 0
     # the first n_dense_layers of n_layers have a dense SwiGLU MLP of
     # d_ff ahead of the expert layers (params["dense_layers"])
     n_dense_layers: int = 0
@@ -145,12 +160,19 @@ class TransformerConfig:
     # this program HOLDS: experts_held of n_experts from expert_first
     # (0 = all). Every token is routed over all n_experts; the held
     # ones' part is computed and the rest left to the chips that hold
-    # them, no exchange.
+    # them, no exchange. n_group > 0 (with the latent model): the
+    # experts in n_group equal groups, a token's choice limited to its
+    # topk_group best groups (a group's score the sum of its two best
+    # experts'); router_bias: a learned bias an expert on the scores
+    # that SELECT, not on the weights (moe.route_topk).
     shared_expert_width: int = 0
     router_score: str = "softmax"
     routed_scale: float = 1.0
     experts_held: int = 0
     expert_first: int = 0
+    n_group: int = 0
+    topk_group: int = 0
+    router_bias: bool = False
     # A stack described BY KIND OF LAYER (served; the 'llama' block over
     # per-head K/V): layer l is of kind layer_pattern[l % len] ("full" |
     # "window"; () = every layer "full"). A "full" layer has n_heads
@@ -194,12 +216,16 @@ class TransformerConfig:
     SERVED_KEYS = ("experts_per_token", "qk_norm", "index_topk",
                    "kv_lora_rank", "sandwich_norm", "n_dense_layers",
                    "layer_pattern", "window_heads", "sliding_window",
-                   "rope_yarn", "head_gate")
+                   "rope_yarn", "head_gate", "index_q_lora",
+                   "rope_softmax_scale", "gated_norm_rank", "n_group",
+                   "topk_group", "router_bias")
 
     @property
     def served_keys(self) -> Tuple[str, ...]:
         """The served-only keys this configuration sets."""
-        return tuple(k for k in self.SERVED_KEYS if getattr(self, k))
+        fields = self.__dataclass_fields__
+        return tuple(k for k in self.SERVED_KEYS
+                     if getattr(self, k) != fields[k].default)
 
     @property
     def served_only(self) -> bool:
@@ -256,18 +282,26 @@ class TransformerConfig:
             per_layer = e * qr + qr * h + e * (r + self.qk_rope_dim) \
                 + r * self.n_heads * (self.qk_nope_dim + self.v_head_dim) \
                 + self.n_heads * self.v_head_dim * e + qr + r
+            if self.index_topk:              # wq, wk, ww, k layernorm
+                per_layer += (qr if self.index_q_lora else e) \
+                    * self.index_heads * self.index_dim \
+                    + e * (self.index_dim + self.index_heads) \
+                    + 2 * self.index_dim
+            per_layer += e * self.n_heads * self.head_gate \
+                + 4 * e * self.gated_norm_rank
         if self.sandwich_norm:
             per_layer += 2 * e
         dense_layer = per_layer + 3 * e * self.d_ff + 2 * e
         if self.qk_norm:
             per_layer += 2 * self.head_dim
-        if self.index_topk:                  # wq, wk, ww, k layernorm
+        if self.index_topk and not self.kv_lora_rank:
             per_layer += e * self.index_dim * (self.index_heads + 1) \
                 + e * self.index_heads + 2 * self.index_dim
         if self.experts_per_token:
             per_layer += e * self.n_experts + 2 * e + 3 * e * (
                 self.n_experts_held * self.d_expert
-                + self.shared_expert_width)
+                + self.shared_expert_width) \
+                + self.n_experts * self.router_bias
         elif self.n_experts:
             per_layer += e * self.n_experts \
                 + self.n_experts * 2 * e * self.d_ff     # router + experts
@@ -470,23 +504,44 @@ def _check_served_forms(c: TransformerConfig) -> None:
                 c.experts_per_token and c.n_dense_layers < c.n_layers):
             raise ValueError("n_dense_layers lead layers of dropless "
                              "experts (experts_per_token > 0)")
-        if c.rope_yarn and len(c.rope_yarn) != 5:
-            raise ValueError("rope_yarn is (factor, original length, "
-                             "beta_fast, beta_slow, attention factor)")
         if c.experts_held:
             raise ValueError("a stack by kind of layer holds every expert")
+    if c.rope_yarn and len(c.rope_yarn) != 5:
+        raise ValueError("rope_yarn is (factor, original length, "
+                         "beta_fast, beta_slow, attention factor)")
     if c.kv_lora_rank:
-        if c.qk_norm or c.index_topk:
-            raise ValueError("qk_norm and index_topk are forms of "
-                             "per-head K/V, not of a latent cache")
+        if c.qk_norm:
+            raise ValueError("qk_norm is a form of per-head K/V, not of "
+                             "a latent cache")
+        if c.index_topk and not (c.index_heads and c.index_dim
+                                 >= c.qk_rope_dim):
+            raise ValueError(
+                "a key selection over a latent cache (index_topk) needs "
+                "index_heads and index_dim >= qk_rope_dim, got "
+                f"{c.index_heads} and {c.index_dim}")
         if c.head_dim != c.qk_nope_dim + c.qk_rope_dim or not (
                 c.q_lora_rank and c.v_head_dim):
             raise ValueError(
                 "latent attention needs q_lora_rank, v_head_dim and "
                 f"head_dim == qk_nope_dim + qk_rope_dim, got {c}")
-    if c.sandwich_norm and not c.kv_lora_rank:
-        raise ValueError("sandwich_norm is served with latent attention "
-                         "(kv_lora_rank > 0)")
+    latent_only = ("sandwich_norm", "index_q_lora", "rope_softmax_scale",
+                   "gated_norm_rank", "n_group", "topk_group",
+                   "router_bias")
+    if not c.kv_lora_rank and set(c.served_keys) & set(latent_only):
+        raise ValueError(f"{', '.join(latent_only)} are served with "
+                         "latent attention (kv_lora_rank > 0)")
+    if c.index_q_lora and not c.index_topk:
+        raise ValueError("index_q_lora names where the indexer's queries "
+                         "come from: it needs index_topk")
+    if c.n_group or c.topk_group:
+        group = c.n_experts // max(c.n_group, 1)
+        if c.n_group < 1 or c.n_experts % c.n_group or group < 2 \
+                or not 0 < c.topk_group <= c.n_group \
+                or c.experts_per_token > c.topk_group * group:
+            raise ValueError(
+                f"{c.n_experts} experts in n_group {c.n_group} groups of "
+                f"two or more, of which topk_group {c.topk_group} hold a "
+                f"token's {c.experts_per_token}")
     if (c.shared_expert_width or c.experts_held
             or c.router_score != "softmax") and not c.experts_per_token:
         raise ValueError("shared_expert_width, experts_held and "
@@ -587,13 +642,27 @@ def _kind_logical_axes(c) -> Dict:
     return axes
 
 
-def _latent_norm_shapes(c: TransformerConfig) -> Dict[str, tuple]:
+#: the router's bias is drawn (at zero a program that ignored it could
+#: not be told from one that used it)
+_ROUTER_BIAS_SD = 0.01
+
+
+def _latent_vector_shapes(c: TransformerConfig, dense: bool
+                          ) -> Dict[str, tuple]:
+    """One layer's float32 vector leaves of the latent model: name ->
+    (width, what it starts at: a number, or None where it is drawn)."""
     e = c.d_model
-    norms = {"attn_norm": e, "mlp_norm": e, "q_a_norm": c.q_lora_rank,
-             "kv_a_norm": c.kv_lora_rank}
+    out = {"attn_norm": (e, 1.0), "mlp_norm": (e, 1.0),
+           "q_a_norm": (c.q_lora_rank, 1.0),
+           "kv_a_norm": (c.kv_lora_rank, 1.0)}
     if c.sandwich_norm:
-        norms.update({"post_attn_norm": e, "post_mlp_norm": e})
-    return norms
+        out.update({"post_attn_norm": (e, 1.0), "post_mlp_norm": (e, 1.0)})
+    if c.index_topk:
+        out.update({"k_idx_scale": (c.index_dim, 1.0),
+                    "k_idx_bias": (c.index_dim, 0.0)})
+    if c.router_bias and not dense:
+        out["router_bias"] = (c.n_experts, None)
+    return out
 
 
 def _latent_layer_shapes(c: TransformerConfig, dense: bool
@@ -612,6 +681,18 @@ def _latent_layer_shapes(c: TransformerConfig, dense: bool
                   (None, "heads")),
         "wo": ((H * c.v_head_dim, e), ("heads", "embed")),
     }
+    if c.head_gate:
+        out["wg"] = ((e, H), ("embed", None))
+    if c.index_topk:
+        src = c.q_lora_rank if c.index_q_lora else e
+        out.update({
+            "wq_idx": ((src, c.index_heads * c.index_dim), (None, None)),
+            "wk_idx": ((e, c.index_dim), ("embed", None)),
+            "ww_idx": ((e, c.index_heads), ("embed", None))})
+    for norm in ("attn", "mlp") if c.gated_norm_rank else ():
+        out.update({
+            f"{norm}_gn_down": ((e, c.gated_norm_rank), ("embed", None)),
+            f"{norm}_gn_up": ((c.gated_norm_rank, e), (None, "embed"))})
     if dense:
         out.update({"w_gate": ((e, c.d_ff), ("embed", "mlp")),
                     "w_up": ((e, c.d_ff), ("embed", "mlp")),
@@ -635,8 +716,10 @@ def _init_latent_params(c, key, dtype, out_scale) -> Dict:
                 jax.random.fold_in(k, i),
                 out_scale if name in ("wo", "w_down", "we_down", "ws_down")
                 else 0.02, n, shape, dtype)
-        out.update({name: jnp.ones((n, width), jnp.float32)
-                    for name, width in _latent_norm_shapes(c).items()})
+        for name, (width, start) in _latent_vector_shapes(c, dense).items():
+            out[name] = jnp.full((n, width), start, jnp.float32) \
+                if start is not None else _ROUTER_BIAS_SD * jax.random.normal(
+                    jax.random.fold_in(k, 1000), (n, width), jnp.float32)
         return out
     keys = jax.random.split(jax.random.fold_in(key, 103), 4)
     n_moe = c.n_layers - c.n_dense_layers
@@ -658,8 +741,8 @@ def _latent_logical_axes(c) -> Dict:
         out = {name: ("layers",) + axes for name, (_, axes)
                in _latent_layer_shapes(c, dense).items()}
         out.update({name: ("layers", "embed" if width == c.d_model
-                           else None)
-                    for name, width in _latent_norm_shapes(c).items()})
+                           else None) for name, (width, _)
+                    in _latent_vector_shapes(c, dense).items()})
         return out
     axes = {"embed": ("vocab", "embed"), "layers": stack(False),
             "final_norm": {"scale": ("embed",)},
@@ -744,7 +827,7 @@ def logical_axes(config: TransformerConfig) -> Dict:
 _F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias",
                          "q_norm", "k_norm", "k_idx_scale", "k_idx_bias",
                          "q_a_norm", "kv_a_norm", "post_attn_norm",
-                         "post_mlp_norm"))
+                         "post_mlp_norm", "router_bias"))
 
 
 def inference_params(config: TransformerConfig, params: Dict) -> Dict:
@@ -1163,8 +1246,8 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 # The serving decode path: a paged KV cache (one pool
 # [n_layers, num_blocks, kv_heads, block_size, head_dim] for k and one
 # for v, with an indexer a third for its keys; with latent attention ONE
-# pool of rows that are every head's key and value; block table per
-# sequence) written by chunked prefill and batched single-token decode
+# pool of rows that are every head's key and value, and with an indexer
+# its keys beside them; block table per sequence) written by chunked prefill and batched single-token decode
 # steps. Both entry points are shape-stable
 # — jit them once at the engine's fixed (batch, chunk, table) shapes
 # and admission never recompiles — and neither slices, stacks or copies
@@ -1194,8 +1277,9 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     so reserved/trash blocks are numerically harmless.
 
     With latent attention (``kv_lora_rank``) the cache is
-    ``{"latent"}`` alone, ``[n_layers, num_blocks, 1, block_size,
-    row]``: what the engine copies, ships, counts and sizes
+    ``{"latent"}``, ``[n_layers, num_blocks, 1, block_size, row]``,
+    alone or, with an indexer, beside its keys ``"ki"`` under the same
+    block ids: what the engine copies, ships, counts and sizes
     (``kv_bytes_per_token``) it takes from the pools returned here.
 
     A stack with "window" layers (``layer_pattern``) has TWO KINDS OF
@@ -1214,9 +1298,13 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
         # to whole lane tiles), under the one "head" the page layout
         # keeps: key and, in its first kv_lora_rank columns, value
         from ray_tpu.ops.latent_attention import latent_row_width
-        return {"latent": jnp.zeros(
-            (c.n_layers, num_blocks, 1, block_size,
-             latent_row_width(c.kv_lora_rank, c.qk_rope_dim)), c.dtype)}
+        page = (c.n_layers, num_blocks, 1, block_size)
+        cache = {"latent": jnp.zeros(
+            page + (latent_row_width(c.kv_lora_rank, c.qk_rope_dim),),
+            c.dtype)}
+        if c.index_topk:
+            cache["ki"] = jnp.zeros(page + (c.index_dim,), c.dtype)
+        return cache
     if c.by_kind and c.sliding_window:
         page = (c.kv_heads, block_size, c.head_dim)
         sizes = {"k": ("full", num_blocks), "v": ("full", num_blocks)}
@@ -1234,15 +1322,18 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
 
 
 @jax.named_scope("indexer")
-def _indexer(c, h, lp, isin, icos, positions):
+def _indexer(c, h, lp, isin, icos, positions, q_from=None):
     """The indexer's side of a layer for the new tokens: queries ``[B,
     C, Hi, Di]`` and the one key head ``[B, C, 1, Di]`` (LayerNorm, then
-    rotary over all of ``index_dim``, both), and the head weights ``[B,
-    C, Hi]`` in float32, scaled by ``Hi^-1/2 * Di^-1/2``."""
-    e = h.shape[-1]
+    rotary over as much of ``index_dim`` as the tables cover, both), and
+    the head weights ``[B, C, Hi]`` in float32, scaled by ``Hi^-1/2 *
+    Di^-1/2``. The queries are projected from ``q_from`` (the latent
+    model's normed query bottleneck) where given, else from ``h``."""
     dt = c.dtype
     hd = h.astype(dt)
-    qi = jnp.einsum("bse,ehd->bshd", hd, lp["wq_idx"].reshape(
+    src = hd if q_from is None else q_from.astype(dt)
+    e = src.shape[-1]
+    qi = jnp.einsum("bse,ehd->bshd", src, lp["wq_idx"].reshape(
         e, c.index_heads, c.index_dim).astype(dt))
     ki = layer_norm(jnp.dot(hd, lp["wk_idx"].astype(dt)),
                     lp["k_idx_scale"], lp["k_idx_bias"], eps=1e-6)
@@ -1354,7 +1445,11 @@ def _latent_attn_sublayer(c, h, lp, rot, layer, cache, block_tables,
     then every head attends the pool's rows themselves
     (``ops/latent_attention.py``): ``wkv_b``'s key half goes into the
     query and its value half comes after the softmax, so no per-head K
-    or V of the context exists. Returns (attn_out, cache)."""
+    or V of the context exists. With an indexer (``index_topk``) the new
+    tokens' index keys go into the same pages' ``ki`` and the heads
+    attend the latent rows it selects. ``head_gate``: a sigmoid gate a
+    head on the heads' outputs ahead of ``wo``. Returns (attn_out,
+    cache)."""
     from ray_tpu.ops.latent_attention import latent_attention
     dt = c.dtype
     b, n, e = h.shape
@@ -1378,19 +1473,49 @@ def _latent_attn_sublayer(c, h, lp, rot, layer, cache, block_tables,
         row = jnp.concatenate(
             [lat[:, :, None], k_rope,
              jnp.zeros((b, n, 1, width - r - dr), dt)], axis=-1)
-    cache = _write_rows(cache, {"latent": row}, layer, block_tables,
-                        positions, write_mask)
+    new, select = {"latent": row}, None
+    if c.index_topk:
+        qi, new["ki"], wi = _indexer(
+            c, h, lp, sin, cos, positions,
+            q_from=cq if c.index_q_lora else None)
+    cache = _write_rows(cache, new, layer, block_tables, positions,
+                        write_mask)
+    if 0 < c.index_topk < block_tables.shape[1] * cache["latent"].shape[3]:
+        # a window of no more than index_topk tokens selects every key:
+        # the dense path, bit for bit (a test holds it)
+        select = (qi, wi, cache["ki"], c.index_topk)
     wkv_b = lp["wkv_b"].reshape(r, H, dn + dv)
     br = c.paged_block_r_prefill if (n > 1 and c.paged_block_r_prefill) \
         else c.paged_block_r
     att = latent_attention(
         q_nope, q_rope, wkv_b[..., :dn], wkv_b[..., dn:], cache["latent"],
         block_tables, positions, layer=layer, lens=lens,
-        sm_scale=(dn + dr) ** -0.5, impl=c.paged_impl, block_r=br or None)
+        sm_scale=(dn + dr) ** -0.5 * c.rope_softmax_scale,
+        impl=c.paged_impl, block_r=br or None, select=select)
+    if c.head_gate:
+        att = _head_gate(c, hd, lp, att)
     with jax.named_scope("mla_out"):
         out = jnp.einsum("bshd,hde->bse", att,
                          lp["wo"].reshape(H, dv, e).astype(dt))
     return out, cache
+
+
+def _yarn_inv_freq(c: TransformerConfig, dim: int):
+    """(inverse frequencies, scale of sin and cos) of ``rope_yarn`` over
+    ``dim`` rotated numbers at ``rope_base``."""
+    factor, original, fast, slow, scale = c.rope_yarn
+    return yarn_inv_freq(dim, c.rope_base, factor, int(original), fast,
+                         slow), float(scale)
+
+
+def _head_gate(c, hd, lp, att):
+    """``head_gate``: each head's output ``att [B, C, H, D]`` times a
+    sigmoid of the sublayer's input ``hd`` through ``wg [e, H]``."""
+    with jax.named_scope("gate"):
+        gate = jax.nn.sigmoid(jnp.dot(
+            hd, lp["wg"].astype(c.dtype),
+            preferred_element_type=jnp.float32))
+        return att * gate[..., None].astype(att.dtype)
 
 
 def _kind_inv_freq(c: TransformerConfig, kind: str):
@@ -1403,9 +1528,7 @@ def _kind_inv_freq(c: TransformerConfig, kind: str):
         return plain(c.window_rotary_dim or c.head_dim,
                      c.window_rope_base), 1.0
     if c.rope_yarn:
-        factor, original, fast, slow, scale = c.rope_yarn
-        return yarn_inv_freq(c.rotary_dim, c.rope_base, factor,
-                             int(original), fast, slow), float(scale)
+        return _yarn_inv_freq(c, c.rotary_dim)
     return plain(c.rotary_dim, c.rope_base), 1.0
 
 
@@ -1447,11 +1570,7 @@ def _kind_attn_sublayer(c, kind, h, lp, rot, layer, cache, tables, first,
             lens=lens - first, impl=c.paged_impl, block_r=br or None,
             window=c.sliding_window if kind == "window" else 0)
     if c.head_gate:
-        with jax.named_scope("gate"):
-            gate = jax.nn.sigmoid(jnp.dot(
-                hd, lp["wg"].astype(dt),
-                preferred_element_type=jnp.float32))
-            att = att * gate[..., None].astype(att.dtype)
+        att = _head_gate(c, hd, lp, att)
     out = jnp.einsum("bshd,hde->bse", att,
                      lp["wo"].reshape(H, c.head_dim, e).astype(dt))
     return out, cache
@@ -1560,7 +1679,9 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                               window_first)
     bs = next(iter(cache.values())).shape[3]
     window = block_tables.shape[1] * bs
-    if c.kv_lora_rank:
+    if c.kv_lora_rank and c.rope_yarn:
+        rot = rotary_at(positions, *_yarn_inv_freq(c, c.qk_rope_dim))
+    elif c.kv_lora_rank:
         rot = rotary_table(window, c.qk_rope_dim, c.rope_base)
     else:
         rot = rotary_table(
@@ -1604,20 +1725,28 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
         dense one (``w_gate`` among its leaves) or an expert layer,
         whose place in the expert stack is ``layer - n_dense_layers``.
         With ``sandwich_norm`` each sublayer's output passes a second
-        RMSNorm ahead of the residual add."""
+        RMSNorm ahead of the residual add; with ``gated_norm_rank`` the
+        norm ahead of each sublayer is gated."""
         eps = c.norm_eps
+
+        def pre(x, name):
+            if not c.gated_norm_rank:
+                return rms_norm(x, lp[f"{name}_norm"], eps=eps)
+            return gated_rms_norm(x, lp[f"{name}_norm"],
+                                  lp[f"{name}_gn_down"],
+                                  lp[f"{name}_gn_up"], eps=eps)
 
         def post(y, name):
             if not c.sandwich_norm:
                 return y
             with jax.named_scope("post_norm"):
                 return rms_norm(y, lp[name], eps=eps)
-        h = rms_norm(x, lp["attn_norm"], eps=eps)
+        h = pre(x, "attn")
         att, cache = _latent_attn_sublayer(
             c, h, lp, rot, layer, cache, block_tables, positions,
             write_mask, lens)
         x = x + post(att, "post_attn_norm").astype(x.dtype)
-        h2 = rms_norm(x, lp["mlp_norm"], eps=eps).astype(c.dtype)
+        h2 = pre(x, "mlp").astype(c.dtype)
         if "w_gate" in lp:
             with jax.named_scope("mlp"):
                 mlp = _swiglu(c, h2, lp)

@@ -22,6 +22,12 @@ and 30k of context, PERF.md, PR 37. It comes back when it is a kernel.)
 ``impl``: "auto" (the kernel on a TPU, else the plain path) | "kernel"
 | "interpret" | "reference" (the plain XLA path), as
 :func:`ray_tpu.ops.attention.paged_attention`.
+
+With a learned key selection (``select``) the heads attend the latent
+rows an indexer ranks highest and no other
+(:func:`ray_tpu.ops.sparse_attention.sparse_latent_attention`, plain XLA
+on every platform): the absorbed query and the expansion after the
+softmax are the ones here.
 """
 
 from __future__ import annotations
@@ -52,7 +58,8 @@ def latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
                      q_positions: jnp.ndarray, *, layer,
                      lens: jnp.ndarray, sm_scale: float,
                      impl: str = "auto",
-                     block_r: Optional[int] = None) -> jnp.ndarray:
+                     block_r: Optional[int] = None,
+                     select: Optional[tuple] = None) -> jnp.ndarray:
     """Causal attention of new-token queries against a latent pool.
 
     ``q_nope [B, C, H, nope]``, ``q_rope [B, C, H, rope]`` (rotated) at
@@ -60,7 +67,10 @@ def latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
     [rank, H, v]`` (the two halves of the stored ``wkv_b``); ``pool``
     the WHOLE ``[L, N, 1, bs, row]`` latent pool with ``layer`` the
     layer attended (never sliced, as every pool); ``lens [B]`` the live
-    tokens after this call's writes. Returns ``[B, C, H, v]``."""
+    tokens after this call's writes. ``select``: ``(qi, wi, ki_pool,
+    topk)``, the indexer's queries ``[B, C, Hi, Di]`` and head weights
+    ``[B, C, Hi]`` of the new tokens, the whole pool of index keys and
+    how many keys a query attends. Returns ``[B, C, H, v]``."""
     rank, rope = w_uk.shape[0], q_rope.shape[-1]
     bs, row = pool.shape[3:]
     dt = q_nope.dtype
@@ -70,10 +80,17 @@ def latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
         unfit = f"latent rank {rank} % 128 != 0"
     elif bs % sublane_tile(pool.dtype):
         unfit = f"block_size {bs} % {sublane_tile(pool.dtype)} != 0"
-    choice = _resolve("latent", impl, "kernel", unfit)
+    if select is None:
+        choice = _resolve("latent", impl, "kernel", unfit)
     with jax.named_scope("mla_q"):
         q_abs = jnp.einsum("bchd,rhd->bchr", q_nope, w_uk.astype(dt))
-    if choice == "reference":
+    if select is not None:
+        from ray_tpu.ops.sparse_attention import sparse_latent_attention
+        qi, wi, ki_pool, topk = select
+        o_lat = sparse_latent_attention(
+            q_abs, q_rope, qi, wi, pool, ki_pool, block_tables,
+            q_positions, lens, layer=layer, topk=topk, sm_scale=sm_scale)
+    elif choice == "reference":
         o_lat = _blocked(q_abs, q_rope, pool, block_tables, q_positions,
                          layer, lens, sm_scale)
     else:
@@ -92,12 +109,14 @@ def latent_attention(q_nope: jnp.ndarray, q_rope: jnp.ndarray,
 
 
 def _blocked(q, q_rope, pool, block_tables, q_positions, layer, lens,
-             sm_scale):
+             sm_scale, chosen=None):
     """The plain XLA path: an online softmax over blocks of
     ``_KEY_BLOCK`` cached rows gathered by table slot; blocks past the
     longest sequence's live rows are not run. ``q`` is the absorbed
     query ``[B, C, H, rank]`` and the result the latent-wide ``[B, C,
-    H, rank]``."""
+    H, rank]``. ``chosen [B, C, W]`` (``W`` at least the live rows):
+    the keys each query attends, none of them ahead of it, in place of
+    every key up to its own position."""
     b, c, h, rank = q.shape
     rope = q_rope.shape[-1]
     bs = pool.shape[3]
@@ -105,6 +124,9 @@ def _blocked(q, q_rope, pool, block_tables, q_positions, layer, lens,
     tb = max(1, min(_KEY_BLOCK // bs, t))      # table slots a block
     n_blocks = -(-t // tb)
     bt = jnp.pad(block_tables, ((0, 0), (0, n_blocks * tb - t)))
+    if chosen is not None and chosen.shape[-1] < n_blocks * tb * bs:
+        chosen = jnp.pad(chosen, ((0, 0), (0, 0), (
+            0, n_blocks * tb * bs - chosen.shape[-1])))
     dt = q.dtype
     f32 = jnp.float32
 
@@ -117,8 +139,12 @@ def _blocked(q, q_rope, pool, block_tables, q_positions, layer, lens,
                        preferred_element_type=f32)
         s = (s + jnp.einsum("bchd,bkd->bhck", q_rope, k_rope,
                             preferred_element_type=f32)) * sm_scale
-        key_pos = i * (tb * bs) + jnp.arange(tb * bs, dtype=jnp.int32)
-        mask = key_pos[None, None, :] <= q_positions[:, :, None]
+        if chosen is None:
+            key_pos = i * (tb * bs) + jnp.arange(tb * bs, dtype=jnp.int32)
+            mask = key_pos[None, None, :] <= q_positions[:, :, None]
+        else:
+            mask = jax.lax.dynamic_slice_in_dim(chosen, i * (tb * bs),
+                                                tb * bs, axis=2)
         s = jnp.where(mask[:, None], s, _NEG_INF)
         m_next = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m_next)

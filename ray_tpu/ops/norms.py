@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 
@@ -34,3 +35,17 @@ def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     y = (x32 - mean) * jnp.reciprocal(jnp.sqrt(var + eps))
     y = y * scale.astype(jnp.float32) + bias.astype(jnp.float32)
     return y.astype(orig_dtype)
+
+
+@jax.named_scope("gated_norm")
+def gated_rms_norm(x: jnp.ndarray, scale: jnp.ndarray, w_down: jnp.ndarray,
+                   w_up: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    """RMSNorm whose output passes a low-rank sigmoid gate of itself:
+    ``n = rms_norm(x)``, ``n * sigmoid((n W_down) W_up)`` with ``W_down
+    [d, rank]``, ``W_up [rank, d]``, no bias and nothing between the two
+    products. The products run in ``x``'s dtype, the gate in float32."""
+    n = rms_norm(x, scale, eps)
+    low = jnp.dot(n, w_down.astype(n.dtype))
+    gate = jax.nn.sigmoid(jnp.dot(low, w_up.astype(n.dtype),
+                                  preferred_element_type=jnp.float32))
+    return (n.astype(jnp.float32) * gate).astype(n.dtype)

@@ -3,11 +3,16 @@ visible key of a query, the ``topk`` best are selected exactly, and
 attention runs over those keys alone (DeepSeek-Sparse-Attention's
 "lightning indexer"; ``TransformerConfig.index_topk``).
 
-A page holds three kinds of state under one block table: K and V
-(``[L, N, kv_heads, bs, D]``) and the indexer's keys ``kI`` (``[L, N, 1,
-bs, Di]``, one shared key head). The pools come in whole with a layer
-index, as in :mod:`ray_tpu.ops.paged_flash`, so a step program's layer
-scan carries and writes them in place.
+Two forms of cache, one indexer. Over per-head K/V
+(:func:`sparse_paged_attention`) a page holds three kinds of state
+under one block table: K and V (``[L, N, kv_heads, bs, D]``) and the
+indexer's keys ``kI`` (``[L, N, 1, bs, Di]``, one shared key head).
+Over a latent (MLA) cache (:func:`sparse_latent_attention`) it holds
+two: the latent rows (``[L, N, 1, bs, row]``, every head's key and
+value, :mod:`ray_tpu.ops.latent_attention`) and ``kI``; the attention
+over the selected rows is the absorbed one. The pools come in whole
+with a layer index, as in :mod:`ray_tpu.ops.paged_flash`, so a step
+program's layer scan carries and writes them in place.
 
 Everything here is plain XLA, one form on every platform (CPU tests run
 what the chip runs). Work follows the live context, not the window: the
@@ -259,22 +264,90 @@ def sparse_paged_attention(q, qi, wi, k_pool, v_pool, ki_pool,
                              topk_mask(scores, topk), lens, layer,
                              sm_scale)
 
-    rows = _ROW_BLOCK
-    if c <= rows or c % rows:
-        return chunk(q, qi, wi, positions, lens)
+    return _in_row_blocks(chunk, (q, qi, wi), positions, lens)
 
-    # a chunk in blocks of rows, as many as hold a live row: a question
-    # of 64 tokens behind a cached document fills one block of a
-    # 2048-row chunk, and a block's keys end at its own last row
+
+def _in_row_blocks(chunk, per_row, positions, lens):
+    """``chunk(*per_row, positions, lens)`` over a chunk's queries in
+    blocks of ``_ROW_BLOCK`` rows, as many as hold a live row: a
+    question of 64 tokens behind a cached document fills one block of a
+    2048-row chunk, and a block's keys end at its own last row. The
+    result has the shape of ``per_row[0]``; rows of blocks not run are
+    zero."""
+    c, rows = positions.shape[1], _ROW_BLOCK
+    if c <= rows or c % rows:
+        return chunk(*per_row, positions, lens)
+
     def block(i, out):
         def rows_of(x):
             return jax.lax.dynamic_slice_in_dim(x, i * rows, rows, axis=1)
         pos = rows_of(positions)
-        o = chunk(rows_of(q), rows_of(qi), rows_of(wi), pos,
+        o = chunk(*(rows_of(x) for x in per_row), pos,
                   jnp.minimum(lens, pos[:, -1] + 1))
         return jax.lax.dynamic_update_slice_in_dim(out, o, i * rows,
                                                    axis=1)
 
     live = jnp.max(lens - positions[:, 0]).astype(jnp.int32)
     return jax.lax.fori_loop(0, (live + rows - 1) // rows, block,
-                             jnp.zeros_like(q))
+                             jnp.zeros_like(per_row[0]))
+
+
+@jax.named_scope("sparse_latent_attn")
+def _decode_selected_latent(q_abs, q_rope, pool, block_tables, idx, chosen,
+                            layer, sm_scale: float):
+    """One query a sequence over the latent rows ``idx [B, k]`` names
+    (``chosen``: which of them count), gathered row by row out of the
+    pool: every head's absorbed query against the ``k`` rows, the
+    softmax weights summing their first ``rank`` columns."""
+    rank, rope = q_abs.shape[-1], q_rope.shape[-1]
+    bs = pool.shape[3]
+    bid = jnp.take_along_axis(block_tables, idx // bs, axis=1)
+    rows = pool[layer, bid, 0, idx % bs]                   # [B, k, row]
+    lat, k_rope = rows[..., :rank], rows[..., rank:rank + rope]
+    s = jnp.einsum("bhr,bkr->bhk", q_abs[:, 0], lat,
+                   preferred_element_type=jnp.float32)
+    s = (s + jnp.einsum("bhd,bkd->bhk", q_rope[:, 0], k_rope,
+                        preferred_element_type=jnp.float32)) * sm_scale
+    p = jax.nn.softmax(jnp.where(chosen[:, None], s, _NEG_INF), axis=-1)
+    o = jnp.einsum("bhk,bkr->bhr", p.astype(lat.dtype), lat,
+                   preferred_element_type=jnp.float32)
+    return o[:, None].astype(q_abs.dtype)
+
+
+def sparse_latent_attention(q_abs, q_rope, qi, wi, pool, ki_pool,
+                            block_tables, positions, lens, *, layer,
+                            topk: int, sm_scale: float):
+    """Absorbed latent attention of new-token queries over the ``topk``
+    cached rows the indexer ranks highest for each (all of them while a
+    query sees at most ``topk``), the new tokens' own latent row and
+    ``kI`` having been written first. ``q_abs [B, C, H, rank]`` (the
+    query with ``W_UK`` folded in), ``q_rope [B, C, H, rope]``, ``qi
+    [B, C, Hi, Di]``, ``wi [B, C, Hi]``; ``pool [L, N, 1, bs, row]`` and
+    ``ki_pool [L, N, 1, bs, Di]`` whole, ``layer`` an int32 scalar.
+    Returns the latent-wide ``[B, C, H, rank]``, for ``W_UV`` to
+    expand. A decode step takes ``jax.lax.top_k`` of its row and
+    gathers the selected rows, ``topk`` a sequence and layer whatever
+    the context; a chunk's queries each have a selection of their own,
+    which masks the plain latent pass over the live pages
+    (:func:`ray_tpu.ops.latent_attention._blocked`)."""
+    from ray_tpu.ops.latent_attention import _blocked
+    if q_abs.shape[1] == 1:
+        scores = index_scores(qi, wi, ki_pool, block_tables, positions,
+                              lens, layer)
+        with jax.named_scope("select"):
+            val, idx = jax.lax.top_k(scores[:, 0],
+                                     min(topk, scores.shape[-1]))
+        # the scores' window is the table padded to whole tiles
+        bt = _pad_table(block_tables, scores.shape[-1] // pool.shape[3])
+        return _decode_selected_latent(q_abs, q_rope, pool, bt, idx,
+                                       val > -jnp.inf, layer, sm_scale)
+
+    def chunk(q_abs, q_rope, qi, wi, positions, lens):
+        scores = index_scores(qi, wi, ki_pool, block_tables, positions,
+                              lens, layer)
+        chosen = topk_mask(scores, topk)
+        with jax.named_scope("sparse_latent_attn"):
+            return _blocked(q_abs, q_rope, pool, block_tables, positions,
+                            layer, lens, sm_scale, chosen=chosen)
+
+    return _in_row_blocks(chunk, (q_abs, q_rope, qi, wi), positions, lens)

@@ -1279,6 +1279,12 @@ class LLMEngine:
                 "keys_visible_total": self._sparse["visible"],
                 "keys_attended_total": self._sparse["attended"],
                 "indexer_keys_scored_total": self._sparse["scored"],
+                # the same two counts by the kind of program that booked
+                # them (they add up to the totals)
+                **{f"{name}_{kind}_total": self._sparse[key, kind]
+                   for name, key in (("keys_attended", "attended"),
+                                     ("indexer_keys_scored", "scored"))
+                   for kind in ("decode", "prefill")},
                 "moe_assignments_total": self._sparse["assigned"],
                 "kv_block_size": self.config.kv_block_size,
                 "paged_impl": self.model_config.paged_impl,
@@ -2095,7 +2101,7 @@ class LLMEngine:
         ec = self.config
         req.prefill_pos += n
         req.n_chunks += 1
-        self._account_queries([start], [n])
+        self._account_queries([start], [n], "prefill")
         if req.trace is not None:
             req.trace.span(RT.PREFILL, t0w, time.time(),
                            pos=start, tokens=n, tick=self._clock.tick_no)
@@ -2173,12 +2179,13 @@ class LLMEngine:
         self._decode_grid_steps += steps
         self._decode_grid_steps_live += live
 
-    def _account_queries(self, first, n) -> None:
+    def _account_queries(self, first, n, kind: str) -> None:
         """Book the queries at positions ``first[i] .. first[i] + n[i] -
-        1`` (numpy arrays, a sequence each): what each could see, what
-        it attended, what the indexer scored and the experts it was
-        sent to. Host arithmetic on positions; a model that neither
-        selects nor routes books nothing (its counters stay 0)."""
+        1`` (numpy arrays, a sequence each) of a program of ``kind``
+        ("decode" | "prefill"): what each could see, what it attended,
+        what the indexer scored and the experts it was sent to. Host
+        arithmetic on positions; a model that neither selects nor routes
+        books nothing (its counters stay 0)."""
         mc, np = self.model_config, self._np
         if not (mc.index_topk or mc.experts_per_token):
             return
@@ -2192,8 +2199,10 @@ class LLMEngine:
             attended = int((hi * (hi + 1) - lo * (lo + 1)).sum()) // 2 \
                 + int(((last - hi) - (first - lo)).sum()) * k
             self._sparse["scored"] += visible * mc.n_layers
+            self._sparse["scored", kind] += visible * mc.n_layers
         self._sparse["visible"] += visible
         self._sparse["attended"] += attended
+        self._sparse["attended", kind] += attended
         self._sparse["assigned"] += int(n.sum()) * mc.experts_per_token \
             * (mc.n_layers - mc.n_dense_layers)
 
@@ -2216,7 +2225,7 @@ class LLMEngine:
                 rows = self._slot_rows.copy()
             self._account_decode_pages(rows[:, 1] + 1)
             self._account_queries([r.seq_len for r in active],
-                                  [1] * len(active))
+                                  [1] * len(active), "decode")
             t0 = time.monotonic()
             self._h2d_transfers += 1
         with clock.phase("engine.decode.dispatch"):
