@@ -21,8 +21,9 @@ import jax.numpy as jnp
 import optax
 
 from ray_tpu.models.transformer import (
-    TransformerConfig, init_params, logical_axes, lm_loss,
-    refuse_training)
+    TransformerConfig, head_loss_form, init_params, logical_axes,
+    lm_loss, refuse_training)
+from ray_tpu.ops.cross_entropy import n_chunks
 from ray_tpu.parallel.quantization import DEFAULT_BLOCK_SIZE, fake_quant
 from ray_tpu.parallel.sharding import (
     ShardingRules, FSDP_RULES, shard_params, batch_sharding, replicated,
@@ -44,6 +45,14 @@ class TrainStepBundle:
     batch_spec: Any
     grad_transport: str = "fp32"
     shard_weight_update: bool = False
+    #: the form the LM-head loss took in ``step_fn`` (the program is
+    #: static: every step or none), ``transformer.head_loss_form``'s:
+    #: ``"per_chip"`` (dW summed on the chip, reduced once a step),
+    #: ``"gspmd"`` (reduced every chunk) or ``"logits"`` (not fused)
+    loss_form: str = "gspmd"
+    #: chunks the fused loss scans a step of ``config.max_seq_len`` tokens
+    #: a sequence (0 where the logits are materialized)
+    loss_chunks: int = 0
     #: live-telemetry cadence (see :meth:`_telemetry`); <= 0 disables
     telemetry_interval_s: float = 0.5
     _tel_last: float = dataclasses.field(default=0.0, repr=False)
@@ -319,11 +328,16 @@ def make_train_step(config: TransformerConfig, mesh,
         donate_argnums=(0,) if donate_state else (),
     )
 
+    loss_form = head_loss_form(config, mesh, rules)[0]
     return TrainStepBundle(config=config, mesh=mesh, rules=rules,
                            init_fn=init_fn, step_fn=step_fn,
                            state_shardings=state_sh, batch_spec=batch_sh,
                            grad_transport=grad_transport,
                            shard_weight_update=shard_weight_update,
+                           loss_form=loss_form,
+                           loss_chunks=0 if loss_form == "logits" else
+                           n_chunks(config.max_seq_len - 1,
+                                    config.ce_chunk_size),
                            telemetry_interval_s=telemetry_interval_s)
 
 

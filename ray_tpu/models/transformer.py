@@ -59,6 +59,7 @@ from ray_tpu.ops import (
     cross_entropy_loss,
     fused_lm_head_loss,
 )
+from ray_tpu.ops.cross_entropy import PerChip
 from ray_tpu.ops.norms import gated_rms_norm
 from ray_tpu.ops.rotary import rotary_at, yarn_inv_freq
 
@@ -1090,6 +1091,36 @@ def apply(config: TransformerConfig, params: Dict, input_ids: jnp.ndarray,
     return logits
 
 
+def _mesh_axes(mesh, rules, logical: str) -> Tuple[str, ...]:
+    """The mesh axes of size > 1 that ``rules`` split ``logical`` over."""
+    target = rules.get(logical) if mesh is not None and rules else None
+    target = (target,) if isinstance(target, str) else tuple(target or ())
+    return tuple(a for a in target if mesh.shape.get(a, 1) > 1)
+
+
+def head_loss_form(config: TransformerConfig, mesh, rules
+                   ) -> Tuple[str, Optional[PerChip]]:
+    """The form :func:`lm_loss` gives the LM-head loss, read off the config,
+    the mesh and the rules alone (no option selects it):
+
+    - ``"logits"``: ``ce_chunk_size == 0``, or the sequence axis is split
+      (``sp > 1``: chunking would regather every chunk) — materialized
+      logits, partitioned by GSPMD;
+    - ``"per_chip"``: fused, and the batch is split over more than one chip
+      while the vocabulary is not (a split one wants a cross-chip
+      logsumexp) — the scan runs per chip (:class:`PerChip`);
+    - ``"gspmd"``: fused, whole arrays, any partitioning GSPMD's: no mesh,
+      one device, ``tp > 1``.
+    """
+    if not config.ce_chunk_size or _mesh_axes(mesh, rules, "sequence"):
+        return "logits", None
+    batch = _mesh_axes(mesh, rules, "batch")
+    if not batch or _mesh_axes(mesh, rules, "vocab"):
+        return "gspmd", None
+    rows = tuple(a for a in _mesh_axes(mesh, rules, "embed") if a in batch)
+    return "per_chip", PerChip(mesh, batch, rows)
+
+
 def lm_loss(config: TransformerConfig, params: Dict, batch: Dict,
             mesh=None, rules=None) -> Tuple[jnp.ndarray, Dict]:
     """Next-token LM loss. batch: {"input_ids": (b,s) int32,
@@ -1099,27 +1130,32 @@ def lm_loss(config: TransformerConfig, params: Dict, batch: Dict,
     fused into the chunked cross entropy (``ops.fused_lm_head_loss``) —
     the full float32 logits tensor is never resident. ``ce_chunk_size=0``
     restores the materialized-logits reference path.
+
+    Under a mesh the fused loss takes one of two forms, decided by
+    :func:`head_loss_form` from ``mesh`` and ``rules`` (no option selects
+    it). Where the data axes split the batch and neither the vocabulary
+    nor the sequence is split (``fsdp``, ``dp``, both), it runs per chip:
+    the head is gathered once a step, each chip sums its own rows' dW
+    through the scan, and the sums over chips (token count, loss, dW in
+    float32, db) happen once, after the scan. Otherwise (no mesh, one
+    device, ``tp > 1``) the whole-array call is left to GSPMD, which
+    reduces every chunk's dW onto the head's sharding; with ``sp > 1`` the
+    logits are materialized.
     """
     c = config
     ids = batch["input_ids"]
     labels = ids[:, 1:]
     mask = batch.get("loss_mask")
     mask = mask[:, 1:] if mask is not None else None
-    # Chunking scans over the sequence axis; when that axis is SHARDED
-    # (sp > 1, the ring-attention meshes) per-chunk slicing would force
-    # the partitioner to regather every chunk — keep materialized logits
-    # there, fuse everywhere else.
-    sp_axis = rules.get("sequence") if rules else None
-    seq_sharded = (mesh is not None and sp_axis is not None
-                   and sp_axis in mesh.shape and mesh.shape[sp_axis] > 1)
-    if c.ce_chunk_size and not seq_sharded:
+    form, per_chip = head_loss_form(c, mesh, rules)
+    if form != "logits":
         x, moe_aux = hidden_states(c, params, ids, mesh=mesh, rules=rules)
         head = params["lm_head"]
         with jax.named_scope("lm_head_loss"):
             loss, n = fused_lm_head_loss(
                 x.astype(c.dtype)[:, :-1], head["w"], labels,
                 head_bias=head.get("b"), mask=mask,
-                chunk_size=c.ce_chunk_size)
+                chunk_size=c.ce_chunk_size, per_chip=per_chip)
     else:
         logits, moe_aux = apply(c, params, ids, mesh=mesh, rules=rules,
                                 return_moe_aux=True)

@@ -279,6 +279,9 @@ class _SPMDProgram(TrainProgram):
             quant_block_size=plan.quant_block_size or DEFAULT_BLOCK_SIZE,
             quant_stochastic=plan.quant_stochastic,
             telemetry_interval_s=telemetry_interval_s)
+        logger.info("plan %s: LM-head loss %s, %d chunks a step",
+                    plan.describe(), self.bundle.loss_form,
+                    self.bundle.loss_chunks)
         self.state = self.bundle.init(seed=seed)
         # the step's own clock: train.step and, inside it, the host's
         # part before the device has the step (dispatch), the blocking
