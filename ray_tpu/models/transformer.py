@@ -248,6 +248,15 @@ class TransformerConfig:
         return (self.window_heads or self.n_heads) if kind == "window" \
             else self.n_heads
 
+    def paged_row_block(self, tokens: int) -> Optional[int]:
+        """The paged kernel's row block for a call of ``tokens`` query
+        tokens a sequence (static under jit): a prefill chunk's much
+        larger row count can carry a bigger block than the one-token
+        decode step compiled from the same sublayer. None: the kernel's
+        own default."""
+        return (self.paged_block_r_prefill if tokens > 1 else 0) \
+            or self.paged_block_r or None
+
     def kind_layers(self, kind: str) -> int:
         """Layers of ``kind`` in the stack, the dense ones among them."""
         return sum(self.layer_kind(l) == kind for l in range(self.n_layers))
@@ -1447,12 +1456,6 @@ def _paged_attn_sublayer(c, h, lp, rot, layout, layer, cache,
     cache = _write_rows(cache, new, layer, block_tables, positions,
                         write_mask)
 
-    # h.shape[1] is static under jit: > 1 means a prefill chunk, whose
-    # much larger query-row count can carry a bigger row block than the
-    # single-token decode step compiled from this same sublayer
-    br = c.paged_block_r_prefill \
-        if (h.shape[1] > 1 and c.paged_block_r_prefill) \
-        else c.paged_block_r
     if 0 < c.index_topk < block_tables.shape[1] * bs:
         from ray_tpu.ops.sparse_attention import sparse_paged_attention
         att = sparse_paged_attention(
@@ -1466,7 +1469,8 @@ def _paged_attn_sublayer(c, h, lp, rot, layout, layer, cache,
         with jax.named_scope("paged_attn"):
             att = paged_attention(q, cache["k"], cache["v"], block_tables,
                                   positions, layer=layer, lens=lens,
-                                  impl=c.paged_impl, block_r=br or None)
+                                  impl=c.paged_impl,
+                                  block_r=c.paged_row_block(h.shape[1]))
     out = jnp.einsum("bshd,hde->bse", att,
                      lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
     return out, cache
@@ -1521,13 +1525,11 @@ def _latent_attn_sublayer(c, h, lp, rot, layer, cache, block_tables,
         # the dense path, bit for bit (a test holds it)
         select = (qi, wi, cache["ki"], c.index_topk)
     wkv_b = lp["wkv_b"].reshape(r, H, dn + dv)
-    br = c.paged_block_r_prefill if (n > 1 and c.paged_block_r_prefill) \
-        else c.paged_block_r
     att = latent_attention(
         q_nope, q_rope, wkv_b[..., :dn], wkv_b[..., dn:], cache["latent"],
         block_tables, positions, layer=layer, lens=lens,
         sm_scale=(dn + dr) ** -0.5 * c.rope_softmax_scale,
-        impl=c.paged_impl, block_r=br or None, select=select)
+        impl=c.paged_impl, block_r=c.paged_row_block(n), select=select)
     if c.head_gate:
         att = _head_gate(c, hd, lp, att)
     with jax.named_scope("mla_out"):
@@ -1597,13 +1599,11 @@ def _kind_attn_sublayer(c, kind, h, lp, rot, layer, cache, tables, first,
                         dict(zip(names, (k, v))), layer, tables, rel,
                         write_mask)
     cache = {**cache, **pools}
-    br = c.paged_block_r_prefill \
-        if (h.shape[1] > 1 and c.paged_block_r_prefill) \
-        else c.paged_block_r
     with jax.named_scope("paged_attn"):
         att = paged_attention(
             q, pools[names[0]], pools[names[1]], tables, rel, layer=layer,
-            lens=lens - first, impl=c.paged_impl, block_r=br or None,
+            lens=lens - first, impl=c.paged_impl,
+            block_r=c.paged_row_block(h.shape[1]),
             window=c.sliding_window if kind == "window" else 0)
     if c.head_gate:
         att = _head_gate(c, hd, lp, att)

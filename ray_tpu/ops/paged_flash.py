@@ -36,14 +36,20 @@ step**:
   online-softmax update and one ``p·V`` a head a step: the step's
   fixed cost is paid once for P pages, and the products are P·block_size
   key columns wide instead of one page's;
-- groups past ``last = (pages − 1) // P`` (``pages =
-  ceil(lens[b] / block_size)``) are **skipped**: no copy is started
-  for them and ``pl.when`` skips the body, so a dead step costs what
-  the three BlockSpec operands' bookkeeping costs (~0.05 µs). A
-  sequence with ``lens[b] <= 0`` holds nothing (the engine's decode
-  slot with no sequence in it): ``pages = 0`` and ``last = −1``, every
-  step of it is a dead one, no page of its table is read and its rows
-  come back zero. In a partly live last group
+- groups past ``last = (pages − 1) // P`` are **skipped**: no copy is
+  started for them and ``pl.when`` skips the body, so a dead step
+  costs what the three BlockSpec operands' bookkeeping costs
+  (~0.05 µs). ``pages`` is a row block's own: **its bound is its
+  highest live position** (``top``, the largest ``0 <= position <
+  lens[b]`` among its rows: pages ``0 .. top // block_size``), which
+  with one row block, a decode or a verify call, is the length's
+  ``ceil(lens[b] / block_size)``. A chunk's row block of padding alone
+  (most of a short question's chunk) has no live row and an early
+  block of a document's chunk stops at its own diagonal. A sequence
+  with ``lens[b] <= 0`` holds nothing (the engine's decode slot with
+  no sequence in it). Either way ``pages = 0`` and ``last = −1``:
+  every step of the sweep is a dead one, no page of the table is read
+  and the rows come back zero. In a partly live last group
   the pages past the last live one are not fetched either: their key
   columns are masked out (``key position < pages · block_size``)
   beside the causal mask, and their V rows are zeroed (a zero weight
@@ -70,15 +76,16 @@ Rows are padded to ``block_r`` (chip-aware default via
 :func:`default_paged_block_r`; :func:`autotune_paged_block_r` times a
 candidate grid once and persists the winner through the SAME on-disk
 table as ``autotune_flash_blocks``). Padded rows carry position −1 —
-fully masked, dropped on unpack.
+fully masked, dropped on unpack. The rows a caller pads with (a chunk's
+tail, positions at and past ``lens``) see the keys up to their block's
+bound or none, and are the caller's to discard.
 
 A **latent cache** (``v_width``; :mod:`ray_tpu.ops.latent_attention`)
 is the same kernel with one key head and no V pool: a page ``[1,
 block_size, row]`` is fetched once and read as the key tile and, in its
-first ``v_width`` columns, as the value tile; a fourth prefetched scalar
-gives each sequence's live query rows, and a row block past them (a
-chunk's padding: at 128 rows a token, most of a short question's chunk)
-fetches and folds nothing. The dense calls' programs are unchanged.
+first ``v_width`` columns, as the value tile. It takes the scalars the
+dense form takes; at 128 rows a token most of a short question's chunk
+is row blocks of padding, which the bound above leaves without a page.
 
 ``interpret=True`` runs the same kernel, copies and semaphores
 included, on CPU (tier-1 parity tests); on TPU it compiles with
@@ -137,7 +144,7 @@ def paged_work_pages(lens, block_size: int):
 
 def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
                   sm_scale: float, v_width: Optional[int] = None,
-                  window: int = 0):
+                  window: int = 0, row_blocks: int = 1):
     """One (batch b, kv head group g, row block r, page group t) step:
     fold pages ``t·pp .. t·pp + pp − 1`` of sequence b into the row
     block's online softmax, one kv head of the group at a time.
@@ -147,41 +154,58 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
     0|1]`` counts a buffer's K and V copies. Scalar refs (bt, lens,
     layer) are in SMEM ahead of the body.
 
+    ``row_blocks`` (the call's, static): with more than one, a row
+    block's pages end at its own highest live position (``top``, the
+    largest ``pos < lens[b]`` among its rows, −1 with none) and not at
+    the sequence's length, whatever the form: a block of a chunk's
+    padding alone holds no page (a dead sweep, like a sequence with
+    ``lens <= 0``), an early block of a chunk stops at its own
+    diagonal. ``top`` is reckoned from ``pos_ref`` at a sweep's first
+    step into one more scratch word, ``top_s`` (SMEM), which the
+    sweep's other steps read. With one row block the last live row
+    sits at ``lens − 1`` and the bound is the length itself: a decode
+    or a verify call has neither the reduction nor the word.
+
     ``v_width`` (a latent cache): there is no V pool and no V buffer;
     a page's value is the first ``v_width`` columns of its key tile,
-    read from the one copy of it. One more scalar rides ahead of the
-    body, ``rows_ref [B]``, the sequence's live query rows: a row block
-    past them (the padding of a chunk that holds a 64-token question:
-    128 heads' rows a token make 2048 tokens 512 blocks) holds no page
-    and folds none.
+    read from the one copy of it.
 
     ``window`` (a sliding-window layer): a row sees the ``window`` keys
     up to its own position and none behind them. One more scalar rides
     ahead of the body, ``first_ref [B, row blocks]``, the lowest
     position among a row block's rows: a page group all of whose keys
     lie behind that row's window lies behind every row's, and is a dead
-    step like one past the length (no copy started, body skipped)."""
-    first_ref = None
-    if window:
-        (bt_ref, lens_ref, layer_ref, first_ref, q_ref, pos_ref, k_hbm,
-         v_hbm, o_ref, m_s, l_s, acc_s, k_buf, v_buf, sem) = refs
-    elif v_width is None:
-        (bt_ref, lens_ref, layer_ref, q_ref, pos_ref, k_hbm, v_hbm, o_ref,
-         m_s, l_s, acc_s, k_buf, v_buf, sem) = refs
-    else:
-        (bt_ref, lens_ref, layer_ref, rows_ref, q_ref, pos_ref, k_hbm,
-         o_ref, m_s, l_s, acc_s, k_buf, sem) = refs
-        v_buf = k_buf           # what a dead page's rows are zeroed in
+    step like one past the block's bound (no copy started, body
+    skipped)."""
+    refs = iter(refs)
+    bt_ref, lens_ref, layer_ref = next(refs), next(refs), next(refs)
+    first_ref = next(refs) if window else None
+    q_ref, pos_ref, k_hbm = next(refs), next(refs), next(refs)
+    v_hbm = next(refs) if v_width is None else None
+    o_ref, m_s, l_s, acc_s, k_buf = (next(refs) for _ in range(5))
+    # a latent page's value rows are its key rows: what a dead page's
+    # rows are zeroed in
+    v_buf = next(refs) if v_width is None else k_buf
+    sem = next(refs)
     b, g, t = pl.program_id(0), pl.program_id(1), pl.program_id(3)
     nt = pl.num_programs(3)
     # Length-aware skipping: groups past the last live one fetch
     # nothing and fold nothing. A sequence holds at most the table, and
     # none of it where lens <= 0: last = -1, every step a dead one.
     pages = jnp.clip(pl.cdiv(lens_ref[b], bs), 0, slots)
-    if v_width is not None:
-        block_r = q_ref.shape[2]
-        pages = jnp.where(pl.program_id(2) * block_r < rows_ref[b],
-                          pages, 0)
+    if row_blocks > 1:
+        top_s = next(refs)
+
+        # written ahead of _init's first fetch, which reads ``pages``
+        # in the same step; rewritten at every sweep's start, so no
+        # sweep reads another's word
+        @pl.when(t == 0)
+        def _top():
+            pos = pos_ref[0]                   # (block_r, 1)
+            live = (pos >= 0) & (pos < lens_ref[b])
+            top_s[0] = jnp.max(jnp.where(live, pos, -1))
+
+        pages = jnp.minimum(pages, (top_s[0] + bs) // bs)
     last = (pages - 1) // pp
     # the first group with a key inside the window of the row block's
     # first row; a block of padding alone (position "never") has none
@@ -371,6 +395,21 @@ def paged_grid_steps(pages, table_len: int, pages_per_step: int):
     return len(pages) * groups, int((-(-pages // pages_per_step)).sum())
 
 
+def paged_row_blocks(rows: int, live_rows: int, head_dim: int, dtype, *,
+                     block_r: Optional[int] = None,
+                     chip: Optional[str] = None):
+    """``(blocks, live)`` of one call's row-block axis, a sequence and a
+    kv head group: the blocks its ``rows`` query rows a kv head make
+    (C · heads per kv head, at the row block the kernel would take),
+    and those with a live row, the first ``live_rows`` (a chunk's rows
+    are its tokens in order, each token's heads together, the padding
+    behind them): the others hold no page and fold none. What the
+    engine books a chunk, as :func:`paged_grid_steps` is a decode
+    step's."""
+    block_r = _row_block(rows, head_dim, dtype, block_r, chip)
+    return -(-rows // block_r), -(-live_rows // block_r)
+
+
 def layered_pool(k_cache: jnp.ndarray, v_cache: jnp.ndarray, layer):
     """``(k_pool, v_pool, layer)`` with the pools 5-D
     ``[L, N, KVH, bs, D]`` and ``layer`` a ``(1,)`` int32 array: a
@@ -412,8 +451,9 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     ``lens [B]`` is the number of LIVE cached positions per sequence
     (after this step's writes); table slots past ``ceil(lens/bs)`` are
     skipped entirely. Rows whose position ≥ ``lens[b]`` (padded prefill
-    tail) attend only live keys — their outputs are the caller's to
-    discard, exactly as with the reference path. A sequence with
+    tail) attend the live keys up to their row block's bound, none in a
+    block of such rows alone (zeros) — their outputs are finite and the
+    caller's to discard, as with the reference path. A sequence with
     ``lens[b] <= 0`` holds nothing: its rows come back zero and no page
     of its table is read (what is in those pages, a NaN included,
     reaches nothing).
@@ -485,11 +525,6 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scalars = (block_tables.astype(jnp.int32), lens.astype(jnp.int32), layer)
     if window:
         scalars += scalars_window
-    if v_width is not None:
-        # live query rows a sequence: a chunk's padding (positions past
-        # lens, the caller's to discard) is no row of them
-        scalars += (jnp.sum(q_positions < lens[:, None], axis=1,
-                            dtype=jnp.int32) * rep,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b, g // hb, nr, pl.cdiv(t, pp)),
@@ -506,12 +541,12 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         ] + [pltpu.VMEM((2, pp, hb, bs, d), pool.dtype)   # two groups'
              for pool in pools] + [                       # pages, a pool
             pltpu.SemaphoreType.DMA((2, 2)),
-        ],
+        ] + [pltpu.SMEM((1,), jnp.int32)] * (nr > 1),     # top_s
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, bs=bs, hb=hb, pp=pp, slots=t,
                           sm_scale=float(sm_scale), v_width=v_width,
-                          window=int(window)),
+                          window=int(window), row_blocks=nr),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, dv), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(

@@ -635,6 +635,22 @@ class LLMEngine:
             pools=2 if "v" in self._cache else 1)
         self._decode_grid_steps = 0
         self._decode_grid_steps_live = 0
+        # and for its row-block axis in a chunk's call: row blocks one
+        # layer of a kind takes a kv head group and those with a live
+        # row (the others, a short prompt's padding, hold no page),
+        # summed over chunks; a kind has its own heads a kv head. A
+        # model that selects its keys runs no chunk through the kernel
+        # and books none.
+        selecting = 0 < model_config.index_topk \
+            < ec.blocks_per_seq * ec.kv_block_size
+        kinds = () if selecting else ("full", "window") if Tw else ("full",)
+        self._row_blocks = {kind: [0, 0] for kind in kinds}  # all, live
+        self._chunk_rep = {kind: model_config.kind_heads(kind) // page_heads
+                           for kind in kinds}
+        self._chunk_row_block = dict(
+            head_dim=row, dtype=model_config.dtype,
+            block_r=model_config.paged_row_block(ec.prefill_chunk),
+            chip="cpu" if model_config.paged_impl == "interpret" else None)
         # what a selecting, routing model did, from positions alone (no
         # device work): keys a query could see and keys it attended
         # (min(visible, index_topk)), summed over queries; keys the
@@ -1197,6 +1213,8 @@ class LLMEngine:
             self._decode_pages_live = self._decode_pages_window = 0
             self._decode_slots_skipped = 0
             self._decode_grid_steps = self._decode_grid_steps_live = 0
+            for booked in self._row_blocks.values():
+                booked[:] = 0, 0
             self._pages_live = dict.fromkeys(self._pages_live, 0)
             self._window_pinned_max = 0
             self._sparse.clear()
@@ -1276,6 +1294,14 @@ class LLMEngine:
                     round(self._decode_grid_steps_live
                           / self._decode_grid_steps, 4)
                     if self._decode_grid_steps else None),
+                # how often a row block's own bound engages: row blocks
+                # a chunk's call takes on one layer of a kind, a kv
+                # head group, and those with a live row (the rest fold
+                # nothing), by kind of layer
+                "prefill_row_blocks": {
+                    kind: n for kind, (n, _) in self._row_blocks.items()},
+                "prefill_row_blocks_live": {
+                    kind: n for kind, (_, n) in self._row_blocks.items()},
                 "keys_visible_total": self._sparse["visible"],
                 "keys_attended_total": self._sparse["attended"],
                 "indexer_keys_scored_total": self._sparse["scored"],
@@ -2053,6 +2079,7 @@ class LLMEngine:
                 ramp = max(0, min(start + n, w) - start)   # rows under w
                 live["prefill_keys_window"] += ramp * start \
                     + ramp * (ramp + 1) // 2 + (n - ramp) * w
+            self._account_row_blocks(n)
             t0w = time.time()
             t0 = time.monotonic()
             if req.t_first_chunk is None:
@@ -2178,6 +2205,18 @@ class LLMEngine:
                                        self._decode_pages_per_step)
         self._decode_grid_steps += steps
         self._decode_grid_steps_live += live
+
+    def _account_row_blocks(self, n: int) -> None:
+        """Book one chunk of ``n`` live tokens: the row blocks the paged
+        kernel's call takes on a layer of each kind, and those with a
+        live row. Host arithmetic, no device work."""
+        from ray_tpu.ops.paged_flash import paged_row_blocks
+        for kind, rep in self._chunk_rep.items():
+            blocks, live = paged_row_blocks(
+                self.config.prefill_chunk * rep, n * rep,
+                **self._chunk_row_block)
+            booked = self._row_blocks[kind]
+            booked[:] = booked[0] + blocks, booked[1] + live
 
     def _account_queries(self, first, n, kind: str) -> None:
         """Book the queries at positions ``first[i] .. first[i] + n[i] -

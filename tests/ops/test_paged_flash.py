@@ -787,3 +787,197 @@ def test_the_scores_are_float32_and_bf16_scores_are_told(seed, window,
 
     monkeypatch.setattr(jax.lax, "dot_general", bf16_scores)
     assert off() > limit * 2.5
+
+
+# --------------------------- a row block's bound: its highest live row
+#: chunks of 16 token rows in four row blocks of four tokens, pages of
+#: four tokens, two pages a grid step; (start, live tokens) a sequence
+CHUNKS = {
+    "padding_blocks_behind_a_prefix": [(13, 3)],   # three blocks of none
+    "first_chunk_at_position_0": [(0, 16)],        # each to its diagonal
+    "last_block_partly_live": [(5, 14)],           # two tokens of padding
+    "a_batch_of_them": [(13, 3), (0, 16), (5, 14), (9, 1)],
+}
+
+
+def _chunk_form(form, chunks, seed):
+    """``(run, pools, bt)`` of one chunk call of ``form`` ("dense" |
+    "window" | "latent") over ``chunks``: ``run(impl, *pools)`` is the
+    call's ``[B, 16, ...]`` result under ``impl``, ``pools`` the arrays
+    whose axis 0 counts pages (trash page 0 first), ``bt`` the table."""
+    C, bs, T = 16, 4, 8
+    B = len(chunks)
+    start, n = np.array(chunks, np.int32).T
+    pos = jnp.asarray(start[:, None] + np.arange(C, dtype=np.int32))
+    lens = jnp.asarray(start + n)
+    rng = np.random.default_rng(seed)
+    if form == "latent":
+        from ray_tpu.ops.latent_attention import (latent_attention,
+                                                  latent_row_width)
+        H, dn, dr, dv, rank = 4, 16, 8, 16, 128
+        pool = rng.normal(size=(1 + B * T, 1, bs,
+                                latent_row_width(rank, dr)))
+        pool[..., rank + dr:] = 0.0
+        bt = 1 + np.arange(B * T, dtype=np.int32).reshape(B, T)
+        q = [jnp.asarray(rng.normal(size=s) * w, jnp.float32)
+             for s, w in (((B, C, H, dn), 1), ((B, C, H, dr), 1),
+                          ((rank, H, dn), .1), ((rank, H, dv), .1))]
+
+        def run(impl, pool):
+            return np.asarray(latent_attention(
+                *q, jnp.asarray(pool, jnp.float32)[None], jnp.asarray(bt),
+                pos, layer=0, lens=lens, sm_scale=(dn + dr) ** -0.5,
+                impl=impl, block_r=4 * H))
+        return run, (pool.astype(np.float32),), bt
+    H, KVH, D = 4, 2, 8
+    _, _, kc, vc, bt = _paged_case(seed, B, T * bs, H, KVH, D, bs, T)
+    q = rng.normal(size=(B, C, H, D)).astype(np.float32)
+
+    def run(impl, kc, vc):
+        return np.asarray(paged_attention(
+            q, kc, vc, bt, pos, lens=lens, impl=impl,
+            block_r=4 * H // KVH, window=6 if form == "window" else 0))
+    return run, (kc, vc), bt
+
+
+@pytest.mark.parametrize("case", sorted(CHUNKS))
+@pytest.mark.parametrize("form", ["dense", "window", "latent"])
+def test_a_row_block_folds_no_page_past_its_highest_live_row(
+        form, case, group_of):
+    """A chunk call with more than one row block: every live row is the
+    reference's, every padded row is finite (zero in a block of padding
+    alone), and for each row block in turn NaN planted in every page
+    past ITS bound (the page of its highest live position the last;
+    none where it has no live row) and in the trash page reaches no
+    row of that block: it fetched none of them."""
+    group_of(2)
+    chunks = CHUNKS[case]
+    run, pools, bt = _chunk_form(form, chunks, seed=31)
+    want, got = run("reference", *pools), run("interpret", *pools)
+    assert np.all(np.isfinite(got))
+    for b, (start, n) in enumerate(chunks):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], **TOL)
+    for r in range(4):
+        tokens = slice(4 * r, 4 * r + 4)
+        poisoned = [_poisoned(pool) for pool in pools]
+        for b, (start, n) in enumerate(chunks):
+            top = start + min(n, 4 * r + 4) - 1 if n > 4 * r else -1
+            for pool in poisoned:
+                pool[bt[b, (top + 4) // 4:]] = np.nan
+            if top < 0:
+                assert not got[b, tokens].any()
+        np.testing.assert_array_equal(
+            run("interpret", *poisoned)[:, tokens], got[:, tokens])
+
+
+def _kernel_of(form, batch, chunk, block_r):
+    """The ``pallas_call`` equation of one call of ``form`` at 8 heads
+    on 2 kv heads (a latent pool: on one) over a table of 8 pages."""
+    D, bs, T = 128, 16, 8
+    s = jax.ShapeDtypeStruct
+    pool = s((1 + batch * T, 1 if form == "latent" else 2, bs, D),
+             jnp.float32)
+    kw = {"latent": dict(v_width=64), "window": dict(window=24),
+          "dense": {}}[form]
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, bt, pos, lens: paged_flash_attention(
+            q, k, v, bt, pos, lens, block_r=block_r, interpret=True, **kw)
+    )(s((batch, chunk, 8, D), jnp.float32), pool,
+      None if form == "latent" else pool, s((batch, T), jnp.int32),
+      s((batch, chunk), jnp.int32), s((batch,), jnp.int32))
+    call, = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    return call
+
+
+def _equations(jaxpr, found):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _equations(inner, found)
+    return found
+
+
+def _int_reductions(call):
+    return [e for e in _equations(call.params["jaxpr"], [])
+            if e.primitive.name == "reduce_max"
+            and e.outvars[0].aval.dtype == jnp.int32]
+
+
+#: equations of the kernel's body at the commit before the bound
+#: (28b846f, counted with _equations at _kernel_of's shapes); the latent
+#: form's had seven more, its test of a fourth scalar with the live rows
+ONE_ROW_BLOCK = {"dense": 219, "window": 261, "latent": 175 - 7}
+
+
+@pytest.mark.parametrize("call", ["decode", "verify"])
+@pytest.mark.parametrize("form", sorted(ONE_ROW_BLOCK))
+def test_a_one_row_block_call_traces_the_kernel_it_traced(form, call):
+    """A decode step (a row a head) and a verify step (k + 1 = 5 rows a
+    head) have one row block: the bound is the length itself, and the
+    kernel is the one the parent traced, equation for equation, with no
+    reduction over the position column, no scratch word for it and the
+    operands it had (three scalars, a window's fourth; q, positions,
+    the pools)."""
+    eqn = _kernel_of(form, 4, {"decode": 1, "verify": 5}[call], None)
+    grid = eqn.params["grid_mapping"]
+    assert grid.grid[2] == 1
+    assert len(_equations(eqn.params["jaxpr"], [])) == ONE_ROW_BLOCK[form]
+    assert not _int_reductions(eqn)
+    assert grid.num_scratch_operands == (5 if form == "latent" else 6)
+    assert grid.num_index_operands == (4 if form == "window" else 3)
+    assert len(eqn.invars) == grid.num_index_operands \
+        + (3 if form == "latent" else 4)
+
+
+@pytest.mark.parametrize("form", sorted(ONE_ROW_BLOCK))
+def test_a_chunk_call_takes_the_bound_in_without_a_new_operand(form):
+    """64 tokens in four row blocks (a latent pool's one key head:
+    eight): the kernel reduces the position
+    column once, keeps the result in one more scratch word, and the
+    call's operands are the one-row-block call's."""
+    eqn, one = _kernel_of(form, 1, 64, 64), _kernel_of(form, 4, 1, None)
+    grid = eqn.params["grid_mapping"]
+    assert grid.grid[2] == (8 if form == "latent" else 4)
+    assert len(_int_reductions(eqn)) == 1
+    assert grid.num_scratch_operands \
+        == one.params["grid_mapping"].num_scratch_operands + 1
+    assert grid.num_index_operands \
+        == one.params["grid_mapping"].num_index_operands
+    assert len(eqn.invars) == len(one.invars)
+
+
+@pytest.mark.parametrize("start,chunk,n,rep,block_r", [
+    (21760, 2048, 64, 6, 512),      # repoqa's question: 1 of 24
+    (0, 2048, 2048, 6, 512),        # a document's chunk: all 24
+    (4096, 2048, 1500, 6, 512),     # a document's ragged end
+    (300, 256, 48, 4, 512),         # docqa's question: 1 of 2
+    (0, 256, 256, 4, 512),
+    (0, 256, 100, 1, 128),          # chat: 1 of 2
+    (128, 256, 129, 1, 128),        # one token into the second block
+    (19000, 2048, 64, 128, 512),    # openPangu's question: 16 of 512
+    (7, 24, 3, 4, 8),               # two tokens a block, the second half
+    (7, 24, 1, 4, 16),
+    (0, 5, 5, 3, 8),                # 15 rows in two blocks, one padded
+    (40, 16, 9, 2, None),           # the default block: one of them
+])
+def test_paged_row_blocks_is_the_kernels_own_count(start, chunk, n, rep,
+                                                   block_r):
+    """What the engine books a chunk equals a count, row block by row
+    block, of those with a row the kernel calls live (``0 <= position <
+    lens``) in the rows the call builds: tokens in order, ``rep`` heads'
+    rows each, padded with position −1 to whole blocks."""
+    blocks, live = pf.paged_row_blocks(chunk * rep, n * rep, 128,
+                                       jnp.bfloat16, block_r=block_r,
+                                       chip="v5e")
+    br = pf._row_block(chunk * rep, 128, jnp.bfloat16, block_r, "v5e")
+    pos = np.repeat(start + np.arange(chunk), rep)
+    pos = np.pad(pos, (0, -len(pos) % br), constant_values=-1)
+    is_live = ((pos >= 0) & (pos < start + n)).reshape(-1, br).any(axis=1)
+    assert (blocks, live) == (len(is_live), int(is_live.sum()))
+    assert is_live[:live].all()

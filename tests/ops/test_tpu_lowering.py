@@ -121,22 +121,29 @@ def one_chip():
 
 
 @pytest.mark.parametrize("batch,chunk,heads,kv_heads,head_dim,window,"
-                         "layers,blocks,block_r", [
-    (64, 1, 16, 16, 256, 2048, 6, 1921, 8),        # chat decode
-    (1, 256, 16, 16, 256, 2048, 6, 1921, 128),     # chat chunk
-    (16, 1, 32, 8, 128, 4096, 8, 3585, 16),        # docqa decode
-    (1, 256, 32, 8, 128, 4096, 8, 3585, 512),      # docqa chunk
+                         "layers,blocks,block_r,sliding", [
+    (64, 1, 16, 16, 256, 2048, 6, 1921, 8, 0),        # chat decode
+    (1, 256, 16, 16, 256, 2048, 6, 1921, 128, 0),     # chat chunk
+    (16, 1, 32, 8, 128, 4096, 8, 3585, 16, 0),        # docqa decode
+    (1, 256, 32, 8, 128, 4096, 8, 3585, 512, 0),      # docqa chunk
+    # the widest chunk a cell sends, Laguna-XS.2's: a full layer's 24
+    # row blocks a kv head, a window layer's 32 over its short table
+    (1, 2048, 48, 8, 128, 65536, 2, 38913, 512, 0),
+    (1, 2048, 64, 8, 128, 161 * BLOCK, 3, 4097, 512, 512),
 ])
 def test_paged_kernel_compiles_for_v5e_at_the_cells_shapes(
         one_chip, batch, chunk, heads, kv_heads, head_dim, window, layers,
-        blocks, block_r):
+        blocks, block_r, sliding):
     """The Mosaic compile proper, which the lowering above stops short
     of: the group's 2·P page copies out of the pool left in HBM, the
     read of P pages as one tile and the VMEM the step plans for
     (``_VMEM_BUDGET``, under the default scoped limit: the kernel asks
     for no other) are accepted for a described v5e at each of the
-    cells' four calls, on the whole pool with a traced layer, and the
-    call has no temporaries."""
+    cells' calls, on the whole pool with a traced layer, and the
+    call has no temporaries. The chunks have more than one row block,
+    each bounded by its highest live position: a max over the block's
+    position column moved to a scalar and kept in an SMEM scratch word
+    (the latent form's 512 blocks are the latent test's chunk, below)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     def s(shape, dtype=jnp.bfloat16):
@@ -149,7 +156,8 @@ def test_paged_kernel_compiles_for_v5e_at_the_cells_shapes(
     try:
         compiled = jax.jit(
             lambda q, k, v, bt, pos, lens, layer: paged_flash_attention(
-                q, k, v, bt, pos, lens, layer=layer, block_r=block_r)
+                q, k, v, bt, pos, lens, layer=layer, block_r=block_r,
+                window=sliding)
         ).lower(s((batch, chunk, heads, head_dim)), pool, pool,
                 s((batch, t), jnp.int32), s((batch, chunk), jnp.int32),
                 s((batch,), jnp.int32), s((), jnp.int32)).compile()
@@ -393,8 +401,9 @@ def test_compiled_selecting_routing_step_reads_the_expert_stack_in_place(
 def test_latent_kernel_compiles_for_v5e_at_the_cells_shapes(
         one_chip, batch, chunk, block_r):
     """The paged kernel over the one-pool latent cache
-    (``v_width``: no V pool, a page's first 512 columns its value, a
-    fourth scalar with the live rows) at openPangu's widths: 128 heads
+    (``v_width``: no V pool, a page's first 512 columns its value; the
+    chunk's 512 row blocks each bounded by its own highest live
+    position) at openPangu's widths: 128 heads
     on one 640-wide row (512 + 64 up to whole lane tiles; a 576-wide
     page copy is refused by Mosaic, "must be aligned to tiling (128)"),
     the whole 36,865-page pool with a traced layer, no temporaries."""

@@ -99,6 +99,9 @@ def test_counters_follow_positions():
         assert s["moe_assignments_total"] == 25 * 2 * 2
         assert eng.config.kv_bytes_per_token(eng.model_config) \
             == 2 * (2 * 2 * 8 + 8) * 4
+        # its chunks select their keys in plain XLA: no paged kernel's
+        # row block to book
+        assert s["prefill_row_blocks"] == {} == s["prefill_row_blocks_live"]
     finally:
         eng.shutdown()
 

@@ -148,6 +148,27 @@ def test_a_hit_whose_window_tail_was_evicted_is_cut_back(plain):
         eng.shutdown()
 
 
+def test_row_blocks_by_kind():
+    """40 prompt tokens in chunks of 16, 16 and 8 at a row block of 16
+    rows: a full layer's call has 3 heads a kv head (48 rows, three
+    blocks, the last chunk's 24 live rows in two of them), a window
+    layer's 4 (64 rows, four blocks, 32 live rows in two)."""
+    eng = LLMEngine(
+        TransformerConfig(**dict(MODEL_KW, paged_block_r_prefill=16)),
+        EngineConfig(decode_slots=2, kv_block_size=BS, max_seq_len=128,
+                     prefill_chunk=CHUNK, max_new_tokens=8))
+    try:
+        list(eng.generate_sync(DOC[:40], 2))
+        s = eng.stats()
+        assert s["prefill_chunks"] == 3
+        assert s["prefill_row_blocks"] == {"full": 9, "window": 12}
+        assert s["prefill_row_blocks_live"] == {"full": 8, "window": 10}
+        eng.warmup()                    # its end resets the books
+        assert eng.stats()["prefill_row_blocks"] == {"full": 0, "window": 0}
+    finally:
+        eng.shutdown()
+
+
 def test_pages_by_kind_and_the_pinned_bound():
     """One request alone: 40 prompt tokens in chunks of 16, 16 and 8,
     then 7 decode steps over a window of 16."""

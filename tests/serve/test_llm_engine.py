@@ -845,6 +845,30 @@ def test_stats_book_the_paged_kernels_grid_steps(spec_tokens,
         == st["prefill_chunks"] + st["decode_steps"]
 
 
+def test_stats_book_the_chunks_row_blocks():
+    """``prefill_row_blocks`` / ``prefill_row_blocks_live``: what the
+    paged kernel's row-block axis takes in a chunk's call and what of
+    it has a live row, by kind of layer (one kind here), at the row
+    block the call runs with: 16 token rows in two blocks of 8, prompts
+    of 12, 12, 5 and 12 tokens with no page in common."""
+    eng = LLMEngine(
+        TransformerConfig(**dict(MODEL_KW, paged_block_r_prefill=8)),
+        EngineConfig(decode_slots=4, kv_block_size=4, max_seq_len=48,
+                     prefill_chunk=16, max_new_tokens=16))
+    try:
+        for first, n in ((7, 12), (8, 12), (9, 5), (10, 12)):
+            list(eng.generate_sync([first] + _SHARED[1:n],
+                                   max_new_tokens=2))
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert st["prefill_chunks"] == 4 and st["prefix_hit_blocks_total"] == 0
+    assert st["prefill_row_blocks"] == {"full": 8}
+    assert st["prefill_row_blocks_live"] == {"full": 7}
+    assert st["h2d_transfers_total"] \
+        == st["prefill_chunks"] + st["decode_steps"]
+
+
 @pytest.mark.parametrize("spec_tokens", [0, 4])
 def test_warm_ticks_run_no_eager_device_op_on_the_step_thread(
         spec_tokens):
