@@ -9,6 +9,12 @@ Supports two block styles behind one config:
 - ``"llama"`` — sequential pre-RMSNorm blocks, SwiGLU MLP, full-dim neox
   rotary, optional GQA (num_kv_heads < num_heads).
 
+Both, and every served form of the 'llama' block (latent attention,
+window layers, experts, a key selection: ``TransformerConfig``), are ONE
+block function (``_block``) over a description of each kind of layer
+that ``_layer_plan`` builds from the configuration, once; training and
+the cache path hand it their attention.
+
 Design (TPU-first, not a port):
 - params are a plain dict pytree; per-layer weights are STACKED on a
   leading ``layers`` axis and the forward pass is one ``lax.scan`` over
@@ -39,9 +45,10 @@ Design (TPU-first, not a port):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -233,13 +240,6 @@ class TransformerConfig:
         """A form only ``prefill`` / ``decode_step`` implement."""
         return bool(self.served_keys)
 
-    @property
-    def by_kind(self) -> bool:
-        """The stack is built by kind of layer (``_forward_kinds``)."""
-        return not self.kv_lora_rank and bool(
-            self.layer_pattern or self.n_dense_layers or self.window_heads
-            or self.sliding_window or self.rope_yarn or self.head_gate)
-
     def layer_kind(self, layer: int) -> str:
         pattern = self.layer_pattern or ("full",)
         return pattern[layer % len(pattern)]
@@ -257,10 +257,6 @@ class TransformerConfig:
         return (self.paged_block_r_prefill if tokens > 1 else 0) \
             or self.paged_block_r or None
 
-    def kind_layers(self, kind: str) -> int:
-        """Layers of ``kind`` in the stack, the dense ones among them."""
-        return sum(self.layer_kind(l) == kind for l in range(self.n_layers))
-
     @property
     def resolved_remat_policy(self) -> str:
         """Effective remat policy, honoring the legacy ``remat`` bool."""
@@ -273,7 +269,7 @@ class TransformerConfig:
         """Parameter count (for MFU accounting)."""
         e, v, h = self.d_model, self.vocab_size, self.n_heads * self.head_dim
         kvh = self.kv_heads * self.head_dim
-        if self.by_kind:
+        if _tree_form(self) == "kinds":
             total = 2 * v * e + e                 # embed, head, final norm
             for l in range(self.n_layers):
                 hk = self.kind_heads(self.layer_kind(l))
@@ -395,11 +391,12 @@ def init_params(config: TransformerConfig, key,
         return dense(k, (L,) + shape, scale)
 
     out_scale = 0.02 / (2 * L) ** 0.5    # scaled residual-out init
-    _check_served_forms(c)
-    if c.kv_lora_rank:
+    tree = _layer_plan(c).tree          # and the refusals
+    if tree == "latent":
         return _init_latent_params(c, key, jnp.dtype(dtype), out_scale)
-    if c.by_kind:
+    if tree == "kinds":
         return _init_kind_params(c, key, jnp.dtype(dtype), out_scale)
+    llama = c.block_style == "llama"
     layers: Dict[str, jnp.ndarray] = {
         "wq": stack(keys[0], (c.d_model, h)),
         "wk": stack(keys[1], (c.d_model, kvh)),
@@ -429,11 +426,6 @@ def init_params(config: TransformerConfig, key,
                 jax.random.fold_in(ek, i),
                 out_scale if name == "we_down" else 0.02, L, shape,
                 jnp.dtype(dtype))
-        layers.update({
-            "attn_norm": jnp.ones((L, c.d_model), jnp.float32),
-            "mlp_norm": jnp.ones((L, c.d_model), jnp.float32)})
-        final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
-        head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
     elif c.n_experts:
         from ray_tpu.models.moe import moe_param_shapes
         mk = jax.random.split(keys[6], 3)
@@ -442,43 +434,31 @@ def init_params(config: TransformerConfig, key,
                         out_scale if name == "moe_wo" else 0.02)
             for i, (name, shape) in
             enumerate(sorted(moe_param_shapes(c).items()))})
-        if c.block_style == "llama":
-            layers.update({
-                "attn_norm": jnp.ones((L, c.d_model), jnp.float32),
-                "mlp_norm": jnp.ones((L, c.d_model), jnp.float32)})
-            final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
-            head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
-        else:
-            layers.update({
-                "ln_scale": jnp.ones((L, c.d_model), jnp.float32),
-                "ln_bias": jnp.zeros((L, c.d_model), jnp.float32)})
-            final = {"scale": jnp.ones((c.d_model,), jnp.float32),
-                     "bias": jnp.zeros((c.d_model,), jnp.float32)}
-            head = {"w": dense(keys[8], (c.d_model, c.vocab_size)),
-                    "b": jnp.zeros((c.vocab_size,), jnp.float32)}
-    elif c.block_style == "llama":
+    elif llama:
         layers.update({
             "w_gate": stack(keys[4], (c.d_model, c.d_ff)),
             "w_up": stack(keys[5], (c.d_model, c.d_ff)),
-            "w_down": stack(keys[6], (c.d_ff, c.d_model), out_scale),
-            "attn_norm": jnp.ones((L, c.d_model), jnp.float32),
-            "mlp_norm": jnp.ones((L, c.d_model), jnp.float32),
-        })
-        final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
-        head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
+            "w_down": stack(keys[6], (c.d_ff, c.d_model), out_scale)})
     else:
         layers.update({
             "fc_in": stack(keys[4], (c.d_model, c.d_ff)),
             "fc_in_b": jnp.zeros((L, c.d_ff), jnp.float32),
             "fc_out": stack(keys[5], (c.d_ff, c.d_model), out_scale),
-            "fc_out_b": jnp.zeros((L, c.d_model), jnp.float32),
+            "fc_out_b": jnp.zeros((L, c.d_model), jnp.float32)})
+    # the block's norms, the final norm and the head: RMS and no bias
+    # anywhere, or LayerNorm and a head with one
+    final = {"scale": jnp.ones((c.d_model,), jnp.float32)}
+    head = {"w": dense(keys[8], (c.d_model, c.vocab_size))}
+    if llama:
+        layers.update({
+            "attn_norm": jnp.ones((L, c.d_model), jnp.float32),
+            "mlp_norm": jnp.ones((L, c.d_model), jnp.float32)})
+    else:
+        layers.update({
             "ln_scale": jnp.ones((L, c.d_model), jnp.float32),
-            "ln_bias": jnp.zeros((L, c.d_model), jnp.float32),
-        })
-        final = {"scale": jnp.ones((c.d_model,), jnp.float32),
-                 "bias": jnp.zeros((c.d_model,), jnp.float32)}
-        head = {"w": dense(keys[8], (c.d_model, c.vocab_size)),
-                "b": jnp.zeros((c.vocab_size,), jnp.float32)}
+            "ln_bias": jnp.zeros((L, c.d_model), jnp.float32)})
+        final["bias"] = jnp.zeros((c.d_model,), jnp.float32)
+        head["b"] = jnp.zeros((c.vocab_size,), jnp.float32)
 
     return {
         "embed": dense(keys[7], (c.vocab_size, c.d_model)),
@@ -494,28 +474,6 @@ def _check_served_forms(c: TransformerConfig) -> None:
             f"the served forms ({', '.join(c.SERVED_KEYS)}) are forms of "
             f"the 'llama' block; got {', '.join(c.served_keys)} with "
             f"block_style {c.block_style!r}")
-    if c.by_kind:
-        kinds = {c.layer_kind(l) for l in range(c.n_layers)}
-        if not kinds <= {"full", "window"}:
-            raise ValueError(f"layer_pattern {c.layer_pattern}: a layer "
-                             f"is 'full' or 'window'")
-        if ("window" in kinds) != bool(c.sliding_window):
-            raise ValueError("'window' layers in layer_pattern and "
-                             "sliding_window > 0 come together, got "
-                             f"{c.layer_pattern} and {c.sliding_window}")
-        if c.qk_norm or c.index_topk:
-            raise ValueError("qk_norm and index_topk are not forms of a "
-                             "stack by kind of layer (layer_pattern, "
-                             "n_dense_layers, head_gate, rope_yarn)")
-        if len({c.layer_kind(l) for l in range(c.n_dense_layers)}) > 1 \
-                or not 0 <= c.n_dense_layers <= c.n_layers:
-            raise ValueError("the leading n_dense_layers are of one kind")
-        if c.n_dense_layers and not (
-                c.experts_per_token and c.n_dense_layers < c.n_layers):
-            raise ValueError("n_dense_layers lead layers of dropless "
-                             "experts (experts_per_token > 0)")
-        if c.experts_held:
-            raise ValueError("a stack by kind of layer holds every expert")
     if c.rope_yarn and len(c.rope_yarn) != 5:
         raise ValueError("rope_yarn is (factor, original length, "
                          "beta_fast, beta_slow, attention factor)")
@@ -568,8 +526,194 @@ def _check_served_forms(c: TransformerConfig) -> None:
             f"held of {c.n_experts}")
 
 
-#: where a stack by kind of layer keeps each kind's routed layers
+#: where the tree keeps each kind's layers (all of them, or with
+#: ``n_dense_layers`` those behind ``dense_layers``)
 KIND_STACKS = {"full": "layers", "window": "window_layers"}
+
+#: a window layer's pools, beside the full layers' "k" / "v"
+WINDOW_POOLS = ("k_window", "v_window")
+
+
+# ------------------------------------------------------ the layer plan
+# What a configuration's keys ask of a layer is decided HERE, once:
+# :func:`_layer_plan` turns them into a description of each kind of layer
+# the stack has and of the runs of layers the forward pass scans. The one
+# block (:func:`_block`), the two mixers (:func:`_paged_attn_sublayer`,
+# :func:`_latent_attn_sublayer`), the scan driver
+# (:func:`_forward_with_cache`), :func:`run_layers` and
+# :func:`init_kv_cache` read the description and test no key themselves.
+
+class _Rotary(NamedTuple):
+    """A kind of layer's rotary embedding."""
+    layout: str                  # "gptj": pairs interleaved | "neox": halves
+    dim: int                     # rotated width, a prefix of the head
+    base: float
+    yarn: Tuple[float, ...]      # () plain, else ``rope_yarn``'s five
+    # computed at the positions asked (``rotary_at``), or a table as long
+    # as the block table reaches, gathered at them: one result, two
+    # programs, and each configuration keeps the one it has (ROADMAP
+    # C1(c))
+    at_positions: bool
+
+
+class _Pool(NamedTuple):
+    """One of the cache's pools: ``[layers, blocks, heads, block_size,
+    width]`` under ``name``."""
+    name: str
+    heads: int
+    width: int
+
+
+@dataclasses.dataclass(frozen=True)
+class _LayerKind:
+    """One kind of layer, as the forward pass needs to know it."""
+    name: str                    # "full" | "window"
+    norm: str                    # "layer" (with bias) | "rms" | "gated"
+    # attention and MLP read the one normed input and are added together
+    # (the 'gptj' form) | the MLP follows the attention's residual
+    parallel: bool
+    post_norm: bool              # a second RMSNorm on each sublayer's output
+    mixer: str                   # "paged": per-head K/V | "latent": MLA rows
+    heads: int                   # query heads
+    window: int                  # keys attended behind a position, 0 = all
+    qk_norm: bool
+    head_gate: bool
+    index_topk: int              # keys a learned selection keeps, 0 = all
+    # the pools a layer writes (its keys, its values, ``ki`` last where it
+    # selects) and the table it reads them through: "main" (entry 0 is
+    # position 0) | "window" (a short table that starts behind the window)
+    pools: Tuple[_Pool, ...]
+    table: str
+    scope: Optional[str]         # the named scope below ``layer/attn``
+    rotary: _Rotary
+    index_rotary: Optional[_Rotary]      # None: the layer's own
+
+
+class _Run(NamedTuple):
+    """Consecutive layers of one kind in one stack of the tree: one scan."""
+    stack: str                   # the stack's name in the tree
+    kind: _LayerKind
+    experts: bool                # dropless experts | a dense MLP
+    at: int                      # the first layer's index in the stack
+    n: int
+    cache_layer: int             # the first layer's, in the kind's pools
+
+
+class _LayerPlan(NamedTuple):
+    tree: str                    # which builder makes the tree (_tree_form)
+    kinds: Tuple[_LayerKind, ...]
+    runs: Tuple[_Run, ...]
+
+
+def _tree_form(c: TransformerConfig) -> str:
+    """Which of the three builders of the parameter tree a configuration
+    takes (ROADMAP C1(b)): "latent" (``kv_lora_rank``), "kinds" (stacks
+    by kind of layer: any of the keys below) or "plain" (one stack)."""
+    if c.kv_lora_rank:
+        return "latent"
+    return "kinds" if (
+        c.layer_pattern or c.n_dense_layers or c.window_heads
+        or c.sliding_window or c.rope_yarn or c.head_gate) else "plain"
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_plan(c: TransformerConfig) -> _LayerPlan:
+    """The description of ``c``'s stack; raises for a combination of keys
+    no path implements."""
+    from ray_tpu.ops.latent_attention import latent_row_width
+    _check_served_forms(c)
+    tree = _tree_form(c)
+    latent, by_kind = tree == "latent", tree == "kinds"
+    pattern = {c.layer_kind(l) for l in range(c.n_layers)}
+    if latent and (pattern != {"full"} or c.sliding_window
+                   or c.window_heads):
+        raise ValueError("layer_pattern, sliding_window and window_heads "
+                         "are forms of per-head K/V, not of a latent cache")
+    if not pattern <= {"full", "window"}:
+        raise ValueError(f"layer_pattern {c.layer_pattern}: a layer "
+                         f"is 'full' or 'window'")
+    if ("window" in pattern) != bool(c.sliding_window):
+        raise ValueError("'window' layers in layer_pattern and "
+                         "sliding_window > 0 come together, got "
+                         f"{c.layer_pattern} and {c.sliding_window}")
+    if by_kind:
+        if c.qk_norm or c.index_topk:
+            raise ValueError("qk_norm and index_topk are not forms of a "
+                             "stack by kind of layer (layer_pattern, "
+                             "n_dense_layers, head_gate, rope_yarn)")
+        if len({c.layer_kind(l) for l in range(c.n_dense_layers)}) > 1 \
+                or not 0 <= c.n_dense_layers <= c.n_layers:
+            raise ValueError("the leading n_dense_layers are of one kind")
+        if c.n_dense_layers and not (
+                c.experts_per_token and c.n_dense_layers < c.n_layers):
+            raise ValueError("n_dense_layers lead layers of dropless "
+                             "experts (experts_per_token > 0)")
+        if c.experts_held:
+            raise ValueError("a stack by kind of layer holds every expert")
+
+    gptj = c.block_style == "gptj"
+    page = (c.kv_heads, c.head_dim)
+
+    def kind(name: str) -> _LayerKind:
+        window = name == "window"
+        if latent:
+            # ONE pool, a row a token and layer for every head (the
+            # normed latent | the rotated shared key | zeros up to whole
+            # lane tiles), under the one "head" the page layout keeps:
+            # key and, in its first kv_lora_rank columns, value
+            pools = (_Pool("latent", 1, latent_row_width(
+                c.kv_lora_rank, c.qk_rope_dim)),)
+            rotary = _Rotary("neox", c.qk_rope_dim, c.rope_base,
+                             c.rope_yarn, bool(c.rope_yarn))
+        elif window:
+            pools = tuple(_Pool(n, *page) for n in WINDOW_POOLS)
+            rotary = _Rotary("neox", c.window_rotary_dim or c.head_dim,
+                             c.window_rope_base, (), True)
+        else:
+            # one stack of 'llama' blocks rotates the whole head, a stack
+            # by kind of layer its "full" layers' first rotary_dim
+            pools = (_Pool("k", *page), _Pool("v", *page))
+            rotary = _Rotary(
+                "gptj" if gptj else "neox",
+                c.rotary_dim if gptj or by_kind else c.head_dim,
+                c.rope_base, c.rope_yarn, by_kind)
+        if c.index_topk:
+            pools += (_Pool("ki", 1, c.index_dim),)
+        return _LayerKind(
+            name=name,
+            norm="layer" if gptj else "gated" if c.gated_norm_rank
+            else "rms",
+            parallel=gptj, post_norm=c.sandwich_norm,
+            mixer="latent" if latent else "paged",
+            heads=c.kind_heads(name),
+            window=c.sliding_window if window else 0,
+            qk_norm=c.qk_norm, head_gate=c.head_gate,
+            index_topk=c.index_topk, pools=pools,
+            table="window" if window else "main",
+            scope=name if by_kind else None, rotary=rotary,
+            # over per-head K/V the indexer rotates all of index_dim,
+            # plain, by a table; over latent rows by the layer's own
+            index_rotary=_Rotary("neox", c.index_dim, c.rope_base, (),
+                                 False)
+            if c.index_topk and not latent else None)
+
+    # a stack with window layers has both kinds of pool, even at a depth
+    # that holds no layer of one of them
+    kinds = {name: kind(name) for name in
+             ("full",) + (("window",) if c.sliding_window else ())}
+    runs, seen, ordinal = [], {}, dict.fromkeys(kinds, 0)
+    for l in range(c.n_layers):
+        k, lead = kinds[c.layer_kind(l)], l < c.n_dense_layers
+        stack = "dense_layers" if lead else KIND_STACKS[k.name]
+        at = seen.get(stack, 0)
+        if runs and runs[-1].stack == stack:
+            runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
+        else:
+            runs.append(_Run(stack, k, bool(c.experts_per_token)
+                             and not lead, at, 1, ordinal[k.name]))
+        seen[stack] = at + 1
+        ordinal[k.name] += 1
+    return _LayerPlan(tree, tuple(kinds.values()), tuple(runs))
 
 
 def _kind_layer_shapes(c: TransformerConfig, kind: str, dense: bool
@@ -765,64 +909,50 @@ def _latent_logical_axes(c) -> Dict:
 def logical_axes(config: TransformerConfig) -> Dict:
     """Pytree (same treedef as params) of logical-axis tuples."""
     c = config
-    if c.kv_lora_rank:
+    tree = _tree_form(c)
+    if tree == "latent":
         return _latent_logical_axes(c)
-    if c.by_kind:
+    if tree == "kinds":
         return _kind_logical_axes(c)
-    common = {
+    llama = c.block_style == "llama"
+    layers = {
         "wq": ("layers", "embed", "heads"),
         "wk": ("layers", "embed", "kv"),
         "wv": ("layers", "embed", "kv"),
         "wo": ("layers", "heads", "embed"),
     }
     if c.qk_norm:
-        common.update({"q_norm": ("layers", None),
+        layers.update({"q_norm": ("layers", None),
                        "k_norm": ("layers", None)})
     if c.index_topk:
-        common.update({"wq_idx": ("layers", "embed", None),
+        layers.update({"wq_idx": ("layers", "embed", None),
                        "wk_idx": ("layers", "embed", None),
                        "ww_idx": ("layers", "embed", None),
                        "k_idx_scale": ("layers", None),
                        "k_idx_bias": ("layers", None)})
     if c.experts_per_token:
         from ray_tpu.models.moe import topk_moe_logical_axes
-        layers = {**common, **topk_moe_logical_axes(c),
-                  "attn_norm": ("layers", "embed"),
-                  "mlp_norm": ("layers", "embed")}
-        final = {"scale": ("embed",)}
-        head = {"w": ("embed", "vocab")}
+        layers.update(topk_moe_logical_axes(c))
     elif c.n_experts:
         from ray_tpu.models.moe import moe_logical_axes
-        layers = {**common, **moe_logical_axes()}
-        if c.block_style == "llama":
-            layers.update({"attn_norm": ("layers", "embed"),
-                           "mlp_norm": ("layers", "embed")})
-            final = {"scale": ("embed",)}
-            head = {"w": ("embed", "vocab")}
-        else:
-            layers.update({"ln_scale": ("layers", "embed"),
-                           "ln_bias": ("layers", "embed")})
-            final = {"scale": ("embed",), "bias": ("embed",)}
-            head = {"w": ("embed", "vocab"), "b": ("vocab",)}
-    elif c.block_style == "llama":
-        layers = {**common,
-                  "w_gate": ("layers", "embed", "mlp"),
-                  "w_up": ("layers", "embed", "mlp"),
-                  "w_down": ("layers", "mlp", "embed"),
-                  "attn_norm": ("layers", "embed"),
-                  "mlp_norm": ("layers", "embed")}
-        final = {"scale": ("embed",)}
-        head = {"w": ("embed", "vocab")}
+        layers.update(moe_logical_axes())
+    elif llama:
+        layers.update({"w_gate": ("layers", "embed", "mlp"),
+                       "w_up": ("layers", "embed", "mlp"),
+                       "w_down": ("layers", "mlp", "embed")})
     else:
-        layers = {**common,
-                  "fc_in": ("layers", "embed", "mlp"),
-                  "fc_in_b": ("layers", "mlp"),
-                  "fc_out": ("layers", "mlp", "embed"),
-                  "fc_out_b": ("layers", "embed"),
-                  "ln_scale": ("layers", "embed"),
-                  "ln_bias": ("layers", "embed")}
-        final = {"scale": ("embed",), "bias": ("embed",)}
-        head = {"w": ("embed", "vocab"), "b": ("vocab",)}
+        layers.update({"fc_in": ("layers", "embed", "mlp"),
+                       "fc_in_b": ("layers", "mlp"),
+                       "fc_out": ("layers", "mlp", "embed"),
+                       "fc_out_b": ("layers", "embed")})
+    final, head = {"scale": ("embed",)}, {"w": ("embed", "vocab")}
+    if llama:
+        layers.update({"attn_norm": ("layers", "embed"),
+                       "mlp_norm": ("layers", "embed")})
+    else:
+        layers.update({"ln_scale": ("layers", "embed"),
+                       "ln_bias": ("layers", "embed")})
+        final["bias"], head["b"] = ("embed",), ("vocab",)
     return {
         "embed": ("vocab", "embed"),
         "layers": layers,
@@ -968,18 +1098,20 @@ def _swiglu(c, h, lp):
 
 @jax.named_scope("mlp")
 def _mlp_sublayer(c, h, lp, layer=None):
-    """Dense or MoE MLP on normed input h; returns (out, moe_aux).
-    ``layer``: ``lp``'s expert leaves are whole stacks and this is the
-    layer's index in them (``moe.topk_moe_mlp``)."""
+    """The MLP the layer's leaves ``lp`` hold, on normed input h: a dense
+    SwiGLU (``w_gate``: the 'llama' block, and a leading dense layer
+    ahead of experts), experts, or the 'gptj' block's biased GELU MLP;
+    returns (out, moe_aux). ``layer``: ``lp``'s expert leaves are whole
+    stacks and this is the layer's index in them (``moe.topk_moe_mlp``)."""
     dt = c.dtype
+    if "w_gate" in lp:
+        return _swiglu(c, h, lp), 0.0
     if c.experts_per_token:
         from ray_tpu.models.moe import topk_moe_mlp
         return topk_moe_mlp(c, lp, h, layer), 0.0
     if c.n_experts:
         from ray_tpu.models.moe import moe_mlp
         return moe_mlp(c, lp, h.astype(dt))
-    if c.block_style == "llama":
-        return _swiglu(c, h, lp), 0.0
     mlp = jnp.dot(h.astype(dt), lp["fc_in"].astype(dt)) \
         + lp["fc_in_b"].astype(dt)
     mlp = jax.nn.gelu(mlp)
@@ -987,29 +1119,48 @@ def _mlp_sublayer(c, h, lp, layer=None):
         + lp["fc_out_b"].astype(dt), 0.0
 
 
-def _gptj_block(c, x, lp, sin, cos, mesh, rules):
-    x = checkpoint_name(x, "block_in")
-    h = layer_norm(x, lp["ln_scale"], lp["ln_bias"])
-    att = _attn_sublayer(c, h, lp, sin, cos, "gptj", mesh, rules)
-    mlp, aux = _mlp_sublayer(c, h, lp)
-    return x + (att + mlp).astype(x.dtype), aux
+def _block(c, kind: _LayerKind, x, lp, attend, mlp):
+    """THE transformer block, for training and for the cache path, of
+    every kind of layer: norm -> ``attend(h) -> (att, cache)`` -> residual
+    -> norm -> ``mlp(h) -> (out, moe_aux)`` -> residual, or with
+    ``kind.parallel`` both sublayers off the one norm and one residual.
+    ``kind`` says which norms (every RMS norm at ``norm_eps``; LayerNorm
+    keeps its own 1e-5, as the final norm does) and whether a second one
+    follows each sublayer; ``lp`` holds the layer's norm leaves. Returns
+    (x, cache, moe_aux)."""
+    eps = c.norm_eps
 
+    def pre(x, name):
+        if kind.norm == "layer":
+            return layer_norm(x, lp["ln_scale"], lp["ln_bias"])
+        if kind.norm == "gated":
+            return gated_rms_norm(x, lp[f"{name}_norm"],
+                                  lp[f"{name}_gn_down"],
+                                  lp[f"{name}_gn_up"], eps=eps)
+        return rms_norm(x, lp[f"{name}_norm"], eps=eps)
 
-def _llama_block(c, x, lp, sin, cos, mesh, rules):
-    dt = c.dtype
+    def post(y, name):
+        if not kind.post_norm:
+            return y
+        with jax.named_scope("post_norm"):
+            return rms_norm(y, lp[name], eps=eps)
+    # a name for the remat policies ("offload"); no op of the program
     x = checkpoint_name(x, "block_in")
-    h = rms_norm(x, lp["attn_norm"])
-    att = _attn_sublayer(c, h, lp, sin, cos, "neox", mesh, rules)
-    x = x + att.astype(x.dtype)
-    h2 = rms_norm(x, lp["mlp_norm"]).astype(dt)
-    mlp, aux = _mlp_sublayer(c, h2, lp)
-    return x + mlp.astype(x.dtype), aux
+    h = pre(x, "attn")
+    att, cache = attend(h)
+    if kind.parallel:
+        out, aux = mlp(h)
+        return x + (att + out).astype(x.dtype), cache, aux
+    x = x + post(att, "post_attn_norm").astype(x.dtype)
+    out, aux = mlp(pre(x, "mlp").astype(c.dtype))
+    return x + post(out, "post_mlp_norm").astype(x.dtype), cache, aux
 
 
 def refuse_training(c: TransformerConfig) -> None:
-    """Raise, naming the keys at fault, for a configuration whose forms
-    only the cache path implements (``run_layers``, ``make_train_step``
-    and ``ParallelPlan.build`` ask)."""
+    """Raise, naming the keys at fault, for a configuration with a form
+    that only :func:`_forward_with_cache` gives :func:`_block` a mixer
+    for (``run_layers``, ``make_train_step`` and ``ParallelPlan.build``
+    ask)."""
     if c.served_only:
         raise NotImplementedError(
             f"{', '.join(c.served_keys)}: set here, and served through "
@@ -1027,14 +1178,16 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
     the stacked layer leaves — same scan, fewer layers)."""
     c = config
     refuse_training(c)
-    seq = x.shape[1]
-    sin, cos = rotary_table(
-        seq, c.rotary_dim if c.block_style == "gptj" else c.head_dim,
-        c.rope_base)
+    (kind,) = _layer_plan(c).kinds    # training: one kind of layer
+    sin, cos = _rotary(kind.rotary, None, x.shape[1])
 
-    block = _gptj_block if c.block_style == "gptj" else _llama_block
-    body = functools.partial(block, c, sin=sin, cos=cos,
-                             mesh=mesh, rules=rules)
+    def body(x, lp):
+        out, _, aux = _block(
+            c, kind, x, lp,
+            lambda h: (_attn_sublayer(c, h, lp, sin, cos,
+                                      kind.rotary.layout, mesh, rules), None),
+            lambda h: _mlp_sublayer(c, h, lp))
+        return out, aux
     policy = c.resolved_remat_policy
     if policy != "none":
         body = jax.checkpoint(body, policy=remat_policy_fn(policy))
@@ -1054,9 +1207,9 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
 @jax.named_scope("final_norm")
 def _final_norm(config: TransformerConfig, params: Dict, x: jnp.ndarray):
     fn = params["final_norm"]
-    if config.block_style == "llama":
-        return rms_norm(x, fn["scale"], eps=config.norm_eps)
-    return layer_norm(x, fn["scale"], fn["bias"])
+    if "bias" in fn:
+        return layer_norm(x, fn["scale"], fn["bias"])
+    return rms_norm(x, fn["scale"], eps=config.norm_eps)
 
 
 def hidden_states(config: TransformerConfig, params: Dict,
@@ -1078,7 +1231,7 @@ def hidden_states(config: TransformerConfig, params: Dict,
 def _lm_head(c: TransformerConfig, params: Dict, x: jnp.ndarray):
     logits = jnp.dot(x.astype(c.dtype),
                      params["lm_head"]["w"].astype(c.dtype))
-    if c.block_style != "llama":
+    if "b" in params["lm_head"]:
         logits = logits + params["lm_head"]["b"].astype(c.dtype)
     return logits
 
@@ -1282,7 +1435,7 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
             head_bias=head.get("b"), mask=mask,
             chunk_size=c.ce_chunk_size)
     logits = jnp.dot(h.astype(c.dtype), head["w"].astype(c.dtype))
-    if c.block_style != "llama":
+    if "b" in head:
         logits = logits + head["b"].astype(c.dtype)
     return cross_entropy_loss(logits[:, :-1], labels, mask=mask)
 
@@ -1299,10 +1452,6 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 # the pool: the layer scan carries it whole, each layer scatters its new
 # rows into it and attends it by (layer, block). Jitted with the cache
 # donated, a step updates the caller's buffer in place.
-
-#: a window layer's pools, beside the full layers' "k" / "v"
-WINDOW_POOLS = ("k_window", "v_window")
-
 
 def init_kv_cache(config: TransformerConfig, num_blocks: int,
                   block_size: int, window_blocks: Optional[int] = None
@@ -1335,34 +1484,21 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     behind ``sliding_window``, so its pages are given back as the
     sequence passes them). ``window_blocks=None``: as many as
     ``num_blocks``, for a caller that reads both kinds through one
-    table."""
-    c = config
-    if c.kv_lora_rank:
-        # a latent cache: ONE pool, a row a token and layer for every
-        # head (the normed latent | the rotated shared key | zeros up
-        # to whole lane tiles), under the one "head" the page layout
-        # keeps: key and, in its first kv_lora_rank columns, value
-        from ray_tpu.ops.latent_attention import latent_row_width
-        page = (c.n_layers, num_blocks, 1, block_size)
-        cache = {"latent": jnp.zeros(
-            page + (latent_row_width(c.kv_lora_rank, c.qk_rope_dim),),
-            c.dtype)}
-        if c.index_topk:
-            cache["ki"] = jnp.zeros(page + (c.index_dim,), c.dtype)
-        return cache
-    if c.by_kind and c.sliding_window:
-        page = (c.kv_heads, block_size, c.head_dim)
-        sizes = {"k": ("full", num_blocks), "v": ("full", num_blocks)}
-        sizes.update(dict.fromkeys(
-            WINDOW_POOLS, ("window", num_blocks if window_blocks is None
-                           else window_blocks)))
-        return {name: jnp.zeros((c.kind_layers(kind), n) + page, c.dtype)
-                for name, (kind, n) in sizes.items()}
-    shape = (c.n_layers, num_blocks, c.kv_heads, block_size, c.head_dim)
-    cache = {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype)}
-    if c.index_topk:
-        cache["ki"] = jnp.zeros(
-            (c.n_layers, num_blocks, 1, block_size, c.index_dim), c.dtype)
+    table.
+
+    Which pools, how wide and of how many layers is the layer
+    description's to say (``_layer_plan``: each kind's ``pools``, its
+    ``table``, and the layers the runs count)."""
+    plan = _layer_plan(config)
+    cache = {}
+    for kind in plan.kinds:
+        layers = sum(run.n for run in plan.runs if run.kind is kind)
+        blocks = num_blocks if kind.table == "main" \
+            or window_blocks is None else window_blocks
+        for pool in kind.pools:
+            cache[pool.name] = jnp.zeros(
+                (layers, blocks, pool.heads, block_size, pool.width),
+                config.dtype)
     return cache
 
 
@@ -1417,133 +1553,20 @@ def _write_rows(cache, new, layer, block_tables, positions, write_mask):
             for name, pool in cache.items()}
 
 
-@jax.named_scope("attn")
-def _paged_attn_sublayer(c, h, lp, rot, layout, layer, cache,
-                         block_tables, positions, write_mask, lens):
-    """Decode-path attention sublayer of layer ``layer`` (an int32
-    scalar, traced by the layer scan): project qkv for the new tokens,
-    rotate at their absolute positions, scatter k/v into that layer's
-    pages of the WHOLE 5-D pools, then attend against the (now-updated)
-    pages by ``(layer, block)``. The pools (``cache``, as
-    :func:`init_kv_cache` made it) come in and go out whole — the scan's
-    carry — so the write is one in-place scatter of the new rows, not a
-    copy of the layer. ``lens`` is the per-sequence live token count
-    after this call's writes — the Pallas kernel skips whole cache
-    blocks past it. With an indexer the new tokens' ``kI`` goes into the
-    same pages, and attention reads the keys the indexer selects.
-    ``rot``: the (sin, cos) tables, the head's and the indexer's.
-    Returns (attn_out, cache)."""
-    e = h.shape[-1]
-    dt = c.dtype
-    sin, cos, isin, icos = rot
-
-    def proj(w, n):
-        return jnp.einsum("bse,ehd->bshd", h.astype(dt),
-                          w.reshape(e, n, -1).astype(dt))
-    q = proj(lp["wq"], c.n_heads)
-    k = proj(lp["wk"], c.kv_heads)
-    v = proj(lp["wv"], c.kv_heads)
-    if c.qk_norm:
-        q = rms_norm(q, lp["q_norm"])
-        k = rms_norm(k, lp["k_norm"])
-    q = apply_rotary(q, sin, cos, positions=positions, layout=layout)
-    k = apply_rotary(k, sin, cos, positions=positions, layout=layout)
-    new = {"k": k, "v": v}
-    if c.index_topk:
-        qi, new["ki"], wi = _indexer(c, h, lp, isin, icos, positions)
-
-    bs = cache["k"].shape[3]
-    cache = _write_rows(cache, new, layer, block_tables, positions,
-                        write_mask)
-
-    if 0 < c.index_topk < block_tables.shape[1] * bs:
-        from ray_tpu.ops.sparse_attention import sparse_paged_attention
-        att = sparse_paged_attention(
-            q, qi, wi, cache["k"], cache["v"], cache["ki"], block_tables,
-            positions, lens, layer=layer, topk=c.index_topk)
-    else:
-        # no indexer: every dense model's path. An indexer whose window
-        # holds no more than index_topk tokens lands here too (the
-        # selection is the identity): no cell runs that, a test holds
-        # it to the dense path bit for bit
-        with jax.named_scope("paged_attn"):
-            att = paged_attention(q, cache["k"], cache["v"], block_tables,
-                                  positions, layer=layer, lens=lens,
-                                  impl=c.paged_impl,
-                                  block_r=c.paged_row_block(h.shape[1]))
-    out = jnp.einsum("bshd,hde->bse", att,
-                     lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
-    return out, cache
-
-
-@jax.named_scope("attn")
-def _latent_attn_sublayer(c, h, lp, rot, layer, cache, block_tables,
-                          positions, write_mask, lens):
-    """The latent (MLA) attention sublayer of cache layer ``layer``:
-    queries through the ``q_lora_rank`` bottleneck, one latent row a
-    new token written to the pool (normed latent | rotated shared key),
-    then every head attends the pool's rows themselves
-    (``ops/latent_attention.py``): ``wkv_b``'s key half goes into the
-    query and its value half comes after the softmax, so no per-head K
-    or V of the context exists. With an indexer (``index_topk``) the new
-    tokens' index keys go into the same pages' ``ki`` and the heads
-    attend the latent rows it selects. ``head_gate``: a sigmoid gate a
-    head on the heads' outputs ahead of ``wo``. Returns (attn_out,
-    cache)."""
-    from ray_tpu.ops.latent_attention import latent_attention
-    dt = c.dtype
-    b, n, e = h.shape
-    H, dn, dr, dv = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
-    r = c.kv_lora_rank
-    sin, cos = rot
-    hd = h.astype(dt)
-    with jax.named_scope("mla_q"):
-        cq = rms_norm(jnp.dot(hd, lp["wq_a"].astype(dt)), lp["q_a_norm"],
-                      eps=c.norm_eps)
-        q = jnp.dot(cq, lp["wq_b"].astype(dt)).reshape(b, n, H, dn + dr)
-        q_nope = q[..., :dn]
-        q_rope = apply_rotary(q[..., dn:], sin, cos, positions=positions,
-                              layout="neox")
-    with jax.named_scope("mla_latent"):
-        ckv = jnp.dot(hd, lp["wkv_a"].astype(dt))
-        lat = rms_norm(ckv[..., :r], lp["kv_a_norm"], eps=c.norm_eps)
-        k_rope = apply_rotary(ckv[..., None, r:], sin, cos,
-                              positions=positions, layout="neox")
-        width = cache["latent"].shape[-1]
-        row = jnp.concatenate(
-            [lat[:, :, None], k_rope,
-             jnp.zeros((b, n, 1, width - r - dr), dt)], axis=-1)
-    new, select = {"latent": row}, None
-    if c.index_topk:
-        qi, new["ki"], wi = _indexer(
-            c, h, lp, sin, cos, positions,
-            q_from=cq if c.index_q_lora else None)
-    cache = _write_rows(cache, new, layer, block_tables, positions,
-                        write_mask)
-    if 0 < c.index_topk < block_tables.shape[1] * cache["latent"].shape[3]:
-        # a window of no more than index_topk tokens selects every key:
-        # the dense path, bit for bit (a test holds it)
-        select = (qi, wi, cache["ki"], c.index_topk)
-    wkv_b = lp["wkv_b"].reshape(r, H, dn + dv)
-    att = latent_attention(
-        q_nope, q_rope, wkv_b[..., :dn], wkv_b[..., dn:], cache["latent"],
-        block_tables, positions, layer=layer, lens=lens,
-        sm_scale=(dn + dr) ** -0.5 * c.rope_softmax_scale,
-        impl=c.paged_impl, block_r=c.paged_row_block(n), select=select)
-    if c.head_gate:
-        att = _head_gate(c, hd, lp, att)
-    with jax.named_scope("mla_out"):
-        out = jnp.einsum("bshd,hde->bse", att,
-                         lp["wo"].reshape(H, dv, e).astype(dt))
-    return out, cache
-
-
-def _yarn_inv_freq(c: TransformerConfig, dim: int):
-    """(inverse frequencies, scale of sin and cos) of ``rope_yarn`` over
-    ``dim`` rotated numbers at ``rope_base``."""
-    factor, original, fast, slow, scale = c.rope_yarn
-    return yarn_inv_freq(dim, c.rope_base, factor, int(original), fast,
-                         slow), float(scale)
+def _rotary(rot: _Rotary, positions, table_len: int):
+    """(sin, cos) of a kind's rotary, in the form its program has: rows
+    at ``positions`` (``rot.at_positions``), or a table of ``table_len``
+    rows for :func:`apply_rotary` to gather at them."""
+    if not rot.at_positions:
+        return rotary_table(table_len, rot.dim, rot.base)
+    if rot.yarn:
+        factor, original, fast, slow, scale = rot.yarn
+        return rotary_at(positions, yarn_inv_freq(
+            rot.dim, rot.base, factor, int(original), fast, slow),
+            float(scale))
+    return rotary_at(positions, (1.0 / rot.base ** (
+        np.arange(0, rot.dim, 2, dtype=np.float64) / rot.dim)
+    ).astype(np.float32))
 
 
 def _head_gate(c, hd, lp, att):
@@ -1556,133 +1579,152 @@ def _head_gate(c, hd, lp, att):
         return att * gate[..., None].astype(att.dtype)
 
 
-def _kind_inv_freq(c: TransformerConfig, kind: str):
-    """(inverse frequencies, scale of sin and cos) of a kind's rotary:
-    numpy, from the configuration alone."""
-    def plain(dim, base):
-        return (1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64)
-                               / dim)).astype(np.float32)
-    if kind == "window":
-        return plain(c.window_rotary_dim or c.head_dim,
-                     c.window_rope_base), 1.0
-    if c.rope_yarn:
-        return _yarn_inv_freq(c, c.rotary_dim)
-    return plain(c.rotary_dim, c.rope_base), 1.0
+@jax.named_scope("attn")
+def _paged_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
+                         tables, first, positions, write_mask, lens):
+    """The attention sublayer over per-head K/V of a layer of ``kind``,
+    cache layer ``layer`` (an int32 scalar, traced by the layer scan) of
+    the kind's pools: project qkv for the new tokens (the kind's head
+    count), RMSNorm q and k a head (``qk_norm``), rotate at their absolute
+    positions, scatter k/v into that layer's pages of the WHOLE 5-D pools
+    through the kind's table, then attend against the (now-updated) pages
+    by ``(layer, block)``, a window layer's keys masked behind its window;
+    a sigmoid gate a head (``head_gate``) on the heads' outputs ahead of
+    ``wo``. The pools (``cache``, as :func:`init_kv_cache` made it) come
+    in and go out whole — the scan's carry — so the write is one in-place
+    scatter of the new rows, not a copy of the layer. ``lens`` is the
+    per-sequence live token count after this call's writes — the Pallas
+    kernel skips whole cache blocks past it. With an indexer the new
+    tokens' ``kI`` goes into the same pages, and attention reads the keys
+    the indexer selects. ``tables`` / ``first``: the kind's block table
+    and the absolute position of its entry 0 (None: position 0; a window
+    layer's short table starts behind the window); ``rot``: the (sin,
+    cos) pairs, the head's and the indexer's. Returns (attn_out, cache)."""
+    with jax.named_scope(kind.scope) if kind.scope \
+            else contextlib.nullcontext():
+        e = h.shape[-1]
+        dt = c.dtype
+        hd = h.astype(dt)
+        (sin, cos), (isin, icos) = rot
+
+        def proj(w, n):
+            return jnp.einsum("bse,ehd->bshd", hd,
+                              w.reshape(e, n, -1).astype(dt))
+        q = proj(lp["wq"], kind.heads)
+        k = proj(lp["wk"], c.kv_heads)
+        v = proj(lp["wv"], c.kv_heads)
+        if kind.qk_norm:
+            q = rms_norm(q, lp["q_norm"])
+            k = rms_norm(k, lp["k_norm"])
+        q = apply_rotary(q, sin, cos, positions=positions,
+                         layout=kind.rotary.layout)
+        k = apply_rotary(k, sin, cos, positions=positions,
+                         layout=kind.rotary.layout)
+        names = [pool.name for pool in kind.pools]
+        new = dict(zip(names, (k, v)))
+        if kind.index_topk:
+            qi, new["ki"], wi = _indexer(c, h, lp, isin, icos, positions)
+        if first is not None:
+            # positions as the kind's table counts them
+            positions = positions - first[:, None]
+
+        def live():                     # and the live rows
+            return lens if first is None else lens - first
+        pools = _write_rows({n: p for n, p in cache.items() if n in names},
+                            new, layer, tables, positions, write_mask)
+        keys, values = pools[names[0]], pools[names[1]]
+        if 0 < kind.index_topk < tables.shape[1] * keys.shape[3]:
+            from ray_tpu.ops.sparse_attention import sparse_paged_attention
+            att = sparse_paged_attention(
+                q, qi, wi, keys, values, pools["ki"], tables, positions,
+                live(), layer=layer, topk=kind.index_topk)
+        else:
+            # no indexer: every dense model's path. An indexer whose
+            # window holds no more than index_topk tokens lands here too
+            # (the selection is the identity): no cell runs that, a test
+            # holds it to the dense path bit for bit
+            with jax.named_scope("paged_attn"):
+                att = paged_attention(
+                    q, keys, values, tables, positions, layer=layer,
+                    lens=live(), impl=c.paged_impl,
+                    block_r=c.paged_row_block(h.shape[1]),
+                    window=kind.window)
+        if kind.head_gate:
+            att = _head_gate(c, hd, lp, att)
+        out = jnp.einsum(
+            "bshd,hde->bse", att,
+            lp["wo"].reshape(kind.heads, c.head_dim, e).astype(dt))
+        return out, {**cache, **pools}
 
 
-def _kind_attn_sublayer(c, kind, h, lp, rot, layer, cache, tables, first,
-                        positions, write_mask, lens):
-    """The attention sublayer of a layer of ``kind`` ("full" |
-    "window"), cache layer ``layer`` of that kind's pools: the kind's
-    head count and rotary, its K/V rows scattered into its own pools
-    through its own table, a window layer's keys masked behind
-    ``sliding_window``, a sigmoid gate a head (``head_gate``) on the
-    heads' outputs ahead of ``wo``. ``tables`` / ``first``: the kind's
-    block table and the absolute position of its entry 0 (a window
-    layer's short table starts behind the window, not at position 0);
-    ``rot``: (sin, cos) at ``positions``. Returns (attn_out, cache)."""
-    e = h.shape[-1]
+@jax.named_scope("attn")
+def _latent_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
+                          block_tables, first, positions, write_mask,
+                          lens):
+    """The latent (MLA) attention sublayer of cache layer ``layer``:
+    queries through the ``q_lora_rank`` bottleneck, one latent row a
+    new token written to the pool (normed latent | rotated shared key),
+    then every head attends the pool's rows themselves
+    (``ops/latent_attention.py``): ``wkv_b``'s key half goes into the
+    query and its value half comes after the softmax, so no per-head K
+    or V of the context exists. With an indexer (``index_topk``) the new
+    tokens' index keys go into the same pages' ``ki`` and the heads
+    attend the latent rows it selects. ``head_gate``: a sigmoid gate a
+    head on the heads' outputs ahead of ``wo``. The arguments are
+    :func:`_paged_attn_sublayer`'s; latent rows are read through the main
+    table (``first`` None). Returns (attn_out, cache)."""
+    from ray_tpu.ops.latent_attention import latent_attention
+    assert first is None
     dt = c.dtype
-    H = c.kind_heads(kind)
+    b, n, e = h.shape
+    H, dn, dr, dv = kind.heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim
+    r = c.kv_lora_rank
+    (sin, cos), (isin, icos) = rot
     hd = h.astype(dt)
-
-    def proj(w, n):
-        return jnp.einsum("bse,ehd->bshd", hd,
-                          w.reshape(e, n, -1).astype(dt))
-    q = apply_rotary(proj(lp["wq"], H), *rot, layout="neox")
-    k = apply_rotary(proj(lp["wk"], c.kv_heads), *rot, layout="neox")
-    v = proj(lp["wv"], c.kv_heads)
-    names = WINDOW_POOLS if kind == "window" else ("k", "v")
-    # positions as the kind's table counts them
-    rel = positions - first[:, None]
-    pools = _write_rows({n: cache[n] for n in names},
-                        dict(zip(names, (k, v))), layer, tables, rel,
+    with jax.named_scope("mla_q"):
+        cq = rms_norm(jnp.dot(hd, lp["wq_a"].astype(dt)), lp["q_a_norm"],
+                      eps=c.norm_eps)
+        q = jnp.dot(cq, lp["wq_b"].astype(dt)).reshape(b, n, H, dn + dr)
+        q_nope = q[..., :dn]
+        q_rope = apply_rotary(q[..., dn:], sin, cos, positions=positions,
+                              layout=kind.rotary.layout)
+    with jax.named_scope("mla_latent"):
+        ckv = jnp.dot(hd, lp["wkv_a"].astype(dt))
+        lat = rms_norm(ckv[..., :r], lp["kv_a_norm"], eps=c.norm_eps)
+        k_rope = apply_rotary(ckv[..., None, r:], sin, cos,
+                              positions=positions,
+                              layout=kind.rotary.layout)
+        width = cache["latent"].shape[-1]
+        row = jnp.concatenate(
+            [lat[:, :, None], k_rope,
+             jnp.zeros((b, n, 1, width - r - dr), dt)], axis=-1)
+    new, select = {"latent": row}, None
+    if kind.index_topk:
+        qi, new["ki"], wi = _indexer(
+            c, h, lp, isin, icos, positions,
+            q_from=cq if c.index_q_lora else None)
+    cache = _write_rows(cache, new, layer, block_tables, positions,
                         write_mask)
-    cache = {**cache, **pools}
-    with jax.named_scope("paged_attn"):
-        att = paged_attention(
-            q, pools[names[0]], pools[names[1]], tables, rel, layer=layer,
-            lens=lens - first, impl=c.paged_impl,
-            block_r=c.paged_row_block(h.shape[1]),
-            window=c.sliding_window if kind == "window" else 0)
-    if c.head_gate:
+    if 0 < kind.index_topk \
+            < block_tables.shape[1] * cache["latent"].shape[3]:
+        # a window of no more than index_topk tokens selects every key:
+        # the dense path, bit for bit (a test holds it)
+        select = (qi, wi, cache["ki"], kind.index_topk)
+    wkv_b = lp["wkv_b"].reshape(r, H, dn + dv)
+    att = latent_attention(
+        q_nope, q_rope, wkv_b[..., :dn], wkv_b[..., dn:], cache["latent"],
+        block_tables, positions, layer=layer, lens=lens,
+        sm_scale=(dn + dr) ** -0.5 * c.rope_softmax_scale,
+        impl=c.paged_impl, block_r=c.paged_row_block(n), select=select)
+    if kind.head_gate:
         att = _head_gate(c, hd, lp, att)
-    out = jnp.einsum("bshd,hde->bse", att,
-                     lp["wo"].reshape(H, c.head_dim, e).astype(dt))
+    with jax.named_scope("mla_out"):
+        out = jnp.einsum("bshd,hde->bse", att,
+                         lp["wo"].reshape(H, dv, e).astype(dt))
     return out, cache
 
 
-def _kind_runs(c: TransformerConfig):
-    """The stack as runs of consecutive layers that share a stack of the
-    tree: (stack name, kind, dense, first index in the stack, layers,
-    first cache layer of the kind)."""
-    runs, seen, ordinal = [], {}, {"full": 0, "window": 0}
-    for l in range(c.n_layers):
-        kind, dense = c.layer_kind(l), l < c.n_dense_layers
-        name = "dense_layers" if dense else KIND_STACKS[kind]
-        at = seen.get(name, 0)
-        if runs and runs[-1][0] == name:
-            runs[-1][4] += 1
-        else:
-            runs.append([name, kind, dense, at, 1, ordinal[kind]])
-        seen[name] = at + 1
-        ordinal[kind] += 1
-    return [tuple(r) for r in runs]
-
-
-def _forward_kinds(c, params, ids, cache, block_tables, positions,
-                   write_mask, lens, window_tables, window_first):
-    """The trunk of a stack by kind of layer: each run of layers of one
-    kind is a scan of its own over that kind's stacked leaves (a run
-    that is a whole stack, as at this repo's depths, reads it in place;
-    a shorter one a static slice of it), all of them carrying ``(x,
-    cache)`` with every pool whole. With no window table given the
-    window layers read ``block_tables`` through the window mask."""
-    from ray_tpu.models.moe import EXPERT_LEAVES
-    if window_tables is None:
-        window_tables = block_tables
-        window_first = jnp.zeros(block_tables.shape[:1], jnp.int32)
-    zero = jnp.zeros_like(window_first)
-    tables = {"full": (block_tables, zero),
-              "window": (window_tables, window_first)}
-    rot = {kind: rotary_at(positions, *_kind_inv_freq(c, kind))
-           for kind in tables}
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
-    carry = (x, dict(cache))
-    for name, kind, dense, at, n, cache_layer in _kind_runs(c):
-        stack = params[name]
-        whole = {} if dense or not c.experts_per_token else \
-            {k: stack[k] for k in EXPERT_LEAVES}
-        scanned = {k: v if (at, n) == (0, v.shape[0]) else v[at:at + n]
-                   for k, v in stack.items() if k not in whole}
-
-        def step(carry, per_layer, kind=kind, dense=dense, whole=whole):
-            x, cache = carry
-            lp, layer, place = per_layer
-            with jax.named_scope("layer"):
-                h = rms_norm(x, lp["attn_norm"], eps=c.norm_eps)
-                with jax.named_scope("attn"), jax.named_scope(kind):
-                    att, cache = _kind_attn_sublayer(
-                        c, kind, h, lp, rot[kind], layer, cache,
-                        *tables[kind], positions, write_mask, lens)
-                x = x + att.astype(x.dtype)
-                h2 = rms_norm(x, lp["mlp_norm"],
-                              eps=c.norm_eps).astype(c.dtype)
-                if dense or not c.experts_per_token:
-                    with jax.named_scope("mlp"):
-                        mlp = _swiglu(c, h2, lp)
-                else:
-                    mlp, _ = _mlp_sublayer(c, h2, {**lp, **whole}, place)
-                return (x + mlp.astype(x.dtype), cache), None
-
-        carry, _ = jax.lax.scan(step, carry, (
-            scanned,
-            jnp.arange(cache_layer, cache_layer + n, dtype=jnp.int32),
-            jnp.arange(at, at + n, dtype=jnp.int32)))
-    x, cache = carry
-    x = _final_norm(c, params, x)
-    return _lm_head(c, params, x), cache
+_MIXERS = {"paged": _paged_attn_sublayer, "latent": _latent_attn_sublayer}
 
 
 def _forward_with_cache(c: TransformerConfig, params: Dict,
@@ -1699,121 +1741,69 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     (B,) is each sequence's live token count including this call's
     writes — the attention kernel's length-skipping bound.
 
-    The scan runs over ``(layers, layer index)`` and carries ``(x,
-    cache)``: the pools are never among the scanned inputs or outputs
-    (those are sliced per layer and stacked into a new buffer — a copy
-    of the whole pool every step)."""
+    Each run of the plan (consecutive layers of one kind in one stack of
+    the tree: a leading dense layer, the expert layers behind it, a
+    stretch of window layers) is one scan over ``(layers, layer index)``
+    that carries ``(x, cache)`` with every pool whole: the pools are
+    never among the scanned inputs or outputs (those are sliced per layer
+    and stacked into a new buffer — a copy of the whole pool every
+    step). Each kind of layer is compiled once a run. A run that is a
+    whole stack, as at this repo's depths, reads it in place; a shorter
+    one a static slice of it. With no window table given the window
+    layers read ``block_tables`` through the window mask."""
+    from ray_tpu.models.moe import EXPERT_LEAVES
     if c.n_experts and not c.experts_per_token:
         raise NotImplementedError(
             "paged decode serves dropless top-k experts "
             "(experts_per_token > 0); Switch top-1 with capacity drops "
             "tokens by the batch they arrive in")
-    _check_served_forms(c)
-    if c.by_kind:
-        return _forward_kinds(c, params, ids, cache, block_tables,
-                              positions, write_mask, lens, window_tables,
-                              window_first)
+    plan = _layer_plan(c)
     bs = next(iter(cache.values())).shape[3]
-    window = block_tables.shape[1] * bs
-    if c.kv_lora_rank and c.rope_yarn:
-        rot = rotary_at(positions, *_yarn_inv_freq(c, c.qk_rope_dim))
-    elif c.kv_lora_rank:
-        rot = rotary_table(window, c.qk_rope_dim, c.rope_base)
-    else:
-        rot = rotary_table(
-            window,
-            c.rotary_dim if c.block_style == "gptj" else c.head_dim,
-            c.rope_base)
-        rot += rotary_table(window, c.index_dim, c.rope_base) \
-            if c.index_topk else (None, None)
-    layout = "gptj" if c.block_style == "gptj" else "neox"
+    table_len = block_tables.shape[1] * bs
+    # a kind's block table and the absolute position of its entry 0
+    tables = {"main": (block_tables, None),
+              "window": (block_tables, None) if window_tables is None
+              else (window_tables, window_first)}
+    rot = {}
+    for kind in plan.kinds:
+        own = _rotary(kind.rotary, positions, table_len)
+        rot[kind] = (own, own if kind.index_rotary is None else
+                     _rotary(kind.index_rotary, positions, table_len))
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
-    # the dropless experts stay out of the scanned leaves: the grouped
-    # product reads layer ``layer`` of the whole stack in place
-    scanned, whole = params["layers"], {}
-    if c.experts_per_token:
-        from ray_tpu.models.moe import EXPERT_LEAVES
-        whole = {k: scanned[k] for k in EXPERT_LEAVES}
-        scanned = {k: v for k, v in scanned.items() if k not in whole}
-
-    def gptj_step(x, lp, layer, cache):
-        h = layer_norm(x, lp["ln_scale"], lp["ln_bias"])
-        att, cache = _paged_attn_sublayer(
-            c, h, lp, rot, layout, layer, cache,
-            block_tables, positions, write_mask, lens)
-        mlp, _ = _mlp_sublayer(c, h, lp)
-        return x + (att + mlp).astype(x.dtype), cache
-
-    def llama_step(x, lp, layer, cache):
-        h = rms_norm(x, lp["attn_norm"])
-        att, cache = _paged_attn_sublayer(
-            c, h, lp, rot, layout, layer, cache,
-            block_tables, positions, write_mask, lens)
-        x = x + att.astype(x.dtype)
-        h2 = rms_norm(x, lp["mlp_norm"]).astype(c.dtype)
-        mlp, _ = _mlp_sublayer(c, h2, {**lp, **whole},
-                               layer if whole else None)
-        return x + mlp.astype(x.dtype), cache
-
-    def latent_step(x, lp, layer, cache):
-        """The latent model's layer (cache layer ``layer``): a leading
-        dense one (``w_gate`` among its leaves) or an expert layer,
-        whose place in the expert stack is ``layer - n_dense_layers``.
-        With ``sandwich_norm`` each sublayer's output passes a second
-        RMSNorm ahead of the residual add; with ``gated_norm_rank`` the
-        norm ahead of each sublayer is gated."""
-        eps = c.norm_eps
-
-        def pre(x, name):
-            if not c.gated_norm_rank:
-                return rms_norm(x, lp[f"{name}_norm"], eps=eps)
-            return gated_rms_norm(x, lp[f"{name}_norm"],
-                                  lp[f"{name}_gn_down"],
-                                  lp[f"{name}_gn_up"], eps=eps)
-
-        def post(y, name):
-            if not c.sandwich_norm:
-                return y
-            with jax.named_scope("post_norm"):
-                return rms_norm(y, lp[name], eps=eps)
-        h = pre(x, "attn")
-        att, cache = _latent_attn_sublayer(
-            c, h, lp, rot, layer, cache, block_tables, positions,
-            write_mask, lens)
-        x = x + post(att, "post_attn_norm").astype(x.dtype)
-        h2 = pre(x, "mlp").astype(c.dtype)
-        if "w_gate" in lp:
-            with jax.named_scope("mlp"):
-                mlp = _swiglu(c, h2, lp)
-        else:
-            mlp, _ = _mlp_sublayer(c, h2, {**lp, **whole},
-                                   layer - c.n_dense_layers)
-        return x + post(mlp, "post_mlp_norm").astype(x.dtype), cache
-
-    step = gptj_step if c.block_style == "gptj" else \
-        latent_step if c.kv_lora_rank else llama_step
-
-    def scan_fn(carry, per_layer):
-        x, cache = carry
-        lp, layer = per_layer
-        with jax.named_scope("layer"):
-            return step(x, lp, layer, cache), None
-
-    n_layers = next(iter(cache.values())).shape[0]
     carry = (x, dict(cache))
-    if c.n_dense_layers:
-        # the leading dense layers, a scan of their own ahead of the
-        # expert layers': each kind of layer is compiled once, and the
-        # pools are the carry of both
-        carry, _ = jax.lax.scan(
-            scan_fn, carry,
-            (params["dense_layers"],
-             jnp.arange(c.n_dense_layers, dtype=jnp.int32)))
-    (x, cache), _ = jax.lax.scan(
-        scan_fn, carry,
-        (scanned, jnp.arange(c.n_dense_layers, n_layers, dtype=jnp.int32)))
+    for run in plan.runs:
+        stack = params[run.stack]
+        # the dropless experts stay out of the scanned leaves: the grouped
+        # product reads layer ``place`` of the whole stack in place
+        whole = {k: stack[k] for k in EXPERT_LEAVES} if run.experts else {}
+        scanned = {k: v if (run.at, run.n) == (0, v.shape[0])
+                   else v[run.at:run.at + run.n]
+                   for k, v in stack.items() if k not in whole}
 
+        def step(carry, per_layer, run=run, whole=whole):
+            x, cache = carry
+            lp, layer = per_layer
+            kind, behind = run.kind, run.cache_layer - run.at
+
+            def attend(h):
+                return _MIXERS[kind.mixer](
+                    c, kind, h, lp, rot[kind], layer, cache,
+                    *tables[kind.table], positions, write_mask, lens)
+
+            def mlp(h):
+                # the layer's place in its stack: behind its place in
+                # the kind's pools by the layers of that kind in the
+                # stacks ahead
+                return _mlp_sublayer(c, h, {**lp, **whole},
+                                     layer - behind if behind else layer)
+            with jax.named_scope("layer"):
+                x, cache, _ = _block(c, kind, x, lp, attend, mlp)
+            return (x, cache), None
+
+        carry, _ = jax.lax.scan(step, carry, (scanned, jnp.arange(
+            run.cache_layer, run.cache_layer + run.n, dtype=jnp.int32)))
+    x, cache = carry
     x = _final_norm(c, params, x)
     return _lm_head(c, params, x), cache
 
