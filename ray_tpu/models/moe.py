@@ -150,6 +150,14 @@ def route_topk(c, lp, x):
 #: the dropless layer's stacked expert leaves
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 
+#: XLA:TPU does not compile a grouped product of fewer rows than this
+#: against a count of groups over 128 that is no multiple of 512 (an
+#: internal error: it bitcasts the group sizes between two tilings that
+#: pad such a count differently; 9 layers of 36 held experts, a batch of
+#: one or two tokens at ten experts each): such a call's rows are padded
+#: up to it (tests/ops/test_tpu_lowering.py compiles the case)
+_FEW_ROWS = 32
+
 
 @jax.named_scope("moe_shared")
 def _shared_expert(c, lp, x):
@@ -203,6 +211,9 @@ def topk_moe_mlp(c, lp, h, layer=None):
     if here is not None:
         flat = jnp.where(here, flat, groups)   # out of bounds: dropped
     sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
+    if N * k < _FEW_ROWS and groups > 128 and groups % 512:
+        # rows of no group behind the others (see _FEW_ROWS)
+        xs = jnp.pad(xs, ((0, _FEW_ROWS - N * k), (0, 0)))
     gate = jax.lax.ragged_dot(xs, w_gate, sizes,
                               preferred_element_type=jnp.float32)
     up = jax.lax.ragged_dot(xs, w_up, sizes,
@@ -213,7 +224,7 @@ def topk_moe_mlp(c, lp, h, layer=None):
     # unsort: row i of ys is assignment order[i]
     inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
         jnp.arange(N * k, dtype=jnp.int32))
-    ys = jnp.take(ys, inverse, axis=0).reshape(N, k, D)
+    ys = jnp.take(ys, inverse, axis=0).reshape(N, k, D)   # and unpad
     if here is not None:
         # a row of no group is whatever the product left there: chosen
         # away, not multiplied by a zero weight

@@ -202,6 +202,29 @@ class TransformerConfig:
     window_rope_base: float = 10000.0
     rope_yarn: Tuple[float, ...] = ()
     head_gate: bool = False
+    # A "mamba" layer in layer_pattern (served): a Mamba-2 mixer
+    # (ops/ssm.py) in the attention's place, ssm_heads heads of
+    # ssm_head_dim over a state of ssm_state numbers a head and channel,
+    # one group of B and C, a depthwise causal convolution of ssm_conv
+    # taps ahead of it, scanned in blocks of ssm_chunk. Such a layer
+    # holds no page: a sequence's recurrent state and the convolution's
+    # last inputs live in per-slot arrays beside the pools
+    # (init_kv_cache). rotary_dim 0: a "full" layer rotates nothing
+    # (NoPE). attn_scale: the softmax scale (0 = head_dim^-1/2).
+    # embed_scale multiplies the embedding, residual_scale each
+    # sublayer's output ahead of its residual, logit_scale the logits;
+    # tie_embeddings: the head is the embedding, transposed (the tree
+    # has no "lm_head").
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    attn_scale: float = 0.0
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         # plain JSON hands lists over: the config stays hashable
@@ -220,13 +243,25 @@ class TransformerConfig:
     def d_expert(self) -> int:
         return self.expert_width or self.d_ff
 
+    @property
+    def ssm_inner(self) -> int:
+        """Channels the scan runs over (the published d_model x expand)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels through the convolution: x and the group's B and C."""
+        return self.ssm_inner + 2 * self.ssm_state
+
     #: the keys of the forms only ``prefill`` / ``decode_step`` implement
     SERVED_KEYS = ("experts_per_token", "qk_norm", "index_topk",
                    "kv_lora_rank", "sandwich_norm", "n_dense_layers",
                    "layer_pattern", "window_heads", "sliding_window",
                    "rope_yarn", "head_gate", "index_q_lora",
                    "rope_softmax_scale", "gated_norm_rank", "n_group",
-                   "topk_group", "router_bias")
+                   "topk_group", "router_bias", "ssm_heads",
+                   "ssm_head_dim", "ssm_state", "attn_scale", "embed_scale",
+                   "residual_scale", "logit_scale", "tie_embeddings")
 
     @property
     def served_keys(self) -> Tuple[str, ...]:
@@ -270,11 +305,18 @@ class TransformerConfig:
         e, v, h = self.d_model, self.vocab_size, self.n_heads * self.head_dim
         kvh = self.kv_heads * self.head_dim
         if _tree_form(self) == "kinds":
-            total = 2 * v * e + e                 # embed, head, final norm
+            # embed, head (the embedding again where tied), final norm
+            total = (1 if self.tie_embeddings else 2) * v * e + e
             for l in range(self.n_layers):
-                hk = self.kind_heads(self.layer_kind(l))
-                total += 2 * e * hk * self.head_dim + 2 * e * kvh + 2 * e \
-                    + (e * hk if self.head_gate else 0)
+                if self.layer_kind(l) == "mamba":
+                    di, cw = self.ssm_inner, self.ssm_conv_width
+                    total += e * (di + cw + self.ssm_heads) + di * e \
+                        + cw * (self.ssm_conv + 1) + 3 * self.ssm_heads \
+                        + di + 2 * e
+                else:
+                    hk = self.kind_heads(self.layer_kind(l))
+                    total += 2 * e * hk * self.head_dim + 2 * e * kvh \
+                        + 2 * e + (e * hk if self.head_gate else 0)
                 if l < self.n_dense_layers or not self.experts_per_token:
                     total += 3 * e * self.d_ff
                 else:
@@ -528,10 +570,24 @@ def _check_served_forms(c: TransformerConfig) -> None:
 
 #: where the tree keeps each kind's layers (all of them, or with
 #: ``n_dense_layers`` those behind ``dense_layers``)
-KIND_STACKS = {"full": "layers", "window": "window_layers"}
+KIND_STACKS = {"full": "layers", "window": "window_layers",
+               "mamba": "mamba_layers"}
 
 #: a window layer's pools, beside the full layers' "k" / "v"
 WINDOW_POOLS = ("k_window", "v_window")
+
+#: what the cache holds beside its pools: the "mamba" layers' recurrent
+#: state and their convolution's last inputs, ``[layers, slots, ...]``, a
+#: row a decode slot and no page (:func:`init_kv_cache`)
+STATE_ARRAYS = ("ssm", "conv")
+
+
+def cache_pools(cache: Dict[str, Any]) -> Dict[str, Any]:
+    """The paged pools of a cache (blocks on axis 1), without the
+    per-slot state arrays a model with "mamba" layers keeps beside them:
+    what copies, ships, adopts or sizes a PAGE goes over these."""
+    return {name: a for name, a in cache.items()
+            if name not in STATE_ARRAYS}
 
 
 # ------------------------------------------------------ the layer plan
@@ -564,16 +620,25 @@ class _Pool(NamedTuple):
     width: int
 
 
+class _State(NamedTuple):
+    """One of the cache's per-slot arrays: ``[layers, slots, *shape]``
+    under ``name``; ``dtype`` None is the compute dtype."""
+    name: str
+    shape: Tuple[int, ...]
+    dtype: Any
+
+
 @dataclasses.dataclass(frozen=True)
 class _LayerKind:
     """One kind of layer, as the forward pass needs to know it."""
-    name: str                    # "full" | "window"
+    name: str                    # "full" | "window" | "mamba"
     norm: str                    # "layer" (with bias) | "rms" | "gated"
     # attention and MLP read the one normed input and are added together
     # (the 'gptj' form) | the MLP follows the attention's residual
     parallel: bool
     post_norm: bool              # a second RMSNorm on each sublayer's output
-    mixer: str                   # "paged": per-head K/V | "latent": MLA rows
+    # "paged": per-head K/V | "latent": MLA rows | "scan": a recurrence
+    mixer: str
     heads: int                   # query heads
     window: int                  # keys attended behind a position, 0 = all
     qk_norm: bool
@@ -583,10 +648,13 @@ class _LayerKind:
     # selects) and the table it reads them through: "main" (entry 0 is
     # position 0) | "window" (a short table that starts behind the window)
     pools: Tuple[_Pool, ...]
+    # ... | "state" (no table: the slot of each row, for ``state``)
     table: str
     scope: Optional[str]         # the named scope below ``layer/attn``
-    rotary: _Rotary
+    rotary: _Rotary              # ``dim`` 0: nothing is rotated
     index_rotary: Optional[_Rotary]      # None: the layer's own
+    # what a "scan" layer carries a sequence, a row a slot
+    state: Tuple[_State, ...] = ()
 
 
 class _Run(NamedTuple):
@@ -629,9 +697,24 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                    or c.window_heads):
         raise ValueError("layer_pattern, sliding_window and window_heads "
                          "are forms of per-head K/V, not of a latent cache")
-    if not pattern <= {"full", "window"}:
+    if not pattern <= {"full", "window", "mamba"}:
         raise ValueError(f"layer_pattern {c.layer_pattern}: a layer "
-                         f"is 'full' or 'window'")
+                         f"is 'full', 'window' or 'mamba'")
+    if ("mamba" in pattern) != bool(c.ssm_heads) or c.ssm_heads and not (
+            c.ssm_head_dim and c.ssm_state and c.ssm_conv > 1
+            and c.ssm_chunk > 0):
+        raise ValueError("'mamba' layers in layer_pattern come with "
+                         "ssm_heads, ssm_head_dim, ssm_state, ssm_conv > 1 "
+                         f"and ssm_chunk, got {c.layer_pattern} and "
+                         f"{c.ssm_heads}, {c.ssm_head_dim}, {c.ssm_state}, "
+                         f"{c.ssm_conv}, {c.ssm_chunk}")
+    by_kind_only = [k for k in ("attn_scale", "embed_scale",
+                                "residual_scale", "logit_scale",
+                                "tie_embeddings") if k in c.served_keys]
+    if by_kind_only and not by_kind:
+        raise ValueError(f"{', '.join(by_kind_only)}: forms of a stack by "
+                         "kind of layer (layer_pattern, n_dense_layers, "
+                         "head_gate, rope_yarn)")
     if ("window" in pattern) != bool(c.sliding_window):
         raise ValueError("'window' layers in layer_pattern and "
                          "sliding_window > 0 come together, got "
@@ -648,14 +731,31 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                 c.experts_per_token and c.n_dense_layers < c.n_layers):
             raise ValueError("n_dense_layers lead layers of dropless "
                              "experts (experts_per_token > 0)")
-        if c.experts_held:
-            raise ValueError("a stack by kind of layer holds every expert")
-
     gptj = c.block_style == "gptj"
     page = (c.kv_heads, c.head_dim)
 
     def kind(name: str) -> _LayerKind:
         window = name == "window"
+        common = dict(
+            name=name, norm="layer" if gptj else "gated"
+            if c.gated_norm_rank else "rms", parallel=gptj,
+            post_norm=c.sandwich_norm, qk_norm=c.qk_norm,
+            head_gate=c.head_gate, index_topk=c.index_topk)
+        if name == "mamba":
+            # no page: the state a head (float32: the recurrence adds into
+            # it at every token) and the convolution's last inputs, a row
+            # a slot
+            return _LayerKind(
+                **{**common, "head_gate": False}, mixer="scan",
+                heads=c.ssm_heads, window=0, pools=(), table="state",
+                scope=None, rotary=_Rotary("neox", 0, c.rope_base, (), True),
+                index_rotary=None, state=(
+                    # heads and their channels as ONE axis: a program
+                    # that could order them either way would relay the
+                    # whole array to its own order on the way in and out
+                    _State("ssm", (c.ssm_inner, c.ssm_state), jnp.float32),
+                    _State("conv", (c.ssm_conv - 1, c.ssm_conv_width),
+                           None)))
         if latent:
             # ONE pool, a row a token and layer for every head (the
             # normed latent | the rotated shared key | zeros up to whole
@@ -680,15 +780,9 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
         if c.index_topk:
             pools += (_Pool("ki", 1, c.index_dim),)
         return _LayerKind(
-            name=name,
-            norm="layer" if gptj else "gated" if c.gated_norm_rank
-            else "rms",
-            parallel=gptj, post_norm=c.sandwich_norm,
-            mixer="latent" if latent else "paged",
+            **common, mixer="latent" if latent else "paged",
             heads=c.kind_heads(name),
-            window=c.sliding_window if window else 0,
-            qk_norm=c.qk_norm, head_gate=c.head_gate,
-            index_topk=c.index_topk, pools=pools,
+            window=c.sliding_window if window else 0, pools=pools,
             table="window" if window else "main",
             scope=name if by_kind else None, rotary=rotary,
             # over per-head K/V the indexer rotates all of index_dim,
@@ -700,7 +794,8 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
     # a stack with window layers has both kinds of pool, even at a depth
     # that holds no layer of one of them
     kinds = {name: kind(name) for name in
-             ("full",) + (("window",) if c.sliding_window else ())}
+             ("full",) + (("window",) if c.sliding_window else ())
+             + (("mamba",) if c.ssm_heads else ())}
     runs, seen, ordinal = [], {}, dict.fromkeys(kinds, 0)
     for l in range(c.n_layers):
         k, lead = kinds[c.layer_kind(l)], l < c.n_dense_layers
@@ -725,12 +820,20 @@ def _kind_layer_shapes(c: TransformerConfig, kind: str, dense: bool
                                     topk_moe_param_shapes)
     e, h = c.d_model, c.kind_heads(kind) * c.head_dim
     kvh = c.kv_heads * c.head_dim
-    out = {"wq": ((e, h), ("embed", "heads")),
-           "wk": ((e, kvh), ("embed", "kv")),
-           "wv": ((e, kvh), ("embed", "kv")),
-           "wo": ((h, e), ("heads", "embed"))}
-    if c.head_gate:
-        out["wg"] = ((e, c.kind_heads(kind)), ("embed", None))
+    if kind == "mamba":
+        # w_in's columns as published: gate z | x, B, C (through the
+        # convolution) | dt
+        di, cw = c.ssm_inner, c.ssm_conv_width
+        out = {"w_in": ((e, di + cw + c.ssm_heads), ("embed", "mlp")),
+               "conv_w": ((cw, c.ssm_conv), ("mlp", None)),
+               "w_out": ((di, e), ("mlp", "embed"))}
+    else:
+        out = {"wq": ((e, h), ("embed", "heads")),
+               "wk": ((e, kvh), ("embed", "kv")),
+               "wv": ((e, kvh), ("embed", "kv")),
+               "wo": ((h, e), ("heads", "embed"))}
+        if c.head_gate:
+            out["wg"] = ((e, c.kind_heads(kind)), ("embed", None))
     if dense or not c.experts_per_token:
         out.update({"w_gate": ((e, c.d_ff), ("embed", "mlp")),
                     "w_up": ((e, c.d_ff), ("embed", "mlp")),
@@ -757,42 +860,82 @@ def _kind_stacks(c: TransformerConfig):
     return out
 
 
+#: the convolution's taps are drawn at this and not at 0.02: their fan-in
+#: is ``ssm_conv``, not d_model
+_CONV_TAP_SD = 0.3
+
+
+def _mamba_vector_init(c, key, n) -> Dict[str, jnp.ndarray]:
+    """The float32 and bias leaves of ``n`` "mamba" layers, as Mamba-2
+    starts them: ``A = -exp(A_log)`` uniform in -16..-1, ``dt_bias`` the
+    inverse softplus of a step log-uniform in 1e-3..1e-1, ``D`` and the
+    gated norm at one; the convolution's bias drawn (at zero a program
+    that left it out could not be told apart)."""
+    ka, kd, kb = jax.random.split(key, 3)
+    step = jnp.exp(jax.random.uniform(
+        kd, (n, c.ssm_heads), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return {
+        "A_log": jnp.log(jax.random.uniform(ka, (n, c.ssm_heads),
+                                            jnp.float32, 1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "D": jnp.ones((n, c.ssm_heads), jnp.float32),
+        "ssm_norm": jnp.ones((n, c.ssm_inner), jnp.float32),
+        "conv_b": 0.02 * jax.random.normal(kb, (n, c.ssm_conv_width),
+                                           jnp.float32)}
+
+
 def _init_kind_params(c, key, dtype, out_scale) -> Dict:
     """The tree of a stack by kind of layer: ``dense_layers`` (the
-    leading ones), ``layers`` (the "full" layers behind them) and
-    ``window_layers``, each stacked, every matmul leaf drawn a layer at
-    a time into ``dtype``."""
-    keys = jax.random.split(jax.random.fold_in(key, 104), 5)
+    leading ones), ``layers`` (the "full" layers behind them),
+    ``window_layers`` and ``mamba_layers``, each stacked, every matmul
+    leaf drawn a layer at a time into ``dtype``. ``tie_embeddings``: no
+    ``lm_head``."""
+    keys = list(jax.random.split(jax.random.fold_in(key, 104), 5))
+    # a fourth stack draws from a key of its own: the five above stay
+    # what they were
+    keys.append(jax.random.fold_in(key, 105))
     params = {
         "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
                              dtype=dtype),
         "final_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
-        "lm_head": {"w": _dense_init(keys[1], (c.d_model, c.vocab_size),
-                                     dtype=dtype)},
     }
+    if not c.tie_embeddings:
+        params["lm_head"] = {"w": _dense_init(
+            keys[1], (c.d_model, c.vocab_size), dtype=dtype)}
+    scales = dict.fromkeys(("wo", "w_down", "we_down", "ws_down", "w_out"),
+                           out_scale)
+    scales["conv_w"] = _CONV_TAP_SD
     for j, (name, kind, dense, n) in enumerate(_kind_stacks(c)):
         stack: Dict[str, jnp.ndarray] = {}
         for i, (leaf, (shape, _)) in enumerate(
                 sorted(_kind_layer_shapes(c, kind, dense).items())):
             stack[leaf] = _layered_init(
                 jax.random.fold_in(keys[2 + j], i),
-                out_scale if leaf in ("wo", "w_down", "we_down", "ws_down")
-                else 0.02, n, shape, dtype)
+                scales.get(leaf, 0.02), n, shape, dtype)
         stack.update({norm: jnp.ones((n, c.d_model), jnp.float32)
                       for norm in ("attn_norm", "mlp_norm")})
+        if kind == "mamba":
+            stack.update(_mamba_vector_init(
+                c, jax.random.fold_in(keys[2 + j], 1000), n))
         params[name] = stack
     return params
 
 
 def _kind_logical_axes(c) -> Dict:
     axes = {"embed": ("vocab", "embed"),
-            "final_norm": {"scale": ("embed",)},
-            "lm_head": {"w": ("embed", "vocab")}}
+            "final_norm": {"scale": ("embed",)}}
+    if not c.tie_embeddings:
+        axes["lm_head"] = {"w": ("embed", "vocab")}
     for name, kind, dense, _ in _kind_stacks(c):
         axes[name] = {leaf: ("layers",) + ax for leaf, (_, ax)
                       in _kind_layer_shapes(c, kind, dense).items()}
         axes[name].update({"attn_norm": ("layers", "embed"),
                            "mlp_norm": ("layers", "embed")})
+        if kind == "mamba":
+            axes[name].update({
+                "A_log": ("layers", None), "dt_bias": ("layers", None),
+                "D": ("layers", None), "ssm_norm": ("layers", "mlp"),
+                "conv_b": ("layers", "mlp")})
     return axes
 
 
@@ -967,7 +1110,8 @@ def logical_axes(config: TransformerConfig) -> Dict:
 _F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias",
                          "q_norm", "k_norm", "k_idx_scale", "k_idx_bias",
                          "q_a_norm", "kv_a_norm", "post_attn_norm",
-                         "post_mlp_norm", "router_bias"))
+                         "post_mlp_norm", "router_bias", "A_log", "dt_bias",
+                         "D", "ssm_norm"))
 
 
 def inference_params(config: TransformerConfig, params: Dict) -> Dict:
@@ -1146,14 +1290,16 @@ def _block(c, kind: _LayerKind, x, lp, attend, mlp):
             return rms_norm(y, lp[name], eps=eps)
     # a name for the remat policies ("offload"); no op of the program
     x = checkpoint_name(x, "block_in")
+    def scaled(y):               # ``residual_scale`` ahead of the residual
+        return y if c.residual_scale == 1.0 else y * c.residual_scale
     h = pre(x, "attn")
     att, cache = attend(h)
     if kind.parallel:
         out, aux = mlp(h)
-        return x + (att + out).astype(x.dtype), cache, aux
-    x = x + post(att, "post_attn_norm").astype(x.dtype)
+        return x + scaled(att + out).astype(x.dtype), cache, aux
+    x = x + scaled(post(att, "post_attn_norm")).astype(x.dtype)
     out, aux = mlp(pre(x, "mlp").astype(c.dtype))
-    return x + post(out, "post_mlp_norm").astype(x.dtype), cache, aux
+    return x + scaled(post(out, "post_mlp_norm")).astype(x.dtype), cache, aux
 
 
 def refuse_training(c: TransformerConfig) -> None:
@@ -1229,11 +1375,15 @@ def hidden_states(config: TransformerConfig, params: Dict,
 
 @jax.named_scope("lm_head")
 def _lm_head(c: TransformerConfig, params: Dict, x: jnp.ndarray):
-    logits = jnp.dot(x.astype(c.dtype),
-                     params["lm_head"]["w"].astype(c.dtype))
-    if "b" in params["lm_head"]:
-        logits = logits + params["lm_head"]["b"].astype(c.dtype)
-    return logits
+    if c.tie_embeddings:         # the embedding's rows are the head's columns
+        logits = jnp.einsum("...e,ve->...v", x.astype(c.dtype),
+                            params["embed"].astype(c.dtype))
+    else:
+        logits = jnp.dot(x.astype(c.dtype),
+                         params["lm_head"]["w"].astype(c.dtype))
+        if "b" in params["lm_head"]:
+            logits = logits + params["lm_head"]["b"].astype(c.dtype)
+    return logits if c.logit_scale == 1.0 else logits * c.logit_scale
 
 
 def apply(config: TransformerConfig, params: Dict, input_ids: jnp.ndarray,
@@ -1454,7 +1604,8 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 # donated, a step updates the caller's buffer in place.
 
 def init_kv_cache(config: TransformerConfig, num_blocks: int,
-                  block_size: int, window_blocks: Optional[int] = None
+                  block_size: int, window_blocks: Optional[int] = None,
+                  state_slots: Optional[int] = None
                   ) -> Dict[str, jnp.ndarray]:
     """Allocate the paged KV cache: ``{"k", "v"}`` of shape
     ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` in the
@@ -1486,9 +1637,20 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     ``num_blocks``, for a caller that reads both kinds through one
     table.
 
+    A stack with "mamba" layers keeps, beside the pools, what is NOT
+    paged (``STATE_ARRAYS``; :func:`cache_pools` leaves them out):
+    ``ssm`` ``[mamba layers, state_slots, ssm_heads * ssm_head_dim,
+    ssm_state]`` float32, a sequence's recurrent state, and ``conv``
+    ``[mamba layers, state_slots, ssm_conv - 1, ssm_conv_width]`` in the
+    compute dtype, the convolution's last inputs: a row a SLOT, the same
+    size however long the sequence. ``state_slots=None`` is ONE slot:
+    enough for a caller that runs one sequence (a batch row b uses slot
+    b unless ``state_rows`` says otherwise); an engine asks for its
+    ``decode_slots``.
+
     Which pools, how wide and of how many layers is the layer
     description's to say (``_layer_plan``: each kind's ``pools``, its
-    ``table``, and the layers the runs count)."""
+    ``table``, its ``state``, and the layers the runs count)."""
     plan = _layer_plan(config)
     cache = {}
     for kind in plan.kinds:
@@ -1499,6 +1661,10 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
             cache[pool.name] = jnp.zeros(
                 (layers, blocks, pool.heads, block_size, pool.width),
                 config.dtype)
+        for state in kind.state:
+            cache[state.name] = jnp.zeros(
+                (layers, state_slots or 1) + state.shape,
+                state.dtype or config.dtype)
     return cache
 
 
@@ -1556,7 +1722,10 @@ def _write_rows(cache, new, layer, block_tables, positions, write_mask):
 def _rotary(rot: _Rotary, positions, table_len: int):
     """(sin, cos) of a kind's rotary, in the form its program has: rows
     at ``positions`` (``rot.at_positions``), or a table of ``table_len``
-    rows for :func:`apply_rotary` to gather at them."""
+    rows for :func:`apply_rotary` to gather at them; (None, None) where
+    the kind rotates nothing."""
+    if not rot.dim:
+        return None, None
     if not rot.at_positions:
         return rotary_table(table_len, rot.dim, rot.base)
     if rot.yarn:
@@ -1616,10 +1785,11 @@ def _paged_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
         if kind.qk_norm:
             q = rms_norm(q, lp["q_norm"])
             k = rms_norm(k, lp["k_norm"])
-        q = apply_rotary(q, sin, cos, positions=positions,
-                         layout=kind.rotary.layout)
-        k = apply_rotary(k, sin, cos, positions=positions,
-                         layout=kind.rotary.layout)
+        if kind.rotary.dim:
+            q = apply_rotary(q, sin, cos, positions=positions,
+                             layout=kind.rotary.layout)
+            k = apply_rotary(k, sin, cos, positions=positions,
+                             layout=kind.rotary.layout)
         names = [pool.name for pool in kind.pools]
         new = dict(zip(names, (k, v)))
         if kind.index_topk:
@@ -1648,7 +1818,7 @@ def _paged_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
                     q, keys, values, tables, positions, layer=layer,
                     lens=live(), impl=c.paged_impl,
                     block_r=c.paged_row_block(h.shape[1]),
-                    window=kind.window)
+                    window=kind.window, sm_scale=c.attn_scale or None)
         if kind.head_gate:
             att = _head_gate(c, hd, lp, att)
         out = jnp.einsum(
@@ -1724,7 +1894,81 @@ def _latent_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
     return out, cache
 
 
-_MIXERS = {"paged": _paged_attn_sublayer, "latent": _latent_attn_sublayer}
+@jax.named_scope("ssm")
+def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
+                   state_rows, first, positions, write_mask, lens):
+    """The Mamba-2 mixer of a "mamba" layer, state layer ``layer`` of the
+    kind's per-slot arrays (``ops/ssm.py`` has the recurrence): project
+    ``h`` to gate ``z``, the convolution's inputs ``x | B | C`` and the
+    heads' steps ``dt``; a causal depthwise convolution over the new
+    inputs behind the slot's last ``ssm_conv - 1``; the recurrence from
+    the slot's state, blocked for a chunk or elementwise for one token;
+    ``y * silu(z)`` through one RMSNorm over all channels and ``w_out``.
+    The arguments are :func:`_paged_attn_sublayer`'s, with the slot of
+    each row (``state_rows [B]``; None: row b is slot b) where that has
+    a block table. A row's state is read at ``(layer, slot)`` and written
+    back there, in place in the scan's carry: a row whose call starts at
+    position 0 reads zeros whatever the slot held (a new sequence), and
+    a token that is not live (``write_mask``, or a row with ``lens`` 0:
+    a decode slot with no sequence) changes neither state nor tail.
+    Returns (out, cache)."""
+    from ray_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssd_step
+    dt_ = c.dtype
+    b, n, _ = h.shape
+    H, P, N = c.ssm_heads, c.ssm_head_dim, c.ssm_state
+    di, cw = c.ssm_inner, c.ssm_conv_width
+    live = write_mask & (lens > 0)[:, None]
+    n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
+    fresh = positions[:, 0] == 0
+
+    def read(name):
+        arr = cache[name]
+        if state_rows is None:
+            rows = jax.lax.dynamic_slice_in_dim(arr, layer, 1, axis=0)[0, :b]
+        else:
+            rows = arr[layer, state_rows]
+        return jnp.where(fresh.reshape((b,) + (1,) * (rows.ndim - 1)),
+                         jnp.zeros((), rows.dtype), rows)
+
+    def write(name, rows):
+        arr = cache[name]
+        if state_rows is None:
+            return jax.lax.dynamic_update_slice(
+                arr, rows[None].astype(arr.dtype),
+                (layer,) + (0,) * (arr.ndim - 1))
+        return arr.at[layer, state_rows].set(rows.astype(arr.dtype))
+
+    with jax.named_scope("ssm_in_proj"):
+        proj = jnp.dot(h.astype(dt_), lp["w_in"].astype(dt_))
+        z, xbc, dt = proj[..., :di], proj[..., di:di + cw], \
+            proj[..., di + cw:]
+    with jax.named_scope("ssm_conv"):
+        xbc, tail = causal_conv(xbc, read("conv"), lp["conv_w"],
+                                lp["conv_b"], n_live)
+        conv = write("conv", tail)
+    with jax.named_scope("ssm_scan"):
+        x = xbc[..., :di].reshape(b, n, H, P)
+        Bm, Cm = xbc[..., di:di + N], xbc[..., di + N:]
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+        A = -jnp.exp(lp["A_log"].astype(jnp.float32))
+        state = read("ssm").reshape(b, H, P, N)
+        if n == 1:
+            y, state = ssd_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                lp["D"], state, live[:, 0])
+            y = y[:, None]
+        else:
+            y, state = ssd_chunk_scan(x, dt, A, Bm, Cm, lp["D"], state,
+                                      live, block=c.ssm_chunk)
+        ssm = write("ssm", state.reshape(b, di, N))
+    with jax.named_scope("ssm_out"):
+        y = y.reshape(b, n, di) * jax.nn.silu(z.astype(jnp.float32))
+        y = rms_norm(y, lp["ssm_norm"], eps=c.norm_eps)
+        out = jnp.dot(y.astype(dt_), lp["w_out"].astype(dt_))
+    return out, {**cache, "conv": conv, "ssm": ssm}
+
+
+_MIXERS = {"paged": _paged_attn_sublayer, "latent": _latent_attn_sublayer,
+           "scan": _scan_sublayer}
 
 
 def _forward_with_cache(c: TransformerConfig, params: Dict,
@@ -1734,7 +1978,8 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                         write_mask: jnp.ndarray,
                         lens: jnp.ndarray,
                         window_tables: Optional[jnp.ndarray] = None,
-                        window_first: Optional[jnp.ndarray] = None):
+                        window_first: Optional[jnp.ndarray] = None,
+                        state_rows: Optional[jnp.ndarray] = None):
     """Shared trunk of :func:`prefill` and :func:`decode_step`:
     (B, C) token ids at absolute ``positions`` -> (B, C, vocab) logits,
     writing each layer's k/v into the paged cache as it goes. ``lens``
@@ -1748,9 +1993,10 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     never among the scanned inputs or outputs (those are sliced per layer
     and stacked into a new buffer — a copy of the whole pool every
     step). Each kind of layer is compiled once a run. A run that is a
-    whole stack, as at this repo's depths, reads it in place; a shorter
-    one a static slice of it. With no window table given the window
-    layers read ``block_tables`` through the window mask."""
+    whole stack scans it; a shorter one scans the layer index alone and
+    reads the layer's leaves out of the whole stack at it. With no
+    window table given the window layers read ``block_tables`` through
+    the window mask."""
     from ray_tpu.models.moe import EXPERT_LEAVES
     if c.n_experts and not c.experts_per_token:
         raise NotImplementedError(
@@ -1758,12 +2004,15 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
             "(experts_per_token > 0); Switch top-1 with capacity drops "
             "tokens by the batch they arrive in")
     plan = _layer_plan(c)
-    bs = next(iter(cache.values())).shape[3]
+    pools = cache_pools(cache)
+    bs = next(iter(pools.values())).shape[3] if pools else 1
     table_len = block_tables.shape[1] * bs
-    # a kind's block table and the absolute position of its entry 0
+    # a kind's block table and the absolute position of its entry 0; a
+    # kind with per-slot state has its rows' slots where a table would be
     tables = {"main": (block_tables, None),
               "window": (block_tables, None) if window_tables is None
-              else (window_tables, window_first)}
+              else (window_tables, window_first),
+              "state": (state_rows, None)}
     rot = {}
     for kind in plan.kinds:
         own = _rotary(kind.rotary, positions, table_len)
@@ -1771,20 +2020,33 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                      _rotary(kind.index_rotary, positions, table_len))
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], ids, axis=0).astype(c.dtype)
+        if c.embed_scale != 1.0:
+            x = x * c.embed_scale
     carry = (x, dict(cache))
     for run in plan.runs:
         stack = params[run.stack]
         # the dropless experts stay out of the scanned leaves: the grouped
         # product reads layer ``place`` of the whole stack in place
         whole = {k: stack[k] for k in EXPERT_LEAVES} if run.experts else {}
-        scanned = {k: v if (run.at, run.n) == (0, v.shape[0])
-                   else v[run.at:run.at + run.n]
-                   for k, v in stack.items() if k not in whole}
+        scanned = {k: v for k, v in stack.items() if k not in whole}
+        # a run shorter than its stack reads each layer's leaves out of
+        # the whole stack at the layer's place, as a scan reads its own
+        # inputs: a static slice of the stack to scan over would be a
+        # copy of those layers' weights in every call
+        indexed = {}
+        if any((run.at, run.n) != (0, v.shape[0]) for v in scanned.values()):
+            scanned, indexed = {}, scanned
 
-        def step(carry, per_layer, run=run, whole=whole):
+        def step(carry, per_layer, run=run, whole=whole, indexed=indexed):
             x, cache = carry
             lp, layer = per_layer
             kind, behind = run.kind, run.cache_layer - run.at
+            # the layer's place in its stack: behind its place in the
+            # kind's pools by the layers of that kind in the stacks ahead
+            place = layer - behind if behind else layer
+            if indexed:
+                lp = {k: jax.lax.dynamic_index_in_dim(v, place, 0, False)
+                      for k, v in indexed.items()}
 
             def attend(h):
                 return _MIXERS[kind.mixer](
@@ -1792,11 +2054,7 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                     *tables[kind.table], positions, write_mask, lens)
 
             def mlp(h):
-                # the layer's place in its stack: behind its place in
-                # the kind's pools by the layers of that kind in the
-                # stacks ahead
-                return _mlp_sublayer(c, h, {**lp, **whole},
-                                     layer - behind if behind else layer)
+                return _mlp_sublayer(c, h, {**lp, **whole}, place)
             with jax.named_scope("layer"):
                 x, cache, _ = _block(c, kind, x, lp, attend, mlp)
             return (x, cache), None
@@ -1812,7 +2070,8 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
             cache: Dict[str, jnp.ndarray], block_tables: jnp.ndarray,
             start_pos: jnp.ndarray, lens: jnp.ndarray,
             window_tables: Optional[jnp.ndarray] = None,
-            window_first: Optional[jnp.ndarray] = None):
+            window_first: Optional[jnp.ndarray] = None,
+            state_rows: Optional[jnp.ndarray] = None):
     """Process one prompt chunk per sequence, writing cache blocks.
 
     ``tokens``: (B, C) int32 — chunk ``start_pos[b] .. start_pos[b]+
@@ -1830,6 +2089,12 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
     of the page), so the table holds the pages from behind the window to
     the chunk's end and none before. Left out, the window layers read
     ``block_tables`` (the window pools then have its pages).
+
+    ``state_rows`` ``(B,)``, for a stack with "mamba" layers: the slot of
+    the per-slot state arrays each sequence's recurrent state lives in
+    (left out: sequence b's is slot b). A chunk at ``start_pos == 0``
+    starts from a zero state whatever its slot held; a later chunk goes
+    on from what the chunk before left there.
     """
     b, chunk = tokens.shape
     positions = start_pos[:, None] + jnp.arange(chunk, dtype=jnp.int32)
@@ -1839,26 +2104,30 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
     live = (start_pos + lens).astype(jnp.int32)
     return _forward_with_cache(config, params, tokens, cache,
                                block_tables, positions, write_mask,
-                               live, window_tables, window_first)
+                               live, window_tables, window_first,
+                               state_rows)
 
 
 def decode_step(config: TransformerConfig, params: Dict,
                 token_ids: jnp.ndarray, cache: Dict[str, jnp.ndarray],
                 block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
                 window_tables: Optional[jnp.ndarray] = None,
-                window_first: Optional[jnp.ndarray] = None):
+                window_first: Optional[jnp.ndarray] = None,
+                state_rows: Optional[jnp.ndarray] = None):
     """One batched decode step: each sequence's newest token
     (``token_ids``: (B,) int32, sitting at absolute position
     ``seq_lens[b]``) is written to its cache block and attends every
     earlier position — causal by construction. Returns
-    ``(logits (B, vocab), cache)``.
+    ``(logits (B, vocab), cache)``. A row with ``seq_lens < 0`` holds no
+    sequence: a "mamba" layer leaves its slot's state as it was.
     """
     positions = seq_lens[:, None].astype(jnp.int32)
     write_mask = jnp.ones_like(positions, dtype=bool)
     logits, cache = _forward_with_cache(
         config, params, token_ids[:, None], cache,
         block_tables, positions, write_mask,
-        seq_lens.astype(jnp.int32) + 1, window_tables, window_first)
+        seq_lens.astype(jnp.int32) + 1, window_tables, window_first,
+        state_rows)
     return logits[:, 0], cache
 
 

@@ -173,13 +173,27 @@ class EngineConfig:
         sequence lives (every pool of a model with one kind of layer: k
         and v, an indexer's keys, or one latent row), ``"window"`` what
         it takes of the window layers' pools, and only while it lies
-        inside the window (0 without such layers)."""
+        inside the window (0 without such layers). A layer with recurrent
+        state holds no page and adds nothing here: its bytes are a slot's,
+        whatever the sequence's length (``state_bytes_per_slot``)."""
         import jax
         from ray_tpu.models import init_kv_cache
-        from ray_tpu.models.transformer import WINDOW_POOLS
-        pools = jax.eval_shape(lambda: init_kv_cache(model_config, 1, 1))
+        from ray_tpu.models.transformer import WINDOW_POOLS, cache_pools
+        pools = cache_pools(jax.eval_shape(
+            lambda: init_kv_cache(model_config, 1, 1)))
         return sum(p.size * p.dtype.itemsize for name, p in pools.items()
                    if (name in WINDOW_POOLS) == (kind == "window"))
+
+    @staticmethod
+    def state_bytes_per_slot(model_config) -> int:
+        """Bytes one decode slot's recurrent state takes, over all layers
+        that have one (0: the model keeps pages and nothing else)."""
+        import jax
+        from ray_tpu.models import init_kv_cache
+        from ray_tpu.models.transformer import STATE_ARRAYS
+        cache = jax.eval_shape(lambda: init_kv_cache(model_config, 1, 1))
+        return sum(a.size * a.dtype.itemsize for name, a in cache.items()
+                   if name in STATE_ARRAYS)
 
 
 def _unpack(rows, width: int, scalars: int):
@@ -211,27 +225,36 @@ def _step_fns(model_config, ec: EngineConfig):
     the RLHF rollout payload, not a sampling change). A model with
     window layers has ``1 + window_blocks_per_seq`` columns more behind
     each table row: the absolute position the window layers' short table
-    starts at, and that table (ids of the window pool)."""
+    starts at, and that table (ids of the window pool). A model with
+    recurrent state (``state_bytes_per_slot`` > 0) has one column more at
+    the end of a PREFILL row: the request's decode slot, where its state
+    lives; a decode row needs none (row i is slot i), and a row staged
+    with no sequence leaves its slot's state as it was."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.models import decode_step, prefill
     capture = ec.capture_logprobs
     T = ec.blocks_per_seq
+    slotted = bool(ec.state_bytes_per_slot(model_config))
 
-    def tables(bt):
-        """(table, the window layers' arguments) out of a row's table
-        part: with window layers it is ``[table | the window table's
-        first position | window table]``, two arguments more out of the
-        same one transfer."""
+    def tables(bt, chunk=False):
+        """(table, the window layers' arguments, the state's) out of a
+        row's table part: with window layers it is ``[table | the window
+        table's first position | window table]``, two arguments more out
+        of the same one transfer; a chunk's row of a model with recurrent
+        state ends in its slot."""
+        state = {}
+        if slotted and chunk:
+            bt, state = bt[:, :-1], {"state_rows": bt[:, -1]}
         if not ec.window_blocks_per_seq(model_config):
-            return bt, ()
-        return bt[:, :T], (bt[:, T + 1:], bt[:, T])
+            return bt, (), state
+        return bt[:, :T], (bt[:, T + 1:], bt[:, T]), state
 
     def _prefill_fn(params, rows, cache):
         tokens, start, lens, bt = _unpack(rows, ec.prefill_chunk, 2)
-        bt, window = tables(bt)
+        bt, window, state = tables(bt, chunk=True)
         logits, cache = prefill(model_config, params, tokens, cache,
-                                bt, start, lens, *window)
+                                bt, start, lens, *window, **state)
         with jax.named_scope("sample"):
             last = jnp.take_along_axis(
                 logits, (lens - 1)[:, None, None], axis=1)[:, 0]
@@ -245,7 +268,7 @@ def _step_fns(model_config, ec: EngineConfig):
 
     def _decode_fn(params, rows, cache):
         toks, seq_lens, bt = _unpack(rows, 1, 1)
-        bt, window = tables(bt)
+        bt, window, _ = tables(bt)
         logits, cache = decode_step(model_config, params, toks[:, 0],
                                     cache, bt, seq_lens, *window)
         with jax.named_scope("sample"):
@@ -391,6 +414,17 @@ class LLMEngine:
                     f"what {ec.decode_slots} sequences can pin at once, "
                     f"{ec.decode_slots} x {Tw} pages and the trash page")
 
+        # recurrent state: a row a decode slot, no page (models/
+        # transformer.py init_kv_cache). What shares, ships or rolls back
+        # a prefix would have to move a state with it, and nothing does
+        self._state_bytes = ec.state_bytes_per_slot(model_config)
+        if self._state_bytes:
+            for name, on in (("enable_prefix_sharing",
+                              ec.enable_prefix_sharing),
+                             ("spec_tokens > 0", ec.spec_tokens > 0)):
+                if on:
+                    raise NotImplementedError(self._state_refusal(name))
+
         # Carried-over paged-kernel follow-on: at long table windows
         # (>= 4k tokens per sequence) the chunked-prefill side of the
         # paged kernel may win with row blocks > 128 — autotune once
@@ -429,7 +463,9 @@ class LLMEngine:
                 dtype=model_config.dtype))
         self._cache = init_kv_cache(
             model_config, ec.resolved_num_blocks, ec.kv_block_size,
-            *([ec.resolved_window_blocks(model_config)] if Tw else []))
+            *([ec.resolved_window_blocks(model_config)] if Tw else []),
+            **({"state_slots": ec.decode_slots} if self._state_bytes
+               else {}))
 
         S, T = ec.decode_slots, ec.blocks_per_seq
         self._np = np
@@ -491,7 +527,10 @@ class LLMEngine:
         # traced scalars, so every CoW reuses the same compiled program
         # With window layers there are two kinds of page and two pairs of
         # ids: ``window`` = (src, dst) in the window pools
-        from ray_tpu.models.transformer import WINDOW_POOLS
+        # The per-slot state arrays a model with recurrent state keeps
+        # beside its pools are no pages: the movers go over the pools
+        # (``cache_pools``) and hand the rest on as it came
+        from ray_tpu.models.transformer import WINDOW_POOLS, cache_pools
 
         @jax.named_scope("kv_copy")
         def _copy_fn(cache, src, dst, *window):
@@ -500,7 +539,8 @@ class LLMEngine:
                 return jax.lax.dynamic_update_slice_in_dim(
                     pool, jax.lax.dynamic_slice_in_dim(pool, a, 1, axis=1),
                     b, axis=1)
-            return {name: one(name, pool) for name, pool in cache.items()}
+            return {**cache, **{name: one(name, pool) for name, pool
+                                in cache_pools(cache).items()}}
 
         self._jit_copy = jax.jit(_copy_fn, donate_argnums=(0,))
 
@@ -514,12 +554,13 @@ class LLMEngine:
         @jax.named_scope("kv_gather")
         def _gather_fn(cache, ids):
             return {name: jnp.take(pool, ids, axis=1)
-                    for name, pool in cache.items()}
+                    for name, pool in cache_pools(cache).items()}
 
         @jax.named_scope("kv_scatter")
         def _scatter_fn(cache, ids, slabs):
-            return {name: pool.at[:, ids].set(slabs[name])
-                    for name, pool in cache.items()}
+            return {**cache, **{name: pool.at[:, ids].set(slabs[name])
+                                for name, pool
+                                in cache_pools(cache).items()}}
 
         self._jit_gather = jax.jit(_gather_fn)
         self._jit_scatter = jax.jit(_scatter_fn, donate_argnums=(0,))
@@ -616,6 +657,10 @@ class LLMEngine:
              "prefill_keys_window"), 0)
         self._prefix_hits = self._prefix_hits_cut = 0
         self._window_pinned_max = 0
+        # recurrent state: live rows the decode steps updated, live
+        # tokens and sequence-calls through the chunk programs
+        self._ssm = dict.fromkeys(("decode_rows", "prefill_tokens",
+                                   "prefill_calls"), 0)
         # the same for the paged kernel's innermost grid axis: it folds
         # P pages of a sequence a grid step, so a decode call takes
         # slots x ceil(T/P) steps of which sum(ceil(pages/P)) have a
@@ -624,7 +669,7 @@ class LLMEngine:
         from ray_tpu.ops.paged_flash import paged_pages_per_step
         # a page's heads and row width as the pools have them (a latent
         # cache: one pool, one "head")
-        pool = next(iter(self._cache.values()))
+        pool = next(iter(cache_pools(self._cache).values()))
         page_heads, row = pool.shape[2], pool.shape[4]
         self._decode_pages_per_step = paged_pages_per_step(
             (ec.spec_tokens + 1) * (model_config.n_heads // page_heads),
@@ -907,6 +952,7 @@ class LLMEngine:
         Blocking; see :class:`LLMServer.prefill_export` for the actor
         wrapper."""
         self._refuse_with_window("prefill_export")
+        self._refuse_with_state("prefill_export")
         req = self.submit(prompt_ids, max_new_tokens=1,
                           trace_ctx=trace_ctx, _export=True)
         deadline = time.monotonic() + timeout_s
@@ -942,6 +988,7 @@ class LLMEngine:
         request streams exactly what a colocated ``submit`` of the same
         prompt would have streamed (first token included)."""
         self._refuse_with_window("submit_adopt")
+        self._refuse_with_state("submit_adopt")
         if int(payload.get("block_size", 0)) != self.config.kv_block_size:
             raise ValueError(
                 f"shipped block_size {payload.get('block_size')} != "
@@ -960,6 +1007,7 @@ class LLMEngine:
         process. Runs on the step thread. Returns None when there is
         nothing worth shipping."""
         self._refuse_with_window("export_warm_prefixes")
+        self._refuse_with_state("export_warm_prefixes")
         ec = self.config
         bs = ec.kv_block_size
         np, jnp = self._np, self._jnp
@@ -1019,6 +1067,7 @@ class LLMEngine:
         if payload is None:
             return 0
         self._refuse_with_window("import_warm_prefixes")
+        self._refuse_with_state("import_warm_prefixes")
         if int(payload.get("block_size", 0)) != self.config.kv_block_size:
             raise ValueError(
                 f"migrated block_size {payload.get('block_size')} != "
@@ -1091,6 +1140,20 @@ class LLMEngine:
                 f"layers' pages alone, and a prefix resumed without the "
                 f"window layers' rows behind it would not be exact")
 
+    def _state_refusal(self, what: str) -> str:
+        return (f"{what} is not served with recurrent state (a model "
+                f"with 'mamba' layers: {self._state_bytes} B a decode "
+                f"slot): a sequence's state is one row of its slot, as of "
+                f"its last token; a shared, shipped or rolled-back prefix "
+                f"would need the state as of the prefix's end, which "
+                f"nothing keeps")
+
+    def _refuse_with_state(self, what: str) -> None:
+        """The hand-off and the warm-prefix migration move pages: a
+        model with recurrent state would need the state moved too."""
+        if self._state_bytes:
+            raise NotImplementedError(self._state_refusal(what))
+
     def _ship_blocks(self, blocks: List[int]) -> Dict[str, Any]:
         """These pages of every pool, packed for the wire (step thread):
         gathered ``blocks_per_seq`` ids at a time, the compiled shape."""
@@ -1142,7 +1205,8 @@ class LLMEngine:
         speculation on, decode steps go through ``verify`` and the
         plain ``decode`` program is never called."""
         progs = {"prefill": self._jit_prefill, "copy": self._jit_copy}
-        if not self._window_table:      # the hand-off's, refused there
+        # the hand-off's, refused with window layers and with state
+        if not (self._window_table or self._state_bytes):
             progs.update(gather=self._jit_gather,
                          scatter=self._jit_scatter)
         if self._jit_verify is not None:
@@ -1157,8 +1221,10 @@ class LLMEngine:
         written back is zeros, so no live block changes."""
         np, jnp = self._np, self._jnp
         zero = np.int32(0)
-        if self._window_table:       # no hand-off: the copy alone
-            self._cache = self._jit_copy(self._cache, *[zero] * 4)
+        if self._window_table or self._state_bytes:
+            # no hand-off: the copy alone
+            self._cache = self._jit_copy(
+                self._cache, *[zero] * (4 if self._window_table else 2))
             self._jax.block_until_ready(self._cache)
             return
         self._cache = self._jit_copy(self._cache, zero, zero)
@@ -1217,6 +1283,7 @@ class LLMEngine:
                 booked[:] = 0, 0
             self._pages_live = dict.fromkeys(self._pages_live, 0)
             self._window_pinned_max = 0
+            self._ssm = dict.fromkeys(self._ssm, 0)
             self._sparse.clear()
             self._prompt_blocks_total = 0
             self._occupancy.clear()
@@ -1280,6 +1347,7 @@ class LLMEngine:
                 # decode_steps x decode_slots less the occupancy
                 "decode_slots_skipped_total": self._decode_slots_skipped,
                 **self._window_stats(),
+                **self._state_stats(),
                 "decode_block_work_frac": (
                     round(self._decode_pages_live
                           / self._decode_pages_window, 4)
@@ -1421,6 +1489,24 @@ class LLMEngine:
             # hit a missing window tail shortened or refused
             "prefix_hits": self._prefix_hits,
             "prefix_hits_cut": self._prefix_hits_cut,
+        }
+
+    def _state_stats(self) -> Dict[str, Any]:
+        """``stats()``' keys of the recurrent state (call with the lock
+        held); none for a model that keeps pages and nothing else."""
+        if not self._state_bytes:
+            return {}
+        return {
+            # a slot's state is its decode slot's row: as many as slots,
+            # each this many bytes over all layers, whatever the length
+            "state_slots_total": self.config.decode_slots,
+            "state_bytes_per_slot": self._state_bytes,
+            # rows with a sequence the decode steps updated (one layer's
+            # count), summed over steps; live tokens and sequence-calls
+            # through the chunk programs
+            "ssm_decode_rows_total": self._ssm["decode_rows"],
+            "ssm_prefill_tokens_total": self._ssm["prefill_tokens"],
+            "ssm_prefill_calls_total": self._ssm["prefill_calls"],
         }
 
     def pool_audit(self) -> List[str]:
@@ -2061,13 +2147,18 @@ class LLMEngine:
         start = req.prefill_pos
         n = min(C, len(req.prompt) - start)
         with clock.phase("engine.prefill.stage"):
-            row = np.zeros((1, C + self._slot_rows.shape[1]), np.int32)
+            slotted = bool(self._state_bytes)
+            row = np.zeros((1, C + self._slot_rows.shape[1] + slotted),
+                           np.int32)
             row[0, :n] = req.prompt[start:start + n]
             row[0, C:C + 2] = start, n
             row[0, C + 2:C + 2 + len(req.blocks)] = req.blocks
+            if slotted:
+                row[0, -1] = req.slot     # where its state lives
             if self._wpool is not None:
                 with self._lock, clock.phase("engine.window.release"):
-                    row[0, C + 2 + ec.blocks_per_seq:] = \
+                    row[0, C + 2 + ec.blocks_per_seq:
+                        C + self._slot_rows.shape[1]] = \
                         self._window_row(req, start, n)
                     req.wspan = None      # a chunk's table is its own
                 live, w = self._pages_live, self._window
@@ -2128,6 +2219,8 @@ class LLMEngine:
         ec = self.config
         req.prefill_pos += n
         req.n_chunks += 1
+        self._ssm["prefill_tokens"] += n
+        self._ssm["prefill_calls"] += 1
         self._account_queries([start], [n], "prefill")
         if req.trace is not None:
             req.trace.span(RT.PREFILL, t0w, time.time(),
@@ -2197,9 +2290,10 @@ class LLMEngine:
         pages = paged_work_pages(
             self._np.asarray(live_lens, self._np.int64),
             ec.kv_block_size)
+        live_rows = int(self._np.count_nonzero(pages))
         self._decode_pages_live += int(pages.sum())
-        self._decode_slots_skipped += \
-            len(pages) - int(self._np.count_nonzero(pages))
+        self._ssm["decode_rows"] += live_rows
+        self._decode_slots_skipped += len(pages) - live_rows
         self._decode_pages_window += ec.decode_slots * ec.blocks_per_seq
         steps, live = paged_grid_steps(pages, ec.blocks_per_seq,
                                        self._decode_pages_per_step)

@@ -1,0 +1,132 @@
+"""The blocked scan, the one-token step and the carried convolution
+(``ray_tpu/ops/ssm.py``) against the recurrence written token by token."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssd_step
+
+H, P, N = 4, 8, 16
+
+
+def _inputs(seed, b, t):
+    rng = np.random.default_rng(seed)
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    return dict(
+        x=f(b, t, H, P), dt=np.log1p(np.exp(f(b, t, H) - 2.0)),
+        A=-np.exp(rng.uniform(0.0, 2.0, H)).astype(np.float32),
+        B=f(b, t, N), C=f(b, t, N), D=f(H), state=f(b, H, P, N))
+
+
+def _sequential(x, dt, A, B, C, D, state, n_live):
+    """Token by token; a row's tokens past ``n_live`` change nothing."""
+    state = state.astype(np.float64).copy()
+    y = np.zeros(x.shape, np.float64)
+    for b in range(x.shape[0]):
+        for t in range(int(n_live[b])):
+            decay = np.exp(dt[b, t] * A)[:, None, None]
+            state[b] = decay * state[b] + (dt[b, t][:, None] * x[b, t])[
+                ..., None] * B[b, t][None, None]
+            y[b, t] = state[b] @ C[b, t] + D[:, None] * x[b, t]
+    return y, state
+
+
+@pytest.mark.parametrize("t,block,n_live", [
+    (8, 8, (8, 8)),          # one block
+    (24, 8, (24, 24)),       # whole blocks
+    (21, 8, (21, 21)),       # not a multiple of the block
+    (24, 8, (24, 13)),       # a padded row
+    (16, 8, (5, 0)),         # a row with nothing live
+    (5, 256, (5, 3)),        # a call shorter than a block
+])
+def test_the_blocked_scan_is_the_recurrence(t, block, n_live):
+    a = _inputs(t, 2, t)
+    n_live = np.asarray(n_live)
+    live = np.arange(t)[None] < n_live[:, None]
+    y, state = jax.jit(ssd_chunk_scan, static_argnames="block")(
+        a["x"], a["dt"], a["A"], a["B"], a["C"], a["D"], a["state"],
+        jnp.asarray(live), block=block)
+    want_y, want_state = _sequential(**a, n_live=n_live)
+    np.testing.assert_allclose(np.asarray(state), want_state, rtol=2e-5,
+                               atol=2e-5)
+    got = np.where(live[..., None, None], np.asarray(y), 0.0)
+    np.testing.assert_allclose(got, want_y, rtol=2e-5, atol=2e-5)
+    # a row with nothing live hands its state on bit for bit
+    for b in np.flatnonzero(n_live == 0):
+        assert np.array_equal(np.asarray(state)[b], a["state"][b])
+
+
+def test_a_state_carried_across_calls_is_one_long_scan():
+    a = _inputs(3, 1, 40)
+    whole, end = ssd_chunk_scan(
+        a["x"], a["dt"], a["A"], a["B"], a["C"], a["D"], a["state"],
+        jnp.ones((1, 40), bool), block=8)
+    parts, state = [], jnp.asarray(a["state"])
+    for lo, hi in ((0, 16), (16, 29), (29, 40)):
+        y, state = ssd_chunk_scan(
+            a["x"][:, lo:hi], a["dt"][:, lo:hi], a["A"], a["B"][:, lo:hi],
+            a["C"][:, lo:hi], a["D"], state, jnp.ones((1, hi - lo), bool),
+            block=8)
+        parts.append(y)
+    np.testing.assert_allclose(np.concatenate(parts, 1), whole, rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(state, end, rtol=2e-5, atol=2e-5)
+
+
+def test_the_one_token_step_is_the_recurrence():
+    a = _inputs(5, 3, 6)
+    live = np.array([True, False, True])
+    state = jnp.asarray(a["state"])
+    ys = []
+    for t in range(6):
+        y, state = ssd_step(a["x"][:, t], a["dt"][:, t], a["A"],
+                            a["B"][:, t], a["C"][:, t], a["D"], state,
+                            jnp.asarray(live))
+        ys.append(y)
+    want_y, want_state = _sequential(**a, n_live=np.where(live, 6, 0))
+    np.testing.assert_allclose(np.asarray(state), want_state, rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.stack(ys, 1)[live], want_y[live],
+                               rtol=2e-5, atol=2e-5)
+    # the row that is not live: untouched, bit for bit
+    assert np.array_equal(np.asarray(state)[1], a["state"][1])
+
+
+def _conv_whole(x, w, b):
+    """The convolution over a whole sequence, zeros before position 0."""
+    k = w.shape[-1]
+    ext = np.concatenate([np.zeros((x.shape[0], k - 1, x.shape[2])), x], 1)
+    acc = b + sum(ext[:, j:j + x.shape[1]] * w[:, j] for j in range(k))
+    return acc / (1.0 + np.exp(-acc))
+
+
+@pytest.mark.parametrize("cuts", [(0, 12), (0, 5, 12), (0, 1, 2, 3, 12),
+                                  (0, 2, 4, 12)])
+def test_the_convolution_carries_its_tail(cuts):
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal((2, 12, 6)).astype(np.float32)
+    w = rng.standard_normal((6, 4)).astype(np.float32)
+    b = rng.standard_normal(6).astype(np.float32)
+    tail, outs = jnp.zeros((2, 3, 6)), []
+    for lo, hi in zip(cuts, cuts[1:]):
+        out, tail = causal_conv(x[:, lo:hi], tail, w, b,
+                                jnp.full((2,), hi - lo, jnp.int32))
+        outs.append(out)
+    np.testing.assert_allclose(np.concatenate(outs, 1), _conv_whole(x, w, b),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(tail, x[:, -3:])
+
+
+def test_padding_moves_neither_the_tail_nor_the_live_outputs():
+    rng = np.random.default_rng(2)
+    x = rng.standard_normal((2, 8, 6)).astype(np.float32)
+    w = rng.standard_normal((6, 4)).astype(np.float32)
+    b = np.zeros(6, np.float32)
+    tail = rng.standard_normal((2, 3, 6)).astype(np.float32)
+    n_live = jnp.asarray([2, 0], jnp.int32)
+    out, new = causal_conv(x, tail, w, b, n_live)
+    short, want = causal_conv(x[:, :2], tail, w, b, jnp.asarray([2, 2]))
+    np.testing.assert_allclose(out[0, :2], short[0], rtol=1e-6)
+    np.testing.assert_array_equal(new[0], want[0])
+    np.testing.assert_array_equal(new[1], tail[1])     # nothing live

@@ -491,3 +491,68 @@ def test_compiled_latent_step_copies_no_pool_and_slices_no_expert_stack(
     assert not [op for op in made if op not in (
         "parameter", "get-tuple-element", "bitcast", "scatter", "fusion")]
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("entry,batch", [
+    ("decode_step", 1), ("decode_step", 32), ("prefill", 1)])
+def test_compiled_step_updates_the_recurrent_state_in_place(
+        entry, batch, one_chip, monkeypatch):
+    """A stack with "mamba" layers at the published scan widths (128
+    heads of 64 over a state of 128, blocks of 256) and a narrow model
+    around them, a period of ten layers with 36 of 72 experts held: the
+    step programs compile for a described v5e, a decode step of ONE row
+    among them (ten rows against 9 x 36 groups of the grouped product,
+    which the compiler refuses unpadded: ``moe._FEW_ROWS``), and the
+    per-slot state, 1.2 GB here, is updated where it lies: the program's
+    temporaries are a small part of it (a chunk's program that ordered
+    heads and channels its own way relaid the whole array on the way in
+    and out: the state keeps them as one axis)."""
+    from ray_tpu.models import (TransformerConfig, decode_step,
+                                inference_params, init_kv_cache,
+                                init_params, prefill)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=512, n_layers=10, n_heads=4, n_kv_heads=2,
+        head_dim=128, d_ff=256, max_seq_len=2048, rotary_dim=0,
+        block_style="llama", dtype=jnp.bfloat16, remat_policy="none",
+        paged_impl="kernel", norm_eps=1e-5,
+        layer_pattern=["mamba"] * 5 + ["full"] + ["mamba"] * 4,
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_chunk=256,
+        attn_scale=1 / 128, embed_scale=12.0, residual_scale=0.22,
+        logit_scale=1 / 16, tie_embeddings=True, n_experts=72,
+        experts_per_token=10, expert_width=256, shared_expert_width=256,
+        experts_held=36)
+    slots, table, chunk = 32, 128, 1024
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    try:
+        params = shaped(jax.eval_shape(lambda: inference_params(
+            cfg, init_params(cfg, jax.random.PRNGKey(0), dtype=cfg.dtype))))
+        cache = shaped(jax.eval_shape(lambda: init_kv_cache(
+            cfg, 1 + slots * table, BLOCK, state_slots=slots)))
+        if entry == "decode_step":
+            fn = functools.partial(decode_step, cfg)
+            args = (params, i32(batch), cache, i32(batch, table), i32(batch))
+        else:
+            fn = functools.partial(prefill, cfg)
+            args = (params, i32(1, chunk), cache, i32(1, table), i32(1),
+                    i32(1), None, None, i32(1))
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    state = 9 * slots * 8192 * 128 * 4
+    assert cache["ssm"].shape == (9, slots, 8192, 128)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state
+    assert memory.temp_size_in_bytes < state // 4, memory.temp_size_in_bytes
+    assert "tpu_custom_call" in compiled.as_text()       # the paged layer
