@@ -753,7 +753,7 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                     # heads and their channels as ONE axis: a program
                     # that could order them either way would relay the
                     # whole array to its own order on the way in and out
-                    _State("ssm", (c.ssm_inner, c.ssm_state), jnp.float32),
+                    _State("ssm", (c.ssm_state, c.ssm_inner), jnp.float32),
                     _State("conv", (c.ssm_conv - 1, c.ssm_conv_width),
                            None)))
         if latent:
@@ -1639,8 +1639,9 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
 
     A stack with "mamba" layers keeps, beside the pools, what is NOT
     paged (``STATE_ARRAYS``; :func:`cache_pools` leaves them out):
-    ``ssm`` ``[mamba layers, state_slots, ssm_heads * ssm_head_dim,
-    ssm_state]`` float32, a sequence's recurrent state, and ``conv``
+    ``ssm`` ``[mamba layers, state_slots, ssm_state, ssm_heads *
+    ssm_head_dim]`` float32, a sequence's recurrent state (the state's
+    width ahead of the channels: ``ops/ssm.py``), and ``conv``
     ``[mamba layers, state_slots, ssm_conv - 1, ssm_conv_width]`` in the
     compute dtype, the convolution's last inputs: a row a SLOT, the same
     size however long the sequence. ``state_slots=None`` is ONE slot:
@@ -1912,7 +1913,8 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
     a token that is not live (``write_mask``, or a row with ``lens`` 0:
     a decode slot with no sequence) changes neither state nor tail.
     Returns (out, cache)."""
-    from ray_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssd_step
+    from ray_tpu.ops.ssm import (causal_conv, put_slot_rows, slot_rows,
+                                 ssd_chunk_scan, ssd_step_slots)
     dt_ = c.dtype
     b, n, _ = h.shape
     H, P, N = c.ssm_heads, c.ssm_head_dim, c.ssm_state
@@ -1922,21 +1924,10 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
     fresh = positions[:, 0] == 0
 
     def read(name):
-        arr = cache[name]
-        if state_rows is None:
-            rows = jax.lax.dynamic_slice_in_dim(arr, layer, 1, axis=0)[0, :b]
-        else:
-            rows = arr[layer, state_rows]
-        return jnp.where(fresh.reshape((b,) + (1,) * (rows.ndim - 1)),
-                         jnp.zeros((), rows.dtype), rows)
+        return slot_rows(cache[name], layer, state_rows, b, fresh)
 
     def write(name, rows):
-        arr = cache[name]
-        if state_rows is None:
-            return jax.lax.dynamic_update_slice(
-                arr, rows[None].astype(arr.dtype),
-                (layer,) + (0,) * (arr.ndim - 1))
-        return arr.at[layer, state_rows].set(rows.astype(arr.dtype))
+        return put_slot_rows(cache[name], layer, state_rows, rows)
 
     with jax.named_scope("ssm_in_proj"):
         proj = jnp.dot(h.astype(dt_), lp["w_in"].astype(dt_))
@@ -1951,15 +1942,17 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
         Bm, Cm = xbc[..., di:di + N], xbc[..., di + N:]
         dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
         A = -jnp.exp(lp["A_log"].astype(jnp.float32))
-        state = read("ssm").reshape(b, H, P, N)
         if n == 1:
-            y, state = ssd_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
-                                lp["D"], state, live[:, 0])
+            # the whole array and the layer's index, never a slice of it
+            y, ssm = ssd_step_slots(
+                x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"],
+                cache["ssm"], layer, state_rows, live[:, 0], fresh,
+                impl=c.paged_impl)
             y = y[:, None]
         else:
-            y, state = ssd_chunk_scan(x, dt, A, Bm, Cm, lp["D"], state,
-                                      live, block=c.ssm_chunk)
-        ssm = write("ssm", state.reshape(b, di, N))
+            y, state = ssd_chunk_scan(x, dt, A, Bm, Cm, lp["D"],
+                                      read("ssm"), live, block=c.ssm_chunk)
+            ssm = write("ssm", state)
     with jax.named_scope("ssm_out"):
         y = y.reshape(b, n, di) * jax.nn.silu(z.astype(jnp.float32))
         y = rms_norm(y, lp["ssm_norm"], eps=c.norm_eps)

@@ -46,7 +46,8 @@ _dispatch: Dict[tuple, int] = collections.Counter()
 
 def dispatch_log() -> List[Dict[str, object]]:
     """Every attention dispatch decision this process has traced:
-    ``{"op": "flash"|"paged", "impl": "kernel"|"interpret"|"reference",
+    ``{"op": "flash"|"paged"|"latent"|"ssm_step" (the one-token state
+    update, ``ops/ssm.py``), "impl": "kernel"|"interpret"|"reference",
     "why": ..., "count": n}``. ``why`` is ``"auto"`` or ``"requested"``
     for a kernel, and for a reference either ``"requested"`` or the
     shape rule (or platform) that ruled the kernel out."""
@@ -56,9 +57,11 @@ def dispatch_log() -> List[Dict[str, object]]:
 
 
 def _resolve(op: str, impl: str, kernel_name: str,
-             unfit: Optional[str]) -> str:
+             unfit: Optional[str], record: bool = True) -> str:
     """Shared dispatch rule -> "kernel" | "interpret" | "reference".
-    ``unfit`` names the shape rule the call breaks (None = it tiles)."""
+    ``unfit`` names the shape rule the call breaks (None = it tiles).
+    ``record=False`` asks what a call would resolve to without adding
+    one to :func:`dispatch_log`."""
     backend = jax.default_backend()
     if impl == "auto":
         if backend != "tpu":
@@ -78,8 +81,9 @@ def _resolve(op: str, impl: str, kernel_name: str,
         choice, why = impl, "requested"
     else:
         raise ValueError(f"unknown {op} attention impl: {impl!r}")
-    with _dispatch_lock:
-        _dispatch[(op, choice, why)] += 1
+    if record:
+        with _dispatch_lock:
+            _dispatch[(op, choice, why)] += 1
     return choice
 
 

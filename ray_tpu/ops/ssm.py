@@ -8,13 +8,34 @@ group's ``B, C [N]``::
     H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t
     y_t = H_t C_t + D x_t
 
+A sequence's state is kept ``[N, H * P]``: the state's width on the
+sublanes, heads and channels as ONE axis on the lanes (a program that
+ordered heads and channels its own way relaid the whole array). So the
+output's sum over ``N`` is plain adds of vregs, not a reduction across
+lanes, a head's decay and a channel's ``dt x`` are rows broadcast down
+the sublanes, ``B`` and ``C`` one column a sequence, and the blocked
+form's two products with the state are plain ``[T, N] x [N, H P]`` and
+``[N, S] x [S, H P]`` matmuls. The layout is private to this module,
+``init_kv_cache``'s per-slot arrays and ``_scan_sublayer``.
+
 - :func:`ssd_chunk_scan`: a call of ``T`` tokens a sequence in the
   blocked form: inside a block of ``block`` tokens the outputs are one
   masked ``(C B^T) * decay`` product with ``x``, between blocks the state
   is carried (a ``lax.scan`` over the blocks). Decays and their running
   sums are float32; the block's products take ``x``'s dtype on the MXU
-  and accumulate in float32.
-- :func:`ssd_step`: one token, elementwise on the state.
+  and accumulate in float32. Plain XLA on every platform.
+- :func:`ssd_step_slots`: one token a sequence over the WHOLE per-slot
+  array ``[layers, slots, N, H P]`` with a layer index, in place, as
+  every pool is handed to its kernel. On a TPU a Pallas kernel
+  (``impl``, resolved as the attention kernels' are, op ``"ssm_step"``
+  of ``ops.attention.dispatch_log``): a grid step holds one ``[N,
+  lanes]`` tile of one slot's state, reads it once, writes it once where
+  it lay (the array is aliased through the layer scan's carry) and sums
+  the output from the tile it holds. Off the chip, or where the shapes
+  do not tile, the plain form: slice the layer, :func:`ssd_step`, write
+  it back. XLA makes two fusions of that and reads the state twice.
+- :func:`ssd_step`: the one-token update on a batch of states,
+  elementwise ``jax.numpy``: the CPU's form and the tests' oracle.
 - :func:`causal_conv`: the depthwise causal convolution ahead of the
   scan, with the last ``K - 1`` inputs carried as a tail.
 
@@ -22,15 +43,30 @@ A token that is not ``live`` (a chunk's zero padding, a decode row with
 no sequence) has its ``dt`` set to 0: the state passes it unchanged
 (``exp(0) H + 0``, exactly) and the tail is taken at the last live token.
 Live tokens are a prefix of the call. One group of ``B`` / ``C`` for all
-heads (``n_groups`` 1). Plain XLA; a Pallas kernel is later work and
-would keep these signatures.
+heads (``n_groups`` 1).
+
+Traps the kernel met (PR 53). *Tiling*: with the state ``[H P, N]`` a
+head's decay and a channel's ``dt x`` are a value a sublane and the sum
+runs across lanes: three XLU or MXU passes a tile (1.39 ms a layer as
+MXU products at full precision against 0.84 for this layout; XLA's two
+fusions 1.54). *The reduction*: over sublanes it is 15 adds of vregs a
+lane column and one small sublane fold. *Aliasing*: the state is an
+input aliased to an output (``input_output_aliases`` counts the scalar
+prefetch arguments too) and the layer scan's carry is then updated
+where it lies: the compiled step has no temporary of the state's size
+(``tests/ops/test_tpu_lowering.py``). A slot may appear once in a call.
 """
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+#: lanes of the kernel's state tile: the widest of these that divides
+#: ``H P`` (level from 1,024 to 4,096 on a v5e, 10% behind at 512)
+_STEP_LANES = (2048, 1024, 512, 256, 128)
 
 
 def causal_conv(x, tail, w, b, n_live) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -55,17 +91,129 @@ def causal_conv(x, tail, w, b, n_live) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return out, new_tail.astype(tail.dtype)
 
 
+def _per_lane(a, p: int):
+    """``[..., H]`` a head -> ``[..., H P]`` a channel."""
+    return jnp.repeat(a, p, axis=-1)
+
+
 def ssd_step(x, dt, A, B, C, D, state, live):
     """One token a sequence. ``x [B, H, P]``, ``dt [B, H]`` float32 (past
-    its softplus), ``A, D [H]``, ``B, C [B, N]``, ``state [B, H, P, N]``
+    its softplus), ``A, D [H]``, ``B, C [B, N]``, ``state [B, N, H P]``
     float32, ``live [B]`` bool. Returns (``y [B, H, P]`` float32, state)."""
+    b, h, p = x.shape
+    decay, dtx, skip = _step_rows(x, dt, A, D, live)
+    state = state * decay[:, None, :] \
+        + B.astype(F32)[:, :, None] * dtx[:, None, :]
+    y = jnp.sum(state * C.astype(F32)[:, :, None], axis=1)
+    return (y + skip).reshape(b, h, p), state
+
+
+def _step_rows(x, dt, A, D, live):
+    """What a channel brings to its column of the state, ``[B, H P]``
+    float32 each: its head's decay ``exp(dt A)``, ``dt x`` and ``D x``,
+    with ``dt`` 0 where the row is not live."""
+    b, h, p = x.shape
     dt = jnp.where(live[:, None], dt.astype(F32), 0.0)
     xf = x.astype(F32)
-    decay = jnp.exp(dt * A.astype(F32))
-    state = state * decay[..., None, None] \
-        + (dt[..., None] * xf)[..., None] * B.astype(F32)[:, None, None, :]
-    y = jnp.sum(state * C.astype(F32)[:, None, None, :], axis=-1)
-    return y + D.astype(F32)[:, None] * xf, state
+    return (_per_lane(jnp.exp(dt * A.astype(F32)), p),
+            (dt[..., None] * xf).reshape(b, h * p),
+            (D.astype(F32)[:, None] * xf).reshape(b, h * p))
+
+
+def slot_rows(arr, layer, slots, b: int, fresh):
+    """Rows ``(layer, slot)`` of a per-slot array ``[layers, slots,
+    ...]`` for a batch of ``b`` (``slots [B]``; None: row b is slot b),
+    zeros where the row is ``fresh [B]``."""
+    if slots is None:
+        rows = jax.lax.dynamic_slice_in_dim(arr, layer, 1, axis=0)[0, :b]
+    else:
+        rows = arr[layer, slots]
+    return jnp.where(fresh.reshape((b,) + (1,) * (rows.ndim - 1)),
+                     jnp.zeros((), rows.dtype), rows)
+
+
+def put_slot_rows(arr, layer, slots, rows):
+    """``arr`` with ``rows`` at ``(layer, slot)``, in place in a scan's
+    carry."""
+    if slots is None:
+        return jax.lax.dynamic_update_slice(
+            arr, rows[None].astype(arr.dtype),
+            (layer,) + (0,) * (arr.ndim - 1))
+    return arr.at[layer, slots].set(rows.astype(arr.dtype))
+
+
+def _step_kernel(layer_ref, slot_ref, fresh_ref, decay_ref, dtx_ref,
+                 skip_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
+    """One ``[N, lanes]`` tile of one slot's state: read once, written
+    once, the output summed from the tile held."""
+    del layer_ref, slot_ref                      # the index maps read them
+    state = jnp.where(fresh_ref[pl.program_id(0)] != 0, 0.0, state_ref[...])
+    state = state * decay_ref[...] + b_ref[...] * dtx_ref[...]
+    out_ref[...] = state
+    y_ref[...] = jnp.sum(state * c_ref[...], axis=0, keepdims=True) \
+        + skip_ref[...]
+
+
+def step_choice(impl: str, n: int, hp: int, record: bool = False) -> str:
+    """What the one-token update of states ``[N, H P]`` resolves to under
+    ``impl``: "kernel" | "interpret" | "reference". A tile is ``[N,
+    lanes]`` float32: ``N`` whole sublane tiles, ``H P`` whole lanes."""
+    from ray_tpu.ops.attention import _resolve
+    unfit = None
+    if hp % 128:
+        unfit = f"heads x head_dim {hp} % 128 != 0"
+    elif n % 8:
+        unfit = f"state {n} % 8 != 0"
+    return _resolve("ssm_step", impl, "kernel", unfit, record)
+
+
+def ssd_step_slots(x, dt, A, B, C, D, states, layer, slots, live, fresh,
+                   impl: str = "auto"):
+    """One token a sequence on the WHOLE per-slot array. ``states
+    [layers, slots, N, H P]`` float32, ``layer`` an int32 scalar (traced
+    inside a layer scan), ``slots [B]`` int32 each row's slot (None: row
+    b is slot b; a slot once a call), ``live``, ``fresh [B]`` bool (a
+    fresh row starts from zeros whatever its slot held), the rest as
+    :func:`ssd_step`. ``impl``: "auto" | "kernel" | "interpret" |
+    "reference" (``ops/attention.py``'s rule). Returns (``y [B, H, P]``
+    float32, ``states`` with layer ``layer`` of the rows' slots
+    updated)."""
+    b, h, p = x.shape
+    n, hp = states.shape[2:]
+    choice = step_choice(impl, n, hp, record=True)
+    if choice == "reference":
+        y, rows = ssd_step(x, dt, A, B, C, D,
+                           slot_rows(states, layer, slots, b, fresh), live)
+        return y, put_slot_rows(states, layer, slots, rows)
+    # (an interpreted call of a shape that does not tile: one tile)
+    lanes = next((w for w in _STEP_LANES if hp % w == 0), hp)
+    if slots is None:
+        slots = jnp.arange(b, dtype=jnp.int32)
+    row = pl.BlockSpec((None, 1, lanes), lambda i, j, *_: (i, 0, j))
+    col = pl.BlockSpec((None, n, 1), lambda i, j, *_: (i, 0, 0))
+    tile = pl.BlockSpec(
+        (None, None, n, lanes),
+        lambda i, j, layer, slot, fresh: (layer[0], slot[i], 0, j))
+    y, states = pl.pallas_call(
+        _step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, hp // lanes),
+            in_specs=[row, row, row, col, col, tile],
+            out_specs=[row, tile]),
+        out_shape=[jax.ShapeDtypeStruct((b, 1, hp), F32),
+                   jax.ShapeDtypeStruct(states.shape, F32)],
+        # argument 8 (the three prefetched scalars count) is output 1
+        input_output_aliases={8: 1},
+        compiler_params=None if choice == "interpret"
+        else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=choice == "interpret", name="ssm_step",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+      fresh.astype(jnp.int32),
+      *(a.reshape(b, 1, hp) for a in _step_rows(x, dt, A, D, live)),
+      B.astype(F32).reshape(b, n, 1), C.astype(F32).reshape(b, n, 1),
+      states)
+    return y.reshape(b, h, p), states
 
 
 def _block(carry, blk, A):
@@ -73,9 +221,9 @@ def _block(carry, blk, A):
     block's ``x [B, Q, H, P]``, ``dt [B, Q, H]`` (0 where not live),
     ``B, C [B, Q, N]`` -> (state out, ``y [B, Q, H, P]`` float32 without
     the ``D x`` term)."""
-    state = carry                                   # [B, H, P, N] f32
+    state = carry                                   # [B, N, H P] f32
     x, dt, Bm, Cm = blk
-    q = x.shape[1]
+    b, q, h, p = x.shape
     dt_h = jnp.moveaxis(dt, 1, 2)                   # [B, H, Q]
     cs = jnp.cumsum(dt_h * A[:, None], axis=-1)     # inclusive, <= 0
     # inside the block: y_t += sum_{s <= t} exp(cs_t - cs_s) dt_s
@@ -86,16 +234,18 @@ def _block(carry, blk, A):
     mix = jnp.exp(gap) * gram[:, None] * dt_h[..., None, :]   # [B, H, t, s]
     y = jnp.einsum("bhts,bshp->bthp", mix.astype(x.dtype), x,
                    preferred_element_type=F32)
-    # what the state that came in adds: exp(cs_t) (H_in C_t)
-    from_state = jnp.einsum("btn,bhpn->bthp", Cm.astype(F32), state,
+    # what the state that came in adds: exp(cs_t) (C_t H_in)
+    from_state = jnp.einsum("btn,bnk->btk", Cm.astype(F32), state,
                             preferred_element_type=F32)
-    y = y + from_state * jnp.moveaxis(jnp.exp(cs), 1, 2)[..., None]
+    y = y + from_state.reshape(b, q, h, p) \
+        * jnp.moveaxis(jnp.exp(cs), 1, 2)[..., None]
     # the state that goes out: exp(cs_Q) H_in + sum_s exp(cs_Q - cs_s)
-    #                          dt_s x_s (outer) B_s
+    #                          B_s (outer) dt_s x_s
     w = jnp.exp(cs[..., -1:] - cs) * dt_h                     # [B, H, s]
     xw = x.astype(F32) * jnp.moveaxis(w, 1, 2)[..., None]     # [B, s, H, P]
-    state = state * jnp.exp(cs[..., -1])[..., None, None] \
-        + jnp.einsum("bshp,bsn->bhpn", xw.astype(x.dtype), Bm,
+    state = state * _per_lane(jnp.exp(cs[..., -1]), p)[:, None, :] \
+        + jnp.einsum("bsn,bsk->bnk", Bm,
+                     xw.astype(x.dtype).reshape(b, q, h * p),
                      preferred_element_type=F32)
     return state, y
 
@@ -103,7 +253,7 @@ def _block(carry, blk, A):
 def ssd_chunk_scan(x, dt, A, B, C, D, state_in, live, block: int = 256):
     """``T`` tokens a sequence, blocked. ``x [B, T, H, P]``, ``dt [B, T,
     H]`` float32 (past its softplus), ``A, D [H]``, ``B, C [B, T, N]``,
-    ``state_in [B, H, P, N]`` float32, ``live [B, T]`` bool (a prefix of
+    ``state_in [B, N, H P]`` float32, ``live [B, T]`` bool (a prefix of
     each row). ``T`` is one block, or is padded here to whole blocks of
     ``block``. Returns (``y [B, T, H, P]`` float32, ``state_out``): the
     outputs of tokens that are not live are finite and mean nothing."""

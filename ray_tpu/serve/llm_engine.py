@@ -419,6 +419,13 @@ class LLMEngine:
         # a prefix would have to move a state with it, and nothing does
         self._state_bytes = ec.state_bytes_per_slot(model_config)
         if self._state_bytes:
+            from ray_tpu.ops.ssm import step_choice
+            # what a decode step's state update runs as (ops/ssm.py): the
+            # kernel, or the plain form where the shapes or the platform
+            # rule it out
+            self._ssm_step_impl = step_choice(
+                model_config.paged_impl, model_config.ssm_state,
+                model_config.ssm_inner)
             for name, on in (("enable_prefix_sharing",
                               ec.enable_prefix_sharing),
                              ("spec_tokens > 0", ec.spec_tokens > 0)):
@@ -1505,6 +1512,11 @@ class LLMEngine:
             # count), summed over steps; live tokens and sequence-calls
             # through the chunk programs
             "ssm_decode_rows_total": self._ssm["decode_rows"],
+            # ... and how many of them the kernel updated: all, or none
+            # where the step fell back to XLA's two passes
+            "ssm_step_impl": self._ssm_step_impl,
+            "ssm_kernel_rows_total": self._ssm["decode_rows"]
+            if self._ssm_step_impl != "reference" else 0,
             "ssm_prefill_tokens_total": self._ssm["prefill_tokens"],
             "ssm_prefill_calls_total": self._ssm["prefill_calls"],
         }

@@ -127,6 +127,19 @@ def test_the_chunk_size_changes_nothing(chunk):
     assert _err(got, _want(params)) < 2e-5
 
 
+@pytest.mark.parametrize("slot", [None, 2])
+def test_the_step_kernel_decodes_what_the_plain_step_decodes(slot):
+    """The decode steps' state update as the Pallas kernel, interpreted
+    (``paged_impl``; the paged layer's kernel with it), on the whole
+    per-slot array through the layer scan: the reference's logits, row b
+    in slot b and in the slot it is told."""
+    cfg, params = _model(paged_impl="interpret")
+    got, _ = _through_cache(cfg, params, IDS[:-1], PROMPT, 24, slot=slot,
+                            slots=None if slot is None else 4)
+    assert _err(got, _want(params)) < 2e-5
+    np.testing.assert_allclose(got, _sound(), rtol=1e-4, atol=1e-6)
+
+
 @pytest.mark.parametrize("control", granite.CONTROLS)
 def test_each_control_is_told_apart(control):
     """The sound program against a reference with one published term
@@ -242,7 +255,7 @@ def test_the_cache_and_the_tree_are_what_the_plan_says():
     cache = init_kv_cache(cfg, 5, BS, state_slots=3)
     assert {k: v.shape for k, v in cache.items()} == {
         "k": (1, 5, 2, 16, 16), "v": (1, 5, 2, 16, 16),
-        "ssm": (3, 3, 128, 8), "conv": (3, 3, 3, 144)}
+        "ssm": (3, 3, 8, 128), "conv": (3, 3, 3, 144)}
     assert cache["ssm"].dtype == jnp.float32
     assert set(cache_pools(cache)) == {"k", "v"}
     assert init_kv_cache(cfg, 5, BS)["ssm"].shape[1] == 1     # the default

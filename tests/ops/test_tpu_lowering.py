@@ -551,8 +551,60 @@ def test_compiled_step_updates_the_recurrent_state_in_place(
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
     state = 9 * slots * 8192 * 128 * 4
-    assert cache["ssm"].shape == (9, slots, 8192, 128)
+    assert cache["ssm"].shape == (9, slots, 128, 8192)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= state
     assert memory.temp_size_in_bytes < state // 4, memory.temp_size_in_bytes
-    assert "tpu_custom_call" in compiled.as_text()       # the paged layer
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                     # the paged layer
+    if entry != "decode_step":
+        return
+    # the one-token update is the kernel, once a run of "mamba" layers,
+    # on the whole array (its output aliased to it), and nothing else
+    # makes an array of the rows' states, in either order of its axes
+    kernels = re.findall(
+        rf"%ssm_step[.\d]* = \(f32\[{batch},1,8192\]\S*, "
+        rf"f32\[9,{slots},128,8192\]\S*\) custom-call\(", text)
+    assert len(kernels) == 2, kernels
+    made = re.findall(
+        r"= f32\[(?:\d+,)+(?:128,8192|8192,128)\]\S* ([\w\-]+)\(", text)
+    assert not [op for op in made if op not in (
+        "parameter", "get-tuple-element", "bitcast")], made
+
+
+def test_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip,
+                                                          monkeypatch):
+    """The one-token state update (``ops/ssm.py`` ``ssd_step_slots``) at
+    the state-space cell's shapes: 64 rows on nine layers of 64 slots of
+    ``[128, 8192]`` float32 (2.4 GB), a traced layer, row b in slot b and
+    the slots given. The Mosaic compile accepts its tiles under the
+    default scoped VMEM limit (the kernel asks for no other), the whole
+    array is aliased to the output and the call has no temporaries."""
+    from ray_tpu.ops.ssm import ssd_step_slots
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    rows, heads, width, n, layers = 64, 128, 64, 128, 9
+    args = (s((rows, heads, width), jnp.bfloat16), s((rows, heads)),
+            s((heads,)), s((rows, n), jnp.bfloat16),
+            s((rows, n), jnp.bfloat16), s((heads,)),
+            s((layers, rows, n, heads * width)), s((), jnp.int32))
+    flags = (s((rows,), jnp.bool_), s((rows,), jnp.bool_))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for slots in (None, s((rows,), jnp.int32)):
+            compiled = jax.jit(
+                functools.partial(ssd_step_slots, impl="auto"),
+                donate_argnums=(6,)).lower(*args, slots, *flags).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+            memory = compiled.memory_analysis()
+            assert memory.alias_size_in_bytes \
+                >= layers * rows * n * heads * width * 4
+            assert memory.temp_size_in_bytes < 1 << 20
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()

@@ -42,15 +42,17 @@ LONG = [(5 * i + 3) % 60 + 2 for i in range(70)]      # five chunks
 SHORT = [(7 * i + 1) % 60 + 2 for i in range(9)]
 STATE_KEYS = {"state_slots_total", "state_bytes_per_slot",
               "ssm_decode_rows_total", "ssm_prefill_tokens_total",
-              "ssm_prefill_calls_total"}
+              "ssm_prefill_calls_total", "ssm_step_impl",
+              "ssm_kernel_rows_total"}
 
 
-def _engine(**kw):
+def _engine(model=(), **kw):
     ekw = dict(decode_slots=3, kv_block_size=BS, max_seq_len=128,
                prefill_chunk=CHUNK, max_new_tokens=8, num_kv_blocks=97,
                enable_prefix_sharing=False)
     ekw.update(kw)
-    return LLMEngine(TransformerConfig(**MODEL_KW), EngineConfig(**ekw))
+    return LLMEngine(TransformerConfig(**{**MODEL_KW, **dict(model)}),
+                     EngineConfig(**ekw))
 
 
 @pytest.fixture(scope="module")
@@ -98,9 +100,31 @@ def test_what_it_serves_is_the_references_choice(engine):
     # the first token comes off the last chunk, the other seven off a
     # decode step each, and the eighth's own step is never run
     assert s["ssm_decode_rows_total"] - s0["ssm_decode_rows_total"] == 7
+    # off the chip the update is the plain form, and the engine says so
+    assert s["ssm_step_impl"] == "reference"
+    assert s["ssm_kernel_rows_total"] == 0
     assert s["active_slots"] == 0 and engine.pool_audit() == []
     assert set(s["compiled_programs"]) == {"prefill", "copy", "decode"}
     assert set(s["compiled_programs"].values()) <= {0, 1}
+
+
+def test_the_engine_says_the_kernel_updated_its_decode_rows(engine):
+    """The same engine with the kernels interpreted: the same tokens,
+    and every decode row counted as the kernel's."""
+    eng = _engine(model={"paged_impl": "interpret"})
+    try:
+        s0 = eng.stats()
+        assert list(eng.generate_sync(LONG, 8)) \
+            == list(engine.generate_sync(LONG, 8))
+        s = eng.stats()
+    finally:
+        eng.shutdown()
+    assert s["ssm_step_impl"] == "interpret"
+    assert s["ssm_kernel_rows_total"] - s0["ssm_kernel_rows_total"] \
+        == s["ssm_decode_rows_total"] - s0["ssm_decode_rows_total"] == 7
+    assert {"op": "ssm_step", "impl": "interpret", "why": "requested"} \
+        in [{k: d[k] for k in ("op", "impl", "why")}
+            for d in s["attention_dispatch"]]
 
 
 def test_chunks_between_other_slots_decode_steps_change_nothing(engine):
