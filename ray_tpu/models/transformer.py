@@ -48,6 +48,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -225,6 +226,28 @@ class TransformerConfig:
     residual_scale: float = 1.0
     logit_scale: float = 1.0
     tie_embeddings: bool = False
+    # A "delta" layer in layer_pattern (served): a gated-delta-rule mixer
+    # (ops/delta.py; Gated DeltaNet) in the attention's place,
+    # delta_heads heads with keys and queries of delta_key_dim and values
+    # of delta_value_dim, a depthwise causal convolution of delta_conv
+    # taps (no bias) over q | k | v ahead of it, scanned in the published
+    # blocks of 64 (ops/delta.py BLOCK); delta_neg_eigval: the write
+    # strength is 2 sigmoid, not sigmoid. Such a layer holds no page: a
+    # sequence's state and the convolution's last inputs live in per-slot
+    # arrays, and with init_kv_cache(state_snapshots=...) in snapshot
+    # rows beside them.
+    # output_norm: no norm ahead of a sublayer and one RMSNorm on its
+    # output, ahead of the residual (the Olmo 2 block). qk_norm_whole:
+    # RMSNorm with a learned weight over ALL of a "full" layer's
+    # projected q channels, and k's, before the heads are split
+    # (qk_norm is a weight a head over head_dim).
+    delta_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 4
+    delta_neg_eigval: bool = False
+    output_norm: bool = False
+    qk_norm_whole: bool = False
 
     def __post_init__(self):
         # plain JSON hands lists over: the config stays hashable
@@ -253,6 +276,17 @@ class TransformerConfig:
         """Channels through the convolution: x and the group's B and C."""
         return self.ssm_inner + 2 * self.ssm_state
 
+    @property
+    def delta_inner(self) -> int:
+        """Channels the delta rule's values, gate and output run over."""
+        return self.delta_heads * self.delta_value_dim
+
+    @property
+    def delta_conv_width(self) -> int:
+        """Channels through the convolution: every head's q, k and v."""
+        return self.delta_heads * (2 * self.delta_key_dim
+                                   + self.delta_value_dim)
+
     #: the keys of the forms only ``prefill`` / ``decode_step`` implement
     SERVED_KEYS = ("experts_per_token", "qk_norm", "index_topk",
                    "kv_lora_rank", "sandwich_norm", "n_dense_layers",
@@ -261,7 +295,9 @@ class TransformerConfig:
                    "rope_softmax_scale", "gated_norm_rank", "n_group",
                    "topk_group", "router_bias", "ssm_heads",
                    "ssm_head_dim", "ssm_state", "attn_scale", "embed_scale",
-                   "residual_scale", "logit_scale", "tie_embeddings")
+                   "residual_scale", "logit_scale", "tie_embeddings",
+                   "delta_heads", "delta_key_dim", "delta_value_dim",
+                   "delta_neg_eigval", "output_norm", "qk_norm_whole")
 
     @property
     def served_keys(self) -> Tuple[str, ...]:
@@ -313,10 +349,20 @@ class TransformerConfig:
                     total += e * (di + cw + self.ssm_heads) + di * e \
                         + cw * (self.ssm_conv + 1) + 3 * self.ssm_heads \
                         + di + 2 * e
+                elif self.layer_kind(l) == "delta":
+                    # w_qkv, w_g, w_ab, w_out, the taps, A_log, dt_bias,
+                    # the output norm a head wide, the block's two norms
+                    di, cw = self.delta_inner, self.delta_conv_width
+                    total += e * (cw + di + 2 * self.delta_heads) \
+                        + di * e + cw * self.delta_conv \
+                        + 2 * self.delta_heads + self.delta_value_dim \
+                        + 2 * e
                 else:
                     hk = self.kind_heads(self.layer_kind(l))
                     total += 2 * e * hk * self.head_dim + 2 * e * kvh \
-                        + 2 * e + (e * hk if self.head_gate else 0)
+                        + 2 * e + (e * hk if self.head_gate else 0) \
+                        + (hk * self.head_dim + kvh
+                           if self.qk_norm_whole else 0)
                 if l < self.n_dense_layers or not self.experts_per_token:
                     total += 3 * e * self.d_ff
                 else:
@@ -571,15 +617,33 @@ def _check_served_forms(c: TransformerConfig) -> None:
 #: where the tree keeps each kind's layers (all of them, or with
 #: ``n_dense_layers`` those behind ``dense_layers``)
 KIND_STACKS = {"full": "layers", "window": "window_layers",
-               "mamba": "mamba_layers"}
+               "mamba": "mamba_layers", "delta": "delta_layers"}
 
 #: a window layer's pools, beside the full layers' "k" / "v"
 WINDOW_POOLS = ("k_window", "v_window")
 
 #: what the cache holds beside its pools: the "mamba" layers' recurrent
-#: state and their convolution's last inputs, ``[layers, slots, ...]``, a
-#: row a decode slot and no page (:func:`init_kv_cache`)
-STATE_ARRAYS = ("ssm", "conv")
+#: state and their convolution's last inputs, the "delta" layers' the
+#: same, ``[layers, slots, ...]``, a row a decode slot and no page; and
+#: the "delta" layers' snapshot rows, ``[layers, 1 + snapshots, ...]``
+#: (:func:`init_kv_cache`)
+STATE_ARRAYS = ("ssm", "conv", "delta", "delta_conv", "delta_snap",
+                "delta_conv_snap")
+
+
+def state_snapshot_arrays(config: "TransformerConfig") -> Dict[str, str]:
+    """Per-slot state array -> its snapshot rows' array, for every state
+    whose mixer hands it out at a chunk's boundaries (``prefill``'s
+    ``snap_rows``); empty for a model that has none."""
+    return {state.name: state.snap for kind in _layer_plan(config).kinds
+            for state in kind.state if state.snap}
+
+
+def state_counters(config: "TransformerConfig") -> Optional[str]:
+    """The name the engine counts the stack's recurrence under (the
+    layer plan's: "ssm", "delta"); None for a model of pages alone."""
+    return next((kind.counters for kind in _layer_plan(config).kinds
+                 if kind.counters), None)
 
 
 def cache_pools(cache: Dict[str, Any]) -> Dict[str, Any]:
@@ -622,26 +686,33 @@ class _Pool(NamedTuple):
 
 class _State(NamedTuple):
     """One of the cache's per-slot arrays: ``[layers, slots, *shape]``
-    under ``name``; ``dtype`` None is the compute dtype."""
+    under ``name``; ``dtype`` None is the compute dtype. ``snap``: the
+    name of its snapshot rows, ``[layers, 1 + snapshots, *shape]`` (row
+    0 the trash row), for a mixer that hands out its state at a call's
+    boundaries; None for one that does not."""
     name: str
     shape: Tuple[int, ...]
     dtype: Any
+    snap: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
 class _LayerKind:
     """One kind of layer, as the forward pass needs to know it."""
-    name: str                    # "full" | "window" | "mamba"
-    norm: str                    # "layer" (with bias) | "rms" | "gated"
+    name: str                    # "full" | "window" | "mamba" | "delta"
+    # ahead of each sublayer: "layer" (with bias) | "rms" | "gated" |
+    # "none" (the sublayer reads the residual stream as it is)
+    norm: str
     # attention and MLP read the one normed input and are added together
     # (the 'gptj' form) | the MLP follows the attention's residual
     parallel: bool
-    post_norm: bool              # a second RMSNorm on each sublayer's output
-    # "paged": per-head K/V | "latent": MLA rows | "scan": a recurrence
+    post_norm: bool              # an RMSNorm on each sublayer's output
+    # "paged": per-head K/V | "latent": MLA rows | "scan": Mamba-2's
+    # recurrence | "delta": the gated delta rule's
     mixer: str
     heads: int                   # query heads
     window: int                  # keys attended behind a position, 0 = all
-    qk_norm: bool
+    qk_norm: bool                # RMSNorm q and k, a weight a head
     head_gate: bool
     index_topk: int              # keys a learned selection keeps, 0 = all
     # the pools a layer writes (its keys, its values, ``ki`` last where it
@@ -655,6 +726,12 @@ class _LayerKind:
     index_rotary: Optional[_Rotary]      # None: the layer's own
     # what a "scan" layer carries a sequence, a row a slot
     state: Tuple[_State, ...] = ()
+    # RMSNorm q and k over the WHOLE projected width, before the heads
+    # are split
+    qk_norm_whole: bool = False
+    # the name the engine counts this kind's recurrence under
+    # (``<counters>_decode_rows_total`` ...); None: no state, no counter
+    counters: Optional[str] = None
 
 
 class _Run(NamedTuple):
@@ -697,9 +774,18 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                    or c.window_heads):
         raise ValueError("layer_pattern, sliding_window and window_heads "
                          "are forms of per-head K/V, not of a latent cache")
-    if not pattern <= {"full", "window", "mamba"}:
+    if not pattern <= {"full", "window", "mamba", "delta"}:
         raise ValueError(f"layer_pattern {c.layer_pattern}: a layer "
-                         f"is 'full', 'window' or 'mamba'")
+                         f"is 'full', 'window', 'mamba' or 'delta'")
+    if ("delta" in pattern) != bool(c.delta_heads) or c.delta_heads and not (
+            c.delta_key_dim and c.delta_value_dim and c.delta_conv > 1
+            ) or c.delta_heads and c.ssm_heads:
+        raise ValueError("'delta' layers in layer_pattern come with "
+                         "delta_heads, delta_key_dim, delta_value_dim "
+                         "and delta_conv > 1, and not with "
+                         f"'mamba' layers, got {c.layer_pattern} and "
+                         f"{c.delta_heads}, {c.delta_key_dim}, "
+                         f"{c.delta_value_dim}, {c.delta_conv}")
     if ("mamba" in pattern) != bool(c.ssm_heads) or c.ssm_heads and not (
             c.ssm_head_dim and c.ssm_state and c.ssm_conv > 1
             and c.ssm_chunk > 0):
@@ -710,7 +796,9 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                          f"{c.ssm_conv}, {c.ssm_chunk}")
     by_kind_only = [k for k in ("attn_scale", "embed_scale",
                                 "residual_scale", "logit_scale",
-                                "tie_embeddings") if k in c.served_keys]
+                                "tie_embeddings", "output_norm",
+                                "qk_norm_whole", "delta_neg_eigval")
+                    if k in c.served_keys]
     if by_kind_only and not by_kind:
         raise ValueError(f"{', '.join(by_kind_only)}: forms of a stack by "
                          "kind of layer (layer_pattern, n_dense_layers, "
@@ -737,16 +825,34 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
     def kind(name: str) -> _LayerKind:
         window = name == "window"
         common = dict(
-            name=name, norm="layer" if gptj else "gated"
-            if c.gated_norm_rank else "rms", parallel=gptj,
-            post_norm=c.sandwich_norm, qk_norm=c.qk_norm,
+            name=name, norm="layer" if gptj else "none" if c.output_norm
+            else "gated" if c.gated_norm_rank else "rms", parallel=gptj,
+            post_norm=c.sandwich_norm or c.output_norm,
+            qk_norm=c.qk_norm, qk_norm_whole=c.qk_norm_whole,
             head_gate=c.head_gate, index_topk=c.index_topk)
+        if name == "delta":
+            # no page, as a "mamba" layer: the state a head (float32, the
+            # key width ahead of heads and value channels as ONE axis:
+            # ops/delta.py) and the convolution's last raw inputs, a row a
+            # slot; and a snapshot row of each a trie node that has one
+            return _LayerKind(
+                **{**common, "head_gate": False, "qk_norm_whole": False},
+                mixer="delta", heads=c.delta_heads, window=0, pools=(),
+                table="state", scope=None,
+                rotary=_Rotary("neox", 0, c.rope_base, (), True),
+                index_rotary=None, state=(
+                    _State("delta", (c.delta_key_dim, c.delta_inner),
+                           jnp.float32, "delta_snap"),
+                    _State("delta_conv",
+                           (c.delta_conv - 1, c.delta_conv_width), None,
+                           "delta_conv_snap")), counters="delta")
         if name == "mamba":
             # no page: the state a head (float32: the recurrence adds into
             # it at every token) and the convolution's last inputs, a row
             # a slot
             return _LayerKind(
-                **{**common, "head_gate": False}, mixer="scan",
+                **{**common, "head_gate": False, "qk_norm_whole": False},
+                mixer="scan",
                 heads=c.ssm_heads, window=0, pools=(), table="state",
                 scope=None, rotary=_Rotary("neox", 0, c.rope_base, (), True),
                 index_rotary=None, state=(
@@ -755,7 +861,7 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                     # whole array to its own order on the way in and out
                     _State("ssm", (c.ssm_state, c.ssm_inner), jnp.float32),
                     _State("conv", (c.ssm_conv - 1, c.ssm_conv_width),
-                           None)))
+                           None)), counters="ssm")
         if latent:
             # ONE pool, a row a token and layer for every head (the
             # normed latent | the rotated shared key | zeros up to whole
@@ -795,7 +901,8 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
     # that holds no layer of one of them
     kinds = {name: kind(name) for name in
              ("full",) + (("window",) if c.sliding_window else ())
-             + (("mamba",) if c.ssm_heads else ())}
+             + (("mamba",) if c.ssm_heads else ())
+             + (("delta",) if c.delta_heads else ())}
     runs, seen, ordinal = [], {}, dict.fromkeys(kinds, 0)
     for l in range(c.n_layers):
         k, lead = kinds[c.layer_kind(l)], l < c.n_dense_layers
@@ -826,6 +933,16 @@ def _kind_layer_shapes(c: TransformerConfig, kind: str, dense: bool
         di, cw = c.ssm_inner, c.ssm_conv_width
         out = {"w_in": ((e, di + cw + c.ssm_heads), ("embed", "mlp")),
                "conv_w": ((cw, c.ssm_conv), ("mlp", None)),
+               "w_out": ((di, e), ("mlp", "embed"))}
+    elif kind == "delta":
+        # w_qkv's columns: every head's q | k | v, through the
+        # convolution; w_g the output's gate; w_ab the decay's and the
+        # write strength's inputs, a head each
+        di, cw = c.delta_inner, c.delta_conv_width
+        out = {"w_qkv": ((e, cw), ("embed", "mlp")),
+               "w_g": ((e, di), ("embed", "mlp")),
+               "w_ab": ((e, 2 * c.delta_heads), ("embed", None)),
+               "conv_w": ((cw, c.delta_conv), ("mlp", None)),
                "w_out": ((di, e), ("mlp", "embed"))}
     else:
         out = {"wq": ((e, h), ("embed", "heads")),
@@ -884,6 +1001,36 @@ def _mamba_vector_init(c, key, n) -> Dict[str, jnp.ndarray]:
                                            jnp.float32)}
 
 
+def _delta_vector_init(c, key, n) -> Dict[str, jnp.ndarray]:
+    """The float32 leaves of ``n`` "delta" layers, as Gated DeltaNet
+    starts them: ``A = exp(A_log)`` uniform in (0, 16), ``dt_bias`` the
+    inverse softplus of a step log-uniform in 1e-3..1e-1, the output norm
+    (a head wide, shared by the heads) at one."""
+    ka, kd = jax.random.split(key)
+    step = jnp.exp(jax.random.uniform(
+        kd, (n, c.delta_heads), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return {
+        "A_log": jnp.log(jax.random.uniform(ka, (n, c.delta_heads),
+                                            jnp.float32, 1e-4, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "delta_norm": jnp.ones((n, c.delta_value_dim), jnp.float32)}
+
+
+def _kind_vector_shapes(c, kind: str) -> Dict[str, tuple]:
+    """One layer's norm leaves of a stack by kind, all drawn at one: name
+    -> (width, logical axis). The block's two norms, ahead of its
+    sublayers or (``output_norm``) on their outputs, and the whole-width
+    QK-norm's weights of a layer with attention."""
+    e = c.d_model
+    out = {name: (e, "embed") for name in (
+        ("post_attn_norm", "post_mlp_norm") if c.output_norm
+        else ("attn_norm", "mlp_norm"))}
+    if c.qk_norm_whole and kind in ("full", "window"):
+        out.update({"q_norm": (c.kind_heads(kind) * c.head_dim, "heads"),
+                    "k_norm": (c.kv_heads * c.head_dim, "kv")})
+    return out
+
+
 def _init_kind_params(c, key, dtype, out_scale) -> Dict:
     """The tree of a stack by kind of layer: ``dense_layers`` (the
     leading ones), ``layers`` (the "full" layers behind them),
@@ -891,9 +1038,9 @@ def _init_kind_params(c, key, dtype, out_scale) -> Dict:
     leaf drawn a layer at a time into ``dtype``. ``tie_embeddings``: no
     ``lm_head``."""
     keys = list(jax.random.split(jax.random.fold_in(key, 104), 5))
-    # a fourth stack draws from a key of its own: the five above stay
-    # what they were
-    keys.append(jax.random.fold_in(key, 105))
+    # a fourth stack draws from a key of its own, and a fifth: the five
+    # above stay what they were
+    keys += [jax.random.fold_in(key, 105), jax.random.fold_in(key, 106)]
     params = {
         "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
                              dtype=dtype),
@@ -912,10 +1059,14 @@ def _init_kind_params(c, key, dtype, out_scale) -> Dict:
             stack[leaf] = _layered_init(
                 jax.random.fold_in(keys[2 + j], i),
                 scales.get(leaf, 0.02), n, shape, dtype)
-        stack.update({norm: jnp.ones((n, c.d_model), jnp.float32)
-                      for norm in ("attn_norm", "mlp_norm")})
+        stack.update({norm: jnp.ones((n, width), jnp.float32)
+                      for norm, (width, _)
+                      in _kind_vector_shapes(c, kind).items()})
         if kind == "mamba":
             stack.update(_mamba_vector_init(
+                c, jax.random.fold_in(keys[2 + j], 1000), n))
+        if kind == "delta":
+            stack.update(_delta_vector_init(
                 c, jax.random.fold_in(keys[2 + j], 1000), n))
         params[name] = stack
     return params
@@ -929,8 +1080,12 @@ def _kind_logical_axes(c) -> Dict:
     for name, kind, dense, _ in _kind_stacks(c):
         axes[name] = {leaf: ("layers",) + ax for leaf, (_, ax)
                       in _kind_layer_shapes(c, kind, dense).items()}
-        axes[name].update({"attn_norm": ("layers", "embed"),
-                           "mlp_norm": ("layers", "embed")})
+        axes[name].update({norm: ("layers", axis) for norm, (_, axis)
+                           in _kind_vector_shapes(c, kind).items()})
+        if kind == "delta":
+            axes[name].update({
+                "A_log": ("layers", None), "dt_bias": ("layers", None),
+                "delta_norm": ("layers", None)})
         if kind == "mamba":
             axes[name].update({
                 "A_log": ("layers", None), "dt_bias": ("layers", None),
@@ -1111,7 +1266,7 @@ _F32_LEAVES = frozenset(("attn_norm", "mlp_norm", "ln_scale", "ln_bias",
                          "q_norm", "k_norm", "k_idx_scale", "k_idx_bias",
                          "q_a_norm", "kv_a_norm", "post_attn_norm",
                          "post_mlp_norm", "router_bias", "A_log", "dt_bias",
-                         "D", "ssm_norm"))
+                         "D", "ssm_norm", "delta_norm"))
 
 
 def inference_params(config: TransformerConfig, params: Dict) -> Dict:
@@ -1269,12 +1424,15 @@ def _block(c, kind: _LayerKind, x, lp, attend, mlp):
     -> norm -> ``mlp(h) -> (out, moe_aux)`` -> residual, or with
     ``kind.parallel`` both sublayers off the one norm and one residual.
     ``kind`` says which norms (every RMS norm at ``norm_eps``; LayerNorm
-    keeps its own 1e-5, as the final norm does) and whether a second one
-    follows each sublayer; ``lp`` holds the layer's norm leaves. Returns
+    keeps its own 1e-5, as the final norm does; "none": a sublayer reads
+    the stream as it is) and whether one follows each sublayer, ahead of
+    the residual; ``lp`` holds the layer's norm leaves. Returns
     (x, cache, moe_aux)."""
     eps = c.norm_eps
 
     def pre(x, name):
+        if kind.norm == "none":
+            return x
         if kind.norm == "layer":
             return layer_norm(x, lp["ln_scale"], lp["ln_bias"])
         if kind.norm == "gated":
@@ -1605,8 +1763,8 @@ def stage_loss(config: TransformerConfig, stage_params: Dict,
 
 def init_kv_cache(config: TransformerConfig, num_blocks: int,
                   block_size: int, window_blocks: Optional[int] = None,
-                  state_slots: Optional[int] = None
-                  ) -> Dict[str, jnp.ndarray]:
+                  state_slots: Optional[int] = None,
+                  state_snapshots: int = 0) -> Dict[str, jnp.ndarray]:
     """Allocate the paged KV cache: ``{"k", "v"}`` of shape
     ``[n_layers, num_blocks, kv_heads, block_size, head_dim]`` in the
     compute dtype — ``kv_heads`` ahead of ``block_size`` so one head's
@@ -1647,7 +1805,15 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
     size however long the sequence. ``state_slots=None`` is ONE slot:
     enough for a caller that runs one sequence (a batch row b uses slot
     b unless ``state_rows`` says otherwise); an engine asks for its
-    ``decode_slots``.
+    ``decode_slots``. A stack with "delta" layers keeps ``delta``
+    ``[delta layers, state_slots, delta_key_dim, delta_heads *
+    delta_value_dim]`` float32 and ``delta_conv`` ``[delta layers,
+    state_slots, delta_conv - 1, delta_conv_width]`` the same way, and
+    with ``state_snapshots`` > 0 a second array of each,
+    ``delta_snap`` / ``delta_conv_snap`` ``[delta layers, 1 +
+    state_snapshots, ...]``: the state as of a boundary inside a prompt,
+    which :func:`prefill` writes where ``snap_rows`` says (row 0 is the
+    trash row, as page 0 is) and a prefix hit copies into the slot.
 
     Which pools, how wide and of how many layers is the layer
     description's to say (``_layer_plan``: each kind's ``pools``, its
@@ -1666,6 +1832,10 @@ def init_kv_cache(config: TransformerConfig, num_blocks: int,
             cache[state.name] = jnp.zeros(
                 (layers, state_slots or 1) + state.shape,
                 state.dtype or config.dtype)
+            if state_snapshots and state.snap:
+                cache[state.snap] = jnp.zeros(
+                    (layers, 1 + state_snapshots) + state.shape,
+                    state.dtype or config.dtype)
     return cache
 
 
@@ -1755,7 +1925,9 @@ def _paged_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
     """The attention sublayer over per-head K/V of a layer of ``kind``,
     cache layer ``layer`` (an int32 scalar, traced by the layer scan) of
     the kind's pools: project qkv for the new tokens (the kind's head
-    count), RMSNorm q and k a head (``qk_norm``), rotate at their absolute
+    count), RMSNorm q and k (``kind.qk_norm``: a weight a head;
+    ``kind.qk_norm_whole``: one over the whole projected width), rotate
+    at their absolute
     positions, scatter k/v into that layer's pages of the WHOLE 5-D pools
     through the kind's table, then attend against the (now-updated) pages
     by ``(layer, block)``, a window layer's keys masked behind its window;
@@ -1786,6 +1958,12 @@ def _paged_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
         if kind.qk_norm:
             q = rms_norm(q, lp["q_norm"])
             k = rms_norm(k, lp["k_norm"])
+        elif kind.qk_norm_whole:
+            # one weight a channel over ALL of q's and of k's, before
+            # the heads are split
+            q, k = (rms_norm(a.reshape(a.shape[:2] + (-1,)), lp[w],
+                             eps=c.norm_eps).reshape(a.shape)
+                    for a, w in ((q, "q_norm"), (k, "k_norm")))
         if kind.rotary.dim:
             q = apply_rotary(q, sin, cos, positions=positions,
                              layout=kind.rotary.layout)
@@ -1960,8 +2138,94 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
     return out, {**cache, "conv": conv, "ssm": ssm}
 
 
+def _unit(x, eps: float = 1e-6):
+    """``x`` over its length along the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+@jax.named_scope("delta")
+def _delta_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
+                    state_rows, first, positions, write_mask, lens,
+                    snap_rows=None):
+    """The gated-delta-rule mixer of a "delta" layer, state layer
+    ``layer`` of the kind's per-slot arrays (``ops/delta.py`` has the
+    recurrence): project ``h`` to the convolution's inputs ``q | k | v``,
+    the output's gate and a decay and a write strength a head; a causal
+    depthwise convolution (no bias, SiLU) over the new inputs behind the
+    slot's last ``delta_conv - 1``; q and k to unit length a head, q over
+    ``sqrt(delta_key_dim)``; the recurrence from the slot's state, blocked
+    for a chunk or elementwise for one token; an RMSNorm a head wide on
+    each head's output, times ``silu(gate)``, through ``w_out``. The
+    arguments are :func:`_scan_sublayer`'s, and the slot's rows are read
+    and written as there; ``snap_rows [B, n_snaps]`` (None: no snapshot)
+    is the row of the snapshot arrays the state and the tail after each
+    ``C / n_snaps`` tokens of this call are written to, 0 (the trash row)
+    for none. Returns (out, cache)."""
+    from ray_tpu.ops.delta import (BLOCK, conv_tails_at,
+                                   gated_delta_chunk_scan,
+                                   gated_delta_step_slots)
+    from ray_tpu.ops.ssm import causal_conv, put_slot_rows, slot_rows
+    dt_ = c.dtype
+    b, n, _ = h.shape
+    H, dk, dv = c.delta_heads, c.delta_key_dim, c.delta_value_dim
+    live = write_mask & (lens > 0)[:, None]
+    n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
+    fresh = positions[:, 0] == 0
+    snaps = {}
+    every = n // snap_rows.shape[1] if snap_rows is not None else None
+
+    def keep(name, rows):            # [n_snaps, B, ...] -> the rows named
+        return cache[name].at[layer, snap_rows.T].set(
+            rows.astype(cache[name].dtype))
+
+    with jax.named_scope("delta_in_proj"):
+        hd = h.astype(dt_)
+        raw = jnp.dot(hd, lp["w_qkv"].astype(dt_))
+        gate = jnp.dot(hd, lp["w_g"].astype(dt_))
+        ab = jnp.dot(hd, lp["w_ab"].astype(dt_),
+                     preferred_element_type=jnp.float32)
+    with jax.named_scope("delta_conv"):
+        tail_in = slot_rows(cache["delta_conv"], layer, state_rows, b, fresh)
+        qkv, tail = causal_conv(raw, tail_in, lp["conv_w"], None, n_live)
+        conv = put_slot_rows(cache["delta_conv"], layer, state_rows, tail)
+        if every:
+            snaps["delta_conv_snap"] = keep(
+                "delta_conv_snap", conv_tails_at(raw, tail_in, every))
+    with jax.named_scope("delta_scan"):
+        q = _unit(qkv[..., :H * dk].reshape(b, n, H, dk)) * dk ** -0.5
+        k = _unit(qkv[..., H * dk:2 * H * dk].reshape(b, n, H, dk))
+        v = qkv[..., 2 * H * dk:].reshape(b, n, H, dv)
+        g = -jnp.exp(lp["A_log"].astype(jnp.float32)) \
+            * jax.nn.softplus(ab[..., :H] + lp["dt_bias"])
+        beta = jax.nn.sigmoid(ab[..., H:])
+        if c.delta_neg_eigval:
+            beta = 2.0 * beta
+        if n == 1:
+            # the whole array and the layer's index, never a slice of it
+            o, state = gated_delta_step_slots(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                cache["delta"], layer, state_rows, live[:, 0], fresh)
+            o = o[:, None]
+        else:
+            o, rows, at = gated_delta_chunk_scan(
+                q, k, v, g, beta,
+                slot_rows(cache["delta"], layer, state_rows, b, fresh),
+                live, block=math.gcd(BLOCK, every) if every else BLOCK,
+                snap_every=every)
+            state = put_slot_rows(cache["delta"], layer, state_rows, rows)
+            if every:
+                snaps["delta_snap"] = keep("delta_snap", at)
+    with jax.named_scope("delta_out"):
+        o = rms_norm(o, lp["delta_norm"], eps=c.norm_eps) \
+            * jax.nn.silu(gate.astype(jnp.float32)).reshape(b, n, H, dv)
+        out = jnp.dot(o.reshape(b, n, H * dv).astype(dt_),
+                      lp["w_out"].astype(dt_))
+    return out, {**cache, "delta_conv": conv, "delta": state, **snaps}
+
+
 _MIXERS = {"paged": _paged_attn_sublayer, "latent": _latent_attn_sublayer,
-           "scan": _scan_sublayer}
+           "scan": _scan_sublayer, "delta": _delta_sublayer}
 
 
 def _forward_with_cache(c: TransformerConfig, params: Dict,
@@ -1972,7 +2236,8 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                         lens: jnp.ndarray,
                         window_tables: Optional[jnp.ndarray] = None,
                         window_first: Optional[jnp.ndarray] = None,
-                        state_rows: Optional[jnp.ndarray] = None):
+                        state_rows: Optional[jnp.ndarray] = None,
+                        snap_rows: Optional[jnp.ndarray] = None):
     """Shared trunk of :func:`prefill` and :func:`decode_step`:
     (B, C) token ids at absolute ``positions`` -> (B, C, vocab) logits,
     writing each layer's k/v into the paged cache as it goes. ``lens``
@@ -1997,6 +2262,12 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
             "(experts_per_token > 0); Switch top-1 with capacity drops "
             "tokens by the batch they arrive in")
     plan = _layer_plan(c)
+    if snap_rows is not None and not any(
+            state.snap in cache for kind in plan.kinds
+            for state in kind.state):
+        raise ValueError("snap_rows: this cache has no snapshot rows "
+                         "(init_kv_cache(state_snapshots=...), for a "
+                         "model whose recurrent layers hand them out)")
     pools = cache_pools(cache)
     bs = next(iter(pools.values())).shape[3] if pools else 1
     table_len = block_tables.shape[1] * bs
@@ -2042,9 +2313,14 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
                       for k, v in indexed.items()}
 
             def attend(h):
+                # a kind whose state has snapshot rows is told where the
+                # call's boundaries go
+                snap = {"snap_rows": snap_rows} if any(
+                    state.snap for state in kind.state) else {}
                 return _MIXERS[kind.mixer](
                     c, kind, h, lp, rot[kind], layer, cache,
-                    *tables[kind.table], positions, write_mask, lens)
+                    *tables[kind.table], positions, write_mask, lens,
+                    **snap)
 
             def mlp(h):
                 return _mlp_sublayer(c, h, {**lp, **whole}, place)
@@ -2064,7 +2340,8 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
             start_pos: jnp.ndarray, lens: jnp.ndarray,
             window_tables: Optional[jnp.ndarray] = None,
             window_first: Optional[jnp.ndarray] = None,
-            state_rows: Optional[jnp.ndarray] = None):
+            state_rows: Optional[jnp.ndarray] = None,
+            snap_rows: Optional[jnp.ndarray] = None):
     """Process one prompt chunk per sequence, writing cache blocks.
 
     ``tokens``: (B, C) int32 — chunk ``start_pos[b] .. start_pos[b]+
@@ -2088,6 +2365,14 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
     (left out: sequence b's is slot b). A chunk at ``start_pos == 0``
     starts from a zero state whatever its slot held; a later chunk goes
     on from what the chunk before left there.
+
+    ``snap_rows`` ``(B, C // stride)`` int32, for a cache with snapshot
+    rows (``init_kv_cache(state_snapshots=...)``): the snapshot row the
+    recurrent state after each ``stride`` tokens of THIS call is written
+    to, 0 (the trash row) for a boundary that wants none or lies past the
+    row's live tokens. ``start_pos`` is then a multiple of the stride, so
+    that the boundaries are the prompt's own. Left out, the program is
+    the one it would be without snapshots.
     """
     b, chunk = tokens.shape
     positions = start_pos[:, None] + jnp.arange(chunk, dtype=jnp.int32)
@@ -2098,7 +2383,7 @@ def prefill(config: TransformerConfig, params: Dict, tokens: jnp.ndarray,
     return _forward_with_cache(config, params, tokens, cache,
                                block_tables, positions, write_mask,
                                live, window_tables, window_first,
-                               state_rows)
+                               state_rows, snap_rows)
 
 
 def decode_step(config: TransformerConfig, params: Dict,

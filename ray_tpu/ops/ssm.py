@@ -73,14 +73,15 @@ def causal_conv(x, tail, w, b, n_live) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal convolution, then SiLU. ``x [B, T, W]`` the new
     inputs, ``tail [B, K-1, W]`` the inputs just before them (zeros
     before position 0), ``w [W, K]`` (tap ``K-1`` meets the current
-    input), ``b [W]``, ``n_live [B]`` how many of the ``T`` are live.
+    input), ``b [W]`` (None: no bias), ``n_live [B]`` how many of the ``T``
+    are live.
     Returns (``[B, T, W]`` in ``x``'s dtype, the new tail: the last ``K -
     1`` inputs up to the last live one, the old tail's end where fewer
     are live)."""
     k = w.shape[-1]
     t = x.shape[1]
     ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    acc = b.astype(F32)
+    acc = 0.0 if b is None else b.astype(F32)
     for j in range(k):
         acc = acc + ext[:, j:j + t].astype(F32) * w[:, j].astype(F32)
     out = jax.nn.silu(acc).astype(x.dtype)

@@ -101,6 +101,18 @@ class EngineConfig:
       tails, which is what a prefix hit needs of the window layers.
     - ``enable_prefix_sharing``: refcounted radix-trie sharing of full
       prompt KV blocks (prefill skips matched prefixes).
+    - ``state_snapshot_stride`` / ``num_state_snapshots``: for a model
+      with recurrent state (a row a decode slot, no page), what lets
+      the trie serve it: the state as of every ``state_snapshot_stride``
+      tokens of a prompt (a multiple of ``kv_block_size`` that divides
+      ``prefill_chunk``) is kept in one of ``num_state_snapshots``
+      snapshot rows, named by the trie node whose page ends there, and a
+      prefix hit is cut back to the deepest such node and copies its row
+      into the request's slot. Stride 0 = none, and then
+      ``enable_prefix_sharing`` is refused for such a model by name;
+      ``num_state_snapshots`` 0 = one row a stride of the page pool's
+      tokens (what the pages can hold a snapshot for). A property of the
+      cache, not of the model.
     - ``spec_tokens``: draft tokens per slot per decode step via
       prompt-lookup speculation (0 = classic one-token decode).
     - ``spec_ngram``: longest history n-gram tried by the draft lookup.
@@ -118,6 +130,8 @@ class EngineConfig:
     prefill_chunk: int = 32
     num_kv_blocks: int = 0
     num_window_blocks: int = 0
+    state_snapshot_stride: int = 0
+    num_state_snapshots: int = 0
     max_new_tokens: int = 64          # default per-request cap
     eos_token_id: Optional[int] = None
     enable_prefix_sharing: bool = True
@@ -184,6 +198,21 @@ class EngineConfig:
         return sum(p.size * p.dtype.itemsize for name, p in pools.items()
                    if (name in WINDOW_POOLS) == (kind == "window"))
 
+    @property
+    def resolved_state_snapshots(self) -> int:
+        """Snapshot rows: as given, or one a stride of the pool's tokens."""
+        if not self.state_snapshot_stride:
+            return 0
+        return self.num_state_snapshots or (
+            (self.resolved_num_blocks - 1) * self.kv_block_size
+            // self.state_snapshot_stride)
+
+    @property
+    def snapshots_per_chunk(self) -> int:
+        """Boundaries of the snapshot stride inside one chunk's call."""
+        return self.prefill_chunk // self.state_snapshot_stride \
+            if self.state_snapshot_stride else 0
+
     @staticmethod
     def state_bytes_per_slot(model_config) -> int:
         """Bytes one decode slot's recurrent state takes, over all layers
@@ -229,13 +258,17 @@ def _step_fns(model_config, ec: EngineConfig):
     recurrent state (``state_bytes_per_slot`` > 0) has one column more at
     the end of a PREFILL row: the request's decode slot, where its state
     lives; a decode row needs none (row i is slot i), and a row staged
-    with no sequence leaves its slot's state as it was."""
+    with no sequence leaves its slot's state as it was. With
+    ``state_snapshot_stride`` a PREFILL row has ``prefill_chunk //
+    stride`` columns more ahead of the slot: the snapshot row each
+    boundary of the call is written to (0: none)."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.models import decode_step, prefill
     capture = ec.capture_logprobs
     T = ec.blocks_per_seq
     slotted = bool(ec.state_bytes_per_slot(model_config))
+    n_snap = ec.snapshots_per_chunk if slotted else 0
 
     def tables(bt, chunk=False):
         """(table, the window layers' arguments, the state's) out of a
@@ -246,6 +279,8 @@ def _step_fns(model_config, ec: EngineConfig):
         state = {}
         if slotted and chunk:
             bt, state = bt[:, :-1], {"state_rows": bt[:, -1]}
+            if n_snap:
+                bt, state["snap_rows"] = bt[:, :-n_snap], bt[:, -n_snap:]
         if not ec.window_blocks_per_seq(model_config):
             return bt, (), state
         return bt[:, :T], (bt[:, T + 1:], bt[:, T]), state
@@ -418,16 +453,52 @@ class LLMEngine:
         # transformer.py init_kv_cache). What shares, ships or rolls back
         # a prefix would have to move a state with it, and nothing does
         self._state_bytes = ec.state_bytes_per_slot(model_config)
+        # ... but for snapshots at a fixed stride, which let the trie
+        # share a prefix: (state array, its snapshot rows) by name
+        from ray_tpu.models.transformer import (state_counters,
+                                                state_snapshot_arrays)
+        self._state_counters = state_counters(model_config)
+        self._snap_arrays = state_snapshot_arrays(model_config) \
+            if ec.state_snapshot_stride else {}
+        self._snap_stride = ec.state_snapshot_stride
+        if self._snap_stride:
+            if not self._snap_arrays:
+                raise NotImplementedError(
+                    f"state_snapshot_stride {self._snap_stride}: no layer "
+                    f"of this model hands out its recurrent state at a "
+                    f"chunk's boundaries (a 'delta' layer does; a 'mamba' "
+                    f"layer's scan does not yet, and a model of pages "
+                    f"alone has no state)")
+            if Tw:
+                raise NotImplementedError(
+                    f"state_snapshot_stride {self._snap_stride} is not "
+                    f"served with window layers (sliding_window="
+                    f"{self._window}): a hit would have to bring a "
+                    f"snapshot AND a window tail, and no match cuts to "
+                    f"both")
+            if self._snap_stride % ec.kv_block_size \
+                    or ec.prefill_chunk % self._snap_stride \
+                    or ec.resolved_state_snapshots \
+                    < ec.snapshots_per_chunk:
+                raise ValueError(
+                    f"state_snapshot_stride {self._snap_stride}: a "
+                    f"multiple of kv_block_size {ec.kv_block_size} that "
+                    f"divides prefill_chunk {ec.prefill_chunk}, with "
+                    f"{ec.resolved_state_snapshots} snapshot rows >= the "
+                    f"{ec.snapshots_per_chunk} boundaries of one chunk")
+        self._ssm_step_impl = None
         if self._state_bytes:
-            from ray_tpu.ops.ssm import step_choice
-            # what a decode step's state update runs as (ops/ssm.py): the
-            # kernel, or the plain form where the shapes or the platform
-            # rule it out
-            self._ssm_step_impl = step_choice(
-                model_config.paged_impl, model_config.ssm_state,
-                model_config.ssm_inner)
+            if model_config.ssm_heads:
+                from ray_tpu.ops.ssm import step_choice
+                # what a decode step's state update runs as (ops/ssm.py):
+                # the kernel, or the plain form where the shapes or the
+                # platform rule it out
+                self._ssm_step_impl = step_choice(
+                    model_config.paged_impl, model_config.ssm_state,
+                    model_config.ssm_inner)
             for name, on in (("enable_prefix_sharing",
-                              ec.enable_prefix_sharing),
+                              ec.enable_prefix_sharing
+                              and not self._snap_stride),
                              ("spec_tokens > 0", ec.spec_tokens > 0)):
                 if on:
                     raise NotImplementedError(self._state_refusal(name))
@@ -472,7 +543,9 @@ class LLMEngine:
             model_config, ec.resolved_num_blocks, ec.kv_block_size,
             *([ec.resolved_window_blocks(model_config)] if Tw else []),
             **({"state_slots": ec.decode_slots} if self._state_bytes
-               else {}))
+               else {}),
+            **({"state_snapshots": ec.resolved_state_snapshots}
+               if self._snap_stride else {}))
 
         S, T = ec.decode_slots, ec.blocks_per_seq
         self._np = np
@@ -509,7 +582,9 @@ class LLMEngine:
             ec.resolved_window_blocks(model_config)) if Tw else None
         self._pool = PrefixBlockPool(
             ec.resolved_num_blocks, ec.kv_block_size, reserved=(0,),
-            window_pool=self._wpool, window=self._window)
+            window_pool=self._wpool, window=self._window,
+            snapshot_stride=self._snap_stride,
+            num_snapshots=ec.resolved_state_snapshots)
 
         # jit once at the fixed shapes; caches are donated so XLA
         # updates them in place step over step: the trunk carries the
@@ -550,6 +625,22 @@ class LLMEngine:
                                 in cache_pools(cache).items()}}
 
         self._jit_copy = jax.jit(_copy_fn, donate_argnums=(0,))
+
+        # a prefix hit of a model with recurrent state: snapshot row
+        # ``row`` of every state array into slot ``slot``, all layers;
+        # traced scalars, one compiled program
+        snap_arrays = self._snap_arrays
+
+        @jax.named_scope("state_copy")
+        def _snap_copy_fn(cache, row, slot):
+            return {**cache, **{
+                name: jax.lax.dynamic_update_slice_in_dim(
+                    cache[name], jax.lax.dynamic_slice_in_dim(
+                        cache[snap], row, 1, axis=1), slot, axis=1)
+                for name, snap in snap_arrays.items()}}
+
+        self._jit_snap_copy = jax.jit(_snap_copy_fn, donate_argnums=(0,)) \
+            if snap_arrays else None
 
         # disaggregated hand-off block I/O (serve/disagg.py): gather
         # pulls a request's blocks into one contiguous slab a pool for
@@ -668,6 +759,11 @@ class LLMEngine:
         # tokens and sequence-calls through the chunk programs
         self._ssm = dict.fromkeys(("decode_rows", "prefill_tokens",
                                    "prefill_calls"), 0)
+        # admissions resumed from a snapshot, the pages the trie matched
+        # for a model with state, and those of them recomputed because
+        # no snapshot stood at their end
+        self._snap_hits = dict.fromkeys(("hits", "matched_blocks",
+                                         "cut_blocks"), 0)
         # the same for the paged kernel's innermost grid axis: it folds
         # P pages of a sequence a grid step, so a decode call takes
         # slots x ceil(T/P) steps of which sum(ceil(pages/P)) have a
@@ -1148,12 +1244,15 @@ class LLMEngine:
                 f"window layers' rows behind it would not be exact")
 
     def _state_refusal(self, what: str) -> str:
+        kept = "nothing keeps" if not self._snap_stride else \
+            "the snapshot rows keep for the trie alone"
         return (f"{what} is not served with recurrent state (a model "
-                f"with 'mamba' layers: {self._state_bytes} B a decode "
-                f"slot): a sequence's state is one row of its slot, as of "
-                f"its last token; a shared, shipped or rolled-back prefix "
-                f"would need the state as of the prefix's end, which "
-                f"nothing keeps")
+                f"with 'mamba' or 'delta' layers: {self._state_bytes} B a "
+                f"decode slot): a sequence's state is one row of its "
+                f"slot, as of its last token; a shared, shipped or "
+                f"rolled-back prefix would need the state as of the "
+                f"prefix's end, which {kept} (state_snapshot_stride "
+                f"{self._snap_stride})")
 
     def _refuse_with_state(self, what: str) -> None:
         """The hand-off and the warm-prefix migration move pages: a
@@ -1212,6 +1311,8 @@ class LLMEngine:
         speculation on, decode steps go through ``verify`` and the
         plain ``decode`` program is never called."""
         progs = {"prefill": self._jit_prefill, "copy": self._jit_copy}
+        if self._jit_snap_copy is not None:
+            progs["state_copy"] = self._jit_snap_copy
         # the hand-off's, refused with window layers and with state
         if not (self._window_table or self._state_bytes):
             progs.update(gather=self._jit_gather,
@@ -1228,6 +1329,9 @@ class LLMEngine:
         written back is zeros, so no live block changes."""
         np, jnp = self._np, self._jnp
         zero = np.int32(0)
+        if self._jit_snap_copy is not None:
+            # the trash row into slot 0, which holds no sequence yet
+            self._cache = self._jit_snap_copy(self._cache, zero, zero)
         if self._window_table or self._state_bytes:
             # no hand-off: the copy alone
             self._cache = self._jit_copy(
@@ -1291,6 +1395,7 @@ class LLMEngine:
             self._pages_live = dict.fromkeys(self._pages_live, 0)
             self._window_pinned_max = 0
             self._ssm = dict.fromkeys(self._ssm, 0)
+            self._snap_hits = dict.fromkeys(self._snap_hits, 0)
             self._sparse.clear()
             self._prompt_blocks_total = 0
             self._occupancy.clear()
@@ -1503,23 +1608,45 @@ class LLMEngine:
         held); none for a model that keeps pages and nothing else."""
         if not self._state_bytes:
             return {}
-        return {
+        out = {
             # a slot's state is its decode slot's row: as many as slots,
             # each this many bytes over all layers, whatever the length
             "state_slots_total": self.config.decode_slots,
             "state_bytes_per_slot": self._state_bytes,
-            # rows with a sequence the decode steps updated (one layer's
-            # count), summed over steps; live tokens and sequence-calls
-            # through the chunk programs
-            "ssm_decode_rows_total": self._ssm["decode_rows"],
+        }
+        # rows with a sequence the decode steps updated (one layer's
+        # count), summed over steps; live tokens and sequence-calls
+        # through the chunk programs: under the name the layer plan gives
+        # the recurrence
+        kind = self._state_counters
+        out.update({f"{kind}_decode_rows_total": self._ssm["decode_rows"],
+                    f"{kind}_prefill_tokens_total":
+                        self._ssm["prefill_tokens"],
+                    f"{kind}_prefill_calls_total":
+                        self._ssm["prefill_calls"]})
+        if self._ssm_step_impl is not None:
             # ... and how many of them the kernel updated: all, or none
             # where the step fell back to XLA's two passes
-            "ssm_step_impl": self._ssm_step_impl,
-            "ssm_kernel_rows_total": self._ssm["decode_rows"]
-            if self._ssm_step_impl != "reference" else 0,
-            "ssm_prefill_tokens_total": self._ssm["prefill_tokens"],
-            "ssm_prefill_calls_total": self._ssm["prefill_calls"],
-        }
+            out.update({
+                "ssm_step_impl": self._ssm_step_impl,
+                "ssm_kernel_rows_total": self._ssm["decode_rows"]
+                if self._ssm_step_impl != "reference" else 0})
+        if self._snap_stride:
+            ps = self._pool.stats()
+            out.update({
+                # gauges: the snapshot rows and those a trie node names;
+                # counters: rows attached to a node, rows taken from one
+                # (for another's call, or with the node's eviction)
+                "state_snapshots_total": ps["snapshots_total"],
+                "state_snapshots_live": ps["snapshots_live"],
+                "state_snapshots_taken_total": ps["snapshots_taken_total"],
+                "state_snapshots_evicted_total":
+                    ps["snapshots_evicted_total"],
+                "state_hits_total": self._snap_hits["hits"],
+                "state_matched_blocks_total":
+                    self._snap_hits["matched_blocks"],
+                "state_cut_blocks_total": self._snap_hits["cut_blocks"]})
+        return out
 
     def pool_audit(self) -> List[str]:
         """Block-accounting integrity check (leak regression tests):
@@ -1812,8 +1939,15 @@ class LLMEngine:
                 # with it, and whether a missing one cut the hit short
                 wtail: Dict[int, int] = {}
                 cut = False
+                # recurrent state: the snapshot row a hit resumes from,
+                # and the pages matched beyond it (recomputed)
+                snap_row = cut_blocks = 0
                 if ec.enable_prefix_sharing:
-                    if self._wpool is None:
+                    if self._snap_stride:
+                        matched, mtok, req.trie_node, snap_row, \
+                            cut_blocks = self._pool.match_prefix_state(
+                                req.prompt)
+                    elif self._wpool is None:
                         matched, mtok, req.trie_node = \
                             self._pool.match_prefix(req.prompt)
                     else:
@@ -1856,6 +1990,10 @@ class LLMEngine:
                     (1 if cow_src is not None else 0)
                 self._prefix_hits_cut += cut
                 self._prefix_hits += bool(cut or req.hit_blocks)
+                self._snap_hits["hits"] += bool(snap_row)
+                self._snap_hits["matched_blocks"] += len(matched) \
+                    + cut_blocks
+                self._snap_hits["cut_blocks"] += cut_blocks
                 self._pool.count_hits(req.hit_blocks)
                 req.trie_cursor = req.hit_blocks
                 req.prefill_pos = (plen - 1) if cow_src is not None \
@@ -1884,8 +2022,15 @@ class LLMEngine:
                                    cow=cow_src is not None)
                     self._slo.observe_queue(req.trace,
                                             req.queue_wait_s)
-            # device-side CoW copy OUTSIDE the lock (the step thread is
-            # the only device user; submit/cancel stay responsive)
+            # device-side copies OUTSIDE the lock (the step thread is the
+            # only device user; submit/cancel stay responsive). The
+            # snapshot goes into the slot ahead of the request's first
+            # chunk, and ahead of any later call that could be handed the
+            # row: the device runs its programs in the order of dispatch
+            if snap_row:
+                self._cache = self._jit_snap_copy(
+                    self._cache, self._np.int32(snap_row),
+                    self._np.int32(req.slot))
             if cow_src is not None:
                 ids = [cow_src, cow_dst] + list(wcow or ())
                 self._cache = self._jit_copy(
@@ -2160,13 +2305,17 @@ class LLMEngine:
         n = min(C, len(req.prompt) - start)
         with clock.phase("engine.prefill.stage"):
             slotted = bool(self._state_bytes)
-            row = np.zeros((1, C + self._slot_rows.shape[1] + slotted),
+            row = np.zeros((1, C + self._slot_rows.shape[1] + slotted
+                            + (ec.snapshots_per_chunk if slotted else 0)),
                            np.int32)
             row[0, :n] = req.prompt[start:start + n]
             row[0, C:C + 2] = start, n
             row[0, C + 2:C + 2 + len(req.blocks)] = req.blocks
+            snaps: Dict[int, int] = {}    # boundary position -> row
             if slotted:
                 row[0, -1] = req.slot     # where its state lives
+                if self._snap_stride and req.trie_node is not None:
+                    snaps = self._stage_snapshots(req, start, n, row)
             if self._wpool is not None:
                 with self._lock, clock.phase("engine.window.release"):
                     row[0, C + 2 + ec.blocks_per_seq:
@@ -2208,11 +2357,28 @@ class LLMEngine:
             else:
                 tok, self._cache = out
                 lp = None
-        return req, start, n, t0, t0w, tok, lp
+        return req, start, n, t0, t0w, tok, lp, snaps
+
+    def _stage_snapshots(self, req: _Request, start: int, n: int, row
+                         ) -> Dict[int, int]:
+        """Name, in a chunk's staged ``row``, the snapshot row of each
+        boundary of the stride among the call's ``n`` live tokens (the
+        columns ahead of the slot's; a boundary past them stays 0, the
+        trash row). Returns boundary position -> row, for the booking to
+        attach to the trie's nodes."""
+        stride, per = self._snap_stride, self.config.snapshots_per_chunk
+        ends = [start + (j + 1) * stride for j in range(per)
+                if (j + 1) * stride <= n]
+        with self._lock:
+            got = self._pool.take_snapshot_rows(len(ends))
+        snaps = dict(zip(ends, got))
+        for pos, r in snaps.items():
+            row[0, -1 - per + (pos - start) // stride - 1] = r
+        return snaps
 
     def _finish_chunk(self, chunk: tuple) -> None:
         """Fetch a launched chunk's result and book it."""
-        req, start, n, t0, t0w, tok, lp = chunk
+        req, start, n, t0, t0w, tok, lp, snaps = chunk
         np = self._np
         clock = self._clock
         ready = tok.is_ready()
@@ -2222,12 +2388,15 @@ class LLMEngine:
                 lp = np.asarray(lp)
         self._prefill_wall_s += self._program_wall("prefill", t0, ready)
         with clock.phase("engine.prefill.book"):
-            self._book_prefill(req, start, n, t0w, tok, lp)
+            self._book_prefill(req, start, n, t0w, tok, lp, snaps)
 
     def _book_prefill(self, req: _Request, start: int, n: int,
-                      t0w: float, tok, lp) -> None:
-        """After a chunk: the trie's new blocks and, at the prompt's
-        end, the first token (or the hand-off)."""
+                      t0w: float, tok, lp,
+                      snaps: Optional[Dict[int, int]] = None) -> None:
+        """After a chunk: the trie's new blocks (and the snapshot rows
+        the call wrote, ``snaps``: boundary position -> row, each to the
+        node whose page ends there) and, at the prompt's end, the first
+        token (or the hand-off)."""
         ec = self.config
         req.prefill_pos += n
         req.n_chunks += 1
@@ -2254,6 +2423,13 @@ class LLMEngine:
                         req.wpages.get(i))
                     req.trie_node = node   # None = parent evicted: stop
                     req.trie_cursor += 1
+                    if snaps and (i + 1) * ec.kv_block_size in snaps:
+                        self._pool.attach_snapshot(
+                            node, snaps.pop((i + 1) * ec.kv_block_size))
+        if snaps:
+            # no node took them (the request stopped indexing)
+            with self._lock:
+                self._pool.return_snapshot_rows(list(snaps.values()))
         if req.prefill_pos < len(req.prompt):
             return
         # prompt fully cached: the final chunk's last logits give the

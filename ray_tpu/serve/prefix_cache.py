@@ -46,6 +46,18 @@ and its full page in place. A prefix hit must be exact, so
 ``match_prefix`` stops at the deepest page boundary ``P`` whose window
 pages covering ``[P - window, P)`` are all there, or at none.
 
+State snapshots (a model with recurrent state): a layer that carries a
+state and no page gives a prefix hit nothing to point at, so the engine
+keeps the state AS OF a boundary in snapshot rows beside the pools, one
+row a trie node whose page ends on a multiple of a fixed stride
+(``node.snap``; row 0 is the trash row). The rows are a third thing the
+pool hands out: a free list, a row's node, and eviction of the least
+recently touched node's snapshot when a chunk's call needs rows and none
+is free (the node and its page stay; a node evicted from the trie gives
+its row back). A hit must bring a whole state, so
+:meth:`PrefixBlockPool.match_prefix_state` cuts the match back to the
+deepest node with a snapshot.
+
 Thread model: the pool is NOT internally locked — the engine calls it
 with its scheduler lock held (all mutations happen on the step
 thread).
@@ -81,7 +93,7 @@ class _TrieNode:
     physical block holding that chunk's KV."""
 
     __slots__ = ("children", "parent", "key", "block", "touch",
-                 "detached", "hits", "wblock")
+                 "detached", "hits", "wblock", "snap")
 
     def __init__(self, parent: Optional["_TrieNode"],
                  key: Optional[tuple], block: Optional[int]):
@@ -93,6 +105,7 @@ class _TrieNode:
         self.detached = False   # evicted — inserts under it must abort
         self.hits = 0           # prefix-match count (migration floor)
         self.wblock: Optional[int] = None   # the window layers' page
+        self.snap: Optional[int] = None     # the state's snapshot row
 
 
 class WindowPagePool:
@@ -247,10 +260,24 @@ class PrefixBlockPool:
     def __init__(self, num_blocks: int, block_size: int,
                  reserved: Sequence[int] = (0,),
                  window_pool: Optional[WindowPagePool] = None,
-                 window: int = 0):
+                 window: int = 0, snapshot_stride: int = 0,
+                 num_snapshots: int = 0):
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
+        if snapshot_stride % block_size or snapshot_stride < 0:
+            raise ValueError(f"snapshot_stride {snapshot_stride}: whole "
+                             f"pages of {block_size} tokens")
         self.block_size = block_size
+        # a recurrent state's snapshot rows: a node whose page ends on a
+        # multiple of the stride may name one (rows 1..num_snapshots)
+        self.snapshot_stride = snapshot_stride
+        self.num_snapshots = num_snapshots if snapshot_stride else 0
+        self._snap_free: "collections.deque[int]" = collections.deque(
+            range(1, self.num_snapshots + 1))
+        self._snap_node: Dict[int, _TrieNode] = {}    # live rows
+        self._snap_out: set = set()        # handed to a call, not booked
+        self.snapshots_taken_total = 0
+        self.snapshots_evicted_total = 0
         # the window layers' pages and the positions a query sees back
         self.window_pool = window_pool
         self.window = window
@@ -366,6 +393,82 @@ class PrefixBlockPool:
             self.window_pool.incref(wblock)
         return self._take(path[:keep]) + (tail, keep < len(path))
 
+    def match_prefix_state(self, tokens: Sequence[int]):
+        """:meth:`match_prefix` for a model with recurrent state: a hit
+        resumes from a whole state or it is not taken, so the match is
+        cut back to the deepest node that has a snapshot, strictly short
+        of the prompt's last token (its logits come from a chunk's
+        call). Returns ``(blocks, matched_tokens, node, snap_row,
+        cut_blocks)``: ``snap_row`` the row to copy into the slot (0: a
+        miss, ``blocks`` empty), ``cut_blocks`` the pages the trie
+        matched beyond the cut, which are recomputed."""
+        path = self._walk(tokens)
+        keep = len(path)
+        while keep and (path[keep - 1].snap is None
+                        or keep * self.block_size >= len(tokens)):
+            keep -= 1
+        row = path[keep - 1].snap if keep else 0
+        return self._take(path[:keep]) + (row, len(path) - keep)
+
+    # ------------------------------------------------------- snapshots
+    def take_snapshot_rows(self, n: int) -> List[int]:
+        """Up to ``n`` rows for a chunk's call to write, the caller's
+        until :meth:`attach_snapshot` or :meth:`return_snapshot_rows`:
+        free ones, then those of the least recently touched nodes (the
+        node keeps its page and loses its snapshot). Fewer than ``n``
+        where every row is out with a call."""
+        got: List[int] = []
+        while len(got) < n:
+            if self._snap_free:
+                row = self._snap_free.popleft()
+            elif self._snap_node:
+                row, node = min(self._snap_node.items(),
+                                key=lambda kv: kv[1].touch)
+                del self._snap_node[row]
+                node.snap = None
+                self.snapshots_evicted_total += 1
+            else:
+                break
+            self._snap_out.add(row)
+            got.append(row)
+        return got
+
+    def attach_snapshot(self, node: Optional[_TrieNode], row: int) -> bool:
+        """``node`` (the page that ends on the boundary ``row`` holds the
+        state of: a multiple of the stride, which the caller sees to and
+        :meth:`audit` checks) names the row from now on; the row goes
+        back to the free list where the node is gone or already has
+        one."""
+        self._snap_out.discard(row)
+        if node is None or node.detached or node.snap is not None:
+            self._snap_free.append(row)
+            return False
+        node.snap = row
+        self._snap_node[row] = node
+        self.snapshots_taken_total += 1
+        return True
+
+    def return_snapshot_rows(self, rows: Sequence[int]) -> None:
+        for row in rows:
+            self._snap_out.discard(row)
+            self._snap_free.append(row)
+
+    def _forget_snapshot(self, node: _TrieNode) -> None:
+        """``node`` leaves the trie: its row is free again."""
+        if node.snap is not None:
+            del self._snap_node[node.snap]
+            self._snap_free.append(node.snap)
+            node.snap = None
+            self.snapshots_evicted_total += 1
+
+    @staticmethod
+    def _depth(node: _TrieNode) -> int:
+        """Pages from the root down to ``node``, its own included."""
+        n = 0
+        while node.parent is not None:
+            n, node = n + 1, node.parent
+        return n
+
     def _walk(self, tokens: Sequence[int]) -> List[_TrieNode]:
         node = self._root
         path: List[_TrieNode] = []
@@ -459,6 +562,7 @@ class PrefixBlockPool:
         self.evictions_total += 1
         if self.window_pool is not None:
             self.window_pool.forget(node)
+        self._forget_snapshot(node)
         return True
 
     # ------------------------------------------------------ insertion
@@ -565,6 +669,10 @@ class PrefixBlockPool:
             "hits_total": self.hits_total,
             "inserts_total": self.inserts_total,
             "evictions_total": self.evictions_total,
+            "snapshots_total": self.num_snapshots,
+            "snapshots_live": len(self._snap_node),
+            "snapshots_taken_total": self.snapshots_taken_total,
+            "snapshots_evicted_total": self.snapshots_evicted_total,
         }
 
     def audit(self) -> List[str]:
@@ -632,6 +740,26 @@ class PrefixBlockPool:
         if list(self._root_fps) != sorted(self._root.children.values(),
                                           key=lambda n: n.touch):
             problems.append("root children out of touch order")
+        # snapshot rows: free, live (one node each) or out with a call
+        free_rows, live, out = set(self._snap_free), set(self._snap_node), \
+            self._snap_out
+        if len(free_rows) != len(self._snap_free) or free_rows & live \
+                or free_rows & out or live & out or free_rows | live | out \
+                != set(range(1, self.num_snapshots + 1)):
+            problems.append(
+                f"snapshot rows: free {sorted(free_rows)}, live "
+                f"{sorted(live)}, out {sorted(out)} of "
+                f"{self.num_snapshots}")
+        named = {n.snap: n for n in self._node_of.values()
+                 if n.snap is not None}
+        if named != self._snap_node:
+            problems.append(
+                f"snapshot rows the trie's nodes name {sorted(named)} "
+                f"and the live rows {sorted(live)} disagree")
+        for row, node in self._snap_node.items():
+            if self._depth(node) * self.block_size % self.snapshot_stride:
+                problems.append(f"snapshot row {row} on a node off the "
+                                f"stride")
         if self.window_pool is not None:
             problems += self.window_pool.audit()
             held = {n.wblock for n in self._node_of.values()

@@ -608,3 +608,72 @@ def test_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip,
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("entry,batch", [
+    ("decode_step", 1), ("decode_step", 16), ("prefill", 1)])
+def test_compiled_step_updates_the_delta_state_in_place(
+        entry, batch, one_chip, monkeypatch):
+    """A stack with "delta" layers at the published widths of the mixer
+    (30 heads, keys of 96 and values of 192, blocks of 64: a key width
+    that is no multiple of the 128 lanes) and a narrow model around them,
+    two periods of four layers in the output-normed block with the
+    whole-width QK-norm: the step programs compile for a described v5e,
+    a decode step of ONE row among them and a chunk's call that writes
+    four snapshot rows, and the per-slot state ``[layers, slots, 96, 30 x
+    192]`` and its snapshot rows are updated where they lie: the
+    program's temporaries are a small part of them."""
+    from ray_tpu.models import (TransformerConfig, decode_step,
+                                inference_params, init_kv_cache,
+                                init_params, prefill)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=512, n_layers=8, n_heads=4, n_kv_heads=4,
+        head_dim=128, d_ff=256, max_seq_len=2048, rotary_dim=0,
+        block_style="llama", dtype=jnp.bfloat16, remat_policy="none",
+        paged_impl="kernel", norm_eps=1e-6,
+        layer_pattern=["delta", "delta", "delta", "full"],
+        delta_heads=30, delta_key_dim=96, delta_value_dim=192,
+        delta_neg_eigval=True, output_norm=True, qk_norm_whole=True)
+    slots, table, chunk, snapshots = 16, 128, 2048, 96
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    try:
+        params = shaped(jax.eval_shape(lambda: inference_params(
+            cfg, init_params(cfg, jax.random.PRNGKey(0), dtype=cfg.dtype))))
+        cache = shaped(jax.eval_shape(lambda: init_kv_cache(
+            cfg, 1 + slots * table, BLOCK, state_slots=slots,
+            state_snapshots=snapshots)))
+        if entry == "decode_step":
+            fn = functools.partial(decode_step, cfg)
+            args = (params, i32(batch), cache, i32(batch, table), i32(batch))
+        else:
+            fn = functools.partial(prefill, cfg)
+            args = (params, i32(1, chunk), cache, i32(1, table), i32(1),
+                    i32(1), None, None, i32(1), i32(1, 4))
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert cache["delta"].shape == (6, slots, 96, 5760)
+    assert cache["delta_snap"].shape == (6, 1 + snapshots, 96, 5760)
+    state = 6 * (slots + 1 + snapshots) * 96 * 5760 * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state
+    # a chunk's call holds its 2,048 tokens' float32 q, k, v, the blocks'
+    # right-hand sides and solves (32 x 30 of [64, 96 + 192], twice) and
+    # what the scan over blocks reads (0.47 GB: as much at the cell's own
+    # widths, where the snapshot rows alone are 2.6 GB); a decode step a
+    # few rows' states
+    limit = state // 2 if entry == "prefill" else state // 32
+    assert memory.temp_size_in_bytes < limit, memory.temp_size_in_bytes
+    assert "tpu_custom_call" in compiled.as_text()       # the paged layers
