@@ -2205,7 +2205,8 @@ def _delta_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
             # the whole array and the layer's index, never a slice of it
             o, state = gated_delta_step_slots(
                 q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                cache["delta"], layer, state_rows, live[:, 0], fresh)
+                cache["delta"], layer, state_rows, live[:, 0], fresh,
+                impl=c.paged_impl)
             o = o[:, None]
         else:
             o, rows, at = gated_delta_chunk_scan(

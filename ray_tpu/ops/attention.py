@@ -46,8 +46,9 @@ _dispatch: Dict[tuple, int] = collections.Counter()
 
 def dispatch_log() -> List[Dict[str, object]]:
     """Every attention dispatch decision this process has traced:
-    ``{"op": "flash"|"paged"|"latent"|"ssm_step" (the one-token state
-    update, ``ops/ssm.py``), "impl": "kernel"|"interpret"|"reference",
+    ``{"op": "flash"|"paged"|"latent"|"ssm_step"|"delta_step" (the
+    one-token state updates, ``ops/ssm.py`` and ``ops/delta.py``),
+    "impl": "kernel"|"interpret"|"reference",
     "why": ..., "count": n}``. ``why`` is ``"auto"`` or ``"requested"``
     for a kernel, and for a reference either ``"requested"`` or the
     shape rule (or platform) that ruled the kernel out."""

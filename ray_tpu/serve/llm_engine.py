@@ -486,16 +486,21 @@ class LLMEngine:
                     f"divides prefill_chunk {ec.prefill_chunk}, with "
                     f"{ec.resolved_state_snapshots} snapshot rows >= the "
                     f"{ec.snapshots_per_chunk} boundaries of one chunk")
-        self._ssm_step_impl = None
+        self._state_step_impl = None
         if self._state_bytes:
+            # what a decode step's state update runs as (ops/ssm.py,
+            # ops/delta.py): the kernel, or the plain form where the
+            # shapes or the platform rule it out
             if model_config.ssm_heads:
                 from ray_tpu.ops.ssm import step_choice
-                # what a decode step's state update runs as (ops/ssm.py):
-                # the kernel, or the plain form where the shapes or the
-                # platform rule it out
-                self._ssm_step_impl = step_choice(
+                self._state_step_impl = step_choice(
                     model_config.paged_impl, model_config.ssm_state,
                     model_config.ssm_inner)
+            elif model_config.delta_heads:
+                from ray_tpu.ops.delta import step_choice
+                self._state_step_impl = step_choice(
+                    model_config.paged_impl, model_config.delta_key_dim,
+                    model_config.delta_heads, model_config.delta_value_dim)
             for name, on in (("enable_prefix_sharing",
                               ec.enable_prefix_sharing
                               and not self._snap_stride),
@@ -1624,13 +1629,13 @@ class LLMEngine:
                         self._ssm["prefill_tokens"],
                     f"{kind}_prefill_calls_total":
                         self._ssm["prefill_calls"]})
-        if self._ssm_step_impl is not None:
+        if self._state_step_impl is not None:
             # ... and how many of them the kernel updated: all, or none
-            # where the step fell back to XLA's two passes
+            # where the step fell back to XLA's plain form
             out.update({
-                "ssm_step_impl": self._ssm_step_impl,
-                "ssm_kernel_rows_total": self._ssm["decode_rows"]
-                if self._ssm_step_impl != "reference" else 0})
+                f"{kind}_step_impl": self._state_step_impl,
+                f"{kind}_kernel_rows_total": self._ssm["decode_rows"]
+                if self._state_step_impl != "reference" else 0})
         if self._snap_stride:
             ps = self._pool.stats()
             out.update({

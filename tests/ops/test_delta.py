@@ -8,6 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import ray_tpu.ops.delta as delta_ops
+from ray_tpu.ops.attention import dispatch_log
 from ray_tpu.ops.delta import (conv_tails_at, from_heads,
                                gated_delta_chunk_scan, gated_delta_step,
                                gated_delta_step_slots, to_heads)
@@ -182,6 +184,143 @@ def test_row_b_is_slot_b_without_slots():
     want_o, want = gated_delta_step(*args, states[0], live)
     np.testing.assert_allclose(o, want_o, rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(out[0], want, rtol=1e-6, atol=1e-6)
+
+
+def _step_inputs(seed, b, h, dk, dv):
+    """One token a row at any widths: ``q, k, v, g, beta``."""
+    rng = np.random.default_rng(seed)
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    k = f(b, h, dk)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    q = f(b, h, dk)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(dk)
+    return (q, k, f(b, h, dv),
+            -rng.uniform(0.0, 1.6, (b, h)).astype(np.float32),
+            (2.0 / (1 + np.exp(-f(b, h)))).astype(np.float32))
+
+
+def _dispatched(impl, why):
+    """How often ``dispatch_log`` has the one-token update as ``impl``."""
+    return sum(d["count"] for d in dispatch_log()
+               if (d["op"], d["impl"], d["why"]) == ("delta_step", impl, why))
+
+
+#: case -> (heads, dk, dv, layers, layer, slots in the array, each row's
+#: slot (None: row b is slot b), live, fresh, the tile's bytes or None)
+_KERNEL_CASES = {
+    # 30 heads of 96 x 192: a column of 128 lanes holds two heads' halves
+    "the_published_widths": (30, 96, 192, 2, 1, 3, None, (1, 1, 1),
+                             (0, 0, 0), None),
+    # four heads in one column of 128 lanes, one tile a row
+    "a_small_shape": (4, 8, 32, 2, 0, 3, None, (1, 1, 1), (0, 0, 0), None),
+    # ... and three tiles a row, of two heads each
+    "tiles_of_whole_heads": (6, 8, 64, 2, 1, 3, None, (1, 1, 1), (0, 0, 0),
+                             8 * 128 * 4),
+    "the_slots_it_is_told": (4, 8, 96, 2, 1, 5, (4, 0, 2), (1, 1, 1),
+                             (0, 0, 0), None),
+    "a_fresh_row_over_a_state": (4, 8, 96, 2, 1, 4, (3, 1, 0), (1, 1, 1),
+                                 (0, 1, 0), None),
+    "a_dead_row_between_live_ones": (6, 8, 64, 2, 1, 4, (2, 0, 3, 1),
+                                     (1, 0, 0, 1), (0, 0, 0, 0),
+                                     8 * 128 * 4),
+    "dead_rows_ahead_of_the_first_live_one": (6, 8, 64, 3, 2, 4, None,
+                                              (0, 0, 1, 0), (0, 0, 1, 0),
+                                              8 * 128 * 4),
+    "no_row_live": (4, 8, 32, 2, 1, 3, (2, 0, 1), (0, 0, 0), (0, 0, 0),
+                    None),
+    "a_dead_row_that_is_fresh": (4, 8, 32, 2, 0, 3, None, (1, 0, 1),
+                                 (0, 1, 0), None),
+    # (under the interpreter alone: 48 lanes are one tile of one column)
+    "lanes_that_are_no_tile": (H, DK, DV, 2, 1, 4, (2, 0, 3), (1, 1, 0),
+                               (0, 1, 0), None),
+}
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_the_step_kernel_is_the_plain_step(case, monkeypatch):
+    """The kernel, interpreted, over the WHOLE ``[layers, slots, dk, H
+    dv]`` array with a layer index against ``gated_delta_step`` on the
+    rows' states: the rows' outputs and states, every other (layer, slot)
+    bit for bit, a row with nothing to do bit for bit, and one entry in
+    the dispatch record a call."""
+    h, dk, dv, layers, layer, n_slots, slots, live, fresh, tile_bytes = \
+        _KERNEL_CASES[case]
+    if tile_bytes:
+        monkeypatch.setattr(delta_ops, "_STEP_TILE_BYTES", tile_bytes)
+        assert h * dv // delta_ops._step_lanes(dk, h, dv) == 3
+    live, fresh = np.array(live, bool), np.array(fresh, bool)
+    b = len(live)
+    q, k, v, g, beta = _step_inputs(21, b, h, dk, dv)
+    rng = np.random.default_rng(22)
+    states = rng.standard_normal(
+        (layers, n_slots, dk, h * dv)).astype(np.float32)
+    rows = list(range(b)) if slots is None else list(slots)
+    if case == "a_fresh_row_over_a_state":
+        states[layer, rows[1], 3, 5] = np.nan    # what it held is not read
+    before = _dispatched("interpret", "requested")
+    # (a function of its own: traced, and so recorded, in every case)
+    o, got = jax.jit(lambda *a: gated_delta_step_slots(
+        *a, impl="interpret"))(
+        q, k, v, g, beta, jnp.asarray(states), jnp.int32(layer),
+        None if slots is None else jnp.asarray(slots, jnp.int32),
+        jnp.asarray(live), jnp.asarray(fresh))
+    assert _dispatched("interpret", "requested") == before + 1
+    start = np.where(fresh[:, None, None], 0.0, states[layer, rows])
+    want_o, want = gated_delta_step(q, k, v, g, beta, jnp.asarray(start),
+                                    jnp.asarray(live))
+    got = np.asarray(got)
+    assert np.isfinite(got).all() and np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(want_o)[live],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got[layer, rows], want, rtol=1e-6, atol=1e-6)
+    others = [l for l in range(layers) if l != layer]
+    assert np.array_equal(got[others], states[others])
+    unnamed = [s for s in range(n_slots) if s not in rows]
+    assert np.array_equal(got[layer, unnamed], states[layer, unnamed])
+    for r in np.flatnonzero(~live & ~fresh):
+        assert np.array_equal(got[layer, rows[r]], states[layer, rows[r]])
+        assert not np.asarray(o)[r].any()
+    for r in np.flatnonzero(~live & fresh):      # from zeros, and no token
+        assert not got[layer, rows[r]].any()
+
+
+def test_a_shape_that_does_not_tile_takes_the_plain_step(monkeypatch):
+    """"auto" on a TPU: the kernel where ``[dk, H dv]`` tiles (dk a
+    multiple of 8, H dv of 128), else the plain form, and the dispatch
+    record says which rule ruled it out; off a TPU the plain form."""
+    def trace(dk, h, dv):
+        """Trace a step of two rows on states ``[1, 2, dk, h dv]``."""
+        jax.eval_shape(
+            lambda s: gated_delta_step_slots(
+                jnp.zeros((2, h, dk)), jnp.zeros((2, h, dk)),
+                jnp.zeros((2, h, dv)), jnp.zeros((2, h)), jnp.zeros((2, h)),
+                s, jnp.int32(0), None, jnp.ones(2, bool),
+                jnp.zeros(2, bool), impl="auto"),
+            jax.ShapeDtypeStruct((1, 2, dk, h * dv), jnp.float32))
+
+    before = _dispatched("reference", "platform is not tpu")
+    trace(96, 30, 192)
+    assert _dispatched("reference", "platform is not tpu") == before + 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for dk, h, dv, impl, why in (
+            (DK, H, DV, "reference",
+             f"heads x value_dim {H * DV} % 128 != 0"),
+            (12, 4, 32, "reference", "key_dim 12 % 8 != 0"),
+            (96, 30, 192, "kernel", "auto"),
+            (DK, 4, 32, "kernel", "auto")):
+        before = _dispatched(impl, why)
+        trace(dk, h, dv)
+        assert _dispatched(impl, why) == before + 1
+
+
+@pytest.mark.parametrize("dk,h,dv,lanes", [
+    (96, 30, 192, 5760),      # the published widths: a row's state a tile
+    (128, 64, 128, 4096),     # 4 MB a row: the widest divisor in 2.5 MiB
+    (96, 30, 64, 1920),       # heads narrower than a column of 128 lanes
+    (8, 3, 16, 48),           # no tile: the interpreter's one
+])
+def test_the_tile_is_whole_heads_and_whole_lanes(dk, h, dv, lanes):
+    assert delta_ops._step_lanes(dk, h, dv) == lanes
 
 
 def test_tails_at_the_boundaries_are_the_convolutions_own():

@@ -610,6 +610,57 @@ def test_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip,
         compilation_cache.reset_cache()
 
 
+def test_delta_step_kernel_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """The delta rule's one-token update (``ops/delta.py``
+    ``gated_delta_step_slots``) at the delta-rule cell's shapes: 16 rows
+    on twelve layers of 16 slots of ``[96, 30 x 192]`` float32 (425 MB),
+    a traced layer, row b in slot b and the slots given. It is ONE
+    ``delta_step`` custom call whose result is the whole array, aliased
+    to the argument, the Mosaic compile accepts its tile (a row's whole
+    state, 2.2 MB, two in and two out) under the default scoped VMEM
+    limit, and the program holds no temporary of the state's size: the
+    rows' ``[4, 5760]`` and ``[96, 60]`` inputs and no more."""
+    from ray_tpu.ops.delta import gated_delta_step_slots
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    rows, heads, dk, dv, layers = 16, 30, 96, 192, 12
+    args = (s((rows, heads, dk)), s((rows, heads, dk)),
+            s((rows, heads, dv), jnp.bfloat16), s((rows, heads)),
+            s((rows, heads)), s((layers, rows, dk, heads * dv)),
+            s((), jnp.int32))
+    flags = (s((rows,), jnp.bool_), s((rows,), jnp.bool_))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for slots in (None, s((rows,), jnp.int32)):
+            compiled = jax.jit(
+                functools.partial(gated_delta_step_slots, impl="auto"),
+                donate_argnums=(5,)).lower(*args, slots, *flags).compile()
+            text = compiled.as_text()
+            kernels = re.findall(
+                rf"%delta_step[.\d]* = \(f32\[{rows},1,5760\]\S*, "
+                rf"f32\[{layers},{rows},96,5760\]\S*\) custom-call\(",
+                text)
+            assert len(kernels) == 1, kernels
+            made = re.findall(
+                r"= f32\[(?:\d+,)+(?:96,5760|5760,96)\]\S* ([\w\-]+)\(",
+                text)
+            assert not [op for op in made if op not in (
+                "parameter", "get-tuple-element", "bitcast")], made
+            memory = compiled.memory_analysis()
+            assert memory.alias_size_in_bytes \
+                >= layers * rows * dk * heads * dv * 4
+            assert memory.temp_size_in_bytes < 1 << 20
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("entry,batch", [
     ("decode_step", 1), ("decode_step", 16), ("prefill", 1)])
 def test_compiled_step_updates_the_delta_state_in_place(
