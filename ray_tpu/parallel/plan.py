@@ -285,9 +285,13 @@ class _SPMDProgram(TrainProgram):
         self.state = self.bundle.init(seed=seed)
         # the step's own clock: train.step and, inside it, the host's
         # part before the device has the step (dispatch), the blocking
-        # fetch of the loss (wait) and what follows it (tail)
+        # fetch of the loss (wait) and what follows it (tail). Its
+        # books by kind hold a step as `step`, and one that compiled
+        # its program as `compile`: a histogram of steps with the
+        # compiling ones in it has them for its tail
         from ray_tpu.util.tracing import PhaseClock
         self.clock = PhaseClock("train", steps=True)
+        self._compiled = 0      # variants of the step program so far
 
     def step(self, batch: Dict[str, Any]) -> PlanStepResult:
         import numpy as np
@@ -299,6 +303,9 @@ class _SPMDProgram(TrainProgram):
                     int(np.asarray(batch["input_ids"]).shape[0]))
                 t0 = time.perf_counter()
                 self.state, metrics = self.bundle.step(self.state, batch)
+                compiled = self.bundle.step_fn._cache_size()
+                if compiled != self._compiled:
+                    self._compiled, clock.kind = compiled, "compile"
             with clock.phase("train.wait"):
                 loss = float(metrics["loss"])
             with clock.phase("train.tail"):

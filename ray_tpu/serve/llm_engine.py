@@ -838,10 +838,15 @@ class LLMEngine:
             "ttft_prefill_wait_s": 0.0, "ttft_prefill_s": 0.0,
             "ttft_prefill_ticks": 0, "ttft_prefill_chunks": 0}
         # the tick's own clock (step thread only): phase totals, the
-        # newest spans, profiler annotations (util/tracing.PhaseClock)
+        # newest spans, profiler annotations, and the tick's books by
+        # kind (util/tracing.PhaseClock). A tick's kind is what it
+        # FETCHED (_fetched): idle, decode, full, part, full_decode,
+        # part_decode, and the _verify forms with speculation on. A part
+        # chunk is anything up to a full one: no median bounds it
         from ray_tpu.util.tracing import PhaseClock
         self._clock = PhaseClock(
-            f"engine:{replica_tag}" if replica_tag else "engine")
+            f"engine:{replica_tag}" if replica_tag else "engine",
+            kind="idle", unbounded=("part",), on_slow=self._tick_slow)
         self._metrics = self._recorder = None
         try:
             from ray_tpu.core.metric_defs import runtime_metrics
@@ -861,7 +866,6 @@ class LLMEngine:
         # REQUEST_SPANS batch ships at request end iff sampled /
         # SLO-tripped / failed.
         self._tracer = self._slo = None
-        self._queue_wait_ewma: Optional[float] = None
         try:
             from ray_tpu.serve.request_trace import RequestTracer
             from ray_tpu.serve.slo import SLOBudget, SLOWatchdog
@@ -1417,6 +1421,8 @@ class LLMEngine:
             hit_rate = (round(ps["hits_total"]
                               / self._prompt_blocks_total, 4)
                         if self._prompt_blocks_total else None)
+            books = self._clock.books()
+            phases = books.pop("phases")
             out = {
                 "queue_depth": len(self._pending),
                 "prefilling": len(self._prefilling),
@@ -1518,21 +1524,21 @@ class LLMEngine:
                 "occupancy_hist": dict(self._occupancy),
                 "ttft_ewma_s": (round(self._ttft_ewma, 6)
                                 if self._ttft_ewma is not None else None),
-                # router-enqueue -> engine-admission wait (EWMA): the
-                # component that, added to the engine-scoped TTFT,
-                # gives the full user-facing TTFT the serve_ttft
-                # histogram and the request waterfalls report
-                "queue_wait_ewma_s": (
-                    round(self._queue_wait_ewma, 6)
-                    if self._queue_wait_ewma is not None else None),
                 # the tick's phases, name -> [count, seconds], and two
                 # sums of them: all tick time, and the part of it in
                 # which the device had nothing queued (from the end of
                 # the *.wait that fetched the last program out to the
                 # start of the next *.dispatch)
-                "phases": self._clock.totals(),
-                "tick_wall_s": self._clock.seconds("engine.tick"),
-                "host_gap_s": self._clock.gap_s,
+                "phases": phases,
+                "tick_wall_s": phases.get("engine.tick", (0, 0.0))[1],
+                "host_gap_s": books.pop("gap_s"),
+                # the ticks by kind (what each fetched), from the same
+                # reading, so they add up to the three above:
+                # tick_kind_total / _s / _wait_s / _gap_s, tick_hist_<kind>
+                # (a bucket's lower edge in seconds -> ticks),
+                # tick_slow_total by kind, tick_slow_s by the phase that
+                # held the overrun, and slow_ticks, the newest whole
+                **books,
                 # first-token time by where it went (ttft_* keys)
                 **self._ttft,
                 # in-flight weight refresh accounting (RLHF rollout
@@ -1824,6 +1830,29 @@ class LLMEngine:
 
     def _op_or_swap_pending_locked(self) -> bool:
         return bool(self._ops) or self._staged_weights is not None
+
+    def _fetched(self, what: str) -> None:
+        """The running tick has fetched a program's result: name the
+        tick after it (its kind in the clock's books; a tick fetches at
+        most a chunk, ``full`` or ``part``, and then a step)."""
+        kind = self._clock.kind
+        self._clock.kind = what if kind is None else f"{kind}_{what}"
+
+    def _tick_slow(self, tick: tuple, phase: str) -> None:
+        """The clock's ``on_slow``: a slow tick goes once to the flight
+        recorder, a slice that ends where the tick did, named after the
+        phase that held most of it."""
+        if self._recorder is None:
+            return
+        number, kind, _, seconds, held = tick
+        try:
+            self._recorder.record(
+                "ENGINE_TICK_SLOW", replica=self.replica_tag,
+                tick=number, kind=kind, phase=phase,
+                dur_s=round(seconds, 6),
+                held_s={name: round(s, 6) for name, s in held.items()})
+        except Exception:
+            pass
 
     def _program_wall(self, kind: str, t0: float, ready: bool) -> float:
         """A fetch has just returned: the fetched program's wall, from
@@ -2392,6 +2421,7 @@ class LLMEngine:
             if lp is not None:
                 lp = np.asarray(lp)
         self._prefill_wall_s += self._program_wall("prefill", t0, ready)
+        self._fetched("full" if n == self.config.prefill_chunk else "part")
         with clock.phase("engine.prefill.book"):
             self._book_prefill(req, start, n, t0w, tok, lp, snaps)
 
@@ -2588,6 +2618,7 @@ class LLMEngine:
                 lps = self._np.asarray(lps)
             out = self._np.asarray(out)
         self._decode_wall_s += self._program_wall("decode", t0, ready)
+        self._fetched("decode")
         with clock.phase("engine.decode.emit"):
             self._emit_decoded(active, out, lps)
 
@@ -2700,6 +2731,7 @@ class LLMEngine:
         with clock.phase("engine.decode.wait", ready=int(ready)):
             preds = np.asarray(preds)
         self._decode_wall_s += self._program_wall("decode", t0, ready)
+        self._fetched("verify")
         with clock.phase("engine.decode.emit"):
             self._emit_verified(active, preds, drafts, t0w)
 
@@ -2888,9 +2920,6 @@ class LLMEngine:
         full = max(ttft, time.time() - t_enq) if t_enq else ttft
         self._ttft_ewma = ttft if self._ttft_ewma is None \
             else 0.8 * self._ttft_ewma + 0.2 * ttft
-        qw_ewma = getattr(self, "_queue_wait_ewma", None)
-        self._queue_wait_ewma = qw if qw_ewma is None \
-            else 0.8 * qw_ewma + 0.2 * qw
         if self._metrics is not None:
             try:
                 self._metrics.serve_ttft.observe(full)

@@ -20,16 +20,20 @@ under the propagated remote context.
 train step): always-on counts and seconds per phase, a bounded ring of
 the newest spans, and a ``jax.profiler`` annotation around the same
 interval so that a running profiler session holds the span on the
-device trace's clock. :func:`clocks` is how readers find them.
+device trace's clock; and the outermost phase's books BY KIND of tick
+(what the loop says the tick did): counts, seconds, waits, idle gap, a
+histogram of lengths, and the slow ones with the phase that held them.
+:func:`clocks` is how readers find them.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import math
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 _enabled = False
 _lock = threading.Lock()
@@ -181,6 +185,14 @@ def task_execution_span(name: str, trace: Optional[tuple]
 # ------------------------------------------------------------ phase clock
 #: newest spans a clock keeps (a few hundred ticks of a dozen phases)
 PHASE_RING = 4096
+#: buckets an octave of the histogram of tick lengths (a bucket is 9% wide)
+HIST_PER_OCTAVE = 8
+#: a tick is slow beyond this many medians of its kind; the median is
+#: the kind's histogram's, read again every ``SLOW_REFRESH`` ticks of the
+#: kind (none before the first); the newest ``SLOW_KEPT`` are kept whole
+SLOW_FACTOR = 4.0
+SLOW_REFRESH = 32
+SLOW_KEPT = 64
 
 _clocks: Dict[str, "PhaseClock"] = {}
 
@@ -231,6 +243,7 @@ class _Phase:
             c._root_t0 = t
             # a program launched in the last root phase may still be out
             c._idle_from = None if c._in_flight else t
+            c.kind, c._tick_wait = None, 0.0    # the loop names the tick
         elif self.dispatch:
             c._in_flight += 1
             if c._idle_from is not None:
@@ -250,15 +263,61 @@ class _Phase:
             c._in_flight -= 1       # fetched, or the launch itself raised
         if self.t0 < c._since:
             return              # began before a reset: not in its books
+        if self.parent is None:
+            c._seq_begin += 1   # books() reads none of a tick half booked
         self.count += 1
         self.seconds += t1 - self.t0
         c.ring.append((self.name, c.tick_no, self.t0, t1, self.parent))
         if self.wait:
+            c._tick_wait += t1 - self.t0
             if c._root_t0 >= c._since and not c._in_flight:
                 c._idle_from = t1
-        elif self.parent is None and c._idle_from is not None:
-            c.gap_s += t1 - c._idle_from
-            c._idle_from = None
+        if self.parent is None:
+            if c._idle_from is not None:
+                c.gap_s += t1 - c._idle_from
+                c._idle_from = None
+            c._book_tick(self, t1 - self.t0)
+            c._seq_end += 1
+
+
+class _Kind:
+    """One kind of tick's books, cumulative since ``reset``: how many,
+    their seconds, the seconds of the ``*.wait`` phases that ended in
+    them, their part of ``gap_s``, a histogram of their lengths
+    (bucket number -> ticks; bucket ``b`` starts at :func:`bucket_edge`),
+    the slow ones, and the length beyond which one is slow (infinite
+    with no median yet, and for a kind the rule leaves out)."""
+
+    __slots__ = ("bounded", "count", "seconds", "wait_s", "gap_s", "hist",
+                 "slow", "slow_above")
+
+    def __init__(self, bounded: bool):
+        self.bounded = bounded
+        self.clear()
+
+    def clear(self) -> None:
+        self.count = self.slow = 0
+        self.seconds = self.wait_s = self.gap_s = 0.0
+        self.hist: Dict[int, int] = {}
+        self.slow_above = math.inf
+
+    def quantile(self, q: float) -> Optional[float]:
+        """Seconds below which ``q`` of the kind's ticks lie, placed
+        inside its bucket by rank (geometrically: a bucket is a ratio
+        wide); None with no tick."""
+        hist = sorted(self.hist.items())
+        rank, below = q * sum(n for _, n in hist), 0
+        for b, n in hist:
+            if below + n >= rank:
+                return bucket_edge(b + (rank - below) / n)
+            below += n
+        return None
+
+
+def bucket_edge(b: float) -> float:
+    """Seconds at which histogram bucket ``b`` starts
+    (``HIST_PER_OCTAVE`` buckets double it)."""
+    return 2.0 ** (b / HIST_PER_OCTAVE)
 
 
 class PhaseClock:
@@ -290,14 +349,44 @@ class PhaseClock:
     wait end]; a program launched in one outermost phase and fetched in
     the next keeps the count up between them.
 
-    Other threads may read ``totals()`` and ``spans()`` at any time.
+    An outermost phase is a tick, and when it ends it is booked under
+    its KIND: ``clock.kind`` if the loop set it inside the tick (the
+    engine: what the tick fetched), else the clock's one ``kind``. By
+    kind, cumulative since ``reset()``: the count, the seconds (the
+    same ``t1 - t0`` the phase's own total sums), the seconds of the
+    ``*.wait`` phases that ended inside, the tick's part of ``gap_s``,
+    and one count in a geometric histogram of its length. A tick longer
+    than ``SLOW_FACTOR`` medians of its kind is slow: one count under
+    the kind, its overrun (length less that median) under the name of
+    the phase directly inside it that held the most of it (the
+    outermost phase's own name: the time between its phases), read back
+    from the ring, and the tick kept whole in ``slow_ticks`` and handed
+    to ``on_slow``. Kinds whose name starts with one of ``unbounded``
+    are left out of that rule (a median bounds nothing where the work
+    of a tick is anything up to a bound). ``books()`` reads them all.
+
+    Other threads may read ``totals()``, ``spans()`` and ``books()`` at
+    any time.
     """
 
-    def __init__(self, owner: str, steps: bool = False):
+    def __init__(self, owner: str, steps: bool = False,
+                 kind: str = "step", unbounded: Tuple[str, ...] = (),
+                 on_slow: Optional[Callable[[tuple, str], None]] = None):
         from jax.profiler import StepTraceAnnotation, TraceAnnotation
         self.owner = owner
         self.tick_no = 0
         self.gap_s = 0.0
+        self.kind: Optional[str] = None     # the running tick's, if named
+        self.default_kind, self.unbounded = kind, tuple(unbounded)
+        self.on_slow = on_slow
+        #: newest slow ticks: (tick, kind, wall-clock start, seconds,
+        #: {phase directly inside: seconds})
+        self.slow_ticks: "collections.deque[tuple]" = collections.deque(
+            maxlen=SLOW_KEPT)
+        self._kinds: Dict[str, _Kind] = {}
+        self._slow_s: Dict[str, float] = {}     # phase -> overrun seconds
+        self._tick_wait = self._gap_booked = 0.0
+        self._seq_begin = self._seq_end = 0
         self.ring: "collections.deque[tuple]" = collections.deque(
             maxlen=PHASE_RING)
         self._phases: Dict[str, _Phase] = {}
@@ -344,5 +433,93 @@ class PhaseClock:
         self._in_flight = 0
         for p in list(self._phases.values()):
             p.count, p.seconds = 0, 0.0
-        self.gap_s = 0.0
+        for k in list(self._kinds.values()):
+            k.clear()           # a kind once met keeps its (empty) books
+        self._slow_s.clear()
+        self.slow_ticks.clear()
+        self.gap_s = self._gap_booked = 0.0
         self.ring.clear()
+
+    # ----------------------------------------------------- books by kind
+    def _book_tick(self, root: _Phase, dt: float) -> None:
+        """An outermost phase has ended after ``dt`` seconds."""
+        kind = self.kind or self.default_kind
+        k = self._kinds.get(kind)
+        if k is None:
+            k = self._kinds[kind] = _Kind(
+                not kind.startswith(self.unbounded))
+        k.count += 1
+        k.seconds += dt
+        k.wait_s += self._tick_wait
+        k.gap_s += self.gap_s - self._gap_booked
+        self._gap_booked = self.gap_s
+        # (a clock that did not move: a bucket under any a tick can reach)
+        b = math.floor(math.log2(dt) * HIST_PER_OCTAVE) if dt > 0 else -512
+        k.hist[b] = k.hist.get(b, 0) + 1
+        if dt > k.slow_above:
+            self._book_slow(root, kind, k, dt)
+        if k.bounded and not k.count % SLOW_REFRESH:
+            k.slow_above = SLOW_FACTOR * k.quantile(0.5)
+
+    def _book_slow(self, root: _Phase, kind: str, k: _Kind,
+                   dt: float) -> None:
+        """A slow tick: what each phase directly inside it held, from
+        the ring's entries of this tick (newest first, the tick's own
+        entry among them)."""
+        held: Dict[str, float] = {}
+        for name, _, t0, t1, parent in reversed(self.ring):
+            if t0 < root.t0:
+                break
+            if parent == root.name:
+                held[name] = held.get(name, 0.0) + (t1 - t0)
+        held[root.name] = max(0.0, dt - sum(held.values()))
+        phase = max(held, key=held.get)
+        k.slow += 1
+        self._slow_s[phase] = self._slow_s.get(phase, 0.0) \
+            + dt - k.slow_above / SLOW_FACTOR
+        tick = (self.tick_no, kind,
+                time.time() - (time.perf_counter() - root.t0), dt, held)
+        self.slow_ticks.append(tick)
+        if self.on_slow is not None:
+            self.on_slow(tick, phase)
+
+    def books(self) -> Dict[str, Any]:
+        """One reading of the books as they stood at a tick's end (read
+        again if a tick was booked meanwhile): ``phases`` (``totals()``),
+        ``gap_s`` (up to the last tick booked; ``clock.gap_s`` runs ahead
+        of it inside a tick), by kind ``tick_kind_total``, ``tick_kind_s``,
+        ``tick_kind_wait_s``, ``tick_kind_gap_s`` and ``tick_slow_total``,
+        ``tick_hist_<kind>`` (the bucket's lower edge in seconds ->
+        ticks), ``tick_slow_s`` (phase -> overrun seconds) and
+        ``slow_ticks``. The kinds' counts add up to the outermost
+        phase's, their seconds to its seconds, their gaps to ``gap_s``,
+        a kind's histogram to its count."""
+        for _ in range(8):
+            end = self._seq_end
+            out = self._read_books()
+            if self._seq_begin == end:
+                break
+        return out
+
+    def _read_books(self) -> Dict[str, Any]:
+        kinds = list(self._kinds.items())
+        out: Dict[str, Any] = {
+            "phases": self.totals(), "gap_s": self._gap_booked,
+            "tick_kind_total": {kind: k.count for kind, k in kinds},
+            "tick_kind_s": {kind: k.seconds for kind, k in kinds},
+            "tick_kind_wait_s": {kind: k.wait_s for kind, k in kinds},
+            "tick_kind_gap_s": {kind: k.gap_s for kind, k in kinds},
+            "tick_slow_total": {kind: k.slow for kind, k in kinds},
+            "tick_slow_s": dict(self._slow_s),
+            "slow_ticks": list(self.slow_ticks)}
+        for kind, k in kinds:
+            out[f"tick_hist_{kind}"] = {
+                bucket_edge(b): n for b, n in list(k.hist.items())}
+        return out
+
+    def quantile(self, kind: str, q: float) -> Optional[float]:
+        """Seconds below which ``q`` (0 to 1) of the kind's ticks lie,
+        from its histogram: within a bucket's width; None for a kind
+        with no tick."""
+        k = self._kinds.get(kind)
+        return k.quantile(q) if k is not None else None

@@ -101,3 +101,28 @@ def test_the_compiled_steps_ops_carry_the_scopes(plan):
     assert "transpose" in text and text.count("lm_head_loss") >= 2
     assert ("grad_transport" in text) == bool(plan)
     assert "jit(step_raw)" in text
+
+
+def test_the_steps_books_hold_a_compiling_step_apart():
+    """The train clock's books by kind: a step is ``step``, one that
+    compiled its program ``compile`` (the first; a new batch shape is
+    another), so the histogram a percentile is read from has no
+    compile for its tail; the kinds add up to ``train.step``'s totals."""
+    prog = _program()
+    for i in range(5):
+        prog.step(_batch(i))
+    clock = prog.clock
+    b = clock.books()
+    assert b["tick_kind_total"] == {"compile": 1, "step": 4}
+    assert sum(b["tick_kind_s"].values()) == pytest.approx(
+        clock.seconds("train.step"), rel=1e-9)
+    assert sum(b["tick_kind_gap_s"].values()) == pytest.approx(
+        clock.gap_s, rel=1e-9)
+    assert sum(b["tick_kind_wait_s"].values()) == pytest.approx(
+        clock.seconds("train.wait"), rel=1e-9)
+    assert sum(b["tick_hist_step"].values()) == 4
+    assert b["tick_kind_s"]["compile"] > b["tick_kind_s"]["step"] / 4
+    assert 0 < clock.quantile("step", 0.99) < b["tick_kind_s"]["compile"]
+    ids = np.random.default_rng(9).integers(2, 64, (8, 32))
+    prog.step({"input_ids": ids.astype(np.int32)})  # a shape not seen yet
+    assert clock.books()["tick_kind_total"] == {"compile": 2, "step": 4}

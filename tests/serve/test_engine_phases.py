@@ -5,7 +5,10 @@ carry a tick the ring knows, the tick's annotations reach a profiler
 trace, the compiled programs' ops carry the named scopes, and each
 program's wall is booked by class from the engine's own fetches: its
 time on the device where it ran behind another, launch + wait where
-nothing was out, neither where a fetch found its result ready."""
+nothing was out, neither where a fetch found its result ready; and
+the tick's books by kind: a tick is booked under what it fetched, the
+kinds add up to the tick's own totals, and a slow tick names the phase
+that held it."""
 import glob
 import time
 
@@ -225,7 +228,7 @@ def test_ttft_parts_sum_to_ttft_for_every_request(served):
     assert st["ttft_prefill_ticks"] >= st["ttft_prefill_chunks"]
     # six requests over two slots queued, and waited behind chunks
     assert st["ttft_queue_s"] > 0 and st["ttft_prefill_wait_s"] > 0
-    assert st["queue_wait_ewma_s"] > 0          # traced or not
+    assert st["ttft_queue_s"] / st["ttft_requests"] > 0   # traced or not
     # tracing decides only whether a RequestTrace exists
     assert len(eng._tracer.recent) == (len(reqs) if traced else 0)
 
@@ -307,7 +310,9 @@ def test_the_walls_by_class_fit_inside_all_of_a_kind(served, kind):
 class _Result:
     """A step program's result as the fetch sees it: ``is_ready()`` says
     what ``ready[kind]`` holds when it is asked, and a fetch that has to
-    wait takes 2 ms (a tick's other phases are tenths of that)."""
+    wait takes ``delay``, 2 ms (a tick's other phases are tenths of
+    that)."""
+    delay = 0.002
 
     def __init__(self, array, kind, ready):
         self.array, self.kind, self.ready = array, kind, ready
@@ -317,7 +322,7 @@ class _Result:
 
     def __array__(self, dtype=None, copy=None):
         if not self.is_ready():
-            time.sleep(0.002)
+            time.sleep(self.delay)
         return np.asarray(self.array, dtype)
 
 
@@ -548,3 +553,154 @@ def test_compiled_programs_ops_carry_the_scopes():
     assert "jit(_prefill_fn)" in prefill and "jit(_decode_fn)" in decode
     assert "kv_copy" in copy and "kv_gather" in gather \
         and "kv_scatter" in scatter
+
+
+# ----------------------------------------------------- the books by kind
+def _assert_the_kinds_add_up(st):
+    """The four identities of ``stats()``: the kinds' counts are the
+    ticks, their seconds ``tick_wall_s``, their gaps ``host_gap_s``, a
+    kind's histogram its count."""
+    assert sum(st["tick_kind_total"].values()) \
+        == st["phases"]["engine.tick"][0]
+    assert sum(st["tick_kind_s"].values()) == pytest.approx(
+        st["tick_wall_s"], rel=1e-9)
+    assert sum(st["tick_kind_gap_s"].values()) == pytest.approx(
+        st["host_gap_s"], rel=1e-9)
+    for kind, n in st["tick_kind_total"].items():
+        assert sum(st[f"tick_hist_{kind}"].values()) == n
+        assert 0 <= st["tick_kind_wait_s"][kind] <= st["tick_kind_s"][kind]
+    assert sum(st["tick_kind_wait_s"].values()) == pytest.approx(
+        st["phases"]["engine.prefill.wait"][1]
+        + st["phases"]["engine.decode.wait"][1], rel=1e-9)
+
+
+@pytest.mark.parametrize("spec_tokens,step", [(0, "decode"), (2, "verify")])
+def test_a_tick_is_booked_under_what_it_fetched(spec_tokens, step):
+    """Counted by hand: a prompt of half a chunk starts decoding in the
+    tick that fetched its chunk (part + step); beside it a prompt of
+    two chunks and a half gives full + step, full + step, part + step;
+    every other tick fetched a step alone."""
+    eng = _engine(spec_tokens=spec_tokens, max_new_tokens=40)
+    try:
+        eng.warmup()
+        a = eng.submit(list(range(2, 6)), 36)
+        for _ in range(2):                   # it is decoding
+            assert isinstance(a.out.get(timeout=60), int)
+        b = eng.submit(list(range(10, 30)), 4)      # 8 + 8 + 4 tokens
+        assert len(_drain(b)) == 4 and len(_drain(a)) == 34
+        st = eng.stats()
+        spans = eng._clock.spans()
+    finally:
+        eng.shutdown()
+    kinds = {k: n for k, n in st["tick_kind_total"].items() if n}
+    assert kinds.pop("idle", 0) <= 1         # a reap with nothing to run
+    assert kinds == {f"full_{step}": 2, f"part_{step}": 2,
+                     step: st["decode_steps"] - 4}
+    assert kinds[step] > 0 and st["prefill_chunks"] == 4
+    _assert_the_kinds_add_up(st)
+    # in that order, by the ring: the chunks' fetches fall in four
+    # ticks, each with a step's fetch behind it; the long prompt's three
+    # follow one another, and a tick with a step alone follows them
+    fetched = {}
+    for name, tick, *_ in spans:
+        if name.endswith(".wait"):
+            fetched.setdefault(tick, []).append(name.split(".")[1])
+    chunk_ticks = [t for t, f in sorted(fetched.items()) if "prefill" in f]
+    assert [fetched[t] for t in chunk_ticks] == [["prefill", "decode"]] * 4
+    first = chunk_ticks[1]
+    assert chunk_ticks[1:] == [first, first + 1, first + 2]
+    assert fetched[first + 3] == ["decode"]
+
+
+class _Recorder:
+    """Stands where the worker's flight recorder does."""
+
+    def __init__(self):
+        self.events = []
+
+    def record(self, ev, **data):
+        self.events.append((ev, data))
+
+    def maybe_flush(self):
+        pass
+
+
+def test_a_slow_fetch_lands_in_the_slow_ticks_under_its_wait():
+    """Every fetch blocks 2 ms (stubbed) and one decode step's, after
+    the kind has had its 32 ticks and a median, 0.3 s: that tick is
+    slow, its overrun is ``engine.decode.wait``'s, it is kept whole and
+    goes once to the flight recorder; the pass-through the benchmark
+    reads ``stats()`` through keeps all of it but the list."""
+    from benchmarks import serve_cell
+    eng = _engine(max_new_tokens=40)
+    try:
+        eng.warmup()
+        eng._recorder = rec = _Recorder()
+        _stub_results(eng, {"prefill": False, "decode": False})
+        inner, steps = eng._jit_decode, []
+
+        def decode(params, rows, cache):
+            first, *rest = inner(params, rows, cache)
+            steps.append(first)
+            if len(steps) == 45:
+                first.delay = 0.3
+            return (first, *rest)
+        decode._cache_size = inner._cache_size
+        eng._jit_decode = decode
+        # 39 decode steps, 38 of them in ticks that fetched no chunk
+        assert len(_drain(eng.submit(list(range(2, 6)), 40))) == 40
+        before = eng.stats()
+        assert before["tick_kind_total"]["decode"] == 38
+        assert before["slow_ticks"] == [] or max(
+            t[3] for t in before["slow_ticks"]) < 0.3
+        # its sixth step is the 45th
+        assert len(_drain(eng.submit(list(range(12, 16)), 40))) == 40
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    _assert_the_kinds_add_up(st)
+    number, kind, began, seconds, held = max(
+        st["slow_ticks"], key=lambda t: t[4].get("engine.decode.wait", 0))
+    assert seconds >= 0.3
+    assert kind == "decode" and began < time.time()
+    assert max(held, key=held.get) == "engine.decode.wait"
+    assert held["engine.decode.wait"] >= 0.3
+    assert set(held) <= TICK_CHILDREN | {"engine.tick"}
+    assert st["tick_slow_total"]["decode"] >= 1
+    # the overrun: the tick less a median of a few ms
+    assert 0.25 < st["tick_slow_s"]["engine.decode.wait"]
+    sent = [data for ev, data in rec.events if ev == "ENGINE_TICK_SLOW"]
+    assert len(sent) == len(st["slow_ticks"])
+    mine, = [d for d in sent if d["tick"] == number]
+    assert mine["kind"] == "decode" and mine["dur_s"] >= 0.3
+    assert mine["phase"] == "engine.decode.wait"
+    assert mine["held_s"]["engine.decode.wait"] >= 0.3
+    # differenced as the benchmark differences it (no edit there)
+    assert "slow_ticks" not in serve_cell.numerics(st)
+    d = serve_cell.counters_delta(st, before)
+    assert "slow_ticks" not in d
+    assert d["tick_kind_total"]["decode"] == 38
+    assert d["tick_kind_total"]["part_decode"] == 1
+    assert sum(d["tick_kind_total"].values()) \
+        == d["phases"]["engine.tick"]["count"]
+    assert sum(d["tick_kind_s"].values()) == pytest.approx(
+        d["tick_wall_s"], rel=1e-9)
+    assert sum(d["tick_kind_gap_s"].values()) == pytest.approx(
+        d["host_gap_s"], rel=1e-6)
+    assert sum(d["tick_hist_decode"].values()) == 38
+    assert d["tick_slow_s"]["engine.decode.wait"] > 0.25
+    assert d["tick_kind_wait_s"]["decode"] >= 0.3 + 37 * 0.002
+
+
+def test_warmup_zeroes_the_books_by_kind(served):
+    eng, _, _ = served
+    st = eng.stats()
+    assert sum(st["tick_kind_total"].values()) > 10
+    eng.warmup()
+    st = eng.stats()
+    # the warm-up request's own ticks ended before the reset
+    assert set(st["tick_kind_total"].values()) <= {0, 1}
+    assert sum(st["tick_kind_s"].values()) == pytest.approx(
+        st["tick_wall_s"], abs=1e-12)
+    assert st["tick_slow_s"] == {} and st["slow_ticks"] == []
+    assert "queue_wait_ewma_s" not in st

@@ -308,9 +308,9 @@ def test_engine_unsampled_fast_request_ships_zero_spans(traced_engine):
 def test_queue_wait_is_split_out_of_ttft(traced_engine):
     """Satellite regression: TTFT = queue_wait + engine time. A
     router-stamped enqueue 0.5s in the past must surface as
-    queue_wait_s on the FIRST_TOKEN span and in the engine's
-    queue_wait_ewma_s gauge, with full ttft_s >= queue_wait_s >
-    engine_ttft_s."""
+    queue_wait_s on the FIRST_TOKEN span, with full ttft_s >=
+    queue_wait_s > engine_ttft_s; the engine's own part of the wait
+    (submit -> slot) is ttft_queue_s over ttft_requests in stats()."""
     eng = traced_engine
     rid = new_request_id()
     list(eng.generate_sync(
@@ -328,7 +328,11 @@ def test_queue_wait_is_split_out_of_ttft(traced_engine):
     # QUEUED span covers the router wait, not just the engine queue
     q = next(s for s in p["spans"] if s["phase"] == RT.QUEUED)
     assert q["t1"] - q["t0"] >= 0.45
-    assert (eng.stats()["queue_wait_ewma_s"] or 0) > 0.1
+    # the mean the benchmark reads starts at the engine's submit: the
+    # router's half second is not in it
+    st = eng.stats()
+    assert 0 < st["ttft_queue_s"] / st["ttft_requests"] \
+        < a["queue_wait_s"]
 
 
 def test_future_enqueue_stamp_is_clamped(traced_engine):

@@ -281,3 +281,204 @@ def test_a_phases_stat_reaches_its_annotation_and_nothing_else(steps):
     assert clock.totals()["loop.wait"][0] == 4
     assert [s[:2] for s in clock.spans()[-3:]] == [
         ("loop.wait", 2), ("loop.wait", 2), ("loop.tick", 2)]
+
+
+# ------------------------------------------------------- the books by kind
+class _FakeTime:
+    """Stands where the module's ``time`` does: the test moves it."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self):
+        return self.now
+
+    def time(self):
+        return 1.7e9 + self.now
+
+    def sleep(self, s):
+        self.now += s
+
+
+@pytest.fixture
+def fake(monkeypatch):
+    fake = _FakeTime()
+    monkeypatch.setattr(tracing, "time", fake)
+    return fake
+
+
+def _tick(clock, fake, kind=None, stage=0.001, wait=0.008, emit=0.001,
+          between=0.0):
+    """One tick of ``stage + wait + emit + between`` seconds; named
+    ``kind`` inside it, as a loop names it where it fetches."""
+    clock.tick()
+    with clock.phase("loop.tick"):
+        with clock.phase("loop.stage"):
+            fake.sleep(stage)
+        with clock.phase("loop.dispatch"):
+            pass
+        with clock.phase("loop.wait"):
+            fake.sleep(wait)
+        if kind is not None:
+            clock.kind = kind
+        fake.sleep(between)             # in the tick, in none of its phases
+        with clock.phase("loop.emit"):
+            fake.sleep(emit)
+
+
+def test_the_kinds_books_add_up_to_the_outermost_phases(fake):
+    clock = PhaseClock("t-kinds", kind="idle")
+    for i in range(60):
+        _tick(clock, fake, kind=(None, "decode", "full_decode")[i % 3],
+              wait=0.002 * (1 + i % 5), between=0.0005 * (i % 2))
+    b = clock.books()
+    assert b["tick_kind_total"] == {"idle": 20, "decode": 20,
+                                    "full_decode": 20}
+    # the four identities: counts, seconds, gaps, a histogram's counts
+    assert sum(b["tick_kind_total"].values()) == b["phases"]["loop.tick"][0]
+    assert sum(b["tick_kind_s"].values()) == pytest.approx(
+        clock.seconds("loop.tick"), rel=1e-12)
+    assert sum(b["tick_kind_gap_s"].values()) == pytest.approx(
+        clock.gap_s, rel=1e-12) and clock.gap_s > 0
+    assert b["gap_s"] == clock.gap_s        # no tick is running
+    for kind, n in b["tick_kind_total"].items():
+        assert sum(b[f"tick_hist_{kind}"].values()) == n
+    # and the waits that ended inside the ticks
+    assert sum(b["tick_kind_wait_s"].values()) == pytest.approx(
+        clock.seconds("loop.wait"), rel=1e-12)
+    # a bucket's lower edge lies at or under the lengths it counts
+    # (every `decode` tick here is 4 to 12.5 ms)
+    assert all(0.004 / 1.1 < edge <= 0.0125
+               for edge in b["tick_hist_decode"])
+    assert b["tick_slow_total"] == dict.fromkeys(b["tick_kind_total"], 0)
+    assert b["tick_slow_s"] == {} and b["slow_ticks"] == []
+
+
+def test_a_clock_whose_loop_names_nothing_books_one_kind(fake):
+    clock = PhaseClock("t-one-kind")
+    for _ in range(3):
+        _tick(clock, fake)
+    assert clock.books()["tick_kind_total"] == {"step": 3}
+
+
+def test_inside_a_tick_the_books_stand_at_the_last_ticks_end(fake):
+    clock = PhaseClock("t-mid")
+    _tick(clock, fake)
+    clock.tick()
+    with clock.phase("loop.tick"):
+        fake.sleep(0.004)
+        with clock.phase("loop.dispatch"):
+            b = clock.books()               # as another thread would
+            assert clock.gap_s == pytest.approx(b["gap_s"] + 0.004)
+            assert b["gap_s"] == sum(b["tick_kind_gap_s"].values())
+            assert b["tick_kind_total"] == {"step": 1}
+            assert b["phases"]["loop.tick"][0] == 1
+
+
+@pytest.mark.parametrize("q", [0.5, 0.9, 0.99])
+def test_a_percentile_reads_back_within_a_buckets_width(fake, q):
+    clock = PhaseClock("t-quantile")
+    lengths = [0.001 * 1.01 ** i for i in range(500)]   # 1 to 143 ms
+    for i, s in enumerate(lengths):
+        # in no order, as ticks come
+        _tick(clock, fake, stage=0.0, emit=0.0,
+              wait=lengths[(i * 137) % 500])
+    exact = sorted(lengths)[int(q * 500) - 1]
+    width = 2 ** (1 / tracing.HIST_PER_OCTAVE)
+    assert exact / width <= clock.quantile("step", q) <= exact * width
+    assert clock.quantile("never", q) is None
+
+
+def _slow_books(clock):
+    b = clock.books()
+    return b["tick_slow_total"], b["tick_slow_s"], b["slow_ticks"]
+
+
+def test_a_slow_tick_books_its_overrun_under_the_phase_that_held_it(fake):
+    seen = []
+    clock = PhaseClock("t-slow", kind="idle",
+                       on_slow=lambda tick, phase: seen.append((tick, phase)))
+    # a compiling first tick, then 10 ms ticks: the median is a 10 ms
+    # tick's (a mean would stand at 0.33 s and see no stall under 1.3 s)
+    _tick(clock, fake, kind="decode", wait=9.998)
+    for _ in range(tracing.SLOW_REFRESH - 3):
+        _tick(clock, fake, kind="decode")
+    # the 31st of its kind: no median yet, and nothing is slow
+    _tick(clock, fake, kind="decode", wait=0.5)
+    assert _slow_books(clock) == ({"decode": 0}, {}, [])
+    _tick(clock, fake, kind="decode")               # the 32nd
+    median = clock.quantile("decode", 0.5)
+    assert 0.01 / 1.1 < median < 0.01 * 1.1
+    _tick(clock, fake, kind="decode", wait=0.03)    # under four medians
+    assert _slow_books(clock)[0] == {"decode": 0}
+    start = fake.time()
+    _tick(clock, fake, kind="decode", wait=0.098)   # a 100 ms tick
+    total, by_phase, kept = _slow_books(clock)
+    assert total == {"decode": 1}
+    assert by_phase == {"loop.wait": pytest.approx(0.1 - median)}
+    (number, kind, began, seconds, held), = kept
+    assert (number, kind) == (clock.tick_no, "decode")
+    assert began == pytest.approx(start) and seconds == pytest.approx(0.1)
+    assert held == pytest.approx({
+        "loop.stage": 0.001, "loop.dispatch": 0.0, "loop.wait": 0.098,
+        "loop.emit": 0.001, "loop.tick": 0.0}, abs=1e-9)
+    assert seen == [(kept[0], "loop.wait")]
+    # time in the tick and in none of its phases is the tick's own
+    _tick(clock, fake, kind="decode", between=0.2)
+    total, by_phase, kept = _slow_books(clock)
+    assert total == {"decode": 2} and len(kept) == 2 and len(seen) == 2
+    assert by_phase["loop.tick"] == pytest.approx(0.21 - median)
+    # another kind keeps its own median: 40 ticks of 10 ms do not make
+    # an idle tick of 1 ms fast, nor one of 30 ms slow
+    for _ in range(40):
+        _tick(clock, fake, stage=0.0, wait=0.001, emit=0.0)
+    _tick(clock, fake, wait=0.03)
+    assert _slow_books(clock)[0] == {"decode": 2, "idle": 1}
+
+
+def test_a_kind_the_rule_leaves_out_books_no_slow_tick(fake):
+    clock = PhaseClock("t-unbounded", unbounded=("part",))
+    for kind in ("part", "part_decode", "full"):
+        for _ in range(40):
+            _tick(clock, fake, kind=kind)
+        _tick(clock, fake, kind=kind, wait=1.0)
+    total, by_phase, kept = _slow_books(clock)
+    assert total == {"part": 0, "part_decode": 0, "full": 1}
+    assert [t[1] for t in kept] == ["full"]
+    # its stall still shows in its histogram
+    assert max(clock.books()["tick_hist_part"]) > 0.5
+
+
+def test_the_newest_slow_ticks_are_kept(fake):
+    clock = PhaseClock("t-kept")
+    for _ in range(tracing.SLOW_REFRESH):
+        _tick(clock, fake)
+    for _ in range(tracing.SLOW_KEPT + 5):
+        _tick(clock, fake, wait=0.5)
+        for _ in range(3):
+            _tick(clock, fake)              # the median stays a 10 ms tick's
+    total, _, kept = _slow_books(clock)
+    assert total == {"step": tracing.SLOW_KEPT + 5}
+    assert len(kept) == tracing.SLOW_KEPT
+    assert kept[-1][0] == clock.tick_no - 3
+
+
+def test_reset_zeroes_the_books_and_the_median(fake):
+    clock = PhaseClock("t-reset-books")
+    for _ in range(40):
+        _tick(clock, fake, kind="decode")
+    _tick(clock, fake, kind="decode", wait=1.0)
+    assert _slow_books(clock)[0] == {"decode": 1}
+    clock.reset()
+    b = clock.books()
+    # a kind once met keeps its name, so that a reader who differences
+    # two readings finds its keys in both
+    assert b["tick_kind_total"] == {"decode": 0}
+    assert b["tick_kind_s"] == b["tick_kind_wait_s"] \
+        == b["tick_kind_gap_s"] == {"decode": 0.0}
+    assert b["tick_hist_decode"] == {} and b["gap_s"] == 0.0
+    assert _slow_books(clock) == ({"decode": 0}, {}, [])
+    # and no median until the kind has had its ticks again
+    _tick(clock, fake, kind="decode", wait=1.0)
+    assert _slow_books(clock)[0] == {"decode": 0}
+    assert clock.books()["tick_kind_total"] == {"decode": 1}
