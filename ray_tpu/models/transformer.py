@@ -1986,7 +1986,8 @@ def _paged_attn_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
             from ray_tpu.ops.sparse_attention import sparse_paged_attention
             att = sparse_paged_attention(
                 q, qi, wi, keys, values, pools["ki"], tables, positions,
-                live(), layer=layer, topk=kind.index_topk)
+                live(), layer=layer, topk=kind.index_topk,
+                impl=c.paged_impl, block_r=c.paged_row_block(h.shape[1]))
         else:
             # no indexer: every dense model's path. An indexer whose
             # window holds no more than index_topk tokens lands here too

@@ -25,8 +25,11 @@ and 30k of context, PERF.md, PR 37. It comes back when it is a kernel.)
 
 With a learned key selection (``select``) the heads attend the latent
 rows an indexer ranks highest and no other
-(:func:`ray_tpu.ops.sparse_attention.sparse_latent_attention`, plain XLA
-on every platform): the absorbed query and the expansion after the
+(:func:`ray_tpu.ops.sparse_attention.sparse_latent_attention`: plain XLA
+on every platform, a decode step gathering the selected rows; the
+paged kernel's ``chosen`` operand, which the per-head form's decode
+step takes on a TPU, is there for a latent pool as well and no caller
+here passes it yet): the absorbed query and the expansion after the
 softmax are the ones here.
 """
 

@@ -87,6 +87,14 @@ first ``v_width`` columns, as the value tile. It takes the scalars the
 dense form takes; at 128 rows a token most of a short question's chunk
 is row blocks of padding, which the bound above leaves without a page.
 
+A **learned key selection** (``chosen``;
+:mod:`ray_tpu.ops.sparse_attention`) is the same kernel with one more
+BlockSpec operand, the mask of the keys each query may count, a block
+of P pages' keys a grid step: it joins the causal mask in the body, the
+pages read are the ones read without it, and a dead step names the
+block it already holds (its index stops at the sequence's last group),
+so no copy follows. A call without it has no such operand.
+
 ``interpret=True`` runs the same kernel, copies and semaphores
 included, on CPU (tier-1 parity tests); on TPU it compiles with
 parallel/arbitrary dimension semantics like the flash kernels (what a
@@ -144,7 +152,8 @@ def paged_work_pages(lens, block_size: int):
 
 def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
                   sm_scale: float, v_width: Optional[int] = None,
-                  window: int = 0, row_blocks: int = 1):
+                  window: int = 0, row_blocks: int = 1,
+                  selects: bool = False):
     """One (batch b, kv head group g, row block r, page group t) step:
     fold pages ``t·pp .. t·pp + pp − 1`` of sequence b into the row
     block's online softmax, one kv head of the group at a time.
@@ -176,11 +185,20 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
     position among a row block's rows: a page group all of whose keys
     lie behind that row's window lies behind every row's, and is a dead
     step like one past the block's bound (no copy started, body
-    skipped)."""
+    skipped).
+
+    ``selects`` (a learned key selection): one more BlockSpec operand
+    behind the position column, ``chosen_ref`` ``(1, 1 | block_r, pp ·
+    bs)``: which of this step's keys each row may count (one row for
+    all of a decode call's, a row a token in a chunk's). It joins the
+    masks above; a key not chosen weighs nothing, and a row none of
+    whose keys is chosen comes back zero."""
     refs = iter(refs)
     bt_ref, lens_ref, layer_ref = next(refs), next(refs), next(refs)
     first_ref = next(refs) if window else None
-    q_ref, pos_ref, k_hbm = next(refs), next(refs), next(refs)
+    q_ref, pos_ref = next(refs), next(refs)
+    chosen_ref = next(refs) if selects else None
+    k_hbm = next(refs)
     v_hbm = next(refs) if v_width is None else None
     o_ref, m_s, l_s, acc_s, k_buf = (next(refs) for _ in range(5))
     # a latent page's value rows are its key rows: what a dead page's
@@ -277,6 +295,8 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
         # a row sees keys up to its own position (causal) and none of
         # the pages past the last live one
         key_max = jnp.minimum(pos_ref[0], pages * bs - 1)  # (block_r, 1)
+        if selects:
+            picked = chosen_ref[0].astype(jnp.int32) != 0
         for i in range(hb):                    # static: kv heads here
             q = q_ref[0, i]                    # (block_r, d)
             # the group's pages of one kv head, one under the other: a
@@ -292,6 +312,8 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
             seen = key_pos <= key_max
             if window:
                 seen &= key_pos > pos_ref[0] - window
+            if selects:
+                seen &= picked
             s = jnp.where(seen, s, _NEG_INF)
 
             m_prev = m_s[i]                    # (block_r, 128) lanes equal
@@ -300,6 +322,10 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
             m_next = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_next)
             p = jnp.exp(s - m_next[:, 0:1])
+            if selects:
+                # a row none of whose keys is chosen so far has m =
+                # -1e30 and would count exp(0) a key
+                p = jnp.where(seen, p, 0.0)
             l_s[i] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
             m_s[i] = m_next
             pv = jax.lax.dot_general(
@@ -338,16 +364,19 @@ def _row_block(rows: int, head_dim: int, dtype, block_r: Optional[int],
 
 
 def _step_vmem_bytes(pp: int, hb: int, bs: int, d: int, itemsize: int,
-                     block_r: int, pools: int = 2) -> int:
+                     block_r: int, pools: int = 2,
+                     chosen_bytes: int = 0) -> int:
     """VMEM one grid step holds with ``pp`` pages a group: the K and V
     pages (two groups each: this step's and the next's; ``pools`` 1
     where a latent page is both), one head's
-    joined K and V tile, the f32 score tile and its exponentials, and
+    joined K and V tile, the f32 score tile and its exponentials
+    (beside them a selection's block, ``chosen_bytes`` a key,
+    double-buffered), and
     what does not grow with ``pp``: q and out blocks (double-buffered)
     and the (m, l, acc) scratch."""
     pages = pools * 2 * pp * hb * bs * d * itemsize
     joined = pools * pp * bs * d * itemsize
-    scores = 2 * block_r * _round_up(pp * bs, 128) * 4
+    scores = (2 * block_r * 4 + 2 * chosen_bytes) * _round_up(pp * bs, 128)
     fixed = 2 * 2 * hb * block_r * d * itemsize \
         + hb * block_r * (2 * 128 + d) * 4
     return pages + joined + scores + fixed
@@ -375,11 +404,13 @@ def paged_pages_per_step(rows: int, kv_heads: int, block_size: int,
 
 
 def _pages_per_step(hb: int, bs: int, d: int, dtype, block_r: int,
-                    table_len: int, pools: int = 2) -> int:
+                    table_len: int, pools: int = 2,
+                    chosen_bytes: int = 0) -> int:
     itemsize = jnp.dtype(dtype).itemsize
     for pp in _PAGE_GROUPS:
         if pp // 2 < table_len and _step_vmem_bytes(
-                pp, hb, bs, d, itemsize, block_r, pools) <= _VMEM_BUDGET:
+                pp, hb, bs, d, itemsize, block_r, pools,
+                chosen_bytes) <= _VMEM_BUDGET:
             return pp
     return 1
 
@@ -436,7 +467,9 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                           block_r: Optional[int] = None,
                           interpret: bool = False,
                           v_width: Optional[int] = None,
-                          window: int = 0) -> jnp.ndarray:
+                          window: int = 0,
+                          chosen: Optional[jnp.ndarray] = None
+                          ) -> jnp.ndarray:
     """Paged attention of new-token queries against the block pool.
 
     Same contract as the XLA reference
@@ -471,9 +504,28 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     ``q_positions`` and ``lens`` counted from that page's first
     position: a window layer's table need hold no page behind the
     window.
+
+    ``chosen [B, C, W]`` (a learned key selection,
+    :mod:`ray_tpu.ops.sparse_attention`): which keys each query may
+    count, over the table's window (``W`` keys from position 0; keys
+    past ``W`` are not chosen, columns past the table are dropped). It
+    joins the causal mask: a key not chosen weighs nothing, a row none
+    of whose keys is chosen comes back zero, and the pages read are
+    the ones a call without it reads (every live page up to the row
+    block's bound). It travels as one more BlockSpec operand, a block
+    of ``pages per step x block_size`` keys a grid step: one int32 row
+    a sequence where ``C == 1`` (a decode call: the heads of a group
+    share their token's selection), an int8 row a token otherwise, the
+    query rows then ordered head-major (row ``r`` of a kv head is head
+    ``r // C'``, token ``r % C'``, ``C'`` = C in whole row blocks) so
+    that a row block's tokens are a run of ``chosen``'s rows. Without
+    it the call has no such operand and is the kernel it was.
     """
     if window and v_width is not None:
         raise ValueError("a latent cache has no sliding window")
+    if window and chosen is not None:
+        raise ValueError("a selection over a sliding window: no layer "
+                         "has both")
     if (v_width is None) != (v_cache is not None):
         raise ValueError("pass a V pool, or v_width for a latent pool "
                          "whose page is key and value, not both")
@@ -488,24 +540,42 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     rows = c * rep
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    block_r = _row_block(rows, d, q.dtype, block_r,
-                         "cpu" if interpret else None)
-    rows_pad = _round_up(rows, block_r)
+    chip = "cpu" if interpret else None
+    # a chunk's selection: head-major rows, the tokens in whole row
+    # blocks, so that a block's rows are a run of ``chosen``'s
+    by_head = chosen is not None and c > 1
+    if by_head:
+        block_r = _row_block(c, d, q.dtype, block_r, chip)
+        c_pad = _round_up(c, block_r)
+        rows_pad = rep * c_pad
+    else:
+        block_r = _row_block(rows, d, q.dtype, block_r, chip)
+        c_pad, rows_pad = c, _round_up(rows, block_r)
     nr = rows_pad // block_r
     hb = _heads_per_step(g, block_r)
+    chosen_bytes = 0 if chosen is None else (block_r if by_head else 4)
     pp = _pages_per_step(hb, bs, d, k_cache.dtype, block_r, t,
-                         2 if v_width is None else 1)
+                         2 if v_width is None else 1, chosen_bytes)
 
     # Group-major query rows: row r of kv head g is (c = r // rep,
     # head = g*rep + r % rep). Only q (tiny) is reshaped — never the
     # cache.
-    qg = q.reshape(b, c, g, rep, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, g, rows, d)
-    pos_rows = jnp.repeat(q_positions.astype(jnp.int32), rep, axis=1)
-    if rows_pad != rows:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
-        pos_rows = jnp.pad(pos_rows, ((0, 0), (0, rows_pad - rows)),
-                           constant_values=-1)
+    pos_rows = q_positions.astype(jnp.int32)
+    if by_head:
+        qg = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, 0))) \
+            .reshape(b, c_pad, g, rep, d).transpose(0, 2, 3, 1, 4) \
+            .reshape(b, g, rows_pad, d)
+        pos_rows = jnp.tile(jnp.pad(pos_rows, ((0, 0), (0, c_pad - c)),
+                                    constant_values=-1), (1, rep))
+    else:
+        qg = q.reshape(b, c, g, rep, d).transpose(0, 2, 1, 3, 4) \
+            .reshape(b, g, rows, d)
+        pos_rows = jnp.repeat(pos_rows, rep, axis=1)
+        if rows_pad != rows:
+            qg = jnp.pad(qg,
+                         ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
+            pos_rows = jnp.pad(pos_rows, ((0, 0), (0, rows_pad - rows)),
+                               constant_values=-1)
     if window:
         # the lowest position of each row block, its padding (-1) aside;
         # a block of padding alone starts behind every page
@@ -525,14 +595,33 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scalars = (block_tables.astype(jnp.int32), lens.astype(jnp.int32), layer)
     if window:
         scalars += scalars_window
+    groups = pl.cdiv(t, pp)
+    selection, selection_specs = (), []
+    if chosen is not None:
+        # the grid's keys wide, the tokens in whole row blocks
+        keys = groups * pp * bs
+        chosen = chosen[:, :, :keys].astype(jnp.int8 if by_head
+                                            else jnp.int32)
+        selection = (jnp.pad(chosen, (
+            (0, 0), (0, c_pad - c), (0, keys - chosen.shape[2]))),)
+
+        def chosen_map(b_, g_, r_, t_, bt_ref, lens_ref, *scalars):
+            # a step past the sequence's last page folds nothing: it
+            # names the block it has, and no copy follows
+            last = jnp.maximum(pl.cdiv(lens_ref[b_], bs) - 1, 0) // pp
+            return (b_, r_ % (c_pad // block_r) if by_head else 0,
+                    jnp.minimum(t_, last))
+        selection_specs = [pl.BlockSpec(
+            (1, block_r if by_head else 1, pp * bs), chosen_map)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, g // hb, nr, pl.cdiv(t, pp)),
+        grid=(b, g // hb, nr, groups),
         in_specs=[
             pl.BlockSpec((1, hb, block_r, d), q_map),
             pl.BlockSpec((1, block_r, 1), pos_map),
-        ] + [pl.BlockSpec(memory_space=pl.ANY)  # the pools, left in HBM
-             for _ in pools],
+        ] + selection_specs + [
+            pl.BlockSpec(memory_space=pl.ANY)  # the pools, left in HBM
+            for _ in pools],
         out_specs=pl.BlockSpec((1, hb, block_r, dv), q_map),
         scratch_shapes=[
             pltpu.VMEM((hb, block_r, 128), jnp.float32),  # running max m
@@ -546,7 +635,8 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, bs=bs, hb=hb, pp=pp, slots=t,
                           sm_scale=float(sm_scale), v_width=v_width,
-                          window=int(window), row_blocks=nr),
+                          window=int(window), row_blocks=nr,
+                          selects=chosen is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, rows_pad, dv), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -554,7 +644,10 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                                  "arbitrary")),
         interpret=interpret,
         name="paged_attention" if v_width is None else "mla_attn",
-    )(*scalars, qg, pos_rows, *pools)
+    )(*scalars, qg, pos_rows, *selection, *pools)
+    if by_head:
+        return out.reshape(b, g, rep, c_pad, dv)[:, :, :, :c] \
+            .transpose(0, 3, 1, 2, 4).reshape(b, c, h, dv)
     out = out[:, :, :rows, :].reshape(b, g, c, rep, dv) \
         .transpose(0, 2, 1, 3, 4).reshape(b, c, h, dv)
     return out

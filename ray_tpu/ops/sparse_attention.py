@@ -14,9 +14,20 @@ over the selected rows is the absorbed one. The pools come in whole
 with a layer index, as in :mod:`ray_tpu.ops.paged_flash`, so a step
 program's layer scan carries and writes them in place.
 
-Everything here is plain XLA, one form on every platform (CPU tests run
-what the chip runs). Work follows the live context, not the window: the
-loops over key tiles stop at the longest live sequence.
+The indexer and the selection are plain XLA, one form on every
+platform, and so is everything over a latent cache. Over per-head K/V
+the attention itself has two forms. A decode step (:func:`decode_choice`)
+is XLA's gather of the selected rows, or the paged kernel
+(:mod:`ray_tpu.ops.paged_flash`) reading every live page with the
+selection as a mask operand, which the TPU takes where the window is
+short enough for streamed pages to beat gathered rows. A chunk
+(:func:`chunk_choice`) is a tiled masked online softmax in XLA
+(:func:`_chunk_masked`), or the same kernel with a mask row a token,
+which the TPU takes wherever the page tiles (both read every live
+page; the kernel keeps its score tiles in VMEM). Off the TPU the plain
+forms run; the kernel is tested in interpret mode. Work follows the
+live context, not the window: the loops over key tiles stop at the
+longest live sequence.
 
 - :func:`index_scores` — ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] .
   kI[s])`` against the cached ``kI``, tiled over keys.
@@ -25,11 +36,13 @@ loops over key tiles stop at the longest live sequence.
   k-th largest value is found two bits a pass on an order-preserving
   integer image of the scores, 16 counting passes and no sort.
   ``jax.lax.approx_max_k`` would be a different model.
-- a decode step (one query a sequence) takes ``jax.lax.top_k`` of its
-  row and reads K and V of the selected tokens only, ``topk`` rows a
-  sequence and layer whatever the context; a prefill chunk (thousands
-  of queries, each with a selection of its own) masks a tiled
-  online-softmax pass over the live pages.
+- a decode step (one query a sequence) either takes ``jax.lax.top_k``
+  of its row and reads K and V of the selected tokens only, ``topk``
+  rows a kv head, sequence and layer whatever the context, or hands
+  ``topk_mask`` of its row to the paged kernel, which reads the live
+  pages once; a prefill chunk (thousands of queries, each with a
+  selection of its own) masks an online-softmax pass over the live
+  pages, XLA's tiled one or the paged kernel's.
 """
 
 from __future__ import annotations
@@ -39,7 +52,19 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention import _paged_unfit, _resolve
+
 _NEG_INF = -1e30
+
+#: seconds XLA's gather takes a selected row of a per-head pool on a
+#: v5e: 256 B rows, 12.6-14.0 ns at windows of 32k-128k whatever the
+#: fill (tools/sparse_decode_crossover.py; PERF.md section 6, PR 59)
+_GATHER_S_PER_ROW = 13.5e-9
+
+#: bytes a second at which the paged kernel's masked decode call reads
+#: full tables on a v5e: 341-365 GB/s at 16k-128k live keys a sequence,
+#: pages of 16 KB (the same tool and runs)
+_MASKED_BYTES_PER_S = 350e9
 
 #: query rows of a chunk handled at a time
 _ROW_BLOCK = 256
@@ -152,6 +177,48 @@ def topk_mask(scores, k: int):
     return ((u > t) | (equal & (index <= cut))) & visible
 
 
+def masked_read_wins(topk: int, kv_heads: int, row_bytes: int,
+                     window: int, pools: int = 2) -> bool:
+    """Whether a decode step over a ``window`` of keys is cheaper as one
+    masked read of every page than as a gather of the selected rows.
+    The gather fetches ``topk`` rows a kv head out of each of ``pools``
+    pools at a cost a row that no context changes; the masked read
+    streams the window, ``row_bytes`` a key and kv head, at the rate the
+    kernel reaches. From shapes alone (the heads and pools multiply both
+    sides, and are there so that each side reads as its seconds): rows
+    of 256 B under top-2048 cross near 38k keys, by the WINDOW, which a
+    full table reaches; at the fills a cell sees the read is shorter."""
+    rows = pools * kv_heads
+    gather_s = topk * rows * _GATHER_S_PER_ROW
+    masked_s = window * rows * row_bytes / _MASKED_BYTES_PER_S
+    return masked_s < gather_s
+
+
+def decode_choice(impl: str, topk: int, k_pool, table_len: int,
+                  record: bool = False) -> str:
+    """What a decode step's selected attention over per-head K/V runs
+    as under ``impl``: the paged kernel with the selection as a mask
+    ("kernel" | "interpret") or the gather of the selected rows
+    ("reference": off the TPU, where the page does not tile, and where
+    :func:`masked_read_wins` says the window is too long)."""
+    kvh, bs, d = k_pool.shape[-3:]
+    unfit = _paged_unfit(k_pool, k_pool)       # a head's q is a row wide
+    if not unfit and not masked_read_wins(
+            topk, kvh, d * k_pool.dtype.itemsize, table_len * bs):
+        unfit = f"gathering {topk} rows beats reading {table_len * bs}"
+    return _resolve("sparse_decode", impl, "kernel", unfit, record)
+
+
+def chunk_choice(impl: str, k_pool, record: bool = False) -> str:
+    """What a chunk's selected attention over per-head K/V runs as under
+    ``impl``: the paged kernel with the selections as a mask ("kernel" |
+    "interpret") or :func:`_chunk_masked` ("reference": off the TPU, or
+    where the page does not tile). Both read every live page, so no
+    length decides between them."""
+    return _resolve("sparse_chunk", impl, "kernel",
+                    _paged_unfit(k_pool, k_pool), record)
+
+
 def _grouped(q, kv_heads: int):
     b, c, h, d = q.shape
     return q.reshape(b, c, kv_heads, h // kv_heads, d)
@@ -234,9 +301,24 @@ def _chunk_masked(q, k_pool, v_pool, block_tables, chosen, lens, layer,
     return o.transpose(0, 3, 1, 2, 4).reshape(b, c, h, d).astype(q.dtype)
 
 
+@jax.named_scope("sparse_attn")
+def _through_kernel(q, k_pool, v_pool, block_tables, positions, lens,
+                    layer, sm_scale: float, block_r, interpret: bool,
+                    chosen):
+    """The queries' attention over their live pages through the paged
+    kernel, a key counted only where ``chosen [B, C, W]`` says its query
+    selected it."""
+    from ray_tpu.ops.paged_flash import paged_flash_attention
+    return paged_flash_attention(
+        q, k_pool, v_pool, block_tables, positions, lens, layer=layer,
+        sm_scale=sm_scale, block_r=block_r, interpret=interpret,
+        chosen=chosen)
+
+
 def sparse_paged_attention(q, qi, wi, k_pool, v_pool, ki_pool,
                            block_tables, positions, lens, *, layer,
-                           topk: int, sm_scale=None):
+                           topk: int, sm_scale=None, impl: str = "auto",
+                           block_r=None):
     """Attention of new-token queries over the ``topk`` cached keys the
     indexer ranks highest for each (all of them while a query sees at
     most ``topk``), the new tokens' own K, V and ``kI`` having been
@@ -244,25 +326,40 @@ def sparse_paged_attention(q, qi, wi, k_pool, v_pool, ki_pool,
     C, Hi]``; pools whole, ``layer`` an int32 scalar; ``positions [B,
     C]``, ``lens [B]`` as for :func:`ray_tpu.ops.paged_attention`. Rows
     at positions past ``lens`` (a chunk's padding) come back zero or
-    attend live keys: the caller's to discard."""
+    attend live keys: the caller's to discard. ``impl`` / ``block_r``:
+    as for :func:`ray_tpu.ops.paged_attention`, for the calls of the
+    paged kernel (:func:`decode_choice`, :func:`chunk_choice`)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     c = q.shape[1]
     if c == 1:
         scores = index_scores(qi, wi, ki_pool, block_tables, positions,
                               lens, layer)
+        choice = decode_choice(impl, topk, k_pool, block_tables.shape[1],
+                               record=True)
+        if choice != "reference":
+            return _through_kernel(q, k_pool, v_pool, block_tables,
+                                   positions, lens, layer, sm_scale,
+                                   block_r, choice == "interpret",
+                                   topk_mask(scores, topk))
         # the scores' window is the table padded to whole tiles
         bt = _pad_table(block_tables,
                         scores.shape[-1] // k_pool.shape[3])
         return _decode_selected(q, k_pool, v_pool, bt, scores, layer,
                                 topk, sm_scale)
 
+    choice = chunk_choice(impl, k_pool, record=True)
+
     def chunk(q, qi, wi, positions, lens):
         scores = index_scores(qi, wi, ki_pool, block_tables, positions,
                               lens, layer)
-        return _chunk_masked(q, k_pool, v_pool, block_tables,
-                             topk_mask(scores, topk), lens, layer,
-                             sm_scale)
+        chosen = topk_mask(scores, topk)
+        if choice == "reference":
+            return _chunk_masked(q, k_pool, v_pool, block_tables, chosen,
+                                 lens, layer, sm_scale)
+        return _through_kernel(q, k_pool, v_pool, block_tables, positions,
+                               lens, layer, sm_scale, block_r,
+                               choice == "interpret", chosen)
 
     return _in_row_blocks(chunk, (q, qi, wi), positions, lens)
 

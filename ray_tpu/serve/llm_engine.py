@@ -792,8 +792,9 @@ class LLMEngine:
         # layer of a kind takes a kv head group and those with a live
         # row (the others, a short prompt's padding, hold no page),
         # summed over chunks; a kind has its own heads a kv head. A
-        # model that selects its keys runs no chunk through the kernel
-        # and books none.
+        # model that selects its keys books none: its chunks reach the
+        # kernel a run of tokens at a time with the rows head-major
+        # (ops/sparse_attention.py), another count.
         selecting = 0 < model_config.index_topk \
             < ec.blocks_per_seq * ec.kv_block_size
         kinds = () if selecting else ("full", "window") if Tw else ("full",)
@@ -809,6 +810,24 @@ class LLMEngine:
         # (min(visible, index_topk)), summed over queries; keys the
         # indexer scored and (token, expert) assignments, over layers
         self._sparse = collections.Counter()
+        # what the step programs' selected attention was built as
+        # (ops/sparse_attention.py), the decode step's and a chunk's:
+        # the paged kernel with the selection as a mask, or the plain
+        # form ("reference": off the TPU, where the shapes rule the
+        # kernel out, and every latent cache). None: the model selects
+        # nothing.
+        self._sparse_impl = None
+        if selecting and model_config.kv_lora_rank:
+            self._sparse_impl = {"decode": "reference",
+                                 "prefill": "reference"}
+        elif selecting:
+            from ray_tpu.ops.sparse_attention import (chunk_choice,
+                                                      decode_choice)
+            self._sparse_impl = {
+                "decode": decode_choice(
+                    model_config.paged_impl, model_config.index_topk,
+                    pool, ec.blocks_per_seq),
+                "prefill": chunk_choice(model_config.paged_impl, pool)}
         self._prompt_blocks_total = 0   # full prompt blocks seen
         self._cow_copies = 0
         # disagg hand-off accounting (the bench's per-request ship
@@ -1503,6 +1522,7 @@ class LLMEngine:
                                      ("indexer_keys_scored", "scored"))
                    for kind in ("decode", "prefill")},
                 "moe_assignments_total": self._sparse["assigned"],
+                **self._sparse_stats(),
                 "kv_block_size": self.config.kv_block_size,
                 "paged_impl": self.model_config.paged_impl,
                 # what each traced attention call resolved to and why
@@ -1613,6 +1633,22 @@ class LLMEngine:
             "prefix_hits": self._prefix_hits,
             "prefix_hits_cut": self._prefix_hits_cut,
         }
+
+    def _sparse_stats(self) -> Dict[str, Any]:
+        """``stats()``' keys of a selecting model's step programs (call
+        with the lock held): the form the decode step's and a chunk's
+        selected attention were built as, and the decode steps that ran
+        through the kernel (all, or none where the gather runs; a
+        verify step is a chunk's call)."""
+        impl = self._sparse_impl
+        if impl is None:
+            return {}
+        through = impl["decode"] != "reference" \
+            and not self.config.spec_tokens
+        return {"sparse_decode_impl": impl["decode"],
+                "sparse_prefill_impl": impl["prefill"],
+                "sparse_decode_kernel_steps_total":
+                    self._decode_steps if through else 0}
 
     def _state_stats(self) -> Dict[str, Any]:
         """``stats()``' keys of the recurrent state (call with the lock

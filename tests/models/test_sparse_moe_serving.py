@@ -60,23 +60,41 @@ def _through_cache(cfg, params, ids, prompt_len, chunk):
     return jnp.concatenate(got)
 
 
-@pytest.mark.parametrize("tile_bytes,chunk,row_block", [
-    (256 << 20, 64, 256),    # one tile holds the window
-    (1 << 16, 64, 256),      # the key loops run several tiles
-    (1 << 12, 48, 256),      # one page a tile, a chunk no power of 2
-    (1 << 16, 64, 16),       # a chunk in four blocks of rows; the last
-                             # chunk (12 live rows) runs one of them
+@pytest.mark.parametrize("tile_bytes,chunk,row_block,impl", [
+    (256 << 20, 64, 256, "reference"),   # one tile holds the window
+    (1 << 16, 64, 256, "reference"),     # the key loops run several tiles
+    (1 << 12, 48, 256, "reference"),     # one page a tile, a chunk no
+                                         # power of 2
+    (1 << 16, 64, 16, "reference"),      # a chunk in four blocks of rows;
+                                         # the last chunk (12 live rows)
+                                         # runs one of them
+    # the decode step and the chunks through the paged kernel, the
+    # selection its mask operand (what the chip runs at Keye's window);
+    # the last in four blocks of rows
+    (256 << 20, 64, 256, "interpret"),
+    (1 << 12, 48, 256, "interpret"),
+    (1 << 16, 64, 16, "interpret"),
 ])
 def test_prefill_then_decode_match_the_reference(monkeypatch, tile_bytes,
-                                                 chunk, row_block):
+                                                 chunk, row_block, impl):
     """150 tokens of context under topk 32: the selection is at work in
-    both the masked chunk path and the gathered decode path."""
+    both the masked chunk path and the decode path, gathered
+    (``reference``) or read through the paged kernel (``interpret``)."""
     monkeypatch.setattr(SA, "_TILE_BYTES", tile_bytes)
     monkeypatch.setattr(SA, "_ROW_BLOCK", row_block)
-    cfg = TransformerConfig(**KEYE)
+    from ray_tpu.ops.attention import dispatch_log
+
+    def calls(op):
+        return sum(e["count"] for e in dispatch_log()
+                   if (e["op"], e["impl"]) == (op, impl))
+    cfg = TransformerConfig(**dict(KEYE, paged_impl=impl))
     params = init_params(cfg, jax.random.PRNGKey(0))
     ids = np.random.default_rng(0).integers(0, 128, 150).astype(np.int32)
+    before = calls("sparse_decode"), calls("sparse_chunk")
     got = _through_cache(cfg, params, ids, 140, chunk)
+    # each program traced once: a layer scan
+    assert (calls("sparse_decode"), calls("sparse_chunk")) \
+        == (before[0] + 1, before[1] + 1)
     want = keye.forward(params, jnp.asarray(ids)[None], _hp())[0]
     assert float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))) \
         < 1e-5
@@ -173,3 +191,41 @@ def test_counts_and_the_paths_that_refuse():
                 jnp.zeros((1, 8), jnp.int32), init_kv_cache(switch, 4, BS),
                 jnp.zeros((1, TABLE), jnp.int32), jnp.zeros((1,), jnp.int32),
                 jnp.full((1,), 8, jnp.int32))
+
+
+@pytest.mark.parametrize("name,kv_heads,row_bytes,window,pools,masked", [
+    # Keye: 4 kv heads x 128 bf16, K and V, a 32,768 window
+    ("keye", 4, 256, 32768, 2, True),
+    # A.X-K2: one latent row of 640 bf16 a token, one pool, 65,536
+    ("a.x-k2", 1, 1280, 65536, 1, False),
+    # a per-head model at a 131,072 window: a read four times Keye's
+    ("per_head_128k", 4, 256, 131072, 2, False),
+    ("per_head_24k", 8, 256, 24576, 2, True),
+    ("per_head_48k", 8, 256, 49152, 2, False),
+])
+def test_the_decode_form_follows_from_shapes(monkeypatch, name, kv_heads,
+                                             row_bytes, window, pools,
+                                             masked):
+    """``masked_read_wins`` by hand: 2048 rows a kv head and pool at the
+    gather's cost a row against the window's bytes at the kernel's rate;
+    and ``decode_choice`` on a TPU takes the kernel exactly where the
+    masked read wins and the page tiles."""
+    rows = pools * kv_heads
+    gather_s = 2048 * rows * SA._GATHER_S_PER_ROW
+    masked_s = window * rows * row_bytes / SA._MASKED_BYTES_PER_S
+    assert (masked_s < gather_s) == masked
+    assert SA.masked_read_wins(2048, kv_heads, row_bytes, window,
+                               pools) == masked
+    if pools == 1:
+        return                          # a latent cache never asks
+    import ray_tpu.ops.attention as A
+    pool = jax.ShapeDtypeStruct((6, 64, kv_heads, 16, 128), jnp.bfloat16)
+    assert SA.decode_choice("auto", 2048, pool, window // 16) \
+        == "reference"                  # off the TPU: the gather
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    assert SA.decode_choice("auto", 2048, pool, window // 16) \
+        == ("kernel" if masked else "reference")
+    # a page that does not tile rules the kernel out whatever the window
+    odd = jax.ShapeDtypeStruct((6, 64, kv_heads, 8, 128), jnp.bfloat16)
+    assert SA.decode_choice("auto", 2048, odd, window // 8) \
+        == "reference"

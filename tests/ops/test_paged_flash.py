@@ -981,3 +981,155 @@ def test_paged_row_blocks_is_the_kernels_own_count(start, chunk, n, rep,
     is_live = ((pos >= 0) & (pos < start + n)).reshape(-1, br).any(axis=1)
     assert (blocks, live) == (len(is_live), int(is_live.sum()))
     assert is_live[:live].all()
+
+
+# ------------------------------------------------- a selection as a mask
+#: B sequences of (first position, query tokens, live tokens) each; the
+#: tables hold 8 pages of 4. ``k``: keys a query keeps; ``ties``: scores
+#: drawn from four values, so that the threshold cuts a run of equals.
+SELECTIONS = {
+    # one query a sequence, 8 heads on a kv head: lengths past and under
+    # k (a row that sees fewer keys than k keeps them all), a slot with
+    # no sequence (lens -1: position -1), a length of one
+    "decode_rep8": dict(H=32, KVH=4, k=6, ties=False, pp=4, block_r=None,
+                        seqs=[(30, 1, 1), (3, 1, 1), (-1, 1, 0),
+                              (0, 1, 1), (17, 1, 1)]),
+    "decode_ties": dict(H=8, KVH=2, k=5, ties=True, pp=2, block_r=None,
+                        seqs=[(21, 1, 1), (-1, 1, 0), (9, 1, 1)]),
+    # the last group partly live (18 keys: pages 0-4 of groups of 4)
+    "decode_part_group": dict(H=4, KVH=4, k=7, ties=False, pp=4,
+                              block_r=None, seqs=[(17, 1, 1), (31, 1, 1)]),
+    # a row block of queries, each with a selection of its own: two row
+    # blocks a head, a ragged end (the second block of sequence 1 is
+    # padding alone), tied scores
+    "chunk": dict(H=8, KVH=2, k=6, ties=False, pp=2, block_r=8,
+                  seqs=[(12, 16, 16), (5, 16, 7)]),
+    "chunk_ties": dict(H=4, KVH=4, k=4, ties=True, pp=4, block_r=8,
+                       seqs=[(0, 16, 16), (16, 16, 3)]),
+    # five tokens (a verify call's): padded to one whole row block
+    "chunk_padded": dict(H=8, KVH=2, k=3, ties=False, pp=None, block_r=None,
+                         seqs=[(20, 5, 5), (2, 5, 5)]),
+}
+
+
+def _selected_softmax(q, k_seq, v_seq, chosen):
+    """Plain float32 softmax of each query over the keys ``chosen [B,
+    C, K]`` names; zero where it names none."""
+    b, c, h, d = q.shape
+    rep = h // k_seq.shape[2]
+    k, v = (np.repeat(a, rep, axis=2) for a in (k_seq, v_seq))
+    s = np.einsum("bchd,bkhd->bhck", q, k) / np.sqrt(d)
+    s = np.where(chosen[:, None], s, -np.inf)
+    top = np.max(s, axis=-1, keepdims=True)
+    p = np.where(chosen[:, None], np.exp(s - np.where(
+        np.isfinite(top), top, 0.0)), 0.0)
+    p = p / np.where(p.sum(-1, keepdims=True) == 0, 1.0,
+                     p.sum(-1, keepdims=True))
+    return np.einsum("bhck,bkhd->bchd", p, v)
+
+
+@pytest.mark.parametrize("case", sorted(SELECTIONS))
+def test_a_selection_masks_the_kernels_read(case, group_of):
+    """``chosen`` as an operand: every live row is the plain float32
+    softmax over the keys its query selected (``topk_mask``: exact, ties
+    to the lower index) and, for one query a sequence, what
+    ``_decode_selected``'s gather of those rows gives; a row with no
+    key chosen (a slot with ``lens <= 0``, a chunk's padding) is zero,
+    and NaN planted in every page past a sequence's live ones and in
+    the trash page reaches nothing."""
+    from ray_tpu.ops.sparse_attention import (_chunk_masked,
+                                              _decode_selected, topk_mask)
+    spec = SELECTIONS[case]
+    group_of(spec["pp"])
+    H, KVH, k = spec["H"], spec["KVH"], spec["k"]
+    D, bs, T = 8, 4, 8
+    first, C, n = np.array(spec["seqs"], np.int32).T
+    B, C = len(first), int(C[0])
+    k_seq, v_seq, kc, vc, bt = _paged_case(71, B, T * bs, H, KVH, D, bs, T)
+    rng = np.random.default_rng(72)
+    q = rng.normal(size=(B, C, H, D)).astype(np.float32)
+    pos = np.where(first[:, None] >= 0,
+                   first[:, None] + np.arange(C, dtype=np.int32), -1)
+    lens = np.where(first >= 0, first + n, first)
+    scores = rng.integers(0, 4, size=(B, C, T * bs)).astype(np.float32) \
+        if spec["ties"] else \
+        rng.normal(size=(B, C, T * bs)).astype(np.float32)
+    scores = np.where(np.arange(T * bs) <= pos[..., None], scores, -np.inf)
+    chosen = np.asarray(topk_mask(jnp.asarray(scores), k))
+    live = np.arange(C) < n[:, None]                       # [B, C]
+    assert (chosen.sum(-1)[live] == np.minimum(pos + 1, k)[live]).all()
+    if spec["ties"]:                 # the cut falls inside a run of equals
+        kth = np.sort(scores, -1)[..., -k]
+        assert ((scores == kth[..., None]).sum(-1)[live] > 1).any()
+
+    def run(kc, vc):
+        return np.asarray(paged_flash_attention(
+            jnp.asarray(q), jnp.asarray(kc)[None], jnp.asarray(vc)[None],
+            jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(lens), layer=0,
+            block_r=spec["block_r"], interpret=True,
+            chosen=jnp.asarray(chosen)))
+
+    got = run(kc, vc)
+    want = _selected_softmax(q, k_seq, v_seq, chosen)
+    np.testing.assert_allclose(got[live], want[live], **TOL)
+    assert not got[lens <= 0].any()
+    if C == 1:
+        other = np.asarray(_decode_selected(
+            jnp.asarray(q), jnp.asarray(kc)[None], jnp.asarray(vc)[None],
+            jnp.asarray(bt), jnp.asarray(scores), 0, k, D ** -0.5))
+    else:
+        other = np.asarray(_chunk_masked(
+            jnp.asarray(q), jnp.asarray(kc)[None], jnp.asarray(vc)[None],
+            jnp.asarray(bt), jnp.asarray(chosen), jnp.asarray(lens), 0,
+            D ** -0.5))
+    np.testing.assert_allclose(got[live], other[live], **TOL)
+    kp, vp = _poisoned(kc), _poisoned(vc)
+    for b in range(B):
+        for pool in (kp, vp):
+            pool[bt[b, max(-(-int(lens[b]) // bs), 0):]] = np.nan
+    np.testing.assert_array_equal(run(kp, vp)[live], got[live])
+
+
+@pytest.mark.parametrize("chunk", [1, 8])
+def test_a_selection_masks_a_latent_pools_read(chunk, group_of):
+    """The operand on the latent form (one pool whose page is key and
+    value, 4 heads on its one key head): the kernel with ``chosen``
+    equals the plain latent pass under the same mask
+    (``latent_attention._blocked``), for one query a sequence and for a
+    row block of queries, a slot with no sequence among them."""
+    from ray_tpu.ops.latent_attention import _blocked, latent_row_width
+    from ray_tpu.ops.sparse_attention import topk_mask
+    group_of(2)
+    H, rope, rank, bs, T, k = 4, 8, 128, 4, 8, 5
+    first = np.array([20, -1, 3], np.int32) if chunk == 1 \
+        else np.array([12, 0, 24], np.int32)
+    B = len(first)
+    rng = np.random.default_rng(91)
+    row = latent_row_width(rank, rope)
+    pool = rng.normal(size=(1 + B * T, 1, bs, row)).astype(np.float32)
+    pool[..., rank + rope:] = 0.0
+    bt = 1 + rng.permutation(B * T).astype(np.int32).reshape(B, T)
+    pos = np.where(first[:, None] >= 0,
+                   first[:, None] + np.arange(chunk, dtype=np.int32), -1)
+    lens = np.where(first >= 0, first + chunk, first)
+    q_abs = rng.normal(size=(B, chunk, H, rank)).astype(np.float32)
+    q_rope = rng.normal(size=(B, chunk, H, rope)).astype(np.float32)
+    scores = rng.normal(size=(B, chunk, T * bs)).astype(np.float32)
+    scores = np.where(np.arange(T * bs) <= pos[..., None], scores, -np.inf)
+    chosen = topk_mask(jnp.asarray(scores), k)
+    scale = (16 + rope) ** -0.5
+    want = np.asarray(_blocked(
+        jnp.asarray(q_abs), jnp.asarray(q_rope), jnp.asarray(pool)[None],
+        jnp.asarray(bt), jnp.asarray(pos), 0, jnp.asarray(lens), scale,
+        chosen=chosen))
+    q_lat = np.concatenate(
+        [q_abs, q_rope, np.zeros((B, chunk, H, row - rank - rope),
+                                 np.float32)], -1)
+    got = np.asarray(paged_flash_attention(
+        jnp.asarray(q_lat), jnp.asarray(_poisoned(pool))[None], None,
+        jnp.asarray(bt), jnp.asarray(pos), jnp.asarray(lens), layer=0,
+        sm_scale=scale, block_r=8, interpret=True, v_width=rank,
+        chosen=chosen))
+    live = lens > 0
+    np.testing.assert_allclose(got[live], want[live], **TOL)
+    assert not got[~live].any()

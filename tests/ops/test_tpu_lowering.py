@@ -107,6 +107,96 @@ def test_old_page_layout_would_not_lower():
         _lower_for_tpu(old_layout, _s((4, BLOCK, HEADS, HEAD_DIM)))
 
 
+# ------------------------- a call without a selection is the parent's
+def _without_locations(text: str) -> str:
+    """A lowered program with each Mosaic kernel's serialized body
+    parsed and printed without source locations (the bytecode carries
+    file lines, which any edit above the kernel moves), the call's own
+    StableHLO behind them."""
+    import base64
+    import json
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    config = re.compile(r'backend_config = "((?:[^"\\]|\\.)*)"')
+    bodies = []
+    for found in config.finditer(text):
+        cfg = json.loads(re.sub(r"\\([0-9A-Fa-f]{2})",
+                                lambda h: chr(int(h.group(1), 16)),
+                                found.group(1)))
+        with ir.Context() as ctx:
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(
+                base64.b64decode(cfg["custom_call_config"]["body"]))
+            bodies.append(module.operation.get_asm(enable_debug_info=False))
+    return "".join(bodies) + config.sub("", text)
+
+
+def _kernel_operands(text: str) -> int:
+    """Operands of the one Mosaic call of a lowered program."""
+    operands, = re.findall(r"stablehlo\.custom_call @tpu_custom_call\("
+                           r"([^)]*)\)", text)
+    return len(operands.split(","))
+
+
+#: batch, chunk, heads, kv heads, row width, table, block_r, v_width ->
+#: sha256 of _without_locations at 06d4f20, the commit before the
+#: ``chosen`` operand (jax 0.9.0)
+UNSELECTED = {
+    "dense_decode": ((16, 1, 32, 8, 128, 256, 16, None),
+                     "9cf5d7b90d335343a375"),
+    "dense_chunk": ((1, 256, 32, 8, 128, 256, 512, None),
+                    "b73afbf62d0be0e26de1"),
+    "latent_decode": ((16, 1, 128, 1, 640, 2048, 128, 512),
+                      "c87a00c39adaf40ad829"),
+    "latent_chunk": ((1, 64, 128, 1, 640, 2048, 128, 512),
+                     "3157719faa4a2ff58321"),
+}
+
+
+@pytest.mark.parametrize("form", sorted(UNSELECTED))
+def test_a_call_without_a_selection_lowers_as_it_did(form):
+    """``chosen=None`` adds no operand and changes no instruction: the
+    dense and the latent form, one row block and several, lower to the
+    kernel and the call the commit before the operand lowered to."""
+    import hashlib
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    (b, c, h, g, d, t, block_r, v_width), want = UNSELECTED[form]
+    pool = _s((3, 1 + b * 8, g, BLOCK, d))
+    pools = (pool,) if v_width else (pool, pool)
+
+    def call(q, *rest):
+        *kv, bt, pos, lens, layer = rest
+        return paged_flash_attention(
+            q, kv[0], None if v_width else kv[1], bt, pos, lens,
+            layer=layer, block_r=block_r, v_width=v_width)
+    text = _lower_for_tpu(
+        call, _s((b, c, h, d)), *pools, _s((b, t), jnp.int32),
+        _s((b, c), jnp.int32), _s((b,), jnp.int32), _s((), jnp.int32))
+    assert _kernel_operands(text) == 5 + len(pools)
+    assert hashlib.sha256(_without_locations(text).encode()) \
+        .hexdigest()[:20] == want
+
+
+def test_a_selection_is_one_more_operand_and_lowers_for_tpu():
+    """Keye's decode call (8 x 32 heads on 4 x 128, a table of 2048
+    pages) and a chunk's 256-row block with ``chosen``: one operand
+    more than the call without."""
+    t = 32768 // BLOCK
+    pool = _s((6, 1 + 8 * 64, 4, BLOCK, 128))
+    for b, c, block_r in ((8, 1, 8), (1, 256, 512)):
+        text = _lower_for_tpu(
+            lambda q, k, v, bt, pos, lens, layer, chosen:
+            paged_flash_attention(q, k, v, bt, pos, lens, layer=layer,
+                                  block_r=block_r, chosen=chosen),
+            _s((b, c, 32, 128)), pool, pool, _s((b, t), jnp.int32),
+            _s((b, c), jnp.int32), _s((b,), jnp.int32), _s((), jnp.int32),
+            _s((b, c, 32768), jnp.bool_))
+        assert _kernel_operands(text) == 8
+
+
 # ------------------------------------------ the compiled step programs
 @pytest.fixture(scope="module")
 def one_chip():
@@ -121,19 +211,24 @@ def one_chip():
 
 
 @pytest.mark.parametrize("batch,chunk,heads,kv_heads,head_dim,window,"
-                         "layers,blocks,block_r,sliding", [
-    (64, 1, 16, 16, 256, 2048, 6, 1921, 8, 0),        # chat decode
-    (1, 256, 16, 16, 256, 2048, 6, 1921, 128, 0),     # chat chunk
-    (16, 1, 32, 8, 128, 4096, 8, 3585, 16, 0),        # docqa decode
-    (1, 256, 32, 8, 128, 4096, 8, 3585, 512, 0),      # docqa chunk
+                         "layers,blocks,block_r,sliding,selects", [
+    (64, 1, 16, 16, 256, 2048, 6, 1921, 8, 0, False),     # chat decode
+    (1, 256, 16, 16, 256, 2048, 6, 1921, 128, 0, False),  # chat chunk
+    (16, 1, 32, 8, 128, 4096, 8, 3585, 16, 0, False),     # docqa decode
+    (1, 256, 32, 8, 128, 4096, 8, 3585, 512, 0, False),   # docqa chunk
     # the widest chunk a cell sends, Laguna-XS.2's: a full layer's 24
     # row blocks a kv head, a window layer's 32 over its short table
-    (1, 2048, 48, 8, 128, 65536, 2, 38913, 512, 0),
-    (1, 2048, 64, 8, 128, 161 * BLOCK, 3, 4097, 512, 512),
+    (1, 2048, 48, 8, 128, 65536, 2, 38913, 512, 0, False),
+    (1, 2048, 64, 8, 128, 161 * BLOCK, 3, 4097, 512, 512, False),
+    # with ``chosen``: Keye's decode step (one int32 row a sequence, a
+    # row of P x 16 lanes a grid step) and a chunk's 256-row block
+    # (an int8 row a token, widened in the body)
+    (8, 1, 32, 4, 128, 32768, 6, 15361, 8, 0, True),
+    (1, 256, 32, 4, 128, 32768, 6, 15361, 512, 0, True),
 ])
 def test_paged_kernel_compiles_for_v5e_at_the_cells_shapes(
         one_chip, batch, chunk, heads, kv_heads, head_dim, window, layers,
-        blocks, block_r, sliding):
+        blocks, block_r, sliding, selects):
     """The Mosaic compile proper, which the lowering above stops short
     of: the group's 2·P page copies out of the pool left in HBM, the
     read of P pages as one tile and the VMEM the step plans for
@@ -155,12 +250,15 @@ def test_paged_kernel_compiles_for_v5e_at_the_cells_shapes(
     compilation_cache.reset_cache()
     try:
         compiled = jax.jit(
-            lambda q, k, v, bt, pos, lens, layer: paged_flash_attention(
+            lambda q, k, v, bt, pos, lens, layer, *chosen:
+            paged_flash_attention(
                 q, k, v, bt, pos, lens, layer=layer, block_r=block_r,
-                window=sliding)
+                window=sliding, chosen=chosen[0] if chosen else None)
         ).lower(s((batch, chunk, heads, head_dim)), pool, pool,
                 s((batch, t), jnp.int32), s((batch, chunk), jnp.int32),
-                s((batch,), jnp.int32), s((), jnp.int32)).compile()
+                s((batch,), jnp.int32), s((), jnp.int32),
+                *[s((batch, chunk, window), jnp.bool_)] * selects
+                ).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()

@@ -146,3 +146,48 @@ def test_a_shipped_page_carries_its_indexer_keys(wire):
     finally:
         for e in (ref, pre, dec):
             e.shutdown()
+
+
+@pytest.mark.parametrize("impl,form", [("auto", "reference"),
+                                       ("interpret", "interpret")])
+def test_stats_say_which_form_the_decode_step_took(impl, form):
+    """``sparse_decode_impl`` is what the decode program's selected
+    attention was built as (off the TPU ``auto`` is the gather of the
+    selected rows; ``interpret`` reads the live pages through the paged
+    kernel with the selection as its mask), and
+    ``sparse_decode_kernel_steps_total`` the decode steps that ran
+    through the kernel: all five, or none; ``sparse_prefill_impl`` is a
+    chunk's form, the kernel or XLA's masked pass. Either way the served tokens
+    are the reference's own choice. A model that selects nothing has
+    neither key."""
+    eng = LLMEngine(TransformerConfig(**dict(MODEL_KW, paged_impl=impl)),
+                    EngineConfig(decode_slots=2, kv_block_size=4,
+                                 max_seq_len=64, prefill_chunk=16,
+                                 max_new_tokens=8))
+    try:
+        served = list(eng.generate_sync(DOC[:20], 6))
+        assert _gap(eng, DOC[:20], served) < 1e-4
+        s = eng.stats()
+        assert s["decode_steps"] == 5
+        assert s["sparse_decode_impl"] == form == s["sparse_prefill_impl"]
+        assert s["sparse_decode_kernel_steps_total"] \
+            == (5 if form == "interpret" else 0)
+        assert {"op": "sparse_decode", "impl": form,
+                "why": "requested" if impl == form
+                else "platform is not tpu"} in [
+            {k: e[k] for k in ("op", "impl", "why")}
+            for e in s["attention_dispatch"]]
+    finally:
+        eng.shutdown()
+    if impl == "auto":
+        dense = LLMEngine(
+            TransformerConfig(**dict(MODEL_KW, index_topk=0, index_heads=0,
+                                     index_dim=0)),
+            EngineConfig(decode_slots=2, kv_block_size=4, max_seq_len=64,
+                         prefill_chunk=16, max_new_tokens=8))
+        try:
+            assert not {"sparse_decode_impl", "sparse_prefill_impl",
+                        "sparse_decode_kernel_steps_total"} \
+                & set(dense.stats())
+        finally:
+            dense.shutdown()
