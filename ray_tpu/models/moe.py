@@ -100,6 +100,14 @@ def moe_logical_axes():
 # that land elsewhere are sorted behind the held ones' rows, belong to no
 # group of the grouped product and are left out of the combine. Nothing
 # stands in for the absent chips' part.
+#
+# The expert's form (``expert_act``): "swiglu", three matrices and a gate,
+# or "relu2", two and none: ``relu(x W_up)^2 W_down``. With ``moe_latent``
+# R > 0 the routed experts live in a latent of R numbers: a token is
+# projected down ONCE ahead of the sort (``w_lat_down [D, R]``), the
+# experts are ``[R, F]`` / ``[F, R]``, their weighted sum is taken in R and
+# projected up once a token (``w_lat_up [R, D]``); the router and the
+# shared expert read the full-width token.
 
 @jax.named_scope("moe_router")
 def _group_limited(c, scores, bias):
@@ -147,8 +155,24 @@ def route_topk(c, lp, x):
     return weights, experts
 
 
-#: the dropless layer's stacked expert leaves
+#: the stacked expert leaves of a gated expert. The program reads
+#: ``expert_leaves(c)``; the name stays for the accepted tests that import
+#: it (tests/models/test_latent_moe_serving.py, test_sparse_latent_serving.py)
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+def expert_leaves(c):
+    """The stacked expert leaves of ``c``'s form of expert: an ungated
+    one ("relu2") has no gate stack."""
+    return EXPERT_LEAVES if c.expert_act == "swiglu" else EXPERT_LEAVES[1:]
+
+
+def _expert_mid(gate, up):
+    """The rows between an expert's matrices: ``silu(gate) * up``, or
+    with no gate (``gate`` None, "relu2") ``relu(up)^2``."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
 
 #: XLA:TPU does not compile a grouped product of fewer rows than this
 #: against a count of groups over 128 that is no multiple of 512 (an
@@ -161,21 +185,77 @@ _FEW_ROWS = 32
 
 @jax.named_scope("moe_shared")
 def _shared_expert(c, lp, x):
-    """The SwiGLU expert every token passes. ``x [N, D]`` -> ``[N, D]``."""
+    """The expert every token passes, in the routed experts' form at the
+    model's width. ``x [N, D]`` -> ``[N, D]``."""
     dt = c.dtype
-    gate = jax.nn.silu(jnp.dot(x, lp["ws_gate"].astype(dt)))
-    return jnp.dot(gate * jnp.dot(x, lp["ws_up"].astype(dt)),
-                   lp["ws_down"].astype(dt))
+    gate = None if c.expert_act == "relu2" \
+        else jnp.dot(x, lp["ws_gate"].astype(dt))
+    mid = _expert_mid(gate, jnp.dot(x, lp["ws_up"].astype(dt)))
+    return jnp.dot(mid, lp["ws_down"].astype(dt))
+
+
+@jax.named_scope("moe_experts")
+def _routed_experts(c, lp, x, weights, experts, layer):
+    """The held experts' part of the weighted sum, ``[N, width of x]``
+    float32: sort the assignments by expert, the grouped products over
+    the sorted rows, unsort, combine. ``x [N, D]`` (``[N, R]`` in a
+    latent), ``weights``, ``experts [N, k]``."""
+    dt = c.dtype
+    N, width = x.shape
+    E, k = c.n_experts_held, c.experts_per_token
+    flat = experts.reshape(N * k)
+    here = None
+    if E != c.n_experts:
+        # an assignment to an expert held elsewhere gets group E: sorted
+        # behind every held expert's rows, counted in no group
+        flat = flat - c.expert_first
+        here = (flat >= 0) & (flat < E)
+        flat = jnp.where(here, flat, E)
+    order = jnp.argsort(flat)                  # stable: assignment order
+    xs = jnp.take(x, order // k, axis=0)       # rows sorted by expert
+    leaves = {name: lp[name].astype(dt) for name in expert_leaves(c)}
+    groups = E
+    if layer is not None:
+        groups = leaves["we_up"].shape[0] * E
+        flat = flat + layer * E
+        leaves = {name: w.reshape((groups,) + w.shape[2:])
+                  for name, w in leaves.items()}
+    if here is not None:
+        flat = jnp.where(here, flat, groups)   # out of bounds: dropped
+    sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
+    if N * k < _FEW_ROWS and groups > 128 and groups % 512:
+        # rows of no group behind the others (see _FEW_ROWS)
+        xs = jnp.pad(xs, ((0, _FEW_ROWS - N * k), (0, 0)))
+    gate = jax.lax.ragged_dot(xs, leaves["we_gate"], sizes,
+                              preferred_element_type=jnp.float32) \
+        if "we_gate" in leaves else None
+    up = jax.lax.ragged_dot(xs, leaves["we_up"], sizes,
+                            preferred_element_type=jnp.float32)
+    mid = _expert_mid(gate, up).astype(dt)
+    ys = jax.lax.ragged_dot(mid, leaves["we_down"], sizes,
+                            preferred_element_type=jnp.float32)
+    # unsort: row i of ys is assignment order[i]
+    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    ys = jnp.take(ys, inverse, axis=0).reshape(N, k, width)   # and unpad
+    if here is not None:
+        # a row of no group is whatever the product left there: chosen
+        # away, not multiplied by a zero weight
+        ys = jnp.where(here.reshape(N, k, 1), ys, 0.0)
+    return jnp.sum(ys * weights[..., None], axis=1)
 
 
 @jax.named_scope("moe")
 def topk_moe_mlp(c, lp, h, layer=None):
-    """Dropless top-k gated-expert MLP. ``h [B, S, D]`` (compute dtype)
-    -> ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the held
+    """Dropless top-k expert MLP. ``h [B, S, D]`` (compute dtype) ->
+    ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the held
     experts ``we_gate / we_up [E_held, D, F]``, ``we_down [E_held, F,
-    D]`` (``E_held`` = ``E`` unless ``experts_held`` is set), and with
+    D]`` (``E_held`` = ``E`` unless ``experts_held`` is set; no
+    ``we_gate`` for ungated experts, ``expert_act`` "relu2"), and with
     ``shared_expert_width`` the shared expert's ``ws_gate / ws_up /
-    ws_down``.
+    ws_down``. With ``moe_latent`` R the routed experts are ``[E_held,
+    R, F]`` / ``[E_held, F, R]`` between ``w_lat_down [D, R]`` and
+    ``w_lat_up [R, D]``, each applied once a token.
 
     Inside a scan over layers pass ``layer`` (the scan's int32 index)
     and the expert leaves WHOLE, ``[L, E_held, ...]``: the grouped
@@ -187,49 +267,17 @@ def topk_moe_mlp(c, lp, h, layer=None):
     the slice."""
     dt = c.dtype
     B, S, D = h.shape
-    E, k = c.n_experts_held, c.experts_per_token
-    N = B * S
-    x = h.reshape(N, D).astype(dt)
+    x = h.reshape(B * S, D).astype(dt)
     weights, experts = route_topk(c, lp, x)
-    flat = experts.reshape(N * k)
-    here = None
-    if E != c.n_experts:
-        # an assignment to an expert held elsewhere gets group E: sorted
-        # behind every held expert's rows, counted in no group
-        flat = flat - c.expert_first
-        here = (flat >= 0) & (flat < E)
-        flat = jnp.where(here, flat, E)
-    order = jnp.argsort(flat)                  # stable: assignment order
-    xs = jnp.take(x, order // k, axis=0)       # rows sorted by expert
-    w_gate, w_up, w_down = (lp[name].astype(dt) for name in EXPERT_LEAVES)
-    groups = E
-    if layer is not None:
-        groups = w_gate.shape[0] * E
-        flat = flat + layer * E
-        w_gate, w_up, w_down = (w.reshape((groups,) + w.shape[2:])
-                                for w in (w_gate, w_up, w_down))
-    if here is not None:
-        flat = jnp.where(here, flat, groups)   # out of bounds: dropped
-    sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
-    if N * k < _FEW_ROWS and groups > 128 and groups % 512:
-        # rows of no group behind the others (see _FEW_ROWS)
-        xs = jnp.pad(xs, ((0, _FEW_ROWS - N * k), (0, 0)))
-    gate = jax.lax.ragged_dot(xs, w_gate, sizes,
-                              preferred_element_type=jnp.float32)
-    up = jax.lax.ragged_dot(xs, w_up, sizes,
-                            preferred_element_type=jnp.float32)
-    mid = (jax.nn.silu(gate) * up).astype(dt)
-    ys = jax.lax.ragged_dot(mid, w_down, sizes,
-                            preferred_element_type=jnp.float32)
-    # unsort: row i of ys is assignment order[i]
-    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
-        jnp.arange(N * k, dtype=jnp.int32))
-    ys = jnp.take(ys, inverse, axis=0).reshape(N, k, D)   # and unpad
-    if here is not None:
-        # a row of no group is whatever the product left there: chosen
-        # away, not multiplied by a zero weight
-        ys = jnp.where(here.reshape(N, k, 1), ys, 0.0)
-    y = jnp.sum(ys * weights[..., None], axis=1)
+    xin = x
+    if c.moe_latent:
+        with jax.named_scope("moe_latent_down"):
+            xin = jnp.dot(x, lp["w_lat_down"].astype(dt))
+    y = _routed_experts(c, lp, xin, weights, experts, layer)
+    if c.moe_latent:
+        with jax.named_scope("moe_latent_up"):
+            y = jnp.dot(y.astype(dt), lp["w_lat_up"].astype(dt),
+                        preferred_element_type=jnp.float32)
     if c.shared_expert_width:
         y = y + _shared_expert(c, lp, x).astype(jnp.float32)
     return y.reshape(B, S, D).astype(dt)
@@ -237,16 +285,20 @@ def topk_moe_mlp(c, lp, h, layer=None):
 
 def topk_moe_param_shapes(c):
     f, held = c.expert_width, c.n_experts_held
-    shapes = {
-        "w_router": (c.d_model, c.n_experts),
-        "we_gate": (held, c.d_model, f),
-        "we_up": (held, c.d_model, f),
-        "we_down": (held, f, c.d_model),
-    }
+    gated = c.expert_act == "swiglu"
+    e = c.moe_latent or c.d_model               # the routed experts' width
+    shapes = {"w_router": (c.d_model, c.n_experts),
+              "we_up": (held, e, f), "we_down": (held, f, e)}
+    if gated:
+        shapes["we_gate"] = (held, e, f)
+    if c.moe_latent:
+        shapes.update({"w_lat_down": (c.d_model, e),
+                       "w_lat_up": (e, c.d_model)})
     if c.shared_expert_width:
         fs = c.shared_expert_width
-        shapes.update({"ws_gate": (c.d_model, fs), "ws_up": (c.d_model, fs),
-                       "ws_down": (fs, c.d_model)})
+        shapes.update({"ws_up": (c.d_model, fs), "ws_down": (fs, c.d_model)})
+        if gated:
+            shapes["ws_gate"] = (c.d_model, fs)
     return shapes
 
 
@@ -256,9 +308,9 @@ def topk_moe_logical_axes(c):
         "we_gate": ("layers", "expert", "embed", "mlp"),
         "we_up": ("layers", "expert", "embed", "mlp"),
         "we_down": ("layers", "expert", "mlp", "embed"),
-    }
-    if c.shared_expert_width:
-        axes.update({"ws_gate": ("layers", "embed", "mlp"),
-                     "ws_up": ("layers", "embed", "mlp"),
-                     "ws_down": ("layers", "mlp", "embed")})
-    return axes
+        "w_lat_down": ("layers", "embed", None),
+        "w_lat_up": ("layers", None, "embed"),
+        "ws_gate": ("layers", "embed", "mlp"),
+        "ws_up": ("layers", "embed", "mlp"),
+        "ws_down": ("layers", "mlp", "embed")}
+    return {name: axes[name] for name in topk_moe_param_shapes(c)}

@@ -248,6 +248,22 @@ class TransformerConfig:
     delta_neg_eigval: bool = False
     output_norm: bool = False
     qk_norm_whole: bool = False
+    # A layer of ONE sublayer (served; a stack by kind of layer). An
+    # "ffn" layer in layer_pattern is a feed-forward alone (the dense
+    # SwiGLU or the dropless experts) behind one norm: no mixer, no
+    # page, no state. mixer_only: a layer of any OTHER kind is its mixer
+    # alone behind one norm and has no feed-forward. ssm_groups: the
+    # "mamba" layers' B and C come a group of ssm_heads / ssm_groups
+    # heads, and the gated norm norms each group's channels apart (1:
+    # one group, one norm over all channels). The dropless experts'
+    # form, expert_act: "swiglu" | "relu2" (relu(x W_up)^2 W_down: two
+    # matrices, no gate; the shared expert takes the same form);
+    # moe_latent R > 0: the routed experts live in a latent of R numbers
+    # between one down- and one up-projection a token (models/moe.py).
+    mixer_only: bool = False
+    ssm_groups: int = 1
+    expert_act: str = "swiglu"
+    moe_latent: int = 0
 
     def __post_init__(self):
         # plain JSON hands lists over: the config stays hashable
@@ -267,14 +283,34 @@ class TransformerConfig:
         return self.expert_width or self.d_ff
 
     @property
+    def expert_matrices(self) -> int:
+        """Matrices of one dropless expert: gate, up, down, or no gate."""
+        return 3 if self.expert_act == "swiglu" else 2
+
+    @property
+    def expert_params(self) -> int:
+        """Parameters of one routed expert (in its latent where it has
+        one)."""
+        return self.expert_matrices * (self.moe_latent or self.d_model) \
+            * self.d_expert
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers whose feed-forward is the dropless experts."""
+        if not self.experts_per_token:
+            return 0
+        return sum(_kind_halves(self, self.layer_kind(l))[1]
+                   for l in range(self.n_dense_layers, self.n_layers))
+
+    @property
     def ssm_inner(self) -> int:
         """Channels the scan runs over (the published d_model x expand)."""
         return self.ssm_heads * self.ssm_head_dim
 
     @property
     def ssm_conv_width(self) -> int:
-        """Channels through the convolution: x and the group's B and C."""
-        return self.ssm_inner + 2 * self.ssm_state
+        """Channels through the convolution: x and each group's B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def delta_inner(self) -> int:
@@ -297,7 +333,8 @@ class TransformerConfig:
                    "ssm_head_dim", "ssm_state", "attn_scale", "embed_scale",
                    "residual_scale", "logit_scale", "tie_embeddings",
                    "delta_heads", "delta_key_dim", "delta_value_dim",
-                   "delta_neg_eigval", "output_norm", "qk_norm_whole")
+                   "delta_neg_eigval", "output_norm", "qk_norm_whole",
+                   "mixer_only", "ssm_groups", "expert_act", "moe_latent")
 
     @property
     def served_keys(self) -> Tuple[str, ...]:
@@ -344,31 +381,39 @@ class TransformerConfig:
             # embed, head (the embedding again where tied), final norm
             total = (1 if self.tie_embeddings else 2) * v * e + e
             for l in range(self.n_layers):
-                if self.layer_kind(l) == "mamba":
+                kind = self.layer_kind(l)
+                mixer, mlp = _kind_halves(self, kind)
+                total += e * (mixer + mlp)            # a norm a sublayer
+                if not mixer:
+                    pass
+                elif kind == "mamba":
                     di, cw = self.ssm_inner, self.ssm_conv_width
                     total += e * (di + cw + self.ssm_heads) + di * e \
                         + cw * (self.ssm_conv + 1) + 3 * self.ssm_heads \
-                        + di + 2 * e
-                elif self.layer_kind(l) == "delta":
+                        + di
+                elif kind == "delta":
                     # w_qkv, w_g, w_ab, w_out, the taps, A_log, dt_bias,
-                    # the output norm a head wide, the block's two norms
+                    # the output norm a head wide
                     di, cw = self.delta_inner, self.delta_conv_width
                     total += e * (cw + di + 2 * self.delta_heads) \
                         + di * e + cw * self.delta_conv \
-                        + 2 * self.delta_heads + self.delta_value_dim \
-                        + 2 * e
+                        + 2 * self.delta_heads + self.delta_value_dim
                 else:
-                    hk = self.kind_heads(self.layer_kind(l))
+                    hk = self.kind_heads(kind)
                     total += 2 * e * hk * self.head_dim + 2 * e * kvh \
-                        + 2 * e + (e * hk if self.head_gate else 0) \
+                        + (e * hk if self.head_gate else 0) \
                         + (hk * self.head_dim + kvh
                            if self.qk_norm_whole else 0)
+                if not mlp:
+                    continue
                 if l < self.n_dense_layers or not self.experts_per_token:
                     total += 3 * e * self.d_ff
                 else:
-                    total += e * self.n_experts + 3 * e * (
-                        self.n_experts_held * self.d_expert
-                        + self.shared_expert_width)
+                    total += e * self.n_experts \
+                        + self.n_experts * self.router_bias \
+                        + 2 * e * self.moe_latent \
+                        + self.n_experts_held * self.expert_params \
+                        + self.expert_matrices * e * self.shared_expert_width
             return total
         per_layer = e * h + 2 * e * kvh + h * e          # q, k, v, o
         if self.kv_lora_rank:    # wq_a, wq_b, wkv_a, wkv_b, wo, 2 norms
@@ -422,10 +467,8 @@ class TransformerConfig:
             # of the held experts a token meets its share of the k
             met = self.experts_per_token * self.n_experts_held \
                 / self.n_experts
-            return int(self.num_params
-                       - (self.n_layers - self.n_dense_layers) * 3
-                       * self.d_model * self.d_expert
-                       * (self.n_experts_held - met))
+            return int(self.num_params - self.expert_layers
+                       * self.expert_params * (self.n_experts_held - met))
         inactive = self.n_layers * (self.n_experts - 1) \
             * 2 * self.d_model * self.d_ff
         return self.num_params - inactive
@@ -581,8 +624,7 @@ def _check_served_forms(c: TransformerConfig) -> None:
                 "latent attention needs q_lora_rank, v_head_dim and "
                 f"head_dim == qk_nope_dim + qk_rope_dim, got {c}")
     latent_only = ("sandwich_norm", "index_q_lora", "rope_softmax_scale",
-                   "gated_norm_rank", "n_group", "topk_group",
-                   "router_bias")
+                   "gated_norm_rank", "n_group", "topk_group")
     if not c.kv_lora_rank and set(c.served_keys) & set(latent_only):
         raise ValueError(f"{', '.join(latent_only)} are served with "
                          "latent attention (kv_lora_rank > 0)")
@@ -598,11 +640,17 @@ def _check_served_forms(c: TransformerConfig) -> None:
                 f"{c.n_experts} experts in n_group {c.n_group} groups of "
                 f"two or more, of which topk_group {c.topk_group} hold a "
                 f"token's {c.experts_per_token}")
-    if (c.shared_expert_width or c.experts_held
-            or c.router_score != "softmax") and not c.experts_per_token:
-        raise ValueError("shared_expert_width, experts_held and "
-                         "router_score belong to the dropless experts "
-                         "(experts_per_token > 0)")
+    if (c.shared_expert_width or c.experts_held or c.router_bias
+            or c.router_score != "softmax" or c.moe_latent
+            or c.expert_act != "swiglu") and not c.experts_per_token:
+        raise ValueError("shared_expert_width, experts_held, router_bias, "
+                         "router_score, expert_act and moe_latent belong "
+                         "to the dropless experts (experts_per_token > 0)")
+    if c.expert_act not in ("swiglu", "relu2"):
+        raise ValueError(f"expert_act {c.expert_act!r}: 'swiglu' or 'relu2'")
+    if c.ssm_groups < 1 or c.ssm_heads % c.ssm_groups:
+        raise ValueError(f"ssm_groups {c.ssm_groups} divides ssm_heads "
+                         f"{c.ssm_heads}")
     if c.kv_lora_rank and not (
             c.experts_per_token and 0 <= c.n_dense_layers < c.n_layers):
         raise ValueError("latent attention is served ahead of dropless "
@@ -617,7 +665,15 @@ def _check_served_forms(c: TransformerConfig) -> None:
 #: where the tree keeps each kind's layers (all of them, or with
 #: ``n_dense_layers`` those behind ``dense_layers``)
 KIND_STACKS = {"full": "layers", "window": "window_layers",
-               "mamba": "mamba_layers", "delta": "delta_layers"}
+               "mamba": "mamba_layers", "delta": "delta_layers",
+               "ffn": "ffn_layers"}
+
+
+def _kind_halves(c: "TransformerConfig", kind: str) -> Tuple[bool, bool]:
+    """(a mixer, a feed-forward): which sublayers a layer of ``kind``
+    has. An "ffn" layer is its feed-forward alone; with ``mixer_only``
+    every other kind is its mixer alone."""
+    return kind != "ffn", kind == "ffn" or not c.mixer_only
 
 #: a window layer's pools, beside the full layers' "k" / "v"
 WINDOW_POOLS = ("k_window", "v_window")
@@ -699,7 +755,7 @@ class _State(NamedTuple):
 @dataclasses.dataclass(frozen=True)
 class _LayerKind:
     """One kind of layer, as the forward pass needs to know it."""
-    name: str                    # "full" | "window" | "mamba" | "delta"
+    name: str            # "full" | "window" | "mamba" | "delta" | "ffn"
     # ahead of each sublayer: "layer" (with bias) | "rms" | "gated" |
     # "none" (the sublayer reads the residual stream as it is)
     norm: str
@@ -708,8 +764,9 @@ class _LayerKind:
     parallel: bool
     post_norm: bool              # an RMSNorm on each sublayer's output
     # "paged": per-head K/V | "latent": MLA rows | "scan": Mamba-2's
-    # recurrence | "delta": the gated delta rule's
-    mixer: str
+    # recurrence | "delta": the gated delta rule's | None: the layer is
+    # its feed-forward alone, with the norm of that half
+    mixer: Optional[str]
     heads: int                   # query heads
     window: int                  # keys attended behind a position, 0 = all
     qk_norm: bool                # RMSNorm q and k, a weight a head
@@ -719,8 +776,9 @@ class _LayerKind:
     # selects) and the table it reads them through: "main" (entry 0 is
     # position 0) | "window" (a short table that starts behind the window)
     pools: Tuple[_Pool, ...]
-    # ... | "state" (no table: the slot of each row, for ``state``)
-    table: str
+    # ... | "state" (no table: the slot of each row, for ``state``) |
+    # None (no mixer)
+    table: Optional[str]
     scope: Optional[str]         # the named scope below ``layer/attn``
     rotary: _Rotary              # ``dim`` 0: nothing is rotated
     index_rotary: Optional[_Rotary]      # None: the layer's own
@@ -732,6 +790,9 @@ class _LayerKind:
     # the name the engine counts this kind's recurrence under
     # (``<counters>_decode_rows_total`` ...); None: no state, no counter
     counters: Optional[str] = None
+    # the feed-forward half is there (False: the layer is its mixer alone,
+    # with the norm of that half)
+    mlp: bool = True
 
 
 class _Run(NamedTuple):
@@ -774,9 +835,9 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
                    or c.window_heads):
         raise ValueError("layer_pattern, sliding_window and window_heads "
                          "are forms of per-head K/V, not of a latent cache")
-    if not pattern <= {"full", "window", "mamba", "delta"}:
+    if not pattern <= set(KIND_STACKS):
         raise ValueError(f"layer_pattern {c.layer_pattern}: a layer "
-                         f"is 'full', 'window', 'mamba' or 'delta'")
+                         f"is 'full', 'window', 'mamba', 'delta' or 'ffn'")
     if ("delta" in pattern) != bool(c.delta_heads) or c.delta_heads and not (
             c.delta_key_dim and c.delta_value_dim and c.delta_conv > 1
             ) or c.delta_heads and c.ssm_heads:
@@ -797,12 +858,17 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
     by_kind_only = [k for k in ("attn_scale", "embed_scale",
                                 "residual_scale", "logit_scale",
                                 "tie_embeddings", "output_norm",
-                                "qk_norm_whole", "delta_neg_eigval")
+                                "qk_norm_whole", "delta_neg_eigval",
+                                "mixer_only", "ssm_groups", "expert_act",
+                                "moe_latent")
                     if k in c.served_keys]
     if by_kind_only and not by_kind:
         raise ValueError(f"{', '.join(by_kind_only)}: forms of a stack by "
                          "kind of layer (layer_pattern, n_dense_layers, "
                          "head_gate, rope_yarn)")
+    if c.router_bias and not (latent or by_kind):
+        raise ValueError("router_bias is served with latent attention "
+                         "(kv_lora_rank > 0) or in a stack by kind of layer")
     if ("window" in pattern) != bool(c.sliding_window):
         raise ValueError("'window' layers in layer_pattern and "
                          "sliding_window > 0 come together, got "
@@ -825,11 +891,19 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
     def kind(name: str) -> _LayerKind:
         window = name == "window"
         common = dict(
-            name=name, norm="layer" if gptj else "none" if c.output_norm
+            name=name, mlp=_kind_halves(c, name)[1],
+            norm="layer" if gptj else "none" if c.output_norm
             else "gated" if c.gated_norm_rank else "rms", parallel=gptj,
             post_norm=c.sandwich_norm or c.output_norm,
             qk_norm=c.qk_norm, qk_norm_whole=c.qk_norm_whole,
             head_gate=c.head_gate, index_topk=c.index_topk)
+        if name == "ffn":
+            # no mixer: no pool, no state, no table, nothing rotated
+            return _LayerKind(
+                **{**common, "head_gate": False, "qk_norm_whole": False},
+                mixer=None, heads=0, window=0, pools=(), table=None,
+                scope=None, rotary=_Rotary("neox", 0, c.rope_base, (), True),
+                index_rotary=None)
         if name == "delta":
             # no page, as a "mamba" layer: the state a head (float32, the
             # key width ahead of heads and value channels as ONE axis:
@@ -902,7 +976,8 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
     kinds = {name: kind(name) for name in
              ("full",) + (("window",) if c.sliding_window else ())
              + (("mamba",) if c.ssm_heads else ())
-             + (("delta",) if c.delta_heads else ())}
+             + (("delta",) if c.delta_heads else ())
+             + (("ffn",) if "ffn" in pattern else ())}
     runs, seen, ordinal = [], {}, dict.fromkeys(kinds, 0)
     for l in range(c.n_layers):
         k, lead = kinds[c.layer_kind(l)], l < c.n_dense_layers
@@ -911,7 +986,7 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
         if runs and runs[-1].stack == stack:
             runs[-1] = runs[-1]._replace(n=runs[-1].n + 1)
         else:
-            runs.append(_Run(stack, k, bool(c.experts_per_token)
+            runs.append(_Run(stack, k, bool(c.experts_per_token) and k.mlp
                              and not lead, at, 1, ordinal[k.name]))
         seen[stack] = at + 1
         ordinal[k.name] += 1
@@ -921,15 +996,19 @@ def _layer_plan(c: TransformerConfig) -> _LayerPlan:
 def _kind_layer_shapes(c: TransformerConfig, kind: str, dense: bool
                        ) -> Dict[str, tuple]:
     """One layer's matmul leaves of a stack by kind: name -> (shape,
-    logical axes without the layers axis). ``kind`` sets the query
-    heads, ``dense`` a SwiGLU MLP of d_ff in place of the experts."""
+    logical axes without the layers axis). ``kind`` sets the mixer, its
+    query heads and which sublayers there are (:func:`_kind_halves`),
+    ``dense`` a SwiGLU MLP of d_ff in place of the experts."""
     from ray_tpu.models.moe import (topk_moe_logical_axes,
                                     topk_moe_param_shapes)
     e, h = c.d_model, c.kind_heads(kind) * c.head_dim
     kvh = c.kv_heads * c.head_dim
-    if kind == "mamba":
-        # w_in's columns as published: gate z | x, B, C (through the
-        # convolution) | dt
+    mixer, mlp = _kind_halves(c, kind)
+    if not mixer:
+        out = {}
+    elif kind == "mamba":
+        # w_in's columns as published: gate z | x, each group's B, each
+        # group's C (through the convolution) | dt
         di, cw = c.ssm_inner, c.ssm_conv_width
         out = {"w_in": ((e, di + cw + c.ssm_heads), ("embed", "mlp")),
                "conv_w": ((cw, c.ssm_conv), ("mlp", None)),
@@ -951,6 +1030,8 @@ def _kind_layer_shapes(c: TransformerConfig, kind: str, dense: bool
                "wo": ((h, e), ("heads", "embed"))}
         if c.head_gate:
             out["wg"] = ((e, c.kind_heads(kind)), ("embed", None))
+    if not mlp:
+        return out
     if dense or not c.experts_per_token:
         out.update({"w_gate": ((e, c.d_ff), ("embed", "mlp")),
                     "w_up": ((e, c.d_ff), ("embed", "mlp")),
@@ -1016,31 +1097,37 @@ def _delta_vector_init(c, key, n) -> Dict[str, jnp.ndarray]:
         "delta_norm": jnp.ones((n, c.delta_value_dim), jnp.float32)}
 
 
-def _kind_vector_shapes(c, kind: str) -> Dict[str, tuple]:
-    """One layer's norm leaves of a stack by kind, all drawn at one: name
-    -> (width, logical axis). The block's two norms, ahead of its
-    sublayers or (``output_norm``) on their outputs, and the whole-width
-    QK-norm's weights of a layer with attention."""
+def _kind_vector_shapes(c, kind: str, dense: bool = False
+                        ) -> Dict[str, tuple]:
+    """One layer's float32 vector leaves of a stack by kind: name ->
+    (width, logical axis). The norm of each sublayer the kind has, ahead
+    of it or (``output_norm``) on its output, and the whole-width
+    QK-norm's weights of a layer with attention, all drawn at one; the
+    router's bias of a layer of experts, drawn."""
     e = c.d_model
-    out = {name: (e, "embed") for name in (
-        ("post_attn_norm", "post_mlp_norm") if c.output_norm
-        else ("attn_norm", "mlp_norm"))}
+    mixer, mlp = _kind_halves(c, kind)
+    names = (("attn",) if mixer else ()) + (("mlp",) if mlp else ())
+    out = {(f"post_{n}_norm" if c.output_norm else f"{n}_norm"): (e, "embed")
+           for n in names}
     if c.qk_norm_whole and kind in ("full", "window"):
         out.update({"q_norm": (c.kind_heads(kind) * c.head_dim, "heads"),
                     "k_norm": (c.kv_heads * c.head_dim, "kv")})
+    if c.router_bias and mlp and not dense:
+        out["router_bias"] = (c.n_experts, None)
     return out
 
 
 def _init_kind_params(c, key, dtype, out_scale) -> Dict:
     """The tree of a stack by kind of layer: ``dense_layers`` (the
     leading ones), ``layers`` (the "full" layers behind them),
-    ``window_layers`` and ``mamba_layers``, each stacked, every matmul
+    ``window_layers``, ``mamba_layers``, ``delta_layers`` and
+    ``ffn_layers``, each stacked, every matmul
     leaf drawn a layer at a time into ``dtype``. ``tie_embeddings``: no
     ``lm_head``."""
     keys = list(jax.random.split(jax.random.fold_in(key, 104), 5))
     # a fourth stack draws from a key of its own, and a fifth: the five
     # above stay what they were
-    keys += [jax.random.fold_in(key, 105), jax.random.fold_in(key, 106)]
+    keys += [jax.random.fold_in(key, 105 + i) for i in range(3)]
     params = {
         "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
                              dtype=dtype),
@@ -1061,7 +1148,11 @@ def _init_kind_params(c, key, dtype, out_scale) -> Dict:
                 scales.get(leaf, 0.02), n, shape, dtype)
         stack.update({norm: jnp.ones((n, width), jnp.float32)
                       for norm, (width, _)
-                      in _kind_vector_shapes(c, kind).items()})
+                      in _kind_vector_shapes(c, kind, dense).items()})
+        if "router_bias" in stack:
+            stack["router_bias"] = _ROUTER_BIAS_SD * jax.random.normal(
+                jax.random.fold_in(keys[2 + j], 1001),
+                stack["router_bias"].shape, jnp.float32)
         if kind == "mamba":
             stack.update(_mamba_vector_init(
                 c, jax.random.fold_in(keys[2 + j], 1000), n))
@@ -1081,7 +1172,7 @@ def _kind_logical_axes(c) -> Dict:
         axes[name] = {leaf: ("layers",) + ax for leaf, (_, ax)
                       in _kind_layer_shapes(c, kind, dense).items()}
         axes[name].update({norm: ("layers", axis) for norm, (_, axis)
-                           in _kind_vector_shapes(c, kind).items()})
+                           in _kind_vector_shapes(c, kind, dense).items()})
         if kind == "delta":
             axes[name].update({
                 "A_log": ("layers", None), "dt_bias": ("layers", None),
@@ -1426,8 +1517,10 @@ def _block(c, kind: _LayerKind, x, lp, attend, mlp):
     ``kind`` says which norms (every RMS norm at ``norm_eps``; LayerNorm
     keeps its own 1e-5, as the final norm does; "none": a sublayer reads
     the stream as it is) and whether one follows each sublayer, ahead of
-    the residual; ``lp`` holds the layer's norm leaves. Returns
-    (x, cache, moe_aux)."""
+    the residual; ``lp`` holds the layer's norm leaves. A kind of ONE
+    sublayer (``kind.mixer`` None, or ``kind.mlp`` False) skips the
+    absent half, its norm with it; a layer with no mixer hands back no
+    cache (None). Returns (x, cache, moe_aux)."""
     eps = c.norm_eps
 
     def pre(x, name):
@@ -1450,14 +1543,18 @@ def _block(c, kind: _LayerKind, x, lp, attend, mlp):
     x = checkpoint_name(x, "block_in")
     def scaled(y):               # ``residual_scale`` ahead of the residual
         return y if c.residual_scale == 1.0 else y * c.residual_scale
-    h = pre(x, "attn")
-    att, cache = attend(h)
-    if kind.parallel:
-        out, aux = mlp(h)
-        return x + scaled(att + out).astype(x.dtype), cache, aux
-    x = x + scaled(post(att, "post_attn_norm")).astype(x.dtype)
-    out, aux = mlp(pre(x, "mlp").astype(c.dtype))
-    return x + scaled(post(out, "post_mlp_norm")).astype(x.dtype), cache, aux
+    cache, aux = None, 0.0
+    if kind.mixer is not None:
+        h = pre(x, "attn")
+        att, cache = attend(h)
+        if kind.parallel:
+            out, aux = mlp(h)
+            return x + scaled(att + out).astype(x.dtype), cache, aux
+        x = x + scaled(post(att, "post_attn_norm")).astype(x.dtype)
+    if kind.mlp:
+        out, aux = mlp(pre(x, "mlp").astype(c.dtype))
+        x = x + scaled(post(out, "post_mlp_norm")).astype(x.dtype)
+    return x, cache, aux
 
 
 def refuse_training(c: TransformerConfig) -> None:
@@ -2083,7 +2180,8 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
     heads' steps ``dt``; a causal depthwise convolution over the new
     inputs behind the slot's last ``ssm_conv - 1``; the recurrence from
     the slot's state, blocked for a chunk or elementwise for one token;
-    ``y * silu(z)`` through one RMSNorm over all channels and ``w_out``.
+    ``y * silu(z)`` through an RMSNorm over each group's channels (one
+    over all of them at ``ssm_groups`` 1) and ``w_out``.
     The arguments are :func:`_paged_attn_sublayer`'s, with the slot of
     each row (``state_rows [B]``; None: row b is slot b) where that has
     a block table. A row's state is read at ``(layer, slot)`` and written
@@ -2096,7 +2194,7 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
                                  ssd_chunk_scan, ssd_step_slots)
     dt_ = c.dtype
     b, n, _ = h.shape
-    H, P, N = c.ssm_heads, c.ssm_head_dim, c.ssm_state
+    H, P, N, G = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
     di, cw = c.ssm_inner, c.ssm_conv_width
     live = write_mask & (lens > 0)[:, None]
     n_live = jnp.sum(live, axis=1, dtype=jnp.int32)
@@ -2118,7 +2216,8 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
         conv = write("conv", tail)
     with jax.named_scope("ssm_scan"):
         x = xbc[..., :di].reshape(b, n, H, P)
-        Bm, Cm = xbc[..., di:di + N], xbc[..., di + N:]
+        Bm = xbc[..., di:di + G * N].reshape(b, n, G, N)
+        Cm = xbc[..., di + G * N:].reshape(b, n, G, N)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
         A = -jnp.exp(lp["A_log"].astype(jnp.float32))
         if n == 1:
@@ -2134,7 +2233,10 @@ def _scan_sublayer(c, kind: _LayerKind, h, lp, rot, layer, cache,
             ssm = write("ssm", state)
     with jax.named_scope("ssm_out"):
         y = y.reshape(b, n, di) * jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(y, lp["ssm_norm"], eps=c.norm_eps)
+        # each group's channels apart
+        y = rms_norm(y.reshape(b, n, G, di // G),
+                     lp["ssm_norm"].reshape(G, di // G),
+                     eps=c.norm_eps).reshape(b, n, di)
         out = jnp.dot(y.astype(dt_), lp["w_out"].astype(dt_))
     return out, {**cache, "conv": conv, "ssm": ssm}
 
@@ -2257,7 +2359,7 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
     reads the layer's leaves out of the whole stack at it. With no
     window table given the window layers read ``block_tables`` through
     the window mask."""
-    from ray_tpu.models.moe import EXPERT_LEAVES
+    from ray_tpu.models.moe import expert_leaves
     if c.n_experts and not c.experts_per_token:
         raise NotImplementedError(
             "paged decode serves dropless top-k experts "
@@ -2281,6 +2383,8 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
               "state": (state_rows, None)}
     rot = {}
     for kind in plan.kinds:
+        if kind.mixer is None:
+            continue
         own = _rotary(kind.rotary, positions, table_len)
         rot[kind] = (own, own if kind.index_rotary is None else
                      _rotary(kind.index_rotary, positions, table_len))
@@ -2293,7 +2397,8 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
         stack = params[run.stack]
         # the dropless experts stay out of the scanned leaves: the grouped
         # product reads layer ``place`` of the whole stack in place
-        whole = {k: stack[k] for k in EXPERT_LEAVES} if run.experts else {}
+        whole = {k: stack[k] for k in expert_leaves(c)} \
+            if run.experts else {}
         scanned = {k: v for k, v in stack.items() if k not in whole}
         # a run shorter than its stack reads each layer's leaves out of
         # the whole stack at the layer's place, as a scan reads its own
@@ -2327,8 +2432,9 @@ def _forward_with_cache(c: TransformerConfig, params: Dict,
             def mlp(h):
                 return _mlp_sublayer(c, h, {**lp, **whole}, place)
             with jax.named_scope("layer"):
-                x, cache, _ = _block(c, kind, x, lp, attend, mlp)
-            return (x, cache), None
+                x, new, _ = _block(c, kind, x, lp, attend, mlp)
+            # a layer with no mixer touched no pool and no state
+            return (x, cache if new is None else new), None
 
         carry, _ = jax.lax.scan(step, carry, (scanned, jnp.arange(
             run.cache_layer, run.cache_layer + run.n, dtype=jnp.int32)))

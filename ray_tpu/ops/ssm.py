@@ -42,8 +42,11 @@ form's two products with the state are plain ``[T, N] x [N, H P]`` and
 A token that is not ``live`` (a chunk's zero padding, a decode row with
 no sequence) has its ``dt`` set to 0: the state passes it unchanged
 (``exp(0) H + 0``, exactly) and the tail is taken at the last live token.
-Live tokens are a prefix of the call. One group of ``B`` / ``C`` for all
-heads (``n_groups`` 1).
+Live tokens are a prefix of the call.
+
+``B`` and ``C`` come a GROUP of heads (``n_groups``): ``[.., G, N]``,
+head ``h`` reads group ``h // (H / G)``, so a group is ``H P / G``
+consecutive lanes of the state (one group: ``G`` 1).
 
 Traps the kernel met (PR 53). *Tiling*: with the state ``[H P, N]`` a
 head's decay and a channel's ``dt x`` are a value a sublane and the sum
@@ -97,15 +100,23 @@ def _per_lane(a, p: int):
     return jnp.repeat(a, p, axis=-1)
 
 
+def _group_lanes(a, hp: int):
+    """``[B, G, N]`` -> ``[B, N, H P]`` float32, a channel its group's
+    column (``[B, N, 1]``, broadcast, where there is one group)."""
+    g = a.shape[1]
+    a = jnp.swapaxes(a.astype(F32), 1, 2)
+    return a if g == 1 else jnp.repeat(a, hp // g, axis=-1)
+
+
 def ssd_step(x, dt, A, B, C, D, state, live):
     """One token a sequence. ``x [B, H, P]``, ``dt [B, H]`` float32 (past
-    its softplus), ``A, D [H]``, ``B, C [B, N]``, ``state [B, N, H P]``
+    its softplus), ``A, D [H]``, ``B, C [B, G, N]``, ``state [B, N, H P]``
     float32, ``live [B]`` bool. Returns (``y [B, H, P]`` float32, state)."""
     b, h, p = x.shape
     decay, dtx, skip = _step_rows(x, dt, A, D, live)
-    state = state * decay[:, None, :] \
-        + B.astype(F32)[:, :, None] * dtx[:, None, :]
-    y = jnp.sum(state * C.astype(F32)[:, :, None], axis=1)
+    Bl, Cl = (_group_lanes(a, h * p) for a in (B, C))
+    state = state * decay[:, None, :] + Bl * dtx[:, None, :]
+    y = jnp.sum(state * Cl, axis=1)
     return (y + skip).reshape(b, h, p), state
 
 
@@ -155,14 +166,18 @@ def _step_kernel(layer_ref, slot_ref, fresh_ref, decay_ref, dtx_ref,
         + skip_ref[...]
 
 
-def step_choice(impl: str, n: int, hp: int, record: bool = False) -> str:
+def step_choice(impl: str, n: int, hp: int, record: bool = False,
+                groups: int = 1) -> str:
     """What the one-token update of states ``[N, H P]`` resolves to under
     ``impl``: "kernel" | "interpret" | "reference". A tile is ``[N,
-    lanes]`` float32: ``N`` whole sublane tiles, ``H P`` whole lanes."""
+    lanes]`` float32: ``N`` whole sublane tiles, whole lanes of ONE of
+    the ``groups`` (it reads one column of ``B`` and of ``C``)."""
     from ray_tpu.ops.attention import _resolve
     unfit = None
     if hp % 128:
         unfit = f"heads x head_dim {hp} % 128 != 0"
+    elif hp // groups % 128:
+        unfit = f"a group's channels {hp} / {groups} % 128 != 0"
     elif n % 8:
         unfit = f"state {n} % 8 != 0"
     return _resolve("ssm_step", impl, "kernel", unfit, record)
@@ -181,17 +196,21 @@ def ssd_step_slots(x, dt, A, B, C, D, states, layer, slots, live, fresh,
     updated)."""
     b, h, p = x.shape
     n, hp = states.shape[2:]
-    choice = step_choice(impl, n, hp, record=True)
+    g = B.shape[1]
+    choice = step_choice(impl, n, hp, record=True, groups=g)
     if choice == "reference":
         y, rows = ssd_step(x, dt, A, B, C, D,
                            slot_rows(states, layer, slots, b, fresh), live)
         return y, put_slot_rows(states, layer, slots, rows)
-    # (an interpreted call of a shape that does not tile: one tile)
-    lanes = next((w for w in _STEP_LANES if hp % w == 0), hp)
+    # a tile never straddles a group (an interpreted call of a shape
+    # that does not tile: one tile a group)
+    lanes = next((w for w in _STEP_LANES if (hp // g) % w == 0), hp // g)
+    per = hp // g // lanes                       # tiles a group
     if slots is None:
         slots = jnp.arange(b, dtype=jnp.int32)
     row = pl.BlockSpec((None, 1, lanes), lambda i, j, *_: (i, 0, j))
-    col = pl.BlockSpec((None, n, 1), lambda i, j, *_: (i, 0, 0))
+    col = pl.BlockSpec((None, None, n, 1),
+                       lambda i, j, *_: (i, j // per, 0, 0))
     tile = pl.BlockSpec(
         (None, None, n, lanes),
         lambda i, j, layer, slot, fresh: (layer[0], slot[i], 0, j))
@@ -212,48 +231,66 @@ def ssd_step_slots(x, dt, A, B, C, D, states, layer, slots, live, fresh,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
       fresh.astype(jnp.int32),
       *(a.reshape(b, 1, hp) for a in _step_rows(x, dt, A, D, live)),
-      B.astype(F32).reshape(b, n, 1), C.astype(F32).reshape(b, n, 1),
+      B.astype(F32).reshape(b, g, n, 1), C.astype(F32).reshape(b, g, n, 1),
       states)
     return y.reshape(b, h, p), states
+
+
+def _each_group(fn, g: int):
+    """``fn(i)`` of every group, side by side on the last axis (the
+    lanes): a group is a slice of whole lane tiles, which a product reads
+    where it lies; a reshape of the lanes into ``[G, lanes / G]`` would
+    relay the state."""
+    return jnp.concatenate([fn(i) for i in range(g)], axis=-1)
 
 
 def _block(carry, blk, A):
     """One block of the blocked form: the state that came in, the
     block's ``x [B, Q, H, P]``, ``dt [B, Q, H]`` (0 where not live),
-    ``B, C [B, Q, N]`` -> (state out, ``y [B, Q, H, P]`` float32 without
-    the ``D x`` term)."""
+    ``B, C [B, Q, G, N]`` -> (state out, ``y [B, Q, H, P]`` float32
+    without the ``D x`` term). A group's heads are ``H P / G`` consecutive
+    lanes of the state: each product with the state is one a group."""
     state = carry                                   # [B, N, H P] f32
     x, dt, Bm, Cm = blk
     b, q, h, p = x.shape
+    g = Bm.shape[2]
+    lanes = h * p // g                              # a group's channels
+
+    def of(a, i):                  # group i's lanes of ``[.., H P]``
+        return a[..., i * lanes:(i + 1) * lanes]
     dt_h = jnp.moveaxis(dt, 1, 2)                   # [B, H, Q]
     cs = jnp.cumsum(dt_h * A[:, None], axis=-1)     # inclusive, <= 0
     # inside the block: y_t += sum_{s <= t} exp(cs_t - cs_s) dt_s
     #                          (C_t . B_s) x_s
-    gram = jnp.einsum("btn,bsn->bts", Cm, Bm, preferred_element_type=F32)
+    gram = jnp.einsum("btgn,bsgn->bgts", Cm, Bm,
+                      preferred_element_type=F32)           # a group
     causal = jnp.tril(jnp.ones((q, q), bool))
     gap = jnp.where(causal, cs[..., :, None] - cs[..., None, :], -jnp.inf)
-    mix = jnp.exp(gap) * gram[:, None] * dt_h[..., None, :]   # [B, H, t, s]
+    mix = (jnp.exp(gap).reshape(b, g, h // g, q, q) * gram[:, :, None]
+           ).reshape(b, h, q, q) * dt_h[..., None, :]         # [B, H, t, s]
     y = jnp.einsum("bhts,bshp->bthp", mix.astype(x.dtype), x,
                    preferred_element_type=F32)
     # what the state that came in adds: exp(cs_t) (C_t H_in)
-    from_state = jnp.einsum("btn,bnk->btk", Cm.astype(F32), state,
-                            preferred_element_type=F32)
+    from_state = _each_group(lambda i: jnp.einsum(
+        "btn,bnk->btk", Cm[:, :, i].astype(F32), of(state, i),
+        preferred_element_type=F32), g)
     y = y + from_state.reshape(b, q, h, p) \
         * jnp.moveaxis(jnp.exp(cs), 1, 2)[..., None]
     # the state that goes out: exp(cs_Q) H_in + sum_s exp(cs_Q - cs_s)
     #                          B_s (outer) dt_s x_s
     w = jnp.exp(cs[..., -1:] - cs) * dt_h                     # [B, H, s]
     xw = x.astype(F32) * jnp.moveaxis(w, 1, 2)[..., None]     # [B, s, H, P]
+    xw = xw.astype(x.dtype).reshape(b, q, h * p)
     state = state * _per_lane(jnp.exp(cs[..., -1]), p)[:, None, :] \
-        + jnp.einsum("bsn,bsk->bnk", Bm,
-                     xw.astype(x.dtype).reshape(b, q, h * p),
-                     preferred_element_type=F32)
+        + _each_group(lambda i: jnp.einsum(
+            "bsn,bsk->bnk", Bm[:, :, i], of(xw, i),
+            preferred_element_type=F32), g)
     return state, y
 
 
 def ssd_chunk_scan(x, dt, A, B, C, D, state_in, live, block: int = 256):
     """``T`` tokens a sequence, blocked. ``x [B, T, H, P]``, ``dt [B, T,
-    H]`` float32 (past its softplus), ``A, D [H]``, ``B, C [B, T, N]``,
+    H]`` float32 (past its softplus), ``A, D [H]``, ``B, C [B, T, G, N]``,
     ``state_in [B, N, H P]`` float32, ``live [B, T]`` bool (a prefix of
     each row). ``T`` is one block, or is padded here to whole blocks of
     ``block``. Returns (``y [B, T, H, P]`` float32, ``state_out``): the

@@ -495,7 +495,7 @@ class LLMEngine:
                 from ray_tpu.ops.ssm import step_choice
                 self._state_step_impl = step_choice(
                     model_config.paged_impl, model_config.ssm_state,
-                    model_config.ssm_inner)
+                    model_config.ssm_inner, groups=model_config.ssm_groups)
             elif model_config.delta_heads:
                 from ray_tpu.ops.delta import step_choice
                 self._state_step_impl = step_choice(
@@ -810,6 +810,13 @@ class LLMEngine:
         # (min(visible, index_topk)), summed over queries; keys the
         # indexer scored and (token, expert) assignments, over layers
         self._sparse = collections.Counter()
+        # layers whose feed-forward is the dropless experts (a layer of
+        # another kind may have none), and layers that are a feed-forward
+        # alone
+        self._expert_layers = model_config.expert_layers
+        self._ffn_layers = sum(
+            model_config.layer_kind(l) == "ffn"
+            for l in range(model_config.n_layers))
         # what the step programs' selected attention was built as
         # (ops/sparse_attention.py), the decode step's and a chunk's:
         # the paged kernel with the selection as a mask, or the plain
@@ -1522,6 +1529,10 @@ class LLMEngine:
                                      ("indexer_keys_scored", "scored"))
                    for kind in ("decode", "prefill")},
                 "moe_assignments_total": self._sparse["assigned"],
+                **{f"moe_{kind}_assignments_total":
+                   self._sparse["assigned", kind]
+                   for kind in ("decode", "prefill")},
+                "ffn_layers": self._ffn_layers,
                 **self._sparse_stats(),
                 "kv_block_size": self.config.kv_block_size,
                 "paged_impl": self.model_config.paged_impl,
@@ -2595,8 +2606,9 @@ class LLMEngine:
         self._sparse["visible"] += visible
         self._sparse["attended"] += attended
         self._sparse["attended", kind] += attended
-        self._sparse["assigned"] += int(n.sum()) * mc.experts_per_token \
-            * (mc.n_layers - mc.n_dense_layers)
+        assigned = int(n.sum()) * mc.experts_per_token * self._expert_layers
+        self._sparse["assigned"] += assigned
+        self._sparse["assigned", kind] += assigned
 
     def _decoding(self) -> List[_Request]:
         # only this thread moves a request in or out of a slot

@@ -36,6 +36,11 @@ def _inputs(seed, b, t):
         B=f(b, t, N), C=f(b, t, N), D=f(H), state=f(b, H, P, N))
 
 
+def _g(a):
+    """``B`` or ``C`` as the ops take it: one group for all heads."""
+    return a[..., None, :]
+
+
 def _sequential(x, dt, A, B, C, D, state, n_live):
     """Token by token; a row's tokens past ``n_live`` change nothing."""
     state = state.astype(np.float64).copy()
@@ -62,7 +67,8 @@ def test_the_blocked_scan_is_the_recurrence(t, block, n_live):
     n_live = np.asarray(n_live)
     live = np.arange(t)[None] < n_live[:, None]
     y, state = jax.jit(ssd_chunk_scan, static_argnames="block")(
-        a["x"], a["dt"], a["A"], a["B"], a["C"], a["D"], _kept(a["state"]),
+        a["x"], a["dt"], a["A"], _g(a["B"]), _g(a["C"]), a["D"],
+        _kept(a["state"]),
         jnp.asarray(live), block=block)
     want_y, want_state = _sequential(**a, n_live=n_live)
     state = _told(state)
@@ -77,13 +83,14 @@ def test_the_blocked_scan_is_the_recurrence(t, block, n_live):
 def test_a_state_carried_across_calls_is_one_long_scan():
     a = _inputs(3, 1, 40)
     whole, end = ssd_chunk_scan(
-        a["x"], a["dt"], a["A"], a["B"], a["C"], a["D"], _kept(a["state"]),
+        a["x"], a["dt"], a["A"], _g(a["B"]), _g(a["C"]), a["D"],
+        _kept(a["state"]),
         jnp.ones((1, 40), bool), block=8)
     parts, state = [], jnp.asarray(_kept(a["state"]))
     for lo, hi in ((0, 16), (16, 29), (29, 40)):
         y, state = ssd_chunk_scan(
-            a["x"][:, lo:hi], a["dt"][:, lo:hi], a["A"], a["B"][:, lo:hi],
-            a["C"][:, lo:hi], a["D"], state, jnp.ones((1, hi - lo), bool),
+            a["x"][:, lo:hi], a["dt"][:, lo:hi], a["A"], _g(a["B"][:, lo:hi]),
+            _g(a["C"][:, lo:hi]), a["D"], state, jnp.ones((1, hi - lo), bool),
             block=8)
         parts.append(y)
     np.testing.assert_allclose(np.concatenate(parts, 1), whole, rtol=2e-5,
@@ -98,7 +105,7 @@ def test_the_one_token_step_is_the_recurrence():
     ys = []
     for t in range(6):
         y, state = ssd_step(a["x"][:, t], a["dt"][:, t], a["A"],
-                            a["B"][:, t], a["C"][:, t], a["D"], state,
+                            _g(a["B"][:, t]), _g(a["C"][:, t]), a["D"], state,
                             jnp.asarray(live))
         ys.append(y)
     want_y, want_state = _sequential(**a, n_live=np.where(live, 6, 0))
@@ -117,8 +124,8 @@ def _whole_array(seed, layers, slots):
 
 def _step_slots(a, t, states, layer, slots, live, fresh, impl):
     return jax.jit(ssd_step_slots, static_argnames="impl")(
-        a["x"][:, t], a["dt"][:, t], a["A"], a["B"][:, t], a["C"][:, t],
-        a["D"], jnp.asarray(states), jnp.asarray(layer, jnp.int32),
+        a["x"][:, t], a["dt"][:, t], a["A"], _g(a["B"][:, t]),
+        _g(a["C"][:, t]), a["D"], jnp.asarray(states), jnp.asarray(layer, jnp.int32),
         None if slots is None else jnp.asarray(slots, jnp.int32),
         jnp.asarray(live), jnp.asarray(fresh), impl=impl)
 
@@ -166,7 +173,7 @@ def test_the_step_kernel_is_the_plain_step(case):
     if case == "a_fresh_row_over_a_nan":
         # what the slot held is not read: the state is the token's own
         alone, _ = ssd_step(a["x"][2:, 0], a["dt"][2:, 0], a["A"],
-                            a["B"][2:, 0], a["C"][2:, 0], a["D"],
+                            _g(a["B"][2:, 0]), _g(a["C"][2:, 0]), a["D"],
                             jnp.zeros((1, N, H * P)), jnp.ones(1, bool))
         np.testing.assert_allclose(ys[0][2], alone[0], rtol=1e-6, atol=1e-6)
     if case == "six_steps_in_a_row":
@@ -194,7 +201,7 @@ def test_a_shape_that_does_not_tile_takes_the_plain_step(monkeypatch):
         jax.eval_shape(
             lambda s: ssd_step_slots(
                 jnp.zeros((2, heads, P)), jnp.zeros((2, heads)),
-                jnp.zeros(heads), jnp.zeros((2, n)), jnp.zeros((2, n)),
+                jnp.zeros(heads), jnp.zeros((2, 1, n)), jnp.zeros((2, 1, n)),
                 jnp.zeros(heads), s, jnp.int32(0), None, jnp.ones(2, bool),
                 jnp.zeros(2, bool), impl="auto"),
             jax.ShapeDtypeStruct((1, 2, n, heads * P), jnp.float32))

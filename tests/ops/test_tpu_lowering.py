@@ -686,8 +686,8 @@ def test_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     rows, heads, width, n, layers = 64, 128, 64, 128, 9
     args = (s((rows, heads, width), jnp.bfloat16), s((rows, heads)),
-            s((heads,)), s((rows, n), jnp.bfloat16),
-            s((rows, n), jnp.bfloat16), s((heads,)),
+            s((heads,)), s((rows, 1, n), jnp.bfloat16),
+            s((rows, 1, n), jnp.bfloat16), s((heads,)),
             s((layers, rows, n, heads * width)), s((), jnp.int32))
     flags = (s((rows,), jnp.bool_), s((rows,), jnp.bool_))
     cache_was = jax.config.jax_enable_compilation_cache
@@ -826,3 +826,100 @@ def test_compiled_step_updates_the_delta_state_in_place(
     limit = state // 2 if entry == "prefill" else state // 32
     assert memory.temp_size_in_bytes < limit, memory.temp_size_in_bytes
     assert "tpu_custom_call" in compiled.as_text()       # the paged layers
+
+
+@pytest.mark.parametrize("entry,batch", [
+    ("decode_step", 1), ("decode_step", 48), ("prefill", 1)])
+def test_compiled_step_of_one_sublayer_a_layer_keeps_its_state_in_place(
+        entry, batch, one_chip, monkeypatch):
+    """A stack whose layers are ONE sublayer each, the published
+    pattern's first 22 letters (``MEMEMEM*EMEMEMEM*EMEME``: ten Mamba-2
+    mixers at the published scan widths, 128 heads of 64 over a state of
+    128 with EIGHT groups of B and C, two paged layers of 16 query heads a
+    kv head, ten expert layers of 64 of 512 ungated experts of 1024 x 2688
+    in a latent, 22 a token) and a narrow model around them: the step programs compile
+    for a described v5e, a decode step of ONE row among them (22
+    assignments against 10 x 64 groups of the grouped product: the case
+    of ``moe._FEW_ROWS``), of 48 rows and a 1,024-token chunk (eight scan
+    blocks of 128). The per-slot state, 2.0 GB here, is updated where it
+    lies (heads and channels are one axis of it, and a group is 1,024
+    consecutive lanes: a tile of the step kernel reads its group's
+    column), the one-token update is the kernel once a mixer layer, and
+    the expert layers slice no expert stack."""
+    from ray_tpu.models import (TransformerConfig, decode_step,
+                                inference_params, init_kv_cache,
+                                init_params, prefill)
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    kinds = {"M": "mamba", "E": "ffn", "*": "full"}
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=512, n_layers=22, n_heads=32, n_kv_heads=2,
+        head_dim=128, d_ff=256, max_seq_len=2048, rotary_dim=0,
+        block_style="llama", dtype=jnp.bfloat16, remat_policy="none",
+        paged_impl="kernel", norm_eps=1e-5, paged_block_r=16,
+        paged_block_r_prefill=512,
+        layer_pattern=[kinds[c] for c in "MEMEMEM*EMEMEMEM*EMEME"],
+        mixer_only=True, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+        ssm_chunk=128, ssm_groups=8, n_experts=512, experts_per_token=22,
+        expert_width=2688, shared_expert_width=512, router_score="sigmoid",
+        router_bias=True, routed_scale=5.0, experts_held=64,
+        expert_act="relu2", moe_latent=1024)
+    slots, table, chunk = 48, 128, 1024
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    try:
+        params = shaped(jax.eval_shape(lambda: inference_params(
+            cfg, init_params(cfg, jax.random.PRNGKey(0), dtype=cfg.dtype))))
+        cache = shaped(jax.eval_shape(lambda: init_kv_cache(
+            cfg, 1 + slots * table, BLOCK, state_slots=slots)))
+        if entry == "decode_step":
+            fn = functools.partial(decode_step, cfg)
+            args = (params, i32(batch), cache, i32(batch, table), i32(batch))
+        else:
+            fn = functools.partial(prefill, cfg)
+            args = (params, i32(1, chunk), cache, i32(1, table), i32(1),
+                    i32(1), None, None, i32(1))
+        compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    state = 10 * slots * 8192 * 128 * 4
+    assert cache["ssm"].shape == (10, slots, 128, 8192)
+    assert cache["conv"].shape == (10, slots, 3, 8192 + 2 * 8 * 128)
+    assert cache["k"].shape[0] == 2                 # the two paged layers
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state
+    # a chunk's 22,528 assignments hold their rows between the experts'
+    # two matrices (0.5 GB, as at the published model width); a decode
+    # step a few rows' states
+    limit = state // 2 if entry == "prefill" else state // 32
+    assert memory.temp_size_in_bytes < limit, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                     # the paged layers
+    # no copy of the held experts of all ten layers, in either matrix
+    stacks = re.findall(
+        r"= bf16\[(?:10,64|640),(?:1024,2688|2688,1024)\]\S* ([\w\-]+)\(",
+        text)
+    assert not [op for op in stacks if op not in (
+        "parameter", "get-tuple-element", "bitcast")], stacks
+    if entry != "decode_step":
+        return
+    # the one-token update is the kernel, once a mixer layer (each a run
+    # of its own between expert layers), on the whole array, and nothing
+    # else makes an array of the rows' states, in either order of its axes
+    kernels = re.findall(
+        rf"%ssm_step[.\d]* = \(f32\[{batch},1,8192\]\S*, "
+        rf"f32\[10,{slots},128,8192\]\S*\) custom-call\(", text)
+    assert len(kernels) == 10, kernels
+    made = re.findall(
+        r"= f32\[(?:\d+,)+(?:128,8192|8192,128)\]\S* ([\w\-]+)\(", text)
+    assert not [op for op in made if op not in (
+        "parameter", "get-tuple-element", "bitcast")], made
