@@ -63,7 +63,18 @@ step**:
   regrouped host-side to ``[B, kv_heads, C·group, D]`` rows (a
   transpose of the tiny q tensor, not of the cache); a grid step loads
   the pages of ``heads_per_step`` kv heads and walks them with a static
-  loop — the cache is never repeated or copied.
+  loop — the cache is never repeated or copied;
+- a **chunk call's step is bound by its page copies**, not by its
+  arithmetic: a step starts 2·P of them whatever their size, about
+  50 ns each on a v5e (64 copies of one kv head's 4 KB: 3.4 µs, where
+  folding them into 512 query rows is 1.8; PERF.md section 6, PR 61),
+  and a copy of four heads' rows of a page costs what one head's does.
+  So the dense form's chunk call on a full-attention layer serves up
+  to four kv heads from one fetch of a group
+  (:func:`_chunk_heads_per_step`: up to ``_MAX_ROWS_PER_CHUNK_STEP``
+  query rows a step, while the step still holds the P pages it held
+  with one): a quarter of the copies and of the sweeps, the same
+  arithmetic a head in the same order, every row bit for bit.
 
 Every operand tiles the way Mosaic requires. A page is a
 ``(block_size, head_dim)`` tile per kv head because ``kv_heads`` sits
@@ -122,6 +133,11 @@ _NEG_INF = -1e30
 #: query rows (heads_per_step × block_r) one grid step may carry: bounds
 #: the f32 (m, l, acc) scratch to ~1 MiB at head_dim 256
 _MAX_ROWS_PER_STEP = 512
+
+#: query rows a grid step of a chunk call may carry on the dense form
+#: (_chunk_heads_per_step): four kv heads' row blocks of 512, whose
+#: f32 (m, l, acc) scratch is 3 MiB at head_dim 128
+_MAX_ROWS_PER_CHUNK_STEP = 2048
 
 #: pages a grid step may fold, largest first (paged_pages_per_step)
 _PAGE_GROUPS = (32, 16, 8, 4, 2, 1)
@@ -343,14 +359,35 @@ def _paged_kernel(*refs, bs: int, hb: int, pp: int, slots: int,
             o_ref[0, i] = (acc_s[i] / l).astype(o_ref.dtype)
 
 
-def _heads_per_step(kv_heads: int, block_r: int) -> int:
-    """Largest divisor of ``kv_heads`` whose rows fit one grid step:
-    decode (a few rows per head) takes every head of a page in one
-    step, a prefill chunk (hundreds of rows per head) one or two."""
-    hb = max(1, min(kv_heads, _MAX_ROWS_PER_STEP // block_r))
+def _heads_per_step(kv_heads: int, block_r: int,
+                    max_rows: Optional[int] = None) -> int:
+    """Largest divisor of ``kv_heads`` whose rows fit one grid step
+    (``max_rows``, ``_MAX_ROWS_PER_STEP`` unless given): decode (a few
+    rows per head) takes every head of a page in one step, a prefill
+    chunk (hundreds of rows per head) one or two."""
+    hb = max(1, min(kv_heads,
+                    (max_rows or _MAX_ROWS_PER_STEP) // block_r))
     while kv_heads % hb:
         hb -= 1
     return hb
+
+
+def _chunk_heads_per_step(kv_heads: int, block_r: int, pages_of) -> int:
+    """kv heads a grid step of a chunk call serves from one fetch of a
+    page group, on the dense form of a full-attention layer: the most
+    (a divisor of ``kv_heads``, up to ``_MAX_ROWS_PER_CHUNK_STEP`` query
+    rows) at which the step still holds the pages it holds with
+    :func:`_heads_per_step`'s (``pages_of(heads)``: the group is what
+    the online softmax folds at once, so the same group is the same
+    bits). A step's copies cost by their number, not their size: more
+    heads a step are fewer copies a head."""
+    narrow = _heads_per_step(kv_heads, block_r)
+    widest = _heads_per_step(kv_heads, block_r, _MAX_ROWS_PER_CHUNK_STEP)
+    pages = pages_of(narrow)
+    for hb in range(widest, narrow, -1):
+        if kv_heads % hb == 0 and pages_of(hb) == pages:
+            return hb
+    return narrow
 
 
 def _row_block(rows: int, head_dim: int, dtype, block_r: Optional[int],
@@ -552,10 +589,18 @@ def paged_flash_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         block_r = _row_block(rows, d, q.dtype, block_r, chip)
         c_pad, rows_pad = c, _round_up(rows, block_r)
     nr = rows_pad // block_r
-    hb = _heads_per_step(g, block_r)
     chosen_bytes = 0 if chosen is None else (block_r if by_head else 4)
-    pp = _pages_per_step(hb, bs, d, k_cache.dtype, block_r, t,
-                         2 if v_width is None else 1, chosen_bytes)
+
+    def pages_of(heads):
+        return _pages_per_step(heads, bs, d, k_cache.dtype, block_r, t,
+                               2 if v_width is None else 1, chosen_bytes)
+    # a chunk of the dense form on a full-attention layer: more kv heads
+    # a fetch (a latent pool has one key head to serve). Every other
+    # call (one row block, a window, a selection) has the grid it had.
+    hb = _chunk_heads_per_step(g, block_r, pages_of) \
+        if nr > 1 and not window and chosen is None \
+        else _heads_per_step(g, block_r)
+    pp = pages_of(hb)
 
     # Group-major query rows: row r of kv head g is (c = r // rep,
     # head = g*rep + r % rep). Only q (tiny) is reshaped — never the

@@ -983,6 +983,142 @@ def test_paged_row_blocks_is_the_kernels_own_count(start, chunk, n, rep,
     assert is_live[:live].all()
 
 
+# ------------------ a chunk call's step serves more kv heads a fetch
+@pytest.fixture
+def rows_a_step(monkeypatch):
+    """``rows_a_step(narrow, chunk)`` sets the query rows a grid step
+    may carry (``_MAX_ROWS_PER_STEP``) and those of a chunk call's on
+    the dense form (``_MAX_ROWS_PER_CHUNK_STEP``): at these tiny row
+    blocks the real 512 / 2048 hold every kv head either way."""
+    def set_(narrow, chunk):
+        monkeypatch.setattr(pf, "_MAX_ROWS_PER_STEP", narrow)
+        monkeypatch.setattr(pf, "_MAX_ROWS_PER_CHUNK_STEP", chunk)
+    return set_
+
+
+def _grid_of(*args, **kw):
+    """The grid of the one ``pallas_call`` of ``paged_flash_attention(q,
+    k, v, bt, pos, lens[, chosen], **kw)``; arrays or their shapes."""
+    jaxpr = jax.make_jaxpr(lambda *a: paged_flash_attention(
+        *a[:6], chosen=a[6] if len(a) > 6 else None, interpret=True,
+        **kw))(*args)
+    call, = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    return call.params["grid_mapping"].grid
+
+
+#: CHUNKS and chunks deep in a context, (start, live tokens) a sequence: a row block
+#: sweeps up to eight page groups of 8 keys, all but the last one or two
+#: wholly under every row's position (96% of a document chunk's tiles in
+#: the window cell: PERF.md section 6, PR 61)
+ALL_CHUNKS = {
+    **CHUNKS,
+    "deep_in_a_long_context": [(44, 16)],
+    "a_ragged_end_deep_in_a_context": [(37, 11)],
+    "two_sequences_of_unlike_length": [(44, 16), (3, 9)],
+}
+
+
+@pytest.mark.parametrize("H,KVH,wide", [(4, 4, 4), (8, 2, 2), (12, 6, 3)])
+@pytest.mark.parametrize("case", sorted(ALL_CHUNKS))
+def test_more_kv_heads_a_step_is_bit_for_bit_one_head_a_step(
+        case, H, KVH, wide, group_of, rows_a_step):
+    """The dense form's chunk call with up to four kv heads' row blocks
+    a grid step (four of 4, two of 2, three of 6: a divisor) against
+    the same call held to one head a step, the grid it had: a quarter,
+    half, a third of the sweeps over the kv-head axis, and every row of
+    the result, live or padding, has the same bits: a head's arithmetic
+    is its own, in the same order, over the same page groups. Every
+    live row is the float32 reference's."""
+    group_of(2)
+    chunks = ALL_CHUNKS[case]
+    C, D, bs, T, block_r = 16, 8, 4, 16, 8
+    B = len(chunks)
+    start, n = np.array(chunks, np.int32).T
+    pos = jnp.asarray(start[:, None] + np.arange(C, dtype=np.int32))
+    lens = jnp.asarray(start + n)
+    _, _, kc, vc, bt = _paged_case(51, B, T * bs, H, KVH, D, bs, T)
+    q = np.random.default_rng(52).normal(
+        size=(B, C, H, D)).astype(np.float32)
+    args = (jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(bt), pos, lens)
+    got = {}
+    for name, chunk_rows in (("one", block_r), ("more", 4 * block_r)):
+        rows_a_step(block_r, chunk_rows)
+        grid = _grid_of(*args, block_r=block_r)
+        assert grid[1] == (KVH if name == "one" else KVH // wide)
+        assert grid[2] == C * (H // KVH) // block_r > 1
+        got[name] = np.asarray(paged_flash_attention(
+            *args, block_r=block_r, interpret=True))
+    np.testing.assert_array_equal(got["more"], got["one"])
+    want = np.asarray(paged_attention(q, kc, vc, bt, pos, impl="reference"))
+    for b in range(B):
+        np.testing.assert_allclose(got["more"][b, :n[b]], want[b, :n[b]],
+                                   **TOL)
+
+
+#: kv-head steps of the grid at 16 heads on 8 kv heads where a step may
+#: carry 128 rows and a chunk's (128, 512): a chunk is 64 tokens in row
+#: blocks of 64 rows (two heads a step, eight of the dense form's)
+KV_HEAD_STEPS = {"decode": (1, 1), "verify": (1, 1), "latent": (1, 1),
+                 "window": (4, 4), "selects": (4, 4), "dense": (4, 1)}
+
+
+@pytest.mark.parametrize("form", sorted(KV_HEAD_STEPS))
+def test_more_heads_a_step_are_the_dense_chunk_calls_alone(form,
+                                                           rows_a_step):
+    """By the call's static arguments: a one-row-block call (decode,
+    verify), a window layer's chunk, a latent pool's (one key head) and
+    a selecting chunk keep ``_heads_per_step``'s heads a step, whatever
+    a chunk's step may carry; the dense form's chunk call takes all
+    eight."""
+    D, bs, T, H, KVH = 128, 16, 8, 16, 8
+    s = jax.ShapeDtypeStruct
+    chunk = {"decode": 1, "verify": 5}.get(form, 64)
+    batch = 4 if chunk < 64 else 1
+    kw = {"window": dict(window=24), "latent": dict(v_width=64)}.get(
+        form, {})
+    pool = s((1 + batch * T, 1 if form == "latent" else KVH, bs, D),
+             jnp.float32)
+    args = [s((batch, chunk, H, D), jnp.float32), pool,
+            None if form == "latent" else pool, s((batch, T), jnp.int32),
+            s((batch, chunk), jnp.int32), s((batch,), jnp.int32)]
+    if form == "selects":
+        args.append(s((batch, chunk, T * bs), jnp.bool_))
+    steps = []
+    for chunk_rows in (128, 512):
+        rows_a_step(128, chunk_rows)
+        steps.append(_grid_of(
+            *args, block_r=None if chunk < 64 else 64, **kw)[1])
+    assert tuple(steps) == KV_HEAD_STEPS[form]
+
+
+@pytest.mark.parametrize("cell,kv_heads,block_r,head_dim,table,heads", [
+    ("repoqa", 8, 512, 128, 4096, 4),       # 48 heads on 8
+    ("docqa", 8, 512, 128, 256, 4),
+    ("sessions64", 8, 512, 128, 512, 4),
+    ("shared_docs12", 30, 512, 128, 512, 3),    # MHA at 30: a divisor
+    ("reason48", 2, 512, 128, 512, 2),          # two kv heads in all
+    # MHA at head_dim 256: four heads a step already, and eight would
+    # halve the page group (16 KB a head a page): the step it had
+    ("chat", 16, 128, 256, 128, 4),
+])
+def test_a_chunk_steps_heads_at_the_cells_shapes(cell, kv_heads, block_r,
+                                                 head_dim, table, heads):
+    """Heads a step of each cell's chunk call on its full-attention
+    layers: up to four, a divisor of the kv heads, and never at the
+    price of a smaller page group (the group is what the softmax folds
+    at once: the same group is the same bits)."""
+    def pages_of(hb):
+        return pf._pages_per_step(hb, 16, head_dim, jnp.bfloat16, block_r,
+                                  table)
+    narrow = pf._heads_per_step(kv_heads, block_r)
+    got = pf._chunk_heads_per_step(kv_heads, block_r, pages_of)
+    assert got == heads and kv_heads % got == 0
+    assert got * block_r <= pf._MAX_ROWS_PER_CHUNK_STEP
+    assert pages_of(got) == pages_of(narrow) == 32
+
+
 # ------------------------------------------------- a selection as a mask
 #: B sequences of (first position, query tokens, live tokens) each; the
 #: tables hold 8 pages of 4. ``k``: keys a query keeps; ``ties``: scores

@@ -156,13 +156,20 @@ UNSELECTED = {
 
 
 @pytest.mark.parametrize("form", sorted(UNSELECTED))
-def test_a_call_without_a_selection_lowers_as_it_did(form):
+def test_a_call_without_a_selection_lowers_as_it_did(form, monkeypatch):
     """``chosen=None`` adds no operand and changes no instruction: the
     dense and the latent form, one row block and several, lower to the
-    kernel and the call the commit before the operand lowered to."""
+    kernel and the call the commit before the operand lowered to. (The
+    dense form's chunk call serves four kv heads a grid step since
+    PR 61: held to one, the grid it had, it is that kernel still; the
+    body of a head did not change.)"""
     import hashlib
+
+    import ray_tpu.ops.paged_flash as pf
     if jax.__version__ != "0.9.0":
         pytest.skip("the digests were taken under jax 0.9.0")
+    monkeypatch.setattr(pf, "_MAX_ROWS_PER_CHUNK_STEP",
+                        pf._MAX_ROWS_PER_STEP)
     (b, c, h, g, d, t, block_r, v_width), want = UNSELECTED[form]
     pool = _s((3, 1 + b * 8, g, BLOCK, d))
     pools = (pool,) if v_width else (pool, pool)
@@ -220,6 +227,12 @@ def one_chip():
     # row blocks a kv head, a window layer's 32 over its short table
     (1, 2048, 48, 8, 128, 65536, 2, 38913, 512, 0, False),
     (1, 2048, 64, 8, 128, 161 * BLOCK, 3, 4097, 512, 512, False),
+    # the paged layers' chunks of the three state cells, at the heads a
+    # step their kv heads allow (PR 61: four of Granite's 8, three of
+    # Olmo-Hybrid's 30, both of Nemotron's 2)
+    (1, 1024, 32, 8, 128, 8192, 1, 4097, 512, 0, False),
+    (1, 2048, 30, 30, 128, 8192, 1, 3073, 512, 0, False),
+    (1, 1024, 32, 2, 128, 8192, 2, 4097, 512, 0, False),
     # with ``chosen``: Keye's decode step (one int32 row a sequence, a
     # row of P x 16 lanes a grid step) and a chunk's 256-row block
     # (an int8 row a token, widened in the body)
