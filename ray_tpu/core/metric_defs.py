@@ -179,6 +179,20 @@ class RuntimeMetrics:
             "train_mfu_pct",
             "Model FLOP utilization (%) from the bench FLOP model "
             "(flops_per_token x tokens/s over the chip's bf16 peak)")
+        # the dropless experts' routing counters of a training step
+        # (models/moe.py route_stats)
+        self.train_moe_balance = Gauge(
+            "train_moe_balance",
+            "Switch-form balance statistic E * sum f_e P_e of the most "
+            "recent step, a mean over the expert layers (1 = even)")
+        self.train_moe_load_max_over_mean = Gauge(
+            "train_moe_load_max_over_mean",
+            "Fullest held expert's rows over the held experts' mean, "
+            "most recent step, a mean over the expert layers")
+        self.train_moe_held_assignments = Gauge(
+            "train_moe_held_assignments",
+            "Assignments that landed on experts held here, most recent "
+            "step, summed over the expert layers")
         # -- MPMD pipeline (parallel/mpmd_pipeline.py)
         self.pipeline_mailbox_depth = Gauge(
             "pipeline_stage_mailbox_depth",

@@ -1,7 +1,8 @@
-"""Mixture-of-experts MLPs: capacity-based top-1 (Switch) routing, the
-trained form, and below it the dropless top-k gated experts, the served
-form (softmax or sigmoid router, a shared expert, a held share of the
-experts).
+"""Mixture-of-experts MLPs: capacity-based top-1 (Switch) routing, and
+below it the dropless top-k gated experts, served in every form they
+have (softmax or sigmoid router, a shared expert, a held share of the
+experts, a latent) and trained in the softmax-routed form, a held share
+included (``transformer.refuse_training`` names the rest).
 
 Switch, TPU-first dispatch: token->expert movement is expressed as einsums over a
 dispatch one-hot ``[tokens, experts, capacity]`` (the flaxformer/Switch
@@ -17,6 +18,8 @@ jits into the one GSPMD program like everything else.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -109,7 +112,6 @@ def moe_logical_axes():
 # projected up once a token (``w_lat_up [R, D]``); the router and the
 # shared expert read the full-width token.
 
-@jax.named_scope("moe_router")
 def _group_limited(c, scores, bias):
     """The experts ``[N, k]`` a token is sent to under a group limit
     and a bias (DeepSeek-V3's ``noaux_tc``): ``scores [N, E]`` plus the
@@ -128,9 +130,11 @@ def _group_limited(c, scores, bias):
     return jax.lax.top_k(pick, c.experts_per_token)[1]
 
 
-def route_topk(c, lp, x):
+@jax.named_scope("moe_router")
+def route_topk(c, lp, x, scores_too=False):
     """Router of the dropless layer. ``x [N, D]`` -> (weights ``[N, k]``
-    float32, experts ``[N, k]`` int32): each expert's score in float32
+    float32, experts ``[N, k]`` int32; with ``scores_too`` every expert's
+    score ``[N, E]`` behind them): each expert's score in float32
     (``router_score``: softmax over all experts, or a sigmoid of each),
     the k largest (with ``n_group`` / ``router_bias`` the k that
     :func:`_group_limited` selects, whose weights are their SCORES, the
@@ -152,6 +156,8 @@ def route_topk(c, lp, x):
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if c.routed_scale != 1.0:
         weights = weights * c.routed_scale
+    if scores_too:
+        return weights, experts, scores
     return weights, experts
 
 
@@ -194,13 +200,121 @@ def _shared_expert(c, lp, x):
     return jnp.dot(mid, lp["ws_down"].astype(dt))
 
 
+# The sort and the unsort move rows by a permutation of the N * k
+# assignments, and each is the other's transpose. Differentiated as
+# written, a row gather's transpose is a scatter-add of as many rows (for
+# the sort into k-fold collisions); written out here it is the other
+# gather: the forward's program is the one it was, the backward's reads
+# rows and adds none in place.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sort_rows(x, order, inverse, k):
+    """``x [N, D]`` -> row ``order[i] // k`` of it at ``i``, ``[N * k,
+    D]``: each token's row where each of its k assignments sorts to."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _sort_rows_fwd(x, order, inverse, k):
+    return _sort_rows(x, order, inverse, k), (inverse,)
+
+
+def _sort_rows_bwd(k, res, g):
+    inverse, = res
+    back = jnp.take(g, inverse, axis=0)        # assignment order again
+    return (jnp.sum(back.reshape(-1, k, g.shape[-1]), axis=1,
+                    dtype=jnp.float32).astype(g.dtype), None, None)
+
+
+_sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsort_rows(ys, order, inverse):
+    """``ys [N * k (or more: padding behind), D]`` -> its rows in
+    assignment order, ``[N * k, D]``: row i of ``ys`` is assignment
+    ``order[i]``."""
+    return jnp.take(ys, inverse, axis=0)
+
+
+def _unsort_rows_fwd(ys, order, inverse):
+    return _unsort_rows(ys, order, inverse), (order, ys.shape[0])
+
+
+def _unsort_rows_bwd(res, g):
+    order, rows = res
+    back = jnp.take(g, order, axis=0)
+    if rows != back.shape[0]:                  # the padding's rows: none
+        back = jnp.pad(back, ((0, rows - back.shape[0]), (0, 0)))
+    return back, None, None
+
+
+_unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
+
+
+# The grouped product and its two transposes. Differentiated as written,
+# ``ragged_dot``'s transposes take the float32 cotangent as an operand
+# (a [rows, width] float32 array a product, and a float32 pass through
+# the MXU); written out here they are grouped products of the compute
+# dtype's operands with float32 accumulation, as the forward: dX the
+# cotangent's rows through each group's transposed matrix, dW a group's
+# own rows' outer products summed. The matrices come in as they are
+# stored (training's float32 masters, cast here at each use; a served
+# tree's are in the compute dtype already and the cast is none), so dW
+# goes out in float32 to a float32 master, with no rounding between. A
+# row of no group is whatever the product left there, in dX as in the
+# forward: zeroed here, because the sort's transpose adds a token's rows.
+
+@jax.custom_vjp
+def _grouped_dot(x, w, sizes):
+    """Rows of group i of ``x [M, K]`` times ``w[i]`` (``w [G, K, N]``,
+    cast to ``x``'s dtype), ``[M, N]`` float32; the rows past
+    ``sum(sizes)`` belong to no group."""
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _grouped_dot_fwd(x, w, sizes):
+    return _grouped_dot(x, w, sizes), (x, w, sizes)
+
+
+_DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_dot_bwd(res, g):
+    x, w, sizes = res
+    g = g.astype(x.dtype)
+    dx = jax.lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2),
+                            sizes, preferred_element_type=x.dtype)
+    in_a_group = jnp.arange(x.shape[0], dtype=jnp.int32) < jnp.sum(sizes)
+    dx = jnp.where(in_a_group[:, None], dx, jnp.zeros((), dx.dtype))
+    dw = jax.lax.ragged_dot_general(
+        x, g, sizes, _DW_DIMS, preferred_element_type=jnp.float32)
+    return dx, dw.astype(w.dtype), None
+
+
+_grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def _expert_rows(leaves, xs, sizes):
+    """The experts on rows sorted by expert: ``xs [M, width]`` in the
+    compute dtype, group i's ``sizes[i]`` rows through expert i's
+    matrices (``leaves``, as stored) -> ``[M, width]`` float32; the rows
+    past ``sum(sizes)`` are of no group."""
+    gate = _grouped_dot(xs, leaves["we_gate"], sizes) \
+        if "we_gate" in leaves else None
+    up = _grouped_dot(xs, leaves["we_up"], sizes)
+    mid = _expert_mid(gate, up).astype(xs.dtype)
+    return _grouped_dot(mid, leaves["we_down"], sizes)
+
+
 @jax.named_scope("moe_experts")
 def _routed_experts(c, lp, x, weights, experts, layer):
     """The held experts' part of the weighted sum, ``[N, width of x]``
     float32: sort the assignments by expert, the grouped products over
     the sorted rows, unsort, combine. ``x [N, D]`` (``[N, R]`` in a
     latent), ``weights``, ``experts [N, k]``."""
-    dt = c.dtype
     N, width = x.shape
     E, k = c.n_experts_held, c.experts_per_token
     flat = experts.reshape(N * k)
@@ -212,8 +326,11 @@ def _routed_experts(c, lp, x, weights, experts, layer):
         here = (flat >= 0) & (flat < E)
         flat = jnp.where(here, flat, E)
     order = jnp.argsort(flat)                  # stable: assignment order
-    xs = jnp.take(x, order // k, axis=0)       # rows sorted by expert
-    leaves = {name: lp[name].astype(dt) for name in expert_leaves(c)}
+    # unsort: row i of the sorted rows is assignment order[i]
+    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    xs = _sort_rows(x, order, inverse, k)      # rows sorted by expert
+    leaves = {name: lp[name] for name in expert_leaves(c)}
     groups = E
     if layer is not None:
         groups = leaves["we_up"].shape[0] * E
@@ -226,18 +343,8 @@ def _routed_experts(c, lp, x, weights, experts, layer):
     if N * k < _FEW_ROWS and groups > 128 and groups % 512:
         # rows of no group behind the others (see _FEW_ROWS)
         xs = jnp.pad(xs, ((0, _FEW_ROWS - N * k), (0, 0)))
-    gate = jax.lax.ragged_dot(xs, leaves["we_gate"], sizes,
-                              preferred_element_type=jnp.float32) \
-        if "we_gate" in leaves else None
-    up = jax.lax.ragged_dot(xs, leaves["we_up"], sizes,
-                            preferred_element_type=jnp.float32)
-    mid = _expert_mid(gate, up).astype(dt)
-    ys = jax.lax.ragged_dot(mid, leaves["we_down"], sizes,
-                            preferred_element_type=jnp.float32)
-    # unsort: row i of ys is assignment order[i]
-    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(
-        jnp.arange(N * k, dtype=jnp.int32))
-    ys = jnp.take(ys, inverse, axis=0).reshape(N, k, width)   # and unpad
+    ys = _expert_rows(leaves, xs, sizes)
+    ys = _unsort_rows(ys, order, inverse).reshape(N, k, width)  # and unpad
     if here is not None:
         # a row of no group is whatever the product left there: chosen
         # away, not multiplied by a zero weight
@@ -245,8 +352,65 @@ def _routed_experts(c, lp, x, weights, experts, layer):
     return jnp.sum(ys * weights[..., None], axis=1)
 
 
+#: a call of more tokens than this (a training step's 16,384; no served
+#: call's: a prefill chunk is 2048 at most) sends them through the
+#: experts this many at a time, :func:`_routed_experts_in_turns`
+_MANY_TOKENS = 4096
+
+
+def _routed_experts_in_turns(c, lp, x, weights, experts, layer):
+    """:func:`_routed_experts` over ``_MANY_TOKENS`` tokens at a time,
+    each turn recomputed in the backward pass. What lies between the sort
+    and the weighted sum is k rows a token, model-wide, several times
+    over and partly in float32 (4 to 5 GB at 16,384 tokens, top-8 and
+    2304 wide, forward and transposed together): a turn at a time it is
+    a turn's. Every turn sorts its own assignments and drops none, and
+    the turns' dW add up in float32 (``_grouped_dot``)."""
+    turns = x.shape[0] // _MANY_TOKENS
+
+    @jax.checkpoint
+    def turn(args):
+        return _routed_experts(c, lp, *args, layer)
+    ys = jax.lax.map(turn, tuple(
+        a.reshape((turns, _MANY_TOKENS) + a.shape[1:])
+        for a in (x, weights, experts)))
+    return ys.reshape(x.shape[0], -1)
+
+
+def route_stats(c, scores, experts):
+    """One expert layer's routing counters, float32 scalars with no
+    gradient: ``held_assignments`` (of the ``N * k`` assignments, those
+    that landed on an expert held here), ``load_max_over_mean`` (the
+    fullest held expert's rows over the held experts' mean: what a
+    grouped product's slowest group is to the even share), ``balance``
+    (the Switch form ``E * sum_e f_e P_e`` over ALL experts, ``f_e`` the
+    share of assignments and ``P_e`` the mean score: 1 when even; a
+    counter here, no term of a loss). ``scores [N, E]``, ``experts [N,
+    k]``."""
+    E = c.n_experts
+    counts = jnp.zeros((E,), jnp.float32).at[experts.reshape(-1)].add(1.0)
+    held = counts[c.expert_first:c.expert_first + c.n_experts_held]
+    balance = E * jnp.sum(counts / experts.size * jnp.mean(scores, axis=0))
+    return jax.lax.stop_gradient({
+        "held_assignments": jnp.sum(held),
+        "load_max_over_mean": jnp.max(held)
+        / jnp.maximum(jnp.mean(held), 1.0),
+        "balance": balance})
+
+
+def sum_route_stats(per_run):
+    """The step's counters from each scan's stacked :func:`route_stats`
+    (``[layers of the run]`` a leaf): assignments summed over the expert
+    layers, the two ratios their mean."""
+    cat = {k: jnp.concatenate([r[k] for r in per_run])
+           for k in per_run[0]}
+    return {"held_assignments": jnp.sum(cat["held_assignments"]),
+            "load_max_over_mean": jnp.mean(cat["load_max_over_mean"]),
+            "balance": jnp.mean(cat["balance"])}
+
+
 @jax.named_scope("moe")
-def topk_moe_mlp(c, lp, h, layer=None):
+def topk_moe_mlp(c, lp, h, layer=None, stats=False):
     """Dropless top-k expert MLP. ``h [B, S, D]`` (compute dtype) ->
     ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the held
     experts ``we_gate / we_up [E_held, D, F]``, ``we_down [E_held, F,
@@ -264,23 +428,35 @@ def topk_moe_mlp(c, lp, h, layer=None):
     other leaves, each layer's experts would be sliced out of the stack
     — a copy of all of them, in every layer of every step — because a
     grouped product, unlike a plain dot, cannot read its operand through
-    the slice."""
+    the slice. (Training scans the experts with the other leaves,
+    ``layer`` None: its float32 masters are cast a layer at a time, which
+    reads them through the slice, and a layer's dW is then its own
+    experts' and not the whole stack's.)
+
+    ``stats``: returns ``(out, route_stats(...))``, the layer's routing
+    counters beside it (training's; the served programs ask for none)."""
     dt = c.dtype
     B, S, D = h.shape
     x = h.reshape(B * S, D).astype(dt)
-    weights, experts = route_topk(c, lp, x)
+    weights, experts, *scores = route_topk(c, lp, x, scores_too=stats)
+    if stats:
+        counters = route_stats(c, scores[0], experts)
     xin = x
     if c.moe_latent:
         with jax.named_scope("moe_latent_down"):
             xin = jnp.dot(x, lp["w_lat_down"].astype(dt))
-    y = _routed_experts(c, lp, xin, weights, experts, layer)
+    if x.shape[0] > _MANY_TOKENS and not x.shape[0] % _MANY_TOKENS:
+        y = _routed_experts_in_turns(c, lp, xin, weights, experts, layer)
+    else:
+        y = _routed_experts(c, lp, xin, weights, experts, layer)
     if c.moe_latent:
         with jax.named_scope("moe_latent_up"):
             y = jnp.dot(y.astype(dt), lp["w_lat_up"].astype(dt),
                         preferred_element_type=jnp.float32)
     if c.shared_expert_width:
         y = y + _shared_expert(c, lp, x).astype(jnp.float32)
-    return y.reshape(B, S, D).astype(dt)
+    y = y.reshape(B, S, D).astype(dt)
+    return (y, counters) if stats else y
 
 
 def topk_moe_param_shapes(c):

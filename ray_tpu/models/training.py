@@ -105,6 +105,12 @@ class TrainStepBundle:
             m.train_step_wall.observe(elapsed / steps)
             m.train_loss.set(float(metrics["loss"]))
             m.train_grad_norm.set(float(metrics["grad_norm"]))
+            if "moe_balance" in metrics:
+                m.train_moe_balance.set(float(metrics["moe_balance"]))
+                m.train_moe_load_max_over_mean.set(
+                    float(metrics["moe_load_max_over_mean"]))
+                m.train_moe_held_assignments.set(
+                    float(metrics["moe_held_assignments"]))
             try:
                 from ray_tpu.parallel.mesh import chip_spec
                 achieved = tokens_per_s * \
@@ -318,6 +324,11 @@ def make_train_step(config: TransformerConfig, mesh,
                      "step": state["step"] + 1}
         metrics = {"loss": loss, "n_tokens": aux["n_tokens"],
                    "grad_norm": optax.global_norm(grads)}
+        # the dropless experts' routing counters of this step
+        # (``moe.route_stats``): ``moe_held_assignments``,
+        # ``moe_load_max_over_mean``, ``moe_balance``
+        metrics.update({f"moe_{k}": v
+                        for k, v in aux.get("moe", {}).items()})
         return new_state, metrics
 
     step_fn = jax.jit(

@@ -88,6 +88,14 @@ class TransformerConfig:
     rope_base: float = 10000.0
     block_style: str = "gptj"               # "gptj" | "llama"
     dtype: Any = jnp.bfloat16                # compute dtype
+    # init_params draws the embedding at this standard deviation (every
+    # matmul leaf at 0.02). At 0.02 a token's own embedding is no larger
+    # in the stream than the attention's running mean over its keys, which
+    # neighbouring tokens share: a router then sends neighbours alike, and
+    # which experts fill is the seed's draw. Well above it (PyTorch's
+    # nn.Embedding draws at 1) a token routes by its own embedding and the
+    # experts' loads average out over the batch.
+    embed_init_std: float = 0.02
     # Legacy bool (True -> "full", False -> "none"); None defers to
     # remat_policy. Kept so existing configs keep their exact behavior.
     remat: Optional[bool] = None
@@ -115,8 +123,9 @@ class TransformerConfig:
     n_experts: int = 0
     capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # Served forms ("llama" blocks, the cache path only; training keeps
-    # Switch top-1). experts_per_token > 0: every token to its k best of
+    # Served forms ("llama" blocks; the cache path, and of these keys
+    # the training path takes ``TRAINED_KEYS`` below: ``refuse_training``
+    # names the rest). experts_per_token > 0: every token to its k best of
     # n_experts gated (SwiGLU) experts of width expert_width (0 = d_ff),
     # renormalised softmax weights, none dropped (models/moe.py).
     experts_per_token: int = 0
@@ -182,7 +191,8 @@ class TransformerConfig:
     n_group: int = 0
     topk_group: int = 0
     router_bias: bool = False
-    # A stack described BY KIND OF LAYER (served; the 'llama' block over
+    # A stack described BY KIND OF LAYER (served, and of "full" and
+    # "window" layers trained; the 'llama' block over
     # per-head K/V): layer l is of kind layer_pattern[l % len] ("full" |
     # "window"; () = every layer "full"). A "full" layer has n_heads
     # query heads and rotates the first rotary_dim of head_dim at
@@ -477,6 +487,17 @@ class TransformerConfig:
         """Approximate train FLOPs/token (6·N active params + attention)."""
         s = seq_len or self.max_seq_len
         attn = 12 * self.n_layers * self.n_heads * self.head_dim * s
+        if self.sliding_window and self.sliding_window < s:
+            # a window layer's queries meet its window's keys, not the
+            # sequence's: s - (w - 1) / 2 positions' worth a full layer's
+            # s, the first w - 1 queries seeing fewer
+            w = self.sliding_window
+            seen = (w * (w + 1) / 2 + (s - w) * w) / (s * (s + 1) / 2)
+            windowed = sum(self.layer_kind(l) == "window"
+                           for l in range(self.n_layers))
+            attn = 12 * self.head_dim * s * (
+                (self.n_layers - windowed) * self.n_heads
+                + windowed * self.kind_heads("window") * seen)
         return 6.0 * self.num_active_params + attn
 
 
@@ -592,7 +613,8 @@ def init_params(config: TransformerConfig, key,
         head["b"] = jnp.zeros((c.vocab_size,), jnp.float32)
 
     return {
-        "embed": dense(keys[7], (c.vocab_size, c.d_model)),
+        "embed": dense(keys[7], (c.vocab_size, c.d_model),
+                       c.embed_init_std),
         "layers": layers,
         "final_norm": final,
         "lm_head": head,
@@ -1130,7 +1152,7 @@ def _init_kind_params(c, key, dtype, out_scale) -> Dict:
     keys += [jax.random.fold_in(key, 105 + i) for i in range(3)]
     params = {
         "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
-                             dtype=dtype),
+                             c.embed_init_std, dtype=dtype),
         "final_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
     }
     if not c.tie_embeddings:
@@ -1268,7 +1290,7 @@ def _init_latent_params(c, key, dtype, out_scale) -> Dict:
     n_moe = c.n_layers - c.n_dense_layers
     params = {
         "embed": _dense_init(keys[0], (c.vocab_size, c.d_model),
-                             dtype=dtype),
+                             c.embed_init_std, dtype=dtype),
         "layers": stack(keys[1], n_moe, False),
         "final_norm": {"scale": jnp.ones((c.d_model,), jnp.float32)},
         "lm_head": {"w": _dense_init(keys[2], (c.d_model, c.vocab_size),
@@ -1424,9 +1446,10 @@ def remat_policy_fn(name: str):
 
 
 # --------------------------------------------------------------- forward
-def _attention(c: TransformerConfig, q, k, v, mesh, rules):
+def _attention(c: TransformerConfig, q, k, v, mesh, rules, window=0):
     """Dispatch attention: ring over the sp axis when it's nontrivial,
-    otherwise the flash/reference dispatcher (ops layer).
+    otherwise the flash/reference dispatcher (ops layer); ``window``: a
+    sliding-window layer's keys behind a position (0: all of them).
 
     Under a mesh the dispatcher runs inside ``shard_map`` over the batch
     and heads axes: attention is independent per (sequence, head), and
@@ -1436,6 +1459,10 @@ def _attention(c: TransformerConfig, q, k, v, mesh, rules):
     sp_axis = rules.get("sequence") if rules else None
     if mesh is not None and sp_axis is not None and sp_axis in mesh.shape \
             and mesh.shape[sp_axis] > 1:
+        if window:
+            raise NotImplementedError(
+                "sliding_window under a split sequence axis (sp > 1): "
+                "ring attention knows the causal structure alone")
         batch_axes = rules.get("batch")
         spec = P(batch_axes, sp_axis, None, None)
         fn = jax.shard_map(
@@ -1445,7 +1472,8 @@ def _attention(c: TransformerConfig, q, k, v, mesh, rules):
         return fn(q, k, v)
     fn = functools.partial(
         multihead_attention, causal=True, impl=c.attn_impl,
-        block_q=c.attn_block_q, block_k=c.attn_block_k)
+        block_q=c.attn_block_q, block_k=c.attn_block_k,
+        **({"window": window} if window else {}))
     if mesh is not None and rules is not None and mesh.size > 1:
         spec = P(rules.get("batch"), None, rules.get("heads"), None)
         fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
@@ -1454,28 +1482,36 @@ def _attention(c: TransformerConfig, q, k, v, mesh, rules):
 
 
 @jax.named_scope("attn")
-def _attn_sublayer(c, h, lp, sin, cos, layout, mesh, rules):
-    """qkv projection → rotary → GQA repeat → attention → output proj.
-    Shared by both block styles (only the rotary layout differs)."""
-    e = h.shape[-1]
-    dt = c.dtype
+def _attn_sublayer(c, kind: _LayerKind, h, lp, sin, cos, mesh, rules):
+    """The training attention sublayer of a layer of ``kind``: qkv
+    projection → rotary (the kind's own: layout, width, table) → GQA
+    repeat → attention (behind the kind's window, where it has one) →
+    output proj. Shared by both block styles and by every kind of layer
+    that trains; in a stack by kind of layer under the kind's scope
+    (``layer/attn/window``, ``layer/attn/full``), as the cache path."""
+    with jax.named_scope(kind.scope) if kind.scope \
+            else contextlib.nullcontext():
+        e = h.shape[-1]
+        dt = c.dtype
 
-    def proj(w, n):
-        return jnp.einsum("bse,ehd->bshd", h.astype(dt),
-                          w.reshape(e, n, -1).astype(dt))
-    q = proj(lp["wq"], c.n_heads)
-    k = proj(lp["wk"], c.kv_heads)
-    v = proj(lp["wv"], c.kv_heads)
-    q = apply_rotary(q, sin, cos, layout=layout)
-    k = apply_rotary(k, sin, cos, layout=layout)
-    if c.kv_heads != c.n_heads:
-        rep = c.n_heads // c.kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    att = _attention(c, q, k, v, mesh, rules)
-    att = checkpoint_name(att, "attn_out")
-    return jnp.einsum("bshd,hde->bse", att,
-                      lp["wo"].reshape(c.n_heads, c.head_dim, e).astype(dt))
+        def proj(w, n):
+            return jnp.einsum("bse,ehd->bshd", h.astype(dt),
+                              w.reshape(e, n, -1).astype(dt))
+        q = proj(lp["wq"], kind.heads)
+        k = proj(lp["wk"], c.kv_heads)
+        v = proj(lp["wv"], c.kv_heads)
+        if kind.rotary.dim:
+            q = apply_rotary(q, sin, cos, layout=kind.rotary.layout)
+            k = apply_rotary(k, sin, cos, layout=kind.rotary.layout)
+        if c.kv_heads != kind.heads:
+            rep = kind.heads // c.kv_heads
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        att = _attention(c, q, k, v, mesh, rules, kind.window)
+        att = checkpoint_name(att, "attn_out")
+        return jnp.einsum(
+            "bshd,hde->bse", att,
+            lp["wo"].reshape(kind.heads, c.head_dim, e).astype(dt))
 
 
 def _swiglu(c, h, lp):
@@ -1487,17 +1523,21 @@ def _swiglu(c, h, lp):
 
 
 @jax.named_scope("mlp")
-def _mlp_sublayer(c, h, lp, layer=None):
+def _mlp_sublayer(c, h, lp, layer=None, stats=False):
     """The MLP the layer's leaves ``lp`` hold, on normed input h: a dense
     SwiGLU (``w_gate``: the 'llama' block, and a leading dense layer
     ahead of experts), experts, or the 'gptj' block's biased GELU MLP;
     returns (out, moe_aux). ``layer``: ``lp``'s expert leaves are whole
-    stacks and this is the layer's index in them (``moe.topk_moe_mlp``)."""
+    stacks and this is the layer's index in them (``moe.topk_moe_mlp``).
+    ``stats`` (training): the dropless experts' ``moe_aux`` is the
+    layer's routing counters (``moe.route_stats``), no term of a loss."""
     dt = c.dtype
     if "w_gate" in lp:
         return _swiglu(c, h, lp), 0.0
     if c.experts_per_token:
         from ray_tpu.models.moe import topk_moe_mlp
+        if stats:
+            return topk_moe_mlp(c, lp, h, layer, stats=True)
         return topk_moe_mlp(c, lp, h, layer), 0.0
     if c.n_experts:
         from ray_tpu.models.moe import moe_mlp
@@ -1557,52 +1597,121 @@ def _block(c, kind: _LayerKind, x, lp, attend, mlp):
     return x, cache, aux
 
 
+#: the served keys whose forms ``run_layers`` gives a training mixer and
+#: a differentiated feed-forward for: dropless top-k experts (softmax
+#: routed, a held share of them or all), and a stack of "window" and
+#: "full" attention layers, plain RoPE or YaRN by kind
+TRAINED_KEYS = ("experts_per_token", "layer_pattern", "sliding_window",
+                "rope_yarn")
+
+
+def untrained_keys(c: TransformerConfig) -> Tuple[str, ...]:
+    """The keys ``c`` sets whose form only the cache path implements:
+    every served key but ``TRAINED_KEYS``, ``layer_pattern`` where it
+    names a kind other than "window" and "full", and the forms of the
+    dropless experts that no test holds a gradient of (a shared expert,
+    a sigmoid router, a scale on the weights)."""
+    out = [k for k in c.served_keys if k not in TRAINED_KEYS]
+    if set(c.layer_pattern) - {"window", "full"}:
+        out.append("layer_pattern")
+    fields = c.__dataclass_fields__
+    out += [k for k in ("shared_expert_width", "router_score",
+                        "routed_scale")
+            if getattr(c, k) != fields[k].default]
+    return tuple(out)
+
+
 def refuse_training(c: TransformerConfig) -> None:
     """Raise, naming the keys at fault, for a configuration with a form
     that only :func:`_forward_with_cache` gives :func:`_block` a mixer
     for (``run_layers``, ``make_train_step`` and ``ParallelPlan.build``
     ask)."""
-    if c.served_only:
+    at_fault = untrained_keys(c)
+    if at_fault:
         raise NotImplementedError(
-            f"{', '.join(c.served_keys)}: set here, and served through "
-            f"prefill / decode_step only (as are "
-            f"{', '.join(c.SERVED_KEYS)}); training keeps Switch top-1 "
-            f"experts and one kind of dense attention layer")
+            f"{', '.join(at_fault)}: set here, and served through "
+            f"prefill / decode_step only. Of the served forms training "
+            f"takes {', '.join(TRAINED_KEYS)} (softmax-routed dropless "
+            f"experts, all or a held share; a stack of 'window' and "
+            f"'full' attention layers, plain RoPE or YaRN by kind), "
+            f"beside Switch top-1 experts and one kind of dense layer")
+
+
+def layer_stacks(config: TransformerConfig, params: Dict) -> Dict:
+    """What :func:`run_layers` scans, out of a parameter tree: the one
+    stack of a plain tree (``params["layers"]``), or of a tree by kind of
+    layer its stacks by name (``layers``, ``window_layers``)."""
+    if _tree_form(config) == "plain":
+        return params["layers"]
+    return {run.stack: params[run.stack]
+            for run in _layer_plan(config).runs}
 
 
 def run_layers(config: TransformerConfig, layer_params: Dict,
                x: jnp.ndarray, mesh=None, rules=None):
-    """Scan the transformer blocks in ``layer_params`` (leaves stacked
-    ``[n, ...]``) over hidden states ``x``: (b, s, e) -> ((b, s, e),
-    moe_aux). The trunk shared by :func:`hidden_states` and the
-    pipeline-stage forward (a stage's trunk is a contiguous slice of
-    the stacked layer leaves — same scan, fewer layers)."""
+    """Scan the transformer blocks in ``layer_params`` over hidden states
+    ``x``: (b, s, e) -> ((b, s, e), moe_aux). ``layer_params``
+    (:func:`layer_stacks`): one stack, its leaves ``[n, ...]``, or for a
+    stack by kind of layer the stacks by name. The trunk shared by
+    :func:`hidden_states` and the pipeline-stage forward (a stage's trunk
+    is a contiguous slice of the stacked layer leaves — same scan, fewer
+    layers).
+
+    The layer plan's runs are walked in order, one scan a run of
+    consecutive layers of one kind; each kind has its own rotary (a
+    table, or with YaRN rows at the positions), its window and its stack
+    of leaves, and is compiled once a run. A run shorter than its stack
+    scans a slice of it. ``moe_aux``: the Switch experts' summed loss
+    term, or for the dropless experts their routing counters
+    (``moe.route_stats``: assignments summed, the two ratios a mean over
+    the expert layers), or 0.0."""
     c = config
     refuse_training(c)
-    (kind,) = _layer_plan(c).kinds    # training: one kind of layer
-    sin, cos = _rotary(kind.rotary, None, x.shape[1])
-
-    def body(x, lp):
-        out, _, aux = _block(
-            c, kind, x, lp,
-            lambda h: (_attn_sublayer(c, h, lp, sin, cos,
-                                      kind.rotary.layout, mesh, rules), None),
-            lambda h: _mlp_sublayer(c, h, lp))
-        return out, aux
+    plan = _layer_plan(c)
+    one_stack = plan.tree == "plain"
+    stats = bool(c.experts_per_token)
+    positions = None if one_stack else \
+        jnp.arange(x.shape[1], dtype=jnp.int32)[None]
     policy = c.resolved_remat_policy
-    if policy != "none":
-        body = jax.checkpoint(body, policy=remat_policy_fn(policy))
+    auxes = []
+    for run in plan.runs:
+        kind = run.kind
+        sin, cos = _rotary(kind.rotary, positions, x.shape[1])
+        # the one stack is scanned as handed in (a pipeline stage hands
+        # in its slice); of a stack by kind, the run's layers
+        stack = layer_params
+        if not one_stack:
+            stack = layer_params[run.stack]
+            if any((run.at, run.n) != (0, v.shape[0])
+                   for v in jax.tree.leaves(stack)):
+                stack = jax.tree.map(
+                    lambda v, run=run: v[run.at:run.at + run.n], stack)
 
-    def scan_fn(carry, lp):
-        with jax.named_scope("layer"):
-            out, aux = body(carry, lp)
-        if mesh is not None and rules is not None:
-            from ray_tpu.parallel.sharding import constrain
-            out = constrain(out, mesh, rules, ("batch", "sequence", None))
-        return out, aux
+        def body(x, lp, kind=kind, sin=sin, cos=cos):
+            out, _, aux = _block(
+                c, kind, x, lp,
+                lambda h: (_attn_sublayer(c, kind, h, lp, sin, cos,
+                                          mesh, rules), None),
+                lambda h: _mlp_sublayer(c, h, lp, stats=stats))
+            return out, aux
+        if policy != "none":
+            body = jax.checkpoint(body, policy=remat_policy_fn(policy))
 
-    x, layer_aux = jax.lax.scan(scan_fn, x, layer_params)
-    return x, (jnp.sum(layer_aux) if c.n_experts else 0.0)
+        def scan_fn(carry, lp, body=body):
+            with jax.named_scope("layer"):
+                out, aux = body(carry, lp)
+            if mesh is not None and rules is not None:
+                from ray_tpu.parallel.sharding import constrain
+                out = constrain(out, mesh, rules,
+                                ("batch", "sequence", None))
+            return out, aux
+
+        x, layer_aux = jax.lax.scan(scan_fn, x, stack)
+        auxes.append(layer_aux)
+    if stats:
+        from ray_tpu.models.moe import sum_route_stats
+        return x, sum_route_stats(auxes)
+    return x, (sum(jnp.sum(a) for a in auxes) if c.n_experts else 0.0)
 
 
 @jax.named_scope("final_norm")
@@ -1624,7 +1733,8 @@ def hidden_states(config: TransformerConfig, params: Dict,
     c = config
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], input_ids, axis=0).astype(c.dtype)
-    x, moe_aux = run_layers(c, params["layers"], x, mesh=mesh, rules=rules)
+    x, moe_aux = run_layers(c, layer_stacks(c, params), x, mesh=mesh,
+                            rules=rules)
     return _final_norm(c, params, x), moe_aux
 
 
@@ -1730,7 +1840,11 @@ def lm_loss(config: TransformerConfig, params: Dict, batch: Dict,
             loss, n = cross_entropy_loss(logits[:, :-1], labels,
                                          mask=mask)
     aux = {"n_tokens": n}
-    if c.n_experts:
+    if c.experts_per_token:
+        # counters, no term of the loss: the dropless experts' published
+        # configurations give no coefficient for one
+        aux["moe"] = moe_aux
+    elif c.n_experts:
         loss = loss + c.moe_aux_weight * moe_aux
         aux["moe_aux"] = moe_aux
     return loss, aux
@@ -1770,6 +1884,11 @@ def stage_slice_params(config: TransformerConfig, params: Dict,
         raise NotImplementedError(
             "pipeline stage splitting does not support MoE configs "
             "(the aux loss would need cross-stage wiring)")
+    if _tree_form(config) != "plain":
+        raise NotImplementedError(
+            "pipeline stage splitting takes one stack of layers, not "
+            "stacks by kind of layer (layer_pattern, sliding_window, "
+            "rope_yarn)")
     lo, hi = stage_layer_ranges(config.n_layers, n_stages)[stage]
     out: Dict = {"layers": jax.tree.map(lambda a: a[lo:hi],
                                         params["layers"])}

@@ -93,8 +93,11 @@ def _resolve(op: str, impl: str, kernel_name: str,
 def attention_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = False,
                         sm_scale: Optional[float] = None,
-                        mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Plain masked-softmax attention in f32, layout (B, S, H, D)."""
+                        mask: Optional[jnp.ndarray] = None,
+                        window: int = 0) -> jnp.ndarray:
+    """Plain masked-softmax attention in f32, layout (B, S, H, D).
+    ``window > 0`` (with ``causal``): a query attends the ``window`` keys
+    up to and including its own position."""
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
@@ -103,6 +106,9 @@ def attention_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if causal:
         sq, sk = q.shape[1], k.shape[1]
         causal_mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window:
+            causal_mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool),
+                                     k=sk - sq - window)
         s = jnp.where(causal_mask[None, None], s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_INF)
@@ -250,8 +256,13 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         mask: Optional[jnp.ndarray] = None,
                         impl: str = "auto",
                         block_q: Optional[int] = None,
-                        block_k: Optional[int] = None) -> jnp.ndarray:
+                        block_k: Optional[int] = None,
+                        window: int = 0) -> jnp.ndarray:
     """Attention over (batch, seq, heads, head_dim).
+
+    ``window > 0`` (causal calls): a sliding-window layer, a query
+    attends the ``window`` keys up to and including its own position,
+    under every ``impl`` (the kernel: ``flash_attention(window=...)``).
 
     ``impl``: "auto" | "flash" | "interpret" | "reference" (module
     docstring). An arbitrary ``mask`` needs the reference path (the
@@ -272,7 +283,7 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     choice = _resolve("flash", impl, "flash", unfit)
     if choice == "reference":
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   mask=mask)
+                                   mask=mask, window=window)
     if cannot:
         raise ValueError(f"flash attention kernel forced on a call it "
                          f"cannot compute: {cannot}")
@@ -281,5 +292,5 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     vt = jnp.swapaxes(v, 1, 2)
     o = flash_attention(qt, kt, vt, causal=causal, sm_scale=sm_scale,
                         block_q=block_q, block_k=block_k,
-                        interpret=choice == "interpret")
+                        interpret=choice == "interpret", window=window)
     return jnp.swapaxes(o, 1, 2)

@@ -55,26 +55,93 @@ class _Cfg:
     block_k: int
     interpret: bool
     bwd: str = "pallas"   # "pallas" | "xla"
+    # > 0: a sliding-window layer, query i attends the ``window`` keys up
+    # to and including its own (``i - window < key <= i``); 0: every key
+    # the causal structure leaves. The windowed calls carry names of
+    # their own (``flash_window_*``) and a narrower innermost grid axis.
+    window: int = 0
+
+
+def _name(cfg: _Cfg, stem: str) -> str:
+    """``flash_fwd`` or, windowed, ``flash_window_fwd``: what reads a
+    trace counts each by its own rule."""
+    return f"flash_window_{stem}" if cfg.window else f"flash_{stem}"
+
+
+# The windowed kernels' innermost grid axis walks only the blocks that
+# can hold a live (query, key) pair: a q block's k blocks start at the one
+# that holds the first row's oldest key, a k block's q blocks at the one
+# that holds the first key's own row. A block the walk reaches past the
+# sequence's end, ahead of the diagonal or behind the window is skipped
+# (``_window_run``) and its index clamped, so nothing new is fetched.
+
+def _k_first(cfg: _Cfg, ib, offset: int):
+    """The first k block a q block's rows see under the window."""
+    return jnp.maximum(ib * cfg.block_q + offset - cfg.window + 1, 0) \
+        // cfg.block_k
+
+
+def _q_first(cfg: _Cfg, kb, offset: int):
+    """The first q block whose rows see a k block under causality."""
+    return jnp.maximum(kb * cfg.block_k - offset, 0) // cfg.block_q
+
+
+def _k_steps(cfg: _Cfg, nk: int) -> int:
+    """k blocks a q block walks: its rows' keys span ``block_q + window
+    - 1`` positions, which touch one block more than they fill."""
+    return min(nk, -(-(cfg.block_q + cfg.window - 1) // cfg.block_k) + 1)
+
+
+def _q_steps(cfg: _Cfg, nq: int) -> int:
+    return min(nq, -(-(cfg.block_k + cfg.window - 1) // cfg.block_q) + 1)
+
+
+def _window_run(cfg: _Cfg, ib, kb, n_blocks_k, offset: int):
+    """Whether the (q block ``ib``, k block ``kb``) pair holds a live
+    pair: not ahead of the diagonal, not wholly behind the window, inside
+    the sequence."""
+    bq, bk = cfg.block_q, cfg.block_k
+    return (kb * bk <= ib * bq + (bq - 1) + offset) \
+        & (kb * bk + (bk - 1) > ib * bq + offset - cfg.window) \
+        & (kb < n_blocks_k)
+
+
+def _window_mask(cfg: _Cfg, s, ib, kb, offset: int):
+    """The causal and window mask inside a block."""
+    bq, bk = cfg.block_q, cfg.block_k
+    rows = ib * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    keep = (cols <= rows + offset) & (cols > rows + offset - cfg.window)
+    return jnp.where(keep, s, _NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_s, l_s, acc_s, *, cfg: _Cfg, offset: int):
+                m_s, l_s, acc_s, *, cfg: _Cfg, offset: int, nk_all: int = 0):
     """``offset = sk - sq``: causality is end-aligned (query i attends keys
     0..i+offset), matching ``attention_reference``'s ``tril(k=sk-sq)`` for
-    decode-style sq < sk calls."""
+    decode-style sq < sk calls. Windowed, the innermost axis counts from
+    the q block's first k block (``_k_first``) and ``nk_all`` is the
+    sequence's count of k blocks."""
     ib = pl.program_id(2)          # q block index
     kb = pl.program_id(3)          # k block index (innermost)
     nk = pl.num_programs(3)
     bq, bk = cfg.block_q, cfg.block_k
+    step = kb                      # the innermost axis's own count
+    if cfg.window:
+        kb = _k_first(cfg, ib, offset) + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
     # Under causality, blocks strictly above the diagonal contribute nothing.
-    run = (kb * bk <= ib * bq + (bq - 1) + offset) if cfg.causal else True
+    if cfg.window:
+        run = _window_run(cfg, ib, kb, nk_all, offset)
+    else:
+        run = (kb * bk <= ib * bq + (bq - 1) + offset) if cfg.causal \
+            else True
 
     @pl.when(run)
     def _compute():
@@ -85,7 +152,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bq, bk)
         s = s * cfg.sm_scale
-        if cfg.causal:
+        if cfg.window:
+            s = _window_mask(cfg, s, ib, kb, offset)
+        elif cfg.causal:
             rows = ib * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             cols = kb * bk + jax.lax.broadcasted_iota(
@@ -105,7 +174,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)          # (bq, d)
         acc_s[...] = acc_s[...] * alpha[:, 0:1] + pv
 
-    @pl.when(kb == nk - 1)
+    @pl.when(step == nk - 1)
     def _final():
         l = l_s[:, 0:1]
         # Fully-masked rows (can't happen with causal self-attn) guard:
@@ -131,15 +200,22 @@ def _fwd_pallas(cfg: _Cfg, q, k, v) -> Tuple[jnp.ndarray, jnp.ndarray]:
     cfg = dataclasses.replace(cfg, block_q=bq, block_k=bk)
     nq, nk = sq // bq, sk // bk
     grid = (b, h, nq, nk)
+    offset = sk - sq
 
-    kernel = functools.partial(_fwd_kernel, cfg=cfg, offset=sk - sq)
+    kernel = functools.partial(_fwd_kernel, cfg=cfg, offset=offset)
+    kv_block = lambda b_, h_, i, j: (b_, h_, j, 0)      # noqa: E731
+    if cfg.window:
+        grid = (b, h, nq, _k_steps(cfg, nk))
+        kernel = functools.partial(kernel, nk_all=nk)
+        kv_block = lambda b_, h_, i, j: (                # noqa: E731
+            b_, h_, jnp.minimum(_k_first(cfg, i, offset) + j, nk - 1), 0)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, bk, d), kv_block),
+            pl.BlockSpec((1, 1, bk, d), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
@@ -156,7 +232,7 @@ def _fwd_pallas(cfg: _Cfg, q, k, v) -> Tuple[jnp.ndarray, jnp.ndarray]:
         ],
         compiler_params=_compiler_params(cfg, 4),
         interpret=cfg.interpret,
-        name="flash_fwd",
+        name=_name(cfg, "fwd"),
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -197,26 +273,36 @@ def _delta_pallas(cfg: _Cfg, o, do):
         out_shape=jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         compiler_params=_compiler_params(cfg, 3),
         interpret=cfg.interpret,
-        name="flash_bwd_delta",
+        name=_name(cfg, "bwd_delta"),
     )(o, do)
 
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_s, dv_s, *, cfg: _Cfg, offset: int):
+                 dk_ref, dv_ref, dk_s, dv_s, *, cfg: _Cfg, offset: int,
+                 nq_all: int = 0, nk_all: int = 0):
     """Grid (b, h, k_blocks, q_blocks), q innermost: dk/dv accumulators
     persist in VMEM across the q scan; P is recomputed from the saved
-    LSE (the flash-attention backward recipe, Dao et al. Alg. 4)."""
+    LSE (the flash-attention backward recipe, Dao et al. Alg. 4).
+    Windowed, the innermost axis counts from the k block's first q
+    block (``_q_first``)."""
     kb = pl.program_id(2)
     ib = pl.program_id(3)
     nq = pl.num_programs(3)
     bq, bk = cfg.block_q, cfg.block_k
+    step = ib
+    if cfg.window:
+        ib = _q_first(cfg, kb, offset) + step
 
-    @pl.when(ib == 0)
+    @pl.when(step == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    run = (kb * bk <= ib * bq + (bq - 1) + offset) if cfg.causal else True
+    if cfg.window:
+        run = _window_run(cfg, ib, kb, nk_all, offset) & (ib < nq_all)
+    else:
+        run = (kb * bk <= ib * bq + (bq - 1) + offset) if cfg.causal \
+            else True
 
     @pl.when(run)
     def _compute():
@@ -229,7 +315,9 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * cfg.sm_scale
-        if cfg.causal:
+        if cfg.window:
+            s = _window_mask(cfg, s, ib, kb, offset)
+        elif cfg.causal:
             rows = ib * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             cols = kb * bk + jax.lax.broadcasted_iota(
@@ -249,26 +337,34 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bk, d)
 
-    @pl.when(ib == nq - 1)
+    @pl.when(step == nq - 1)
     def _final():
         dk_ref[0, 0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_s, *, cfg: _Cfg, offset: int):
+               dq_ref, dq_s, *, cfg: _Cfg, offset: int, nk_all: int = 0):
     """Grid (b, h, q_blocks, k_blocks), k innermost: dq accumulates in
-    VMEM across the k scan."""
+    VMEM across the k scan (windowed: from ``_k_first``, as the
+    forward)."""
     ib = pl.program_id(2)
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
     bq, bk = cfg.block_q, cfg.block_k
+    step = kb
+    if cfg.window:
+        kb = _k_first(cfg, ib, offset) + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    run = (kb * bk <= ib * bq + (bq - 1) + offset) if cfg.causal else True
+    if cfg.window:
+        run = _window_run(cfg, ib, kb, nk_all, offset)
+    else:
+        run = (kb * bk <= ib * bq + (bq - 1) + offset) if cfg.causal \
+            else True
 
     @pl.when(run)
     def _compute():
@@ -281,7 +377,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * cfg.sm_scale
-        if cfg.causal:
+        if cfg.window:
+            s = _window_mask(cfg, s, ib, kb, offset)
+        elif cfg.causal:
             rows = ib * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             cols = kb * bk + jax.lax.broadcasted_iota(
@@ -296,7 +394,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bq, d)
 
-    @pl.when(kb == nk - 1)
+    @pl.when(step == nk - 1)
     def _final():
         dq_ref[0, 0] = dq_s[...].astype(dq_ref.dtype)
 
@@ -313,16 +411,35 @@ def _bwd_pallas(cfg: _Cfg, q, k, v, o, lse, do):
     lse4 = lse[:, :, None, :]                             # (b,h,1,sq)
 
     compiler_params = _compiler_params(cfg, 4)
+    dkdv_kernel = functools.partial(_dkdv_kernel, cfg=cfg, offset=offset)
+    dq_kernel = functools.partial(_dq_kernel, cfg=cfg, offset=offset)
+    dkdv_grid, dq_grid = (b, h, nk, nq), (b, h, nq, nk)
+    # the q block a dkdv step reads, the k block a dq step reads
+    q_of = lambda j, i: i                                # noqa: E731
+    k_of = lambda i, j: j                                # noqa: E731
+    if cfg.window:
+        dkdv_kernel = functools.partial(dkdv_kernel, nq_all=nq, nk_all=nk)
+        dq_kernel = functools.partial(dq_kernel, nk_all=nk)
+        dkdv_grid = (b, h, nk, _q_steps(cfg, nq))
+        dq_grid = (b, h, nq, _k_steps(cfg, nk))
+        q_of = lambda j, i: jnp.minimum(                 # noqa: E731
+            _q_first(cfg, j, offset) + i, nq - 1)
+        k_of = lambda i, j: jnp.minimum(                 # noqa: E731
+            _k_first(cfg, i, offset) + j, nk - 1)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, cfg=cfg, offset=offset),
-        grid=(b, h, nk, nq),
+        dkdv_kernel,
+        grid=dkdv_grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bq, d),
+                         lambda b_, h_, j, i: (b_, h_, q_of(j, i), 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b_, h_, j, i: (b_, h_, 0, i)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b_, h_, j, i: (b_, h_, 0, i)),
+            pl.BlockSpec((1, 1, bq, d),
+                         lambda b_, h_, j, i: (b_, h_, q_of(j, i), 0)),
+            pl.BlockSpec((1, 1, 1, bq),
+                         lambda b_, h_, j, i: (b_, h_, 0, q_of(j, i))),
+            pl.BlockSpec((1, 1, 1, bq),
+                         lambda b_, h_, j, i: (b_, h_, 0, q_of(j, i))),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
@@ -338,16 +455,18 @@ def _bwd_pallas(cfg: _Cfg, q, k, v, o, lse, do):
         ],
         compiler_params=compiler_params,
         interpret=cfg.interpret,
-        name="flash_bwd_dkdv",
+        name=_name(cfg, "bwd_dkdv"),
     )(q, k, v, do, lse4, delta)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, cfg=cfg, offset=offset),
-        grid=(b, h, nq, nk),
+        dq_kernel,
+        grid=dq_grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h_, i, j: (b_, h_, k_of(i, j), 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h_, i, j: (b_, h_, k_of(i, j), 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b_, h_, i, j: (b_, h_, 0, i)),
             pl.BlockSpec((1, 1, 1, bq), lambda b_, h_, i, j: (b_, h_, 0, i)),
@@ -358,7 +477,7 @@ def _bwd_pallas(cfg: _Cfg, q, k, v, o, lse, do):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=compiler_params,
         interpret=cfg.interpret,
-        name="flash_bwd_dq",
+        name=_name(cfg, "bwd_dq"),
     )(q, k, v, do, lse4, delta)
     return dq, dk, dv
 
@@ -389,7 +508,10 @@ def _flash_bwd(cfg: _Cfg, res, do):
         s = jnp.einsum("bhqd,bhkd->bhqk", q32, kb_) * scale
         if cfg.causal:
             cols = j * bk + jnp.arange(bk)[None, :]
-            s = jnp.where(cols <= rows, s, _NEG_INF)
+            keep = cols <= rows
+            if cfg.window:
+                keep &= cols > rows - cfg.window
+            s = jnp.where(keep, s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])                        # (b,h,sq,bk)
         dv = jnp.einsum("bhqk,bhqd->bhkd", p, do32)
         dp = jnp.einsum("bhqd,bhkd->bhqk", do32, vb_)
@@ -651,8 +773,16 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
-                    backward: str = "pallas") -> jnp.ndarray:
+                    backward: str = "pallas",
+                    window: int = 0) -> jnp.ndarray:
     """Flash attention over (batch, heads, seq, head_dim) arrays.
+
+    ``window > 0`` (causal calls only): a sliding-window layer, a query
+    attends the ``window`` keys up to and including its own position.
+    The calls are then named ``flash_window_fwd`` / ``_bwd_dkdv`` /
+    ``_bwd_dq`` / ``_bwd_delta`` and their innermost grid axis walks only
+    the blocks the window reaches; ``window=0`` is the kernels as they
+    were, name and instructions.
 
     Requires seq divisible by the (clamped) block sizes; ``block_q`` /
     ``block_k`` left as ``None`` (or 0) pick chip-aware defaults
@@ -668,6 +798,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if backward not in ("pallas", "xla"):
         raise ValueError(f"backward must be 'pallas' or 'xla', "
                          f"got {backward!r}")
+    if window < 0 or window and not causal:
+        raise ValueError(f"window {window}: a sliding window is a form "
+                         f"of causal attention, 0 or more keys wide")
     if not block_q or not block_k:
         dq_, dk_ = default_flash_blocks(q.shape[2], k.shape[2], d,
                                         chip="cpu" if interpret else None)
@@ -675,5 +808,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         block_k = block_k or dk_
     cfg = _Cfg(causal=causal, sm_scale=float(sm_scale),
                block_q=block_q, block_k=block_k, interpret=interpret,
-               bwd=backward)
+               bwd=backward, window=int(window))
     return _flash(cfg, q, k, v)

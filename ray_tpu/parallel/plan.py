@@ -166,6 +166,12 @@ class ParallelPlan:
                 f"{self.stage_world}")
 
     def validate_config(self, config) -> None:
+        if self.pp > 1 and (config.layer_pattern or config.sliding_window
+                            or config.rope_yarn):
+            raise NotImplementedError(
+                "pp > 1 splits one stack of layers; a stack by kind of "
+                "layer (layer_pattern, sliding_window, rope_yarn) trains "
+                "under pp = 1")
         if self.pp > 1 and self.pp * self.virtual > config.n_layers:
             raise ValueError(
                 f"pp*virtual = {self.pp * self.virtual} chunks need at "

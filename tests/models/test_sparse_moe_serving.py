@@ -183,7 +183,9 @@ def test_counts_and_the_paths_that_refuse():
     cache = init_kv_cache(cfg, 4, BS)
     assert cache["ki"].shape == (2, 4, 1, BS, 8)
     from ray_tpu.models.transformer import run_layers
-    with pytest.raises(NotImplementedError, match="served"):
+    # (PR 62) experts_per_token trains; Keye's QK-norm and key selection
+    # do not, and are what the refusal names
+    with pytest.raises(NotImplementedError, match="qk_norm, index_topk"):
         run_layers(cfg, params["layers"], jnp.zeros((1, 8, 64)))
     switch = TransformerConfig(**dict(TINY, n_experts=4))
     with pytest.raises(NotImplementedError, match="dropless"):

@@ -184,16 +184,32 @@ def test_counts_and_refusals():
     assert jax.tree.structure(params) == jax.tree.structure(
         logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
     assert cfg.served_only
-    with pytest.raises(NotImplementedError, match="layer_pattern") as e:
+    # (PR 62) window and full layers, YaRN and softmax-routed dropless
+    # experts train now: the refusal names the keys STILL at fault, ahead
+    # of its colon, and no key that trains
+    with pytest.raises(NotImplementedError, match="n_dense_layers") as e:
         apply(cfg, params, jnp.zeros((1, 8), jnp.int32))
+    at_fault = str(e.value).split(":")[0]
     assert "served through" in str(e.value) \
-        and "sliding_window" in str(e.value)
+        and "window_heads" in at_fault and "shared_expert_width" in at_fault
+    assert not any(k in at_fault for k in ("layer_pattern", "rope_yarn",
+                                           "sliding_window"))
     from ray_tpu.parallel.plan import ParallelPlan
     with pytest.raises(NotImplementedError, match="head_gate"):
         ParallelPlan().build(cfg)
     from ray_tpu.models import make_train_step
-    with pytest.raises(NotImplementedError, match="rope_yarn"):
+    with pytest.raises(NotImplementedError, match="router_score"):
         make_train_step(cfg, mesh=None)
+    # a configuration with the keys that train and no other is let through
+    from ray_tpu.models.transformer import refuse_training, untrained_keys
+    trains = TransformerConfig(**{
+        k: v for k, v in LAGUNA.items() if k not in (
+            "head_gate", "window_heads", "n_dense_layers",
+            "shared_expert_width", "router_score", "routed_scale")})
+    assert set(trains.served_keys) == {"experts_per_token", "layer_pattern",
+                                       "sliding_window", "rope_yarn"}
+    assert untrained_keys(trains) == ()
+    refuse_training(trains)
     for bad in (dict(sliding_window=0), dict(layer_pattern=["full"]),
                 dict(layer_pattern=["full", "local"]),
                 dict(n_dense_layers=2), dict(qk_norm=True),
