@@ -193,6 +193,12 @@ class RuntimeMetrics:
             "train_moe_held_assignments",
             "Assignments that landed on experts held here, most recent "
             "step, summed over the expert layers")
+        self.train_moe_grouped_products = Gauge(
+            "train_moe_grouped_products",
+            "The experts' differentiated grouped products in the step "
+            "program as traced, by form: pallas_gmm (the Pallas grouped "
+            "matmul) or xla_ragged_dot (the shape rule's fallback, or "
+            "off a TPU)", tag_keys=("form",))
         # -- MPMD pipeline (parallel/mpmd_pipeline.py)
         self.pipeline_mailbox_depth = Gauge(
             "pipeline_stage_mailbox_depth",

@@ -23,6 +23,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox.ops import backend as _megablox
+
+from ray_tpu.ops.attention import _resolve, dispatch_log
 
 
 def moe_mlp(c, lp, h):
@@ -263,18 +266,133 @@ _unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 # goes out in float32 to a float32 master, with no rounding between. A
 # row of no group is whatever the product left there, in dX as in the
 # forward: zeroed here, because the sort's transpose adds a token's rows.
+#
+# Only a differentiated call reaches the two rules, and in a program that
+# runs whole on one TPU chip (``one_chip``: the caller's mesh is of one
+# device; XLA cannot partition a Pallas custom call, and a grouped
+# product split over chips wants a ``shard_map`` of its own) they run
+# the Pallas grouped matmul that ships with JAX (megablox ``gmm`` /
+# ``gmm(transpose_rhs=True)`` / ``tgmm``: the same operands, the same
+# float32 accumulation, two to three times ``ragged_dot``'s speed at a
+# training turn's 32,768 rows of 16 experts). The primal, which every
+# served program and any undifferentiated forward runs, is ``ragged_dot``
+# to the instruction. The kernels are megablox's own jitted entry points
+# and their tiling one cached tuple a shape, so equal calls (gate and up,
+# a turn's recomputation, the scans of a stack by kind of layer, a second
+# program of the same turn) are traced once a process and lowered once a
+# program.
 
-@jax.custom_vjp
-def _grouped_dot(x, w, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_dot(x, w, sizes, one_chip=False):
     """Rows of group i of ``x [M, K]`` times ``w[i]`` (``w [G, K, N]``,
     cast to ``x``'s dtype), ``[M, N]`` float32; the rows past
-    ``sum(sizes)`` belong to no group."""
+    ``sum(sizes)`` belong to no group. ``one_chip``: the program runs
+    whole on one chip, so its differentiated form may be a kernel."""
     return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
                               preferred_element_type=jnp.float32)
 
 
-def _grouped_dot_fwd(x, w, sizes):
-    return _grouped_dot(x, w, sizes), (x, w, sizes)
+#: the kernels' row tile: 512 read best at a turn's rows on a v5e (256 a
+#: fifth slower, 1024 past the kernel's VMEM), and a call whose rows it
+#: does not divide stays ``ragged_dot``
+_ROW_TILE = 512
+#: what one grid step's blocks may hold, by :func:`_block_bytes`' count:
+#: the v5e compiler gives a kernel 16 MiB of VMEM and the count errs
+#: high: every tiling the rule can pick under it compiles (each one was;
+#: tests/ops/test_tpu_lowering.py compiles the largest of each kernel),
+#: the smallest that does not counts 19.4 MiB
+_VMEM_BUDGET = 16 * 2 ** 20
+
+
+def grouped_product_counts():
+    """The differentiated grouped products this process has traced, by
+    form: ``{"pallas_gmm": n, "xla_ragged_dot": m}`` (a product in a
+    scan's body counts once; the second is the shape rule's fallback, a
+    program over several chips, or every product off a TPU). Op
+    ``"grouped_dot"`` of ``ops.attention.dispatch_log``, which has the
+    reasons."""
+    counts = {"pallas_gmm": 0, "xla_ragged_dot": 0}
+    for entry in dispatch_log():
+        if entry["op"] == "grouped_dot":
+            counts["pallas_gmm" if entry["impl"] == "kernel"
+                   else "xla_ragged_dot"] += entry["count"]
+    return counts
+
+
+def _block_bytes(tm, tk, tn, size, out_size, dw):
+    """A grid step's VMEM by the blocks' shapes: the two operand blocks
+    and the result's, each twice (the pipeline's two buffers), the
+    float32 accumulator, and what the kernel's body holds in float32
+    beside them (``gmm``: the accumulator read back and the result block
+    it is selected into; ``tgmm``: both operand blocks, masked)."""
+    if dw:      # [tm, tk]^T [tm, tn] -> [tk, tn]
+        return (2 * size * tm * (tk + tn) + (2 * out_size + 4) * tk * tn
+                + 4 * tm * (tk + tn))
+    return (2 * size * (tm * tk + tk * tn)       # [tm, tk] [tk, tn]
+            + (2 * out_size + 4 + 8) * tm * tn)
+
+
+def _tile(width, most):
+    """The largest multiple of 128 that divides ``width`` and is at most
+    ``most``, or None."""
+    return next((t for t in range(most - most % 128, 0, -128)
+                 if not width % t), None)
+
+
+@functools.lru_cache(maxsize=None)
+def _gmm_tiles(rows, k, n, dtype):
+    """The tilings ``(tm, tk, tn)`` of the three kernels of ``x [rows, k]
+    @ w[i] [k, n]`` in ``dtype`` (forward, dX, dW), a function of the
+    shapes alone, or None where the kernels cannot tile them (rows no
+    multiple of the row tile, a width no multiple of 128, a dtype that
+    is not theirs, no blocks inside the VMEM budget): the product then
+    stays ``ragged_dot``. Wide blocks first (fewer passes over an
+    operand), the wider of the two narrowed until the blocks fit."""
+    if rows % _ROW_TILE or k % 128 or n % 128 \
+            or dtype not in ("bfloat16", "float32"):
+        return None
+    size = jnp.dtype(dtype).itemsize
+
+    def fit(k, n, out_size, dw):
+        most_k = most_n = 1024
+        while True:
+            tk, tn = _tile(k, most_k), _tile(n, most_n)
+            if tk is None or tn is None:
+                return None
+            if _block_bytes(_ROW_TILE, tk, tn, size, out_size,
+                            dw) <= _VMEM_BUDGET:
+                return _ROW_TILE, tk, tn
+            if tk >= tn:
+                most_k = tk - 128
+            else:
+                most_n = tn - 128
+    tiles = (fit(k, n, 4, False), fit(n, k, size, False), fit(k, n, 4, True))
+    return None if None in tiles else tiles
+
+
+def _kernel_tiles(x, w, one_chip, record=False):
+    """:func:`_gmm_tiles` of the call in a program whole on one TPU
+    chip, where the kernels run; None elsewhere. ``record``: the choice
+    goes to ``dispatch_log`` (once a product: the forward rule's)."""
+    tiles = _gmm_tiles(x.shape[0], x.shape[1], w.shape[2],
+                       jnp.dtype(x.dtype).name)
+    unfit = None
+    if not one_chip:
+        unfit = "the program is not whole on one chip"
+    elif tiles is None:
+        unfit = f"no tiling of {x.dtype}{list(x.shape)} x {list(w.shape)}"
+    choice = _resolve("grouped_dot", "auto", "kernel", unfit, record)
+    return tiles if choice == "kernel" else None
+
+
+def _grouped_dot_fwd(x, w, sizes, one_chip):
+    tiles = _kernel_tiles(x, w, one_chip, record=True)
+    if tiles is None:
+        y = _grouped_dot(x, w, sizes)
+    else:
+        y = _megablox.gmm(x, w.astype(x.dtype), sizes, jnp.float32,
+                          tiles[0])
+    return y, (x, w, sizes)
 
 
 _DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
@@ -282,35 +400,41 @@ _DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _grouped_dot_bwd(res, g):
+def _grouped_dot_bwd(one_chip, res, g):
     x, w, sizes = res
     g = g.astype(x.dtype)
-    dx = jax.lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2),
-                            sizes, preferred_element_type=x.dtype)
+    tiles = _kernel_tiles(x, w, one_chip)
+    if tiles is None:
+        dx = jax.lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2),
+                                sizes, preferred_element_type=x.dtype)
+        dw = jax.lax.ragged_dot_general(
+            x, g, sizes, _DW_DIMS, preferred_element_type=jnp.float32)
+    else:
+        dx = _megablox.gmm(g, w.astype(x.dtype), sizes, x.dtype, tiles[1],
+                           transpose_rhs=True)
+        dw = _megablox.tgmm(x.T, g, sizes, jnp.float32, tiles[2])
     in_a_group = jnp.arange(x.shape[0], dtype=jnp.int32) < jnp.sum(sizes)
     dx = jnp.where(in_a_group[:, None], dx, jnp.zeros((), dx.dtype))
-    dw = jax.lax.ragged_dot_general(
-        x, g, sizes, _DW_DIMS, preferred_element_type=jnp.float32)
     return dx, dw.astype(w.dtype), None
 
 
 _grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
-def _expert_rows(leaves, xs, sizes):
+def _expert_rows(leaves, xs, sizes, one_chip):
     """The experts on rows sorted by expert: ``xs [M, width]`` in the
     compute dtype, group i's ``sizes[i]`` rows through expert i's
     matrices (``leaves``, as stored) -> ``[M, width]`` float32; the rows
     past ``sum(sizes)`` are of no group."""
-    gate = _grouped_dot(xs, leaves["we_gate"], sizes) \
+    gate = _grouped_dot(xs, leaves["we_gate"], sizes, one_chip) \
         if "we_gate" in leaves else None
-    up = _grouped_dot(xs, leaves["we_up"], sizes)
+    up = _grouped_dot(xs, leaves["we_up"], sizes, one_chip)
     mid = _expert_mid(gate, up).astype(xs.dtype)
-    return _grouped_dot(mid, leaves["we_down"], sizes)
+    return _grouped_dot(mid, leaves["we_down"], sizes, one_chip)
 
 
 @jax.named_scope("moe_experts")
-def _routed_experts(c, lp, x, weights, experts, layer):
+def _routed_experts(c, lp, x, weights, experts, layer, one_chip):
     """The held experts' part of the weighted sum, ``[N, width of x]``
     float32: sort the assignments by expert, the grouped products over
     the sorted rows, unsort, combine. ``x [N, D]`` (``[N, R]`` in a
@@ -343,7 +467,7 @@ def _routed_experts(c, lp, x, weights, experts, layer):
     if N * k < _FEW_ROWS and groups > 128 and groups % 512:
         # rows of no group behind the others (see _FEW_ROWS)
         xs = jnp.pad(xs, ((0, _FEW_ROWS - N * k), (0, 0)))
-    ys = _expert_rows(leaves, xs, sizes)
+    ys = _expert_rows(leaves, xs, sizes, one_chip)
     ys = _unsort_rows(ys, order, inverse).reshape(N, k, width)  # and unpad
     if here is not None:
         # a row of no group is whatever the product left there: chosen
@@ -358,7 +482,7 @@ def _routed_experts(c, lp, x, weights, experts, layer):
 _MANY_TOKENS = 4096
 
 
-def _routed_experts_in_turns(c, lp, x, weights, experts, layer):
+def _routed_experts_in_turns(c, lp, x, weights, experts, layer, one_chip):
     """:func:`_routed_experts` over ``_MANY_TOKENS`` tokens at a time,
     each turn recomputed in the backward pass. What lies between the sort
     and the weighted sum is k rows a token, model-wide, several times
@@ -370,7 +494,7 @@ def _routed_experts_in_turns(c, lp, x, weights, experts, layer):
 
     @jax.checkpoint
     def turn(args):
-        return _routed_experts(c, lp, *args, layer)
+        return _routed_experts(c, lp, *args, layer, one_chip)
     ys = jax.lax.map(turn, tuple(
         a.reshape((turns, _MANY_TOKENS) + a.shape[1:])
         for a in (x, weights, experts)))
@@ -410,7 +534,7 @@ def sum_route_stats(per_run):
 
 
 @jax.named_scope("moe")
-def topk_moe_mlp(c, lp, h, layer=None, stats=False):
+def topk_moe_mlp(c, lp, h, layer=None, stats=False, mesh=None):
     """Dropless top-k expert MLP. ``h [B, S, D]`` (compute dtype) ->
     ``[B, S, D]``. ``lp`` carries ``w_router [D, E]`` and the held
     experts ``we_gate / we_up [E_held, D, F]``, ``we_down [E_held, F,
@@ -434,7 +558,10 @@ def topk_moe_mlp(c, lp, h, layer=None, stats=False):
     experts' and not the whole stack's.)
 
     ``stats``: returns ``(out, route_stats(...))``, the layer's routing
-    counters beside it (training's; the served programs ask for none)."""
+    counters beside it (training's; the served programs ask for none).
+    ``mesh``: the mesh the calling program is partitioned over, where the
+    caller has one; of one device, the differentiated grouped products
+    may be Pallas kernels (``_grouped_dot``)."""
     dt = c.dtype
     B, S, D = h.shape
     x = h.reshape(B * S, D).astype(dt)
@@ -445,10 +572,12 @@ def topk_moe_mlp(c, lp, h, layer=None, stats=False):
     if c.moe_latent:
         with jax.named_scope("moe_latent_down"):
             xin = jnp.dot(x, lp["w_lat_down"].astype(dt))
+    one_chip = mesh is not None and mesh.size == 1
     if x.shape[0] > _MANY_TOKENS and not x.shape[0] % _MANY_TOKENS:
-        y = _routed_experts_in_turns(c, lp, xin, weights, experts, layer)
+        y = _routed_experts_in_turns(c, lp, xin, weights, experts, layer,
+                                     one_chip)
     else:
-        y = _routed_experts(c, lp, xin, weights, experts, layer)
+        y = _routed_experts(c, lp, xin, weights, experts, layer, one_chip)
     if c.moe_latent:
         with jax.named_scope("moe_latent_up"):
             y = jnp.dot(y.astype(dt), lp["w_lat_up"].astype(dt),

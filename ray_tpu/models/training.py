@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_tpu.models.moe import grouped_product_counts
 from ray_tpu.models.transformer import (
     TransformerConfig, head_loss_form, init_params, logical_axes,
     lm_loss, refuse_training)
@@ -53,6 +54,12 @@ class TrainStepBundle:
     #: chunks the fused loss scans a step of ``config.max_seq_len`` tokens
     #: a sequence (0 where the logits are materialized)
     loss_chunks: int = 0
+    #: the dropless experts' differentiated grouped products in ``step_fn``
+    #: by form, ``{"pallas_gmm": n, "xla_ragged_dot": m}`` (a product in
+    #: a scan's body counts once; ``moe.grouped_product_counts``), filled
+    #: in when the step program is traced: empty before the first step
+    grouped_products: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
     #: live-telemetry cadence (see :meth:`_telemetry`); <= 0 disables
     telemetry_interval_s: float = 0.5
     _tel_last: float = dataclasses.field(default=0.0, repr=False)
@@ -111,6 +118,9 @@ class TrainStepBundle:
                     float(metrics["moe_load_max_over_mean"]))
                 m.train_moe_held_assignments.set(
                     float(metrics["moe_held_assignments"]))
+                for form, n in self.grouped_products.items():
+                    m.train_moe_grouped_products.set(
+                        float(n), tags={"form": form})
             try:
                 from ray_tpu.parallel.mesh import chip_spec
                 achieved = tokens_per_s * \
@@ -293,11 +303,18 @@ def make_train_step(config: TransformerConfig, mesh,
                                   quant_stochastic, key))
         return jax.tree.unflatten(treedef, out)
 
+    grouped_products: Dict[str, int] = {}
+
     def step_raw(state, batch):
         def loss_fn(p):
             return lm_loss(config, p, batch, mesh=mesh, rules=rules)
+        traced = grouped_product_counts()
         (loss, aux), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"])
+        # this runs as the program is traced: what the trace added
+        grouped_products.update(
+            {form: n - traced[form]
+             for form, n in grouped_product_counts().items()})
         if grad_transport == "int8":
             with jax.named_scope("grad_transport"):
                 grads = _quantize_grads(grads, state["step"])
@@ -349,6 +366,7 @@ def make_train_step(config: TransformerConfig, mesh,
                            loss_chunks=0 if loss_form == "logits" else
                            n_chunks(config.max_seq_len - 1,
                                     config.ce_chunk_size),
+                           grouped_products=grouped_products,
                            telemetry_interval_s=telemetry_interval_s)
 
 

@@ -1523,21 +1523,21 @@ def _swiglu(c, h, lp):
 
 
 @jax.named_scope("mlp")
-def _mlp_sublayer(c, h, lp, layer=None, stats=False):
+def _mlp_sublayer(c, h, lp, layer=None, stats=False, mesh=None):
     """The MLP the layer's leaves ``lp`` hold, on normed input h: a dense
     SwiGLU (``w_gate``: the 'llama' block, and a leading dense layer
     ahead of experts), experts, or the 'gptj' block's biased GELU MLP;
     returns (out, moe_aux). ``layer``: ``lp``'s expert leaves are whole
     stacks and this is the layer's index in them (``moe.topk_moe_mlp``).
-    ``stats`` (training): the dropless experts' ``moe_aux`` is the
-    layer's routing counters (``moe.route_stats``), no term of a loss."""
+    ``stats`` (training): the dropless experts' ``moe_aux`` is the layer's
+    routing counters (``moe.route_stats``), no loss term; ``mesh`` theirs."""
     dt = c.dtype
     if "w_gate" in lp:
         return _swiglu(c, h, lp), 0.0
     if c.experts_per_token:
         from ray_tpu.models.moe import topk_moe_mlp
         if stats:
-            return topk_moe_mlp(c, lp, h, layer, stats=True)
+            return topk_moe_mlp(c, lp, h, layer, stats=True, mesh=mesh)
         return topk_moe_mlp(c, lp, h, layer), 0.0
     if c.n_experts:
         from ray_tpu.models.moe import moe_mlp
@@ -1692,7 +1692,7 @@ def run_layers(config: TransformerConfig, layer_params: Dict,
                 c, kind, x, lp,
                 lambda h: (_attn_sublayer(c, kind, h, lp, sin, cos,
                                           mesh, rules), None),
-                lambda h: _mlp_sublayer(c, h, lp, stats=stats))
+                lambda h: _mlp_sublayer(c, h, lp, stats=stats, mesh=mesh))
             return out, aux
         if policy != "none":
             body = jax.checkpoint(body, policy=remat_policy_fn(policy))

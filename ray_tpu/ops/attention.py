@@ -48,8 +48,8 @@ def dispatch_log() -> List[Dict[str, object]]:
     """Every attention dispatch decision this process has traced:
     ``{"op": "flash"|"paged"|"latent"|"ssm_step"|"delta_step" (the
     one-token state updates, ``ops/ssm.py`` and ``ops/delta.py``)
-    |"sparse_decode"|"sparse_chunk" (a selecting model's attention over
-    per-head K/V, ``ops/sparse_attention.py``),
+    |"sparse_decode"|"sparse_chunk" (``ops/sparse_attention.py``)
+    |"grouped_dot" (the experts' differentiated one, ``models/moe.py``),
     "impl": "kernel"|"interpret"|"reference",
     "why": ..., "count": n}``. ``why`` is ``"auto"`` or ``"requested"``
     for a kernel, and for a reference either ``"requested"`` or the

@@ -312,6 +312,13 @@ class _SPMDProgram(TrainProgram):
                 compiled = self.bundle.step_fn._cache_size()
                 if compiled != self._compiled:
                     self._compiled, clock.kind = compiled, "compile"
+                    products = self.bundle.grouped_products
+                    if compiled == 1 and any(products.values()):
+                        logger.info(
+                            "plan %s: experts' grouped products: %d "
+                            "pallas_gmm, %d xla_ragged_dot",
+                            self.plan.describe(), products["pallas_gmm"],
+                            products["xla_ragged_dot"])
             with clock.phase("train.wait"):
                 loss = float(metrics["loss"])
             with clock.phase("train.tail"):

@@ -15,6 +15,7 @@ import pytest
 
 from benchmarks.reference import mellum
 from ray_tpu.models import TransformerConfig, init_params
+from ray_tpu.models import moe
 from ray_tpu.models.moe import topk_moe_mlp
 from ray_tpu.models.transformer import lm_loss, untrained_keys
 
@@ -309,3 +310,272 @@ def test_a_token_that_routes_by_its_own_embedding_evens_the_held_share():
     narrow, wide = held(0.02), held(0.32)
     assert np.max(np.abs(wide - even)) < 0.03 * even
     assert np.ptp(wide) < np.ptp(narrow) / 3
+
+
+# ------------------- the differentiated grouped product's Pallas rules
+ROWS, GROUPS = 1024, 5                    # two row tiles of 512
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The rules as on a TPU, their kernels in Pallas interpret mode: the
+    steering is the test's, the program has no switch."""
+    import functools
+    import types
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_megablox", types.SimpleNamespace(
+        gmm=functools.partial(moe._megablox.gmm, interpret=True),
+        tgmm=functools.partial(moe._megablox.tgmm, interpret=True)))
+
+
+def _product(sizes, dtype, k=128, n=256, seed=7, one_chip=True):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(ks[0], (ROWS, k), dtype)
+    w = 0.1 * jax.random.normal(ks[1], (GROUPS, k, n), jnp.float32)
+    g = jax.random.normal(ks[2], (ROWS, n), jnp.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    y, vjp = jax.vjp(lambda x, w: moe._grouped_dot(x, w, sizes, one_chip),
+                     x, w)
+    return (y,) + vjp(g)
+
+
+def _plain(sizes, dtype, k=128, n=256, seed=7):
+    """``ragged_dot`` / ``ragged_dot_general`` on the same operands, with
+    nothing of ``models/moe.py`` between."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(ks[0], (ROWS, k), dtype)
+    w = (0.1 * jax.random.normal(ks[1], (GROUPS, k, n), jnp.float32))
+    g = jax.random.normal(ks[2], (ROWS, n), jnp.float32).astype(dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    wc = w.astype(dtype)
+    y = jax.lax.ragged_dot(x, wc, sizes, preferred_element_type=jnp.float32)
+    dx = jax.lax.ragged_dot(g, jnp.swapaxes(wc, 1, 2), sizes,
+                            preferred_element_type=dtype)
+    dw = jax.lax.ragged_dot_general(
+        x, g, sizes, moe._DW_DIMS, preferred_element_type=jnp.float32)
+    return y, dx, dw
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("sizes,what", [
+    ((300, 0, 400, 100, 0), "empty groups, a group across the row tile, "
+                            "224 rows of no group behind the last"),
+    ((512, 512, 0, 0, 0), "groups that end on the row tile, none behind"),
+    ((0, 0, 0, 0, 7), "one short group behind four empty ones"),
+    ((0, 0, 0, 0, 0), "no row in any group"),
+    ((1, 1021, 1, 1, 0), "a group over both row tiles"),
+])
+def test_the_pallas_rules_are_the_grouped_product(interpreted, sizes, what,
+                                                  dtype):
+    """The VJP rules in Pallas interpret mode against XLA's grouped
+    products: the forward, dX (zero on the rows of no group) and dW
+    (float32 for a float32 master), each to the accumulation's order."""
+    before = moe.grouped_product_counts()
+    y, dx, dw = _product(sizes, dtype)
+    after = moe.grouped_product_counts()
+    assert after["pallas_gmm"] == before["pallas_gmm"] + 1, what
+    assert after["xla_ragged_dot"] == before["xla_ragged_dot"]
+    want_y, want_dx, want_dw = _plain(sizes, dtype)
+    live = np.arange(ROWS) < sum(sizes)
+    f32 = lambda a: np.asarray(a, np.float32)
+    ulp = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5
+    # a row of no group is whatever the product left there, in the
+    # forward: only the groups' rows are compared
+    np.testing.assert_allclose(f32(y)[live], f32(want_y)[live],
+                               rtol=1e-5, atol=1e-5, err_msg=what)
+    assert dx.dtype == dtype and dw.dtype == jnp.float32
+    np.testing.assert_allclose(f32(dx)[live], f32(want_dx)[live],
+                               rtol=ulp, atol=ulp, err_msg=what)
+    assert not np.any(f32(dx)[~live]), what
+    np.testing.assert_allclose(f32(dw), f32(want_dw), rtol=1e-5,
+                               atol=1e-4, err_msg=what)
+
+
+@pytest.mark.parametrize("rows,k,n,why", [
+    (1000, 128, 256, "rows no multiple of the row tile"),
+    (1024, 96, 256, "the contraction no multiple of 128"),
+    (1024, 128, 200, "the result's width no multiple of 128"),
+])
+def test_a_shape_the_rule_cannot_tile_stays_ragged_dot(interpreted, rows, k,
+                                                       n, why):
+    assert moe._gmm_tiles(rows, k, n, "bfloat16") is None, why
+    x = jnp.ones((rows, k), jnp.bfloat16)
+    w = jnp.ones((2, k, n), jnp.float32)
+    sizes = jnp.asarray([rows // 2, rows // 4], jnp.int32)
+    before = moe.grouped_product_counts()
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda x, w: jnp.sum(moe._grouped_dot(x, w, sizes, True)),
+        argnums=(0, 1)))(x, w))
+    after = moe.grouped_product_counts()
+    assert "pallas_call" not in text and "ragged_dot" in text
+    assert after["xla_ragged_dot"] == before["xla_ragged_dot"] + 1
+    assert after["pallas_gmm"] == before["pallas_gmm"]
+
+
+def test_off_a_tpu_the_rules_are_ragged_dot_and_counted_so():
+    before = moe.grouped_product_counts()
+    _product((300, 0, 400, 100, 0), jnp.bfloat16)
+    after = moe.grouped_product_counts()
+    assert after == {"pallas_gmm": before["pallas_gmm"],
+                     "xla_ragged_dot": before["xla_ragged_dot"] + 1}
+
+
+def test_a_program_over_several_chips_keeps_ragged_dot(monkeypatch):
+    """XLA cannot partition a Pallas custom call: under a mesh of more
+    than one device (or with none named) the rules stay ``ragged_dot``
+    on a TPU too, at widths the kernels would tile, and the step program
+    of a plan over two chips lowers no grouped body."""
+    from ray_tpu.models.training import make_train_step
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = moe.grouped_product_counts()
+    _product((300, 0, 400, 100, 0), jnp.bfloat16, one_chip=False)
+    assert moe.grouped_product_counts()["xla_ragged_dot"] \
+        == before["xla_ragged_dot"] + 1
+    cfg = TransformerConfig(**{
+        **TINY, "dtype": jnp.bfloat16, "d_model": 128, "expert_width": 128,
+        "max_seq_len": 256, "head_dim": 32, "rotary_dim": 32,
+        "window_rotary_dim": 32})
+    counts = {}
+    for chips in (1, 2):
+        mesh = build_mesh(MeshSpec(dp=1, fsdp=chips), jax.devices()[:chips])
+        bundle = make_train_step(cfg, mesh, telemetry_interval_s=0)
+        state = jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0))
+        batch = {"input_ids": jax.ShapeDtypeStruct((2, 256), jnp.int32),
+                 "loss_mask": jax.ShapeDtypeStruct((2, 256), jnp.float32)}
+        text = bundle.step_fn.trace(state, batch).lower(
+            lowering_platforms=("tpu",)).as_text()
+        counts[chips] = (_grouped_bodies(text), bundle.grouped_products)
+    assert counts[1][1] == {"pallas_gmm": 6, "xla_ragged_dot": 0}
+    assert counts[1][0] >= 3
+    assert counts[2] == (0, {"pallas_gmm": 0, "xla_ragged_dot": 6})
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (32768, 2304, 896), (32768, 896, 2304),    # the cell's turn, up / down
+    (512, 128, 128), (4096, 4096, 14336), (8192, 1024, 512),
+    (1024, 7168, 2048), (2048, 2048, 7168)])
+def test_every_tiling_the_rule_picks_is_inside_its_budget(rows, k, n):
+    """Tiles divide the shape, stand on whole lane tiles, and the blocks'
+    bytes stay inside the budget (every tiling that does compiles for a
+    v5e; tests/ops/test_tpu_lowering.py compiles the cell's)."""
+    tiles = moe._gmm_tiles(rows, k, n, "bfloat16")
+    for (tm, tk, tn), (kk, nn), out_size, dw in zip(
+            tiles, ((k, n), (n, k), (k, n)), (4, 2, 4),
+            (False, False, True)):
+        assert rows % tm == 0 and kk % tk == 0 and nn % tn == 0
+        assert tk % 128 == 0 and tn % 128 == 0
+        assert moe._block_bytes(tm, tk, tn, 2, out_size, dw) \
+            <= moe._VMEM_BUDGET
+    if (k, n) == (2304, 896):
+        assert tiles == ((512, 768, 896), (512, 896, 768), (512, 768, 896))
+
+
+def _grouped_bodies(text):
+    """Pallas bodies of the grouped kernels in a lowered program's text:
+    the ``tpu_custom_call``s inside megablox's jitted ``gmm`` / ``tgmm``."""
+    return sum(part.count("tpu_custom_call")
+               for part in text.split("func.func")
+               if part.lstrip().startswith(("private @gmm", "private @tgmm")))
+
+
+def test_the_cells_two_programs_hold_at_most_eight_grouped_bodies(
+        monkeypatch):
+    """Lowered for a TPU with no chip, at the widths of
+    ``mellum2-12b-a2.5b.train_moe_8k``: the check's loss-and-gradient
+    program (2 x 4096, traced first, as the cell's set-up does) and the
+    step program (2 x 8192) each lower SIX distinct kernels to at most
+    eight bodies (a turn's forward and its recomputation lower one each),
+    not one a product, a turn and a scan; no ``ragged_dot`` is left in
+    either, and the bundle carries the step's count by form. This pins
+    what the kernels add to the cell's set-up."""
+    from benchmarks import spec
+    from ray_tpu.models.training import default_optimizer, make_train_step
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = spec.load_cell("mellum2-12b-a2.5b.train_moe_8k")
+    p = cell.params
+    cfg = dataclasses.replace(
+        TransformerConfig(**{**cell.model_kwargs(), "dtype": jnp.bfloat16}),
+        max_seq_len=p["seq"], remat_policy=p["remat_policy"])
+    mesh = build_mesh(MeshSpec(dp=1, fsdp=1), jax.devices()[:1])
+    bundle = make_train_step(
+        cfg, mesh, optimizer=default_optimizer(p["learning_rate"], 0.0, 1.0),
+        telemetry_interval_s=0)
+    assert bundle.grouped_products == {}
+    state = jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0))
+
+    def batch(seq):
+        return {"input_ids": jax.ShapeDtypeStruct((2, seq), jnp.int32),
+                "loss_mask": jax.ShapeDtypeStruct((2, seq), jnp.float32)}
+
+    def check(params, batch):
+        return jax.value_and_grad(lambda q: lm_loss(
+            cfg, q, batch, mesh=mesh, rules=bundle.rules)[0])(params)
+    for name, fn, args in (
+            ("check", jax.jit(check),
+             (state["params"], batch(p["check_seq"]))),
+            ("step", bundle.step_fn, (state, batch(p["seq"])))):
+        text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        assert 6 <= _grouped_bodies(text) <= 8, name
+        assert "ragged_dot" not in text, name
+    # two scans (the window run, the full layer), three products each
+    assert bundle.grouped_products == {"pallas_gmm": 6, "xla_ragged_dot": 0}
+
+
+def test_a_served_program_lowers_no_grouped_kernel(monkeypatch):
+    """One decode step and one chunk of a model whose differentiated
+    products DO tile: lowered for a TPU, neither holds a ``gmm`` body,
+    and each is to the letter the text it lowers to with the rules
+    patched back to ``ragged_dot``; the same layer's gradient holds the
+    kernels. Only differentiation reaches the rules."""
+    from ray_tpu.models import decode_step, init_kv_cache, prefill
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig(**{
+        **TINY, "dtype": jnp.bfloat16, "d_model": 128, "expert_width": 128,
+        "experts_per_token": 2, "paged_impl": "reference"})
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, 1 + 256 // 16, 16))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def lowered():
+        chunk = jax.jit(lambda p, t, c, bt, s, n: prefill(
+            cfg, p, t, c, bt, s, n)).trace(
+                params, i32(1, 256), cache, i32(1, 16), i32(1), i32(1))
+        step = jax.jit(lambda p, t, c, bt, pos: decode_step(
+            cfg, p, t, c, bt, pos)).trace(
+                params, i32(4), cache, i32(4, 16), i32(4))
+        return [t.lower(lowering_platforms=("tpu",)).as_text()
+                for t in (chunk, step)]
+    before = moe.grouped_product_counts()
+    served = lowered()
+    assert moe.grouped_product_counts() == before
+    for text in served:
+        assert _grouped_bodies(text) == 0 and "ragged_dot" in text
+    monkeypatch.setattr(moe, "_gmm_tiles", lambda *a: None)
+    assert lowered() == served
+    monkeypatch.undo()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lp = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
+                      params["layers"])
+    h = jax.ShapeDtypeStruct((1, 256, 128), jnp.bfloat16)
+    one = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("fsdp",))
+    grad = jax.jit(jax.grad(lambda lp, h: jnp.sum(
+        topk_moe_mlp(cfg, lp, h, mesh=one).astype(jnp.float32)))).trace(lp, h)
+    text = grad.lower(lowering_platforms=("tpu",)).as_text()
+    assert _grouped_bodies(text) == 3     # gmm, its transpose, tgmm
+
+
+def test_the_plan_logs_the_grouped_products_by_form(caplog):
+    import logging
+    with caplog.at_level(logging.INFO, logger="ray_tpu.parallel.plan"):
+        _, prog, _ = _plan_run({"fsdp": 1}, steps=2)
+    # off a TPU every product is ragged_dot: two scans, three products
+    assert prog.bundle.grouped_products == {"pallas_gmm": 0,
+                                            "xla_ragged_dot": 6}
+    lines = [r.getMessage() for r in caplog.records
+             if "grouped products" in r.getMessage()]
+    assert len(lines) == 1 and lines[0].endswith(
+        "experts' grouped products: 0 pallas_gmm, 6 xla_ragged_dot")
+

@@ -936,3 +936,42 @@ def test_compiled_step_of_one_sublayer_a_layer_keeps_its_state_in_place(
         r"= f32\[(?:\d+,)+(?:128,8192|8192,128)\]\S* ([\w\-]+)\(", text)
     assert not [op for op in made if op not in (
         "parameter", "get-tuple-element", "bitcast")], made
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304), (1024, 1024)],
+                         ids=["up", "down", "the widest blocks"])
+def test_grouped_kernels_compile_for_v5e_at_the_rules_tiles(one_chip, k, n):
+    """The trained experts' three kernels (``models/moe.py``: megablox
+    ``gmm``, its transpose, ``tgmm``) at the tiles the shape rule picks
+    for a turn of ``mellum2-12b-a2.5b.train_moe_8k`` (32,768 rows of 16
+    experts, 2304 x 896 and back) and at the widest blocks it can pick
+    (1024 x 1024: the most VMEM it accepts for the forward and dX; dW's
+    most is the turn's own): the Mosaic compile accepts each for a
+    described v5e. The next tile up of each is refused there for its
+    VMEM (PERF.md, PR 64), which is what the rule's budget keeps out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from ray_tpu.models import moe
+    rows, groups = 32768, 16
+    fwd, dx, dw = moe._gmm_tiles(rows, k, n, "bfloat16")
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    sizes = s((groups,), jnp.int32)
+    calls = [
+        (lambda x, w, z: moe._megablox.gmm(x, w, z, jnp.float32, fwd),
+         s((rows, k)), s((groups, k, n))),
+        (lambda g, w, z: moe._megablox.gmm(g, w, z, jnp.bfloat16, dx,
+                                           transpose_rhs=True),
+         s((rows, n)), s((groups, k, n))),
+        (lambda x, g, z: moe._megablox.tgmm(x.T, g, z, jnp.float32, dw),
+         s((rows, k)), s((rows, n)))]
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for fn, a, b in calls:
+            compiled = jax.jit(fn).lower(a, b, sizes).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
