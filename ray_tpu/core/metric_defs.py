@@ -161,8 +161,7 @@ class RuntimeMetrics:
             "update_from_state gauge-refresh failures (a broken gauge "
             "path is visible here instead of silently swallowed)",
             tag_keys=("source",))
-        # -- training telemetry (models/training.py + MPMDPipeline):
-        # the live versions of what bench.py records offline
+        # -- training telemetry (models/training.py + MPMDPipeline)
         self.train_step_wall = Histogram(
             "train_step_wall_seconds",
             "Wall time per optimizer step (dispatch to completion)",
@@ -177,7 +176,7 @@ class RuntimeMetrics:
             "train_grad_norm", "Most recent global gradient norm")
         self.train_mfu = Gauge(
             "train_mfu_pct",
-            "Model FLOP utilization (%) from the bench FLOP model "
+            "Model FLOP utilization (%) from the configuration's FLOP model "
             "(flops_per_token x tokens/s over the chip's bf16 peak)")
         # the dropless experts' routing counters of a training step
         # (models/moe.py route_stats)

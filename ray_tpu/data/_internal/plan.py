@@ -26,8 +26,7 @@ design keeps the reference's two key properties, re-expressed compactly:
 
 ``DataContext.execution_mode = "staged"`` selects the serialized
 baseline (per-block tasks, in-order window, materialize barrier between
-stages) that ``bench.py --data`` measures the streaming executor
-against.
+stages).
 
 All-to-all ops (shuffle/sort/repartition) are barriers, as in the
 reference's exchange operators (``planner/exchange/``).

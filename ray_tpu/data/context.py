@@ -31,7 +31,7 @@ class DataContext:
     #: stream, so stage N+1 starts the moment stage N yields its first
     #: block. "staged": the serialized baseline — per-block tasks with
     #: an in-order submission window and a materialize barrier between
-    #: stages (what `bench.py --data` measures streaming against).
+    #: stages.
     execution_mode: str = "streaming"
     #: yield blocks in submission order (deterministic — what `sort`/
     #: `limit`/`take` assume) instead of completion order. Disable for

@@ -78,14 +78,13 @@ class TrainStepBundle:
         return out
 
     def _telemetry(self, batch: Dict, metrics: Dict) -> None:
-        """Per-step training telemetry into the fleet metrics plane —
-        the live version of what bench.py records offline. Steps are
-        only *counted* on the hot path; every ``telemetry_interval_s``
-        the accumulated window is closed: block on the (already
-        dispatched) step metrics, then set tokens/s, an MFU gauge from
-        the bench FLOP model (flops_per_token x tokens/s over the
-        chip's bf16 peak across the mesh), loss and grad norm, and
-        observe the mean step wall. Never raises; the interval gate
+        """Per-step training telemetry into the fleet metrics plane.
+        Steps are only *counted* on the hot path; every
+        ``telemetry_interval_s`` the accumulated window is closed: block
+        on the (already dispatched) step metrics, then set tokens/s, an
+        MFU gauge from the configuration's FLOP model (flops_per_token x
+        tokens/s over the chip's bf16 peak across the mesh), loss and
+        grad norm, and observe the mean step wall. Never raises; the gate
         keeps device syncs off the steady-state step path."""
         if self.telemetry_interval_s <= 0:
             return

@@ -160,8 +160,8 @@ def paged_work_pages(lens, block_size: int):
     """Pages a length-aware kernel reads per sequence:
     ``ceil(lens / block_size)``, and 0 for ``lens <= 0`` (a decode slot
     that holds no sequence: the kernel reads no page for it). Works on
-    numpy and jax arrays — the engine's FLOP accounting and the bench's
-    work-reduction math share this definition with the kernel."""
+    numpy and jax arrays — the engine's FLOP accounting shares this
+    definition with the kernel."""
     return ((lens + block_size - 1) // block_size).clip(min=0) \
         if hasattr(lens, "clip") else max(-(-lens // block_size), 0)
 

@@ -46,9 +46,7 @@ Recovery emits ``ELASTIC_NOTICE`` / ``ELASTIC_SNAPSHOT`` /
 ``ELASTIC_RELOWER`` / ``ELASTIC_RESUME`` flight-recorder events
 (``core/events.py``); ``ELASTIC_RESUME`` carries ``dur_s`` = the full
 notice-to-resume window, so ``tools/timeline.py`` renders the recovery
-as a duration slice — the preemption postmortem. ``bench.py
---elastic`` measures recovery wall-clock, steps lost and post-recovery
-trajectory parity, gated by ``tools/perf_gate.py --metric elastic``.
+as a duration slice — the preemption postmortem.
 """
 
 from __future__ import annotations

@@ -48,7 +48,7 @@ Every forward/backward/opt/idle interval is recorded as a
 virtual-stage (chunk) index, so the Perfetto ``/timeline`` export
 doubles as the bubble visualization, and
 :meth:`PipelineStage.step_stats` returns the measured busy/idle split
-the bench turns into a bubble fraction.
+(the bubble fraction).
 
 Checkpointing: :meth:`PipelineStage.stage_checkpoint` returns the
 stage's param/opt-state slices keyed by global chunk id;
@@ -401,9 +401,8 @@ class PipelineStage:
         self.quant_stochastic = bool(quant_stochastic)
         #: shard_map'd stage programs: automatic when the stage grid is
         #: nontrivial; ``stage_mesh=True`` forces the path onto a
-        #: 1-device mesh (the bench's comm/compute reference and the
-        #: clusterless tests use this to exercise the 3D programs
-        #: without multiple devices)
+        #: 1-device mesh (the clusterless tests use this to exercise
+        #: the 3D programs without multiple devices)
         self.use_mesh = (self.n_model > 1 if stage_mesh is None
                          else bool(stage_mesh))
         #: seconds a mailbox take may starve before the stage fails
@@ -1412,8 +1411,7 @@ class MPMDPipeline:
                                res: PipelineStepResult) -> None:
         """Per-step training telemetry into the fleet metrics plane:
         step wall, tokens/s, measured bubble, grad norm and an MFU
-        gauge from the bench FLOP model — the live versions of what
-        ``bench.py --pipeline`` records offline."""
+        gauge from the configuration's FLOP model."""
         try:
             from ray_tpu.core.metric_defs import runtime_metrics
             m = runtime_metrics()

@@ -96,7 +96,7 @@ def unpack_weights(packed: Dict[str, Any]
 
 def packed_wire_bytes(packed: Dict[str, Any]) -> int:
     """Actual payload bytes of one refresh (int8 values + f32 scales +
-    raw leaves) — the number the bench's compression column reports."""
+    raw leaves)."""
     total = 0
     for e in packed["entries"].values():
         if "raw" in e:

@@ -19,8 +19,7 @@ surfaces whichever runner has a block buffered (one straggler never
 stalls the learner), blocks re-chunk into fixed minibatches via
 ``iter_batches`` (numpy twin of ``data.iterator.
 iter_batches_over_blocks``), and the time the consumer spends blocked
-with no block ready is measured as the rollout→train *bubble* —
-the number ``bench.py --data`` reports streaming vs epoch-barriered.
+with no block ready is measured as the rollout→train *bubble*.
 """
 
 from __future__ import annotations
@@ -96,7 +95,7 @@ def rollout_stream(env_creator: Callable[[], Any],
     a small info dict (episode returns, ids).
 
     ``fault={"die_at_block": i, "marker": path}`` is the chaos hook
-    used by tests and the bench's kill leg: the first execution
+    used by tests: the first execution
     SIGKILLs its own worker right before yielding block ``i`` (and
     drops a marker file so the lineage replay runs through)."""
     from ray_tpu.rllib.env_runner import EnvRunner

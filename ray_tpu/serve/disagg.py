@@ -191,8 +191,8 @@ class _DisaggMethod:
 
 
 class _DisaggOptions:
-    """``handle.options(...)`` shim so the bench harness's ``run_load``
-    drives a :class:`DisaggRouter` exactly like a DeploymentHandle:
+    """``handle.options(...)`` shim so a caller drives a
+    :class:`DisaggRouter` exactly like a DeploymentHandle:
     ``router.options(stream=True).generate.remote(prompt, n)``."""
 
     def __init__(self, router: "DisaggRouter", opts: Dict[str, Any]):
@@ -321,10 +321,10 @@ class DisaggRouter:
                 request_id: Optional[str] = None,
                 routing_policy: Optional[str] = None,
                 **kwargs) -> _DisaggOptions:
-        """Handle-compatible surface for the bench harness. Disagg
-        requests are always streamed and always gauge-routed;
-        ``routing_policy`` is accepted (and ignored beyond validation)
-        so ``run_load``'s handle_opts pass through unchanged."""
+        """Handle-compatible surface. Disagg requests are always
+        streamed and always gauge-routed; ``routing_policy`` is accepted
+        (and ignored beyond validation) so a DeploymentHandle's options
+        pass through unchanged."""
         if kwargs:
             raise TypeError(
                 f"unsupported disagg options: {sorted(kwargs)}")

@@ -14,7 +14,7 @@ policies (``options(routing_policy=...)``, default ``"gauge"``):
 - ``"pow2"`` — classic power-of-two-choices on the router's own
   outstanding-refs count per replica plus live streams.
 - ``"round_robin"`` — cycle the membership list (the pre-gauge
-  baseline; ``bench_serve --fleet`` measures gauge routing against it).
+  baseline).
 
 ``options(session_id=...)`` adds **session affinity**: every call with
 the same session id lands on the same replica while it lives, so a

@@ -141,7 +141,7 @@ class EngineConfig:
     capture_logprobs: bool = False
     #: Per-request tracing (serve/request_trace.py): None follows the
     #: runtime config's enable_request_trace; True/False force it for
-    #: this engine (bench_serve's trace-overhead on/off legs).
+    #: this engine (a benchmark cell's engines turn it off).
     enable_trace: Optional[bool] = None
     #: Tokens per DECODE trace span — bounds span count for long
     #: generations (a 4k-token decode is ~256 spans at 16, not 4k).
@@ -719,7 +719,7 @@ class LLMEngine:
         # cheaper than an explicit device_put ahead of the call), so it
         # equals prefill_chunks + decode_steps
         self._h2d_transfers = 0
-        # device-wall split (the kernel-vs-reference bench reads these):
+        # device-wall split:
         # a program's wall runs from its staging, or from the fetch of
         # the program before it where that came later (it was launched
         # ahead and queued behind that one), to its own fetch, so the
@@ -743,11 +743,9 @@ class LLMEngine:
                              for cls in ("device", "serial")
                              for kind in ("prefill", "decode")}
         # length-aware work accounting: pages a lens-skipping kernel
-        # touches per decode step vs the full table window — FLOPs are
-        # proportional to pages, so live/window IS the measured
-        # work fraction of the paged fast path (any backend)
+        # touches, summed over decode steps (FLOPs are proportional to
+        # pages)
         self._decode_pages_live = 0
-        self._decode_pages_window = 0
         self._decode_slots_skipped = 0
         # by kind of layer: pages ONE layer of the kind reads in the
         # decode programs and in the chunks (full: the sequence's live
@@ -837,14 +835,12 @@ class LLMEngine:
                 "prefill": chunk_choice(model_config.paged_impl, pool)}
         self._prompt_blocks_total = 0   # full prompt blocks seen
         self._cow_copies = 0
-        # disagg hand-off accounting (the bench's per-request ship
-        # bytes/wall come from here; exports count on the prefill
+        # disagg hand-off accounting (exports count on the prefill
         # fleet, adopts on the decode fleet)
         self._kv_exports = 0
         self._kv_adopts = 0
         self._kv_adopt_bytes = 0
         self._kv_adopt_blocks = 0
-        self._kv_ship_wall_s = 0.0
         self._spec_drafted = 0
         self._spec_accepted = 0
         self._spec_disables = 0
@@ -1422,7 +1418,7 @@ class LLMEngine:
             for booked in self._class_walls.values():
                 booked[:] = 0, 0.0
             self._found_ready = dict.fromkeys(self._found_ready, 0)
-            self._decode_pages_live = self._decode_pages_window = 0
+            self._decode_pages_live = 0
             self._decode_slots_skipped = 0
             self._decode_grid_steps = self._decode_grid_steps_live = 0
             for booked in self._row_blocks.values():
@@ -1477,8 +1473,7 @@ class LLMEngine:
                 # the launches that waited for the fetch, by reason
                 "programs_ahead_total": self._programs_ahead,
                 "ahead_blocked_total": dict(self._ahead_blocked),
-                # device-wall split + length-aware work fraction (the
-                # paged-kernel bench legs and perf gate read these)
+                # device-wall split and length-aware page accounting
                 "decode_wall_s": round(self._decode_wall_s, 4),
                 "prefill_wall_s": round(self._prefill_wall_s, 4),
                 # the walls by class: *_device_* of programs launched
@@ -1490,17 +1485,12 @@ class LLMEngine:
                 **self._class_wall_stats(),
                 "fetch_found_ready_total": dict(self._found_ready),
                 "decode_pages_live": self._decode_pages_live,
-                "decode_pages_window": self._decode_pages_window,
                 # slots a decode step staged with no sequence (the
                 # kernel reads nothing for them), summed over steps:
                 # decode_steps x decode_slots less the occupancy
                 "decode_slots_skipped_total": self._decode_slots_skipped,
                 **self._window_stats(),
                 **self._state_stats(),
-                "decode_block_work_frac": (
-                    round(self._decode_pages_live
-                          / self._decode_pages_window, 4)
-                    if self._decode_pages_window else None),
                 # how often the kernel's page groups engage: grid steps
                 # a decode call took on its innermost axis, those with
                 # a live page to fold, and their ratio
@@ -1575,15 +1565,13 @@ class LLMEngine:
                 # in-flight weight refresh accounting (RLHF rollout
                 # backend): swaps are pointer flips between decode
                 # steps, so sync_stall_s — decode time lost waiting on
-                # a refresh — must stay 0.0 (the bench gates on it)
+                # a refresh — must stay 0.0
                 # disagg hand-off accounting: exports tick on the
-                # prefill fleet, adopts (+ ship wall measured
-                # ship_ts -> adoption-complete) on the decode fleet
+                # prefill fleet, adopts on the decode fleet
                 "kv_exports": self._kv_exports,
                 "kv_adopts": self._kv_adopts,
                 "kv_adopt_bytes": self._kv_adopt_bytes,
                 "kv_adopt_blocks": self._kv_adopt_blocks,
-                "kv_ship_wall_s": round(self._kv_ship_wall_s, 4),
                 "weight_version": self._weight_version,
                 "weight_swaps": self._weight_swaps,
                 # bytes of the tree the programs take, and how many
@@ -2224,7 +2212,6 @@ class LLMEngine:
         self._kv_adopts += 1
         self._kv_adopt_bytes += int(payload.get("wire_bytes") or 0)
         self._kv_adopt_blocks += len(dst)
-        self._kv_ship_wall_s += max(0.0, t1w - ship_ts)
         if self._metrics is not None:
             try:
                 self._metrics.serve_kv_ship_seconds.observe(
@@ -2564,7 +2551,6 @@ class LLMEngine:
         self._decode_pages_live += int(pages.sum())
         self._ssm["decode_rows"] += live_rows
         self._decode_slots_skipped += len(pages) - live_rows
-        self._decode_pages_window += ec.decode_slots * ec.blocks_per_seq
         steps, live = paged_grid_steps(pages, ec.blocks_per_seq,
                                        self._decode_pages_per_step)
         self._decode_grid_steps += steps

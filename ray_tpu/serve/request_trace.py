@@ -30,9 +30,9 @@ SHED           terminal: rejected by admission before any replica
 =============  =====================================================
 
 Spans are recorded locally in a bounded per-request buffer at
-flight-recorder cost (one dict + append, ~couple µs — bench_serve
-guards the <=20µs bound) and ship to the controller as REQUEST_SPANS
-(``b"RSP"``) messages riding the PR-2 reliable layer exactly like TEV:
+flight-recorder cost (one dict + append) and ship to the controller as
+REQUEST_SPANS (``b"RSP"``) messages riding the PR-2 reliable layer
+exactly like TEV:
 fire-and-forget for the producer, chaos-droppable, exactly-once-effect
 at the controller (the store additionally dedups by
 ``(request_id, part, seq)`` so a dup never doubles a waterfall).
@@ -117,8 +117,8 @@ class RequestTrace:
     def span(self, phase: str, t0: float, t1: Optional[float] = None,
              **attrs: Any) -> None:
         """Record one phase span (wall-clock seconds; ``t1=None`` makes
-        an instant). Must stay O(1) and allocation-light: bench_serve
-        guards a <=20µs bound on this call."""
+        an instant). Must stay O(1) and allocation-light: the engine's
+        step thread calls it."""
         if t1 is None:
             t1 = t0
         elif t1 < t0:

@@ -3,8 +3,7 @@ sharded weight update (``make_train_step(grad_transport=,
 shard_weight_update=)``) vs the fp32 replicated baseline.
 
 Model kept tiny (1 layer, d=32) so the three compiled step programs fit
-the suite's time budget; the same paths run at bench scale via
-``bench.py``'s MULTICHIP variants.
+the suite's time budget.
 """
 
 import dataclasses

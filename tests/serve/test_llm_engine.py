@@ -339,26 +339,19 @@ def test_compile_once_with_sharing_and_speculation(engine_spec):
 
 
 def test_stats_decode_wall_split_and_page_accounting(engine4):
-    """PR-15 stat surface: the prefill/decode device-wall split and the
-    length-aware page accounting (live pages / window pages < 1 for
-    short sequences in a wide window) that the bench's mixed-length leg
-    and the paged kernel's FLOP claim read."""
+    """The prefill/decode device-wall split and the length-aware page
+    accounting: the pages a decode step's sequences hold and the slots
+    it staged with none, summed over steps."""
     was = engine4.stats()
     list(engine4.generate_sync([3, 1, 4, 1, 5], max_new_tokens=6))
     s = engine4.stats()
     assert s["decode_wall_s"] > 0 and s["prefill_wall_s"] > 0
-    assert 0 < s["decode_pages_live"] <= s["decode_pages_window"]
     # five decode steps of the one sequence (5 prompt tokens): its
     # pages alone, the three slots beside it read nothing
     assert s["decode_pages_live"] - was["decode_pages_live"] == sum(
         -(-(5 + i + 1) // 4) for i in range(5))
     assert s["decode_slots_skipped_total"] \
         - was["decode_slots_skipped_total"] == 5 * 3
-    frac = s["decode_block_work_frac"]
-    assert frac == pytest.approx(
-        s["decode_pages_live"] / s["decode_pages_window"], abs=1e-3)
-    # short sequences in a 12-block window: most pages are skippable
-    assert frac < 0.5
     assert s["kv_block_size"] == 4
     assert s["paged_impl"] == "auto"
     _assert_clean(engine4, 4)

@@ -64,7 +64,7 @@ def test_one_helper_holds_every_mention():
     """`grep -rn compilation_cache` finds the helper and nothing else
     in the program."""
     hits = []
-    for top in ("ray_tpu", "chip_smoke.py", "bench.py", "bench_serve.py"):
+    for top in ("ray_tpu", "chip_smoke.py"):
         path = os.path.join(REPO, top)
         files = [path] if os.path.isfile(path) else [
             os.path.join(d, f) for d, _, fs in os.walk(path)
